@@ -11,32 +11,18 @@ performance numbers (``/root/reference/README.md`` is qualitative only;
 BASELINE.json ``published: {}``), so there is no external number to ratio
 against; cross-round BENCH_r{N}.json values are the comparable series.
 
-A bare ``python bench.py`` (the driver's invocation) runs **suite mode**
-(``run_suite``): a budget-capped backend escape (≤~20% of the claim
-window — round 4 burned 97% of its window on one probe and never ran the
-bench), then the cheapest real metric first (SD1.5 512px), then the SDXL
-1024px headline with MFU and a clip/denoise/vae phase split.  Every
-completed phase is flushed to stdout/--out immediately, and the SIGTERM
-watchdog re-emits the best completed phase instead of a zero, so a
-driver timeout mid-compile can no longer zero the round.  If the backend
-is unreachable inside the capped budget, the suite replays this round's
-recovery-loop on-chip artifact with explicit provenance rather than
-reporting 0.0 (the patient ≥claim-window probing lives in
-``benchmarks/tpu_recovery_loop.sh``, which runs all round).
+A bare ``python bench.py`` runs **suite mode** (``run_suite``): the
+cheapest real metric first (SD1.5 512px), then the SDXL 1024px headline
+with MFU and a clip/denoise/vae phase split, then the CPU contract phases
+in subprocesses.  Every completed phase is flushed to stdout/--out as it
+lands.
 
-Resilience (rounds 1+2 both died in ``jax.devices()`` — the TPU client can
-hang *or* crash intermittently when the chip is held by a stale process):
-
-* the backend is probed in a **subprocess with a hard timeout** through the
-  shared escape ladder (``parallel/mesh.py``): the env config retried with
-  escalating 60→300 s sleeps across a ≥25 min budget, alternate
-  ``JAX_PLATFORMS`` configs ('' / 'tpu') tried whenever the env one hangs,
-  every rung's result logged into the failure artifact;
-* the in-process init is guarded by a **watchdog thread** that emits the
-  structured-failure JSON and hard-exits if the C client wedges;
-* every failure path still prints one JSON line with ``metric/value/unit/
-  vs_baseline`` plus an ``error`` object (``stage`` + ``detail``), so an
-  environment flake is distinguishable from a code bug.
+No chip, no number: unless ``--platform cpu`` was given, the backend that
+comes up must be a TPU or the run exits non-zero — a CPU timing under a
+device metric's name is worse than no timing.  Every failure path prints
+one JSON line with ``metric/value/unit/vs_baseline`` plus an ``error``
+object (``stage`` + ``detail``) and exits non-zero; nothing exits 0 after
+a failed phase, and nothing republishes an older artifact.
 
 Extra modes:
 
@@ -63,14 +49,8 @@ import time
 
 UNIT = "images/sec/chip"
 
-# Round tag for on-chip artifact names — single source of truth shared
-# with benchmarks/tpu_recovery_loop.sh (which reads it via `python -c
-# "import bench; print(bench.ROUND)"`), so the replay fallback can never
-# publish a PRIOR round's artifact under this round's provenance.
-ROUND = os.environ.get("DTPU_ROUND", "r5")
-
-# bf16 peak FLOPs/s per chip by device-kind substring (public TPU specs);
-# used only for the advisory MFU figure printed to stderr.
+# bf16 peak FLOPs/s per chip by device-kind substring (public TPU specs),
+# for the MFU figure.  A TPU that is not in the table is an error.
 PEAK_FLOPS = [
     ("v6e", 918e12), ("trillium", 918e12),
     ("v5p", 459e12),
@@ -106,18 +86,6 @@ def parse_args(argv=None):
     p.add_argument("--attn", default="xla", choices=["xla", "pallas", "ring"],
                    help="UNet attention impl — 'pallas' benchmarks the "
                         "custom flash kernel against the default XLA path")
-    p.add_argument("--init-patience", type=int, default=None,
-                   help="total seconds to spend escaping a wedged backend. "
-                        "Default: suite mode caps this at ~20%% of the "
-                        "claim window (the driver's whole run fits in one "
-                        "window — r4 burned 97%% of it on the first probe); "
-                        "single modes keep the patient ≥25 min ladder")
-    p.add_argument("--init-timeout", type=int, default=None,
-                   help="seconds per backend probe / in-process init "
-                        "(default: one LONG probe sized to the patience "
-                        "budget — killing a TPU client mid-claim wedges "
-                        "the server-side lease, so the probe must resolve "
-                        "naturally: devices or UNAVAILABLE)")
     p.add_argument("--phase", default=None,
                    choices=["tensor_plane", "pipeline", "observability",
                             "fault", "telemetry", "failover", "overload",
@@ -430,10 +398,6 @@ def failure_payload(args, stage, detail, diagnostics=None):
 
 
 _PAYLOAD_EMITTED = False
-# Best completed-phase payload (suite mode): the SIGTERM watchdog AND
-# fail() deliver THIS instead of a zero when the run dies mid-phase — a
-# measured SD1.5 number must survive an SDXL compile/OOM that came later.
-_BEST_PAYLOAD = None
 _LAST_PAYLOAD = None
 
 
@@ -441,14 +405,12 @@ def emit(args, payload, partial=False):
     """Print one JSON line (the driver parses the LAST stdout line) and
     mirror it to --out.  ``partial=True`` flushes a phase result without
     marking the run delivered — later phases may upgrade it."""
-    global _PAYLOAD_EMITTED, _BEST_PAYLOAD, _LAST_PAYLOAD
+    global _PAYLOAD_EMITTED, _LAST_PAYLOAD
     if not partial:
         # flag BEFORE writing: the SIGTERM watchdog must not clobber a
         # result whose delivery is already in progress (a timeout line
         # overwriting a just-written success in args.out)
         _PAYLOAD_EMITTED = True
-    if payload.get("value", 0.0) > 0:
-        _BEST_PAYLOAD = payload
     _LAST_PAYLOAD = payload
     line = json.dumps(payload)
     print(line, flush=True)
@@ -493,127 +455,29 @@ def collect_diagnostics():
 
 
 def fail(args, stage, detail, diagnostics=None):
-    """Print the structured-failure JSON line and exit nonzero — UNLESS
-    an earlier phase already measured a real >0 number, in which case the
-    best completed phase is delivered (with the later failure attached)
-    and the exit is clean: a measured result must never be replaced by a
-    0.0 because a LATER, more expensive phase died (the r4 failure
-    mode, just via an exception instead of SIGTERM)."""
+    """Print the structured-failure JSON line and exit nonzero.  Phases
+    that completed earlier were already flushed to stdout; the last line
+    says what failed."""
     log(f"FAIL stage={stage}: {detail}")
-    if _BEST_PAYLOAD is not None:
-        payload = dict(_BEST_PAYLOAD)
-        payload["error_after"] = {"stage": stage, "detail": str(detail)[:2000]}
-        log("delivering the best completed phase despite the failure above")
-        emit(args, payload)
-        sys.exit(0)
     emit(args, failure_payload(args, stage, detail, diagnostics))
     sys.exit(1)
 
 
-class BackendInitError(RuntimeError):
-    """Backend unusable after the ladder; carries the diagnostics dict so
-    suite mode can fall back to a recorded artifact instead of exiting."""
-
-    def __init__(self, msg, diagnostics=None):
-        super().__init__(msg)
-        self.diagnostics = diagnostics
-
-
-def ladder_budget(args):
-    """Resolve the escape-ladder (patience, probe_timeout) for this mode.
-
-    Suite mode (the driver's bare invocation) gets a HARD CAP of ~20% of
-    the claim window: round 4 spent 1506.9 s of a ~1560 s driver window
-    on the ladder's first rung and the actual bench never ran
-    (benchmarks/sdxl_tpu_r4.json).  The patient ≥claim-window probing —
-    which a background loop with unbounded time SHOULD do so a wedged
-    claim resolves naturally instead of being killed mid-claim — belongs
-    to the recovery loop (benchmarks/tpu_recovery_loop.sh), which passes
-    --init-patience explicitly."""
-    from comfyui_distributed_tpu.parallel.mesh import claim_window_s
-    window = claim_window_s()
-    if args.init_patience is not None:
-        patience = args.init_patience
-        probe = args.init_timeout or max(patience - 120, window + 60)
-    elif getattr(args, "suite", False):
-        frac = float(os.environ.get("DTPU_SUITE_LADDER_FRACTION", "0.2"))
-        patience = int(window * frac)
-        # ONE long probe (nearly the whole capped budget), not several
-        # short ones: every SIGKILLed mid-claim probe re-wedges the
-        # server-side lease, so within the cap we kill at most once and
-        # leave ~60s for the fast-failing alternate configs afterwards
-        probe = args.init_timeout or max(60, patience - 60)
-    else:
-        patience = 1800
-        probe = args.init_timeout or max(patience - 120, window + 60)
-    return patience, probe
-
-
 def init_backend(args):
-    """Escape-ladder probe (parallel/mesh.py: env config retried with
-    escalating sleeps, then alternate JAX_PLATFORMS configs — '' and
-    'tpu') then init in-process under a watchdog.  No CPU fallback here:
-    a silent CPU number on the TPU metric would be worse than a
-    structured failure.  Returns the list of devices."""
-    t_start = time.monotonic()
+    """``--platform cpu``: virtual CPU devices (harness smokes, the CPU
+    contract phases).  Otherwise the backend that comes up in THIS process
+    must be a TPU, whatever ``JAX_PLATFORMS`` says: these modes publish
+    device metrics.  Returns the list of devices."""
+    import jax
     if args.platform == "cpu":
         from comfyui_distributed_tpu.parallel.mesh import force_cpu_platform
         force_cpu_platform(max(args.cpu_devices, 1))
-    else:
-        from comfyui_distributed_tpu.parallel.mesh import (
-            ensure_usable_backend)
-        patience, probe_timeout = ladder_budget(args)
-        rep = ensure_usable_backend(patience_s=patience,
-                                    probe_timeout=probe_timeout,
-                                    allow_cpu_fallback=False, force=True)
-        if not rep["ok"]:
-            diag = collect_diagnostics()
-            diag["escape_ladder"] = rep["attempts"]
-            if diag["device_holders"]:
-                log(f"device holders: {diag['device_holders']}")
-            last = rep["attempts"][-1] if rep["attempts"] else {}
-            raise BackendInitError(
-                f"default backend unusable after the full escape ladder "
-                f"({len(rep['attempts'])} probes within {patience}s); "
-                f"last: {last.get('info')}", diag)
-        log(f"backend via config: {rep['config']}")
-
-    # The probe succeeding doesn't guarantee the in-process init can't wedge
-    # (the flake is intermittent) — guard it with a hard-exit watchdog.
-    # Suite mode: the timeout respects the capped ladder budget, and the
-    # watchdog takes the same artifact-replay exit as a failed ladder
-    # (it cannot raise into a main thread wedged inside the C client, so
-    # the fallback runs HERE) — a wedged in-process init must not zero a
-    # round that has a green recovery-loop artifact.
-    done = threading.Event()
-    if args.init_timeout:
-        inproc_timeout = args.init_timeout
-    elif getattr(args, "suite", False):
-        # budget from time REMAINING in the capped window, not a fresh
-        # allowance — the ladder may already have spent most of it
-        spent = time.monotonic() - t_start
-        inproc_timeout = max(30.0, ladder_budget(args)[0] - spent)
-    else:
-        inproc_timeout = 600
-
-    def watchdog():
-        if not done.wait(inproc_timeout):
-            log(f"in-process backend init hung >{inproc_timeout}s")
-            if getattr(args, "suite", False):
-                rec = _artifact_replay(args)
-                if rec is not None:
-                    emit(args, rec)
-                    os._exit(0)
-            emit(args, failure_payload(
-                args, "backend_init_inprocess",
-                f"in-process jax.devices() wedged "
-                f"(platform={args.platform})"))
-            os._exit(1)
-
-    threading.Thread(target=watchdog, daemon=True).start()
-    import jax
     devices = jax.devices()
-    done.set()
+    if args.platform != "cpu" and devices[0].platform != "tpu":
+        fail(args, "backend_init",
+             f"no TPU: JAX came up on {devices[0].platform!r} "
+             f"({len(devices)} x {devices[0].device_kind}); pass "
+             f"--platform cpu for a harness smoke")
     return devices
 
 
@@ -653,30 +517,19 @@ def peak_flops_for(kind):
     for sub, peak in PEAK_FLOPS:
         if sub in k:
             return peak
-    return None
+    raise KeyError(f"device_kind {kind!r} is not in bench.PEAK_FLOPS; add "
+                   f"its published peak with the source")
 
 
 def enable_compile_cache():
-    """Persistent XLA compilation cache (repo-local, gitignored).
-
-    SDXL-1024's one-time compile dominates a cold bench run; with the
-    cache warm a repeat invocation skips straight to execution, so the
-    driver's end-of-round run isn't hostage to a 5-10 min compile.
-    Canonical implementation: ``runtime.manager`` (shared with the
-    server's startup path); env ``DTPU_COMPILE_CACHE_DIR`` overrides the
-    repo-local default."""
+    """Persistent XLA compilation cache, the same one the server uses
+    (``runtime.manager.enable_persistent_compile_cache``)."""
     from comfyui_distributed_tpu.runtime.manager import \
         enable_persistent_compile_cache
-    enable_persistent_compile_cache(
-        min_compile_secs=1.0,
-        default_dir=os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                 ".jax_cache"))
+    enable_persistent_compile_cache(min_compile_secs=1.0)
 
 
 def run_throughput(args):
-    # NOTE: the per-step interrupt poll stays ON — serving always compiles
-    # it in (registry keys the executable on polling_enabled()), so the
-    # published series must measure the same program production runs
     devices = init_backend(args)
     enable_compile_cache()
     emit(args, _measure_throughput(args, devices))
@@ -785,13 +638,15 @@ def _measure_throughput(args, devices):
         log(f"steady-state phases {steady[0]}")
 
     mfu = None
+    # the CPU has no published peak worth a utilization figure; a TPU that
+    # is missing from the table fails the run here, before the estimate
+    peak = peak_flops_for(kind) if dev.platform != "cpu" else None
     try:
         cfg_mult = 2 if args.cfg != 1.0 else 1
         fwd = estimate_unet_flops(
             pipe, cfg_mult * B, lat.shape[1], lat.shape[2],
             context.shape[1], y)
         flops_per_img = args.steps * fwd / B
-        peak = peak_flops_for(kind)
         log(f"unet fwd (cfg batch): {fwd/1e12:.2f} TFLOP; "
             f"{flops_per_img/1e12:.2f} TFLOP/img over {args.steps} steps")
         if peak:
@@ -815,47 +670,6 @@ def _measure_throughput(args, devices):
     if mfu is not None:
         payload["mfu"] = round(mfu, 4)
     return payload
-
-
-def _artifact_replay(args):
-    """Backend unusable inside the driver's bounded window: fall back to
-    the most recent GREEN on-chip throughput artifact recorded earlier
-    this round by the recovery loop (same code, same chip — just measured
-    when the chip was actually claimable), with explicit provenance so
-    the number is never mistaken for a live measurement.  Returns None
-    when no green artifact exists (then the structured failure stands)."""
-    import datetime
-    bench_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "benchmarks")
-    # ONLY the two headline batch-1 artifacts are replayable: the b8 and
-    # pallas artifacts carry the same/similar metric strings but are a
-    # different series (batch-amortized / different kernel) — publishing
-    # one as the headline would inflate the cross-round comparison
-    candidates = []
-    for name in (f"sd15_tpu_{ROUND}.json", f"sdxl_tpu_{ROUND}.json"):
-        path = os.path.join(bench_dir, name)
-        try:
-            with open(path) as f:
-                rec = json.loads(f.readline())
-        except (OSError, ValueError):
-            continue
-        if rec.get("value", 0) > 0 and rec.get("unit") == UNIT:
-            candidates.append((path, rec))
-    if not candidates:
-        return None
-    path, rec = candidates[-1]  # sdxl (the headline) when green, else sd15
-    rec = dict(rec)
-    rec["source"] = {
-        "replayed_from": os.path.basename(path),
-        "measured_at_utc": datetime.datetime.utcfromtimestamp(
-            os.path.getmtime(path)).isoformat() + "Z",
-        "reason": "backend unavailable inside the driver window; this "
-                  "value was measured ON CHIP earlier this round by "
-                  "benchmarks/tpu_recovery_loop.sh at the same code",
-    }
-    log(f"replaying green on-chip artifact {os.path.basename(path)} "
-        f"(backend unavailable live)")
-    return rec
 
 
 # --- perf-regression watchdog (--check) --------------------------------------
@@ -4977,9 +4791,6 @@ def run_multimaster(args):
     owns a mid-flight tiled-upscale must end at completion 1.0 with a
     bit-identical blend, p95 within 20%% of the no-kill run, and every
     shard's WAL verifying clean."""
-    # resolves + re-exports DTPU_COMPILE_CACHE_DIR so the 5 spawned
-    # processes share one warm XLA cache (the masters' warmup pays the
-    # tiny-model compile once per container, not once per process)
     enable_compile_cache()
     m = measure_multimaster()
     log(f"multimaster: scaling {m['scaling_x']}x; kill completion "
@@ -5024,161 +4835,72 @@ def run_multimaster(args):
     emit(args, payload)
 
 
-def run_suite(args):
-    """The driver's default invocation: budget-capped backend escape
-    (ladder_budget — ≤~20% of the claim window), then cheapest-first
-    on-chip metrics with a best-so-far flush after every phase:
+# The CPU contract phases suite mode re-proves after the on-chip numbers,
+# each in a subprocess pinned to the CPU by its environment:
+# (phase, timeout seconds, extra argv).  ``--check`` compares the phase's
+# payload with the prior BENCH artifact of the same metric.
+SUITE_CPU_PHASES = (
+    ("tensor_plane", 600.0, ()),
+    ("telemetry", 600.0, ("--check",)),
+    ("failover", 600.0, ("--check",)),
+    ("overload", 600.0, ("--check",)),
+    ("batching", 600.0, ("--check",)),
+    ("reuse", 600.0, ("--check",)),
+    ("multimaster", 900.0, ("--check",)),
+    ("tp_serve", 600.0, ("--check",)),
+    ("preempt", 600.0, ("--check",)),
+    ("slo", 600.0, ("--check",)),
+    ("sim", 600.0, ("--check",)),
+    ("analysis", 600.0, ("--check",)),
+)
 
-      A. SD1.5 512px (small compile — lands a real >0 number early)
+
+def run_suite(args):
+    """The bare invocation: on-chip metrics cheapest first, each flushed as
+    it completes,
+
+      A. SD1.5 512px (small compile)
       B. SDXL 1024px (the headline) + MFU + clip/denoise/vae phase split
 
-    A SIGTERM at any point emits the best COMPLETED phase instead of a
-    zero (_install_sigterm_payload); a dead backend falls back to this
-    round's recovery-loop artifact with provenance (_artifact_replay)."""
+    then the CPU contract phases (``SUITE_CPU_PHASES``).  A phase that
+    fails is named in ``failed_stages`` and the suite exits non-zero."""
     from argparse import Namespace
-    # Tell the recovery loop to stand down: the driver window owns the
-    # chip now, and two clients must not fight for the single claim.
-    # Removed again on the way out (and the loop treats a >1h-old flag
-    # as expired) so one suite run can't silence the loop for the round.
-    stop_flag = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                             "benchmarks", ".recovery_stop")
-    try:
-        open(stop_flag, "w").close()
-    except OSError:
-        pass
-    try:
-        try:
-            devices = init_backend(args)
-        except BackendInitError as e:
-            rec = _artifact_replay(args)
-            if rec is not None:
-                emit(args, rec)
-                return
-            diag = e.diagnostics or collect_diagnostics()
-            fail(args, "backend_init", str(e), diag)
-        enable_compile_cache()
-        a = Namespace(**vars(args))
-        a.family, a.height, a.width = "sd15", 512, 512
-        payload_a = _measure_throughput(a, devices)
-        emit(args, payload_a, partial=True)
+    devices = init_backend(args)
+    enable_compile_cache()
+    a = Namespace(**vars(args))
+    a.family, a.height, a.width = "sd15", 512, 512
+    payload_a = _measure_throughput(a, devices)
+    emit(args, payload_a, partial=True)
 
-        b = Namespace(**vars(args))
-        b.family, b.height, b.width = "sdxl", 1024, 1024
-        payload_b = _measure_throughput(b, devices)
-        payload_b["stages"] = {
-            payload_a["metric"]: {k: v for k, v in payload_a.items()
-                                  if k not in ("metric", "unit",
-                                               "vs_baseline")}}
-        tp = _phase_subprocess("tensor_plane")
-        if tp is not None:
-            payload_b["stages"]["tensor_plane"] = tp
-        # telemetry watchdog stage: the CPU proxy re-proves the <=3%
-        # tracing+telemetry overhead AND --check compares it against the
-        # prior BENCH artifact — a regression marks the stage, never
-        # zeroes the on-chip headline
-        tel = _phase_subprocess("telemetry", extra=("--check",))
-        if tel is not None:
-            payload_b["stages"]["telemetry"] = tel
-        # failover watchdog stage: the CPU proxy re-proves the durable-
-        # master contract (standby completion 1.0, bit-identical blend)
-        # and --check flags a completion-rate regression against the
-        # prior BENCH artifact
-        fo = _phase_subprocess("failover", extra=("--check",))
-        if fo is not None:
-            payload_b["stages"]["failover"] = fo
-        # overload watchdog stage: the CPU proxy re-proves the elastic-
-        # fleet contract (zero dropped paid, p95 ordering, autoscaler
-        # convergence without flaps) under chaos, and --check flags a
-        # paid-completion regression against the prior BENCH artifact
-        ov = _phase_subprocess("overload", extra=("--check",))
-        if ov is not None:
-            payload_b["stages"]["overload"] = ov
-        # batching watchdog stage: the CPU proxy re-proves the
-        # continuous-batching contract (>=2x over the head-run
-        # coalescer on Poisson mixed arrivals at equal-or-better p95,
-        # zero steady-state retraces, continuous==serial bit-exactness)
-        # and --check flags a speedup regression vs the prior artifact
-        cbp = _phase_subprocess("batching", extra=("--check",))
-        if cbp is not None:
-            payload_b["stages"]["batching"] = cbp
-        # reuse watchdog stage: the CPU proxy re-proves the cross-
-        # request compute-reuse contract (exact-hit replay, storm
-        # speedup at equal p95, changed-tile-only upscaling, client-
-        # gone slot free) and --check flags a storm-speedup regression
-        # against the prior BENCH artifact
-        ru = _phase_subprocess("reuse", extra=("--check",))
-        if ru is not None:
-            payload_b["stages"]["reuse"] = ru
-        # multimaster watchdog stage: the CPU proxy re-proves the
-        # sharded-control-plane contract (3 real master processes
-        # >=2.5x one master's saturation, SIGKILL'd owner's shard
-        # absorbed by its ring successor at completion 1.0 with a
-        # bit-identical blend) and --check flags a scaling regression
-        # against the prior BENCH artifact
-        mm = _phase_subprocess("multimaster", timeout_s=900.0,
-                               extra=("--check",))
-        if mm is not None:
-            payload_b["stages"]["multimaster"] = mm
-        # tp_serve watchdog stage: the CPU proxy re-proves the tensor-
-        # parallel serving contract (sharded params + 2-D CB buckets
-        # with per-array spec assertions, TP-vs-replicated tolerance,
-        # late-join bit-exactness, zero steady-state retraces) and
-        # --check flags any exactness drop vs the prior BENCH artifact
-        tps = _phase_subprocess("tp_serve", extra=("--check",))
-        if tps is not None:
-            payload_b["stages"]["tp_serve"] = tps
-        # preempt watchdog stage: the CPU proxy re-proves the latent-
-        # paging / SLO-preemption contract (paid burst against a full
-        # batch-tier bucket lands within ~1 denoise step of the
-        # idle-fleet p95, parked batch work completes 1.0 with zero
-        # steady-state retraces, park→resume bit-exact) and --check
-        # flags any completion drop vs the prior BENCH artifact
-        pe = _phase_subprocess("preempt", extra=("--check",))
-        if pe is not None:
-            payload_b["stages"]["preempt"] = pe
-        # slo watchdog stage: the CPU proxy re-proves the continuous
-        # capture plane (<=3% fully-armed overhead, burst burn >1.0
-        # decaying after the load drops, exemplar->committed-trace
-        # resolution, exact capture round-trip inside the retention
-        # budget) and --check flags a throughput regression against
-        # the prior BENCH artifact
-        sl = _phase_subprocess("slo", extra=("--check",))
-        if sl is not None:
-            payload_b["stages"]["slo"] = sl
-        # sim watchdog stage: the traffic twin's fidelity gate —
-        # calibration against the committed overload/multimaster
-        # artifacts (within SIM_CALIBRATION_MAX_ERR with every
-        # ordering bar intact), byte-identical determinism, and the
-        # 1000-worker virtual-day scale bar (<60s wall); --check flags
-        # any calibration drift against the prior BENCH artifact
-        sm = _phase_subprocess("sim", extra=("--check",))
-        if sm is not None:
-            payload_b["stages"]["sim"] = sm
-        # analysis watchdog stage: the critical-path analytics plane —
-        # armed live anomaly detection within 3% of disarmed with zero
-        # retraces, blame + gap reconstructing e2e (gap <10%), the
-        # differ flagging the sim-seeded +30% compute regression and
-        # passing the null diff; --check flags a throughput regression
-        # against the prior BENCH artifact
-        an = _phase_subprocess("analysis", extra=("--check",))
-        if an is not None:
-            payload_b["stages"]["analysis"] = an
-        emit(args, payload_b)
-    finally:
-        try:
-            os.remove(stop_flag)
-        except OSError:
-            pass
+    b = Namespace(**vars(args))
+    b.family, b.height, b.width = "sdxl", 1024, 1024
+    payload_b = _measure_throughput(b, devices)
+    payload_b["stages"] = {
+        payload_a["metric"]: {k: v for k, v in payload_a.items()
+                              if k not in ("metric", "unit",
+                                           "vs_baseline")}}
+    failed = []
+    for phase, timeout_s, extra in SUITE_CPU_PHASES:
+        stage, ok = _phase_subprocess(phase, timeout_s, extra)
+        if stage is not None:
+            payload_b["stages"][phase] = stage
+        if not ok:
+            failed.append(phase)
+    if failed:
+        payload_b["failed_stages"] = failed
+    emit(args, payload_b)
+    if failed:
+        log(f"FAIL suite stages: {failed}")
+        sys.exit(1)
 
 
 def _phase_subprocess(phase: str, timeout_s: float = 600.0, extra=()):
-    """Run a named CPU-proxy phase in a SUBPROCESS (the phases pin the
-    CPU backend — doing that in-process would clobber the accelerator
-    backend the suite just benchmarked) and return its payload dict, or
-    None on any failure.  A ``--check`` in ``extra`` may exit nonzero on
-    regression: the payload is still returned (stamped with the rc) so
-    the suite surfaces it without zeroing a round that measured real
-    on-chip numbers."""
+    """Run a named CPU contract phase in a SUBPROCESS whose environment
+    pins it to the CPU before it imports JAX (this process holds the
+    chip).  Returns ``(payload or None, ok)``: ``ok`` is False when the
+    phase crashed, timed out, left no artifact or exited non-zero (a
+    ``--check`` regression verdict keeps its payload, stamped with the
+    rc, so the suite shows what regressed)."""
     import subprocess
     import tempfile
     out_path = os.path.join(tempfile.mkdtemp(prefix=f"bench_{phase}_"),
@@ -5190,25 +4912,20 @@ def _phase_subprocess(phase: str, timeout_s: float = 600.0, extra=()):
             [sys.executable, os.path.abspath(__file__),
              "--phase", phase, *extra, "--out", out_path],
             env=env, capture_output=True, text=True, timeout=timeout_s)
-        payload = None
-        try:
-            with open(out_path) as f:
-                payload = json.load(f)
-        except (OSError, ValueError) as e:
-            log(f"{phase} phase artifact unreadable: {e!r}")
-        if r.returncode != 0:
-            log(f"{phase} phase rc={r.returncode}: "
-                f"{r.stderr.strip()[-500:]}")
-            # only a --check run keeps its payload on nonzero rc (the
-            # watchdog's regression verdict IS the result); a plain
-            # phase crash stays out of the suite artifact, as before
-            if "--check" not in extra or payload is None:
-                return None
+    except subprocess.TimeoutExpired:
+        log(f"{phase} phase timed out after {timeout_s:.0f}s")
+        return None, False
+    payload = None
+    try:
+        with open(out_path) as f:
+            payload = json.load(f)
+    except (OSError, ValueError) as e:
+        log(f"{phase} phase artifact unreadable: {e!r}")
+    if r.returncode != 0:
+        log(f"{phase} phase rc={r.returncode}: {r.stderr.strip()[-500:]}")
+        if payload is not None:
             payload["check_rc"] = r.returncode
-        return payload
-    except Exception as e:  # noqa: BLE001 - advisory phase
-        log(f"{phase} phase unavailable: {e!r}")
-        return None
+    return payload, r.returncode == 0 and payload is not None
 
 
 def _run_fixture_bench(args, fixture_name, override_graph, label):
@@ -5460,9 +5177,12 @@ def run_multiproc_sweep(args):
         local_dev = n // procs
         repo = os.path.dirname(os.path.abspath(__file__))
         inherited = os.environ.get("PYTHONPATH")
+        # the workers are CPU stand-ins for pod hosts: pinned by their
+        # environment, before they import jax
         env_base = {**os.environ,
                     "PYTHONPATH": (repo + os.pathsep + inherited)
                     if inherited else repo,
+                    "JAX_PLATFORMS": "cpu",
                     "DTPU_BENCH_LOCAL_DEVICES": str(local_dev),
                     "DTPU_BENCH_STEPS": str(args.steps),
                     "DTPU_BENCH_REPEATS": str(max(args.repeats, 2))}
@@ -5550,22 +5270,11 @@ def _install_sigterm_payload(args):
         delivered = False
         try:
             if not _PAYLOAD_EMITTED:
-                if _BEST_PAYLOAD is not None:
-                    # a phase already measured a real >0 number — deliver
-                    # THAT, marked truncated, never a zero (r4 died with
-                    # value 0.0 during the SDXL cold compile)
-                    payload = dict(_BEST_PAYLOAD)
-                    payload["terminated"] = (
-                        "SIGTERM before the full suite finished; value "
-                        "is the best completed phase")
-                    emit(args, payload)
-                    delivered = True
-                else:
-                    emit(args, failure_payload(
-                        args, "timeout",
-                        "SIGTERM during run (driver timeout? cold compile "
-                        "can take minutes — the persistent cache makes "
-                        "the retry fast)", diagnostics=diag))
+                emit(args, failure_payload(
+                    args, "timeout",
+                    "SIGTERM during run (a cold compile can take minutes; "
+                    "the persistent cache makes the retry fast)",
+                    diagnostics=diag))
             else:
                 # a payload was already fully emitted; the exit code must
                 # agree with what the driver will parse from the LAST line
@@ -5629,9 +5338,6 @@ def main():
             sys.exit(run_check(args))
     except SystemExit:
         raise
-    except BackendInitError as e:
-        fail(args, "backend_init", str(e),
-             e.diagnostics or collect_diagnostics())
     except MemoryError:
         fail(args, "oom", "host OOM during bench")
     except Exception as e:

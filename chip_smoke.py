@@ -1,0 +1,566 @@
+#!/usr/bin/env python3
+"""chip_smoke.py: the quickest proof that the serve path still starts on the chip.
+
+Drives the system's main path once, through the entry points a user calls:
+
+  python -m comfyui_distributed_tpu.cli serve      (a child process)
+    -> POST /prompt -> admission -> scheduler -> CLIP -> denoise -> VAE
+    -> PNG -> GET /history
+
+at the full width of SDXL-base, 1024x1024, with ``workflows/
+distributed-sdxl.json`` exactly as shipped (three seeds), then the tiled
+upscale ``workflows/distributed-upscale.json`` as shipped (512 -> 2048,
+16 tiles, SD1.5 refine).  Weights are random, made from a seed
+(``models/registry.py`` builds them when no checkpoint file exists), so
+nothing is downloaded.  After the server has exited, a second child
+compiles the Pallas flash-attention kernel (``interpret=False``) at the
+shapes the two UNets produce and compares it with ``xla_attention``.
+
+This process never imports JAX: a chip belongs to one process at a time,
+and a parent that touched JAX would hold it.  It talks to the server
+over HTTP only, and the children run one after the other.
+
+It never falls back.  No TPU, a failed request, a wrong image, a compile
+in the steady state, a kernel the compiler refuses: each ends the script
+non-zero with the reason on stderr and NOTHING on stdout.  On success
+stdout holds two lines, each one JSON object.  The first is the summary
+(also written to ``<out>/summary.json``): the device, the mesh axes, the
+phases, and ``smoke_facts`` (per-request wall times, the compile-inclusive
+first request, peak HBM, versions), ending with ``"claim": null``.  Its
+timings are smoke facts (one run, compile included where it says so),
+not benchmark numbers.  The last line is the result, and holds nothing
+but the verdict and the device as JAX reports it:
+
+  {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}
+
+``--rehearse`` is the CPU rehearsal tier-1 runs: the tiny family, small
+sizes, ``JAX_PLATFORMS=cpu``, the kernel in interpret mode.  Both lines
+then say ``"platform": "cpu"`` and the summary says ``"rehearsal": true``.
+
+``--phases`` picks from ``sdxl,upscale,kernels`` (default: all three) so
+a second run in one chip call can time a cache-warm first request
+without paying for the rest again.
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import importlib.metadata
+import io
+import json
+import os
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.error
+import urllib.request
+import uuid
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+PHASES = ("sdxl", "upscale", "kernels")
+SDXL_SEEDS = (777, 100777, 200777)   # far apart: fan-out replica r adds r
+
+# (q [B, N, H, D], kv length M): SDXL-base at 1024 px and SD1.5 at 512 px,
+# CFG-stacked batch of 2; M=77 is the text cross-attention
+KERNEL_SHAPES = (
+    ((2, 4096, 10, 64), 4096),   # SDXL level 1 self-attention
+    ((2, 1024, 20, 64), 1024),   # SDXL level 2 / mid self-attention
+    ((2, 4096, 8, 40), 4096),    # SD1.5 level 0 self-attention
+    ((2, 256, 8, 160), 256),     # SD1.5 level 2 self-attention
+    ((2, 4096, 10, 64), 77),     # SDXL cross-attention
+    ((2, 4096, 8, 40), 77),      # SD1.5 cross-attention
+)
+KERNEL_SHAPES_REHEARSAL = (((1, 200, 2, 16), 200), ((2, 64, 2, 16), 77))
+# bf16 inputs, fp32 accumulation on both sides: max |diff| over max |ref|
+KERNEL_REL_TOL = 2e-2
+
+
+class SmokeFailure(Exception):
+    """One phase missed; the script exits non-zero and prints no result."""
+
+
+def check(cond: bool, why: str) -> None:
+    if not cond:
+        raise SmokeFailure(why)
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", file=sys.stderr, flush=True)
+
+
+# --- HTTP (stdlib only) ------------------------------------------------------
+
+def get_json(url: str, timeout: float = 60.0):
+    with urllib.request.urlopen(url, timeout=timeout) as r:
+        return json.loads(r.read())
+
+
+def post_json(url: str, payload, timeout: float = 60.0):
+    req = urllib.request.Request(
+        url, data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return json.loads(r.read())
+
+
+def upload_png(base: str, name: str, png: bytes) -> None:
+    boundary = uuid.uuid4().hex
+    body = (f"--{boundary}\r\nContent-Disposition: form-data; "
+            f'name="image"; filename="{name}"\r\n'
+            f"Content-Type: image/png\r\n\r\n").encode() \
+        + png + f"\r\n--{boundary}--\r\n".encode()
+    req = urllib.request.Request(
+        f"{base}/upload/image", data=body,
+        headers={"Content-Type":
+                 f"multipart/form-data; boundary={boundary}"})
+    with urllib.request.urlopen(req, timeout=60.0) as r:
+        check(json.loads(r.read()).get("name") == name,
+              f"/upload/image did not store {name}")
+
+
+# --- the server child --------------------------------------------------------
+
+class Server:
+    """``cli serve`` as a child process with its working directory under
+    the output directory, so ``output/``, ``input/``, ``logs/`` and
+    ``cluster_config.json`` land there and not in the checkout."""
+
+    def __init__(self, out_dir: str, env: dict):
+        self.cwd = os.path.join(out_dir, "server")
+        os.makedirs(self.cwd, exist_ok=True)
+        self.log_path = os.path.join(out_dir, "server.stderr.log")
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        self.base = f"http://127.0.0.1:{port}"
+        self._log = open(self.log_path, "wb")
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "comfyui_distributed_tpu.cli", "serve",
+             "--host", "127.0.0.1", "--port", str(port),
+             "--config", os.path.join(self.cwd, "cluster_config.json")],
+            cwd=self.cwd, env=env, stdout=self._log,
+            stderr=subprocess.STDOUT)
+
+    def log_tail(self, n: int = 3000) -> str:
+        with open(self.log_path, "rb") as f:
+            return f.read()[-n:].decode("utf-8", "replace")
+
+    def require_alive(self) -> None:
+        rc = self.proc.poll()
+        check(rc is None,
+              f"server child exited with code {rc}:\n{self.log_tail()}")
+
+    def wait_ready(self, timeout: float = 300.0) -> dict:
+        """First answer of /distributed/status (it builds the mesh)."""
+        deadline = time.monotonic() + timeout
+        while True:
+            self.require_alive()
+            try:
+                return get_json(f"{self.base}/distributed/status")
+            except (urllib.error.URLError, ConnectionError, OSError):
+                check(time.monotonic() < deadline,
+                      f"server not answering after {timeout:.0f}s:\n"
+                      f"{self.log_tail()}")
+                time.sleep(0.5)
+
+    def run_prompt(self, prompt: dict, timeout: float):
+        """POST /prompt, await the id on /history.  Returns (history
+        entry, wall seconds from POST to the entry appearing)."""
+        t0 = time.monotonic()
+        pid = post_json(f"{self.base}/prompt",
+                        {"prompt": prompt,
+                         "client_id": "chip_smoke"})["prompt_id"]
+        while True:
+            self.require_alive()
+            hist = get_json(f"{self.base}/history")
+            if pid in hist:
+                return hist[pid], time.monotonic() - t0
+            check(time.monotonic() - t0 < timeout,
+                  f"prompt {pid} not in /history after {timeout:.0f}s:\n"
+                  f"{self.log_tail()}")
+            time.sleep(0.25)
+
+    def metrics(self) -> dict:
+        return get_json(f"{self.base}/distributed/metrics")
+
+    def shut_down(self, timeout: float = 120.0) -> int:
+        """SIGTERM -> aiohttp's graceful exit (drain, close).  Returns the
+        exit code."""
+        self.proc.send_signal(signal.SIGTERM)
+        try:
+            return self.proc.wait(timeout=timeout)
+        finally:
+            self.kill()
+
+    def kill(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+            self.proc.wait()
+        self._log.close()
+
+
+def load_workflow(name: str) -> dict:
+    with open(os.path.join(HERE, "workflows", name), encoding="utf-8") as f:
+        doc = json.load(f)
+    # "__doc__" is the fixture's comment, not a node
+    return {k: v for k, v in doc.items() if isinstance(v, dict)}
+
+
+def read_png(path: str):
+    import numpy as np
+    from PIL import Image
+    with Image.open(path) as im:
+        return np.asarray(im.convert("RGB"))
+
+
+def node_seconds(metrics: dict) -> dict:
+    """Host wall seconds spent in each workflow node type so far (the
+    server's own per-node histogram; dispatch is asynchronous, so a
+    device wait shows in the node that first needs the value)."""
+    return {k: float(v["total_s"]) for k, v in metrics["nodes"].items()}
+
+
+def check_all_differ(images, what: str) -> None:
+    import numpy as np
+    for a in range(len(images)):
+        for b in range(a + 1, len(images)):
+            check(not np.array_equal(images[a], images[b]),
+                  f"{what} {a} and {b} gave the same image")
+
+
+def sdxl_phase(server: Server, cfg: dict, data_axis: int, facts: dict):
+    """Three SDXL prompts, three seeds; every image checked."""
+    out_dir = os.path.join(server.cwd, "output")
+    firsts = []
+    walls = []
+    metrics = server.metrics()
+    for i, seed in enumerate(SDXL_SEEDS):
+        wf = load_workflow("distributed-sdxl.json")
+        wf["5"]["inputs"]["seed"] = seed
+        if cfg["rehearsal"]:
+            wf["2"]["inputs"].update(width=cfg["sdxl_px"],
+                                     height=cfg["sdxl_px"])
+            wf["6"]["inputs"]["steps"] = cfg["steps"]
+        before = set(os.listdir(out_dir)) if os.path.isdir(out_dir) \
+            else set()
+        compiles_before = metrics["retraces"]["compiles"]
+        entry, wall = server.run_prompt(wf, cfg["first_timeout"] if i == 0
+                                        else cfg["steady_timeout"])
+        metrics = server.metrics()
+        compiled = metrics["retraces"]["compiles"] - compiles_before
+        walls.append(round(wall, 2))
+        say(f"sdxl seed {seed}: {entry.get('status')} in {wall:.1f}s, "
+            f"{compiled} compile(s)")
+        check(entry.get("status") == "success",
+              f"sdxl seed {seed} ended {entry}:\n{server.log_tail()}")
+        check(entry.get("images") == data_axis,
+              f"sdxl seed {seed}: {entry.get('images')} image(s), the "
+              f"mesh's data axis is {data_axis}")
+        if i == 0:
+            facts["first_request_compiles"] = compiled
+            first_nodes = node_seconds(metrics)
+            facts["first_request_node_s"] = {
+                k: round(v, 2) for k, v in first_nodes.items() if v >= 0.05}
+        else:
+            check(compiled == 0,
+                  f"sdxl request {i + 1} compiled {compiled} program(s); "
+                  f"the steady state must compile nothing")
+        new = sorted(set(os.listdir(out_dir)) - before)
+        check(len(new) == data_axis,
+              f"sdxl seed {seed}: {len(new)} new PNG(s) {new}, expected "
+              f"{data_axis}")
+        imgs = [read_png(os.path.join(out_dir, n)) for n in new]
+        side = cfg["sdxl_out_px"]
+        for name, im in zip(new, imgs):
+            check(im.shape == (side, side, 3),
+                  f"{name} decodes to {im.shape}, expected {side}x{side}")
+            check(float(im.std()) > 1.0,
+                  f"{name} is a constant image (std {im.std():.3f}): a "
+                  f"NaN or saturated latent decodes to one")
+        check_all_differ(imgs, f"seed {seed}: replicas")
+        firsts.append(imgs[0])
+    check_all_differ(firsts, f"seeds {SDXL_SEEDS}: requests")
+    facts["sdxl_request_wall_s"] = walls
+    steady = {k: (v - first_nodes.get(k, 0.0)) / (len(SDXL_SEEDS) - 1)
+              for k, v in node_seconds(metrics).items()}
+    facts["steady_request_node_s"] = {
+        k: round(v, 3) for k, v in steady.items() if v >= 0.005}
+    facts["first_request_s_compile_inclusive"] = walls[0]
+    # same seed, same program => same pixels, run to run and cache or not
+    facts["first_image_sha256"] = hashlib.sha256(
+        firsts[0].tobytes()).hexdigest()[:16]
+
+
+def memory_facts(server: Server, cfg: dict, n_devices: int,
+                 weights_resident: bool, facts: dict):
+    """The resource probe once the requests are done: real allocator
+    numbers, and on several chips a shard of the work on every one."""
+    time.sleep(cfg["monitor_interval_s"] + 1.0)   # a sample taken after
+    res = get_json(f"{server.base}/distributed/resource")["resources"]
+    facts["memory_source"] = res["source"]
+    if cfg["rehearsal"]:
+        return
+    check(res["source"] == "memory_stats",
+          f"resource probe reports source {res['source']!r}, not the "
+          f"device allocator's memory_stats")
+    per_device = res["per_device_bytes"]
+    check(len(per_device) == n_devices,
+          f"memory_stats from {len(per_device)} of {n_devices} devices")
+    facts["per_device_bytes_in_use"] = [d[0] for d in per_device]
+    facts["peak_hbm_bytes_per_device"] = [d[1] for d in per_device]
+    facts["peak_hbm_bytes"] = max(d[1] for d in per_device)
+    if not weights_resident:
+        return
+    for i, (in_use, _) in enumerate(per_device):
+        # SDXL's bf16 towers are ~6.9 GB whole and ~3.5 GB split in two
+        check(in_use > 2 ** 30,
+              f"device {i} holds {in_use} bytes after the SDXL requests: "
+              f"everything sits on another chip")
+
+
+def upscale_phase(server: Server, cfg: dict, facts: dict):
+    """Upload a seeded PNG, run the tiled upscale as shipped."""
+    import numpy as np
+    from PIL import Image
+    side = cfg["upscale_in_px"]
+    src = (np.random.default_rng(0).random((side, side, 3)) * 255
+           ).astype("uint8")
+    buf = io.BytesIO()
+    Image.fromarray(src).save(buf, "PNG")
+    upload_png(server.base, "input.png", buf.getvalue())
+    wf = load_workflow("distributed-upscale.json")
+    if cfg["rehearsal"]:
+        wf["16"]["inputs"].update(width=cfg["upscale_out_px"],
+                                  height=cfg["upscale_out_px"])
+        wf["2"]["inputs"].update(steps=1, tile_width=64, tile_height=64,
+                                 padding=8, mask_blur=2)
+    entry, wall = server.run_prompt(wf, cfg["first_timeout"])
+    say(f"upscale: {entry.get('status')} in {wall:.1f}s")
+    check(entry.get("status") == "success",
+          f"upscale ended {entry}:\n{server.log_tail()}")
+    out = cfg["upscale_out_px"]
+    check(entry.get("image_shapes") == [[out, out, 3]],
+          f"upscale output {entry.get('image_shapes')}, expected one "
+          f"{out}x{out} image")
+    facts["upscale_wall_s_compile_inclusive"] = round(wall, 2)
+
+
+def server_phases(phases, cfg: dict, out_dir: str, env: dict,
+                  result: dict) -> None:
+    facts = result["smoke_facts"]
+    server = Server(out_dir, env)
+    try:
+        t0 = time.monotonic()
+        status = server.wait_ready()
+        facts["server_ready_s"] = round(time.monotonic() - t0, 2)
+        dev0 = status["devices"][0]
+        result["device"] = {"platform": status["platform"],
+                            "kind": dev0["kind"],
+                            "count": status["num_devices"]}
+        result["mesh_axes"] = status["axes"]
+        say(f"server up: {result['device']} mesh {status['axes']}")
+        check(status["platform"] == cfg["platform"],
+              f"/distributed/status reports platform "
+              f"{status['platform']!r}, not {cfg['platform']!r}")
+        if "sdxl" in phases:
+            sdxl_phase(server, cfg, int(status["axes"]["data"]), facts)
+        if "upscale" in phases:
+            upscale_phase(server, cfg, facts)
+        memory_facts(server, cfg, int(status["num_devices"]),
+                     "sdxl" in phases, facts)
+        counters = server.metrics()["pipeline"]["counters"]
+        facts["compile_cache_hits"] = counters.get("compile_cache_hits", 0)
+        facts["compile_cache_writes"] = counters.get(
+            "compile_cache_writes", 0)
+        server.require_alive()
+        rc = server.shut_down()
+        check(rc == 0, f"server child exited with code {rc} on SIGTERM:\n"
+                       f"{server.log_tail()}")
+        check("Traceback" not in server.log_tail(1 << 30),
+              f"traceback in the server's stderr ({server.log_path}):\n"
+              f"{server.log_tail()}")
+    finally:
+        server.kill()
+
+
+# --- the kernel child --------------------------------------------------------
+
+def kernel_child(rehearse: bool) -> int:
+    """Runs in its own process (it owns the chip while it lives): compile
+    the Pallas kernel at each shape and compare with ``xla_attention``.
+    Prints one JSON line; any refusal or mismatch raises."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.layers import xla_attention
+    from comfyui_distributed_tpu.ops.pallas.flash_attention import \
+        flash_attention
+    from comfyui_distributed_tpu.runtime.manager import \
+        enable_persistent_compile_cache
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != ("cpu" if rehearse else "tpu"):
+        raise SystemExit(f"kernel phase: platform is {platform!r}")
+    enable_persistent_compile_cache()
+    rows, failures = [], []
+    shapes = KERNEL_SHAPES_REHEARSAL if rehearse else KERNEL_SHAPES
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    for (b, n, h, d), m in shapes:
+        rng = np.random.default_rng(n * 31 + m)
+        q = jnp.asarray(rng.standard_normal((b, n, h, d)), dtype)
+        k = jnp.asarray(rng.standard_normal((b, m, h, d)), dtype)
+        v = jnp.asarray(rng.standard_normal((b, m, h, d)), dtype)
+        ref = np.asarray(jax.jit(lambda q, k, v: xla_attention(
+            q, k, v, 1.0 / math.sqrt(d)))(q, k, v), np.float32)
+        try:
+            out = np.asarray(jax.jit(lambda q, k, v: flash_attention(
+                q, k, v, interpret=rehearse))(q, k, v), np.float32)
+        except Exception as e:  # noqa: BLE001 - report every shape, then fail
+            failures.append(f"q {(b, n, h, d)} M={m}: the compiler refused "
+                            f"it: {type(e).__name__}: {str(e)[:1500]}")
+            continue
+        err = float(np.max(np.abs(out - ref)) / np.max(np.abs(ref)))
+        if not np.isfinite(out).all() or err > KERNEL_REL_TOL:
+            failures.append(f"q {(b, n, h, d)} M={m}: rel err {err:.4f} "
+                            f"against xla_attention (tolerance "
+                            f"{KERNEL_REL_TOL})")
+        rows.append({"q": [b, n, h, d], "kv_len": m,
+                     "rel_err": round(err, 5)})
+    if failures:
+        raise SystemExit("kernel phase failed:\n" + "\n".join(failures))
+    print(json.dumps({"device": {"platform": platform,
+                                 "kind": devices[0].device_kind,
+                                 "count": len(devices)},
+                      "interpret": rehearse, "shapes": rows}), flush=True)
+    return 0
+
+
+def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    log_path = os.path.join(out_dir, "kernels.stderr.log")
+    cmd = [sys.executable, os.path.abspath(__file__), "--kernel-child"]
+    if cfg["rehearsal"]:
+        cmd.append("--rehearse")
+    with open(log_path, "wb") as log:
+        proc = subprocess.run(cmd, cwd=out_dir, env=env,
+                              stdout=subprocess.PIPE, stderr=log,
+                              timeout=cfg["first_timeout"])
+    with open(log_path, "rb") as f:
+        tail = f.read()[-3000:].decode("utf-8", "replace")
+    check(proc.returncode == 0,
+          f"kernel child exited with code {proc.returncode}:\n{tail}")
+    lines = proc.stdout.decode().strip().splitlines()
+    check(bool(lines), f"kernel child printed nothing:\n{tail}")
+    report = json.loads(lines[-1])
+    if result["device"] is None:     # a kernels-only run
+        result["device"] = report["device"]
+    result["smoke_facts"]["pallas_flash_attention"] = {
+        "interpret": report["interpret"], "shapes": report["shapes"]}
+    say(f"kernels: {len(report['shapes'])} shape(s) match xla_attention")
+
+
+# --- main --------------------------------------------------------------------
+
+def configuration(rehearse: bool) -> dict:
+    if rehearse:
+        # tiny family: its VAE scales by 2 where the latent rule divides
+        # by 8, so a 64 px request decodes to 16 px
+        return {"rehearsal": True, "platform": "cpu", "sdxl_px": 64,
+                "sdxl_out_px": 16, "steps": 2, "upscale_in_px": 32,
+                "upscale_out_px": 128, "first_timeout": 600.0,
+                "steady_timeout": 120.0, "monitor_interval_s": 0.0}
+    return {"rehearsal": False, "platform": "tpu", "sdxl_out_px": 1024,
+            "upscale_in_px": 512, "upscale_out_px": 2048,
+            "first_timeout": 900.0, "steady_timeout": 300.0,
+            "monitor_interval_s": 5.0}
+
+
+def child_env(rehearse: bool) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = HERE + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    if rehearse:
+        env["JAX_PLATFORMS"] = "cpu"
+        env["DTPU_DEFAULT_FAMILY"] = "tiny"
+    else:
+        # full width or nothing: never a family override on the chip
+        env.pop("DTPU_DEFAULT_FAMILY", None)
+    return env
+
+
+def versions() -> dict:
+    out = {}
+    for dist in ("jax", "jaxlib", "libtpu", "flax", "numpy"):
+        try:
+            out[dist] = importlib.metadata.version(dist)
+        except importlib.metadata.PackageNotFoundError:
+            out[dist] = None
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU rehearsal: tiny family, small sizes")
+    ap.add_argument("--out", default=os.path.join(
+        HERE, "chiprun_out", "chip_smoke"), help="output directory")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help=f"comma-separated subset of {','.join(PHASES)}")
+    ap.add_argument("--kernel-child", action="store_true",
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.kernel_child:
+        return kernel_child(args.rehearse)
+
+    phases = [p for p in args.phases.split(",") if p]
+    for missing in ("comfyui_distributed_tpu", "workflows"):
+        if not os.path.isdir(os.path.join(HERE, missing)):
+            print(f"chip_smoke: {missing}/ is not next to this script; "
+                  f"it drives the repo it lives in", file=sys.stderr)
+            return 2
+    if not set(phases) <= set(PHASES) or not phases:
+        print(f"chip_smoke: --phases takes a subset of {PHASES}",
+              file=sys.stderr)
+        return 2
+    if not args.rehearse and (os.environ.get("JAX_PLATFORMS") or ""
+                              ).strip().lower() == "cpu":
+        print("chip_smoke: JAX_PLATFORMS=cpu holds JAX to the CPU here; "
+              "this is the chip check and it does not fall back "
+              "(--rehearse is the CPU rehearsal)", file=sys.stderr)
+        return 2
+
+    cfg = configuration(args.rehearse)
+    out_dir = os.path.abspath(args.out)
+    os.makedirs(out_dir, exist_ok=True)
+    env = child_env(args.rehearse)
+    summary = {"device": None, "mesh_axes": None,
+               "rehearsal": args.rehearse, "phases": phases,
+               "smoke_facts": {"versions": versions()}}
+    t0 = time.monotonic()
+    try:
+        if "sdxl" in phases or "upscale" in phases:
+            server_phases(phases, cfg, out_dir, env, summary)
+        if "kernels" in phases:
+            kernel_phase(cfg, out_dir, env, summary)
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
+        return 1
+    summary["smoke_facts"]["total_wall_s"] = round(time.monotonic() - t0, 1)
+    summary["claim"] = None
+    with open(os.path.join(out_dir, "summary.json"), "w",
+              encoding="utf-8") as f:
+        json.dump(summary, f, indent=1)
+    print(json.dumps(summary), flush=True)
+    # the result: the verdict and the device, nothing else, last
+    print(json.dumps({"ok": True, "device": summary["device"]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -26,23 +26,22 @@ def _maybe_init_multihost() -> None:
     initialize_multihost()
 
 
-def _guard_backend() -> None:
-    """Wedge-resistant startup (escape ladder, parallel/mesh.py).  CPU
-    fallback only when single-host: one silently-CPU process in an
-    otherwise-TPU pod would hang or crash the whole pod at mesh build —
-    a wedged pod member must fail fast with the ladder report instead."""
-    from comfyui_distributed_tpu.parallel.mesh import ensure_usable_backend
-    multihost = os.environ.get("DTPU_COORDINATOR") is not None
-    rep = ensure_usable_backend(allow_cpu_fallback=not multihost)
-    if not rep["ok"]:
-        raise SystemExit(
-            f"backend unusable after the escape ladder (multihost member "
-            f"must not fall back to CPU): {json.dumps(rep['attempts'])}")
+def _start_backend() -> None:
+    """What ``serve``, ``worker`` and ``run`` do before any JAX work:
+    initialise the backend in THIS process — exiting non-zero when it is
+    not a TPU and the operator did not ask for the CPU
+    (``parallel/mesh.require_backend``) — and switch the persistent
+    compile cache on before the first compilation."""
+    from comfyui_distributed_tpu.parallel.mesh import require_backend
+    from comfyui_distributed_tpu.runtime.manager import \
+        enable_persistent_compile_cache
+    require_backend()
+    enable_persistent_compile_cache()
 
 
 def cmd_serve(args) -> int:
     _maybe_init_multihost()
-    _guard_backend()
+    _start_backend()
     if getattr(args, "standby", False):
         # hot-standby master: watch the primary's lease in the shared
         # DTPU_WAL_DIR, take over (replay + resume) on expiry
@@ -59,7 +58,7 @@ def cmd_serve(args) -> int:
 
 def cmd_worker(args) -> int:
     _maybe_init_multihost()
-    _guard_backend()
+    _start_backend()
     from comfyui_distributed_tpu.server.app import ServerState, serve
     state = ServerState(config_path=args.config, is_worker=True,
                         models_dir=args.models_dir)
@@ -95,7 +94,7 @@ def cmd_run(args) -> int:
     if args.via:
         return _run_via_server(args)
     _maybe_init_multihost()
-    _guard_backend()
+    _start_backend()
     from comfyui_distributed_tpu.ops.base import OpContext
     from comfyui_distributed_tpu.parallel.mesh import get_runtime
     from comfyui_distributed_tpu.workflow import WorkflowExecutor

@@ -148,14 +148,12 @@ def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   scale: float) -> jax.Array:
-    """The reference attention math with a memory ceiling.  The single
-    copy both the default impl and the flash kernel's over-VMEM fallback
-    use — duplicates would drift.
+    """The reference attention math with a memory ceiling: the default
+    impl, and the oracle the flash kernel is checked against.
 
     The fp32 score tensor is [B, H, N, M]; at SDXL 1024px (N=M=4096)
-    with a CFG-stacked batch that is ~10 GB — more than a v5e chip's
-    HBM (the r4 on-chip OOM).  Softmax is per-QUERY-row, so scanning
-    over query chunks is numerically EXACT (no online rescaling
+    with a CFG-stacked batch that is 1.3 GB per attention.  Softmax is
+    per-QUERY-row, so scanning over query chunks is numerically EXACT (no online rescaling
     needed); each chunk materializes only [B, H, chunk, M].  The chunk
     choice is static (shapes + env), so there is no dynamic control
     flow under jit; ``DTPU_ATTN_SCORES_BYTES`` tunes the ceiling

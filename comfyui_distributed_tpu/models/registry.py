@@ -286,26 +286,28 @@ class DiffusionPipeline:
 
     # --- tensor parallelism -------------------------------------------------
 
-    def _ensure_tp_sharded(self) -> None:
-        """Lay the UNet/CLIP/VAE params out for tensor parallelism
-        when the live mesh has a ``tensor`` axis (megatron-style column splits via
-        ``parallel/sharding.params_shardings``; GSPMD inserts the
-        matching collectives inside the jitted sample core).  No-op on
-        tensor==1 meshes and when already laid out for this mesh, so the
-        single-chip serving path pays nothing.  This is the serving-side
-        counterpart of ``parallel/train.shard_train_step`` — without it
-        tp was train-only and inference weights stayed replicated.
+    def _ensure_tp_sharded(self, batch: Any = None) -> None:
+        """Lay the UNet/CLIP/VAE params out over the serving mesh, once per
+        mesh (``parallel/sharding.params_shardings``): megatron-style
+        column splits over a ``tensor`` axis (GSPMD inserts the matching
+        collectives inside the jitted sample core), full replicas over
+        ``data``.  Without it the weights sit uncommitted on device 0 and
+        every sharded call copies them to the other chips again.
+
+        The mesh is the one ``batch`` (latents, images) is sharded over
+        when it is, else the live runtime's — in a server they are the
+        same mesh.  No-op on a one-device mesh and when already laid out
+        for this mesh, so the single-chip serving path pays nothing.  This
+        is the serving-side counterpart of
+        ``parallel/train.shard_train_step``.
         Floor override for tiny test models: ``DTPU_TP_MIN_SHARD_ELEMENTS``."""
         from comfyui_distributed_tpu.parallel.mesh import get_live_runtime
-        from comfyui_distributed_tpu.utils.constants import TENSOR_AXIS
-        rt = get_live_runtime()
-        if rt is None or rt.mesh is None:
+        mesh = shd.mesh_of(batch)
+        if mesh is None:
+            rt = get_live_runtime()
+            mesh = rt.mesh if rt is not None else None
+        if mesh is None or mesh.size <= 1 or self._tp_mesh is mesh:
             return
-        mesh = rt.mesh
-        if int(mesh.shape.get(TENSOR_AXIS, 1)) <= 1 \
-                or self._tp_mesh is mesh:
-            return
-        from comfyui_distributed_tpu.parallel import sharding as shd
         min_el = int(os.environ.get("DTPU_TP_MIN_SHARD_ELEMENTS",
                                     shd.MIN_SHARD_ELEMENTS))
         with self._lock:
@@ -331,8 +333,8 @@ class DiffusionPipeline:
             # serve-boot one-off; drop the cache so post-layout traces
             # re-resolve the gates against the live mesh.
             self._jit_cache.clear()
-            log(f"tp: UNet/CLIP/VAE params laid out over tensor="
-                f"{int(mesh.shape[TENSOR_AXIS])} for serving")
+            log(f"{self.name}: UNet/CLIP/VAE params laid out over mesh "
+                f"{dict(mesh.shape)} for serving")
 
     # --- text ---------------------------------------------------------------
 
@@ -389,7 +391,7 @@ class DiffusionPipeline:
     # --- latents ------------------------------------------------------------
 
     def vae_encode(self, images: jnp.ndarray) -> jnp.ndarray:
-        self._ensure_tp_sharded()
+        self._ensure_tp_sharded(images)
         fn = self._jitted("vae_enc", lambda p, x: self.vae.apply(
             {"params": p}, x, method=self.vae.encode))
         return fn(self.vae_params, images)
@@ -415,7 +417,7 @@ class DiffusionPipeline:
             check_interrupt=check_interrupt))
 
     def vae_decode(self, latents: jnp.ndarray) -> jnp.ndarray:
-        self._ensure_tp_sharded()
+        self._ensure_tp_sharded(latents)
         fn = self._jitted("vae_dec", lambda p, z: self.vae.apply(
             {"params": p}, z, method=self.vae.decode))
         return fn(self.vae_params, latents)
@@ -579,9 +581,9 @@ class DiffusionPipeline:
         per-sample ADM array (replicated over every block) or a list
         with one array per entry, conds first then unconds.
         The denoise loop is jit-compiled and cached per static config."""
-        # serving-side tensor parallelism: lay the tower params out
-        # over the mesh's tensor axis before they enter the jitted core
-        self._ensure_tp_sharded()
+        # lay the tower params out over the mesh the batch lives on
+        # before they enter the jitted core
+        self._ensure_tp_sharded(latents)
 
         def _norm(entries):
             if not isinstance(entries, (list, tuple)):
@@ -1040,7 +1042,8 @@ class DiffusionPipeline:
             return fn
 
 
-def _virtual_params(module, seed: int, *shaped_args) -> Any:
+def _virtual_params(module, seed: int, *shaped_args,
+                    storage_dtype: Any = None) -> Any:
     """Deterministic random init WITHOUT compiling the model's init graph.
 
     ``module.init`` traces the full forward pass — for SDXL that is a
@@ -1051,15 +1054,17 @@ def _virtual_params(module, seed: int, *shaped_args) -> Any:
     for norm scales.  Per-leaf streams are keyed by crc32 of the tree path —
     stable across processes and hosts, so every mesh host materializes
     identical weights (the reference's "same models on all machines"
-    requirement, ``README.md:189-193``)."""
-    import zlib
+    requirement, ``README.md:189-193``).
 
+    ``storage_dtype`` (bf16 weight storage) casts each float32 leaf on
+    the HOST before the transfer: SDXL's towers are 13.6 GB as fp32, and
+    a 16 GB chip cannot hold them next to their own bf16 copy."""
     shapes = jax.eval_shape(module.init, jax.random.PRNGKey(0), *shaped_args)
-    leaf = _virtual_leaf(seed)
+    leaf = _virtual_leaf(seed, storage_dtype)
     return jax.tree_util.tree_map_with_path(leaf, shapes)["params"]
 
 
-def _virtual_leaf(seed: int):
+def _virtual_leaf(seed: int, storage_dtype: Any = None):
     """The ONE copy of the virtual-init fill rules (shared with partial
     initializers like gligen_attach's missing-leaf graft)."""
     import zlib
@@ -1079,7 +1084,9 @@ def _virtual_leaf(seed: int):
             fan_in = int(np.prod(shape[:-1])) or 1
             arr = rng.standard_normal(shape, dtype=np.float32) \
                 / np.sqrt(fan_in)
-        return jnp.asarray(arr, dtype=dtype)
+        if storage_dtype is not None and dtype == jnp.float32:
+            dtype = storage_dtype
+        return jnp.asarray(arr.astype(dtype))
 
     return leaf
 
@@ -1129,12 +1136,15 @@ def load_pipeline(ckpt_name: str, models_dir: Optional[str] = None,
         x = jnp.zeros((1, h // ds, w // ds, fam.unet.in_channels))
         ts = jnp.zeros((1,))
         ctx = jnp.zeros((1, 77, ctx_dim))
-        unet_p = _virtual_params(unet_mod.UNet(fam.unet), seed, x, ts, ctx)
+        store = _storage_dtype(fam)
+        unet_p = _virtual_params(unet_mod.UNet(fam.unet), seed, x, ts, ctx,
+                                 storage_dtype=store)
         clip_ps = []
         for i, ccfg in enumerate(fam.clips):
             tok = jnp.zeros((1, ccfg.max_length), jnp.int32)
             clip_ps.append(_virtual_params(
-                clip_mod.CLIPTextModel(ccfg), seed + 1 + i, tok))
+                clip_mod.CLIPTextModel(ccfg), seed + 1 + i, tok,
+                storage_dtype=store))
         img = jnp.zeros((1, h, w, 3))
         vae_p = _virtual_params(vae_mod.VAE(fam.vae), seed + 100, img)
         log(f"virtual checkpoint {ckpt_name!r} ({fam.name}): no file on disk, "
@@ -1169,6 +1179,12 @@ def _bf16_weights_enabled(fam: ModelFamily) -> bool:
     if env is not None:
         return env not in ("0", "false", "")
     return fam.unet.dtype == jnp.bfloat16
+
+
+def _storage_dtype(fam: ModelFamily) -> Any:
+    """Storage dtype for virtually-initialised compute towers: bf16 where
+    `_bf16_weights_enabled`, else None (leave each leaf's own dtype)."""
+    return jnp.bfloat16 if _bf16_weights_enabled(fam) else None
 
 
 def _cast_bf16(tree):
@@ -1559,7 +1575,8 @@ def load_clip(clip_names: List[str], models_dir: Optional[str] = None,
             seed = _name_seed(name) + i
             tok = jnp.zeros((1, ccfg.max_length), jnp.int32)
             clip_ps.append(_virtual_params(
-                clip_mod.CLIPTextModel(ccfg), seed, tok))
+                clip_mod.CLIPTextModel(ccfg), seed, tok,
+                storage_dtype=_storage_dtype(fam)))
             log(f"virtual CLIP tower {name!r} ({fam.name}[{i}]): no file "
                 f"on disk, deterministic init (seed {seed})")
 
@@ -1610,7 +1627,8 @@ def load_unet(unet_name: str, models_dir: Optional[str] = None,
         x = jnp.zeros((1, 8, 8, fam.unet.in_channels))
         unet_p = _virtual_params(
             unet_mod.UNet(fam.unet), seed, x, jnp.zeros((1,)),
-            jnp.zeros((1, 77, fam.unet.context_dim)))
+            jnp.zeros((1, 77, fam.unet.context_dim)),
+            storage_dtype=_storage_dtype(fam))
         log(f"virtual UNet {unet_name!r} ({fam.name}): no file on disk, "
             f"deterministic init (seed {seed})")
 
@@ -1618,7 +1636,8 @@ def load_unet(unet_name: str, models_dir: Optional[str] = None,
     for i, ccfg in enumerate(fam.clips):
         tok = jnp.zeros((1, ccfg.max_length), jnp.int32)
         clip_ps.append(_virtual_params(
-            clip_mod.CLIPTextModel(ccfg), seed + 1 + i, tok))
+            clip_mod.CLIPTextModel(ccfg), seed + 1 + i, tok,
+            storage_dtype=_storage_dtype(fam)))
     img = jnp.zeros((1, 8 * fam.vae.downscale, 8 * fam.vae.downscale, 3))
     vae_p = _virtual_params(vae_mod.VAE(fam.vae), seed + 100, img)
     if _bf16_weights_enabled(fam):

@@ -138,8 +138,10 @@ def _scan_sampler(step_fn, x, sigmas, carry_init=None):
     breaks and peak memory doubles; history slots (``carry_init``) are
     extra buffers by design (multistep samplers need them).
 
-    Per-step interrupt (reference parity with ComfyUI's in-sampler
-    interrupt): each iteration polls the process-global flag
+    Per-step interrupt (``DTPU_INTERRUPT_POLL=1``; reference parity with
+    ComfyUI's in-sampler interrupt, off by default because a program
+    with a host callback is never persisted to the compile cache): each
+    iteration polls the process-global flag
     (:mod:`comfyui_distributed_tpu.runtime.interrupt`) via a host callback
     and, once set, skips the model call — the scan still runs its remaining
     (now trivial) iterations and returns the partially-denoised latent.

@@ -30,11 +30,11 @@ NEG_INF = -1e30
 BLOCK_Q = 128
 BLOCK_K = 512
 
-# Per-program VMEM budget (bytes).  Each program holds its q tile, the
-# FULL padded K/V for its head, the output tile and fp32 accumulators;
-# v5e TensorCore VMEM is ~16 MiB, and exceeding it is a compile-time
-# failure on hardware that interpret-mode tests can't see.  Shapes over
-# budget fall back to the XLA path instead of crashing the serving run.
+# Per-program VMEM budget (bytes), under Mosaic's default 16 MiB scoped
+# limit.  Each program holds its q tile, the FULL padded K/V for its head
+# and the output tile — every one of them twice, because the pipeline
+# double-buffers each BlockSpec'd operand — plus the kernel's fp32
+# temporaries.  A shape over budget raises (see `flash_attention`).
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
@@ -85,18 +85,32 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, pads)
 
 
+def vmem_bytes(kv_pad: int, block_k: int, dp: int, itemsize: int) -> int:
+    """What one program of the kernel keeps in VMEM: the double-buffered
+    q/out tiles and full K/V, and the fp32 temporaries of `_flash_kernel`
+    (scaled q, one k and one v block, the logits and probability tiles,
+    the accumulator)."""
+    pipelined = 2 * itemsize * (2 * BLOCK_Q * dp + 2 * kv_pad * dp)
+    temporaries = 4 * (2 * BLOCK_Q * dp + 2 * block_k * dp
+                       + 2 * BLOCK_Q * block_k)
+    return pipelined + temporaries
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     scale: Optional[float] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """[B, N, H, D] attention, q vs k/v (cross-attention allowed: M != N).
 
     Pads N to BLOCK_Q, M to BLOCK_K, D to 128 lanes; grid is
     (B*H, N/BLOCK_Q); each program holds its q tile and streams the full
-    K/V for its head out of VMEM.  ``interpret`` defaults to True off-TPU
-    (CPU meshes in tests) so the same model code runs everywhere.
+    K/V for its head out of VMEM.
+
+    ``interpret=True`` runs the Pallas interpreter (CPU tests pass it);
+    nothing selects it on its own.  A caller that asked for this kernel
+    gets this kernel: a shape whose K/V do not fit the VMEM budget raises
+    ``ValueError`` naming the shape, and is never handed to another
+    implementation behind the caller's back.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, N, H, D = q.shape
     M = k.shape[1]
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
@@ -112,21 +126,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     vf = _pad_to(_pad_to(vf, 1, block_k), 2, 128)
     n_pad, dp = qf.shape[1], qf.shape[2]
 
-    # static VMEM estimate for one program: q/out tiles + full K/V +
-    # fp32 logits/accumulator tiles (shapes are trace-time constants, so
-    # this branch is resolved at trace time — no control flow under jit)
-    itemsize = jnp.dtype(q.dtype).itemsize
-    vmem = (2 * BLOCK_Q * dp * itemsize            # q tile + out tile
-            + 2 * kf.shape[1] * dp * itemsize      # full K + V
-            + BLOCK_Q * block_k * 4                # logits tile (fp32)
-            + BLOCK_Q * dp * 4)                    # accumulator (fp32)
+    vmem = vmem_bytes(kf.shape[1], block_k, dp,
+                      jnp.dtype(q.dtype).itemsize)
     if vmem > VMEM_BUDGET_BYTES:
-        from comfyui_distributed_tpu.models.layers import xla_attention
-        from comfyui_distributed_tpu.utils.logging import debug_log
-        debug_log(f"flash_attention: est. {vmem/2**20:.1f} MiB/program "
-                  f"VMEM > {VMEM_BUDGET_BYTES/2**20:.0f} MiB budget "
-                  f"(kv_len {kf.shape[1]}) — using XLA fallback")
-        return xla_attention(q, k, v, scale)
+        raise ValueError(
+            f"flash_attention: q {tuple(q.shape)} against kv length {M} "
+            f"needs about {vmem / 2**20:.1f} MiB of VMEM per program "
+            f"(the full K and V of a head, double-buffered), over the "
+            f"{VMEM_BUDGET_BYTES / 2**20:.0f} MiB budget; use "
+            f"attn_impl='xla' for this shape")
 
     grid = (B * H, n_pad // BLOCK_Q)
     kernel = functools.partial(_flash_kernel, scale=scale, kv_len=M,

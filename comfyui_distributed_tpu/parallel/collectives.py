@@ -15,7 +15,6 @@ Reference semantics preserved:
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional
 
 import jax
@@ -23,31 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # JAX >= 0.6: top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:                     # older JAX: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
 from comfyui_distributed_tpu.parallel import sharding as shd
 from comfyui_distributed_tpu.utils.constants import DATA_AXIS
-
-# the replication-check kwarg was renamed check_rep -> check_vma across JAX
-# versions; resolve the installed spelling once
-_SHARD_MAP_REP_KW = next(
-    (kw for kw in ("check_vma", "check_rep")
-     if kw in inspect.signature(_shard_map_impl).parameters), None)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: one spelling for every call site
-    (here, ``parallel/ring.py``, tests).  ``check_vma=False`` disables the
-    static replication checker under whichever name the installed JAX
-    uses (``check_vma``, formerly ``check_rep``)."""
-    kwargs = {}
-    if _SHARD_MAP_REP_KW is not None:
-        kwargs[_SHARD_MAP_REP_KW] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
 
 
 def replica_seeds(base_seed: int, num_replicas: int,
@@ -102,8 +78,8 @@ def all_gather_data(x: jax.Array, mesh: Mesh) -> jax.Array:
         return jax.lax.all_gather(shard, DATA_AXIS, axis=0, tiled=True)
     # check_vma=False: replication over the unused tensor/seq axes (size 1)
     # can't be statically inferred by shard_map's rep checker.
-    return shard_map(f, mesh=mesh, in_specs=shd.mesh_spec(DATA_AXIS),
-                     out_specs=shd.mesh_spec(), check_vma=False)(x)
+    return jax.shard_map(f, mesh=mesh, in_specs=shd.mesh_spec(DATA_AXIS),
+                         out_specs=shd.mesh_spec(), check_vma=False)(x)
 
 
 def psum_data(x: jax.Array, mesh: Mesh) -> jax.Array:
@@ -111,8 +87,8 @@ def psum_data(x: jax.Array, mesh: Mesh) -> jax.Array:
     gathering and for gradient reduction in the train step)."""
     def f(shard):
         return jax.lax.psum(shard, DATA_AXIS)
-    return shard_map(f, mesh=mesh, in_specs=shd.mesh_spec(DATA_AXIS),
-                     out_specs=shd.mesh_spec(), check_vma=False)(x)
+    return jax.shard_map(f, mesh=mesh, in_specs=shd.mesh_spec(DATA_AXIS),
+                         out_specs=shd.mesh_spec(), check_vma=False)(x)
 
 
 def pad_to_multiple(n: int, m: int) -> int:

@@ -30,10 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-# version-portable shard_map (check_vma/check_rep shim) — ONE shim for
-# every call site, see parallel/collectives.py
 from comfyui_distributed_tpu.parallel import sharding as shd
-from comfyui_distributed_tpu.parallel.collectives import shard_map
 
 from comfyui_distributed_tpu.utils.constants import (
     DATA_AXIS,
@@ -159,8 +156,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     spec = shd.mesh_spec(b_ax, axis_name, h_ax, None)
     body = partial(_ring_body, axis_name=axis_name, n_shards=n_shards,
                    causal=causal, scale=scale)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def attention_reference(q, k, v, causal: bool = False,

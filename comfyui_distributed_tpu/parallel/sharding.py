@@ -111,6 +111,13 @@ def put_on_mesh(x: Any, mesh: Mesh, spec: P) -> Any:
     return jax.device_put(x, NamedSharding(mesh, spec))
 
 
+def mesh_of(x: Any) -> Optional[Mesh]:
+    """The multi-device mesh an array is laid out over, else None (host
+    arrays, single-device arrays, one-device meshes)."""
+    mesh = getattr(getattr(x, "sharding", None), "mesh", None)
+    return mesh if isinstance(mesh, Mesh) and mesh.size > 1 else None
+
+
 def serving_mesh() -> Optional[Mesh]:
     """The live runtime's mesh IFF tensor parallelism is engaged (a built
     runtime whose ``tensor`` axis is > 1); None otherwise.  The gate every

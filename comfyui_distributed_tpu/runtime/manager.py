@@ -6,9 +6,13 @@ files with session headers, PID persistence in the config file,
 revive-or-purge on restart, process-tree kill, cleanup-on-exit hooks and
 delayed auto-launch.
 
-On TPU a "worker" is not one-process-per-chip (the mesh handles local chips);
-managed workers exist for multi-host deployments and CPU staging — each runs
-``python -m comfyui_distributed_tpu.cli worker --port N``.
+On TPU a "worker" is not one-process-per-chip: ONE process drives all of
+a host's chips through the mesh, and a chip belongs to one process at a
+time.  Managed workers exist for other hosts and for CPU staging — each runs
+``python -m comfyui_distributed_tpu.cli worker --port N``.  A worker
+launched next to a master that holds the chips must say so in its config
+(``"platform": "cpu"``); otherwise it finds no TPU, exits non-zero, and the
+start-up watch below reports it.
 """
 
 from __future__ import annotations
@@ -29,61 +33,40 @@ from comfyui_distributed_tpu.utils.logging import debug_log, log
 
 MASTER_PID_ENV = "DTPU_MASTER_PID"
 
-_compile_cache_dir: Optional[str] = None
-_compile_cache_lock = threading.Lock()
+# <checkout>/.jax_cache, resolved from this package's own location: the
+# path is part of what a cache hit needs, so it depends on neither the
+# CWD, nor ``~``, nor anything made up at run time
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
 def enable_persistent_compile_cache(
-        cache_dir: Optional[str] = None,
-        min_compile_secs: Optional[float] = None,
-        default_dir: Optional[str] = None) -> Optional[str]:
-    """Turn on JAX's persistent (on-disk) XLA compilation cache.
+        min_compile_secs: Optional[float] = None) -> str:
+    """Turn on JAX's persistent (on-disk) XLA compilation cache, so a
+    fresh process pays trace + deserialize for a program an earlier one
+    compiled.  ``serve``, ``worker``, ``run``, ``bench.py``,
+    ``chip_smoke.py``'s children and the tests all call this.
 
-    Makes compilation a ONE-TIME cost across process restarts: a warm
-    cache turns the cold-start SDXL compile into a trace + deserialize.
-    Resolution order for the directory: explicit ``cache_dir`` >
-    ``DTPU_COMPILE_CACHE_DIR`` env > ``default_dir`` (a caller's
-    preferred location — bench/tests pass the repo-local ``.jax_cache``)
-    > the default under ``~/.cache``; the values "0"/"off"/"" in the
-    env disable the cache entirely.
-
-    The resolved dir is re-exported to ``os.environ`` so workers spawned
-    by :class:`WorkerProcessManager` (which inherit the environment)
-    share one cache with the master — every participant compiles each
-    program at most once per fleet, not once per process.  Idempotent;
-    returns the active dir (None when disabled)."""
-    global _compile_cache_dir
-    with _compile_cache_lock:
-        if cache_dir is None:
-            cache_dir = os.environ.get(C.COMPILE_CACHE_ENV)
-            if cache_dir is not None \
-                    and cache_dir.strip().lower() in ("", "0", "off"):
-                debug_log("persistent compile cache disabled via env")
-                return None
-            cache_dir = cache_dir or default_dir \
-                or C.COMPILE_CACHE_DEFAULT_DIR
-        cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-        if _compile_cache_dir == cache_dir:
-            return _compile_cache_dir
-        import jax
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs",
-                float(C.COMPILE_CACHE_MIN_COMPILE_SECS
-                      if min_compile_secs is None else min_compile_secs))
-            # cache every entry that clears the time bar, regardless of
-            # serialized size
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception as e:  # noqa: BLE001 - cache is an optimization
-            log(f"persistent compile cache unavailable: {e!r}")
-            return None
-        os.environ[C.COMPILE_CACHE_ENV] = cache_dir
-        _compile_cache_dir = cache_dir
-        log(f"persistent compile cache at {cache_dir}")
-        return cache_dir
+    One rule for the directory: where ``JAX_COMPILATION_CACHE_DIR`` is
+    set, JAX already uses it and this sets NO directory in code (a sealed
+    machine's cache must be placeable from outside); otherwise it is
+    ``<checkout>/.jax_cache``.  Spawned workers resolve the same way, so
+    one host's processes share one cache.  Returns the directory in use."""
+    import jax
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = _CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(C.COMPILE_CACHE_MIN_COMPILE_SECS
+              if min_compile_secs is None else min_compile_secs))
+    # cache every entry that clears the time bar, regardless of
+    # serialized size
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    log(f"persistent compile cache at {cache_dir}")
+    return cache_dir
 
 
 class WorkerProcessManager:
@@ -158,6 +141,11 @@ class WorkerProcessManager:
             for k in ("DTPU_COORDINATOR", "DTPU_NUM_PROCESSES",
                       "DTPU_PROCESS_ID"):
                 env.pop(k, None)
+            # a worker next to a master that holds the chips runs on the
+            # CPU only when its config says so — pinned by ITS
+            # environment, before it imports jax
+            if str(worker.get("platform") or "").lower() == "cpu":
+                env["JAX_PLATFORMS"] = "cpu"
             # serve-path mesh layout (ISSUE 16): the worker inherits
             # DTPU_TP / DTPU_MESH_SHAPE — resolve them HERE so a
             # malformed layout fails THIS launch with a clear error
@@ -231,7 +219,33 @@ class WorkerProcessManager:
         self.save_processes()
         log(f"launched worker {wid} (pid {p.pid}, port {worker['port']}, "
             f"log {log_path})")
+        threading.Thread(target=self._watch_startup, args=(wid, p, log_path),
+                         daemon=True, name=f"dtpu-watch-{wid}").start()
         return {k: v for k, v in entry.items() if k != "process"}
+
+    def _watch_startup(self, wid: str, p, log_path: str) -> None:
+        """A worker that dies while starting (no TPU left for it, a bad
+        argument) says why only in its own log; say it in the master's
+        too, and keep the exit code for ``get_managed_workers``."""
+        import subprocess
+        try:
+            code = p.wait(timeout=C.WORKER_STARTUP_WATCH_S)
+        except subprocess.TimeoutExpired:
+            return
+        with self._lock:
+            entry = self.processes.get(wid)
+            if entry is None or entry.get("pid") != p.pid:
+                return              # stopped on purpose, or relaunched
+            entry["exit_code"] = code
+            entry["launching"] = False
+        try:
+            with open(log_path, "rb") as f:
+                f.seek(max(0, os.path.getsize(log_path) - 600))
+                tail = f.read().decode("utf-8", "replace").strip()
+        except OSError:
+            tail = ""
+        log(f"worker {wid} (pid {p.pid}) EXITED with code {code} while "
+            f"starting; its log {log_path} ends:\n{tail}")
 
     # --- stop (reference stop_worker :768) ---------------------------------
 
@@ -262,6 +276,7 @@ class WorkerProcessManager:
             out[wid] = {
                 "pid": entry.get("pid"),
                 "alive": proc.is_process_alive(entry.get("pid", -1)),
+                "exit_code": entry.get("exit_code"),
                 "launching": entry.get("launching", False),
                 "started_at": entry.get("started_at"),
                 "log_file": entry.get("log_file"),

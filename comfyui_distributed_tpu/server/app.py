@@ -705,6 +705,9 @@ class ServerState:
             reuse_on = reuse_mod.reuse_enabled()
             for item, imgs in zip(group, per_prompt):
                 entry = {"status": "success", "images": len(imgs),
+                         # what a client without disk access can check
+                         # about a PreviewImage output (chip_smoke.py)
+                         "image_shapes": [list(im.shape) for im in imgs],
                          "duration_s": res.total_s,
                          "finished_at": done_t}
                 if k > 1:
@@ -2770,13 +2773,8 @@ def serve(host: str = "0.0.0.0", port: int = 8288,
     """Blocking server entry point."""
     state = state or ServerState()
     state.port = port
-    # compilation is a one-time cost: persistent XLA cache across restarts
-    # (spawned workers inherit the resolved dir and share it), plus an
     # optional startup warmup — DTPU_WARMUP='{"ckpt_name": ..., "width":
     # ..., ...}' AOT-compiles the serving shape before the first request
-    from comfyui_distributed_tpu.runtime.manager import \
-        enable_persistent_compile_cache
-    enable_persistent_compile_cache()
     # NOTE: the warmup thread compiles while the server is already
     # accepting requests; jax.monitoring events are process-wide, so a
     # prompt executed DURING warmup may report the warmup's traces in its

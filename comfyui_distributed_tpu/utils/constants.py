@@ -42,6 +42,7 @@ PROCESS_WAIT_TIMEOUT = 3.0
 WORKER_CHECK_INTERVAL = 2.0      # s between liveness polls
 STATUS_CHECK_INTERVAL = 5.0
 WORKER_STARTUP_DELAY = 2.0       # s before auto-launching workers
+WORKER_STARTUP_WATCH_S = 120.0   # s a launch watches for an early exit
 MEMORY_CLEAR_DELAY = 0.5
 PREFLIGHT_TIMEOUT = 0.3          # s health probe before dispatch
 
@@ -518,12 +519,6 @@ CHAOS_DELAY_DEFAULT_S = 0.25
 COORDINATOR_ENV = "DTPU_COORDINATOR"        # host:port -> jax.distributed
 NUM_PROCESSES_ENV = "DTPU_NUM_PROCESSES"    # pod process count
 PROCESS_ID_ENV = "DTPU_PROCESS_ID"          # this host's process index
-# wedge-resistant backend startup (parallel/mesh escape ladder)
-CLAIM_WINDOW_ENV = "DTPU_CLAIM_WINDOW_S"    # stale-claim takeover window
-SKIP_BACKEND_PROBE_ENV = "DTPU_SKIP_BACKEND_PROBE"  # skip subprocess probe
-INIT_PATIENCE_ENV = "DTPU_INIT_PATIENCE_S"  # total backend-init budget
-INIT_PROBE_TIMEOUT_ENV = "DTPU_INIT_PROBE_TIMEOUT_S"  # per-probe bound
-CPU_FALLBACK_DEVICES_ENV = "DTPU_CPU_FALLBACK_DEVICES"  # virtual dev count
 # serve-path mesh layout (parallel/mesh.axes_from_env, ISSUE 16): full
 # shape ("data=2,tensor=2" or positional "2x2x1") or the tensor-size
 # shorthand; unset keeps the pure data-parallel default
@@ -608,13 +603,7 @@ TRACE_ATTR_WHITELIST = frozenset({
 })
 
 # --- persistent compilation cache -------------------------------------------
-# Directory for JAX's persistent (on-disk) XLA compilation cache.  Resolution
-# (runtime/manager.enable_persistent_compile_cache): explicit arg > this env
-# > COMPILE_CACHE_DEFAULT_DIR.  Set to "0"/"off" to disable.  The resolved
-# dir is re-exported into the environment so spawned HTTP workers share one
-# cache with the master.
-COMPILE_CACHE_ENV = "DTPU_COMPILE_CACHE_DIR"
-COMPILE_CACHE_DEFAULT_DIR = "~/.cache/comfyui_distributed_tpu/xla_cache"
+# (directory rule: runtime/manager.enable_persistent_compile_cache)
 # only persist compilations worth the disk round trip; 0 also caches the
 # tiny convert/broadcast jits (useful in tests, noisy in production)
 COMPILE_CACHE_MIN_COMPILE_SECS = 0.5

@@ -87,15 +87,17 @@ def host_rss_peak_bytes() -> int:
 
 def device_memory_snapshot() -> Dict[str, Any]:
     """Device memory now: ``{"bytes_in_use", "peak_bytes_in_use",
-    "bytes_limit", "n_devices", "source"}``.
+    "bytes_limit", "n_devices", "per_device", "source"}``.
 
-    Sums ``memory_stats()`` over the local devices.  Backends whose
-    devices report ``None`` (CPU here; some PJRT plugins) fall back to
+    Sums ``memory_stats()`` over the local devices; ``per_device`` keeps
+    each device's own ``[bytes_in_use, peak_bytes_in_use]`` so "all on
+    device 0" is visible (empty under the host-RSS fallback).  Backends
+    whose devices report ``None`` (CPU here; some PJRT plugins) fall back to
     host RSS (current) / ``ru_maxrss`` (peak) with ``source:
     "host_rss"`` — the numbers stay meaningful (the CPU "device" IS host
     memory) and callers can tell which regime they're reading."""
     in_use = peak = limit = 0
-    n = 0
+    per_device: List[List[int]] = []
     try:
         import jax
         for d in jax.local_devices():
@@ -105,21 +107,23 @@ def device_memory_snapshot() -> Dict[str, Any]:
                 ms = None
             if not ms:
                 continue
-            in_use += int(ms.get("bytes_in_use", 0))
-            peak += int(ms.get("peak_bytes_in_use",
-                               ms.get("bytes_in_use", 0)))
+            d_in_use = int(ms.get("bytes_in_use", 0))
+            d_peak = int(ms.get("peak_bytes_in_use", d_in_use))
+            per_device.append([d_in_use, d_peak])
+            in_use += d_in_use
+            peak += d_peak
             limit += int(ms.get("bytes_limit", 0))
-            n += 1
     except Exception as e:  # noqa: BLE001 - jax may be mid-init elsewhere
         debug_log(f"device memory probe failed: {e}")
-    if n:
+    if per_device:
         return {"bytes_in_use": in_use, "peak_bytes_in_use": peak,
-                "bytes_limit": limit or None, "n_devices": n,
-                "source": "memory_stats"}
+                "bytes_limit": limit or None, "n_devices": len(per_device),
+                "per_device": per_device, "source": "memory_stats"}
     rss = host_rss_bytes()
     return {"bytes_in_use": rss,
             "peak_bytes_in_use": max(host_rss_peak_bytes(), rss),
-            "bytes_limit": None, "n_devices": 0, "source": "host_rss"}
+            "bytes_limit": None, "n_devices": 0, "per_device": [],
+            "source": "host_rss"}
 
 
 def _cache_bytes() -> int:
@@ -143,6 +147,7 @@ def snapshot_now(queue_depth: Optional[int] = None,
         "device_bytes_in_use": mem["bytes_in_use"],
         "device_peak_bytes": mem["peak_bytes_in_use"],
         "device_bytes_limit": mem["bytes_limit"],
+        "per_device_bytes": mem["per_device"],
         "host_rss_bytes": host_rss_bytes(),
         "utilization": utilization,
         "queue_depth": queue_depth,
@@ -436,6 +441,7 @@ def _host_only_snapshot() -> Dict[str, Any]:
         "device_bytes_in_use": rss,
         "device_peak_bytes": max(host_rss_peak_bytes(), rss),
         "device_bytes_limit": None,
+        "per_device_bytes": [],
         "host_rss_bytes": rss,
         "utilization": None,
         "queue_depth": None,

@@ -515,6 +515,13 @@ GLOBAL_RETRACES = RetraceStats()
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# persistent compile cache: pipeline counter per event.  JAX records
+# "cache_misses" where it WRITES an entry (a compile that was too short,
+# or whose program holds a host callback, is neither a hit nor a write)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile_cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile_cache_writes",
+}
 
 _monitoring_installed = False
 _monitoring_lock = threading.Lock()
@@ -522,7 +529,8 @@ _monitoring_lock = threading.Lock()
 
 def install_jax_monitoring() -> None:
     """Register the (process-global, idempotent) ``jax.monitoring``
-    listener feeding :data:`GLOBAL_RETRACES`.  Cheap to call per run."""
+    listeners feeding :data:`GLOBAL_RETRACES` and the persistent
+    compile cache's hit/write pipeline counters.  Cheap to call per run."""
     global _monitoring_installed
     with _monitoring_lock:
         if _monitoring_installed:
@@ -535,7 +543,13 @@ def install_jax_monitoring() -> None:
             elif name == _COMPILE_EVENT:
                 GLOBAL_RETRACES.bump("compiles")
 
+        def on_event(name: str, **kw) -> None:
+            counter = _CACHE_EVENTS.get(name)
+            if counter is not None:
+                GLOBAL_COUNTERS.bump(counter)
+
         monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(on_event)
         _monitoring_installed = True
 
 

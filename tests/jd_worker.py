@@ -21,7 +21,6 @@ initialize_multihost()         # DTPU_COORDINATOR/_NUM_PROCESSES/_PROCESS_ID
 
 import jax                     # noqa: E402  (after platform pin)
 import jax.numpy as jnp        # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 assert jax.process_count() == 2, jax.process_count()
@@ -44,7 +43,8 @@ def f(xs):
 
 
 total, gathered = jax.jit(
-    shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=(P(), P("data"))))(x)
+    jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                  out_specs=(P(), P("data"))))(x)
 
 tv = np.asarray(jax.device_get(total.addressable_data(0)))
 assert np.allclose(tv, 1 + 1 + 2 + 2), tv  # both processes contributed

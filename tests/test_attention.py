@@ -242,34 +242,55 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_layers_dispatch(self, rng):
-        """attn_impl='pallas' routes through the kernel and matches xla."""
+    def test_layers_dispatch(self, rng, monkeypatch):
+        """attn_impl='pallas' routes through the kernel and matches xla.
+        The model stack asks for the compiled kernel; this CPU test puts
+        the interpreter behind the same name explicitly."""
+        import functools
+        import importlib
+
         from comfyui_distributed_tpu.models.layers import (
             scaled_dot_product_attention)
+        fa = importlib.import_module(
+            "comfyui_distributed_tpu.ops.pallas.flash_attention")
+        monkeypatch.setattr(fa, "flash_attention", functools.partial(
+            fa.flash_attention, interpret=True))
         q, k, v = _qkv(rng, B=1, N=48, H=2, D=16)
         out_p = scaled_dot_product_attention(q, k, v, impl="pallas")
         out_x = scaled_dot_product_attention(q, k, v, impl="xla")
         np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_vmem_guard_falls_back_correctly(self, rng, monkeypatch):
-        """Shapes whose full K/V exceed the per-program VMEM budget must
-        take the XLA fallback (numerically identical) rather than hand
-        pallas_call a program that can't compile on hardware."""
+    def test_interpret_mode_is_never_chosen_for_the_caller(self):
+        """`interpret` is an explicit argument that defaults to False: no
+        backend check picks the interpreter behind the caller's back."""
+        import importlib
+        import inspect
+        fa = importlib.import_module(
+            "comfyui_distributed_tpu.ops.pallas.flash_attention")
+        sig = inspect.signature(fa.flash_attention)
+        assert sig.parameters["interpret"].default is False
+        assert "default_backend" not in inspect.getsource(fa)
+
+    def test_over_budget_shape_raises_naming_the_shape(self, rng,
+                                                       monkeypatch):
+        """A shape whose K/V do not fit the per-program VMEM budget is an
+        error that names the shape — never another implementation handed
+        back in the kernel's name."""
         import importlib
         fa = importlib.import_module(
             "comfyui_distributed_tpu.ops.pallas.flash_attention")
-        # shrink the budget so a modest shape trips the guard
-        monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 64 * 1024)
+        # SDXL's largest self-attention fits, double buffers included
+        assert fa.vmem_bytes(4096, fa.BLOCK_K, 128, 2) \
+            <= fa.VMEM_BUDGET_BYTES
+        # 16384 tokens of K and V per head do not
+        q = jnp.zeros((1, 16384, 1, 128), jnp.bfloat16)
         called = []
         monkeypatch.setattr(fa.pl, "pallas_call",
-                            lambda *a, **k: called.append(1) or fa.pl.pallas_call)
-        q, k, v = _qkv(rng, B=1, N=256, H=2, D=16)
-        out = fa.flash_attention(q, k, v, interpret=True)
-        assert not called, "guard did not divert away from pallas_call"
-        ref = attention_reference(q, k, v)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
+                            lambda *a, **k: called.append(1))
+        with pytest.raises(ValueError, match=r"\(1, 16384, 1, 128\).*VMEM"):
+            fa.flash_attention(q, q, q, interpret=True)
+        assert not called, "an over-budget shape reached pallas_call"
 
 
 class TestChunkedXLAAttention:

@@ -68,14 +68,20 @@ def test_real_ckpt_missing_file_fails_structured(tmp_path):
 
 
 class TestSuiteMode:
-    """Round-5 driver-window suite: detection, capped ladder budget,
-    artifact replay, best-completed-phase delivery."""
+    """Suite detection, and the rule that nothing exits 0 after a failed
+    phase or without the device the metric names."""
 
     def _bench(self):
         if REPO not in sys.path:
             sys.path.insert(0, REPO)
         import bench
         return bench
+
+    def _run(self, code, **env):
+        return subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120, cwd=REPO,
+            env={**os.environ, "PYTHONPATH": REPO, **env})
 
     def test_bare_invocation_is_suite_and_flags_opt_out(self):
         bench = self._bench()
@@ -87,85 +93,71 @@ class TestSuiteMode:
                      ["--repeats", "1"]):
             assert not bench.parse_args(argv).suite, argv
 
-    def test_ladder_budget_caps_suite_and_keeps_single_patient(self,
-                                                               monkeypatch):
-        bench = self._bench()
-        monkeypatch.delenv("DTPU_CLAIM_WINDOW_S", raising=False)
-        monkeypatch.delenv("DTPU_SUITE_LADDER_FRACTION", raising=False)
-        pat, probe = bench.ladder_budget(bench.parse_args([]))
-        assert pat == 312 and probe == 252        # ~20% of 1560, 1 probe
-        pat, probe = bench.ladder_budget(bench.parse_args(["--family",
-                                                           "sdxl"]))
-        assert pat == 1800 and probe >= 1560      # patient single mode
-
-    def test_artifact_replay_prefers_headline_and_skips_zeros(self,
-                                                              tmp_path):
-        bench = self._bench()
-        bdir = tmp_path / "benchmarks"
-        bdir.mkdir()
-        (tmp_path / "bench.py").symlink_to(os.path.join(REPO, "bench.py"))
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "bench_sandbox", str(tmp_path / "bench.py"))
-        bsb = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bsb)
-        args = bsb.parse_args([])
-        assert bsb._artifact_replay(args) is None          # nothing yet
-        (bdir / f"sd15_tpu_{bsb.ROUND}.json").write_text(json.dumps(
-            {"metric": "sd15_512x512_20step_images_per_sec_per_chip",
-             "value": 1.5, "unit": "images/sec/chip",
-             "vs_baseline": 1.0}) + "\n")
-        rec = bsb._artifact_replay(args)
-        assert rec["metric"].startswith("sd15") and "source" in rec
-        (bdir / f"sdxl_tpu_{bsb.ROUND}.json").write_text(json.dumps(
-            {"metric": "sdxl_1024x1024_20step_images_per_sec_per_chip",
-             "value": 0.8, "unit": "images/sec/chip",
-             "vs_baseline": 1.0}) + "\n")
-        rec = bsb._artifact_replay(args)
-        assert rec["metric"].startswith("sdxl")            # headline wins
-        (bdir / f"sdxl_tpu_{bsb.ROUND}.json").write_text(json.dumps(
-            {"metric": "x", "value": 0.0, "unit": "images/sec/chip",
-             "vs_baseline": 0.0, "error": {}}) + "\n")
-        rec = bsb._artifact_replay(args)
-        assert rec["metric"].startswith("sd15")            # zeros skipped
-
-    def test_fail_delivers_best_completed_phase(self, tmp_path):
-        """A later-phase failure must deliver the measured number, not a
-        zero (the r4 zeroed-round failure mode)."""
-        r = subprocess.run(
-            [sys.executable, "-c", (
-                "import sys; sys.argv=['bench.py']\n"
-                "import bench\n"
-                "a = bench.parse_args([])\n"
-                "bench.emit(a, {'metric': 'sd15_x', 'value': 2.0,"
-                " 'unit': 'images/sec/chip', 'vs_baseline': 1.0},"
-                " partial=True)\n"
-                "bench.fail(a, 'runtime', 'phase B OOM')\n")],
-            capture_output=True, text=True, timeout=120, cwd=REPO,
-            env={**os.environ, "PYTHONPATH": REPO})
-        assert r.returncode == 0, r.stderr[-500:]
+    def test_no_tpu_exits_nonzero_even_with_the_cpu_pinned(self):
+        """Device-metric modes need the device: without --platform cpu a
+        run that comes up on the CPU fails with a structured line, whether
+        or not JAX_PLATFORMS=cpu asked for it."""
+        r = self._run(
+            "import sys; sys.argv=['bench.py', '--family', 'tiny']\n"
+            "import bench\n"
+            "bench.main()\n", JAX_PLATFORMS="cpu")
+        assert r.returncode == 1, (r.returncode, r.stderr[-800:])
         last = json.loads(r.stdout.strip().splitlines()[-1])
-        assert last["value"] == 2.0
-        assert last["error_after"]["stage"] == "runtime"
+        assert last["value"] == 0.0
+        assert last["error"]["stage"] == "backend_init"
+        assert "no TPU" in last["error"]["detail"]
 
-    def test_sigterm_delivers_best_completed_phase(self):
-        r = subprocess.run(
-            [sys.executable, "-c", (
-                "import os, signal, sys, time; sys.argv=['bench.py']\n"
-                "import bench\n"
-                "a = bench.parse_args([])\n"
-                "bench._install_sigterm_payload(a)\n"
-                "bench.emit(a, {'metric': 'sd15_x', 'value': 2.1,"
-                " 'unit': 'images/sec/chip', 'vs_baseline': 1.0},"
-                " partial=True)\n"
-                "os.kill(os.getpid(), signal.SIGTERM)\n"
-                "time.sleep(10)\n"
-                "sys.exit(3)\n")],
-            capture_output=True, text=True, timeout=120, cwd=REPO,
-            env={**os.environ, "PYTHONPATH": REPO})
-        assert r.returncode == 0, (r.returncode, r.stderr[-500:])
+    def test_fail_after_a_completed_phase_still_exits_nonzero(self):
+        """A later-phase failure leaves the completed phase's line on
+        stdout, ends with the failure line and exits 1."""
+        r = self._run(
+            "import sys; sys.argv=['bench.py']\n"
+            "import bench\n"
+            "a = bench.parse_args([])\n"
+            "bench.emit(a, {'metric': 'sd15_x', 'value': 2.0,"
+            " 'unit': 'images/sec/chip', 'vs_baseline': 1.0},"
+            " partial=True)\n"
+            "bench.fail(a, 'runtime', 'phase B OOM')\n")
+        assert r.returncode == 1, r.stderr[-500:]
+        lines = [json.loads(ln) for ln in r.stdout.strip().splitlines()]
+        assert lines[0]["value"] == 2.0
+        assert lines[-1]["value"] == 0.0
+        assert lines[-1]["error"]["stage"] == "runtime"
+
+    def test_sigterm_mid_run_exits_nonzero(self):
+        r = self._run(
+            "import os, signal, sys, time; sys.argv=['bench.py']\n"
+            "import bench\n"
+            "a = bench.parse_args([])\n"
+            "bench._install_sigterm_payload(a)\n"
+            "bench.emit(a, {'metric': 'sd15_x', 'value': 2.1,"
+            " 'unit': 'images/sec/chip', 'vs_baseline': 1.0},"
+            " partial=True)\n"
+            "os.kill(os.getpid(), signal.SIGTERM)\n"
+            "time.sleep(10)\n"
+            "sys.exit(3)\n")
+        assert r.returncode == 124, (r.returncode, r.stderr[-500:])
         last = json.loads(r.stdout.strip().splitlines()[-1])
-        assert last["value"] == 2.1 and "terminated" in last
+        assert last["value"] == 0.0
+        assert last["error"]["stage"] == "timeout"
+
+    def test_crashed_cpu_phase_is_not_ok(self, monkeypatch):
+        """_phase_subprocess reports a crashed phase instead of turning
+        it into a silent None (run_suite exits 1 on any not-ok stage)."""
+        bench = self._bench()
+
+        class _R:
+            returncode, stderr = 1, "boom"
+
+        import subprocess as sp
+        monkeypatch.setattr(sp, "run", lambda *a, **k: _R())
+        assert bench._phase_subprocess("tensor_plane") == (None, False)
+
+    def test_unknown_tpu_kind_is_an_error(self):
+        bench = self._bench()
+        assert bench.peak_flops_for("TPU v5 lite") == 197e12
+        with pytest.raises(KeyError, match="PEAK_FLOPS"):
+            bench.peak_flops_for("TPU v9000")
 
 
 class TestTpServePhaseSurface:

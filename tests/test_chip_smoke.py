@@ -1,0 +1,86 @@
+"""chip_smoke.py: the CPU rehearsal passes, and the chip check itself
+refuses to pass anywhere but on a chip.
+
+The real run needs a TPU (``python chip_smoke.py`` through the chip tool);
+what tier-1 can hold is the control flow: the same server child, the same
+requests over HTTP, the same assertions, at tiny size — and the three ways
+the default mode must fail here."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _run(argv, cwd, timeout, **env):
+    base = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "DTPU_DEFAULT_FAMILY")}
+    return subprocess.run([sys.executable, *argv], cwd=str(cwd),
+                          env={**base, **env}, capture_output=True,
+                          text=True, timeout=timeout)
+
+
+def test_rehearsal_passes_on_the_cpu(tmp_path):
+    """Server child -> 3 SDXL-fixture prompts -> tiled upscale -> clean
+    shutdown -> kernel child (interpret mode), all asserted, at tiny
+    size.  The summary line says it is a CPU rehearsal; the last line is
+    the verdict and the device and nothing else."""
+    r = _run([SMOKE, "--rehearse", "--out", str(tmp_path / "out")],
+             tmp_path, 600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    summary, last = map(json.loads, r.stdout.strip().splitlines())
+    assert set(last) == {"ok", "device"} and last["ok"] is True
+    assert set(last["device"]) == {"platform", "kind", "count"}
+    assert last["device"]["platform"] == "cpu"
+    assert isinstance(last["device"]["kind"], str)
+    assert type(last["device"]["count"]) is int
+    assert last["device"]["count"] >= 1
+    assert summary["device"] == last["device"]
+    assert summary["rehearsal"] is True
+    assert summary["phases"] == ["sdxl", "upscale", "kernels"]
+    facts = summary["smoke_facts"]
+    assert len(facts["sdxl_request_wall_s"]) == 3
+    assert facts["memory_source"] == "host_rss"     # no allocator on CPU
+    assert facts["pallas_flash_attention"]["interpret"] is True
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
+    with open(tmp_path / "out" / "summary.json", encoding="utf-8") as f:
+        assert json.load(f) == summary
+    # the server worked under the output directory, not in the checkout
+    assert os.path.isdir(tmp_path / "out" / "server" / "output")
+    assert not os.path.exists(tmp_path / "output")
+
+
+def test_default_mode_refuses_a_cpu_pinned_jax(tmp_path):
+    """JAX_PLATFORMS=cpu (this sandbox) is not a chip: exit non-zero with
+    the reason, before any child starts, and print no result."""
+    r = _run([SMOKE, "--out", str(tmp_path / "out")], tmp_path, 60,
+             JAX_PLATFORMS="cpu")
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "does not fall back" in r.stderr
+    assert not os.path.exists(tmp_path / "out")
+
+
+def test_default_mode_fails_when_jax_finds_no_accelerator(tmp_path):
+    """With nothing pinned, JAX falls back to the CPU on its own when no
+    TPU comes up; the server child refuses that, so the smoke fails with
+    the child's reason and prints no result."""
+    r = _run([SMOKE, "--phases", "sdxl", "--out", str(tmp_path / "out")],
+             tmp_path, 300)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "FAILED" in r.stderr and "no TPU" in r.stderr
+
+
+def test_alone_in_a_directory_it_fails(tmp_path):
+    """The script drives the repo it lives in; without it there is
+    nothing to pass."""
+    shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    r = _run([str(tmp_path / "chip_smoke.py")], tmp_path, 60)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "not next to this script" in r.stderr

@@ -201,8 +201,11 @@ def test_jax_distributed_two_process_collectives(tmp_path):
     cli.py takes on a pod), then run cross-process psum + all_gather over
     the mesh data axis.  CPU devices + gRPC/Gloo stand in for chips + DCN."""
     port = find_free_port()
+    # CPU-pinned by ITS environment, before the child imports jax
     env_base = {**os.environ,
-                "PYTHONPATH": "/root/repo",
+                "PYTHONPATH": os.path.dirname(os.path.dirname(
+                    os.path.abspath(__file__))),
+                "JAX_PLATFORMS": "cpu",
                 "DTPU_COORDINATOR": f"127.0.0.1:{port}",
                 "DTPU_NUM_PROCESSES": "2"}
     procs = []

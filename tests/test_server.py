@@ -309,9 +309,9 @@ class TestPromptSurface:
             r = await client.post("/interrupt")
             assert r.status == 200
             assert state.interrupt_event.is_set()
-            # the server's event IS the process-global flag that compiled
-            # samplers poll per step (runtime/interrupt.py) — so /interrupt
-            # reaches a sample already inside its lax.scan
+            # the server's event IS the process-global flag the executor,
+            # the CB step loop and (DTPU_INTERRUPT_POLL=1) the compiled
+            # samplers read (runtime/interrupt.py)
             assert itr.is_interrupted()
             itr.clear_interrupt()
         run_with_client(body, tmp_path, start_exec_thread=False)

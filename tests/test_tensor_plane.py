@@ -8,6 +8,8 @@ fetch is the PNG edge), and the second run re-traces NOTHING (compilation
 is a one-time cost).  All measurable on the virtual CPU mesh.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,6 +26,8 @@ from comfyui_distributed_tpu.ops.base import (
 from comfyui_distributed_tpu.parallel import mesh as mesh_mod
 from comfyui_distributed_tpu.utils import trace as trace_mod
 from comfyui_distributed_tpu.workflow import WorkflowExecutor, parse_workflow
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TXT2IMG = "/root/repo/workflows/distributed-txt2img.json"
 
@@ -216,43 +220,62 @@ class TestWarmupAndCompileCache:
         assert trace_mod.GLOBAL_RETRACES.since(mark)["traces"] == 0
         assert t2["sample_s"] <= t["sample_s"]
 
-    def test_persistent_cache_configures_and_exports_env(self, tmp_path,
-                                                         monkeypatch):
-        import os
+    # One rule (runtime/manager.enable_persistent_compile_cache), checked
+    # in a fresh interpreter each time: the session's own cache config
+    # (conftest) must not leak into the answer, nor this test into it.
+    _PROBE = (
+        "import jax\n"
+        "from comfyui_distributed_tpu.runtime.manager import "
+        "enable_persistent_compile_cache as en\n"
+        "before = jax.config.jax_compilation_cache_dir\n"
+        "used = en()\n"
+        "import json; print(json.dumps({'before': before, 'used': used, "
+        "'after': jax.config.jax_compilation_cache_dir}))\n")
 
-        from comfyui_distributed_tpu.runtime import manager as mgr
-        prev_dir = mgr._compile_cache_dir
-        prev_cfg = jax.config.jax_compilation_cache_dir
-        monkeypatch.setattr(mgr, "_compile_cache_dir", None)
-        d = str(tmp_path / "xla_cache")
-        try:
-            out = mgr.enable_persistent_compile_cache(d)
-            assert out == d
-            assert jax.config.jax_compilation_cache_dir == d
-            # spawned workers inherit the resolved dir -> shared cache
-            assert os.environ["DTPU_COMPILE_CACHE_DIR"] == d
-            # idempotent
-            assert mgr.enable_persistent_compile_cache(d) == d
-        finally:
-            # put the session-wide cache (conftest) back: this test must
-            # not redirect every later compile into a deleted tmp dir
-            jax.config.update("jax_compilation_cache_dir", prev_cfg)
-            mgr._compile_cache_dir = prev_dir
-            if prev_cfg:
-                os.environ["DTPU_COMPILE_CACHE_DIR"] = prev_cfg
+    def _probe(self, cwd, **env):
+        import json
+        import subprocess
+        import sys
+        base = {k: v for k, v in os.environ.items()
+                if k != "JAX_COMPILATION_CACHE_DIR"}
+        r = subprocess.run(
+            [sys.executable, "-c", self._PROBE], cwd=str(cwd),
+            env={**base, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
+                 **env},
+            capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
 
-    def test_persistent_cache_env_disable(self, monkeypatch):
-        from comfyui_distributed_tpu.runtime import manager as mgr
-        monkeypatch.setattr(mgr, "_compile_cache_dir", None)
-        monkeypatch.setenv("DTPU_COMPILE_CACHE_DIR", "off")
-        assert mgr.enable_persistent_compile_cache() is None
+    def test_cache_dir_from_outside_is_left_to_jax(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR set => the program sets NO cache
+        directory in code: the config holds what JAX read from the env
+        before and after the call."""
+        d = str(tmp_path / "placed_from_outside")
+        got = self._probe(tmp_path, JAX_COMPILATION_CACHE_DIR=d)
+        assert got == {"before": d, "used": d, "after": d}
+
+    def test_default_cache_dir_is_the_checkout_whatever_cwd_and_home(
+            self, tmp_path):
+        """Unset => <checkout>/.jax_cache, resolved from the package's
+        own location: neither the CWD nor HOME moves it."""
+        want = os.path.join(REPO, ".jax_cache")
+        for cwd, home in ((tmp_path, tmp_path / "h1"),
+                          (REPO, tmp_path / "h2")):
+            got = self._probe(cwd, HOME=str(home))
+            assert got["before"] is None
+            assert got["used"] == want and got["after"] == want
+            assert not os.path.exists(home)      # nothing under ~
+
+    def test_no_other_cache_knob(self, tmp_path):
+        """The repo's own cache knob is gone: DTPU_COMPILE_CACHE_DIR
+        places nothing."""
+        got = self._probe(tmp_path,
+                          DTPU_COMPILE_CACHE_DIR=str(tmp_path / "x"))
+        assert got["used"] == os.path.join(REPO, ".jax_cache")
 
 
-class TestShardMapShim:
-    def test_shim_accepts_check_vma_on_installed_jax(self):
-        """The seed's `from jax import shard_map` broke 6 test modules on
-        JAX without the top-level export; the shim must serve both the
-        old check_rep and new check_vma spellings."""
+class TestShardMap:
+    def test_all_gather_over_the_data_axis(self):
         from comfyui_distributed_tpu.parallel import collectives as coll
         mesh = mesh_mod.build_mesh()
         x = np.arange(8 * 2, dtype=np.float32).reshape(8, 2)
