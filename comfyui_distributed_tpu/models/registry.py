@@ -37,6 +37,7 @@ from comfyui_distributed_tpu.models.upscalers import (
     TINY_RRDB_CONFIG,
     RRDBNet,
 )
+from comfyui_distributed_tpu.utils import trace as trace_mod
 from comfyui_distributed_tpu.utils.logging import log
 
 
@@ -321,9 +322,12 @@ class DiffusionPipeline:
                                           min_elements=min_el)
                 return shd.apply_shardings(tree, sh)
 
-            self.unet_params = lay_out(self.unet_params)
-            self.clip_params = [lay_out(p) for p in self.clip_params]
-            self.vae_params = lay_out(self.vae_params)
+            with trace_mod.stage("load_weights"):
+                self.unet_params = lay_out(self.unet_params)
+                self.clip_params = [lay_out(p) for p in self.clip_params]
+                self.vae_params = lay_out(self.vae_params)
+                jax.block_until_ready((self.unet_params, self.clip_params,
+                                       self.vae_params))
             self._tp_mesh = mesh
             # Cached cores were TRACED while no mesh was live, so every
             # activation constraint (shd.constrain*) resolved to a no-op
@@ -934,9 +938,15 @@ class DiffusionPipeline:
             # caller (or the workflow graph) still references by donating
             # a fresh on-device copy instead
             lat_arg = jnp.copy(lat_arg)
-        return core(self.unet_params, lat_arg, ctx_list, area_list,
-                    keys, sigmas, y_arg, mask_arg,
-                    cn_params_arg, hint_arg, concat_arg, objs_arg)
+        # met by name, then enqueued by a plain call: a helper or a lambda
+        # around ``core(...)`` costs seconds whenever the call traces
+        # (see wait_previous_denoise)
+        wait_previous_denoise()
+        out = core(self.unet_params, lat_arg, ctx_list, area_list,
+                   keys, sigmas, y_arg, mask_arg,
+                   cn_params_arg, hint_arg, concat_arg, objs_arg)
+        note_denoise(out)
+        return out
 
     # --- warmup -------------------------------------------------------------
 
@@ -1042,6 +1052,41 @@ class DiffusionPipeline:
             return fn
 
 
+# The previous denoise's output, of any pipeline (the device is one
+# queue).  The TPU runtime lets the host run only so far ahead: some
+# enqueue call of the NEXT request returns only when the previous
+# request's denoise has finished.  Which call depends on how many small
+# programs the request enqueues first (seen on the v5e: 0.56 s of every
+# 0.61 s SD1.5 request inside PjitFunction(core); 3.4 s of every 3.6 s
+# SDXL request inside the jnp.repeat of _sdxl_vector_cond; 1.77 s of a
+# 2.6 s four-chip cycle at the first shard placement).  At those three
+# places the host meets the device by name (trace.device_wait), so the
+# calls themselves are the enqueue alone and ``dispatch`` is the host's
+# own seconds.  It blocks where the runtime blocked anyway.
+#
+# Both are plain calls beside the jitted call, never a wrapper around it:
+# two Python frames (a helper and its lambda) between ``sample`` and
+# ``core(...)`` made the SD1.5 denoise program's first call 5 s slower on
+# the v5e host (trace 23.7 -> 26.3 s, lowering 9.4 -> 12.9 s over a
+# set-up; PERF.md, PR 24), which is what refused PR 23.
+_last_denoise: Any = None
+
+
+def wait_previous_denoise() -> None:
+    prev = _last_denoise
+    # (a buffer donated onward reads as deleted: nothing to wait on)
+    if prev is not None and not prev.is_deleted():
+        with trace_mod.device_wait():
+            jax.block_until_ready(prev)
+
+
+def note_denoise(out) -> None:
+    """``out`` is the next request's denoise to wait on (a tracer, under
+    someone's jit or make_jaxpr, is nothing to wait on)."""
+    global _last_denoise
+    _last_denoise = None if isinstance(out, jax.core.Tracer) else out
+
+
 def _virtual_params(module, seed: int, *shaped_args,
                     storage_dtype: Any = None) -> Any:
     """Deterministic random init WITHOUT compiling the model's init graph.
@@ -1107,7 +1152,20 @@ def load_pipeline(ckpt_name: str, models_dir: Optional[str] = None,
     with _pipeline_lock:
         if key in _pipeline_cache:
             return _pipeline_cache[key]
+    # making or reading the weights and placing them on the device, to
+    # the moment the device holds them
+    with trace_mod.stage("load_weights"):
+        pipe = _make_pipeline(ckpt_name, models_dir, family_name)
+        jax.block_until_ready((pipe.unet_params, pipe.clip_params,
+                               pipe.vae_params))
+    pipe.cache_token = key
+    with _pipeline_lock:
+        _pipeline_cache[key] = pipe
+    return pipe
 
+
+def _make_pipeline(ckpt_name: str, models_dir: Optional[str],
+                   family_name: Optional[str]) -> DiffusionPipeline:
     fam = FAMILIES[family_name or detect_family(ckpt_name)]
     path = None
     if models_dir:
@@ -1162,13 +1220,9 @@ def load_pipeline(ckpt_name: str, models_dir: Optional[str] = None,
         log(f"{ckpt_name}: UNet/CLIP weights stored bf16 "
             f"(DTPU_BF16_WEIGHTS=0 for fp32)")
 
-    pipe = DiffusionPipeline(ckpt_name, fam, unet_p, clip_ps, vae_p,
+    return DiffusionPipeline(ckpt_name, fam, unet_p, clip_ps, vae_p,
                              prediction_type=fam.unet.prediction_type,
                              assets_dir=models_dir)
-    pipe.cache_token = key
-    with _pipeline_lock:
-        _pipeline_cache[key] = pipe
-    return pipe
 
 
 def _bf16_weights_enabled(fam: ModelFamily) -> bool:

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from comfyui_distributed_tpu.utils import trace as trace_mod
 from comfyui_distributed_tpu.utils.trace import record_transfer
 
 # sentinel for widget slots that are UI chrome (control_after_generate)
@@ -324,6 +325,23 @@ def fanout_meta(x) -> Dict[str, Any]:
         meta["local_batch"] = int(lb)
     meta["fanout"] = int(getattr(x, "fanout", 1) or 1)
     return meta
+
+
+def fetch_image_array(x) -> np.ndarray:
+    """:func:`as_image_array` at a deferred host edge (PNG, HTTP wire),
+    as the ``d2h`` stage it always was, now told apart inside: first
+    ``device_wait`` until the device has produced ``x`` (dispatch is
+    asynchronous, so this is where the host meets the still-running
+    program; its end is the request's ``device_ready`` instant), then
+    ``d2h_copy`` for the copy alone."""
+    with trace_mod.stage("d2h"):
+        dev = x.data if isinstance(x, DeviceTensor) else x
+        if isinstance(dev, jax.Array):
+            with trace_mod.device_wait():
+                jax.block_until_ready(dev)
+        trace_mod.mark_instant("device_ready")
+        with trace_mod.stage("d2h_copy"):
+            return as_image_array(x)
 
 
 def as_image_array(x) -> np.ndarray:

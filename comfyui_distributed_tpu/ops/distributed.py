@@ -111,8 +111,10 @@ class DistributedCollector(Op):
         if multi_job_id and ctx.job_store is not None:
             # true host edge: remote results arrive over HTTP and
             # concatenate with ours on host
-            gathered = self._collect_http(ctx, as_image_array(images),
-                                          multi_job_id, enabled_worker_ids)
+            with trace_mod.stage("gather"):
+                gathered = self._collect_http(
+                    ctx, as_image_array(images), multi_job_id,
+                    enabled_worker_ids)
             return (gathered,)
 
         # SPMD mode: batch already replica-major (master first) by
@@ -125,13 +127,14 @@ class DistributedCollector(Op):
         # batch that already lives on host (an image-space numpy op
         # upstream) stays host — uploading it just to re-fetch would ADD
         # a full-batch round trip.
-        with Timer("collector_gather"):
+        with Timer("collector_gather"), trace_mod.stage("gather"):
             if isinstance(images, (DeviceTensor, jax.Array)):
                 gathered = as_device_image(images)
                 if ctx.host_pool is None:
                     # serial path: flush XLA's async dispatch here so the
                     # timer measures the real wait for the sharded batch
-                    gathered = jax.block_until_ready(gathered)
+                    with trace_mod.device_wait():
+                        gathered = jax.block_until_ready(gathered)
                 # overlapped pipeline: do NOT synchronize at this op
                 # boundary — the deferred host edge (PNG/HTTP in the
                 # host-IO pool) absorbs the wait while the next job's

@@ -42,6 +42,7 @@ from comfyui_distributed_tpu.ops.base import (
     OpContext,
     as_device_array,
     as_image_array,
+    fetch_image_array,
     register_op,
 )
 from comfyui_distributed_tpu.parallel import collectives as coll
@@ -576,8 +577,7 @@ class UltimateSDUpscaleDistributed(Op):
             def prep_body(k):
                 tile_idx = indices[k]
                 # d2h ONE tile (counted; refined may be a device batch)
-                with trace_mod.stage("d2h"):
-                    row = as_image_array(refined[k:k + 1])[0]
+                row = fetch_image_array(refined[k:k + 1])[0]
                 # the wire carries the clamped extraction region at
                 # natural size — the exact form the master's blend
                 # consumes; sending the raw window would make the master

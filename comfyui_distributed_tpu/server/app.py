@@ -239,6 +239,9 @@ class ServerState:
         # encode/disk.  FIFO -> history lands in execution order.
         self._finalize_q: "queue.Queue" = queue.Queue()
         self._finalize_pending = 0
+        # the previous finalized request's device_ready instant (the
+        # finaliser is FIFO, so "previous" is in execution order)
+        self._last_device_ready = 0.0
         # multi-master shard plane (ISSUE 14): resolve the shard config
         # BEFORE the durability plane attaches — each shard keeps its
         # own WAL/epoch stream under DTPU_SHARD_WAL_ROOT/<id>, its
@@ -460,6 +463,7 @@ class ServerState:
                                     "span": sp,
                                     "t_enq": time.perf_counter()})
                 self._inflight.add(pid)
+                trace_mod.mark_instant("enqueued", sp)
         if reject is not None:
             self._abandon_span(sp, pid, reject[1])
             raise reject[0]
@@ -501,7 +505,6 @@ class ServerState:
         done_t = time.time()
         self.metrics["prompts_replayed"] += 1
         trace_mod.GLOBAL_COUNTERS.bump("cache_result_replays")
-        trace_mod.GLOBAL_STAGES.record("cache_replay", 0.0)
         self._history[pid] = {
             "status": "success",
             "images": len(entry.get("images", ())),
@@ -575,16 +578,16 @@ class ServerState:
         now_wall = time.time()
         for item in group:
             wait = now - item.get("t_enq", now)
-            trace_mod.GLOBAL_STAGES.record("queue_wait", wait)
-            if item.get("span") is not None:
-                trace_mod.event_span("queue_wait", now_wall - wait,
-                                     now_wall, parent=item["span"])
+            trace_mod.record_stage("queue_wait", now_wall - wait, now_wall,
+                                   parent=item.get("span"))
+            trace_mod.mark_instant("popped", item.get("span"), now_wall)
         return group
 
     def _exec_loop(self) -> None:
         while True:
-            self._queue_event.wait()
-            self._exec_gate.wait()
+            with trace_mod.stage("exec_idle"):
+                self._queue_event.wait()
+                self._exec_gate.wait()
             self._purge_abandoned()
             group = self._pop_group()
             if group is None:
@@ -622,7 +625,11 @@ class ServerState:
             # (coalesced followers' traces stay thin — job +
             # queue_wait — and name their leader); per-node and
             # stage spans created inside attach to this trace
+            # ``dispatch``: pop to the return of the last enqueue, the
+            # host's own seconds (what the thread spends in
+            # trace.device_wait inside is taken out of the aggregate)
             with trace_mod.use_span(first.get("span")), \
+                    trace_mod.stage("dispatch", own=True), \
                     trace_mod.span("execute",
                                    coalesced=len(group)):
                 if len(group) > 1:
@@ -649,6 +656,8 @@ class ServerState:
                         extra_pnginfo=first.get("extra_data", {}).get(
                             "extra_pnginfo"))
             trace_mod.GLOBAL_STAGES.record("compute", res.total_s)
+            for item in group:
+                trace_mod.mark_instant("dispatched", item.get("span"))
         except Exception as e:  # noqa: BLE001 - survive bad prompts
             err = e
         finally:
@@ -679,21 +688,30 @@ class ServerState:
             group, res, err, t0 = self._finalize_q.get()
             self._finalize_group(group, res, err, t0)
 
-    def _finalize_group(self, group, res, err, t0) -> None:
-        """Join deferred host edges, split per-prompt results, write
-        history/metrics, drop orphan tile queues, seal the group's job
-        traces into the flight recorder (+ the slow-job log line)."""
-        if res is not None and err is None:
-            try:
-                # the join runs under the head job's span so the
-                # host-edge wait is visible in the trace tree
-                with trace_mod.use_span(group[0].get("span")), \
-                        trace_mod.span("finalize"):
-                    res.wait_host()
-            except Exception as e:  # noqa: BLE001 - host edge failed
-                err = e
+    def _record_queue_to_device(self, group, ready_fallback: float) -> None:
+        """``queue_to_device``: enqueue to the instant the request's own
+        denoise could start — the later of its dispatch and the previous
+        request's ``device_ready`` on this executor.  The queue delay
+        measured where it happens: the executor pops a request as it
+        arrives, so the wait lies behind the device, not in the queue."""
+        head = group[0].get("span")
+        if head is None:
+            return
+        ready = head.attrs.get("instants", {}).get("device_ready",
+                                                   ready_fallback)
+        prev, self._last_device_ready = self._last_device_ready, ready
+        for item in group:
+            sp = item.get("span")
+            inst = sp.attrs.get("instants", {}) if sp is not None else {}
+            if "enqueued" in inst and "dispatched" in inst:
+                start = max(inst["dispatched"], prev)
+                trace_mod.record_stage("queue_to_device", inst["enqueued"],
+                                       start, parent=sp)
+
+    def _write_history(self, group, res, err, done_t: float) -> None:
+        """The history entries of a finalized group (and what rides with
+        them: counters, the exact-hit result tier)."""
         k = len(group)
-        done_t = time.time()
         abandoned = isinstance(err, reuse_mod.AbandonedError)
         if err is None:
             per_prompt = sched_mod.split_images(res.images, k)
@@ -741,6 +759,29 @@ class ServerState:
                 if k > 1:
                     entry["coalesced"] = k
                 self._history[item["id"]] = entry
+
+    def _finalize_group(self, group, res, err, t0) -> None:
+        """Join deferred host edges, split per-prompt results, write
+        history/metrics, drop orphan tile queues, seal the group's job
+        traces into the flight recorder (+ the slow-job log line)."""
+        if res is not None and err is None:
+            try:
+                # the join runs under the head job's span so the
+                # host-edge wait is visible in the trace tree
+                with trace_mod.use_span(group[0].get("span")), \
+                        trace_mod.span("finalize"):
+                    res.wait_host()
+            except Exception as e:  # noqa: BLE001 - host edge failed
+                err = e
+        k = len(group)
+        done_t = time.time()
+        abandoned = isinstance(err, reuse_mod.AbandonedError)
+        self._record_queue_to_device(group, done_t)
+        with trace_mod.use_span(group[0].get("span")), \
+                trace_mod.stage("history_write"):
+            self._write_history(group, res, err, done_t)
+        for item in group:
+            trace_mod.mark_instant("in_history", item.get("span"))
         # seal each prompt's trace: end the job span, commit to the
         # flight recorder under the prompt id, and emit the always-on
         # slow-job line when the end-to-end span exceeds DTPU_SLOW_JOB_S
@@ -1101,7 +1142,8 @@ def build_app(state: Optional[ServerState] = None) -> web.Application:
     async def metrics(request):
         from comfyui_distributed_tpu.utils.trace import (
             GLOBAL_NODES, GLOBAL_PHASES, GLOBAL_TRACES,
-            counters_snapshot, pipeline_snapshot, tracing_enabled)
+            counters_snapshot, pipeline_snapshot, profile_summary,
+            tracing_enabled)
         # wal stats list segment files and may contend with an
         # append's fsync/rotation under the WAL lock — off the loop
         dur_stats = {"enabled": False}
@@ -1223,7 +1265,11 @@ def build_app(state: Optional[ServerState] = None) -> web.Application:
                                   # + jit trace/XLA compile counts: the
                                   # tensor-plane health signals (steady
                                   # serving => retraces stop growing)
-                                  **counters_snapshot()})
+                                  **counters_snapshot(),
+                                  # the program's own reduction of its
+                                  # last device trace (profile/stop);
+                                  # metrics/reset leaves it
+                                  "profile": profile_summary()})
 
     _build_info_cache: List[Any] = []
 
@@ -1663,14 +1709,18 @@ def build_app(state: Optional[ServerState] = None) -> web.Application:
 
     async def profile_stop(request):
         # off the loop for the same reason: stop flushes the collected
-        # device trace to disk before returning
+        # device trace to disk, then a child process reduces it to the
+        # summary (<dir>/summary.json, and "profile" on /metrics)
         from comfyui_distributed_tpu.utils import trace as trace_mod
         try:
             out = await asyncio.get_running_loop().run_in_executor(
                 None, trace_mod.stop_device_trace)
         except RuntimeError as e:
             return web.json_response({"error": str(e)}, status=409)
-        return ok({"dir": out})
+        summary = trace_mod.profile_summary()
+        if summary is not None and summary.get("dir") != out:
+            summary = None      # this trace left nothing to reduce
+        return ok({"dir": out, "summary": summary})
 
     async def profile_status(request):
         from comfyui_distributed_tpu.utils import trace as trace_mod

@@ -18,15 +18,20 @@ Here profiling is a first-class subsystem:
   distributed HTTP edges, and a bounded per-job flight recorder
   (:class:`FlightRecorder`) behind ``GET /distributed/trace/<prompt_id>``;
 - XLA/device traces via ``jax.profiler`` (viewable in TensorBoard /
-  Perfetto), driven by ``POST /distributed/profile/start`` + ``/stop`` or
-  the :func:`trace` context manager;
+  Perfetto), driven by ``POST /distributed/profile/start`` + ``/stop``;
+  while one runs, every stage/span is also a ``dtpu/<name>`` annotation
+  on the profiler's clock, and ``stop`` ends by reducing the trace to a
+  summary (``trace_summary.py``): device seconds per jitted program by
+  kernel class (:data:`KERNEL_CLASSES`, read from the module paths the
+  operations carry), idle seconds by the host span over them;
 - host<->device transfer accounting (:class:`TransferStats`): every device
   edge in the ops layer reports bytes through :func:`record_transfer`,
   attributed to the executing workflow node (:func:`node_scope`) — the
   software-measurable proxy for "tensors never leave HBM";
 - retrace/compile counters (:class:`RetraceStats`) fed by
-  ``jax.monitoring`` events: a steady-state serving process must report
-  ZERO new traces on a repeated workflow (``install_jax_monitoring``).
+  ``jax.monitoring`` events, telling a compile from a cache load, with
+  their seconds: a steady-state serving process must report ZERO new
+  traces on a repeated workflow (``install_jax_monitoring``).
 
 Telemetry never touches traced code paths: spans and histograms are pure
 host-side Python around (never inside) the jitted programs, so tracing-on
@@ -38,6 +43,7 @@ from __future__ import annotations
 
 import contextvars
 import os
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -203,6 +209,110 @@ def phase(name: str):
         GLOBAL_PHASES.record(name, time.perf_counter() - t0)
 
 
+# --- names on the profiler's clock -------------------------------------------
+#
+# Two kinds of name reach a device trace.  The operations carry theirs
+# already: the models are Flax modules, Flax wraps every module call in
+# ``jax.named_scope(<module name>)``, and a TPU trace keeps the resulting
+# path of every operation (the HLO ``op_name``, e.g.
+# ``jit(core)/while/body/closed_call/UNet/down_2_attn_0/blocks_0/attn1/
+# to_q/dot_general``) in the statistic ``tf_op`` of the operation's event
+# metadata.  KERNEL_CLASSES reads those paths; nothing in ``models/`` is
+# written for it, so renaming a module there is what changes a class
+# (tests/test_trace_names.py breaks first).  On the host, stage()/span()/
+# event_span() open a ``dtpu/<name>`` TraceAnnotation while a device
+# trace runs, so host spans and device operations share a clock.
+
+HOST_PREFIX = "dtpu/"
+OTHER = "other"
+
+# class -> (model the path must lie in or None for any, pattern over one
+# ``/``-separated segment of the path).  Ordered: the INNERMOST segment
+# that matches any row of its model decides, and within a segment the
+# first row.  So ``.../attn1/to_q/dot_general`` is attn_proj, the einsum
+# written in ``attn1`` itself (``.../attn1/bnhd,bmhd->bhnm/dot_general``)
+# is attn_self, and a GroupNorm inside a ResBlock is norm.
+_UNET, _VAE, _CLIP = "UNet", "VAE", "CLIPTextModel"
+_BLOCK = r"(?:down_\d+|up_\d+|mid)"
+KERNEL_CLASSES = (
+    ("norm", None, r"GroupNorm_\d+|LayerNorm_\d+|(?:in_|out_)?norm\d*"
+                   r"|ln\d+|ln_final"),
+    ("attn_proj", _UNET, r"to_q|to_k|to_v|to_out|proj_in|proj_out"),
+    ("attn_self", _UNET, r"attn1"),
+    ("attn_cross", _UNET, r"attn2"),
+    ("ff", _UNET, r"ff|geglu"),
+    ("resblock", _UNET, _BLOCK + r"_res_\d+"),
+    ("resample", _UNET, r"down_\d+_ds|up_\d+_us|conv_in|conv_out"),
+    ("embed", _UNET, r"time_fc\d+|label_fc\d+"),
+    # a transformer block's and the SpatialTransformer's own operations
+    # (residual adds, token reshapes): with the projections
+    ("attn_proj", _UNET, r"blocks_\d+|" + _BLOCK + r"_attn(?:_\d+)?"),
+    # UNet.__call__'s own: skip concatenations, the final activation,
+    # the timestep sinusoid
+    ("resample", _UNET, r"UNet"),
+    ("vae_attn", _VAE, r"mid_attn"),
+    ("vae_res", _VAE, _BLOCK + r"_res_\d+"),
+    # the VAE's convolutions, resamplers and its own glue
+    ("vae_conv", _VAE, r"conv_in|conv_out|(?:post_)?quant_conv"
+                       r"|down_\d+_ds|up_\d+_us|encoder|decoder|VAE"),
+    ("clip_mlp", _CLIP, r"fc\d+"),
+    ("embed", _CLIP, r"token_embedding|position_embedding|text_projection"
+                     r"|CLIPTextModel"),
+    ("clip_attn", _CLIP, r"layers_\d+"),
+)
+# the denoise programs' own operations under no module (CFG combine,
+# solver update, noise): ``core`` / ``step`` are the functions
+# models/registry.py jits
+SAMPLER = "sampler"
+_SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
+_MODEL_OF = re.compile(r"^(UNet|VAE|CLIPTextModel)(?:\.\w+)?$")
+_ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
+              for cls, model, pat in KERNEL_CLASSES)
+
+
+def classify(op_name: str) -> str:
+    """The class of KERNEL_CLASSES an operation's path (HLO ``op_name``)
+    falls in; ``sampler`` for an operation of a denoise program under no
+    model; ``other`` for the rest (no path, or a program that is none of
+    ours).  Pure string work: needs no JAX."""
+    segments = op_name.split("/")
+    model = None
+    for i, seg in enumerate(segments):
+        m = _MODEL_OF.match(seg)
+        if m:
+            model, segments = m.group(1), segments[i:]
+            segments[0] = model
+            break
+    if model is None:
+        return SAMPLER if _SAMPLER_PROGRAM.search(op_name) else OTHER
+    for seg in reversed(segments[:-1] if len(segments) > 1 else segments):
+        for cls, of, pat in _ROWS:
+            if (of is None or of == model) and pat.match(seg):
+                return cls
+    return OTHER
+
+
+def _annotate(name: str, **args: Any):
+    """An entered ``TraceAnnotation("dtpu/<name>")`` carrying the
+    request's ids while a device trace runs; None (one module-level
+    read) otherwise.  The caller exits it on the thread that opened it."""
+    if _trace_dir is None:
+        return None
+    import jax
+    ids = current_trace_ids()
+    if ids:
+        args = {"trace_id": ids["trace_id"],
+                "prompt_id": ids.get("prompt_id", ""), **args}
+    ann = jax.profiler.TraceAnnotation(HOST_PREFIX + name, **args)
+    ann.__enter__()
+    return ann
+
+
+def _end_annotation(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 # --- pipeline stage timeline -------------------------------------------------
 
 # Per-job stage wall-clock for the overlapped serving pipeline
@@ -220,17 +330,37 @@ GLOBAL_STAGES = PhaseStats()
 GLOBAL_NODES = PhaseStats()
 
 
+_wait_state = threading.local()
+
+
+def _waited_s() -> float:
+    """Seconds this thread has spent inside :func:`device_wait`."""
+    return getattr(_wait_state, "s", 0.0)
+
+
 @contextmanager
-def stage(name: str):
+def stage(name: str, own: bool = False):
     """Time one pipeline stage into :data:`GLOBAL_STAGES`.
 
     When a request trace is active (``current_span()``), the stage is ALSO
     recorded as a child span of the same name, so the flight recorder's
     per-job tree shows exactly where the wall-clock went — the aggregate
     histogram and the per-job trace are fed by one instrumentation
-    point."""
+    point.  While a device trace runs it is also a ``dtpu/<name>``
+    annotation on the profiler's clock.
+
+    ``own=True`` records the host's own seconds of a stage that holds
+    other spans: what this thread spent in :func:`device_wait` inside the
+    block is taken out of the aggregate and recorded apart as
+    ``<name>_wait``.  Its span keeps the whole interval, carries the
+    difference as ``device_wait_s``, and is added beside the spans opened
+    inside the block (they stay children of the current span) when the
+    block ends."""
     t0 = time.perf_counter()
-    sp = _begin_span(name)
+    wall0 = time.time()
+    w0 = _waited_s()
+    sp = None if own else _begin_span(name)
+    ann = _annotate(name)
     try:
         yield
     except BaseException:
@@ -238,8 +368,56 @@ def stage(name: str):
             sp.set_status("error")
         raise
     finally:
-        GLOBAL_STAGES.record(name, time.perf_counter() - t0)
+        _end_annotation(ann)
+        dur = time.perf_counter() - t0
+        if own:
+            # ``device_wait`` sums every thread's waits; this is the
+            # share that lay inside this stage
+            waited = _waited_s() - w0
+            dur -= waited
+            GLOBAL_STAGES.record(name + "_wait", waited)
+            _add_event_span(name, wall0, time.time(),
+                            parent=_SPAN_VAR.get(),
+                            attrs={"device_wait_s": round(waited, 6)})
+        GLOBAL_STAGES.record(name, dur)
         _end_span(sp)
+
+
+@contextmanager
+def device_wait():
+    """Around every place a host thread blocks for the device: the
+    ``device_wait`` stage, and the seconds an enclosing ``stage(...,
+    own=True)`` on this thread leaves out."""
+    t0 = time.perf_counter()
+    try:
+        with stage("device_wait"):
+            yield
+    finally:
+        _wait_state.s = _waited_s() + time.perf_counter() - t0
+
+
+def mark_instant(name: str, sp: Optional["Span"] = None,
+                 at: Optional[float] = None) -> None:
+    """Stamp a lifecycle instant (wall clock) on the request's root span
+    — ``sp`` or the root of the current span; the first stamp of a name
+    stands.  No-op outside a request."""
+    node = sp if sp is not None else _SPAN_VAR.get()
+    if node is None:
+        return
+    while node.parent is not None:
+        node = node.parent
+    node.attrs.setdefault("instants", {}).setdefault(
+        name, round(time.time() if at is None else at, 6))
+
+
+def record_stage(name: str, start_s: float, end_s: float,
+                 parent: Optional["Span"] = None) -> None:
+    """An interval measured by its caller (wall-clock ``time.time()``
+    bounds): into :data:`GLOBAL_STAGES` and, under ``parent``, as a child
+    span of the request — what :func:`stage` does for a ``with`` block."""
+    GLOBAL_STAGES.record(name, end_s - start_s)
+    if parent is not None:
+        event_span(name, start_s, end_s, parent=parent)
 
 
 class CounterStats:
@@ -315,11 +493,16 @@ def pipeline_snapshot() -> Dict[str, Any]:
 
 _trace_lock = threading.Lock()
 _trace_dir: Optional[str] = None
+_trace_t0 = 0.0
+# the program's own reduction of its last device trace (trace_summary.py);
+# kept until the next one, on /distributed/metrics as "profile"
+_profile: Optional[Dict[str, Any]] = None
+SUMMARY_TIMEOUT_S = 900.0
 
 
 def start_device_trace(out_dir: Optional[str] = None) -> str:
     """Begin a ``jax.profiler`` trace (TensorBoard/Perfetto format)."""
-    global _trace_dir
+    global _trace_dir, _trace_t0
     import jax
     with _trace_lock:
         if _trace_dir is not None:
@@ -328,18 +511,22 @@ def start_device_trace(out_dir: Optional[str] = None) -> str:
             os.getcwd(), "traces", time.strftime("%Y%m%d-%H%M%S"))
         os.makedirs(out_dir, exist_ok=True)
         jax.profiler.start_trace(out_dir)
-        _trace_dir = out_dir
+        _trace_dir, _trace_t0 = out_dir, time.time()
         log(f"device trace started -> {out_dir}")
         return out_dir
 
 
 def stop_device_trace() -> str:
-    global _trace_dir
+    """Stop the trace, then reduce the ``.xplane.pb`` it wrote
+    (:func:`_summarize`): the summary is kept for :func:`profile_summary`
+    and written beside the trace as ``summary.json``."""
+    global _trace_dir, _profile
     import jax
     with _trace_lock:
         if _trace_dir is None:
             raise RuntimeError("no trace running")
         out = _trace_dir
+        t_stop = time.time()
         try:
             jax.profiler.stop_trace()
         finally:
@@ -347,22 +534,61 @@ def stop_device_trace() -> str:
             # _trace_dir set would wedge every later start_device_trace
             # with "trace already running" for the life of the process
             _trace_dir = None
-        log(f"device trace stopped -> {out}")
-        return out
+        written_s = time.time() - t_stop
+        log(f"device trace stopped -> {out} (written in {written_s:.1f}s)")
+    summary = _summarize(out, t_stop - _trace_t0)
+    if summary is not None:
+        summary["stop_trace_s"] = round(written_s, 3)
+        with _trace_lock:
+            _profile = summary
+    return out
+
+
+def _summarize(trace_dir: str, traced_s: float) -> Optional[Dict[str, Any]]:
+    """Reduce the trace under ``trace_dir`` in a child process
+    (``JAX_PLATFORMS=cpu``: reading the protobuf imports JAX, and this
+    process's GIL and chip are busy serving).  None where the profiler
+    left no ``.xplane.pb`` or the child failed (logged, never raised:
+    the trace itself is on disk either way)."""
+    import json
+    import subprocess
+    import sys
+    found = [os.path.join(base, f) for base, _, files in os.walk(trace_dir)
+             for f in files if f.endswith(".xplane.pb")]
+    if not found:
+        return None
+    path = max(found, key=os.path.getmtime)
+    out_path = os.path.join(trace_dir, "summary.json")
+    t0 = time.time()
+    try:
+        subprocess.run(
+            [sys.executable, "-m",
+             "comfyui_distributed_tpu.utils.trace_summary", path, out_path,
+             str(traced_s)],
+            env={**os.environ, "JAX_PLATFORMS": "cpu"}, check=True,
+            capture_output=True, text=True, timeout=SUMMARY_TIMEOUT_S)
+        with open(out_path, encoding="utf-8") as f:
+            summary = json.load(f)
+    except (subprocess.SubprocessError, OSError, ValueError) as e:
+        detail = getattr(e, "stderr", "") or ""
+        log(f"trace summary failed for {path}: {e} {detail[-500:]}")
+        return None
+    summary["dir"] = trace_dir
+    summary["summary_s"] = round(time.time() - t0, 3)
+    log(f"trace summary -> {out_path} in {summary['summary_s']:.1f}s "
+        f"(names found: {summary.get('names_found')})")
+    return summary
+
+
+def profile_summary() -> Optional[Dict[str, Any]]:
+    """The summary of the last device trace this process stopped."""
+    with _trace_lock:
+        return _profile
 
 
 def trace_status() -> Dict[str, Any]:
     with _trace_lock:
         return {"running": _trace_dir is not None, "dir": _trace_dir}
-
-
-@contextmanager
-def device_trace(out_dir: Optional[str] = None):
-    d = start_device_trace(out_dir)
-    try:
-        yield d
-    finally:
-        stop_device_trace()
 
 
 # --- host<->device transfer accounting ---------------------------------------
@@ -485,36 +711,101 @@ def record_transfer(direction: str, nbytes: int) -> None:
 # --- retrace / compile counters ----------------------------------------------
 
 class RetraceStats:
-    """Monotonic counters over ``jax.monitoring`` events (thread-safe).
+    """Monotonic counters over ``jax.monitoring`` events.
 
     ``traces`` counts jaxpr traces (every cache-missed jit call),
-    ``compiles`` counts backend (XLA) compilations — with the persistent
-    compilation cache warm, a retrace can hit the disk cache and skip the
-    backend compile, so the two differ."""
+    ``compiles`` counts the backend-compile events: JAX emits one whether
+    XLA compiled the program or the persistent cache held it, so
+    ``cache_loads`` counts the ones that were loaded and
+    ``compiles_uncached`` (in :meth:`mark`) the ones XLA really compiled.
+    Seconds: ``trace_s`` (a jit traced inside another's trace counts
+    once), ``lower_s`` (jaxpr to MLIR), ``cache_load_s`` (reading and
+    deserialising cached executables) and ``compile_s`` (the
+    backend-compile events less ``cache_load_s``: XLA's own time, plus
+    hashing the cache key).
+
+    The listener runs for every eager primitive and every inner jit of a
+    set-up (~26,000 times for SDXL), so a thread adds to a cell of its
+    own: no lock on that path, and :meth:`mark` sums the cells."""
+
+    _FIELDS = ("traces", "compiles", "cache_loads", "trace_s", "lower_s",
+               "compile_s", "cache_load_s")
+    _COUNTS = 3                 # the first _COUNTS fields are whole numbers
+    _MAX_OPEN = 4096            # top-level trace intervals kept per thread
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self.traces = 0    # guarded-by: self._lock
-        self.compiles = 0  # guarded-by: self._lock
+        self._cells: List[List[float]] = []   # guarded-by: self._lock
+        self._local = threading.local()
 
-    def bump(self, what: str) -> None:
-        with self._lock:
-            setattr(self, what, getattr(self, what) + 1)
+    def _mine(self):
+        local = self._local
+        if not hasattr(local, "cell"):
+            local.cell = [0.0] * len(self._FIELDS)
+            # trace intervals counted so far that no later one has
+            # enclosed yet: disjoint, ordered by start
+            local.starts, local.durs = [], []
+            with self._lock:
+                self._cells.append(local.cell)
+        return local
 
-    def mark(self) -> Dict[str, int]:
-        with self._lock:
-            return {"traces": self.traces, "compiles": self.compiles}
+    def add(self, count: int, seconds: int, duration: float) -> None:
+        """One duration event: ``count`` / ``seconds`` index _FIELDS
+        (``count`` < 0: none)."""
+        local = self._mine()
+        if count >= 0:
+            local.cell[count] += 1
+        if seconds == _TRACE_S:
+            duration = self._own_trace_seconds(local, duration)
+        local.cell[seconds] += duration
 
-    def since(self, mark: Dict[str, int]) -> Dict[str, int]:
+    def _own_trace_seconds(self, local, duration: float) -> float:
+        """``duration`` less what was already counted inside it.  JAX
+        reports a jit traced inside another's trace once on its own (the
+        inner event ends first) and again within the outer one; summed
+        as they come, the seconds would count that time twice."""
+        # (the listener runs a few microseconds after the event's end, so
+        # an inner interval may seem to start that much before its outer)
+        start = time.perf_counter() - duration
+        starts, durs = local.starts, local.durs
+        inside = 0.0
+        while starts and starts[-1] >= start - 2e-5:
+            starts.pop()
+            inside += durs.pop()
+        starts.append(start)
+        durs.append(duration)
+        if len(starts) > self._MAX_OPEN:
+            del starts[:self._MAX_OPEN // 2], durs[:self._MAX_OPEN // 2]
+        return max(duration - inside, 0.0)
+
+    def mark(self) -> Dict[str, float]:
         with self._lock:
-            return {"traces": self.traces - mark["traces"],
-                    "compiles": self.compiles - mark["compiles"]}
+            cells = list(self._cells)
+        out: Dict[str, float] = {}
+        for i, k in enumerate(self._FIELDS):
+            total = sum(c[i] for c in cells)
+            out[k] = int(total) if i < self._COUNTS else total
+        out["compiles_uncached"] = out["compiles"] - out["cache_loads"]
+        return out
+
+    def since(self, mark: Dict[str, float]) -> Dict[str, float]:
+        now = self.mark()
+        return {k: now[k] - mark.get(k, 0) for k in now}
 
 
 GLOBAL_RETRACES = RetraceStats()
 
-_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_S = RetraceStats._FIELDS.index("trace_s")
+_COMPILE_S = RetraceStats._FIELDS.index("compile_s")
+_CACHE_LOAD_S = RetraceStats._FIELDS.index("cache_load_s")
+# jax.monitoring duration events -> indices of (count, seconds) in _FIELDS
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": (0, _TRACE_S),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        (-1, RetraceStats._FIELDS.index("lower_s")),
+    "/jax/core/compile/backend_compile_duration": (1, _COMPILE_S),
+    "/jax/compilation_cache/cache_retrieval_time_sec": (2, _CACHE_LOAD_S),
+}
 # persistent compile cache: pipeline counter per event.  JAX records
 # "cache_misses" where it WRITES an entry (a compile that was too short,
 # or whose program holds a host callback, is neither a hit nor a write)
@@ -538,10 +829,13 @@ def install_jax_monitoring() -> None:
         import jax.monitoring as monitoring
 
         def on_duration(name: str, duration: float, **kw) -> None:
-            if name == _TRACE_EVENT:
-                GLOBAL_RETRACES.bump("traces")
-            elif name == _COMPILE_EVENT:
-                GLOBAL_RETRACES.bump("compiles")
+            fields = _DURATION_EVENTS.get(name)
+            if fields is None:
+                return
+            GLOBAL_RETRACES.add(fields[0], fields[1], duration)
+            if fields[1] == _CACHE_LOAD_S:
+                # the load happened inside a backend-compile event
+                GLOBAL_RETRACES.add(-1, _COMPILE_S, -duration)
 
         def on_event(name: str, **kw) -> None:
             counter = _CACHE_EVENTS.get(name)
@@ -723,6 +1017,7 @@ def span(name: str, **attrs: Any):
     """Child span of the current span, current within the block; yields
     None (and records nothing) when no trace is active."""
     sp = _begin_span(name, **attrs)
+    ann = _annotate(name)
     try:
         yield sp
     except BaseException as e:
@@ -730,6 +1025,7 @@ def span(name: str, **attrs: Any):
             sp.set_status("error", repr(e))
         raise
     finally:
+        _end_annotation(ann)
         _end_span(sp)
 
 
@@ -764,7 +1060,22 @@ def event_span(name: str, start_s: float, end_s: float,
                status: str = "ok") -> Optional[Dict[str, Any]]:
     """Record an already-finished interval as a span (queue_wait measured
     at pop time, an inbound upload measured by the handler).  Accepts a
-    parent Span or raw (trace_id, parent_id) for remote parents."""
+    parent Span or raw (trace_id, parent_id) for remote parents.  The
+    interval is over, so a running device trace gets an instant that
+    carries its bounds."""
+    if _trace_dir is not None:
+        _end_annotation(_annotate(name, start_s=round(start_s, 6),
+                                  end_s=round(end_s, 6)))
+    return _add_event_span(name, start_s, end_s, parent, trace_id,
+                           parent_id, attrs, status)
+
+
+def _add_event_span(name: str, start_s: float, end_s: float,
+                    parent: Optional[Span] = None,
+                    trace_id: Optional[str] = None,
+                    parent_id: Optional[str] = None,
+                    attrs: Optional[Dict[str, Any]] = None,
+                    status: str = "ok") -> Optional[Dict[str, Any]]:
     if not _tracing_enabled:
         return None
     if parent is not None:

@@ -1,0 +1,443 @@
+"""The program's own reduction of a device trace: where the device time
+went, by name, without TensorBoard.
+
+``trace.stop_device_trace()`` runs this module as a child process
+(``python -m comfyui_distributed_tpu.utils.trace_summary <xplane.pb>
+<summary.json> <traced seconds>``, ``JAX_PLATFORMS=cpu``) on the
+``.xplane.pb`` the profiler just wrote.  Two stages, so the second can be
+tested on made-up and recorded events without JAX:
+
+  read_events(path)   .xplane.pb -> plain lists (needs ``jax.profiler``)
+  summarize(events)   events -> the summary
+
+What a TPU trace holds: one plane per chip (``/device:TPU:<n>``) whose
+line ``XLA Modules`` has one event per execution of a jitted program and
+whose line ``XLA Ops`` one per HLO operation, nested where an operation
+(a ``while``) contains others; ``/host:CPU`` has one line per host
+thread, on which ``trace.stage()`` / ``span()`` leave ``dtpu/<name>``
+annotations.  The summary holds, per chip and as a mean over chips:
+
+* ``programs``: for each jitted program (module name without its id) the
+  whole executions seen, their mean seconds, and per class of
+  ``trace.KERNEL_CLASSES`` the leaf-operation seconds per execution (the
+  class of the path the operation's metadata carries, ``other`` for an
+  operation under none, ``top_other`` naming the costliest of those),
+  ``gaps`` for the time inside the execution in which no operation ran.
+  The rows add up to the execution's seconds;
+* ``idle``: the seconds *between* program executions in which no
+  operation ran, by the innermost ``dtpu/`` host span over each gap
+  (``none`` under none), and ``idle_under``: the same seconds under each
+  span name at any depth; ``gaps_in_programs_s``: the idle seconds
+  inside executions, which no host span can answer for;
+* ``names_found``: whether any operation carried a path at all.
+"""
+
+from __future__ import annotations
+
+import json
+import re
+import sys
+from typing import Any, Dict, List, Optional
+
+import numpy as np
+
+from comfyui_distributed_tpu.utils.trace import HOST_PREFIX, OTHER, classify
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+HOST_PLANE = "/host:CPU"
+MODULES_LINE = "XLA Modules"
+OPS_LINE = "XLA Ops"
+EDGE_NS = 10_000        # an execution this close to the slice's edge is cut
+_MODULE_ID = re.compile(r"\(\d+\)$")
+GAPS, NONE = "gaps", "none"
+OP_NAME = "tf_op"       # the event-metadata statistic that holds op_name
+TOP_OTHER = 8           # the costliest unclassed operations a program lists
+
+
+# --- the event metadata, straight from the protobuf ---------------------------
+#
+# ``jax.profiler.ProfileData`` hands out an event's own statistics (its
+# device offset and duration) but not those of its metadata entry, and
+# that is where a TPU trace keeps what an operation *is*: the name stack
+# it was traced under, its category, its source line.  The few fields
+# needed are read from the wire format directly (tsl/profiler/protobuf/
+# xplane.proto: XSpace.planes=1; XPlane.name=2, .lines=3,
+# .event_metadata=4, .stat_metadata=5; XEventMetadata.id=1, .name=2,
+# .stats=5; XStat.metadata_id=1, .str_value=5, .bytes_value=6,
+# .ref_value=7; XStatMetadata.id=1, .name=2).  The lines, which are
+# nearly all of the file, are skipped whole.
+
+def _varint(buf, i):
+    shift = value = 0
+    while True:
+        b = buf[i]
+        i += 1
+        value |= (b & 0x7F) << shift
+        if b < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf, i, end):
+    """(field number, wire type, value) of one message; a length-delimited
+    value is its (start, end) in ``buf``."""
+    while i < end:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            value, i = (i, i + n), i + n
+        elif wire == 1:
+            value, i = None, i + 8
+        elif wire == 5:
+            value, i = None, i + 4
+        else:
+            raise ValueError(f"wire type {wire} at byte {i}")
+        yield key >> 3, wire, value
+
+
+def _text(buf, span) -> str:
+    return bytes(buf[span[0]:span[1]]).decode("utf-8", "replace")
+
+
+def read_op_metadata(path: str) -> Dict[str, Dict[str, Dict[str, str]]]:
+    """Per device plane: event-metadata name (the HLO instruction as the
+    trace prints it) -> its string statistics by name."""
+    import mmap
+    out: Dict[str, Dict[str, Dict[str, str]]] = {}
+    with open(path, "rb") as f, \
+            mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as buf:
+        for no, wire, plane in _fields(buf, 0, len(buf)):
+            if no != 1 or wire != 2:
+                continue
+            name, entries, stat_names = "", [], {}
+            for pno, pwire, val in _fields(buf, *plane):
+                if pno == 2:
+                    name = _text(buf, val)
+                    if not DEVICE_PLANE.match(name):
+                        break
+                elif pno == 4:
+                    entries.append(val)
+                elif pno == 5:
+                    sid, sname = 0, ""
+                    for eno, _, ev in _fields(buf, *val):
+                        if eno == 2:
+                            for mno, _, mv in _fields(buf, *ev):
+                                if mno == 1:
+                                    sid = mv
+                                elif mno == 2:
+                                    sname = _text(buf, mv)
+                    stat_names[sid] = sname
+            if not DEVICE_PLANE.match(name):
+                continue
+            table: Dict[str, Dict[str, str]] = {}
+            for entry in entries:
+                for eno, _, ev in _fields(buf, *entry):
+                    if eno != 2:
+                        continue
+                    ename, stats = "", {}
+                    for mno, mwire, mv in _fields(buf, *ev):
+                        if mno == 2:
+                            ename = _text(buf, mv)
+                        elif mno == 5:
+                            key, text = 0, None
+                            for sno, swire, sv in _fields(buf, *mv):
+                                if sno == 1:
+                                    key = sv
+                                elif sno in (5, 6):
+                                    text = _text(buf, sv)
+                                elif sno == 7:
+                                    text = stat_names.get(sv, "")
+                            if text is not None:
+                                stats[stat_names.get(key, str(key))] = text
+                    table[ename] = stats
+            out[name] = table
+    return out
+
+
+def read_events(path: str) -> Dict[str, Any]:
+    """The device planes' module and operation events (an operation with
+    the path its metadata names) and the host's ``dtpu/`` annotations."""
+    from jax.profiler import ProfileData
+    metadata = read_op_metadata(path)
+    data = ProfileData.from_file(path)
+    planes = []
+    stat_names: set = set()
+    for plane in data.planes:
+        is_device = bool(DEVICE_PLANE.match(plane.name))
+        if not is_device and plane.name != HOST_PLANE:
+            continue
+        table = metadata.get(plane.name, {})
+        lines = []
+        for line in plane.lines:
+            if is_device and line.name not in (MODULES_LINE, OPS_LINE):
+                continue
+            ops = is_device and line.name == OPS_LINE
+            names: Dict[str, int] = {}
+            idx, start, dur = [], [], []
+            for ev in line.events:
+                name = ev.name
+                if not is_device and not name.startswith(HOST_PREFIX):
+                    continue
+                i = names.get(name)
+                if i is None:
+                    i = names[name] = len(names)
+                idx.append(i)
+                start.append(int(ev.start_ns))
+                dur.append(int(ev.duration_ns))
+            row = {"name": line.name, "names": list(names),
+                   "name_idx": idx, "start_ns": start, "dur_ns": dur}
+            if ops:
+                # libtpu keeps the HLO op_name in the statistic "tf_op"
+                # ("jit(core)/while/body/closed_call/UNet/mid_attn/
+                # blocks_0/attn1/to_q/dot_general:"); a fusion carries
+                # its root's
+                row["paths"] = [table.get(n, {}).get(OP_NAME, "")
+                                for n in row["names"]]
+                for n in row["names"][:50]:
+                    stat_names.update(table.get(n, {}))
+            lines.append(row)
+        planes.append({"name": plane.name, "lines": lines})
+    return {"planes": planes, "op_stat_names": sorted(stat_names)}
+
+
+def _arrays(line: dict):
+    return (np.asarray(line["start_ns"], np.int64),
+            np.asarray(line["dur_ns"], np.int64),
+            np.asarray(line["name_idx"], np.int64))
+
+
+def _leaves(start, dur):
+    """Mask of the events that contain no other event of their line."""
+    order = np.lexsort((-dur, start))
+    s, e = start[order], start[order] + dur[order]
+    holds_next = np.zeros(len(s), bool)
+    holds_next[:-1] = s[1:] < e[:-1]
+    holds_next &= dur[order] > 0
+    mask = np.ones(len(s), bool)
+    mask[order] = ~holds_next
+    return mask
+
+
+def _union(start, end):
+    if len(start) == 0:
+        return start, end
+    order = np.argsort(start, kind="stable")
+    s, e = start[order], end[order]
+    reach = np.maximum.accumulate(e)
+    new = np.ones(len(s), bool)
+    new[1:] = s[1:] > reach[:-1]
+    firsts = np.flatnonzero(new)
+    lasts = np.append(firsts[1:] - 1, len(s) - 1)
+    return s[firsts], reach[lasts]
+
+
+def _host_spans(events: dict):
+    name, start, end = [], [], []
+    for plane in events["planes"]:
+        if plane["name"] != HOST_PLANE:
+            continue
+        for line in plane["lines"]:
+            for i, s, d in zip(line["name_idx"], line["start_ns"],
+                               line["dur_ns"]):
+                label = line["names"][i]
+                if label.startswith(HOST_PREFIX) and d > 0:
+                    name.append(label[len(HOST_PREFIX):])
+                    start.append(s)
+                    end.append(s + d)
+    return name, np.asarray(start, np.int64), np.asarray(end, np.int64)
+
+
+def _cut_at(gap_s, gap_e, edges):
+    """The gaps cut wherever one of ``edges`` falls inside them, sorted:
+    each piece then lies wholly inside or outside whatever the edges
+    bound."""
+    cuts = np.unique(edges)
+    lo = np.searchsorted(cuts, gap_s, "right")
+    hi = np.searchsorted(cuts, gap_e, "left")
+    ps, pe = [gap_s[hi <= lo]], [gap_e[hi <= lo]]
+    for g in np.flatnonzero(hi > lo):
+        pieces = np.concatenate(([gap_s[g]], cuts[lo[g]:hi[g]],
+                                 [gap_e[g]]))
+        ps.append(pieces[:-1])
+        pe.append(pieces[1:])
+    ps, pe = np.concatenate(ps), np.concatenate(pe)
+    order = np.argsort(ps, kind="stable")
+    return ps[order], pe[order]
+
+
+def _idle_by_span(gap_s, gap_e, spans):
+    """Seconds of the gaps by the innermost (shortest) host span over
+    each, and under each span name at any depth (the gaps cut at the
+    spans' edges first)."""
+    names, hs, he = spans
+    total_ns = int((gap_e - gap_s).sum())
+    if not len(hs) or not len(gap_s):
+        return ({NONE: total_ns / 1e9} if total_ns else {}), {}
+    ps, pe = _cut_at(gap_s, gap_e, np.concatenate((hs, he)))
+    mid, dur = (ps + pe) // 2, pe - ps
+    distinct = sorted(set(names))
+    code = {n: i for i, n in enumerate(distinct)}
+    inner = np.full(len(ps), -1, np.int64)
+    under = np.zeros((len(distinct), len(ps)), bool)
+    first = np.searchsorted(mid, hs, "left")
+    last = np.searchsorted(mid, he, "left")
+    # longest first, so that a shorter span over the same piece wins
+    for k in np.argsort(-(he - hs), kind="stable"):
+        if last[k] > first[k]:
+            inner[first[k]:last[k]] = code[names[k]]
+            under[code[names[k]], first[k]:last[k]] = True
+    by_inner = {NONE: float(dur[inner < 0].sum()) / 1e9}
+    for n, i in code.items():
+        sec = float(dur[inner == i].sum()) / 1e9
+        if sec:
+            by_inner[n] = sec
+    by_under = {n: float(dur[under[i]].sum()) / 1e9
+                for n, i in code.items() if under[i].any()}
+    return by_inner, by_under
+
+
+def _chip(plane: dict, spans) -> Dict[str, Any]:
+    lines = {ln["name"]: ln for ln in plane["lines"]}
+    chip: Dict[str, Any] = {"busy_s": 0.0, "window_s": 0.0, "ops": 0,
+                            "gaps_in_programs_s": 0.0, "programs": {},
+                            "idle": {}, "idle_under": {},
+                            "names_found": False}
+    ops_ln, mod_ln = lines.get(OPS_LINE), lines.get(MODULES_LINE)
+    if not ops_ln or not ops_ln["start_ns"]:
+        return chip
+    s, d, idx = _arrays(ops_ln)
+    paths = ops_ln.get("paths") or [""] * len(ops_ln["names"])
+    classes = np.asarray([classify(p) for p in paths])
+    leaf = _leaves(s, d)
+    ls, ld, lidx = s[leaf], d[leaf], idx[leaf]
+    lclass = classes[lidx]
+    chip["ops"] = int(leaf.sum())
+    chip["names_found"] = bool(np.asarray([bool(p) for p in paths])[lidx]
+                               .any())
+    t0, t1 = int(s.min()), int((s + d).max())
+    if mod_ln and mod_ln["start_ns"]:
+        ms, md, midx = _arrays(mod_ln)
+        t0, t1 = min(t0, int(ms.min())), max(t1, int((ms + md).max()))
+    bs, be = _union(ls, ls + ld)
+    chip["busy_s"] = float((be - bs).sum()) / 1e9
+    chip["window_s"] = (t1 - t0) / 1e9
+    gap_s = np.concatenate(([t0], be))
+    gap_e = np.concatenate((bs, [t1]))
+    keep = gap_e > gap_s
+    gap_s, gap_e = gap_s[keep], gap_e[keep]
+    if not mod_ln or not mod_ln["start_ns"]:
+        chip["idle"], chip["idle_under"] = _idle_by_span(gap_s, gap_e,
+                                                         spans)
+        return chip
+    order = np.argsort(ms, kind="stable")
+    ms, md, midx = ms[order], md[order], midx[order]
+    # a gap inside an execution (between two of its operations) is the
+    # program's own and is counted with it; the host can only answer for
+    # the gaps between executions
+    gap_s, gap_e = _cut_at(gap_s, gap_e, np.concatenate((ms, ms + md)))
+    mid = (gap_s + gap_e) // 2
+    k = np.maximum(np.searchsorted(ms, mid, "right") - 1, 0)
+    within = (mid >= ms[k]) & (mid < (ms + md)[k])
+    chip["gaps_in_programs_s"] = float(
+        (gap_e - gap_s)[within].sum()) / 1e9
+    chip["idle"], chip["idle_under"] = _idle_by_span(
+        gap_s[~within], gap_e[~within], spans)
+    whole = (ms > t0 + EDGE_NS) & (ms + md < t1 - EDGE_NS)
+    # the execution each leaf operation started in
+    k = np.searchsorted(ms, ls, "right") - 1
+    inside = (k >= 0) & (ls < (ms + md)[np.maximum(k, 0)])
+    programs: Dict[str, Dict[str, Any]] = {}
+    for m in np.flatnonzero(whole):
+        name = _MODULE_ID.sub("", mod_ln["names"][midx[m]])
+        row = programs.setdefault(name, {"count": 0, "total_s": 0.0,
+                                         "classes": {}})
+        row["count"] += 1
+        row["total_s"] += md[m] / 1e9
+    names_of = np.asarray([_MODULE_ID.sub("", n)
+                           for n in mod_ln["names"]])
+    sel = inside & whole[np.maximum(k, 0)]
+    prog_of_op = names_of[midx[np.maximum(k, 0)]]
+    for name, row in programs.items():
+        mine = sel & (prog_of_op == name)
+        per_class = {}
+        for cl in np.unique(lclass[mine]):
+            per_class[str(cl)] = float(
+                ld[mine & (lclass == cl)].sum()) / 1e9 / row["count"]
+        row["mean_s"] = row["total_s"] / row["count"]
+        per_class[GAPS] = row["mean_s"] - sum(per_class.values())
+        row["classes"] = per_class
+        # what ``other`` holds, by name: the path where there is one,
+        # else the HLO instruction as the trace prints it
+        unclassed = mine & (lclass == OTHER)
+        sec = np.bincount(lidx[unclassed], weights=ld[unclassed],
+                          minlength=len(paths)) / 1e9 / row["count"]
+        row["top_other"] = [
+            {"op": paths[i] or ops_ln["names"][i][:120],
+             "s": float(sec[i])}
+            for i in np.argsort(-sec)[:TOP_OTHER] if sec[i] > 0]
+    chip["programs"] = programs
+    return chip
+
+
+def _mean(rows: List[Dict[str, float]]) -> Dict[str, float]:
+    keys = sorted({k for r in rows for k in r})
+    return {k: sum(r.get(k, 0.0) for r in rows) / len(rows) for k in keys}
+
+
+def summarize(events: dict, traced_s: float = 0.0) -> Dict[str, Any]:
+    spans = _host_spans(events)
+    chips = []
+    for plane in events["planes"]:
+        m = DEVICE_PLANE.match(plane["name"])
+        if m:
+            chips.append({"chip": int(m.group(1)), **_chip(plane, spans)})
+    chips.sort(key=lambda c: c["chip"])
+    out: Dict[str, Any] = {
+        "traced_s": float(traced_s), "chips": chips,
+        "names_found": any(c["names_found"] for c in chips),
+        "host_spans": sorted(set(spans[0])),
+        "op_stat_names": events.get("op_stat_names", [])}
+    if not chips:
+        return out
+    # a program counts where every chip saw it whole
+    shared = set.intersection(*(set(c["programs"]) for c in chips))
+    programs = {}
+    for name in sorted(shared):
+        rows = [c["programs"][name] for c in chips]
+        programs[name] = {
+            "count": sum(r["count"] for r in rows) / len(rows),
+            "mean_s": sum(r["mean_s"] for r in rows) / len(rows),
+            "classes": _mean([r["classes"] for r in rows]),
+            "top_other": rows[0]["top_other"]}
+    # a slice that starts or ends in an idle gap holds no device event
+    # there: the time the profiler was on still counts as idle
+    window_s = max(max(c["window_s"] for c in chips), float(traced_s))
+    idle = _mean([c["idle"] for c in chips])
+    edge = window_s - sum(c["window_s"] for c in chips) / len(chips)
+    if edge > 0:
+        idle["slice_edge"] = edge
+    out.update({
+        "window_s": window_s,
+        "busy_s": sum(c["busy_s"] for c in chips) / len(chips),
+        "gaps_in_programs_s": sum(c["gaps_in_programs_s"]
+                                  for c in chips) / len(chips),
+        "programs": programs, "idle": idle,
+        "idle_under": _mean([c["idle_under"] for c in chips])})
+    return out
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    argv = sys.argv[1:] if argv is None else argv
+    path, out_path = argv[0], argv[1]
+    traced_s = float(argv[2]) if len(argv) > 2 else 0.0
+    summary = summarize(read_events(path), traced_s)
+    with open(out_path, "w", encoding="utf-8") as f:
+        json.dump(summary, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

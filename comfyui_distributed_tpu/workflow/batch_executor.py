@@ -933,10 +933,8 @@ class ContinuousBatchExecutor:
         now_wall = time.time()
         for item in items:
             wait = now - item.get("t_enq", now)
-            trace_mod.GLOBAL_STAGES.record("queue_wait", wait)
-            if item.get("span") is not None:
-                trace_mod.event_span("queue_wait", now_wall - wait,
-                                     now_wall, parent=item["span"])
+            trace_mod.record_stage("queue_wait", now_wall - wait, now_wall,
+                                   parent=item.get("span"))
 
     def _admit_boundary(self) -> bool:
         """Pop-and-admit at a step boundary until the queue, capacity or
@@ -1304,7 +1302,9 @@ class ContinuousBatchExecutor:
         first_timers = [s for s in bkt.slots if s.step == 0]
         t0 = time.perf_counter()
         try:
-            bkt.step_once()
+            # the CB executor's share of ``dispatch``: one step's enqueue
+            with trace_mod.stage("dispatch", own=True):
+                bkt.step_once()
         except Exception as e:  # noqa: BLE001 - poison bucket, not loop
             log(f"cb: step failed in bucket {bkt.sig[:8]}: "
                 f"{type(e).__name__}: {e}")

@@ -123,7 +123,8 @@ class TestWorkflowTensorPlane:
         g = _scaled_txt2img()
         WorkflowExecutor(ctx).execute(g)
         res2 = WorkflowExecutor(OpContext(runtime=ctx.runtime)).execute(g)
-        assert res2.retraces == {"traces": 0, "compiles": 0}
+        assert res2.retraces["traces"] == 0
+        assert res2.retraces["compiles"] == 0
 
     def test_results_unchanged_by_tensor_plane(self, ctx):
         """Determinism across runs survives the device-resident rewrite

@@ -1,0 +1,551 @@
+"""Kernel names the programs already carry, and one clock (ISSUE 24): the
+classes of ``trace.KERNEL_CLASSES`` over the op_name paths of the lowered
+programs (this is what breaks when someone renames a module), host spans
+on the profiler's clock, the counters that tell a compile from a load,
+the program's own trace summary, and the executor's self times."""
+
+import collections
+import json
+import os
+import re
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.models import registry
+from comfyui_distributed_tpu.server.app import ServerState
+from comfyui_distributed_tpu.utils import trace
+from comfyui_distributed_tpu.utils import trace_summary as ts
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DATA = os.path.join(REPO, "tests", "data")
+OP_NAME = re.compile(r'op_name="([^"]+)"')
+
+DENOISE_CLASSES = {"attn_self", "attn_cross", "attn_proj", "ff", "norm",
+                   "resblock", "resample", "embed", "sampler"}
+VAE_CLASSES = {"vae_res", "vae_conv", "norm"}      # the tiny VAE has no
+CLIP_CLASSES = {"clip_attn", "clip_mlp", "norm", "embed"}   # mid_attn
+
+
+@pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
+def pipe(request):
+    return registry.load_pipeline(f"names-{request.param}.safetensors",
+                                  family_name=request.param)
+
+
+def latent(pipe):
+    return jnp.zeros((1, 8, 8, pipe.family.latent_channels), jnp.float32)
+
+
+def compiled_op_names(fn):
+    return OP_NAME.findall(jax.jit(fn).lower().compile().as_text())
+
+
+# --- 1. kernel classes from the names the programs carry -------------------
+
+def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
+    classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
+    assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
+        | {"vae_attn"}
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; from comfyui_distributed_tpu.utils.trace import "
+         "classify; print(classify('jit(core)/mul'), 'jax' in sys.modules)"],
+        capture_output=True, text=True, timeout=60,
+        env={**os.environ, "PYTHONPATH": REPO})
+    assert r.stdout.split() == ["sampler", "False"], r.stderr[-1000:]
+
+
+UNET = "jit(core)/while/body/closed_call/UNet/"
+
+
+@pytest.mark.parametrize("path, want", [
+    # the innermost module segment decides
+    (UNET + "down_2_attn_0/blocks_0/attn1/to_q/dot_general", "attn_proj"),
+    (UNET + "down_2_attn_0/blocks_9/attn1/bnhd,bmhd->bhnm/dot_general",
+     "attn_self"),
+    (UNET + "mid_attn/blocks_0/attn2/bhnm,bmhd->bnhd/dot_general:",
+     "attn_cross"),
+    (UNET + "up_1_attn_2/blocks_0/attn2/to_out/add", "attn_proj"),
+    (UNET + "up_1_attn_2/blocks_0/ff/geglu/proj/dot_general", "ff"),
+    (UNET + "up_1_attn_2/blocks_0/ff/out/dot_general", "ff"),
+    (UNET + "up_1_attn_2/blocks_0/norm3/mul", "norm"),
+    (UNET + "up_1_attn_2/norm/GroupNorm_0/reduce_sum", "norm"),
+    (UNET + "up_1_attn_2/proj_in/dot_general", "attn_proj"),
+    (UNET + "up_1_attn_2/blocks_0/add", "attn_proj"),
+    (UNET + "down_0_res_1/in_conv/conv_general_dilated", "resblock"),
+    (UNET + "down_0_res_1/emb_proj/dot_general", "resblock"),
+    (UNET + "down_0_res_1/out_norm/GroupNorm_0/rsqrt", "norm"),
+    (UNET + "mid_res_0/jit(silu)/logistic", "resblock"),
+    (UNET + "down_1_ds/conv/conv_general_dilated", "resample"),
+    (UNET + "up_2_us/jit(_resize)/dot_general", "resample"),
+    (UNET + "conv_in/conv_general_dilated", "resample"),
+    (UNET + "concatenate", "resample"),
+    (UNET + "time_fc1/dot_general", "embed"),
+    (UNET + "label_fc2/add", "embed"),
+    # a fusion CPU XLA names without the program prefix
+    ("UNet/down_1_res_0/in_norm/GroupNorm_0/add", "norm"),
+    # the denoise program's own operations
+    ("jit(core)/while/body/closed_call/mul", "sampler"),
+    ("jit(core)/vmap(jit(_normal))/jit(_normal_real)/erf_inv", "sampler"),
+    ("jit(step)/sub", "sampler"),
+    ("jit(<lambda>)/jit(<lambda>)/VAE.decode/decoder/mid_attn/q/dot_general",
+     "vae_attn"),
+    ("jit(<lambda>)/jit(<lambda>)/VAE.decode/decoder/mid_attn/norm/"
+     "GroupNorm_0/sub", "norm"),
+    ("jit(<lambda>)/jit(<lambda>)/VAE.decode/decoder/up_3_res_2/conv1/"
+     "conv_general_dilated", "vae_res"),
+    ("jit(<lambda>)/jit(<lambda>)/VAE.decode/decoder/up_2_us/"
+     "conv_general_dilated", "vae_conv"),
+    ("jit(<lambda>)/jit(<lambda>)/VAE.decode/post_quant_conv/add",
+     "vae_conv"),
+    ("jit(<lambda>)/VAE.encode/encoder/conv_in/add", "vae_conv"),
+    ("jit(<unknown>)/CLIPTextModel/layers_3/q/dot_general", "clip_attn"),
+    ("jit(<unknown>)/CLIPTextModel/layers_3/bhnm,bmhd->bnhd/dot_general",
+     "clip_attn"),
+    ("jit(<unknown>)/CLIPTextModel/layers_3/fc2/dot_general", "clip_mlp"),
+    ("jit(<unknown>)/CLIPTextModel/layers_3/ln1/mul", "norm"),
+    ("jit(<unknown>)/CLIPTextModel/token_embedding/gather", "embed"),
+    # nothing of ours
+    ("jit(<lambda>)/jit(<lambda>)/mul", "other"),
+    ("reduce_sum", "other"), ("", "other"),
+])
+def test_classify(path, want):
+    assert trace.classify(path) == want
+
+
+def test_every_op_of_the_compiled_denoise_falls_in_one_class(pipe):
+    lat = latent(pipe)
+    ctx, _ = pipe.encode_prompt(["a cat"])
+    names = compiled_op_names(lambda: pipe.sample(
+        lat, ctx, ctx, np.zeros((1,), np.uint64), steps=2, cfg=7.0,
+        sampler_name="euler", scheduler="karras"))
+    assert len(names) > 3000
+    by_class = collections.defaultdict(list)
+    for n in names:
+        by_class[trace.classify(n)].append(n)
+    assert set(by_class) - {"other"} == DENOISE_CLASSES
+    # the listed remainder: a reduction's combiner, which XLA names by
+    # its primitive alone, and what this test's own jit wraps around
+    # ``core`` (the sampling keys)
+    for n in by_class["other"]:
+        assert "/" not in n or ("jit(core)" not in n and "UNet" not in n), n
+    # attention proper holds both einsums and the softmax, and nothing
+    # of the projections
+    attn = {n.rsplit("/", 1)[-1] for n in by_class["attn_self"]}
+    assert {"dot_general", "exp", "reduce_max"} <= attn
+    assert not any(re.search(r"/to_(q|k|v|out)/", n)
+                   for n in by_class["attn_self"] + by_class["attn_cross"])
+
+
+@pytest.mark.parametrize("program, want", [("vae", VAE_CLASSES),
+                                           ("clip", CLIP_CLASSES)])
+def test_every_op_of_the_compiled_vae_and_text_encoder(pipe, program, want):
+    lat = latent(pipe)
+    fn = {"vae": lambda: pipe.vae_decode(lat),
+          "clip": lambda: pipe.encode_prompt(["a cat"])[0]}[program]
+    by_class = collections.defaultdict(list)
+    for n in compiled_op_names(fn):
+        by_class[trace.classify(n)].append(n)
+    assert set(by_class) - {"other"} == want
+    # the remainder: combiners, and the programs' own operations around
+    # the module (latent scaling, clipping, pooling)
+    for n in by_class["other"]:
+        assert "/" not in n or not re.search(r"VAE|CLIPTextModel", n), n
+
+
+def test_no_module_of_the_models_is_named_like_another_models_class():
+    """The table reads module names: the names it relies on are the ones
+    the model files declare."""
+    src = {f: open(os.path.join(REPO, "comfyui_distributed_tpu", "models",
+                                f"{f}.py"), encoding="utf-8").read()
+           for f in ("layers", "unet", "vae", "clip")}
+    for name in ("attn1", "attn2", "to_q", "to_k", "to_v", "to_out",
+                 "geglu", "ff", "proj_in", "proj_out", "in_conv",
+                 "out_conv", "emb_proj"):
+        assert f'name="{name}"' in src["layers"], name
+    for name in ("time_fc1", "label_fc1", "conv_in", "conv_out", "mid_attn"):
+        assert f'name="{name}"' in src["unet"], name
+    for name in ("mid_attn", "post_quant_conv", "decoder", "conv_out"):
+        assert f'name="{name}"' in src["vae"], name
+    for name in ("fc1", "ln1", "ln_final", "token_embedding"):
+        assert f'name="{name}"' in src["clip"], name
+
+
+def test_the_denoise_program_is_enqueued_by_a_plain_call():
+    """PR 23 was refused for this: a helper and a lambda between
+    ``sample`` and ``core(...)`` made the program's first call seconds
+    slower on the chip's host (PERF.md, PR 24).  The wait and the note
+    stand beside the call."""
+    import inspect
+    src = inspect.getsource(registry.DiffusionPipeline.sample)
+    call = src.index("out = core(self.unet_params")
+    assert src.index("wait_previous_denoise()") < call \
+        < src.index("note_denoise(out)")
+    assert "lambda: core" not in src
+    registry.note_denoise(jnp.ones((2,)))
+    registry.wait_previous_denoise()        # a ready array: returns
+    assert registry._last_denoise is not None
+    registry.note_denoise(None)
+
+
+# --- 2. host spans on the profiler's clock ---------------------------------
+
+def host_annotations(trace_dir):
+    from jax.profiler import ProfileData
+    found = [os.path.join(b, f) for b, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(found) == 1
+    out = {}
+    for plane in ProfileData.from_file(found[0]).planes:
+        if plane.name != ts.HOST_PLANE:
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(trace.HOST_PREFIX):
+                    out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+def test_stage_is_an_annotation_only_while_a_device_trace_runs(
+        tmp_path, monkeypatch):
+    opened = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **kw):
+        opened.append(name)
+        return real(name, **kw)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    with trace.stage("before_the_trace"):
+        pass
+    assert opened == []
+    trace.start_device_trace(str(tmp_path / "t"))
+    try:
+        root = trace.start_span("job", attrs={"prompt_id": "p-7"})
+        with trace.use_span(root):
+            with trace.stage("encode"):
+                time.sleep(0.002)
+            with trace.span("KSampler", node="3"):
+                with trace.device_wait():
+                    time.sleep(0.002)
+            trace.event_span("queue_wait", time.time() - 1.0, time.time(),
+                             parent=root)
+    finally:
+        trace.stop_device_trace()
+    found = host_annotations(str(tmp_path / "t"))
+    assert {"dtpu/encode", "dtpu/KSampler", "dtpu/device_wait",
+            "dtpu/queue_wait"} <= set(found)
+    assert "dtpu/before_the_trace" not in found
+    stats = found["dtpu/encode"][0]
+    assert stats["prompt_id"] == "p-7" and stats["trace_id"] == root.trace_id
+    opened.clear()
+    with trace.stage("after_the_trace"):
+        pass
+    assert opened == []
+    # the program's own summary of that trace: no device plane on the CPU
+    summary = trace.profile_summary()
+    assert summary["dir"] == str(tmp_path / "t") and summary["chips"] == []
+    assert {"encode", "KSampler", "device_wait"} <= set(
+        summary["host_spans"])
+    with open(tmp_path / "t" / "summary.json") as f:
+        assert json.load(f)["host_spans"] == summary["host_spans"]
+
+
+def test_own_stage_leaves_out_the_threads_device_waits():
+    trace.GLOBAL_STAGES.reset()
+    root = trace.start_span("job")
+    with trace.use_span(root):
+        with trace.stage("dispatch", own=True):
+            time.sleep(0.01)
+            with trace.device_wait():
+                time.sleep(0.03)
+            with trace.span("KSampler"):
+                pass
+    root.end()
+    st = trace.GLOBAL_STAGES.snapshot()
+    assert st["device_wait"]["total_s"] >= 0.03
+    assert 0.009 <= st["dispatch"]["total_s"] < 0.03
+    spans = {s["name"]: s for s in trace.GLOBAL_TRACES.export(root.trace_id)}
+    # the stage's span lies beside what ran inside it
+    assert spans["dispatch"]["parent_id"] == root.span_id
+    assert spans["KSampler"]["parent_id"] == root.span_id
+    assert spans["dispatch"]["duration_s"] >= 0.04
+    assert spans["dispatch"]["attrs"]["device_wait_s"] >= 0.03
+
+
+# --- 3. counters that tell a compile from a load ---------------------------
+
+_COUNTER_PROBE = """
+import json, jax, jax.numpy as jnp
+from comfyui_distributed_tpu.runtime.manager import \\
+    enable_persistent_compile_cache
+from comfyui_distributed_tpu.utils import trace
+print(enable_persistent_compile_cache(min_compile_secs=0.0))
+trace.install_jax_monitoring()
+jax.jit(lambda x: jnp.tanh(x @ x) + 1)(jnp.ones((64, 64))).block_until_ready()
+print(json.dumps(trace.GLOBAL_RETRACES.mark()))
+"""
+
+
+def test_retrace_seconds_and_uncached_compiles_cold_then_warm(tmp_path):
+    def run():
+        r = subprocess.run(
+            [sys.executable, "-c", _COUNTER_PROBE], capture_output=True,
+            text=True, timeout=120, cwd=str(tmp_path),
+            env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
+                 "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
+        assert r.returncode == 0, r.stderr[-2000:]
+        lines = r.stdout.strip().splitlines()
+        return lines[-2], json.loads(lines[-1])
+    used, cold = run()
+    _, warm = run()
+    # the cache rule of PR 21 stands: the directory given from outside
+    assert used == str(tmp_path / "cache")
+    for m in (cold, warm):
+        assert m["traces"] >= 1 and m["trace_s"] > 0 and m["lower_s"] > 0
+        assert m["compiles_uncached"] == m["compiles"] - m["cache_loads"]
+    assert cold["cache_loads"] == 0 and cold["cache_load_s"] == 0
+    assert cold["compiles_uncached"] == cold["compiles"] >= 1
+    assert cold["compile_s"] > 0
+    assert warm["compiles"] == cold["compiles"]
+    assert warm["cache_loads"] >= 1 and warm["cache_load_s"] > 0
+    assert warm["compiles_uncached"] < cold["compiles_uncached"]
+
+
+def test_a_jit_traced_inside_anothers_trace_counts_its_seconds_once():
+    trace.install_jax_monitoring()
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.3)
+        return x + 1
+
+    @jax.jit
+    def outer(x):
+        time.sleep(0.05)
+        return inner(x) * 2
+
+    mark = trace.GLOBAL_RETRACES.mark()
+    outer(jnp.ones((3,))).block_until_ready()
+    got = trace.GLOBAL_RETRACES.since(mark)
+    assert got["traces"] >= 2 and isinstance(got["traces"], int)
+    # summed as JAX reports them the two events are 0.3 + 0.35 s
+    assert 0.35 <= got["trace_s"] < 0.5
+    assert got["lower_s"] > 0 and got["compiles"] >= 1
+
+
+def test_retrace_counters_add_up_over_threads_without_a_lock_per_event():
+    import threading
+    stats = trace.RetraceStats()
+    traces = stats._FIELDS.index("traces")
+    lower_s = stats._FIELDS.index("lower_s")
+
+    def work():
+        for _ in range(5000):
+            stats.add(traces, lower_s, 0.001)
+    threads = [threading.Thread(target=work) for _ in range(8)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    got = stats.mark()
+    assert got["traces"] == 40000
+    assert got["lower_s"] == pytest.approx(40.0)
+    assert got["compiles_uncached"] == 0
+
+
+# --- 4. the program reduces its own trace ----------------------------------
+
+def made_up_events():
+    k = 1000
+    return {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops",
+             "names": ["while.1", "fusion.1", "fusion.2", "copy.3", "pad.0"],
+             "paths": ["jit(core)/while",
+                       UNET + "mid_attn/blocks_0/attn1/bnhd,bmhd->bhnm/"
+                       "dot_general:",
+                       UNET + "mid_res_0/in_norm/GroupNorm_0/reduce_sum:",
+                       "", "jit(pad)/pad:"],
+             "name_idx": [4, 0, 1, 2, 3, 1],
+             "start_ns": [0, 100 * k, 110 * k, 130 * k, 160 * k, 400 * k],
+             "dur_ns": [20 * k, 100 * k, 10 * k, 20 * k, 30 * k, 50 * k]},
+            {"name": "XLA Modules",
+             "names": ["jit_pad(1)", "jit_core(12)", "jit_core(7)"],
+             "name_idx": [0, 1, 2],
+             "start_ns": [0, 100 * k, 400 * k],
+             "dur_ns": [20 * k, 100 * k, 50 * k]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "exec", "names": ["dtpu/dispatch", "dtpu/KSampler",
+                                       "dtpu/exec_idle", "python frame"],
+             "name_idx": [0, 1, 2, 3],
+             "start_ns": [190 * k, 200 * k, 300 * k, 0],
+             "dur_ns": [100 * k, 40 * k, 60 * k, 900 * k]}]}]}
+
+
+def test_summary_of_a_made_up_trace():
+    s = ts.summarize(made_up_events())
+    assert s["names_found"] is True
+    # executions that touch the slice's edges are cut, not counted
+    assert set(s["programs"]) == {"jit_core"}
+    core = s["programs"]["jit_core"]
+    assert core["count"] == 1 and core["mean_s"] == pytest.approx(100e-6)
+    # the while holds two operations and is no leaf; copy.3 has no path
+    assert core["classes"] == {
+        "attn_self": pytest.approx(10e-6), "norm": pytest.approx(20e-6),
+        "other": pytest.approx(30e-6), "gaps": pytest.approx(40e-6)}
+    assert sum(core["classes"].values()) == pytest.approx(core["mean_s"])
+    assert core["top_other"] == [{"op": "copy.3", "s": pytest.approx(30e-6)}]
+    assert s["busy_s"] == pytest.approx(130e-6)
+    assert s["window_s"] == pytest.approx(450e-6)
+    # the gaps inside the whole execution (100-110, 120-130, 150-160,
+    # 190-200) are the program's; between executions 20-100 lies under
+    # no span, and 200-400 is cut at the spans' edges: KSampler
+    # (innermost) 200-240, dispatch 240-290, exec_idle 300-360, nothing
+    # 290-300 and 360-400
+    assert s["gaps_in_programs_s"] == pytest.approx(40e-6)
+    assert s["idle"] == {
+        "none": pytest.approx(130e-6), "KSampler": pytest.approx(40e-6),
+        "dispatch": pytest.approx(50e-6),
+        "exec_idle": pytest.approx(60e-6)}
+    assert s["idle_under"]["dispatch"] == pytest.approx(90e-6)
+    assert sum(s["idle"].values()) + s["gaps_in_programs_s"] == \
+        pytest.approx(s["window_s"] - s["busy_s"])
+    assert s["host_spans"] == ["KSampler", "dispatch", "exec_idle"]
+
+
+def test_summary_without_names_says_so_and_counts_the_profilers_time():
+    ev = made_up_events()
+    del ev["planes"][0]["lines"][0]["paths"]
+    s = ts.summarize(ev, traced_s=500e-6)
+    assert s["names_found"] is False
+    assert s["programs"]["jit_core"]["classes"]["other"] == \
+        pytest.approx(60e-6)
+    assert s["window_s"] == pytest.approx(500e-6)
+    assert s["idle"]["slice_edge"] == pytest.approx(50e-6)
+    assert ts.summarize({"planes": []})["chips"] == []
+
+
+RECORDED = os.path.join(DATA, "tpu_v5e_unet_block_scan.xplane.pb")
+
+
+def test_summary_of_a_recorded_chip_slice():
+    """A scan over one SpatialTransformer and one ResBlock of layers.py,
+    inside a module named UNet, traced on the TPU v5e (PR 24's probe,
+    Python tracer off): the operations' paths come from the ``tf_op``
+    statistic of their metadata entries, which only the protobuf itself
+    gives, and they are the module paths Flax put there."""
+    assert os.path.getsize(RECORDED) < 200_000
+    table = ts.read_op_metadata(RECORDED)["/device:TPU:0"]
+    paths = {st.get(ts.OP_NAME, "") for st in table.values()}
+    assert any("/UNet/down_0_attn_0/blocks_0/attn1/" in p for p in paths)
+    s = ts.summarize(ts.read_events(RECORDED), traced_s=0.004)
+    assert s["names_found"] and ts.OP_NAME in s["op_stat_names"]
+    assert s["host_spans"] == ["dispatch"]
+    core = s["programs"]["jit_core"]
+    assert core["count"] == 1
+    assert core["mean_s"] == pytest.approx(4.5638e-05, abs=1e-10)
+    assert set(core["classes"]) == {
+        "attn_self", "attn_cross", "attn_proj", "ff", "norm", "resblock",
+        "sampler", "other", "gaps"}
+    assert sum(core["classes"].values()) == pytest.approx(core["mean_s"])
+    assert core["classes"]["attn_self"] == pytest.approx(3.545e-06, abs=1e-10)
+    assert core["classes"]["attn_cross"] == pytest.approx(1.624e-06,
+                                                          abs=1e-10)
+    assert core["classes"]["resblock"] == pytest.approx(9.464e-06, abs=1e-10)
+    # at this toy size the weights' copies into fast memory show: they
+    # carry no path, and ``top_other`` names them
+    assert core["classes"]["other"] == pytest.approx(3.562e-06, abs=1e-10)
+    assert all(o["op"].startswith("%copy-done") for o in core["top_other"])
+    assert s["idle"]["dispatch"] == pytest.approx(0.003057694, abs=1e-9)
+    assert s["gaps_in_programs_s"] == pytest.approx(1.059e-06, abs=1e-10)
+
+
+# --- 5. the executor's self times add up -----------------------------------
+
+def make_prompt(seed):
+    return {
+        "7": {"class_type": "CheckpointLoaderSimple",
+              "inputs": {"ckpt_name": "tiny.safetensors"}},
+        "5": {"class_type": "CLIPTextEncode",
+              "inputs": {"text": f"cat {seed}", "clip": ["7", 1]}},
+        "6": {"class_type": "CLIPTextEncode",
+              "inputs": {"text": "", "clip": ["7", 1]}},
+        "9": {"class_type": "EmptyLatentImage",
+              "inputs": {"width": 32, "height": 32, "batch_size": 1}},
+        "8": {"class_type": "KSampler",
+              "inputs": {"model": ["7", 0], "positive": ["5", 0],
+                         "negative": ["6", 0], "latent_image": ["9", 0],
+                         "seed": seed, "steps": 2, "cfg": 2.0,
+                         "sampler_name": "euler", "scheduler": "normal",
+                         "denoise": 1.0}},
+        "1": {"class_type": "VAEDecode",
+              "inputs": {"samples": ["8", 0], "vae": ["7", 2]}},
+        "3": {"class_type": "SaveImage",
+              "inputs": {"images": ["1", 0], "filename_prefix": f"s{seed}"}},
+    }
+
+
+def wait_history(state, pids, timeout=120):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if all(p in state._history for p in pids):
+            return
+        time.sleep(0.005)
+    raise AssertionError("prompts never finished")
+
+
+def test_executor_self_times_add_up_and_requests_carry_their_instants(
+        tmp_path):
+    trace.GLOBAL_STAGES.reset()
+    t0 = time.perf_counter()
+    st = ServerState(config_path=str(tmp_path / "cfg.json"),
+                     input_dir=str(tmp_path / "in"),
+                     output_dir=str(tmp_path / "out"), overlap=True,
+                     coalesce=False)
+    wait_history(st, [st.enqueue_prompt(make_prompt(0), "warm")])
+    pids = []
+    for seed in range(1, 9):
+        pids.append(st.enqueue_prompt(make_prompt(seed), "c"))
+        time.sleep(0.03 if seed % 2 else 0.0)
+    wait_history(st, pids)
+    # the executor waits in an exec_idle that is still open: a wake-up
+    # with nothing queued closes it (and opens the next)
+    st._queue_event.set()
+    wall = time.perf_counter() - t0
+    time.sleep(0.1)
+    stages = trace.GLOBAL_STAGES.snapshot()
+    assert stages["dispatch"]["count"] == stages["dispatch_wait"]["count"] \
+        == 9
+    # exec_idle + dispatch (the host's own) + dispatch_wait (the device
+    # waits inside it) are the executor thread's whole time, once each
+    covered = sum(stages[k]["total_s"]
+                  for k in ("exec_idle", "dispatch", "dispatch_wait"))
+    assert 0.98 * wall <= covered <= wall + 0.1
+    # every thread's waits: the executor's are among them
+    assert stages["device_wait"]["total_s"] \
+        >= stages["dispatch_wait"]["total_s"]
+    for name in ("history_write", "queue_to_device", "d2h", "d2h_copy",
+                 "encode", "queue_wait", "compute", "job_e2e"):
+        assert stages[name]["count"] >= 9, name
+    rec = trace.GLOBAL_TRACES.get(pids[-1])
+    root = [s for s in rec["spans"] if s["name"] == "job"][0]
+    inst = root["attrs"]["instants"]
+    order = ["enqueued", "popped", "dispatched", "device_ready", "encoded",
+             "in_history"]
+    assert [k for k in order if k in inst] == order
+    assert [inst[k] for k in order] == sorted(inst[k] for k in order)
+    names = {s["name"] for s in rec["spans"]}
+    assert {"dispatch", "device_wait", "d2h", "d2h_copy", "history_write",
+            "queue_to_device", "execute"} <= names
