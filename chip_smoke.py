@@ -14,7 +14,8 @@ upscale ``workflows/distributed-upscale.json`` as shipped (512 -> 2048,
 (``models/registry.py`` builds them when no checkpoint file exists), so
 nothing is downloaded.  After the server has exited, a second child
 compiles the Pallas flash-attention kernel (``interpret=False``) at the
-shapes the two UNets produce and compares it with ``xla_attention``.
+shapes the UNets' attention rule sends it and holds its error against an
+fp32 oracle to ``xla_attention``'s.
 
 This process never imports JAX: a chip belongs to one process at a time,
 and a parent that touched JAX would hold it.  It talks to the server
@@ -63,19 +64,27 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 PHASES = ("sdxl", "upscale", "kernels")
 SDXL_SEEDS = (777, 100777, 200777)   # far apart: fan-out replica r adds r
 
-# (q [B, N, H, D], kv length M): SDXL-base at 1024 px and SD1.5 at 512 px,
-# CFG-stacked batch of 2; M=77 is the text cross-attention
+# (q [B, N, H, D], kv length M) at a CFG-stacked batch of 2: the
+# self-attentions `models/layers.py:attention_path` sends to the kernel on
+# a TPU, then two it keeps on the XLA path (``attn_impl="pallas"`` can
+# still force the kernel there, so it has to compile); M=77 is the text
+# cross-attention
 KERNEL_SHAPES = (
     ((2, 4096, 10, 64), 4096),   # SDXL level 1 self-attention
     ((2, 1024, 20, 64), 1024),   # SDXL level 2 / mid self-attention
     ((2, 4096, 8, 40), 4096),    # SD1.5 level 0 self-attention
+    ((2, 1024, 8, 80), 1024),    # SD1.5 level 1 self-attention
+    ((2, 9216, 5, 64), 9216),    # SD2.1 at 768x768, level 0
     ((2, 256, 8, 160), 256),     # SD1.5 level 2 self-attention
     ((2, 4096, 10, 64), 77),     # SDXL cross-attention
-    ((2, 4096, 8, 40), 77),      # SD1.5 cross-attention
 )
 KERNEL_SHAPES_REHEARSAL = (((1, 200, 2, 16), 200), ((2, 64, 2, 16), 77))
-# bf16 inputs, fp32 accumulation on both sides: max |diff| over max |ref|
-KERNEL_REL_TOL = 2e-2
+# The kernel is held to the precision of the path it replaces: its error
+# against an fp32 oracle (max |diff| over max |ref|) may be at most this
+# many times `xla_attention`'s against the same oracle.  The floor is for
+# the fp32 rehearsal, where both errors are a few ulps.
+KERNEL_ERR_RATIO = 1.25
+KERNEL_ERR_FLOOR = 4e-6
 
 
 class SmokeFailure(Exception):
@@ -390,15 +399,17 @@ def server_phases(phases, cfg: dict, out_dir: str, env: dict,
 
 def kernel_child(rehearse: bool) -> int:
     """Runs in its own process (it owns the chip while it lives): compile
-    the Pallas kernel at each shape and compare with ``xla_attention``.
-    Prints one JSON line; any refusal or mismatch raises."""
+    the Pallas kernel at each shape and compare it and ``xla_attention``
+    with an fp32 oracle.  Prints one JSON line; any refusal, or an error
+    over KERNEL_ERR_RATIO x ``xla_attention``'s, raises."""
     import math
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from comfyui_distributed_tpu.models.layers import xla_attention
+    from comfyui_distributed_tpu.models.layers import (attention_path,
+                                                       xla_attention)
     from comfyui_distributed_tpu.ops.pallas.flash_attention import \
         flash_attention
     from comfyui_distributed_tpu.runtime.manager import \
@@ -409,6 +420,14 @@ def kernel_child(rehearse: bool) -> int:
     if platform != ("cpu" if rehearse else "tpu"):
         raise SystemExit(f"kernel phase: platform is {platform!r}")
     enable_persistent_compile_cache()
+
+    def oracle(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jnp.einsum("bnhd,bmhd->bhnm", q, k, precision="highest") \
+            / math.sqrt(q.shape[-1])
+        return jnp.einsum("bhnm,bmhd->bnhd", jax.nn.softmax(s, axis=-1),
+                          v, precision="highest")
+
     rows, failures = [], []
     shapes = KERNEL_SHAPES_REHEARSAL if rehearse else KERNEL_SHAPES
     dtype = jnp.float32 if rehearse else jnp.bfloat16
@@ -417,22 +436,31 @@ def kernel_child(rehearse: bool) -> int:
         q = jnp.asarray(rng.standard_normal((b, n, h, d)), dtype)
         k = jnp.asarray(rng.standard_normal((b, m, h, d)), dtype)
         v = jnp.asarray(rng.standard_normal((b, m, h, d)), dtype)
-        ref = np.asarray(jax.jit(lambda q, k, v: xla_attention(
-            q, k, v, 1.0 / math.sqrt(d)))(q, k, v), np.float32)
+        ref = np.asarray(jax.jit(oracle)(q, k, v))
+
+        def rel_err(out):
+            return float(np.max(np.abs(np.asarray(out, np.float32) - ref))
+                         / np.max(np.abs(ref)))
+
+        err_xla = rel_err(jax.jit(lambda q, k, v: xla_attention(
+            q, k, v, 1.0 / math.sqrt(d)))(q, k, v))
         try:
-            out = np.asarray(jax.jit(lambda q, k, v: flash_attention(
-                q, k, v, interpret=rehearse))(q, k, v), np.float32)
+            out = jax.jit(lambda q, k, v: flash_attention(
+                q, k, v, interpret=rehearse))(q, k, v)
         except Exception as e:  # noqa: BLE001 - report every shape, then fail
             failures.append(f"q {(b, n, h, d)} M={m}: the compiler refused "
                             f"it: {type(e).__name__}: {str(e)[:1500]}")
             continue
-        err = float(np.max(np.abs(out - ref)) / np.max(np.abs(ref)))
-        if not np.isfinite(out).all() or err > KERNEL_REL_TOL:
-            failures.append(f"q {(b, n, h, d)} M={m}: rel err {err:.4f} "
-                            f"against xla_attention (tolerance "
-                            f"{KERNEL_REL_TOL})")
+        err = rel_err(out)
+        if not (err <= max(KERNEL_ERR_RATIO * err_xla, KERNEL_ERR_FLOOR)):
+            failures.append(
+                f"q {(b, n, h, d)} M={m}: rel err {err:.5f} against the "
+                f"fp32 oracle, xla_attention's is {err_xla:.5f} (at most "
+                f"{KERNEL_ERR_RATIO} x allowed)")
         rows.append({"q": [b, n, h, d], "kv_len": m,
-                     "rel_err": round(err, 5)})
+                     "path": attention_path(platform, b, n, m, h),
+                     "rel_err": round(err, 6),
+                     "rel_err_xla": round(err_xla, 6)})
     if failures:
         raise SystemExit("kernel phase failed:\n" + "\n".join(failures))
     print(json.dumps({"device": {"platform": platform,
@@ -462,7 +490,8 @@ def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
         result["device"] = report["device"]
     result["smoke_facts"]["pallas_flash_attention"] = {
         "interpret": report["interpret"], "shapes": report["shapes"]}
-    say(f"kernels: {len(report['shapes'])} shape(s) match xla_attention")
+    say(f"kernels: {len(report['shapes'])} shape(s) within "
+        f"{KERNEL_ERR_RATIO} x xla_attention's error against fp32")
 
 
 # --- main --------------------------------------------------------------------

@@ -18,6 +18,9 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from comfyui_distributed_tpu.parallel import sharding as shd
+from comfyui_distributed_tpu.utils.constants import (DATA_AXIS, SEQ_AXIS,
+                                                     TENSOR_AXIS)
+from comfyui_distributed_tpu.utils.trace import ATTENTION_PATHS
 
 Dtype = Any
 
@@ -60,12 +63,17 @@ class Attention(nn.Module):
 
     Self-attention when ``context`` is None, cross-attention otherwise.
     Shapes: q from ``x [B, N, C]``, k/v from ``context [B, M, Cc]``.
-    ``attn_impl`` selects the math: "xla" (fused by the compiler),
-    "pallas" (custom flash kernel, ops/pallas/flash_attention.py), or
-    "ring" (sequence-parallel over the mesh's ``seq`` axis,
-    parallel/ring.py; falls back to "xla" when the sequence is short,
-    indivisible, or the mesh has no seq axis — e.g. the 77-token text
-    cross-attention).
+    How the math runs is decided per call site, at trace time, by
+    `scaled_dot_product_attention`: with ``attn_impl="xla"`` (what every
+    config carries) `attention_path` reads the platform and the operands'
+    shapes and sends the large self-attentions on a TPU to the fused
+    Pallas flash kernel (ops/pallas/flash_attention.py; inside
+    ``shard_map`` under a multi-device mesh) and everything else — the
+    77-token text cross-attention, the small levels, every CPU run — to
+    `xla_attention`.  ``"pallas"`` forces the kernel; ``"ring"`` asks for
+    sequence parallelism over the mesh's ``seq`` axis (parallel/ring.py;
+    falls back to the rule when the sequence is short, indivisible, or
+    the mesh has no seq axis).
     """
     num_heads: int
     head_dim: Optional[int] = None
@@ -121,19 +129,117 @@ class Attention(nn.Module):
         return nn.Dense(c, dtype=self.dtype, name="to_out")(out)
 
 
+# Token counts (queries and keys both) from which the fused kernel beats
+# the XLA path on a TPU: PERF.md §6, PR 25, the step-0 table.  Below it a
+# grid step's fixed cost and the layout copies outweigh the score bytes
+# the kernel saves; M = 77 cross-attention stays on the XLA path with it.
+FUSED_MIN_TOKENS = 1024
+
+
+def _query_chunk(b: int, n: int, m: int, h: int) -> Optional[int]:
+    """The query chunk `xla_attention` scans over, or None where the
+    whole fp32 score tensor [b, h, n, m] stays under the ceiling
+    (``DTPU_ATTN_SCORES_BYTES``, default 512 MB): the largest divisor of
+    ``n`` whose score block does."""
+    import os
+
+    limit = int(os.environ.get("DTPU_ATTN_SCORES_BYTES",
+                               str(512 * 1024 * 1024)))
+    if 4 * b * h * n * m <= limit or n <= 128:
+        return None
+    want = max(1, limit // (4 * b * h * m))
+    return next(c for c in range(min(want, n), 0, -1) if n % c == 0)
+
+
+def attention_path(platform: str, b: int, n: int, m: int, h: int,
+                   mesh_axes: Optional[dict] = None) -> str:
+    """Which implementation ``impl="xla"`` (the default) runs for q
+    [b, n, h, *] against k/v [b, m, h, *]: ``fused`` (the Pallas flash
+    kernel), ``xla_whole`` or ``xla_chunked`` (`xla_attention` with the
+    score tensor whole or scanned over query chunks).
+
+    A function of what the code can see at trace time and nothing else:
+    the backend's platform, the operands' static shapes and the live
+    mesh's ``{axis: size}`` (None on one device).  The kernel takes
+    self-attention-sized calls on a TPU (both token counts at least
+    FUSED_MIN_TOKENS) at every head width.  Under a multi-device mesh
+    each chip runs it on its own rows and heads (`_fused_on_mesh`), which
+    beats what the partitioned XLA path does per chip (PERF.md §6, PR
+    25); a mesh that `shard_map` could not split the call over — a live
+    ``seq`` axis, rows that do not divide ``data``, heads that do not
+    divide ``tensor`` — would run the whole call on every peer, so there
+    the call stays with XLA, which partitions it."""
+    axes = mesh_axes or {}
+    splits = (axes.get(SEQ_AXIS, 1) == 1 and b % axes.get(DATA_AXIS, 1) == 0
+              and h % axes.get(TENSOR_AXIS, 1) == 0)
+    if platform == "tpu" and min(n, m) >= FUSED_MIN_TOKENS and splits:
+        return "fused"
+    return "xla_whole" if _query_chunk(b, n, m, h) is None \
+        else "xla_chunked"
+
+
 def scaled_dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                  impl: str = "xla") -> jax.Array:
-    """[B, N, H, D] attention. fp32 softmax accumulation."""
+    """[B, N, H, D] attention, fp32 softmax accumulation.
+
+    ``impl="xla"`` — what every model config carries — leaves the choice
+    to `attention_path`: the fused Pallas kernel for the large
+    self-attentions on a TPU, `xla_attention` for everything else and for
+    every CPU run.  ``"pallas"`` forces the kernel and ``"ring"`` asks for
+    the sequence-parallel ring (tests and `bench.py --attn`).  Every path
+    is differentiable (the training step runs this).  Each call site
+    counts the path it took, once, at trace time (``attention_paths`` on
+    ``GET /distributed/metrics``)."""
     if impl == "ring":
         out = _maybe_ring_attention(q, k, v)
         if out is not None:
+            ATTENTION_PATHS.bump("ring")
             return out
         impl = "xla"
-    if impl == "pallas":
-        from comfyui_distributed_tpu.ops.pallas.flash_attention import (
-            flash_attention)
+    B, N, H, D = q.shape
+    mesh = _live_mesh()
+    path = "fused" if impl == "pallas" else attention_path(
+        jax.default_backend(), B, N, k.shape[1], H,
+        dict(mesh.shape) if mesh is not None else None)
+    ATTENTION_PATHS.bump(path)
+    if path == "fused":
+        return _fused_on_mesh(q, k, v, mesh)
+    return xla_attention(q, k, v, 1.0 / math.sqrt(D))
+
+
+def _live_mesh():
+    """The live runtime's mesh if it spans several devices, else None."""
+    from comfyui_distributed_tpu.parallel.mesh import get_live_runtime
+
+    mesh = getattr(get_live_runtime(), "mesh", None)
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def _fused_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
+                   mesh) -> jax.Array:
+    """The flash kernel, each chip on its own share.  XLA cannot
+    partition a Mosaic custom call: handed batch-sharded operands inside
+    a program over the mesh it would gather them and run every row on
+    every chip.  So under a multi-device mesh the call goes through
+    ``jax.shard_map``: batch rows over ``data`` and heads over ``tensor``,
+    and each chip runs the one-chip kernel.  The rule sends only calls
+    that split so; a caller that forces the kernel (``impl="pallas"``)
+    gets what does not divide replicated."""
+    from comfyui_distributed_tpu.ops.pallas.flash_attention import (
+        flash_attention)
+
+    if mesh is None:
         return flash_attention(q, k, v)
-    return xla_attention(q, k, v, 1.0 / math.sqrt(q.shape[-1]))
+
+    def axis(name: str, dim: int) -> Optional[str]:
+        size = int(mesh.shape.get(name, 1))
+        return name if size > 1 and dim % size == 0 else None
+
+    spec = shd.mesh_spec(axis(DATA_AXIS, q.shape[0]), None,
+                         axis(TENSOR_AXIS, q.shape[2]), None)
+    return jax.shard_map(flash_attention, mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
 
 
 def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -148,8 +254,9 @@ def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   scale: float) -> jax.Array:
-    """The reference attention math with a memory ceiling: the default
-    impl, and the oracle the flash kernel is checked against.
+    """The reference attention math with a memory ceiling: the path of
+    everything `attention_path` does not send to the flash kernel, and
+    the oracle the kernel is checked against.
 
     The fp32 score tensor is [B, H, N, M]; at SDXL 1024px (N=M=4096)
     with a CFG-stacked batch that is 1.3 GB per attention.  Softmax is
@@ -158,20 +265,10 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     choice is static (shapes + env), so there is no dynamic control
     flow under jit; ``DTPU_ATTN_SCORES_BYTES`` tunes the ceiling
     (default 512 MB)."""
-    import os
-
     B, N, H, D = q.shape
-    M = k.shape[1]
-    limit = int(os.environ.get("DTPU_ATTN_SCORES_BYTES",
-                               str(512 * 1024 * 1024)))
-    if 4 * B * H * N * M <= limit or N <= 128:
+    chunk = _query_chunk(B, N, k.shape[1], H)
+    if chunk is None:
         return _attn_scores_block(q, k, v, scale)
-    want = max(1, limit // (4 * B * H * M))
-    chunk = 1
-    for d in range(min(want, N), 0, -1):    # largest divisor of N <= want
-        if N % d == 0:
-            chunk = d
-            break
     n_chunks = N // chunk
     qr = q.reshape(B, n_chunks, chunk, H, D).transpose(1, 0, 2, 3, 4)
 
@@ -195,7 +292,6 @@ def _maybe_ring_attention(q: jax.Array, k: jax.Array,
 
     from comfyui_distributed_tpu.parallel.mesh import get_runtime
     from comfyui_distributed_tpu.parallel.ring import ring_attention
-    from comfyui_distributed_tpu.utils.constants import SEQ_AXIS
 
     mesh = get_runtime().mesh
     n = int(mesh.shape.get(SEQ_AXIS, 1))

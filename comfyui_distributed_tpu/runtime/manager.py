@@ -36,24 +36,39 @@ MASTER_PID_ENV = "DTPU_MASTER_PID"
 # <checkout>/.jax_cache, resolved from this package's own location: the
 # path is part of what a cache hit needs, so it depends on neither the
 # CWD, nor ``~``, nor anything made up at run time
-_CHECKOUT_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".jax_cache")
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+_CHECKOUT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 
 def enable_persistent_compile_cache(
         min_compile_secs: Optional[float] = None) -> str:
-    """Turn on JAX's persistent (on-disk) XLA compilation cache, so a
-    fresh process pays trace + deserialize for a program an earlier one
-    compiled.  ``serve``, ``worker``, ``run``, ``bench.py``,
-    ``chip_smoke.py``'s children and the tests all call this.
+    """Turn on JAX's persistent (on-disk) XLA compilation cache and, for
+    the whole process, strip the checkout's path from the source
+    locations of every program's HLO (profiles and HLO dumps then show
+    ``comfyui_distributed_tpu/...`` relative paths).  A fresh process pays
+    trace + deserialize for a program an earlier one compiled.
+    ``serve``, ``worker``, ``run``, ``bench.py``, ``chip_smoke.py``'s
+    children and the tests all call this.
 
     One rule for the directory: where ``JAX_COMPILATION_CACHE_DIR`` is
     set, JAX already uses it and this sets NO directory in code (a sealed
     machine's cache must be placeable from outside); otherwise it is
     ``<checkout>/.jax_cache``.  Spawned workers resolve the same way, so
-    one host's processes share one cache.  Returns the directory in use."""
+    one host's processes share one cache.  Returns the directory in use.
+
+    Why the locations (``jax_hlo_source_file_canonicalization_regex``,
+    a process-wide JAX option): a Pallas kernel is serialised into its
+    custom call WITH them and that blob is hashed into the cache key as
+    it is, so without this a checkout at a new path would compile every
+    program that holds the flash kernel again (PERF.md §6, PR 25).
+    Programs without a kernel are keyed with their locations stripped
+    either way."""
+    import re
+
     import jax
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_CHECKOUT + os.sep))
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = _CHECKOUT_CACHE_DIR

@@ -28,6 +28,8 @@ Here profiling is a first-class subsystem:
   edge in the ops layer reports bytes through :func:`record_transfer`,
   attributed to the executing workflow node (:func:`node_scope`) — the
   software-measurable proxy for "tensors never leave HBM";
+- attention call sites by the path each took (:data:`ATTENTION_PATHS`),
+  counted while a program is traced;
 - retrace/compile counters (:class:`RetraceStats`) fed by
   ``jax.monitoring`` events, telling a compile from a cache load, with
   their seconds: a steady-state serving process must report ZERO new
@@ -449,6 +451,13 @@ class CounterStats:
 # /distributed/metrics and bench.py --phase pipeline read
 GLOBAL_COUNTERS = CounterStats()
 
+# Attention call sites by the path each took (``fused``, ``xla_whole``,
+# ``xla_chunked``, ``ring``): bumped by models/layers.py once per site
+# while a program is TRACED, never around a jitted call, so a served
+# request adds nothing.  A process's whole life: metrics/reset leaves it,
+# like ``retraces``.
+ATTENTION_PATHS = CounterStats()
+
 
 class GaugeStats:
     """Named level gauges (thread-safe) — current-state values the
@@ -850,7 +859,8 @@ def install_jax_monitoring() -> None:
 def counters_snapshot() -> Dict[str, Any]:
     """One payload for /distributed/metrics and bench artifacts."""
     return {"transfers": GLOBAL_TRANSFERS.snapshot(),
-            "retraces": GLOBAL_RETRACES.mark()}
+            "retraces": GLOBAL_RETRACES.mark(),
+            "attention_paths": ATTENTION_PATHS.snapshot()}
 
 
 # --- request-scoped distributed tracing (spans) ------------------------------

@@ -46,6 +46,14 @@ def test_rehearsal_passes_on_the_cpu(tmp_path):
     assert len(facts["sdxl_request_wall_s"]) == 3
     assert facts["memory_source"] == "host_rss"     # no allocator on CPU
     assert facts["pallas_flash_attention"]["interpret"] is True
+    # per shape: the path the rule chose (never the kernel on a CPU) and
+    # the kernel's error against fp32 beside xla_attention's
+    shapes = facts["pallas_flash_attention"]["shapes"]
+    assert len(shapes) == 2
+    for row in shapes:
+        assert set(row) == {"q", "kv_len", "path", "rel_err", "rel_err_xla"}
+        assert row["path"] in ("xla_whole", "xla_chunked")
+        assert row["rel_err"] <= max(1.25 * row["rel_err_xla"], 4e-6)
     assert list(summary)[-1] == "claim" and summary["claim"] is None
     with open(tmp_path / "out" / "summary.json", encoding="utf-8") as f:
         assert json.load(f) == summary
@@ -84,3 +92,21 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert r.returncode != 0
     assert r.stdout.strip() == ""
     assert "not next to this script" in r.stderr
+
+
+def test_kernel_phase_holds_the_kernel_to_xla_attentions_error():
+    """On the chip the kernel child runs the shapes the rule sends to the
+    kernel: the four large self-attentions of the two benchmarked UNets
+    among them, each a shape `attention_path` answers ``fused`` for."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    from comfyui_distributed_tpu.models.layers import attention_path
+    fused = [(q, m) for q, m in smoke.KERNEL_SHAPES
+             if attention_path("tpu", q[0], q[1], m, q[2]) == "fused"]
+    for shape in (((2, 4096, 10, 64), 4096), ((2, 1024, 20, 64), 1024),
+                  ((2, 4096, 8, 40), 4096), ((2, 1024, 8, 80), 1024)):
+        assert shape in fused
+    assert len(fused) < len(smoke.KERNEL_SHAPES)    # and some it keeps
+    assert smoke.KERNEL_ERR_RATIO == 1.25
