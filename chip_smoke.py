@@ -40,7 +40,12 @@ then say ``"platform": "cpu"`` and the summary says ``"rehearsal": true``.
 
 ``--phases`` picks from ``sdxl,upscale,kernels`` (default: all three) so
 a second run in one chip call can time a cache-warm first request
-without paying for the rest again.
+without paying for the rest again.  A fourth phase, ``lm``, is run only
+when named: the language model of ``workflows/prompt-expand-txt2img.json``
+at its published widths, served through ``POST /prompt`` by a server of
+its own, and the logits of that request held to the plain float32
+reference (``benchmarks/chip/verify_lm.py``, which says what is compared
+and why each limit is what it is).
 """
 
 from __future__ import annotations
@@ -61,7 +66,8 @@ import urllib.request
 import uuid
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-PHASES = ("sdxl", "upscale", "kernels")
+DEFAULT_PHASES = ("sdxl", "upscale", "kernels")
+PHASES = DEFAULT_PHASES + ("lm",)
 SDXL_SEEDS = (777, 100777, 200777)   # far apart: fan-out replica r adds r
 
 # (q [B, N, H, D], kv length M) at a CFG-stacked batch of 2: the
@@ -494,6 +500,46 @@ def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
         f"{KERNEL_ERR_RATIO} x xla_attention's error against fp32")
 
 
+# --- the language model against its reference --------------------------------
+
+def lm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    """``benchmarks/chip/verify_lm.py`` as a child: one request of the
+    prompt expander's graph at the timed size, then the reference."""
+    log_path = os.path.join(out_dir, "verify_lm.stderr.log")
+    cmd = [sys.executable,
+           os.path.join(HERE, "benchmarks", "chip", "verify_lm.py"),
+           "--requests", "1", "--out", os.path.join(out_dir, "verify_lm")]
+    if cfg["rehearsal"]:
+        cmd.append("--rehearse")
+    with open(log_path, "wb") as log:
+        proc = subprocess.run(cmd, cwd=out_dir, env=env,
+                              stdout=subprocess.PIPE, stderr=log,
+                              timeout=2 * cfg["first_timeout"])
+    with open(log_path, "rb") as f:
+        tail = f.read()[-3000:].decode("utf-8", "replace")
+    lines = proc.stdout.decode().strip().splitlines()
+    check(bool(lines), f"verify_lm printed nothing (exit "
+                       f"{proc.returncode}):\n{tail}")
+    report = json.loads(lines[-1])
+    check(proc.returncode == 0 and report["ok"],
+          f"the served logits are outside a limit, or an 8-bit reading is "
+          f"inside all of them: {json.dumps(report)}\n{tail}")
+    if result["device"] is None:     # an lm-only run
+        dev = report["device"]
+        result["device"] = {"platform": dev["platform"], "kind": dev["kind"],
+                            "count": 1}
+    served = report["served"][0]
+    result["smoke_facts"]["language_model"] = {
+        key: served[key] for key in
+        ("positions", "prompt_ids", "max_over_std", "mean_over_std",
+         "margin_over_std", "argmax_agree", "limits")} | {
+        "weights_8bit": report["weights_8bit"]["mean_over_std"],
+        "cache_8bit": report["cache_8bit"]["mean_over_std"]}
+    say(f"lm: {served['positions']} positions within the limits "
+        f"(mean {served['mean_over_std']:.4f}, max "
+        f"{served['max_over_std']:.4f} of a standard deviation)")
+
+
 # --- main --------------------------------------------------------------------
 
 def configuration(rehearse: bool) -> dict:
@@ -539,7 +585,7 @@ def main() -> int:
                     help="CPU rehearsal: tiny family, small sizes")
     ap.add_argument("--out", default=os.path.join(
         HERE, "chiprun_out", "chip_smoke"), help="output directory")
-    ap.add_argument("--phases", default=",".join(PHASES),
+    ap.add_argument("--phases", default=",".join(DEFAULT_PHASES),
                     help=f"comma-separated subset of {','.join(PHASES)}")
     ap.add_argument("--kernel-child", action="store_true",
                     help=argparse.SUPPRESS)
@@ -577,6 +623,8 @@ def main() -> int:
             server_phases(phases, cfg, out_dir, env, summary)
         if "kernels" in phases:
             kernel_phase(cfg, out_dir, env, summary)
+        if "lm" in phases:
+            lm_phase(cfg, out_dir, env, summary)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -751,3 +751,43 @@ def load_upscaler_checkpoint(path: str, cfg) -> Params:
     log(f"loaded upscaler checkpoint {os.path.basename(path)} "
         f"(scale {cfg.scale}, {cfg.num_blocks} blocks)")
     return tree
+
+
+# --- the looped language model (models/looplm.py) ----------------------------
+
+_LOOPLM_LAYER_KEYS = {
+    "input_layernorm": "input_layernorm.weight",
+    "input_layernorm_2": "input_layernorm_2.weight",
+    "post_attention_layernorm": "post_attention_layernorm.weight",
+    "post_attention_layernorm_2": "post_attention_layernorm_2.weight",
+    "q_proj": "self_attn.q_proj.weight", "k_proj": "self_attn.k_proj.weight",
+    "v_proj": "self_attn.v_proj.weight", "o_proj": "self_attn.o_proj.weight",
+    "gate_proj": "mlp.gate_proj.weight", "up_proj": "mlp.up_proj.weight",
+    "down_proj": "mlp.down_proj.weight",
+}
+
+
+def load_looplm_checkpoint(path: str, cfg) -> Params:
+    """The model's Hugging Face state dict (``model.layers.<l>...``,
+    linear weights ``[out, in]``) as the tree ``models/looplm.py`` serves:
+    kernels ``[in, out]``, the layers' leaves stacked on a leading axis,
+    everything in the model's dtype."""
+    import jax
+    import jax.numpy as jnp
+    sd = load_state_dict(path)
+
+    def leaf(key: str):
+        w = sd[key]
+        return t_lin(w) if w.ndim == 2 and "embed_tokens" not in key else w
+
+    layers = {
+        name: np.stack([leaf(f"model.layers.{l}.{key}")
+                        for l in range(cfg.num_hidden_layers)])
+        for name, key in _LOOPLM_LAYER_KEYS.items()}
+    tree = {"embed_tokens": leaf("model.embed_tokens.weight"),
+            "layers": layers, "norm": leaf("model.norm.weight"),
+            "early_exit_gate": {
+                "kernel": sd["model.early_exit_gate.weight"].reshape(-1),
+                "bias": sd["model.early_exit_gate.bias"].reshape(())},
+            "lm_head": leaf("lm_head.weight")}
+    return jax.tree_util.tree_map(lambda a: jnp.asarray(a, cfg.dtype), tree)

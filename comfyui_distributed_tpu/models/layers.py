@@ -152,11 +152,16 @@ def _query_chunk(b: int, n: int, m: int, h: int) -> Optional[int]:
 
 
 def attention_path(platform: str, b: int, n: int, m: int, h: int,
-                   mesh_axes: Optional[dict] = None) -> str:
+                   mesh_axes: Optional[dict] = None,
+                   masked: bool = False) -> str:
     """Which implementation ``impl="xla"`` (the default) runs for q
     [b, n, h, *] against k/v [b, m, h, *]: ``fused`` (the Pallas flash
     kernel), ``xla_whole`` or ``xla_chunked`` (`xla_attention` with the
-    score tensor whole or scanned over query chunks).
+    score tensor whole or scanned over query chunks); for a ``masked``
+    call (each query sees the keys up to its own position: a language
+    model's) ``xla_decode`` where one query meets a cache and
+    ``xla_causal`` otherwise.  A masked call stays with `xla_attention`
+    on every platform: the kernel has not been taught a mask.
 
     A function of what the code can see at trace time and nothing else:
     the backend's platform, the operands' static shapes and the live
@@ -169,6 +174,8 @@ def attention_path(platform: str, b: int, n: int, m: int, h: int,
     ``seq`` axis, rows that do not divide ``data``, heads that do not
     divide ``tensor`` — would run the whole call on every peer, so there
     the call stays with XLA, which partitions it."""
+    if masked:
+        return "xla_decode" if n == 1 else "xla_causal"
     axes = mesh_axes or {}
     splits = (axes.get(SEQ_AXIS, 1) == 1 and b % axes.get(DATA_AXIS, 1) == 0
               and h % axes.get(TENSOR_AXIS, 1) == 0)
@@ -179,8 +186,16 @@ def attention_path(platform: str, b: int, n: int, m: int, h: int,
 
 
 def scaled_dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                                 impl: str = "xla") -> jax.Array:
+                                 impl: str = "xla",
+                                 q_positions: Optional[jax.Array] = None
+                                 ) -> jax.Array:
     """[B, N, H, D] attention, fp32 softmax accumulation.
+
+    ``q_positions [N]`` masks the call: query ``i`` sees the keys whose
+    index is at most ``q_positions[i]``.  That is both a causal prefill
+    (``arange(N)`` against the call's own keys) and a decode step (one
+    query at position ``t`` against a cache of any length, valid to
+    ``t``).
 
     ``impl="xla"`` — what every model config carries — leaves the choice
     to `attention_path`: the fused Pallas kernel for the large
@@ -198,13 +213,14 @@ def scaled_dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         impl = "xla"
     B, N, H, D = q.shape
     mesh = _live_mesh()
-    path = "fused" if impl == "pallas" else attention_path(
+    masked = q_positions is not None
+    path = "fused" if impl == "pallas" and not masked else attention_path(
         jax.default_backend(), B, N, k.shape[1], H,
-        dict(mesh.shape) if mesh is not None else None)
+        dict(mesh.shape) if mesh is not None else None, masked=masked)
     ATTENTION_PATHS.bump(path)
     if path == "fused":
         return _fused_on_mesh(q, k, v, mesh)
-    return xla_attention(q, k, v, 1.0 / math.sqrt(D))
+    return xla_attention(q, k, v, 1.0 / math.sqrt(D), q_positions)
 
 
 def _live_mesh():
@@ -243,17 +259,24 @@ def _fused_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
-                       scale: float) -> jax.Array:
+                       scale: float,
+                       q_positions: Optional[jax.Array] = None) -> jax.Array:
     """One materialized-score attention block (einsum -> fp32 softmax ->
-    einsum)."""
+    einsum); with ``q_positions [n]`` each query's keys end at its own
+    position."""
     logits = jnp.einsum("bnhd,bmhd->bhnm", q, k,
                         preferred_element_type=jnp.float32) * scale
+    if q_positions is not None:
+        seen = jnp.arange(k.shape[1])[None, :] <= q_positions[:, None]
+        logits = jnp.where(seen[None, None], logits,
+                           jnp.finfo(jnp.float32).min)
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhnm,bmhd->bnhd", weights.astype(v.dtype), v)
 
 
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                  scale: float) -> jax.Array:
+                  scale: float,
+                  q_positions: Optional[jax.Array] = None) -> jax.Array:
     """The reference attention math with a memory ceiling: the path of
     everything `attention_path` does not send to the flash kernel, and
     the oracle the kernel is checked against.
@@ -268,14 +291,17 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, N, H, D = q.shape
     chunk = _query_chunk(B, N, k.shape[1], H)
     if chunk is None:
-        return _attn_scores_block(q, k, v, scale)
+        return _attn_scores_block(q, k, v, scale, q_positions)
     n_chunks = N // chunk
     qr = q.reshape(B, n_chunks, chunk, H, D).transpose(1, 0, 2, 3, 4)
+    pos = None if q_positions is None \
+        else q_positions.reshape(n_chunks, chunk)
 
     def body(_, qc):
-        return None, _attn_scores_block(qc, k, v, scale)
+        qc, pc = qc
+        return None, _attn_scores_block(qc, k, v, scale, pc)
 
-    _, out = jax.lax.scan(body, None, qr)
+    _, out = jax.lax.scan(body, None, (qr, pos))
     return out.transpose(1, 0, 2, 3, 4).reshape(B, N, H, D)
 
 

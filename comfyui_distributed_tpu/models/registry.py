@@ -1266,6 +1266,150 @@ def clear_pipeline_cache() -> None:
     gg_mod.clear_gligen_cache()
 
 
+# --- language models (models/looplm.py) ----------------------------------------
+#
+# A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
+# model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
+# imported, made or traced until a graph names it.
+
+# the text a prompt expander is asked to continue
+EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
+                   "Prompt: {text} Detailed prompt:")
+
+
+def detect_lm_family(name: str) -> str:
+    """``tiny`` under ``DTPU_DEFAULT_FAMILY=tiny`` (tests, rehearsals) or
+    by name, else ``ouro`` (Ouro-2.6B's published config)."""
+    lowered = name.lower()
+    if os.environ.get(FAMILY_ENV, "").startswith("tiny") \
+            or "tiny" in lowered or "test" in lowered:
+        return "tiny"
+    return "ouro"
+
+
+@dataclasses.dataclass
+class LMOutput:
+    """What a generation leaves on the device: the prompt's real ids, the
+    new ids ``[B, N]``, the float32 logits each was drawn from
+    ``[B, N, V]`` and the exit probabilities ``[B, N, R]``."""
+    prompt_ids: np.ndarray
+    tokens: Any
+    logits: Any
+    exit_probs: Any
+
+
+class LanguageModel:
+    """A looped decoder, its tokenizer and its jitted program."""
+
+    def __init__(self, name: str, cfg: Any, params: Any, tokenizer: Any):
+        self.name, self.cfg, self.params = name, cfg, params
+        self.tokenizer = tokenizer
+        self._programs: Dict[int, Any] = {}
+        self._mesh = None
+        self._lock = threading.Lock()
+
+    def _ensure_laid_out(self) -> None:
+        """Under a multi-device mesh: the weights replicated over ``data``
+        (column-split over a live ``tensor`` axis by the shape rule every
+        tower takes), once per mesh, so that the program runs where the
+        rest of the graph does.  No-op on one device."""
+        from comfyui_distributed_tpu.parallel.mesh import get_live_runtime
+        mesh = getattr(get_live_runtime(), "mesh", None)
+        if mesh is None or mesh.size <= 1 or self._mesh is mesh:
+            return
+        with self._lock, trace_mod.stage("load_weights"):
+            self.params = shd.apply_shardings(
+                self.params, shd.params_shardings(self.params, mesh))
+            jax.block_until_ready(self.params)
+            self._mesh = mesh
+            self._programs.clear()
+
+    def generate(self, text: str, seed: int = 0, max_new_tokens: int = 64,
+                 prompt_tokens: int = 64, temperature: float = 0.0
+                 ) -> Tuple[str, LMOutput]:
+        """The continuation of ``text`` under the expander's template:
+        one execution of ``lm_generate`` (the prompt padded to
+        ``prompt_tokens``, then exactly ``max_new_tokens`` decode steps;
+        the end-of-text id does not stop it, so one shape runs).  The
+        host meets the device here, in the middle of a graph: the ids
+        have to be words before the text encoder can be enqueued."""
+        from comfyui_distributed_tpu.models import looplm
+        self._ensure_laid_out()
+        ids = self.tokenizer.encode(EXPAND_TEMPLATE.format(text=text))
+        ids = np.asarray(ids[:prompt_tokens], np.int32)
+        # an index out of range raises nothing on the device: it is clamped
+        if max_new_tokens < 1 or not len(ids) or ids.min() < 0 \
+                or ids.max() >= self.cfg.vocab_size:
+            raise ValueError(
+                f"{self.name}: a prompt of {len(ids)} ids in "
+                f"[{ids.min(initial=0)}, {ids.max(initial=0)}] and "
+                f"{max_new_tokens} new tokens cannot be generated from a "
+                f"vocabulary of {self.cfg.vocab_size}")
+        padded = np.full((1, prompt_tokens), self.tokenizer.pad_id, np.int32)
+        padded[0, :len(ids)] = ids
+        n = int(max_new_tokens)
+        with self._lock:
+            program = self._programs.get(n)
+            if program is None:
+                program = self._programs[n] = looplm.make_generate(
+                    self.cfg, n)
+        with trace_mod.stage("lm_generate"):
+            tokens, logits, exits = program(
+                self.params, padded, np.int32(len(ids)),
+                np.uint32(int(seed) & 0xFFFFFFFF), np.float32(temperature))
+            with trace_mod.device_wait():
+                # dtpu-lint: ignore[spine-host-fetch] ids must be words before CLIP can run
+                host_tokens = np.asarray(jax.device_get(tokens))
+        trace_mod.mark_instant("lm_ids_ready")
+        with trace_mod.stage("detokenize"):
+            words = self.tokenizer.decode(host_tokens[0])
+        trace_mod.GLOBAL_COUNTERS.bump("lm.prompt_tokens", len(ids))
+        trace_mod.GLOBAL_COUNTERS.bump("lm.tokens_decoded", n)
+        trace_mod.GLOBAL_COUNTERS.bump(
+            "lm.layer_applications", n * self.cfg.cache_slots)
+        trace_mod.GLOBAL_GAUGES.set(
+            "lm.kv_cache_bytes",
+            looplm.kv_cache_bytes(self.cfg, 1, prompt_tokens + n))
+        return words, LMOutput(ids, tokens, logits, exits)
+
+
+def load_language_model(name: str, models_dir: Optional[str] = None
+                        ) -> LanguageModel:
+    """Load or virtually-initialize the named language model (cached
+    beside the pipelines).  A file of that name under ``models_dir`` is
+    read as the model's Hugging Face safetensors; without one the
+    weights are seeded from the name and drawn ON THE DEVICE in one
+    jitted call (2.67 B values: numpy on the host would take a minute)."""
+    from comfyui_distributed_tpu.models import looplm
+    from comfyui_distributed_tpu.models.tokenizer import make_lm_tokenizer
+    key = f"lm:{name}:{models_dir or ''}"
+    with _pipeline_lock:
+        if key in _pipeline_cache:
+            return _pipeline_cache[key]
+    cfg = {"ouro": looplm.OURO_2_6B,
+           "tiny": looplm.TINY_LOOPLM}[detect_lm_family(name)]
+    path = os.path.join(models_dir, name) if models_dir else None
+    with trace_mod.stage("load_weights"):
+        if path is not None and os.path.exists(path):
+            from comfyui_distributed_tpu.models.checkpoints import \
+                load_looplm_checkpoint
+            params = load_looplm_checkpoint(path, cfg)
+            log(f"loaded language model {name} from {path}")
+        else:
+            seed = _name_seed(name)
+            params = jax.jit(partial(looplm.init_params, cfg))(
+                np.uint32(seed))
+            log(f"virtual language model {name!r}: no file on disk, "
+                f"{looplm.param_count(cfg) / 1e9:.3f} B seeded values made "
+                f"on the device (seed {seed})")
+        jax.block_until_ready(params)
+    model = LanguageModel(name, cfg, params,
+                          make_lm_tokenizer(models_dir, cfg.vocab_size))
+    with _pipeline_lock:
+        _pipeline_cache[key] = model
+    return model
+
+
 # derived pipelines (clip-skip variants, external VAEs): param trees are
 # SHARED with the base — only configs/modules differ — but each clone
 # carries its own jit caches, so keep identity stable across runs

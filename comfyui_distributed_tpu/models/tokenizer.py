@@ -78,6 +78,14 @@ def parse_weighted_prompt(text: str) -> List[Tuple[str, float]]:
     return [(t, w) for t, w in out if t.strip()]
 
 
+_WORDS = re.compile(r"[a-z0-9]+|[^\sa-z0-9]")
+
+
+def _word_hash(word: str) -> int:
+    """A word's stable 32-bit hash (md5: the same in every process)."""
+    return int.from_bytes(hashlib.md5(word.encode()).digest()[:4], "little")
+
+
 class HashTokenizer:
     """Deterministic word-hash tokenizer (no external assets).
 
@@ -95,13 +103,11 @@ class HashTokenizer:
         self.pad_id = self.end if pad_with_end else 0
 
     def _word_id(self, word: str) -> int:
-        h = int.from_bytes(hashlib.md5(word.encode()).digest()[:4], "little")
         usable = max(self.start - 1, 1)
-        return 1 + (h % (usable - 1))
+        return 1 + (_word_hash(word) % (usable - 1))
 
     def _frag_ids(self, frag: str) -> List[int]:
-        return [self._word_id(w)
-                for w in re.findall(r"[a-z0-9]+|[^\sa-z0-9]", frag.lower())]
+        return [self._word_id(w) for w in _WORDS.findall(frag.lower())]
 
     def encode(self, text: str) -> Tuple[np.ndarray, np.ndarray]:
         """Returns (ids [max_length] int32, weights [max_length] float32)."""
@@ -267,3 +273,69 @@ def make_tokenizer(assets_dir: Optional[str] = None,
                                 pad_with_end=pad_with_end)
     return HashTokenizer(vocab_size=vocab_size, max_length=max_length,
                          pad_with_end=pad_with_end)
+
+
+# --- the language model's tokenizers (models/looplm.py) ----------------------
+#
+# The same pair as above for a decoder's vocabulary, which needs the way
+# back too: ids -> text.
+
+_SYLLABLES = [c + v for c in "bdfgklmnprstvz" for v in "aeiou"]
+
+
+class HashLMTokenizer:
+    """Words to stable hashed ids, and ids back to stable words, with no
+    downloaded asset: id ``i`` reads as the syllables of ``i`` in base 70
+    ("kobe", "tazumi"), so every id has a word of its own and two
+    expansions that differ in an id differ in their text.  Ids 0..2 are
+    pad, beginning and end of text."""
+
+    pad_id, bos_id, eos_id = 0, 1, 2
+    _FIRST = 3
+
+    def __init__(self, vocab_size: int):
+        self.vocab_size = int(vocab_size)
+
+    def encode(self, text: str) -> List[int]:
+        span = self.vocab_size - self._FIRST
+        return [self.bos_id] + [self._FIRST + _word_hash(w) % span
+                                for w in _WORDS.findall(text.lower())]
+
+    def word(self, token: int) -> str:
+        n, out = int(token), []
+        while True:
+            n, digit = divmod(n, len(_SYLLABLES))
+            out.append(_SYLLABLES[digit])
+            if n == 0 and len(out) >= 2:
+                return "".join(reversed(out))
+
+    def decode(self, ids) -> str:
+        return " ".join(self.word(i) for i in ids
+                        if int(i) >= self._FIRST)
+
+
+class JsonLMTokenizer:
+    """The model's own ``tokenizer.json`` (Hugging Face ``tokenizers``)."""
+
+    def __init__(self, path: str):
+        from tokenizers import Tokenizer
+        self._tok = Tokenizer.from_file(path)
+        self.vocab_size = self._tok.get_vocab_size()
+        self.pad_id = self._tok.token_to_id("<|endoftext|>") or 0
+
+    def encode(self, text: str) -> List[int]:
+        return list(self._tok.encode(text).ids)
+
+    def decode(self, ids) -> str:
+        return self._tok.decode([int(i) for i in ids],
+                                skip_special_tokens=True)
+
+
+def make_lm_tokenizer(assets_dir: Optional[str], vocab_size: int):
+    """The real ``tokenizer.json`` beside the checkpoint if present, the
+    hash pair otherwise (so a graph runs with no downloaded asset)."""
+    if assets_dir:
+        path = os.path.join(assets_dir, "tokenizer.json")
+        if os.path.exists(path):
+            return JsonLMTokenizer(path)
+    return HashLMTokenizer(vocab_size)

@@ -1001,6 +1001,75 @@ class CLIPTextEncode(Op):
 
 
 @register_op
+class LanguageModelLoader(Op):
+    """-> LANGUAGE_MODEL (models/looplm.py): a decoder resident beside
+    the diffusion checkpoints.  The model's safetensors and
+    ``tokenizer.json`` from the models dir if present; otherwise seeded
+    weights made on the device and the hash tokenizer pair."""
+    TYPE = "LanguageModelLoader"
+    WIDGETS = ["model_name"]
+    DEFAULTS = {"model_name": "ouro-2.6b.safetensors"}
+
+    def execute(self, ctx: OpContext,
+                model_name: str = "ouro-2.6b.safetensors"):
+        return (registry.load_language_model(str(model_name),
+                                             models_dir=ctx.models_dir),)
+
+
+@register_op
+class LanguageModelGenerate(Op):
+    """The prompt expander: ``text`` under the expander's template is
+    continued by exactly ``max_new_tokens`` tokens (greedy at
+    ``temperature`` 0, else sampled from ``seed``) and the continuation
+    is appended to it.  -> (STRING for ``CLIPTextEncode.text``,
+    LM_OUTPUT: the ids and the float32 logits each was drawn from, left
+    on the device)."""
+    TYPE = "LanguageModelGenerate"
+    WIDGETS = ["text", "seed", CONTROL, "max_new_tokens", "prompt_tokens",
+               "temperature"]
+    DEFAULTS = {"seed": 0, "max_new_tokens": 64, "prompt_tokens": 64,
+                "temperature": 0.0}
+
+    def execute(self, ctx: OpContext, model, text: str, seed=0,
+                max_new_tokens: int = 64, prompt_tokens: int = 64,
+                temperature: float = 0.0):
+        ctx.check_interrupt()
+        base = seed.base if isinstance(seed, SeedValue) else seed
+        # dtpu-lint: ignore[spine-host-fetch] a widget's number, never a device value
+        temperature = float(temperature)
+        words, out = model.generate(
+            str(text), seed=int(base), max_new_tokens=int(max_new_tokens),
+            prompt_tokens=int(prompt_tokens), temperature=temperature)
+        return (f"{text}, {words}", out)
+
+
+@register_op
+class SaveLanguageModelOutput(Op):
+    """LM_OUTPUT -> ``<filename_prefix>.npz`` in the output directory:
+    ``prompt_ids``, ``tokens [N]``, ``logits [N, V]`` float32 and
+    ``exit_probs [N, R]`` of the first row, for comparison with a
+    reference (benchmarks/chip/verify_lm.py)."""
+    TYPE = "SaveLanguageModelOutput"
+    WIDGETS = ["filename_prefix"]
+    DEFAULTS = {"filename_prefix": "lm_output"}
+    OUTPUT_NODE = True
+
+    def execute(self, ctx: OpContext, lm_output,
+                filename_prefix: str = "lm_output"):
+        import jax
+        path = _safe_output_path(ctx.output_dir or ".",
+                                 f"{filename_prefix}.npz")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with trace_mod.device_wait():
+            # dtpu-lint: ignore[spine-host-fetch] an OUTPUT node's host edge
+            tokens, logits, exits = jax.device_get(
+                (lm_output.tokens, lm_output.logits, lm_output.exit_probs))
+        np.savez(path, prompt_ids=lm_output.prompt_ids, tokens=tokens[0],
+                 logits=logits[0], exit_probs=exits[0])
+        return ()
+
+
+@register_op
 class CLIPVisionLoader(Op):
     """-> CLIP_VISION (models/clip_vision.py tower); HF safetensors
     layout from <models>/clip_vision/, virtual init otherwise."""

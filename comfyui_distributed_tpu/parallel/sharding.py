@@ -15,7 +15,12 @@ inserts the collectives.  This module centralises those annotations:
   attention inputs; ring attention in :mod:`.ring` keeps the shards resident).
 
 Rules are shape-driven rather than name-driven so they apply uniformly to any
-flax param tree (UNet, CLIP, VAE) without per-module tables.
+param tree (UNet, CLIP, VAE) without per-module tables.  The language model's
+tree (models/looplm.py) takes them as it is: its layers' leaves carry a
+leading ``L`` axis that is never split (a kernel ``[L, in, out]`` is
+column-split over ``tensor`` like any ``[in, out]``), everything is a full
+replica over ``data``, and its q/k/v and MLP activations name the same
+logical axes (``"heads"``, ``"mlp"``) the UNet's do.
 
 Activation placement (ISSUE 16) goes through a **logical-axis rule table**
 instead of hand-built specs: model code names what a dim *is* (``"batch"``,

@@ -16,7 +16,7 @@ content-addressed tiers plus a preview/cancellation channel:
 - **Sub-graph memoization** (:attr:`ReusePlane.subgraph`): text-encoder
   embeddings and VAE-encoded conditioning latents cached ON DEVICE
   across requests, keyed by a content hash of their input sub-graph
-  (:func:`subgraph_keys`) — a retry/variant storm pays encode once;
+  (:func:`node_key`) — a retry/variant storm pays encode once;
   the continuous-batching bucket build's prefix run consumes the same
   cache, so new slots skip straight to denoise.
 - **Changed-tile skipping** (:attr:`ReusePlane.tiles`): per-tile
@@ -189,50 +189,65 @@ def _node_salt(node: Any, input_dir: Optional[str],
     return ""
 
 
+def node_key(graph: Any, nid: str, hidden: Dict[str, Dict[str, Any]],
+             keys: Dict[str, str], input_dir: Optional[str] = None,
+             models_dir: Optional[str] = None,
+             resolved: Optional[Dict[Tuple[str, int], str]] = None
+             ) -> Optional[str]:
+    """Content hash of one node's input SUB-GRAPH: node type + widget
+    values + the content keys (``keys``) of every upstream producer.
+    None unless the node and its whole subtree are in
+    ``REUSE_KEY_NODE_TYPES`` (pure functions of their widgets/inputs), so
+    a cache hit can never alias differing inputs.  Nodes carrying per-run
+    hidden overrides (coalesced seeds, recovery state) get none either.
+
+    ``resolved`` maps ``(node id, slot)`` to the text a STRING output
+    turned out to be in this run: a link to one is keyed on that text, as
+    a widget holding the same text would be, whatever produced it (a
+    language model's expansion into ``CLIPTextEncode.text``)."""
+    node = graph.nodes[nid]
+    if node.class_type not in C.REUSE_KEY_NODE_TYPES:
+        return None
+    if node.hidden or hidden.get(nid):
+        return None
+    salt = _node_salt(node, input_dir, models_dir)
+    if salt is None:
+        return None
+    parts: List[str] = [node.class_type, salt]
+    for name in sorted(node.inputs):
+        if name == "__widgets__":
+            continue
+        value = node.inputs[name]
+        if isinstance(value, (list, tuple)) and len(value) == 2 \
+                and not isinstance(value[0], (list, dict)) \
+                and isinstance(value[1], int) \
+                and str(value[0]) in graph.nodes:
+            src = (str(value[0]), int(value[1]))
+            if resolved and src in resolved:
+                value = resolved[src]
+            else:
+                up = keys.get(src[0])
+                if up is None:
+                    return None
+                parts.append(f"{name}<-{up}:{src[1]}")
+                continue
+        try:
+            parts.append(f"{name}={json.dumps(value, sort_keys=True, default=str)}")
+        except (TypeError, ValueError):
+            return None
+    return _sha("|".join(parts))
+
+
 def subgraph_keys(graph: Any, hidden: Dict[str, Dict[str, Any]],
                   input_dir: Optional[str] = None,
                   models_dir: Optional[str] = None) -> Dict[str, str]:
-    """Per-node content hash of each node's input SUB-GRAPH: node type +
-    widget values + the content keys of every upstream producer, in
-    topo order.  Only nodes whose whole subtree is in
-    ``REUSE_KEY_NODE_TYPES`` (pure functions of their widgets/inputs)
-    get a key; anything downstream of a non-addressable node is
-    excluded, so a cache hit can never alias differing inputs.  Nodes
-    carrying per-run hidden overrides (coalesced seeds, recovery state)
-    are excluded too."""
+    """:func:`node_key` of every node that has one, in topo order, from
+    the graph alone (no STRING output is resolved before a run)."""
     keys: Dict[str, str] = {}
     for nid in graph.topo_order():
-        node = graph.nodes[nid]
-        if node.class_type not in C.REUSE_KEY_NODE_TYPES:
-            continue
-        if node.hidden or hidden.get(nid):
-            continue
-        salt = _node_salt(node, input_dir, models_dir)
-        if salt is None:
-            continue
-        parts: List[str] = [node.class_type, salt]
-        ok = True
-        for name in sorted(node.inputs):
-            if name == "__widgets__":
-                continue
-            value = node.inputs[name]
-            if isinstance(value, (list, tuple)) and len(value) == 2 \
-                    and not isinstance(value[0], (list, dict)) \
-                    and isinstance(value[1], int) \
-                    and str(value[0]) in graph.nodes:
-                up = keys.get(str(value[0]))
-                if up is None:
-                    ok = False
-                    break
-                parts.append(f"{name}<-{up}:{value[1]}")
-            else:
-                try:
-                    parts.append(f"{name}={json.dumps(value, sort_keys=True, default=str)}")
-                except (TypeError, ValueError):
-                    ok = False
-                    break
-        if ok:
-            keys[nid] = _sha("|".join(parts))
+        key = node_key(graph, nid, hidden, keys, input_dir, models_dir)
+        if key is not None:
+            keys[nid] = key
     return keys
 
 

@@ -104,6 +104,10 @@ COALESCE_SAFE_NODE_TYPES = frozenset({
     "CheckpointLoaderSimple", "CLIPTextEncode", "CLIPSetLastLayer",
     "LoraLoader", "LoraLoaderModelOnly", "EmptyLatentImage", "KSampler",
     "VAEDecode", "VAEDecodeTiled", "SaveImage", "PreviewImage",
+    # the prompt expander in front of a text encode, treated as the
+    # encode is: its text and seed are in the signature, so merged
+    # prompts share one expansion as they share one embedding
+    "LanguageModelLoader", "LanguageModelGenerate",
 })
 
 # --- iteration-level continuous batching (workflow/batch_executor.py) --------
@@ -195,7 +199,7 @@ PREVIEW_MAX_CLIENTS_DEFAULT = 64
 
 # Node types whose output is a pure function of (widgets, upstream
 # content keys) — the sub-graph memoization's addressable set
-# (runtime/reuse.subgraph_keys).  Deliberately conservative: these feed
+# (runtime/reuse.node_key).  Deliberately conservative: these feed
 # the two cached producers (text-encoder embeddings via CLIPTextEncode,
 # VAE-encoded conditioning via VAEEncode).  LoadImage is addressable
 # through a file-stat salt (name + mtime + size), so a re-upload under

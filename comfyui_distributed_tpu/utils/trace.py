@@ -234,7 +234,7 @@ OTHER = "other"
 # first row.  So ``.../attn1/to_q/dot_general`` is attn_proj, the einsum
 # written in ``attn1`` itself (``.../attn1/bnhd,bmhd->bhnm/dot_general``)
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
-_UNET, _VAE, _CLIP = "UNet", "VAE", "CLIPTextModel"
+_UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
     ("norm", None, r"GroupNorm_\d+|LayerNorm_\d+|(?:in_|out_)?norm\d*"
@@ -261,13 +261,28 @@ KERNEL_CLASSES = (
     ("embed", _CLIP, r"token_embedding|position_embedding|text_projection"
                      r"|CLIPTextModel"),
     ("clip_attn", _CLIP, r"layers_\d+"),
+    # the looped language model (models/looplm.py), whose scopes carry the
+    # published modules' names.  Its RMSNorms are ``lm_norm``, not
+    # ``norm``: none of their names matches the first row
+    ("lm_norm", _LM, r"(?:input|post_attention)_layernorm(?:_2)?"
+                     r"|final_norm"),
+    ("lm_proj", _LM, r"[qkvo]_proj"),
+    ("lm_cache", _LM, r"kv_cache"),
+    # QK^T, the mask, the softmax, PV; the rotary embedding
+    ("lm_attn", _LM, r"self_attn|rotary"),
+    ("lm_mlp", _LM, r"mlp|gate_proj|up_proj|down_proj"),
+    ("lm_head", _LM, r"lm_head|early_exit_gate|sample"),
+    ("embed", _LM, r"embed_tokens"),
+    # the layer scan's and the program's own glue (residual adds, the
+    # slice that picks the prompt's last row)
+    ("lm_proj", _LM, r"layers|LoopLM"),
 )
 # the denoise programs' own operations under no module (CFG combine,
 # solver update, noise): ``core`` / ``step`` are the functions
 # models/registry.py jits
 SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
-_MODEL_OF = re.compile(r"^(UNet|VAE|CLIPTextModel)(?:\.\w+)?$")
+_MODEL_OF = re.compile(r"^(UNet|VAE|CLIPTextModel|LoopLM)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 
@@ -452,7 +467,8 @@ class CounterStats:
 GLOBAL_COUNTERS = CounterStats()
 
 # Attention call sites by the path each took (``fused``, ``xla_whole``,
-# ``xla_chunked``, ``ring``): bumped by models/layers.py once per site
+# ``xla_chunked``, ``ring``; ``xla_causal`` and ``xla_decode`` for a
+# language model's masked calls): bumped by models/layers.py once per site
 # while a program is TRACED, never around a jitted call, so a served
 # request adds nothing.  A process's whole life: metrics/reset leaves it,
 # like ``retraces``.

@@ -134,16 +134,16 @@ class WorkflowExecutor:
         graph = workflow if isinstance(workflow, Graph) \
             else parse_workflow(workflow)
         hidden = hidden or {}
-        # cross-request compute reuse (runtime/reuse.py): one pass over
-        # the graph computes each addressable node's input-sub-graph
-        # content hash; the encode ops key their device memo caches on
-        # it.  DTPU_CACHE=0 skips the pass entirely (kill switch).
+        # cross-request compute reuse (runtime/reuse.py): each
+        # addressable node's input-sub-graph content hash, computed as
+        # the walk reaches the node (a STRING another node produced is
+        # then known, and keyed as the text it is); the encode ops key
+        # their device memo caches on it.  DTPU_CACHE=0 skips it
+        # entirely (kill switch).
         from comfyui_distributed_tpu.runtime import reuse as reuse_mod
+        reuse_on = reuse_mod.reuse_enabled()
         reuse_keys: Dict[str, str] = {}
-        if reuse_mod.reuse_enabled():
-            reuse_keys = reuse_mod.subgraph_keys(
-                graph, hidden, input_dir=self.ctx.input_dir,
-                models_dir=self.ctx.models_dir)
+        resolved_text: Dict[Tuple[str, int], str] = {}
         # fresh per-run collection state (assign, don't clear — prior
         # ExecutionResults keep their own lists)
         self.ctx.saved_images = []
@@ -193,6 +193,12 @@ class WorkflowExecutor:
                                              or nid in fan_nodes) else 1
                 node = graph.nodes[nid]
                 op = get_op(node.class_type)
+                if reuse_on:
+                    key = reuse_mod.node_key(
+                        graph, nid, hidden, reuse_keys, self.ctx.input_dir,
+                        self.ctx.models_dir, resolved_text)
+                    if key is not None:
+                        reuse_keys[nid] = key
                 self.ctx.content_key = reuse_keys.get(nid)
                 kwargs: Dict[str, Any] = {}
                 for name, value in node.inputs.items():
@@ -250,6 +256,9 @@ class WorkflowExecutor:
                     # the graph tail (decode/save) does NOT run
                     break
                 timings[nid] = time.perf_counter() - t0
+                for slot, out in enumerate(outputs[nid]):
+                    if isinstance(out, str):
+                        resolved_text[(nid, slot)] = out
                 # per-node-type latency histogram (p50/p95/p99 on
                 # /distributed/metrics and the dtpu_node_seconds family)
                 trace_mod.GLOBAL_NODES.record(node.class_type, timings[nid])

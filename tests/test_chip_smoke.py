@@ -62,6 +62,25 @@ def test_rehearsal_passes_on_the_cpu(tmp_path):
     assert not os.path.exists(tmp_path / "output")
 
 
+def test_lm_phase_holds_the_served_logits_to_the_reference(tmp_path):
+    """``--phases lm``: verify_lm.py as a child (a server of its own, one
+    request of the prompt expander's graph, then the plain reference),
+    tiny on the CPU; the 8-bit readings have to be refused."""
+    r = _run([SMOKE, "--rehearse", "--phases", "lm", "--out",
+              str(tmp_path / "out")], tmp_path, 600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    summary, last = map(json.loads, r.stdout.strip().splitlines())
+    assert last == {"ok": True, "device": summary["device"]}
+    assert summary["phases"] == ["lm"] and summary["rehearsal"] is True
+    lm = summary["smoke_facts"]["language_model"]
+    assert lm["positions"] == 4 and lm["argmax_agree"] == 1.0
+    assert lm["mean_over_std"] <= lm["limits"]["mean_over_std"]
+    assert lm["weights_8bit"] > lm["limits"]["mean_over_std"]
+    assert lm["cache_8bit"] > lm["limits"]["mean_over_std"]
+    with open(tmp_path / "out" / "verify_lm" / "verify_lm.json") as f:
+        assert json.load(f)["ok"] is True
+
+
 def test_default_mode_refuses_a_cpu_pinned_jax(tmp_path):
     """JAX_PLATFORMS=cpu (this sandbox) is not a chip: exit non-zero with
     the reason, before any child starts, and print no result."""

@@ -30,6 +30,9 @@ DENOISE_CLASSES = {"attn_self", "attn_cross", "attn_proj", "ff", "norm",
                    "resblock", "resample", "embed", "sampler"}
 VAE_CLASSES = {"vae_res", "vae_conv", "norm"}      # the tiny VAE has no
 CLIP_CLASSES = {"clip_attn", "clip_mlp", "norm", "embed"}   # mid_attn
+# the looped language model's (PR 26; tests/test_looplm.py holds its rows)
+LM_CLASSES = {"lm_attn", "lm_proj", "lm_mlp", "lm_norm", "lm_cache",
+              "lm_head", "embed"}
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
@@ -51,7 +54,7 @@ def compiled_op_names(fn):
 def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
     classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
     assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
-        | {"vae_attn"}
+        | LM_CLASSES | {"vae_attn"}
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; from comfyui_distributed_tpu.utils.trace import "
