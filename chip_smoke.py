@@ -45,7 +45,9 @@ when named: the language model of ``workflows/prompt-expand-txt2img.json``
 at its published widths, served through ``POST /prompt`` by a server of
 its own, and the logits of that request held to the plain float32
 reference (``benchmarks/chip/verify_lm.py``, which says what is compared
-and why each limit is what it is).
+and why each limit is what it is); then four such requests sent together,
+which the server runs as the four rows of ONE execution, each row held
+to the same reference inside the same limits.
 """
 
 from __future__ import annotations
@@ -540,6 +542,108 @@ def lm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
         f"{served['max_over_std']:.4f} of a standard deviation)")
 
 
+LM_TOGETHER = 4      # requests sent together: one execution's rows
+LM_SEED = 2800000033
+
+
+def lm_together_phase(cfg: dict, out_dir: str, env: dict,
+                      result: dict) -> None:
+    """The batched served path against the reference.  A plain SD1.5
+    request holds the executor (it is the cold one: weights, compiles)
+    while LM_TOGETHER requests of the prompt expander's graph queue
+    behind it, each with ``verify_lm.py``'s save node; the first of them
+    then leads the others through one execution of ``lm_generate``.
+    Each row's logits go through ``verify_lm.py --compare`` (its
+    functions and its limits, in a child: the reference needs the chip
+    the server has given back)."""
+    bench = os.path.join(HERE, "benchmarks", "chip")
+    sys.path.insert(0, bench)
+    import run as chipbench
+    import verify_lm
+    from lib.traffic import Traffic
+    config = chipbench.load_json(os.path.join(
+        bench, "configs", "ouro-2.6b-expand-sd15-512.json"))
+    if cfg["rehearsal"]:
+        config = chipbench.rehearsal_config(config)
+    prefixes = [f"together_{i}" for i in range(LM_TOGETHER)]
+    graphs = []
+    for i, prefix in enumerate(prefixes):
+        # texts of 6, 9, 12, 15 words: rows of different real lengths
+        req = Traffic({"loop": "closed", "text_words": 6 + 3 * i},
+                      config["name"], LM_SEED + i).next_request()
+        graphs.append(verify_lm.verify_graph(config, req["text"],
+                                             req["seed"], prefix))
+    (gen,) = [nid for nid, node in graphs[0].items()
+              if node["class_type"] == "LanguageModelGenerate"]
+    holder = {nid: json.loads(json.dumps(node))
+              for nid, node in graphs[0].items()
+              if not node["class_type"].startswith(("LanguageModel",
+                                                    "SaveLanguageModel"))}
+    for node in holder.values():
+        for name, value in node["inputs"].items():
+            if value == [gen, 0]:
+                node["inputs"][name] = "a plain request that holds the queue"
+    server = Server(os.path.join(out_dir, "lm_together"),
+                    {**env, "DTPU_MESH_SHAPE": "data=1"})
+    try:
+        server.wait_ready()
+        pids = [post_json(f"{server.base}/prompt",
+                          {"prompt": g, "client_id": "chip_smoke"}
+                          )["prompt_id"] for g in [holder] + graphs]
+        deadline = time.monotonic() + 2 * cfg["first_timeout"]
+        while True:
+            server.require_alive()
+            hist = get_json(f"{server.base}/history")
+            if all(p in hist for p in pids):
+                break
+            check(time.monotonic() < deadline,
+                  f"the requests sent together never finished:\n"
+                  f"{server.log_tail()}")
+            time.sleep(0.25)
+        check(all(hist[p].get("status") == "success" for p in pids),
+              f"a request sent together ended "
+              f"{[hist[p] for p in pids]}:\n{server.log_tail()}")
+        counters = server.metrics()["pipeline"]["counters"]
+        rc = server.shut_down()
+        check(rc == 0, f"server child exited with code {rc}")
+    finally:
+        server.kill()
+    shared = {k: counters.get(f"lm.{k}", 0) for k in
+              ("executions", "rows", "padded_rows", "followers_served",
+               "followers_dropped")}
+    check(shared == {"executions": 1, "rows": LM_TOGETHER, "padded_rows": 0,
+                     "followers_served": LM_TOGETHER - 1,
+                     "followers_dropped": 0},
+          f"{LM_TOGETHER} requests sent together did not run as one "
+          f"execution: {shared}")
+    paths = [os.path.join(server.cwd, "output", f"{p}.npz")
+             for p in prefixes]
+    cmd = [sys.executable, os.path.join(bench, "verify_lm.py"),
+           "--compare", *paths] + (["--rehearse"] if cfg["rehearsal"]
+                                   else [])
+    proc = subprocess.run(cmd, cwd=HERE, env=env, capture_output=True,
+                          text=True, timeout=2 * cfg["first_timeout"])
+    lines = proc.stdout.strip().splitlines()
+    check(proc.returncode == 0 and bool(lines),
+          f"verify_lm --compare failed (exit {proc.returncode}):\n"
+          f"{proc.stderr[-3000:]}")
+    report = json.loads(lines[-1])
+    check(report["ok"] and len(report["served"]) == LM_TOGETHER,
+          f"a row of the shared execution is outside a limit: "
+          f"{json.dumps(report)}")
+    result["smoke_facts"]["language_model_together"] = shared | {
+        "served": [{key: row[key] for key in
+                    ("prompt_ids", "positions", "max_over_std",
+                     "mean_over_std", "margin_over_std", "argmax_agree")}
+                   for row in report["served"]],
+        "limits": report["served"][0]["limits"]}
+    say(f"lm: {LM_TOGETHER} requests as the rows of one execution, each "
+        f"within the limits (mean "
+        f"{[round(r['mean_over_std'], 5) for r in report['served']]}, max "
+        f"{[round(r['max_over_std'], 4) for r in report['served']]} of a "
+        f"standard deviation)")
+
+
 # --- main --------------------------------------------------------------------
 
 def configuration(rehearse: bool) -> dict:
@@ -625,6 +729,7 @@ def main() -> int:
             kernel_phase(cfg, out_dir, env, summary)
         if "lm" in phases:
             lm_phase(cfg, out_dir, env, summary)
+            lm_together_phase(cfg, out_dir, env, summary)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

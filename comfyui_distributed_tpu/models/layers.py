@@ -187,7 +187,8 @@ def attention_path(platform: str, b: int, n: int, m: int, h: int,
 
 def scaled_dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                  impl: str = "xla",
-                                 q_positions: Optional[jax.Array] = None
+                                 q_positions: Optional[jax.Array] = None,
+                                 kv_start: Optional[jax.Array] = None
                                  ) -> jax.Array:
     """[B, N, H, D] attention, fp32 softmax accumulation.
 
@@ -195,7 +196,8 @@ def scaled_dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     index is at most ``q_positions[i]``.  That is both a causal prefill
     (``arange(N)`` against the call's own keys) and a decode step (one
     query at position ``t`` against a cache of any length, valid to
-    ``t``).
+    ``t``).  With ``kv_start [B]`` beside it, row ``b`` sees no key in
+    front of index ``kv_start[b]`` either (a left-padded row's padding).
 
     ``impl="xla"`` — what every model config carries — leaves the choice
     to `attention_path`: the fused Pallas kernel for the large
@@ -220,7 +222,7 @@ def scaled_dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ATTENTION_PATHS.bump(path)
     if path == "fused":
         return _fused_on_mesh(q, k, v, mesh)
-    return xla_attention(q, k, v, 1.0 / math.sqrt(D), q_positions)
+    return xla_attention(q, k, v, 1.0 / math.sqrt(D), q_positions, kv_start)
 
 
 def _live_mesh():
@@ -260,23 +262,27 @@ def _fused_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
                        scale: float,
-                       q_positions: Optional[jax.Array] = None) -> jax.Array:
+                       q_positions: Optional[jax.Array] = None,
+                       kv_start: Optional[jax.Array] = None) -> jax.Array:
     """One materialized-score attention block (einsum -> fp32 softmax ->
     einsum); with ``q_positions [n]`` each query's keys end at its own
-    position."""
+    position, and with ``kv_start [b]`` a row's begin at its own index."""
     logits = jnp.einsum("bnhd,bmhd->bhnm", q, k,
                         preferred_element_type=jnp.float32) * scale
     if q_positions is not None:
-        seen = jnp.arange(k.shape[1])[None, :] <= q_positions[:, None]
-        logits = jnp.where(seen[None, None], logits,
-                           jnp.finfo(jnp.float32).min)
+        at = jnp.arange(k.shape[1])
+        seen = (at[None, :] <= q_positions[:, None])[None, None]
+        if kv_start is not None:
+            seen = seen & (at >= kv_start[:, None])[:, None, None, :]
+        logits = jnp.where(seen, logits, jnp.finfo(jnp.float32).min)
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhnm,bmhd->bnhd", weights.astype(v.dtype), v)
 
 
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   scale: float,
-                  q_positions: Optional[jax.Array] = None) -> jax.Array:
+                  q_positions: Optional[jax.Array] = None,
+                  kv_start: Optional[jax.Array] = None) -> jax.Array:
     """The reference attention math with a memory ceiling: the path of
     everything `attention_path` does not send to the flash kernel, and
     the oracle the kernel is checked against.
@@ -291,7 +297,7 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, N, H, D = q.shape
     chunk = _query_chunk(B, N, k.shape[1], H)
     if chunk is None:
-        return _attn_scores_block(q, k, v, scale, q_positions)
+        return _attn_scores_block(q, k, v, scale, q_positions, kv_start)
     n_chunks = N // chunk
     qr = q.reshape(B, n_chunks, chunk, H, D).transpose(1, 0, 2, 3, 4)
     pos = None if q_positions is None \
@@ -299,7 +305,7 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def body(_, qc):
         qc, pc = qc
-        return None, _attn_scores_block(qc, k, v, scale, pc)
+        return None, _attn_scores_block(qc, k, v, scale, pc, kv_start)
 
     _, out = jax.lax.scan(body, None, (qr, pos))
     return out.transpose(1, 0, 2, 3, 4).reshape(B, N, H, D)

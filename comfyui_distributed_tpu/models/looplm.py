@@ -25,6 +25,15 @@ layers' weights are stacked on a leading ``L`` axis and scanned
 (``lax.scan`` over ``l``, a Python loop over ``r``): the 192 layer
 applications of the 2.6 B model trace and compile as one body.
 
+The rows of a call are requests of different people: each has its own
+real length, seed and temperature, and a decode step reads the weights
+once for all of them.  Inside the program every row's prompt is moved to
+the END of the prompt buffer, so that all rows write the cache at one
+shared index (one in-place ``dynamic_update_slice`` a slot, whatever the
+rows' lengths); a row's padding then lies in front of it and a per-row
+lower bound in the mask hides it.  RoPE is given each row's own
+positions, so a row's numbers are those of its single-row run.
+
 Precision: weights, cache and matmul operands in ``cfg.dtype`` (bf16 for
 the published model); the residual stream, every RMSNorm, RoPE, the
 softmax and the logits in float32; every matmul accumulates in float32.
@@ -187,11 +196,11 @@ def _dense(x, kernel, cfg):
 
 def _rope(x, positions, theta):
     """Rotary embedding, the ``rotate_half`` convention, in float32:
-    ``x [B, N, H, D]``, ``positions [N]``."""
+    ``x [B, N, H, D]``, ``positions [B, N]`` (each row's own)."""
     half = x.shape[-1] // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1)
@@ -199,7 +208,7 @@ def _rope(x, positions, theta):
 
 def _qkv(cfg: LoopLMConfig, lp, x, positions):
     """This call's queries, keys and values, ``[B, N, H, D]`` in the
-    model's dtype, keys and queries rotated to ``positions``."""
+    model's dtype, keys and queries rotated to ``positions [B, N]``."""
     B, N, _ = x.shape
     heads = (B, N, cfg.num_attention_heads, cfg.head_dim)
     with jax.named_scope("input_layernorm"):
@@ -218,13 +227,15 @@ def _qkv(cfg: LoopLMConfig, lp, x, positions):
                                None) for t in (q, k, v))
 
 
-def _attend_and_mlp(cfg: LoopLMConfig, lp, x, q, k, v, positions):
-    """The rest of the layer: ``q`` (at ``positions``) against the keys
-    and values ``k``, ``v`` whose index is their position, then the two
-    sandwich-normed residual updates."""
+def _attend_and_mlp(cfg: LoopLMConfig, lp, x, q, k, v, index, first):
+    """The rest of the layer: ``q`` (at the buffer indices ``index``)
+    against the keys and values ``k``, ``v`` from each row's ``first``
+    real index up to the query's own, then the two sandwich-normed
+    residual updates."""
     B, N, _ = x.shape
     with jax.named_scope("self_attn"):
-        a = scaled_dot_product_attention(q, k, v, q_positions=positions)
+        a = scaled_dot_product_attention(q, k, v, q_positions=index,
+                                         kv_start=first)
         with jax.named_scope("o_proj"):
             a = _dense(a.reshape(B, N, -1), lp["o_proj"], cfg)
     with jax.named_scope("input_layernorm_2"):
@@ -244,18 +255,23 @@ def _attend_and_mlp(cfg: LoopLMConfig, lp, x, q, k, v, positions):
                          cfg.rms_norm_eps)
 
 
-def _stack(cfg: LoopLMConfig, params, x, positions, cache, use_cache: bool):
+def _stack(cfg: LoopLMConfig, params, x, index, first, cache,
+           use_cache: bool):
     """All ``R`` loops over the ``L`` layers.  ``cache`` is the pair of
     ``[R, L, B, T, H, D]`` key and value buffers; every layer application
-    writes this call's entries into its own slot ``(r, l)`` at
-    ``positions[0]`` onward.  With ``use_cache`` the queries attend to the
-    slot (a decode step: one query against everything up to its
-    position), without to this call's own keys (the prefill: causal among
-    the prompt).  Returns the last loop's normed state, the exit
+    writes this call's entries into its own slot ``(r, l)`` at the buffer
+    indices ``index [N]`` (consecutive, the same for every row: the
+    update stays in place).  Row ``b``'s real entries start at index
+    ``first[b]``, which is its position 0: what lies in front is padding
+    and never attended to.  With ``use_cache`` the queries attend to the
+    slot (a decode step: one query against everything up to its index),
+    without to this call's own keys (the prefill: causal among the
+    prompt).  Returns the last loop's normed state, the exit
     probabilities ``[B, N, R]`` and the cache."""
     B, N, _ = x.shape
     T = cache[0].shape[3]
     slot = (1, 1, B, T, cfg.num_attention_heads, cfg.head_dim)
+    positions = index[None, :] - first[:, None]
     exits = []
     for r in range(cfg.total_ut_steps):
         def layer(carry, xs, r=r):
@@ -266,16 +282,16 @@ def _stack(cfg: LoopLMConfig, params, x, positions, cache, use_cache: bool):
                 at = _cache_slot(r, l)
                 kc = jax.lax.dynamic_update_slice(
                     kc, k[None, None].astype(kc.dtype),
-                    (*at, 0, positions[0], 0, 0))
+                    (*at, 0, index[0], 0, 0))
                 vc = jax.lax.dynamic_update_slice(
                     vc, v[None, None].astype(vc.dtype),
-                    (*at, 0, positions[0], 0, 0))
+                    (*at, 0, index[0], 0, 0))
                 if use_cache:
                     k = jax.lax.dynamic_slice(
                         kc, (*at, 0, 0, 0, 0), slot)[0, 0].astype(cfg.dtype)
                     v = jax.lax.dynamic_slice(
                         vc, (*at, 0, 0, 0, 0), slot)[0, 0].astype(cfg.dtype)
-            x = _attend_and_mlp(cfg, lp, x, q, k, v, positions)
+            x = _attend_and_mlp(cfg, lp, x, q, k, v, index, first)
             return (x, kc, vc), None
 
         with jax.named_scope("layers"):
@@ -319,46 +335,54 @@ def kv_cache_bytes(cfg: LoopLMConfig, batch: int, length: int) -> int:
 def generate(cfg: LoopLMConfig, max_new_tokens: int, params, prompt_ids,
              prompt_len, seed, temperature
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Prefill, then ``max_new_tokens`` decode steps.
+    """Prefill, then ``max_new_tokens`` decode steps, for every row.
 
-    ``prompt_ids [B, P]`` holds ``prompt_len`` real ids (a scalar, the
-    same for every row) and padding behind them.  Token ``i`` is drawn
-    from the logits at position ``prompt_len + i - 1``: greedy where
-    ``temperature`` is 0, else sampled at that temperature from the key
-    ``seed`` gives.  Padded rows of the prefill are never attended to:
-    the causal mask hides them from the real rows, and decode step ``i``
-    overwrites cache position ``prompt_len + i`` before any query can see
-    it.  Returns the new ids ``[B, N]``, the float32 logits each was
-    drawn from ``[B, N, V]`` and the exit probabilities ``[B, N, R]``.
+    ``prompt_ids [B, P]`` holds in row ``b`` ``prompt_len[b]`` real ids
+    and padding behind them; ``prompt_len``, ``seed`` and ``temperature``
+    are ``[B]``, or scalars where every row has the same.  Token ``i`` of
+    a row is drawn from the logits at its position ``prompt_len + i - 1``:
+    greedy where its ``temperature`` is 0, else sampled at that
+    temperature from the key its ``seed`` gives.  A row's numbers do not
+    depend on what the other rows hold.  Padding is never attended to
+    (`_stack`).  Returns the new ids ``[B, N]``, the float32 logits each
+    was drawn from ``[B, N, V]`` and the exit probabilities ``[B, N, R]``.
     """
     B, P = prompt_ids.shape
-    key = jax.random.PRNGKey(seed)
+    prompt_len, seed, temperature = (
+        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
+    first = P - prompt_len
+    keys = jax.vmap(jax.random.PRNGKey)(seed)
+
+    def draw(key, logits, temperature, i):
+        drawn = jax.random.categorical(
+            jax.random.fold_in(key, i),
+            logits / jnp.maximum(temperature, 1e-6))
+        return jnp.where(temperature > 0, drawn,
+                         jnp.argmax(logits)).astype(jnp.int32)
+
     with jax.named_scope("LoopLM"):
+        # every row's last real id at P - 1
+        prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
         cache = empty_cache(cfg, B, P + max_new_tokens)
         x, exits, cache = _stack(cfg, params, _embed(params, prompt_ids),
-                                 jnp.arange(P), cache, use_cache=False)
-        last = (0, prompt_len - 1, 0)
-        x = jax.lax.dynamic_slice(x, last, (B, 1, x.shape[-1]))
-        exits = jax.lax.dynamic_slice(exits, last, (B, 1, exits.shape[-1]))
-        logits = _head(cfg, params, x)[:, 0]
+                                 jnp.arange(P), first, cache,
+                                 use_cache=False)
+        logits = _head(cfg, params, x[:, P - 1:])[:, 0]
 
         def step(carry, i):
             logits, exits, cache = carry
             with jax.named_scope("sample"):
-                drawn = jax.random.categorical(
-                    jax.random.fold_in(key, i),
-                    logits / jnp.maximum(temperature, 1e-6), axis=-1)
-                token = jnp.where(temperature > 0, drawn,
-                                  jnp.argmax(logits, axis=-1)
-                                  ).astype(jnp.int32)
+                token = jax.vmap(draw, (0, 0, 0, None))(
+                    keys, logits, temperature, i)
             x, nxt_exits, cache = _stack(
-                cfg, params, _embed(params, token[:, None]),
-                prompt_len + i[None], cache, use_cache=True)
+                cfg, params, _embed(params, token[:, None]), P + i[None],
+                first, cache, use_cache=True)
             nxt = _head(cfg, params, x)[:, 0]
             return (nxt, nxt_exits[:, 0], cache), (token, logits, exits)
 
         _, (tokens, logits, exits) = jax.lax.scan(
-            step, (logits, exits[:, 0], cache), jnp.arange(max_new_tokens))
+            step, (logits, exits[:, P - 1], cache),
+            jnp.arange(max_new_tokens))
     return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
             exits.swapaxes(0, 1))
 

@@ -17,8 +17,9 @@ import dataclasses
 import hashlib
 import os
 import threading
+import time
 from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1289,13 +1290,42 @@ def detect_lm_family(name: str) -> str:
 
 @dataclasses.dataclass
 class LMOutput:
-    """What a generation leaves on the device: the prompt's real ids, the
-    new ids ``[B, N]``, the float32 logits each was drawn from
-    ``[B, N, V]`` and the exit probabilities ``[B, N, R]``."""
+    """What a generation leaves on the device: the prompt's real ids, and
+    of the execution that served it the new ids ``[B, N]``, the float32
+    logits each was drawn from ``[B, N, V]`` and the exit probabilities
+    ``[B, N, R]``; ``row`` is this request's."""
     prompt_ids: np.ndarray
     tokens: Any
     logits: Any
     exit_probs: Any
+    row: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class LMRow:
+    """One request's call of the generate node: a row of an execution."""
+    text: str
+    seed: int = 0
+    temperature: float = 0.0
+
+
+# The row counts ``lm_generate`` is compiled for, all of them when the
+# first request of a length meets the model (no shape is built later:
+# a served window compiles nothing).  An execution is padded to the next
+# count with copies of its first row.  Why 4 and no more: a decode step
+# streams 20 GB of weights for all rows and 0.2 GB of cache for each, the
+# cache is 0.2 GB a row beside 8 GB resident on a 16 GB chip, and every
+# count is one more program to trace, lower and load at set-up.  Why no
+# count between: what sharing costs is the step from one row to more than
+# one, not the rows.  On the chip an execution takes 1.93 s at 1 row and
+# 2.18 s at 4, all of the difference in the attention sub-layer (from two
+# rows up XLA lowers its projections another way; the MLP's stream takes
+# the same 1.18 s), and with (1, 2, 4) the four-caller cell, whose
+# executions carry two real rows, gained 0.5% in images a second for a
+# third program at set-up: about 2.17 s at 2 rows (PERF.md section 6,
+# PR 28).  The last count also bounds what is kept for requests still
+# in the queue (server/lm_handover.py): three results.
+LM_ROW_COUNTS = (1, 4)
 
 
 class LanguageModel:
@@ -1304,7 +1334,8 @@ class LanguageModel:
     def __init__(self, name: str, cfg: Any, params: Any, tokenizer: Any):
         self.name, self.cfg, self.params = name, cfg, params
         self.tokenizer = tokenizer
-        self._programs: Dict[int, Any] = {}
+        # (new tokens, prompt positions) -> {rows: compiled lm_generate}
+        self._programs: Dict[Tuple[int, int], Dict[int, Any]] = {}
         self._mesh = None
         self._lock = threading.Lock()
 
@@ -1324,53 +1355,114 @@ class LanguageModel:
             self._mesh = mesh
             self._programs.clear()
 
-    def generate(self, text: str, seed: int = 0, max_new_tokens: int = 64,
-                 prompt_tokens: int = 64, temperature: float = 0.0
-                 ) -> Tuple[str, LMOutput]:
-        """The continuation of ``text`` under the expander's template:
-        one execution of ``lm_generate`` (the prompt padded to
-        ``prompt_tokens``, then exactly ``max_new_tokens`` decode steps;
-        the end-of-text id does not stop it, so one shape runs).  The
-        host meets the device here, in the middle of a graph: the ids
-        have to be words before the text encoder can be enqueued."""
-        from comfyui_distributed_tpu.models import looplm
-        self._ensure_laid_out()
+    def prompt_ids(self, text: str, prompt_tokens: int) -> np.ndarray:
+        """The real ids of ``text`` under the expander's template, cut to
+        ``prompt_tokens``; refused here where the device would clamp an
+        index out of range in silence."""
         ids = self.tokenizer.encode(EXPAND_TEMPLATE.format(text=text))
         ids = np.asarray(ids[:prompt_tokens], np.int32)
-        # an index out of range raises nothing on the device: it is clamped
-        if max_new_tokens < 1 or not len(ids) or ids.min() < 0 \
-                or ids.max() >= self.cfg.vocab_size:
+        if not len(ids) or ids.min() < 0 or ids.max() >= self.cfg.vocab_size:
             raise ValueError(
                 f"{self.name}: a prompt of {len(ids)} ids in "
-                f"[{ids.min(initial=0)}, {ids.max(initial=0)}] and "
-                f"{max_new_tokens} new tokens cannot be generated from a "
-                f"vocabulary of {self.cfg.vocab_size}")
-        padded = np.full((1, prompt_tokens), self.tokenizer.pad_id, np.int32)
-        padded[0, :len(ids)] = ids
-        n = int(max_new_tokens)
+                f"[{ids.min(initial=0)}, {ids.max(initial=0)}] cannot be "
+                f"generated from a vocabulary of {self.cfg.vocab_size}")
+        return ids
+
+    def _compiled(self, n: int, prompt_tokens: int) -> Dict[int, Any]:
+        """``lm_generate`` for ``n`` new tokens behind ``prompt_tokens``
+        positions, compiled for every count of LM_ROW_COUNTS at once."""
+        from comfyui_distributed_tpu.models import looplm
         with self._lock:
-            program = self._programs.get(n)
-            if program is None:
-                program = self._programs[n] = looplm.make_generate(
-                    self.cfg, n)
+            programs = self._programs.get((n, prompt_tokens))
+            if programs is None:
+                jitted = looplm.make_generate(self.cfg, n)
+
+                def row(dtype, *shape):
+                    return jax.ShapeDtypeStruct(shape, dtype)
+
+                programs = self._programs[(n, prompt_tokens)] = {
+                    b: jitted.lower(
+                        self.params, row(np.int32, b, prompt_tokens),
+                        row(np.int32, b), row(np.uint32, b),
+                        row(np.float32, b)).compile()
+                    for b in LM_ROW_COUNTS}
+        return programs
+
+    def generate_rows(self, rows: Sequence[LMRow], max_new_tokens: int = 64,
+                      prompt_tokens: int = 64,
+                      spans: Sequence[Any] = ()
+                      ) -> List[Tuple[str, LMOutput]]:
+        """The continuations of the rows' texts under the expander's
+        template: ONE execution of ``lm_generate`` for all of them (each
+        prompt padded to ``prompt_tokens``, then exactly
+        ``max_new_tokens`` decode steps; the end-of-text id does not stop
+        it, so one shape runs), each row with its own length, seed and
+        temperature.  The host meets the device here, in the middle of a
+        graph: the ids have to be words before the text encoder can be
+        enqueued.
+
+        Counted per request served, so that tokens over stages stays the
+        steps of one execution: the first row is the caller's and its
+        ``lm_generate`` stage lies on the current span; row ``i`` behind
+        it is a request still in the queue whose root span is
+        ``spans[i - 1]``, and gets the same interval there."""
+        from comfyui_distributed_tpu.models import looplm
+        self._ensure_laid_out()
+        n, real = int(max_new_tokens), len(rows)
+        if n < 1 or not 1 <= real <= LM_ROW_COUNTS[-1]:
+            raise ValueError(
+                f"{self.name}: {n} new tokens for {real} row(s) cannot be "
+                f"generated (at least 1 token, 1 to {LM_ROW_COUNTS[-1]} "
+                f"rows)")
+        ids = [self.prompt_ids(r.text, prompt_tokens) for r in rows]
+        count = next(b for b in LM_ROW_COUNTS if b >= real)
+        # a padded row repeats the first
+        source = [*range(real), *[0] * (count - real)]
+        padded = np.full((count, prompt_tokens), self.tokenizer.pad_id,
+                         np.int32)
+        for b, i in enumerate(source):
+            padded[b, :len(ids[i])] = ids[i]
+        program = self._compiled(n, prompt_tokens)[count]
+        t0 = time.time()
         with trace_mod.stage("lm_generate"):
             tokens, logits, exits = program(
-                self.params, padded, np.int32(len(ids)),
-                np.uint32(int(seed) & 0xFFFFFFFF), np.float32(temperature))
+                self.params, padded,
+                np.asarray([len(ids[i]) for i in source], np.int32),
+                np.asarray([rows[i].seed & 0xFFFFFFFF for i in source],
+                           np.uint32),
+                np.asarray([rows[i].temperature for i in source],
+                           np.float32))
             with trace_mod.device_wait():
                 # dtpu-lint: ignore[spine-host-fetch] ids must be words before CLIP can run
                 host_tokens = np.asarray(jax.device_get(tokens))
-        trace_mod.mark_instant("lm_ids_ready")
-        with trace_mod.stage("detokenize"):
-            words = self.tokenizer.decode(host_tokens[0])
-        trace_mod.GLOBAL_COUNTERS.bump("lm.prompt_tokens", len(ids))
-        trace_mod.GLOBAL_COUNTERS.bump("lm.tokens_decoded", n)
-        trace_mod.GLOBAL_COUNTERS.bump(
-            "lm.layer_applications", n * self.cfg.cache_slots)
+        t1 = time.time()
+        trace_mod.mark_instant("lm_ids_ready", at=t1)
+        for span in spans:
+            trace_mod.record_stage("lm_generate", t0, t1, parent=span)
+            trace_mod.mark_instant("lm_ids_ready", span, t1)
+        out = []
+        for b in range(real):
+            with trace_mod.stage("detokenize"):
+                words = self.tokenizer.decode(host_tokens[b])
+            out.append((words, LMOutput(ids[b], tokens, logits, exits, b)))
+        bump = trace_mod.GLOBAL_COUNTERS.bump
+        bump("lm.prompt_tokens", sum(len(i) for i in ids))
+        bump("lm.tokens_decoded", n * real)
+        bump("lm.layer_applications", n * real * self.cfg.cache_slots)
+        bump("lm.executions")
+        bump("lm.rows", real)
+        bump("lm.padded_rows", count - real)
         trace_mod.GLOBAL_GAUGES.set(
             "lm.kv_cache_bytes",
-            looplm.kv_cache_bytes(self.cfg, 1, prompt_tokens + n))
-        return words, LMOutput(ids, tokens, logits, exits)
+            looplm.kv_cache_bytes(self.cfg, count, prompt_tokens + n))
+        return out
+
+    def generate(self, text: str, seed: int = 0, max_new_tokens: int = 64,
+                 prompt_tokens: int = 64, temperature: float = 0.0
+                 ) -> Tuple[str, LMOutput]:
+        """One request alone: `generate_rows` of one row."""
+        return self.generate_rows([LMRow(text, int(seed), temperature)],
+                                  max_new_tokens, prompt_tokens)[0]
 
 
 def load_language_model(name: str, models_dir: Optional[str] = None

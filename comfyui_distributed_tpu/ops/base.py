@@ -158,6 +158,10 @@ class OpContext:
     # sub-graph memo tiers (CLIPTextEncode embeddings, VAEEncode
     # conditioning latents) key their device caches on it
     content_key: Optional[str] = None
+    # a server's look into its own queue for a LanguageModelGenerate node
+    # (server/lm_handover.py): the requests waiting behind this one join
+    # its execution.  None outside a server: the node runs its one row.
+    lm_handover: Any = None
 
     def check_interrupt(self):
         if self.interrupt_event is not None and self.interrupt_event.is_set():

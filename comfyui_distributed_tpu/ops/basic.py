@@ -1036,18 +1036,58 @@ class LanguageModelGenerate(Op):
         ctx.check_interrupt()
         base = seed.base if isinstance(seed, SeedValue) else seed
         # dtpu-lint: ignore[spine-host-fetch] a widget's number, never a device value
-        temperature = float(temperature)
-        words, out = model.generate(
-            str(text), seed=int(base), max_new_tokens=int(max_new_tokens),
-            prompt_tokens=int(prompt_tokens), temperature=temperature)
+        row = registry.LMRow(str(text), int(base), float(temperature))
+        n, p = int(max_new_tokens), int(prompt_tokens)
+        if ctx.lm_handover is None:
+            words, out = model.generate_rows([row], n, p)[0]
+        else:
+            # a server: one execution for this request and for those
+            # waiting in its queue (server/lm_handover.py)
+            words, out = ctx.lm_handover.generate(model, row, n, p)
         return (f"{text}, {words}", out)
+
+    @classmethod
+    def literal_call(cls, graph, node, is_worker: bool = False):
+        """What `execute` will be called with, read from the graph alone:
+        ``(model name, LMRow, max_new_tokens, prompt_tokens)``, or None
+        where an input is not a literal.  The model is a
+        ``LanguageModelLoader``'s; the seed may be a ``DistributedSeed``'s
+        on a master, which passes its widget through."""
+        links = node.link_inputs()
+
+        def source(name, class_type):
+            src = graph.nodes.get(str(links[name][0])) if name in links \
+                else None
+            literal = src is not None and src.class_type == class_type \
+                and not src.hidden
+            return src if literal else None
+
+        inputs = {**cls.DEFAULTS, **node.inputs}
+        loader = source("model", "LanguageModelLoader")
+        if loader is None or node.hidden:
+            return None
+        name = {**LanguageModelLoader.DEFAULTS, **loader.inputs}["model_name"]
+        seed = inputs["seed"]
+        seeded = source("seed", "DistributedSeed")
+        if seeded is not None and not is_worker:
+            seed = seeded.inputs.get("seed")
+        numbers = (seed, inputs["max_new_tokens"], inputs["prompt_tokens"],
+                   inputs["temperature"])
+        if not isinstance(name, str) or not isinstance(inputs["text"], str) \
+                or not all(isinstance(x, (int, float))
+                           and not isinstance(x, bool) for x in numbers):
+            return None
+        # dtpu-lint: ignore[spine-host-fetch] a number of the graph's JSON, never a device value
+        temperature = float(inputs["temperature"])
+        return (name, registry.LMRow(inputs["text"], int(seed), temperature),
+                int(inputs["max_new_tokens"]), int(inputs["prompt_tokens"]))
 
 
 @register_op
 class SaveLanguageModelOutput(Op):
     """LM_OUTPUT -> ``<filename_prefix>.npz`` in the output directory:
     ``prompt_ids``, ``tokens [N]``, ``logits [N, V]`` float32 and
-    ``exit_probs [N, R]`` of the first row, for comparison with a
+    ``exit_probs [N, R]`` of the request's row, for comparison with a
     reference (benchmarks/chip/verify_lm.py)."""
     TYPE = "SaveLanguageModelOutput"
     WIDGETS = ["filename_prefix"]
@@ -1064,8 +1104,9 @@ class SaveLanguageModelOutput(Op):
             # dtpu-lint: ignore[spine-host-fetch] an OUTPUT node's host edge
             tokens, logits, exits = jax.device_get(
                 (lm_output.tokens, lm_output.logits, lm_output.exit_probs))
-        np.savez(path, prompt_ids=lm_output.prompt_ids, tokens=tokens[0],
-                 logits=logits[0], exit_probs=exits[0])
+        row = lm_output.row
+        np.savez(path, prompt_ids=lm_output.prompt_ids, tokens=tokens[row],
+                 logits=logits[row], exit_probs=exits[row])
         return ()
 
 

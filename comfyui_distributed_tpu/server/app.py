@@ -65,6 +65,7 @@ from comfyui_distributed_tpu.runtime import cluster as cluster_mod
 from comfyui_distributed_tpu.runtime import reuse as reuse_mod
 from comfyui_distributed_tpu.runtime import shard as shard_mod
 from comfyui_distributed_tpu.runtime.jobs import JobStore
+from comfyui_distributed_tpu.server.lm_handover import GenerateHandover
 from comfyui_distributed_tpu.utils import chaos as chaos_mod
 from comfyui_distributed_tpu.runtime.manager import (
     WorkerProcessManager,
@@ -230,6 +231,9 @@ class ServerState:
         # group, and set it again — no race against the pop
         self._exec_gate = threading.Event()
         self._exec_gate.set()
+        # what a LanguageModelGenerate node sees of this queue, and the
+        # rows it ran for requests still in it (server/lm_handover.py)
+        self.lm_handover = GenerateHandover(self)
         self._running = False
         self._draining = False
         self._history: Dict[str, Any] = {}
@@ -618,6 +622,7 @@ class ServerState:
                 cluster=self.cluster,
                 ledger=self.ledger,
                 fault_inject=self.fault_inject,
+                lm_handover=self.lm_handover,
             )
             first = group[0]
             trace_mod.GLOBAL_COUNTERS.bump("exec_runs")
@@ -764,6 +769,11 @@ class ServerState:
         """Join deferred host edges, split per-prompt results, write
         history/metrics, drop orphan tile queues, seal the group's job
         traces into the flight recorder (+ the slow-job log line)."""
+        # every way a prompt ends passes here (run, failed, purged as
+        # abandoned, a CB slot): what a generate node kept for it and it
+        # never asked for goes now
+        for item in group:
+            self.lm_handover.drop(item["id"])
         if res is not None and err is None:
             try:
                 # the join runs under the head job's span so the
@@ -1003,6 +1013,7 @@ class ServerState:
                 self._inflight.discard(item["id"])
         done_t = time.time()
         for item in purged:
+            self.lm_handover.drop(item["id"])
             self._abandon_span(item.get("span"), item["id"],
                                "cancelled: server drain timeout")
             self._history[item["id"]] = {

@@ -1,0 +1,141 @@
+"""One weight stream for the prompt expander's waiting requests.
+
+A decode step of the language model reads all its weights to make one
+token; a second, third or fourth row through the same step costs that
+row's cache and nothing else.  So when a request reaches its
+``LanguageModelGenerate`` node, ``lm_generate`` runs ONCE over that
+request (the leader) and over the requests still waiting in the server's
+queue whose graphs hold a generate call of the same model and lengths
+with literal inputs (the followers).  A follower stays where it is in
+the queue: its class, its place and `pop_fair_group` know nothing of
+this.  Its words and its ``LM_OUTPUT`` row are kept here and handed over,
+once, when its own graph reaches the node.
+
+What is kept is keyed on everything the result is a function of (model,
+lengths, text, seed, temperature), read again from the values the
+follower's node is really called with: a request that was edited, or
+whose inputs the graph alone did not give away, finds nothing under its
+key and runs on its own.  At most ``LM_ROW_COUNTS[-1] - 1`` results are
+kept at a time; a follower that ends any other way (purged as abandoned,
+cancelled by a drain, run by the step executor, failed before its node)
+has its result dropped when it is finalized, or is never kept if it left
+the queue while the execution ran.  An error in a shared execution is the
+leader's: nothing is kept, and each follower runs alone at its turn.
+"""
+
+from __future__ import annotations
+
+import threading
+from typing import Any, Dict, List, Optional, Tuple
+
+from comfyui_distributed_tpu.models.registry import LM_ROW_COUNTS, LMRow
+from comfyui_distributed_tpu.ops.base import get_op
+from comfyui_distributed_tpu.utils import trace as trace_mod
+from comfyui_distributed_tpu.workflow.graph import parse_workflow
+
+NODE = "LanguageModelGenerate"
+
+
+class GenerateHandover:
+    """What a generate node sees of the server's queue (``OpContext.
+    lm_handover``), and where the rows it ran for queued requests wait
+    for them."""
+
+    def __init__(self, state: Any):
+        self._state = state
+        self._lock = threading.Lock()
+        # (prompt id, key, (words, LMOutput)) in the order they were made
+        self._kept: List[Tuple[str, tuple, tuple]] = []  # guarded-by: self._lock
+
+    def generate(self, model: Any, row: LMRow, max_new_tokens: int,
+                 prompt_tokens: int) -> tuple:
+        """``(words, LMOutput)`` for the request whose node this is: what
+        an earlier execution kept for it, else one execution over it and
+        over whoever waits behind it."""
+        def key(of: LMRow) -> tuple:
+            return (model.name, max_new_tokens, prompt_tokens, of)
+
+        with self._lock:
+            at = next((i for i, kept in enumerate(self._kept)
+                       if kept[1] == key(row)), None)
+            mine = self._kept.pop(at)[2] if at is not None else None
+        if mine is not None:
+            trace_mod.GLOBAL_COUNTERS.bump("lm.followers_served")
+            return mine
+        waiting = self._waiting(model, max_new_tokens, prompt_tokens)
+        results = model.generate_rows(
+            [row] + [w[1] for w in waiting], max_new_tokens, prompt_tokens,
+            spans=[w[2] for w in waiting])
+        made = [(pid, key(theirs), result) for (pid, theirs, _), result
+                in zip(waiting, results[1:])]
+        # a follower that left the queue while the execution ran (a drain
+        # that timed out) was finalized before this was made, and nobody
+        # would drop it later.  Under the lock `drop` takes: a purge lands
+        # wholly before this or wholly after
+        with self._lock:
+            with self._state._queue_lock:
+                queued = {item["id"] for item in self._state._queue}
+            self._kept += [m for m in made if m[0] in queued]
+        gone = sum(m[0] not in queued for m in made)
+        if gone:
+            trace_mod.GLOBAL_COUNTERS.bump("lm.followers_dropped", gone)
+        return results[0]
+
+    def drop(self, pid: str) -> None:
+        """The request left the queue without reaching its node, or has
+        run: nothing is kept for it any longer."""
+        with self._lock:
+            before = len(self._kept)
+            self._kept = [k for k in self._kept if k[0] != pid]
+            dropped = before - len(self._kept)
+        if dropped:
+            trace_mod.GLOBAL_COUNTERS.bump("lm.followers_dropped", dropped)
+
+    def kept(self) -> int:
+        with self._lock:
+            return len(self._kept)
+
+    def _waiting(self, model: Any, max_new_tokens: int, prompt_tokens: int
+                 ) -> List[Tuple[str, LMRow, Any]]:
+        """``(prompt id, row, root span)`` of the queued requests' calls
+        this execution has room for, in queue order."""
+        state = self._state
+        with state._queue_lock:
+            queued = list(state._queue)
+        with self._lock:
+            served = {pid for pid, _, _ in self._kept}
+        room = LM_ROW_COUNTS[-1] - 1 - len(served)
+        found: List[Tuple[str, LMRow, Any]] = []
+        for item in queued:
+            if item["id"] in served:
+                continue
+            for name, row, n, p in self._calls(item):
+                if len(found) >= room:
+                    return found
+                if (name, n, p) != (model.name, max_new_tokens,
+                                    prompt_tokens):
+                    continue
+                try:
+                    model.prompt_ids(row.text, prompt_tokens)
+                except ValueError:
+                    continue        # it fails at its own turn, alone
+                found.append((item["id"], row, item.get("span")))
+        return found
+
+    def _calls(self, item: Dict[str, Any]) -> list:
+        """The generate calls a queued graph holds with literal inputs,
+        read once per request."""
+        calls = item.get("lm_calls")
+        if calls is None:
+            calls = item["lm_calls"] = []
+            try:
+                graph = parse_workflow(item["prompt"])
+            except Exception:  # noqa: BLE001 - it fails at its own turn
+                return calls
+            op = get_op(NODE) if graph.find_by_type(NODE) else None
+            for nid in graph.find_by_type(NODE):
+                call = op.literal_call(graph, graph.nodes[nid],
+                                       self._state.is_worker)
+                if call is not None:
+                    calls.append(call)
+        return calls

@@ -79,6 +79,17 @@ def test_lm_phase_holds_the_served_logits_to_the_reference(tmp_path):
     assert lm["cache_8bit"] > lm["limits"]["mean_over_std"]
     with open(tmp_path / "out" / "verify_lm" / "verify_lm.json") as f:
         assert json.load(f)["ok"] is True
+    # then four requests sent together: one execution, four rows of
+    # different lengths, each inside the same limits
+    rows = summary["smoke_facts"]["language_model_together"]
+    assert rows["executions"] == 1 and rows["rows"] == 4
+    assert rows["followers_served"] == 3 and rows["padded_rows"] == 0
+    assert len(rows["served"]) == 4 and rows["limits"] == lm["limits"]
+    assert len({row["prompt_ids"] for row in rows["served"]}) == 4
+    for row in rows["served"]:
+        assert row["positions"] == 4 and row["argmax_agree"] == 1.0
+        assert row["mean_over_std"] <= rows["limits"]["mean_over_std"]
+        assert row["max_over_std"] <= rows["limits"]["max_over_std"]
 
 
 def test_default_mode_refuses_a_cpu_pinned_jax(tmp_path):

@@ -1,0 +1,456 @@
+"""One execution of ``lm_generate`` for the request that reaches its
+generate node and the requests queued behind it (server/lm_handover.py),
+tiny families on the CPU: who joins, what is kept and for whom, what is
+counted, and that nothing of it touches a graph without the node."""
+
+import asyncio
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from comfyui_distributed_tpu.models import registry
+from comfyui_distributed_tpu.ops import base as ops_base
+from comfyui_distributed_tpu.ops.base import get_op
+from comfyui_distributed_tpu.runtime import reuse
+from comfyui_distributed_tpu.server.app import ServerState, build_app
+from comfyui_distributed_tpu.utils import trace
+from comfyui_distributed_tpu.workflow.graph import parse_workflow
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+WORKFLOW = os.path.join(REPO, "workflows", "prompt-expand-txt2img.json")
+LOADER, GENERATE, SEED = "20", "21", "13"
+NEW, PROMPT = 4, 32
+TEXTS = ["a red fox in the snow", "a harbour at night, long exposure",
+         "a walled garden in june, seen from above", "a cat"]
+
+
+@pytest.fixture(autouse=True)
+def tiny_family(monkeypatch):
+    monkeypatch.setenv(registry.FAMILY_ENV, "tiny")
+    trace.reset_aggregate_metrics()
+    yield
+
+
+def graph(text, seed=5, **generate):
+    with open(WORKFLOW, encoding="utf-8") as f:
+        g = json.load(f)
+    g.pop("__doc__")
+    g["5"]["inputs"].update(width=64, height=64)
+    g["3"]["inputs"]["steps"] = 2
+    g[SEED]["inputs"]["seed"] = seed
+    g[GENERATE]["inputs"].update(text=text, max_new_tokens=NEW,
+                                 prompt_tokens=PROMPT, **generate)
+    return g
+
+
+@pytest.fixture
+def state(tmp_path):
+    """A server whose executor the test drives by hand: pop, execute."""
+    return ServerState(config_path=str(tmp_path / "cfg.json"),
+                       input_dir=str(tmp_path / "input"),
+                       output_dir=str(tmp_path / "output"),
+                       start_exec_thread=False, overlap=False)
+
+
+def run_next(state):
+    """What one turn of ``_exec_loop`` does."""
+    state._purge_abandoned()
+    group = state._pop_group()
+    state._execute_group(group)
+    return [item["id"] for item in group]
+
+
+def counters():
+    snap = trace.GLOBAL_COUNTERS.snapshot()
+    return {k[3:]: v for k, v in snap.items() if k.startswith("lm.")}
+
+
+def stage_count(name):
+    return trace.GLOBAL_STAGES.snapshot().get(name, {}).get("count", 0)
+
+
+@pytest.fixture
+def encoded(monkeypatch):
+    """Every text that reaches ``CLIPTextEncode``."""
+    texts = []
+    cls = ops_base.NODE_CLASS_MAPPINGS["CLIPTextEncode"]
+    real = cls.execute
+
+    def execute(self, ctx, clip, text, **kw):
+        texts.append(text)
+        return real(self, ctx, clip=clip, text=text, **kw)
+
+    monkeypatch.setattr(cls, "execute", execute)
+    return texts
+
+
+def alone(text, seed=5, temperature=0.0):
+    model = registry.load_language_model("ouro-2.6b.safetensors")
+    words, _ = model.generate(text, seed=seed, max_new_tokens=NEW,
+                              prompt_tokens=PROMPT, temperature=temperature)
+    return f"{text}, {words}"
+
+
+# --- who joins, and what each request gets -----------------------------------
+
+def test_four_prompts_behind_a_blocked_executor_share_one_execution(
+        tmp_path, encoded):
+    """Through ``POST /prompt`` and the executor's own thread: the first
+    request leads, the three queued behind it are rows of its execution,
+    each is counted as a request served and gets the words of its own
+    single-row run."""
+    async def body():
+        state = ServerState(config_path=str(tmp_path / "cfg.json"),
+                            input_dir=str(tmp_path / "input"),
+                            output_dir=str(tmp_path / "output"))
+        client = TestClient(TestServer(build_app(state)))
+        await client.start_server()
+        try:
+            state._exec_gate.clear()
+            pids = []
+            for i, text in enumerate(TEXTS):
+                r = await client.post("/prompt", json={
+                    "prompt": graph(text, seed=10 + i), "client_id": "t"})
+                assert r.status == 200, await r.text()
+                pids.append((await r.json())["prompt_id"])
+            assert [it["id"] for it in state._queue] == pids
+            state._exec_gate.set()
+            for _ in range(2400):
+                hist = await (await client.get("/history")).json()
+                if all(p in hist for p in pids):
+                    break
+                await asyncio.sleep(0.05)
+            assert [hist[p]["status"] for p in pids] == ["success"] * 4
+            m = await (await client.get("/distributed/metrics")).json()
+            spans = [await (await client.get(
+                f"/distributed/trace/{p}")).json() for p in pids]
+            return state, m, spans
+        finally:
+            await client.close()
+
+    state, m, traces = asyncio.run(body())
+    got = {k[3:]: v for k, v in m["pipeline"]["counters"].items()
+           if k.startswith("lm.")}
+    assert got["executions"] == 1 and got["rows"] == 4
+    assert got["padded_rows"] == 0 and got["followers_served"] == 3
+    assert "followers_dropped" not in got and state.lm_handover.kept() == 0
+    # counted per request: tokens over stages is one execution's steps
+    stages = m["pipeline"]["stages"]
+    assert stages["lm_generate"]["count"] == 4
+    assert stages["detokenize"]["count"] == 4
+    assert got["tokens_decoded"] / stages["lm_generate"]["count"] == NEW
+    assert got["layer_applications"] == 4 * NEW * 4 * 3
+    assert m["pipeline"]["gauges"]["lm.kv_cache_bytes"] == \
+        4 * 2 * 12 * (PROMPT + NEW) * 4 * 16 * 4
+    # the stage and the instant are on every request's own trace, the
+    # followers' over the leader's interval
+    intervals = []
+    for tr in traces:
+        spans = tr["spans"] if isinstance(tr, dict) and "spans" in tr else tr
+        (gen,) = [s for s in spans if s["name"] == "lm_generate"]
+        root = [s for s in spans if not s.get("parent_id")][0]
+        assert "lm_ids_ready" in root["attrs"]["instants"]
+        intervals.append((gen["start_s"], gen["end_s"]))
+    assert all(abs(a - b) < 0.05 for iv in intervals[1:]
+               for a, b in zip(iv, intervals[0]))
+    # four distinct texts into the text encoder, each its own request's
+    positive = [t for t in encoded if t != "blurry, lowres, watermark"]
+    assert len(set(positive)) == 4
+    assert positive == [alone(t, 10 + i) for i, t in enumerate(TEXTS)]
+
+
+def test_rows_keep_their_own_seed_and_temperature(state):
+    """Two sampled followers of one text differ by their seeds, as their
+    own runs do; the greedy leader ignores its seed."""
+    for seed, t in ((1, 0.0), (2, 1.0), (3, 1.0)):
+        state.enqueue_prompt(graph("a tower", seed=seed, temperature=t), "t")
+    outs = []
+    cls = ops_base.NODE_CLASS_MAPPINGS["LanguageModelGenerate"]
+    real = cls.execute
+
+    def execute(self, ctx, **kw):
+        out = real(self, ctx, **kw)
+        outs.append(out[0])
+        return out
+
+    cls.execute = execute
+    try:
+        for _ in range(3):
+            run_next(state)
+    finally:
+        cls.execute = real
+    assert counters()["executions"] == 1 and counters()["padded_rows"] == 1
+    assert outs == [alone("a tower", 1), alone("a tower", 2, 1.0),
+                    alone("a tower", 3, 1.0)]
+    assert outs[1] != outs[2] != outs[0]
+
+
+def test_a_follower_stays_where_it_was_in_the_queue(state):
+    pids = [state.enqueue_prompt(graph(t), "t") for t in TEXTS]
+    assert run_next(state) == pids[:1]
+    assert [it["id"] for it in state._queue] == pids[1:]
+    assert state.lm_handover.kept() == 3
+    assert [run_next(state) for _ in range(3)] == [[p] for p in pids[1:]]
+    assert state.lm_handover.kept() == 0
+    assert counters()["followers_served"] == 3
+    assert all(state._history[p]["status"] == "success" for p in pids)
+
+
+def test_no_more_rows_than_the_largest_count_and_no_request_twice(state):
+    """Six wait: the first leads three, the fifth leads the sixth; a
+    request that already has its result is not a row again."""
+    pids = [state.enqueue_prompt(graph(f"prompt number {i}"), "t")
+            for i in range(6)]
+    run_next(state)
+    assert counters()["rows"] == registry.LM_ROW_COUNTS[-1] == 4
+    assert state.lm_handover.kept() == 3
+    for _ in range(3):
+        run_next(state)
+    assert counters()["executions"] == 1
+    run_next(state)                     # the fifth: alone with the sixth
+    pad = next(b for b in registry.LM_ROW_COUNTS if b >= 2) - 2
+    assert counters().get("padded_rows", 0) == pad
+    assert counters() | {"executions": 2, "rows": 6} == counters()
+    run_next(state)
+    assert counters()["followers_served"] == 4
+    assert all(state._history[p]["status"] == "success" for p in pids)
+
+
+# --- what is kept is dropped when its request goes another way ----------------
+
+def test_a_follower_cancelled_before_its_turn_is_dropped(state):
+    pids = [state.enqueue_prompt(graph(t), "t") for t in TEXTS[:3]]
+    run_next(state)
+    assert state.lm_handover.kept() == 2
+    reuse.PREVIEWS.abandon(pids[1])
+    assert run_next(state) == [pids[2]]       # the purge, then the pop
+    assert counters()["followers_dropped"] == 1
+    assert counters()["followers_served"] == 1
+    assert state.lm_handover.kept() == 0 and not state._queue
+    assert state._history[pids[1]]["status"] != "success"
+    # it was a request served when the execution ran: the ratio holds
+    assert counters()["tokens_decoded"] / stage_count("lm_generate") == NEW
+
+
+def test_a_drain_that_times_out_drops_what_was_kept(state):
+    for t in TEXTS[:3]:
+        state.enqueue_prompt(graph(t), "t")
+    run_next(state)
+    state._exec_started = True          # a queue that somebody would pop
+    assert state.drain(timeout=0.0) is False
+    assert state.lm_handover.kept() == 0
+    assert counters()["followers_dropped"] == 2
+
+
+def test_a_follower_that_left_while_the_execution_ran_is_never_kept(
+        state, monkeypatch):
+    """A drain times out while the leader's execution is on the device:
+    its followers are finalized before anything could be kept for them,
+    so nothing is, and no later execution loses a row to them."""
+    model = registry.load_language_model("ouro-2.6b.safetensors")
+    real = model.generate_rows
+
+    def generate_rows(rows, *a, **kw):
+        out = real(rows, *a, **kw)
+        state._exec_started = True
+        assert state.drain(timeout=0.0) is False
+        return out
+
+    monkeypatch.setattr(model, "generate_rows", generate_rows)
+    pids = [state.enqueue_prompt(graph(t), "t") for t in TEXTS[:3]]
+    run_next(state)
+    assert counters()["rows"] == 3 and not state._queue
+    assert state.lm_handover.kept() == 0
+    assert counters()["followers_dropped"] == 2
+    assert [state._history[p]["status"] for p in pids[1:]] == ["error"] * 2
+    monkeypatch.setattr(model, "generate_rows", real)
+    state._draining = False
+    state.interrupt_event.clear()
+    for t in TEXTS:
+        state.enqueue_prompt(graph(t + ", again"), "t")
+    run_next(state)
+    assert counters()["rows"] == 3 + registry.LM_ROW_COUNTS[-1]
+
+
+def test_an_edited_request_never_receives_anothers_words(state, encoded):
+    """The result is kept under what it is a function of.  A request
+    whose text changed after the leader read it finds nothing under its
+    key, runs alone, and what was kept for it is dropped."""
+    state.enqueue_prompt(graph(TEXTS[0]), "t")
+    state.enqueue_prompt(graph(TEXTS[1]), "t")
+    run_next(state)
+    state._queue[0]["prompt"][GENERATE]["inputs"]["text"] = "a late edit"
+    run_next(state)
+    got = counters()
+    assert got["executions"] == 2 and got["followers_dropped"] == 1
+    assert "followers_served" not in got and state.lm_handover.kept() == 0
+    positive = [t for t in encoded if t != "blurry, lowres, watermark"]
+    assert positive == [alone(TEXTS[0]), alone("a late edit")]
+
+
+def test_an_error_in_a_shared_execution_fails_the_leader_only(
+        state, monkeypatch):
+    model = registry.load_language_model("ouro-2.6b.safetensors")
+    real = model.generate_rows
+
+    def generate_rows(rows, *a, **kw):
+        if len(rows) > 1:
+            raise RuntimeError("the shared execution broke")
+        return real(rows, *a, **kw)
+
+    monkeypatch.setattr(model, "generate_rows", generate_rows)
+    pids = [state.enqueue_prompt(graph(t), "t") for t in TEXTS[:3]]
+    run_next(state)
+    assert state._history[pids[0]]["status"] == "error"
+    assert state.lm_handover.kept() == 0
+    run_next(state)                     # leads the third, and fails
+    run_next(state)                     # nobody waits: it runs alone
+    assert state._history[pids[1]]["status"] == "error"
+    assert state._history[pids[2]]["status"] == "success"
+    assert counters()["executions"] == 1 and counters()["rows"] == 1
+
+
+# --- who does not join ---------------------------------------------------------
+
+def test_two_callers_in_a_closed_loop_never_meet_at_the_node(state):
+    """A closed loop of two: while one request is in its generate node
+    the other caller's is in its own denoise, and posts its next only
+    when that has ended.  Whoever reaches the node finds nobody."""
+    waiting = state.enqueue_prompt(graph("caller a, round 0"), "a")
+    for turn in range(1, 7):
+        assert run_next(state) == [waiting]
+        waiting = state.enqueue_prompt(
+            graph(f"caller {'ab'[turn % 2]}, round {turn // 2}"), "ab"[turn % 2])
+    got = counters()
+    assert got["rows"] / got["executions"] == 1.0 and got["rows"] == 6
+    assert got["padded_rows"] == 0 and "followers_served" not in got
+    assert got["tokens_decoded"] / stage_count("lm_generate") == NEW
+
+
+@pytest.mark.parametrize("change, joins", [
+    ({}, True),
+    ({"max_new_tokens": NEW + 1}, False),
+    ({"prompt_tokens": PROMPT - 8}, False),
+    ({"model_name": "another-tiny-lm.safetensors"}, False),
+    ({"text": ["30", 0]}, False),           # a text another node makes
+    ({"temperature": 0.5}, True),
+])
+def test_only_a_call_of_the_same_model_and_lengths_joins(state, change,
+                                                         joins):
+    state.enqueue_prompt(graph("the leader"), "t")
+    g = graph("the one behind")
+    name = change.pop("model_name", None)
+    if name:
+        g[LOADER]["inputs"]["model_name"] = name
+    g[GENERATE]["inputs"].update(change)
+    if isinstance(change.get("text"), list):
+        g["30"] = {"class_type": "LanguageModelGenerate", "inputs": {
+            **g[GENERATE]["inputs"], "text": "inner"}}
+    state.enqueue_prompt(g, "t")
+    run_next(state)
+    # an inner literal call of a graph may join where its outer cannot
+    rows = 2 if joins or isinstance(change.get("text"), list) else 1
+    assert counters()["rows"] == rows
+    run_next(state)
+    assert all(h["status"] == "success" for h in state._history.values())
+    assert state.lm_handover.kept() == 0
+
+
+@pytest.mark.parametrize("edit, want", [
+    (lambda g: None, ("ouro-2.6b.safetensors", "x", 5, 0.0, NEW, PROMPT)),
+    (lambda g: g[GENERATE]["inputs"].update(seed=9, temperature=1),
+     ("ouro-2.6b.safetensors", "x", 9, 1.0, NEW, PROMPT)),
+    (lambda g: [g[GENERATE]["inputs"].pop(k) for k in
+                ("max_new_tokens", "prompt_tokens", "temperature")],
+     ("ouro-2.6b.safetensors", "x", 5, 0.0, 64, 64)),
+    (lambda g: g[GENERATE]["inputs"].update(seed=["5", 0]), None),
+    (lambda g: g[GENERATE]["inputs"].update(text=["6", 0]), None),
+    (lambda g: g[GENERATE]["inputs"].update(model=["4", 1]), None),
+    (lambda g: g[GENERATE]["inputs"].update(temperature="hot"), None),
+    (lambda g: g[GENERATE].update(hidden={"x": 1}), None),
+    (lambda g: g[SEED].update(hidden={"is_worker": True}), None),
+])
+def test_literal_call_reads_the_graph_alone(edit, want):
+    g = graph("x")
+    edit(g)
+    parsed = parse_workflow(g)
+    call = get_op("LanguageModelGenerate").literal_call(
+        parsed, parsed.nodes[GENERATE])
+    if want is None:
+        assert call is None
+    else:
+        name, row, n, p = call
+        assert (name, row.text, row.seed, row.temperature, n, p) == want
+
+
+def test_on_a_worker_a_distributed_seed_is_not_read_from_the_graph():
+    parsed = parse_workflow(graph("x"))
+    op = get_op("LanguageModelGenerate")
+    assert op.literal_call(parsed, parsed.nodes[GENERATE]) is not None
+    assert op.literal_call(parsed, parsed.nodes[GENERATE],
+                           is_worker=True) is None
+
+
+# --- nothing compiles in a served window ---------------------------------------
+
+def test_the_first_shared_execution_compiles_nothing(state):
+    """Every row count is compiled when the first request of a length
+    meets the model: between the end of the first single request and the
+    end of the first shared one ``retraces.compiles`` does not move."""
+    state.enqueue_prompt(graph("the first request, alone"), "t")
+    run_next(state)
+    mark = trace.GLOBAL_RETRACES.mark()
+    for t in TEXTS:
+        state.enqueue_prompt(graph(t), "t")
+    for _ in TEXTS:
+        run_next(state)
+    assert counters()["executions"] == 2 and counters()["rows"] == 5
+    assert trace.GLOBAL_RETRACES.since(mark)["compiles"] == 0
+    model = registry.load_language_model("ouro-2.6b.safetensors")
+    assert sorted(model._programs[(NEW, PROMPT)]) == \
+        list(registry.LM_ROW_COUNTS)
+
+
+# --- a graph without the node ----------------------------------------------------
+
+_PROBE = """
+import json, sys, tempfile
+from comfyui_distributed_tpu.server.app import ServerState
+g = json.load(open(sys.argv[1])); g.pop("__doc__", None)
+g["5"]["inputs"].update(width=64, height=64); g["3"]["inputs"]["steps"] = 2
+d = tempfile.mkdtemp()
+state = ServerState(config_path=d + "/cfg.json", input_dir=d, output_dir=d,
+                    start_exec_thread=False, overlap=False)
+pids = [state.enqueue_prompt(json.loads(json.dumps(g)), "t") for _ in "ab"]
+for _ in pids:
+    state._purge_abandoned()
+    state._execute_group(state._pop_group())
+from comfyui_distributed_tpu.utils import trace
+print(json.dumps({
+    "status": [state._history[p]["status"] for p in pids],
+    "looplm_imported": "comfyui_distributed_tpu.models.looplm" in sys.modules,
+    "handover": type(state.lm_handover).__name__,
+    "lm_calls": [it.get("lm_calls") for it in state._queue],
+    "counters": [c for c in trace.GLOBAL_COUNTERS.snapshot()
+                 if c.startswith("lm.")]}))
+"""
+
+
+def test_a_server_whose_graphs_hold_no_generate_node_never_imports_looplm(
+        tmp_path):
+    r = subprocess.run(
+        [sys.executable, "-c", _PROBE,
+         os.path.join(REPO, "workflows", "distributed-txt2img.json")],
+        capture_output=True, text=True, timeout=600, cwd=str(tmp_path),
+        env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
+             "DTPU_DEFAULT_FAMILY": "tiny",
+             "DISTRIBUTED_TPU_CONFIG": str(tmp_path / "cfg.json")})
+    assert r.returncode == 0, r.stderr[-3000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got["status"] == ["success", "success"]
+    assert got["looplm_imported"] is False and got["counters"] == []
+    assert got["handover"] == "GenerateHandover"

@@ -76,6 +76,20 @@ def serve(cfg, params, ids, n=PROMPT, new=NEW, temperature=0.0, seed=3):
     return np.asarray(tokens[0]), np.asarray(logits[0]), np.asarray(exits[0])
 
 
+def serve_rows(cfg, params, lens, new=NEW, temperatures=None, seeds=None,
+               ids=None):
+    """One execution over rows of the real lengths ``lens`` (row ``b``'s
+    prompt is ``prompt(b, lens[b])``), each with its own seed and
+    temperature: ``tokens [B, N]``, ``logits [B, N, V]``, ``exits``."""
+    if ids is None:
+        ids = np.concatenate([prompt(b, n) for b, n in enumerate(lens)])
+    out = looplm.make_generate(cfg, new)(
+        params, jnp.asarray(ids), np.asarray(lens, np.int32),
+        np.asarray(seeds or [3] * len(lens), np.uint32),
+        np.asarray(temperatures or [0.0] * len(lens), np.float32))
+    return tuple(np.asarray(x) for x in out)
+
+
 def reference_rows(cfg, params, ids, tokens, n=PROMPT):
     """The reference's full forward pass, teacher-forced over the prompt
     and the served ids: the rows each served token was drawn from."""
@@ -162,8 +176,9 @@ def test_the_cache_has_a_slot_per_loop_and_layer_and_they_differ(params):
 
     def decode_after_prefill(swap):
         x = looplm._embed(params, jnp.asarray(prompt()[:, :PROMPT]))
+        first = jnp.zeros(1, jnp.int32)
         _, _, (kc, vc) = looplm._stack(
-            TINY, params, x, jnp.arange(PROMPT),
+            TINY, params, x, jnp.arange(PROMPT), first,
             looplm.empty_cache(TINY, 1, 12), use_cache=False)
         # every slot was written, and no two loops hold the same keys
         assert float(jnp.abs(kc[:, :, :, :PROMPT]).min(axis=(2, 3, 4, 5)
@@ -175,7 +190,7 @@ def test_the_cache_has_a_slot_per_loop_and_layer_and_they_differ(params):
             kc, vc = kc[order], vc[order]
         tok = looplm._embed(params, jnp.asarray([[5]]))
         x, _, _ = looplm._stack(TINY, params, tok, jnp.asarray([PROMPT]),
-                                (kc, vc), use_cache=True)
+                                first, (kc, vc), use_cache=True)
         return np.asarray(looplm._head(TINY, params, x))
 
     assert np.abs(decode_after_prefill(False)
@@ -201,6 +216,67 @@ def test_sampling_follows_the_seed_and_greedy_ignores_it(params):
     assert np.array_equal(
         a, serve(TINY, params, prompt(), temperature=1.0, seed=1)[0])
     assert not np.array_equal(a, b) and not np.array_equal(a, greedy)
+
+
+# --- several requests in one execution ----------------------------------------
+
+LENS = [9, 5, 16, 12]               # PAD_TO = 16: one row has no padding
+TEMPERATURES = [0.0, 0.0, 1.0, 0.7]
+SEEDS = [3, 4, 5, 6]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [2, 4])
+def test_rows_of_different_lengths_match_their_single_row_runs(rows, dtype):
+    """Rows of different real lengths, seeds and temperatures in one
+    execution: each row's ids are those of its own single-row run, greedy
+    and sampled alike, and its logits lie inside the file's limits both
+    of that run's and of the reference teacher-forced over its own ids."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.dtype(dtype))
+    limits = verify.LIMITS_FP32 if dtype == "float32" else TINY_BF16_LIMITS
+    p = make_params(cfg)
+    lens, temps, seeds = LENS[:rows], TEMPERATURES[:rows], SEEDS[:rows]
+    tokens, logits, exits = serve_rows(cfg, p, lens, temperatures=temps,
+                                       seeds=seeds)
+    assert tokens.shape == (rows, NEW) and exits.shape == (rows, NEW, 4)
+    for b, n in enumerate(lens):
+        alone = serve(cfg, p, prompt(b, n), n=n, temperature=temps[b],
+                      seed=seeds[b])
+        assert np.array_equal(tokens[b], alone[0]), (b, tokens[b], alone[0])
+        got = verify.compare_logits(logits[b], alone[1], tokens[b], limits)
+        assert got["max_over_std"] <= limits["max_over_std"], (b, got)
+        np.testing.assert_allclose(exits[b], alone[2], atol=1e-3)
+        want, _ = reference_rows(cfg, p, prompt(b, n), tokens[b], n=n)
+        got = verify.compare_logits(logits[b], want, tokens[b], limits)
+        if temps[b] == 0:
+            assert got["correct"], (b, got)
+        else:       # a sampled id need not be the reference's largest
+            assert got["max_over_std"] <= limits["max_over_std"], (b, got)
+            assert got["mean_over_std"] <= limits["mean_over_std"], (b, got)
+
+
+def test_rows_do_not_read_each_other_and_padding_changes_no_real_row(params):
+    """The fourth row holds a copy of the first, another prompt, or
+    another length; ids in a row's padding are anything: the three real
+    rows' ids and logits stay what they were."""
+    lens = LENS[:3]
+    base = np.concatenate([prompt(b, n) for b, n in enumerate(lens)])
+    tokens, logits, _ = serve_rows(TINY, params, lens + [lens[0]],
+                                   ids=np.concatenate([base, base[:1]]))
+    for last, n in ((prompt(9, 16), 16), (prompt(8, 3), 3)):
+        noisy = np.concatenate([base, last])
+        for b, real in enumerate(lens):
+            noisy[b, real:] = 77 + b
+        t, l, _ = serve_rows(TINY, params, lens + [n], ids=noisy)
+        assert np.array_equal(t[:3], tokens[:3])
+        np.testing.assert_allclose(l[:3], logits[:3], atol=1e-5)
+    # and a scalar length, seed or temperature stands for every row
+    same = serve_rows(TINY, params, [9, 9], ids=np.concatenate(
+        [prompt(0), prompt(1)]))
+    scalar = looplm.make_generate(TINY, NEW)(
+        params, jnp.asarray(np.concatenate([prompt(0), prompt(1)])),
+        np.int32(9), np.uint32(3), np.float32(0.0))
+    assert np.array_equal(same[0], np.asarray(scalar[0]))
 
 
 # --- each breakage fails the comparison -------------------------------------
@@ -235,19 +311,30 @@ BREAKAGES = ["a loop dropped", "two loops share a slot",
              "the weights in 8 bits"]
 
 
+@pytest.mark.parametrize("rows", [1, 3])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("what", BREAKAGES)
-def test_each_breakage_fails_the_comparison(what, dtype, monkeypatch):
-    """Under the float32 limits and under the bf16 limits alike.  The
-    reference always runs the model as stated, on the stated weights."""
+def test_each_breakage_fails_the_comparison(what, dtype, rows, monkeypatch):
+    """Under the float32 limits and under the bf16 limits alike, alone
+    and as the last of three rows of different lengths in one execution.
+    The reference always runs the model as stated, on the stated
+    weights."""
     cfg = dataclasses.replace(TINY, dtype=jnp.dtype(dtype))
     limits = verify.LIMITS_FP32 if dtype == "float32" else TINY_BF16_LIMITS
     p = make_params(cfg)
-    tokens, logits, _ = serve(cfg, p, prompt())
+    lens = [5, 12, PROMPT][-rows:]
+    mine = np.concatenate([prompt(b) if n == PROMPT else prompt(b, n)
+                           for b, n in zip(range(rows - 1, -1, -1), lens)])
+
+    def last_row(cfg, p):
+        tokens, logits, _ = serve_rows(cfg, p, lens, ids=mine)
+        return tokens[-1], logits[-1]
+
+    tokens, logits = last_row(cfg, p)
     want, _ = reference_rows(cfg, p, prompt(), tokens)
     assert verify.compare_logits(logits, want, tokens, limits)["correct"]
     broken_cfg, broken_p = _break(what, monkeypatch, cfg, p)
-    tokens, logits, _ = serve(broken_cfg, broken_p, prompt())
+    tokens, logits = last_row(broken_cfg, broken_p)
     want, _ = reference_rows(cfg, p, prompt(), tokens)
     got = verify.compare_logits(logits, want, tokens, limits)
     assert not got["correct"], (what, got)
