@@ -1,15 +1,17 @@
-"""Calibration gate: the sim vs the measured bench artifacts.
+"""Calibration gate: the sim vs the measured records.
 
-A simulator that cannot reproduce the benches it claims to model is a
+A simulator that cannot reproduce the runs it claims to model is a
 random-number generator with extra steps.  This module scores a sim
-summary against a committed BENCH artifact two ways:
+summary against a committed measured record
+(``benchmarks/scenarios/*.measured.json``: loopback runs of the ``tiny``
+family on the CPU, kept as the sim's calibration target and as nothing
+else) two ways:
 
 - **quantities** — relative error on the numbers the bench measured
   (per-class admitted/shed counts, per-class p95, completion rate for
   the overload bench; completion for the multimaster kill arm).  The
   headline ``calibration_error`` is the mean relative error, floored at
-  1e-4 so ``bench --check``'s positive-value invariant holds even on a
-  perfect run.
+  1e-4 so it stays positive even on a perfect run.
 - **hard bars** — the *orderings* the bench proves (paid sheds zero,
   shedding is batch-first, per-class p95 orders paid < free < batch,
   the kill arm completes 1.0 with exactly one takeover by the measured
@@ -17,7 +19,7 @@ summary against a committed BENCH artifact two ways:
   the point of the policies, so a sim that inverts one must fail the
   gate no matter how close the raw numbers land.
 
-``bench.py --phase sim`` runs both fixtures under
+``tests/test_sim.py::TestCalibration`` runs both fixtures under
 ``benchmarks/scenarios/`` and gates on
 ``calibration_error <= C.SIM_CALIBRATION_MAX_ERR``.
 """
@@ -28,8 +30,7 @@ from typing import Any, Dict, List, Tuple
 
 from comfyui_distributed_tpu.utils import constants as C
 
-# floor keeps the headline metric positive (bench --check treats
-# value <= 0 as a broken run)
+# floor keeps the headline metric positive on a perfect run
 _ERR_FLOOR = 1e-4
 
 
@@ -65,7 +66,8 @@ def _score(quantities: List[Tuple[str, float, float]],
 def score_overload(summary: Dict[str, Any],
                    artifact: Dict[str, Any]) -> Dict[str, Any]:
     """Score a sim run of the overload fixture against
-    ``BENCH_overload_r09.json`` (the measured elastic-fleet proof)."""
+    ``benchmarks/scenarios/overload_r09.measured.json`` (the measured
+    elastic-fleet run)."""
     ref = artifact.get("per_class") or {}
     quantities: List[Tuple[str, float, float]] = []
     for cls in C.TENANT_CLASSES:
@@ -109,7 +111,8 @@ def score_overload(summary: Dict[str, Any],
 def score_multimaster(summary: Dict[str, Any],
                       artifact: Dict[str, Any]) -> Dict[str, Any]:
     """Score a sim run of the multimaster kill fixture against
-    ``BENCH_multimaster_r14.json`` (the sharded control-plane proof)."""
+    ``benchmarks/scenarios/multimaster_r14.measured.json`` (the measured
+    sharded control-plane run)."""
     ref_kill = artifact.get("kill") or {}
     ref_tk = artifact.get("takeover") or {}
     tk = summary.get("takeover") or {}

@@ -16,9 +16,10 @@ produces is the decision production would have made at that instant.
 What IS virtual: workers (a service-time sample instead of a denoise),
 the network (a :class:`~.faults.SimChaos` roll instead of a socket) and
 time itself.  The fidelity contract is enforced by
-``bench.py --phase sim``: the sim must reproduce the committed overload
-and multimaster bench artifacts within tolerance before any sweep
-result is worth reading.
+``tests/test_sim.py::TestCalibration``: the sim must reproduce the
+committed overload and multimaster records
+(``benchmarks/scenarios/*.measured.json``) within tolerance before any
+sweep result is worth reading.
 
 Mechanics mirrored from the live harness rather than idealized:
 

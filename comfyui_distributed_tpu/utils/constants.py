@@ -494,7 +494,7 @@ SHARD_FORWARD_HEADER = "x-dtpu-forwarded-from"
 
 # --- chaos fault-injection harness (utils/chaos.py) --------------------------
 # Env/route-driven fault injection on the HTTP edges and worker
-# lifecycle, for tests and `bench.py --phase overload`.  DTPU_CHAOS is a
+# lifecycle, for tests (tests/test_overload.py).  DTPU_CHAOS is a
 # JSON spec; unset = zero overhead (one dict lookup per edge).  Fields:
 #   {"drop_pct": 5, "delay_pct": 5, "delay_s": 0.2, "http_5xx_pct": 5,
 #    "corrupt_pct": 2, "freeze_heartbeats": true|["w0"],
@@ -550,8 +550,9 @@ SIM_MAX_EVENTS_ENV = "DTPU_SIM_MAX_EVENTS"  # runaway-scenario backstop
 SIM_MAX_EVENTS_DEFAULT = 5_000_000
 SIM_EVENT_LOG_TAIL_ENV = "DTPU_SIM_EVENT_LOG_TAIL"  # human-readable tail
 SIM_EVENT_LOG_TAIL_DEFAULT = 256            # full log feeds the digest
-# calibration gate (bench.py --phase sim): max tolerated mean relative
-# error between simulated and measured bench artifacts
+# calibration gate (tests/test_sim.py::TestCalibration): max tolerated
+# mean relative error between the sim and the measured records
+# (benchmarks/scenarios/*.measured.json)
 SIM_CALIBRATION_MAX_ERR = 0.15
 
 # --- critical-path analytics plane (utils/trace_analysis.py, ISSUE 20) ------

@@ -31,9 +31,10 @@ Pieces:
   label) and the federated ``/distributed/cluster/metrics.prom``
   (``worker_id``-labelled, one series per participant).
 
-Everything here is host-side Python outside the jitted programs — the
-telemetry bench (``bench.py --phase telemetry``) proves monitor-on vs
-monitor-off throughput stays within noise with zero new jit traces.
+Everything here is host-side Python outside the jitted programs:
+``tests/test_observability.py::TestServerTraceLifecycle`` serves warm
+requests beside a monitor sampling at 100x the production rate and
+holds them to nothing lowered or compiled.
 """
 
 from __future__ import annotations

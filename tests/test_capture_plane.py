@@ -465,8 +465,8 @@ class TestPerfetto:
 
 
 class TestServerSurfaces:
-    def test_slo_route_metrics_and_total_reset(self, tmp_path,
-                                               monkeypatch):
+    def test_slo_route_metrics_and_total_reset(self, tmp_path, monkeypatch,
+                                               assert_nothing_compiled):
         d = str(tmp_path / "cap")
         monkeypatch.setenv(C.TRACE_EXPORT_DIR_ENV, d)
         monkeypatch.setenv(C.SLO_SPEC_ENV,
@@ -498,6 +498,7 @@ class TestServerSurfaces:
             assert m["slo"]["enabled"] is True
             assert m["tracing"]["export"]["enabled"] is True
             assert m["tracing"]["export"]["exported"] >= 1
+            assert m["tracing"]["export"]["dropped"] == 0
             assert "evictions" in m["tracing"]
 
             # prom text: new families + exemplar-aware grammar
@@ -521,6 +522,14 @@ class TestServerSurfaces:
             # capture file round-trips the job
             disk = te.load_trace(d, prompt_id=pid)
             assert disk is not None and disk["status"] == "ok"
+
+            # the armed plane (tracing + export + SLO engine + exemplars)
+            # never touches compiled code: a warm request compiles nothing
+            mark = tr.GLOBAL_RETRACES.mark()
+            r = await client.post("/prompt", json={
+                "prompt": make_prompt(13), "client_id": "cp"})
+            await wait_remote_history(client, (await r.json())["prompt_id"])
+            assert_nothing_compiled(tr.GLOBAL_RETRACES.since(mark))
 
             # total reset: SLO windows + exporter counters clear too
             r = await client.post("/distributed/metrics/reset", json={})

@@ -738,6 +738,53 @@ class TestFaultAcceptance:
         asyncio.run(go())
 
     @pytest.mark.slow
+    def test_armed_and_idle_does_no_speculative_work(
+            self, tmp_path, monkeypatch, assert_nothing_compiled):
+        """While every worker is healthy the armed control plane
+        (reassign policy, hedging on) is free where it can be counted:
+        no unit reassigned or hedged, and a warm job lowers and compiles
+        nothing."""
+        monkeypatch.setenv(C.FAULT_POLICY_ENV, "reassign")
+        monkeypatch.setenv(C.HEDGE_ENV, "1")
+        # jax compute starves the shared loop of this one process: leases
+        # generous enough that live workers are not taken for dead
+        monkeypatch.setenv(C.LEASE_ENV, "30.0")
+        monkeypatch.setenv(C.SUSPECT_PROBES_ENV, "3")
+
+        async def go():
+            clu = await _Cluster(tmp_path, n_workers=2).start()
+            try:
+                await asyncio.get_running_loop().run_in_executor(
+                    None, clu.master_state.health.poll_once)
+                clu.master_state.health.start()
+                # two jobs warm every participant's programs (the second
+                # still loads a few); the third is the one held to zero
+                for seed in (4, 5, 6):
+                    mark = tr.GLOBAL_RETRACES.mark()
+                    r = await clu.master_client.post("/prompt", json={
+                        "prompt": upscale_prompt(seed=seed),
+                        "client_id": "acc"})
+                    body = await r.json()
+                    assert sorted(body["workers"]) == ["w0", "w1"], body
+                    hist = await _wait_history(clu.master_client,
+                                               body["prompt_id"])
+                    assert hist["status"] == "success", hist
+                assert_nothing_compiled(tr.GLOBAL_RETRACES.since(mark))
+                snap = await (await clu.master_client.get(
+                    "/distributed/cluster")).json()
+                jobs = [j for j in snap["ledger"]["completed_jobs"]
+                        if j["kind"] == "tile"]
+                assert len(jobs) == 3
+                for job in jobs:
+                    assert job["done_units"] == job["total_units"] == 4
+                    assert job["reassigned_units"] == 0, job
+                    assert job["hedged_units"] == 0, job
+            finally:
+                await clu.stop()
+
+        asyncio.run(go())
+
+    @pytest.mark.slow
     def test_policy_partial_preserves_seed_behavior(self, tmp_path,
                                                     monkeypatch):
         """Opt-out: DTPU_FAULT_POLICY=partial blends what arrived (the

@@ -3,14 +3,11 @@
 import asyncio
 import json
 
-import pytest
-
 from comfyui_distributed_tpu.workflow import parse_workflow
 from comfyui_distributed_tpu.workflow import dispatcher as dsp
 from comfyui_distributed_tpu.workflow.graph import Graph, Node
 
-TXT2IMG = "/root/reference/workflows/distributed-txt2img.json"
-UPSCALE = "/root/reference/workflows/distributed-upscale.json"
+from tests.test_workflow import TXT2IMG, UPSCALE, _only
 
 
 class TestPrune:
@@ -27,7 +24,7 @@ class TestPrune:
                                      "batch_size": 1})
         pruned = dsp.prune_for_worker(g)
         assert "99" not in pruned.nodes
-        assert "2" in pruned.nodes  # collector stays
+        assert _only(g, "DistributedCollector") in pruned.nodes
 
     def test_prune_does_not_mutate_original(self):
         g = parse_workflow(TXT2IMG)
@@ -43,10 +40,11 @@ class TestInjection:
         jm = dsp.make_job_id_map(g, prefix="exec_t")
         out = dsp.prepare_for_participant(g, "master", jm, ["worker_0",
                                                             "worker_1"])
-        seed = out.nodes["4"].hidden
+        seed = out.nodes[_only(g, "DistributedSeed")].hidden
         assert seed["is_worker"] is False
-        coll = out.nodes["2"].hidden
-        assert coll["multi_job_id"] == "exec_t_2"
+        cid = _only(g, "DistributedCollector")
+        coll = out.nodes[cid].hidden
+        assert coll["multi_job_id"] == f"exec_t_{cid}"
         assert json.loads(coll["enabled_worker_ids"]) == ["worker_0",
                                                           "worker_1"]
         assert "master_url" not in coll
@@ -57,10 +55,10 @@ class TestInjection:
         out = dsp.prepare_for_participant(
             g, "worker", jm, ["worker_0", "worker_1"],
             master_url="http://10.0.0.1:8288", worker_index=1, batch_size=4)
-        seed = out.nodes["4"].hidden
+        seed = out.nodes[_only(g, "DistributedSeed")].hidden
         assert seed["is_worker"] is True
         assert seed["worker_id"] == "worker_1"
-        coll = out.nodes["2"].hidden
+        coll = out.nodes[_only(g, "DistributedCollector")].hidden
         assert coll["master_url"] == "http://10.0.0.1:8288"
         assert coll["worker_batch_size"] == 4
         assert "enabled_worker_ids" not in coll
@@ -71,20 +69,22 @@ class TestInjection:
         m = dsp.prepare_for_participant(g, "master", jm, ["worker_0"])
         w = dsp.prepare_for_participant(g, "worker", jm, ["worker_0"],
                                         master_url="http://m:1", worker_index=0)
+        up = _only(g, "UltimateSDUpscaleDistributed")
         # workers need the enabled list for tile math (gpupanel.js:1157-1174)
-        assert json.loads(m.nodes["13"].hidden["enabled_worker_ids"]) == \
+        assert json.loads(m.nodes[up].hidden["enabled_worker_ids"]) == \
             ["worker_0"]
-        assert json.loads(w.nodes["13"].hidden["enabled_worker_ids"]) == \
+        assert json.loads(w.nodes[up].hidden["enabled_worker_ids"]) == \
             ["worker_0"]
-        assert w.nodes["13"].hidden["master_url"] == "http://m:1"
+        assert w.nodes[up].hidden["master_url"] == "http://m:1"
 
     def test_collector_downstream_of_upscaler_passthrough(self):
         """A collector fed (transitively) by a distributed upscaler becomes
         pass_through (gpupanel.js:1146-1154)."""
         g = parse_workflow(UPSCALE)
+        up = _only(g, "UltimateSDUpscaleDistributed")
         g.nodes["20"] = Node(id="20", class_type="DistributedCollector",
-                             inputs={"images": ["13", 0]})
-        g.nodes["10"].inputs["images"] = ["20", 0]
+                             inputs={"images": [up, 0]})
+        g.nodes[_only(g, "PreviewImage")].inputs["images"] = ["20", 0]
         jm = dsp.make_job_id_map(g)
         out = dsp.prepare_for_participant(g, "master", jm, ["worker_0"])
         assert out.nodes["20"].hidden.get("pass_through") is True
@@ -93,19 +93,21 @@ class TestInjection:
     def test_job_id_map(self):
         g = parse_workflow(TXT2IMG)
         jm = dsp.make_job_id_map(g)
-        assert set(jm) == {"2"}
-        assert jm["2"].endswith("_2")
-        assert jm["2"].startswith("exec_")
+        cid = _only(g, "DistributedCollector")
+        assert set(jm) == {cid}
+        assert jm[cid].endswith(f"_{cid}")
+        assert jm[cid].startswith("exec_")
 
 
 class TestUpstream:
     def test_has_upstream_type(self):
         g = parse_workflow(UPSCALE)
-        # preview (10) is downstream of the upscaler (13)
-        assert dsp.has_upstream_type(g, "10",
+        # the preview is downstream of the upscaler
+        assert dsp.has_upstream_type(g, _only(g, "PreviewImage"),
                                      ("UltimateSDUpscaleDistributed",))
-        assert not dsp.has_upstream_type(g, "13",
-                                         ("UltimateSDUpscaleDistributed",))
+        assert not dsp.has_upstream_type(
+            g, _only(g, "UltimateSDUpscaleDistributed"),
+            ("UltimateSDUpscaleDistributed",))
 
     def test_cycle_safe(self):
         g = Graph(nodes={

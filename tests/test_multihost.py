@@ -14,11 +14,11 @@ import urllib.request
 import pytest
 
 from comfyui_distributed_tpu.utils.net import find_free_port
-from comfyui_distributed_tpu.workflow import parse_workflow
 from comfyui_distributed_tpu.workflow import dispatcher as dsp
 
-TXT2IMG = "/root/reference/workflows/distributed-txt2img.json"
-UPSCALE = "/root/reference/workflows/distributed-upscale.json"
+from tests.test_workflow import _scaled_txt2img, _scaled_upscale
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _post(url, payload, timeout=10):
@@ -48,7 +48,7 @@ def _wait_up(port, timeout=90):
 def _spawn_cluster(tmp_path, n_workers=1):
     env = {
         **os.environ,
-        "PYTHONPATH": "/root/repo",
+        "PYTHONPATH": _REPO,
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
         "DTPU_DEFAULT_FAMILY": "tiny",
@@ -64,10 +64,15 @@ def _spawn_cluster(tmp_path, n_workers=1):
     for i, wp in enumerate(wports):
         f = open(tmp_path / f"worker{i}.log", "w")
         logs.append(f)
+        # a directory of its own: a worker's SaveImage writes its share
+        # under <cwd>/output, and in the master's directory that file can
+        # land after the master's blend and be taken for it
+        wdir = tmp_path / f"worker{i}"
+        wdir.mkdir()
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "comfyui_distributed_tpu.cli", "worker",
              "--host", "127.0.0.1", "--port", str(wp)],
-            env=env, cwd=str(tmp_path), stdout=f, stderr=f))
+            env=env, cwd=str(wdir), stdout=f, stderr=f))
     return mport, wports, procs, logs
 
 
@@ -111,9 +116,7 @@ def test_parallel_generation_over_http(servers):
     mport, wport, tmp_path = servers
     master_url = f"http://127.0.0.1:{mport}"
 
-    g = parse_workflow(TXT2IMG)
-    g.nodes["9"].inputs.update(width=64, height=64, batch_size=1)
-    g.nodes["8"].inputs.update(steps=1)
+    g = _scaled_txt2img(steps=1)
 
     # the reference dispatch protocol (gpupanel.js:836-941)
     job_map = dsp.make_job_id_map(g, prefix="exec_test")
@@ -171,9 +174,7 @@ def test_interceptor_orchestrates_automatically(servers):
     _post(f"{master_url}/distributed/config/update_worker",
           {"id": "w0", "name": "w0", "port": wport, "enabled": True})
 
-    g = parse_workflow(TXT2IMG)
-    g.nodes["9"].inputs.update(width=64, height=64, batch_size=1)
-    g.nodes["8"].inputs.update(steps=1)
+    g = _scaled_txt2img(steps=1)
 
     mr = _post(f"{master_url}/prompt",
                {"prompt": g.to_api_format(), "client_id": "test"})
@@ -203,8 +204,7 @@ def test_jax_distributed_two_process_collectives(tmp_path):
     port = find_free_port()
     # CPU-pinned by ITS environment, before the child imports jax
     env_base = {**os.environ,
-                "PYTHONPATH": os.path.dirname(os.path.dirname(
-                    os.path.abspath(__file__))),
+                "PYTHONPATH": _REPO,
                 "JAX_PLATFORMS": "cpu",
                 "DTPU_COORDINATOR": f"127.0.0.1:{port}",
                 "DTPU_NUM_PROCESSES": "2"}
@@ -231,14 +231,10 @@ def test_jax_distributed_two_process_collectives(tmp_path):
 
 
 def _scaled_upscale_graph():
-    """The reference's distributed-upscale fixture scaled for CPU CI, with
-    the terminal preview swapped for SaveImage so the master persists the
+    """The distributed-upscale fixture scaled for CPU CI, with the
+    terminal preview swapped for SaveImage so the master persists the
     blended result for pixel comparison."""
-    g = parse_workflow(UPSCALE)
-    g.nodes["12"].inputs["image"] = "__missing__.png"   # synthetic test card
-    g.nodes["17"].inputs.update(width=64, height=64)
-    g.nodes["13"].inputs.update(steps=1, tile_width=32, tile_height=32,
-                                padding=8, mask_blur=2)
+    g = _scaled_upscale()
     for n in g.nodes.values():
         if n.class_type == "PreviewImage":
             n.class_type = "SaveImage"

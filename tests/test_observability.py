@@ -10,6 +10,7 @@ same topology test_server.py/test_dispatcher.py use.
 """
 
 import asyncio
+import itertools
 import json
 import logging
 import os
@@ -36,7 +37,7 @@ def tiny_family(monkeypatch):
 @pytest.fixture(autouse=True)
 def tracing_on():
     """Tests assume the always-on default; restore whatever a prior test
-    (or bench import) left behind."""
+    left behind."""
     was = tr.tracing_enabled()
     tr.set_tracing(True)
     yield
@@ -90,6 +91,19 @@ async def wait_remote_history(client, pid, timeout_s=180.0):
             return hist[pid]
         await asyncio.sleep(0.05)
     raise AssertionError(f"prompt {pid} never finished")
+
+
+async def wait_trace(client, pid, timeout_s=5.0):
+    """The job's committed trace record.  History marks success slightly
+    before the finaliser commits the trace, so poll briefly instead of
+    racing one read; on a timeout the last response decides."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        r = await client.get(f"/distributed/trace/{pid}")
+        rec = await r.json() if r.status == 200 else None
+        if (rec and rec.get("spans")) or time.monotonic() >= deadline:
+            return r.status, rec
+        await asyncio.sleep(0.05)
 
 
 def spans_by_name(rec):
@@ -207,7 +221,7 @@ class TestHistogram:
         for v in (0.01, 0.02, 0.03):
             ps.record("x", v)
         snap = ps.snapshot()["x"]
-        # legacy readers (bench stage_totals, metrics tests) rely on these
+        # legacy readers (the metrics tests) rely on these
         assert snap["count"] == 3
         assert abs(snap["total_s"] - 0.06) < 1e-9
         assert abs(snap["max_s"] - 0.03) < 1e-9
@@ -486,9 +500,23 @@ class TestDeviceTraceLeakFix:
 
 
 class TestServerTraceLifecycle:
-    def test_single_prompt_trace_tree(self, tmp_path):
+    def test_single_prompt_trace_tree(self, tmp_path,
+                                      assert_nothing_compiled):
         """Local job: /prompt -> flight recorder holds job/queue_wait/
-        execute/per-node spans under ONE trace id with intact links."""
+        execute/per-node spans under ONE trace id with intact links; and
+        telemetry never touches compiled code: with tracing off, then on
+        beside a resource monitor sampling at 100x the production rate,
+        warm requests lower and compile nothing."""
+        from comfyui_distributed_tpu.utils import resource as res_mod
+
+        async def served(client, seed):
+            r = await client.post("/prompt", json={
+                "prompt": make_prompt(seed=seed), "client_id": "t"})
+            pid = (await r.json())["prompt_id"]
+            hist = await wait_remote_history(client, pid)
+            assert hist["status"] == "success", hist
+            return pid
+
         async def body(client, state):
             r = await client.post("/prompt", json={
                 "prompt": make_prompt(seed=3), "client_id": "t"})
@@ -496,9 +524,8 @@ class TestServerTraceLifecycle:
             pid = (await r.json())["prompt_id"]
             hist = await wait_remote_history(client, pid)
             assert hist["status"] == "success", hist
-            r = await client.get(f"/distributed/trace/{pid}")
-            assert r.status == 200
-            rec = await r.json()
+            status, rec = await wait_trace(client, pid)
+            assert status == 200
             assert rec["status"] == "ok"
             assert {s["trace_id"] for s in rec["spans"]} == \
                 {rec["trace_id"]}
@@ -515,6 +542,25 @@ class TestServerTraceLifecycle:
             # the index lists it newest-first
             idx = await (await client.get("/distributed/traces")).json()
             assert idx["traces"][0]["prompt_id"] == pid
+
+            mark = tr.GLOBAL_RETRACES.mark()
+            monitor = res_mod.ResourceMonitor(
+                interval=0.05, ring=64,
+                queue_depth_fn=state.queue_remaining)
+            try:
+                tr.set_tracing(False)
+                untraced = await served(client, seed=4)
+                tr.set_tracing(True)
+                monitor.start()
+                traced = await served(client, seed=5)
+                assert (await wait_trace(client, traced))[0] == 200
+                # committed after it, so the untraced job's is not coming
+                r = await client.get(f"/distributed/trace/{untraced}")
+                assert r.status == 404
+            finally:
+                monitor.stop(join=True)
+            assert monitor.snapshot()["n_samples"] >= 1
+            assert_nothing_compiled(tr.GLOBAL_RETRACES.since(mark))
         run_with_client(body, tmp_path, start_exec_thread=True)
 
     def test_slow_job_log_line(self, tmp_path, monkeypatch):
@@ -581,6 +627,13 @@ class TestDistributedTraceAcceptance:
                 config_path=str(wdir / "cfg.json"),
                 input_dir=str(wdir / "in"), output_dir=str(wdir / "out"),
                 is_worker=True, start_exec_thread=True)
+            # prompt ids are p_<ms>_<a counter of the state's own>: two
+            # states in ONE process can mint the same id in the same
+            # millisecond, and the process-wide flight recorder then seals
+            # the later job's spans over the earlier one's record (seen
+            # under load as a tree of 0 spans).  Separate processes, as
+            # deployed, share no recorder; here the counters are kept apart.
+            worker_state._id_counter = itertools.count(1000)
             wclient = TestClient(TestServer(build_app(worker_state)))
             await wclient.start_server()
             wport = wclient.server.port
@@ -607,9 +660,8 @@ class TestDistributedTraceAcceptance:
                 pid = body["prompt_id"]
                 hist = await wait_remote_history(mclient, pid)
                 assert hist["status"] == "success", hist
-                r = await mclient.get(f"/distributed/trace/{pid}")
-                assert r.status == 200
-                rec = await r.json()
+                status, rec = await wait_trace(mclient, pid)
+                assert status == 200
                 # ONE trace id across every span in the tree
                 assert {s["trace_id"] for s in rec["spans"]} == \
                     {rec["trace_id"]}, rec["spans"]

@@ -6,7 +6,6 @@ worker + injected faults)."""
 
 import asyncio
 import os
-import sys
 import threading
 import time
 
@@ -21,8 +20,6 @@ from comfyui_distributed_tpu.utils import chaos as chaos_mod
 from comfyui_distributed_tpu.utils import constants as C
 from comfyui_distributed_tpu.utils import net as net_mod
 from comfyui_distributed_tpu.workflow import scheduler as sched
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -789,30 +786,330 @@ class TestRehomeHeartbeat:
 
 # --- slow loopback acceptance ------------------------------------------------
 
+def _p95(values):
+    """Nearest-rank 95th percentile over a small latency sample."""
+    if not values:
+        return None
+    xs = sorted(values)
+    return xs[min(int(0.95 * (len(xs) - 1) + 0.5), len(xs) - 1)]
+
+
+def run_overload(tmp_path, monkeypatch, duration_s, rates, seed=7,
+                 wait_s=300.0):
+    """One loopback topology — master + 2 config workers, all real
+    aiohttp servers — under three Poisson tenant streams:
+
+    * **overload** (chaos ON): the classes submit plain tiny prompts
+      whose combined rate exceeds the master's (coalescing off — the
+      mixed-traffic worst case) service rate, while chaos drops, delays
+      and 5xx's the data-plane + heartbeat edges.  Admission sheds batch
+      first; weighted fair dequeue orders the queue waits;
+    * **churn**: the paid stream also carries tiled-upscale fan-out
+      jobs; worker w1 is KILLED after the first one completes — the
+      later jobs must recover through the ledger (reassign / redispatch)
+      with the chaos still armed;
+    * **convergence**: an armed FleetAutoscaler (injected spawner
+      building REAL in-process loopback workers that register and
+      heartbeat) must scale up under the backlog and scale back down
+      after the drain, with zero direction-reversal flaps.
+
+    Returns the counts the acceptance asserts on."""
+    import json
+    import random
+
+    from comfyui_distributed_tpu.utils import trace as tr
+    from tests.test_cluster import upscale_prompt
+    from tests.test_pipeline import make_prompt
+
+    # repeated seeded fan-out jobs in one process: result/tile cache
+    # hits would settle later paid jobs without dispatching — this
+    # harness exercises admission + recovery under load, pin reuse off
+    monkeypatch.setenv(C.CACHE_ENV, "0")
+    monkeypatch.setenv(C.FAULT_POLICY_ENV, "reassign")
+    monkeypatch.setenv(C.HEDGE_ENV, "1")
+    # single-process CPU proxy: jax compute starves the shared loop, so
+    # leases must be generous enough that LIVE workers don't flap dead
+    monkeypatch.setenv(C.LEASE_ENV, "4.0")
+    monkeypatch.setenv(C.SUSPECT_PROBES_ENV, "3")
+    # queue geometry for the shed ladder: batch sheds at 30% of 64,
+    # free at 65%, paid only at a full queue the drain never lets
+    # happen — "zero dropped paid" is enforced by the threshold gap
+    monkeypatch.setenv(C.MAX_QUEUE_ENV, "64")
+    monkeypatch.setenv(C.TENANT_SHED_ENV, "paid=1.0,free=0.65,batch=0.3")
+
+    async def go():
+        rng = random.Random(seed)
+        workers, cfg_workers, heartbeats = [], [], []
+
+        async def make_worker(wid):
+            wdir = tmp_path / wid
+            os.makedirs(wdir / "in", exist_ok=True)
+            st = make_state(wdir, is_worker=True)
+            client = TestClient(TestServer(build_app(st)))
+            await client.start_server()
+            return st, client
+
+        for i in range(2):
+            st, client = await make_worker(f"w{i}")
+            workers.append((st, client))
+            cfg_workers.append({"id": f"w{i}", "host": "127.0.0.1",
+                                "port": client.server.port,
+                                "enabled": True})
+        mdir = tmp_path / "master"
+        os.makedirs(mdir / "in")
+        with open(mdir / "cfg.json", "w") as f:
+            json.dump({"workers": cfg_workers,
+                       "master": {"host": "127.0.0.1"}, "settings": {}},
+                      f)
+
+        # the overload master: coalescing OFF (mixed production traffic
+        # degenerates to batch=1 — the worst case the fleet must absorb)
+        mstate = make_state(mdir, is_worker=False, overlap=True,
+                            coalesce=False)
+        mclient = TestClient(TestServer(build_app(mstate)))
+        await mclient.start_server()
+        mstate.port = mclient.server.port
+        master_url = f"http://127.0.0.1:{mstate.port}"
+        mstate.health.interval = 0.5
+        loop = asyncio.get_running_loop()
+        await loop.run_in_executor(None, mstate.health.poll_once)
+        mstate.health.start()
+
+        # config workers heartbeat their leases like spawned ones would
+        for w in cfg_workers:
+            hb = cluster_mod.HeartbeatSender(master_url, w["id"],
+                                             interval=1.0,
+                                             port=w["port"])
+            hb.start()
+            heartbeats.append(hb)
+
+        # the autoscaler, spawning REAL loopback workers (register +
+        # heartbeat) and retiring them by drain
+        spawned: dict = {}
+
+        async def spawn_async():
+            wid = f"auto{len(spawned)}"
+            st, client = await make_worker(wid)
+            hb = cluster_mod.HeartbeatSender(master_url, wid,
+                                             interval=1.0,
+                                             port=client.server.port)
+            hb.start()
+            heartbeats.append(hb)
+            spawned[wid] = (st, client, hb)
+            mstate.cluster.register(wid, info={
+                "host": "127.0.0.1", "port": client.server.port,
+                "name": wid})
+            return wid
+
+        def spawner():
+            return asyncio.run_coroutine_threadsafe(
+                spawn_async(), loop).result(timeout=30)
+
+        def retirer(wid):
+            entry = spawned.get(wid)
+            if entry is None:
+                return False
+            st, client, hb = entry
+            hb.stop()
+            asyncio.run_coroutine_threadsafe(
+                client.close(), loop).result(timeout=10)
+            st.drain(2)
+            return True
+
+        def worker_queue(wid):
+            entry = spawned.get(wid)
+            if entry is not None:
+                return entry[0].queue_remaining()
+            return None   # config workers: registry hint covers them
+
+        scaler = autoscale_mod.FleetAutoscaler(
+            registry=mstate.cluster,
+            queue_depth_fn=mstate.queue_remaining,
+            util_fn=None,
+            spawner=spawner, retirer=retirer,
+            worker_queue_fn=worker_queue,
+            min_workers=2, max_workers=4,
+            up_queue=2.0, down_queue=0.5,
+            up_util=0.95, down_util=0.99,
+            window=2, cooldown_s=3.0, interval_s=0.25, drain_s=10.0)
+        mstate.autoscaler = scaler
+
+        async def post(tenant, prompt, **extra):
+            r = await mclient.post("/prompt", json={
+                "prompt": prompt, "client_id": f"{tenant}-client",
+                "priority": tenant, **extra})
+            return r.status, await r.json()
+
+        def post_plain(tenant, seq):
+            return post(tenant, make_prompt(1000 + seq, steps=2,
+                                            text="a lighthouse"))
+
+        def post_fanout(tenant, seed_):
+            # 96px -> 9 tiles of 32px over master + 2 workers
+            return post(tenant, upscale_prompt(seed=seed_, size=96),
+                        slo_s=60.0)
+
+        async def wait_history(pids, bound_s):
+            deadline = time.monotonic() + bound_s
+            while time.monotonic() < deadline:
+                hist = await (await mclient.get("/history")).json()
+                if all(p in hist for p in pids):
+                    return hist
+                await asyncio.sleep(0.05)
+            return await (await mclient.get("/history")).json()
+
+        try:
+            # warm every participant's compiled programs with chaos OFF:
+            # one plain prompt and one fan-out job
+            for status, body in (await post_plain("paid", 0),
+                                 await post_fanout("paid", 5)):
+                assert status == 200, body
+                await wait_history([body["prompt_id"]], wait_s)
+
+            # arm chaos for everything that follows: the data-plane +
+            # heartbeat edges flake at ~5%, uploads corrupt at 2% — the
+            # retry/idempotency machinery must absorb it all
+            chaos_mod.set_chaos({
+                "drop_pct": 5, "delay_pct": 5, "delay_s": 0.05,
+                "http_5xx_pct": 5, "corrupt_pct": 2, "seed": seed,
+                "routes": ["/distributed/tile_complete",
+                           "/distributed/job_complete",
+                           "/distributed/heartbeat"]})
+            chaos_before = {
+                k: v for k, v in tr.GLOBAL_COUNTERS.snapshot().items()
+                if k.startswith("chaos_")}
+            scaler.start()
+
+            # the Poisson overload window with chaos armed.  Independent
+            # exponential inter-arrival streams per class; the paid
+            # stream additionally carries the fan-out jobs whose worker
+            # gets killed mid-window.
+            submissions = {cls: [] for cls in rates}   # (pid, t_submit)
+            sheds = {cls: 0 for cls in rates}
+            fanout_pids = []
+            killed = {"done": False}
+
+            async def tenant_stream(cls, rate):
+                t_end = time.monotonic() + duration_s
+                seq = 0
+                while time.monotonic() < t_end:
+                    await asyncio.sleep(rng.expovariate(rate))
+                    t_sub = time.time()
+                    status, body = await post_plain(cls, seq)
+                    seq += 1
+                    if status == 200:
+                        submissions[cls].append(
+                            (body["prompt_id"], t_sub))
+                    elif status == 429:
+                        sheds[cls] += 1
+                    else:
+                        raise AssertionError(
+                            f"{cls} submit -> {status}: {body}")
+
+            async def churn():
+                # fan-out job 1 completes pre-kill; then w1 dies; jobs
+                # 2 and 3 must complete through ledger recovery
+                status, body = await post_fanout("paid", 101)
+                assert status == 200, body
+                fanout_pids.append(body["prompt_id"])
+                await wait_history([body["prompt_id"]], wait_s)
+                await asyncio.sleep(duration_s * 0.25)
+                await workers[1][1].close()
+                killed["done"] = True
+                for s in (102, 103):
+                    status, body = await post_fanout("paid", s)
+                    assert status == 200, body
+                    fanout_pids.append(body["prompt_id"])
+
+            await asyncio.gather(
+                churn(), *(tenant_stream(cls, r)
+                           for cls, r in rates.items()))
+            admitted_pids = [p for cls in submissions
+                             for p, _ in submissions[cls]] + fanout_pids
+            hist = await wait_history(admitted_pids, wait_s)
+
+            # convergence: the drained fleet must scale back down
+            # (retire the autoscaled workers) without flapping
+            deadline = time.monotonic() + 60
+            while time.monotonic() < deadline:
+                snap = scaler.snapshot()
+                if snap["scale_downs"] >= 1 and not snap["retiring"] \
+                        and not snap["spawned"]:
+                    break
+                await asyncio.sleep(0.25)
+            scaler.stop()
+            chaos_mod.set_chaos(None)
+            mstate.health.stop()
+
+            def succeeded(pid):
+                return (hist.get(pid) or {}).get("status") == "success"
+
+            per_class = {}
+            for cls in rates:
+                lats = [hist[pid]["finished_at"] - t_sub
+                        for pid, t_sub in submissions[cls]
+                        if succeeded(pid)]
+                per_class[cls] = {"admitted": len(submissions[cls]),
+                                  "shed": sheds[cls],
+                                  "completed": len(lats),
+                                  "p95_s": _p95(lats)}
+            chaos_injected = {
+                k.split("chaos_", 1)[1]: v - chaos_before.get(k, 0)
+                for k, v in tr.GLOBAL_COUNTERS.snapshot().items()
+                if k.startswith("chaos_")}
+            return {
+                "per_class": per_class,
+                "fanout_jobs": len(fanout_pids),
+                "fanout_completed": sum(map(succeeded, fanout_pids)),
+                "worker_killed": killed["done"],
+                "autoscale": scaler.snapshot(),
+                "chaos_injected": chaos_injected,
+            }
+        finally:
+            chaos_mod.set_chaos(None)
+            scaler.stop()
+            mstate.health.stop()
+            for hb in heartbeats:
+                hb.stop()
+            await mclient.close()
+            for _st, client in list(workers) \
+                    + [(s, c) for s, c, _h in spawned.values()]:
+                try:
+                    await client.close()
+                except Exception:  # noqa: BLE001 - already closed
+                    pass
+            mstate.drain(5)
+            for st, _ in workers:
+                st.drain(5)
+            for st, _c, _h in spawned.values():
+                st.drain(2)
+
+    return asyncio.run(go())
+
+
 @pytest.mark.slow
 class TestOverloadAcceptance:
-    def test_three_tenants_killed_worker_chaos(self):
+    def test_three_tenants_killed_worker_chaos(self, tmp_path,
+                                               monkeypatch):
         """ISSUE 9 acceptance, scaled down: 3 Poisson tenants + 1
         killed worker + injected 5xx/drops/delays -> every admitted job
         (paid ESPECIALLY) completes, shedding is batch-first with paid
         untouched, the p95 ordering holds, and the autoscaler scales
         up AND down without a flap."""
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-        m = bench.measure_overload(duration_s=6.0,
-                                   rates={"paid": 2.0, "free": 2.5,
-                                          "batch": 3.0})
+        m = run_overload(tmp_path, monkeypatch, duration_s=6.0,
+                         rates={"paid": 2.0, "free": 2.5, "batch": 3.0})
+        paid, free, batch = (m["per_class"][c]
+                             for c in ("paid", "free", "batch"))
         assert m["worker_killed"]
-        assert m["paid_shed"] == 0
-        assert m["paid_completion_rate"] == 1.0
-        assert m["completion_rate"] == 1.0
+        assert paid["shed"] == 0
         assert m["fanout_completed"] == m["fanout_jobs"]
-        assert m["batch_shed"] >= 1
-        assert m["batch_shed"] >= m["free_shed"]
-        assert m["p95_paid_s"] is not None \
-            and m["p95_batch_s"] is not None
-        assert m["p95_paid_s"] < m["p95_batch_s"]
-        assert m["scale_ups"] >= 1 and m["scale_downs"] >= 1
-        assert m["autoscale_flaps"] == 0
+        for cls in (paid, free, batch):       # every admitted job done
+            assert cls["completed"] == cls["admitted"], m["per_class"]
+        assert batch["shed"] >= 1
+        assert batch["shed"] >= free["shed"]
+        assert paid["p95_s"] is not None and batch["p95_s"] is not None
+        assert paid["p95_s"] < batch["p95_s"]
+        scale = m["autoscale"]
+        assert scale["scale_ups"] >= 1 and scale["scale_downs"] >= 1
+        assert scale["flaps"] == 0
         assert sum(m["chaos_injected"].values()) >= 1

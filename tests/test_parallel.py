@@ -10,7 +10,8 @@ from jax.sharding import PartitionSpec as P
 
 from comfyui_distributed_tpu.parallel import collectives as coll
 from comfyui_distributed_tpu.parallel import mesh as mesh_mod
-from comfyui_distributed_tpu.utils.constants import DATA_AXIS, SEQ_AXIS, TENSOR_AXIS
+from comfyui_distributed_tpu.utils.constants import (
+    DATA_AXIS, MESH_SHAPE_ENV, SEQ_AXIS, TENSOR_AXIS, TP_ENV)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -35,6 +36,18 @@ class TestMesh:
     def test_fill_axis(self):
         m = mesh_mod.build_mesh({DATA_AXIS: -1, TENSOR_AXIS: 4})
         assert m.shape[DATA_AXIS] == 2
+
+    def test_tp_env_resolves_the_serving_mesh(self, monkeypatch):
+        """DTPU_TP=2, the serve path's switch: tensor=2 and the rest of
+        the devices on the data axis (no runtime goes live here, so the
+        compile cache stays on)."""
+        monkeypatch.delenv(MESH_SHAPE_ENV, raising=False)
+        monkeypatch.setenv(TP_ENV, "2")
+        assert mesh_mod.axes_from_env() == {TENSOR_AXIS: 2, DATA_AXIS: -1}
+        m = mesh_mod.build_mesh(devices=jax.devices()[:4])
+        assert m.shape[DATA_AXIS] == 2 and m.shape[TENSOR_AXIS] == 2
+        monkeypatch.setenv(TP_ENV, "1")
+        assert mesh_mod.axes_from_env() is None
 
     def test_bad_product_raises(self):
         with pytest.raises(ValueError):

@@ -1,6 +1,6 @@
 """Resource telemetry plane (ISSUE 5): probes, rings, monitor, per-job
-HBM attribution, fleet federation, freed-bytes clear_memory, build-info
-gauge, and the bench perf-regression watchdog.
+HBM attribution, fleet federation, freed-bytes clear_memory and the
+build-info gauge.
 
 All CPU-only: the device-memory probe exercises the RSS fallback the CPU
 backend forces (its ``memory_stats()`` returns None on this JAX), and
@@ -9,9 +9,6 @@ aiohttp test servers.
 """
 
 import asyncio
-import json
-import os
-import sys
 import time
 
 import pytest
@@ -26,15 +23,6 @@ from comfyui_distributed_tpu.utils import trace as tr
 
 from test_observability import (make_prompt, run_with_client,
                                 validate_prometheus)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _bench():
-    if REPO not in sys.path:
-        sys.path.insert(0, REPO)
-    import bench
-    return bench
 
 
 @pytest.fixture(autouse=True)
@@ -445,119 +433,3 @@ class TestLocalMetricsSurfaces:
             assert any(l.startswith("dtpu_res_host_rss_bytes ")
                        for l in text.splitlines())
         run_with_client(body, tmp_path, start_exec_thread=False)
-
-
-# --- bench perf-regression watchdog ------------------------------------------
-
-class TestBenchCheck:
-    def _payload(self, value, unit="imgs/s", metric="m"):
-        return {"metric": metric, "value": value, "unit": unit}
-
-    def test_flags_injected_20pct_regression(self):
-        bench = _bench()
-        v = bench.check_regression(self._payload(0.8),
-                                   self._payload(1.0),
-                                   tolerance_pct=3.0)
-        assert v["regressed"] is True
-        assert v["change_pct"] == -20.0
-
-    def test_passes_within_tolerance(self):
-        bench = _bench()
-        v = bench.check_regression(self._payload(0.99),
-                                   self._payload(1.0),
-                                   tolerance_pct=3.0)
-        assert v["regressed"] is False
-
-    def test_improvement_never_regresses(self):
-        bench = _bench()
-        v = bench.check_regression(self._payload(2.0),
-                                   self._payload(1.0),
-                                   tolerance_pct=0.0)
-        assert v["regressed"] is False
-
-    def test_lower_is_better_direction(self):
-        bench = _bench()
-        worse = bench.check_regression(
-            self._payload(1.3, unit="sec/image"),
-            self._payload(1.0, unit="sec/image"), tolerance_pct=10.0)
-        assert worse["regressed"] is True
-        better = bench.check_regression(
-            self._payload(0.8, unit="sec/image"),
-            self._payload(1.0, unit="sec/image"), tolerance_pct=10.0)
-        assert better["regressed"] is False
-
-    def test_no_baseline_value_passes(self):
-        bench = _bench()
-        v = bench.check_regression(self._payload(1.0),
-                                   self._payload(0.0))
-        assert v["regressed"] is False
-        assert "note" in v
-
-    def test_per_metric_tolerance_lookup(self):
-        bench = _bench()
-        v = bench.check_regression(
-            self._payload(0.99, metric="fault_recovery_completion_rate",
-                          unit="fraction"),
-            self._payload(1.0, metric="fault_recovery_completion_rate",
-                          unit="fraction"))
-        assert v["tolerance_pct"] == 0.0
-        assert v["regressed"] is True  # completion rate tolerates nothing
-
-    def test_check_against_non_object_fails_cleanly(self, tmp_path):
-        # a valid-JSON but non-object baseline (e.g. a sweep table) must
-        # produce the clean rc=1 path, not an AttributeError
-        import argparse
-        bench = _bench()
-        bad = tmp_path / "sweep.json"
-        bad.write_text(json.dumps([1, 2, 3]))
-        prev = bench._LAST_PAYLOAD
-        bench._LAST_PAYLOAD = self._payload(1.0)
-        try:
-            rc = bench.run_check(argparse.Namespace(
-                check_against=str(bad), check_tolerance=None, out=None))
-        finally:
-            bench._LAST_PAYLOAD = prev
-        assert rc == 1
-
-    def test_check_against_metric_mismatch_fails(self, tmp_path):
-        # an explicit baseline for a DIFFERENT metric must be an error,
-        # not a silently meaningless comparison
-        import argparse
-        bench = _bench()
-        other = tmp_path / "other.json"
-        other.write_text(json.dumps(
-            {"metric": "other_metric", "value": 9.0, "unit": "imgs/s"}))
-        prev = bench._LAST_PAYLOAD
-        bench._LAST_PAYLOAD = self._payload(1.0)
-        try:
-            rc = bench.run_check(argparse.Namespace(
-                check_against=str(other), check_tolerance=None,
-                out=None))
-        finally:
-            bench._LAST_PAYLOAD = prev
-        assert rc == 1
-
-    def test_find_prior_artifact_scans_and_filters(self, tmp_path):
-        bench = _bench()
-        (tmp_path / "BENCH_a.json").write_text(json.dumps(
-            {"metric": "m1", "value": 1.0, "unit": "imgs/s"}))
-        time.sleep(0.02)
-        (tmp_path / "BENCH_b.json").write_text(json.dumps(
-            {"n": 2, "parsed": {"metric": "m1", "value": 2.0,
-                                "unit": "imgs/s"}}))
-        (tmp_path / "BENCH_zero.json").write_text(json.dumps(
-            {"metric": "m1", "value": 0.0, "unit": "imgs/s"}))
-        (tmp_path / "not_bench.json").write_text(json.dumps(
-            {"metric": "m1", "value": 9.0}))
-        found = bench.find_prior_artifact("m1", search_dir=str(tmp_path))
-        assert found is not None
-        path, payload = found
-        assert path.endswith("BENCH_b.json")  # newest, parsed shape
-        assert payload["value"] == 2.0
-        assert bench.find_prior_artifact("nope",
-                                         search_dir=str(tmp_path)) is None
-        # excluding the fresh run's own --out file
-        found = bench.find_prior_artifact(
-            "m1", search_dir=str(tmp_path),
-            exclude=(str(tmp_path / "BENCH_b.json"),))
-        assert found[0].endswith("BENCH_a.json")

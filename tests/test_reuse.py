@@ -302,10 +302,12 @@ class TestKillSwitch:
 # --- result tier (server level) ----------------------------------------------
 
 class TestResultTier:
-    def test_exact_hit_replay_and_near_miss(self, tmp_path):
+    def test_exact_hit_replay_and_near_miss(self, tmp_path,
+                                            assert_nothing_compiled):
         st = make_state(tmp_path)
         pid1 = st.enqueue_prompt(make_prompt(42), "c")
         wait_history(st, [pid1])
+        retraces = trace_mod.GLOBAL_RETRACES.mark()
         # byte-identical re-submission: settled synchronously, stamped
         t0 = time.perf_counter()
         pid2 = st.enqueue_prompt(make_prompt(42), "c")
@@ -321,6 +323,9 @@ class TestResultTier:
                     if s["span_id"] == rec["root_span_id"])
         assert root["attrs"]["cache_hit"] is True
         assert root["attrs"]["cache_tier"] == "result"
+        # the replay ran no program, so it compiled none
+        assert_nothing_compiled(
+            trace_mod.GLOBAL_RETRACES.since(retraces))
         # near miss: ONE widget changed -> full execution, no hit
         pid3 = st.enqueue_prompt(make_prompt(42, cfg=2.5), "c")
         hist = wait_history(st, [pid3])
@@ -378,8 +383,8 @@ class TestResultTier:
 # --- sub-graph tier ----------------------------------------------------------
 
 class TestEmbedTier:
-    def test_variant_storm_hits_and_stays_bit_identical(self, tmp_path,
-                                                        monkeypatch):
+    def test_variant_storm_hits_and_stays_bit_identical(
+            self, tmp_path, monkeypatch, assert_nothing_compiled):
         """Seed variants share the text encodes; the cached-conditioning
         run's image is bit-identical to a cache-off run."""
         ctx = lambda: OpContext(input_dir=str(tmp_path),  # noqa: E731
@@ -389,6 +394,7 @@ class TestEmbedTier:
         cached = WorkflowExecutor(ctx()).execute(make_prompt(2))
         assert reuse_mod.get_reuse().subgraph.snapshot()["hits"] \
             >= before + 2                                  # both encodes
+        assert_nothing_compiled(cached.retraces)
         monkeypatch.setenv(C.CACHE_ENV, "0")
         plain = WorkflowExecutor(ctx()).execute(make_prompt(2))
         assert np.array_equal(cached.images[0], plain.images[0])
@@ -580,6 +586,9 @@ class TestPreviewSSEAcceptance:
                 assert hist[pid_next]["status"] == "success"
                 assert state.cb.snapshot()["slots_active"] == 0
                 assert state.cb.snapshot()["abandoned"] == 1
+                # the freed slot's exit is in the abandoned job's trace
+                rec = trace_mod.GLOBAL_TRACES.get(pid_long)
+                assert any(s["name"] == "cb_exit" for s in rec["spans"])
                 # both metrics surfaces carry the counters
                 m = await (await client.get(
                     "/distributed/metrics")).json()

@@ -6,9 +6,9 @@ gets a test class here: a (seed, scenario) pair must fully determine
 the event log (byte-identical digests across runs), the capture-replay
 adapter must survive torn segment tails without drifting the virtual
 clock, and the committed scenario fixtures must keep reproducing the
-measured bench artifacts (the same gate ``bench.py --phase sim``
-enforces, run in-tree so a policy change that un-calibrates the twin
-fails fast).
+measured records beside them (``*.measured.json``: loopback runs of
+``tiny`` on the CPU, kept as the calibration target), so a policy change
+that un-calibrates the twin fails fast.
 """
 
 import copy
@@ -212,14 +212,14 @@ class TestReplayAdapter:
 
 
 class TestCalibration:
-    """The in-tree copy of the ``bench.py --phase sim`` gate: the
-    committed fixtures must keep reproducing the measured artifacts.
-    A change to scheduler/cluster/autoscale policy code that breaks
-    this is a real behavior change — recalibrate deliberately (see
-    benchmarks/README) or fix the regression."""
+    """The calibration gate: the committed fixtures must keep
+    reproducing the measured records.  A change to scheduler/cluster/
+    autoscale policy code that breaks this is a real behavior change —
+    recalibrate deliberately (see benchmarks/README) or fix the
+    regression."""
 
     def _score(self, kind, scn, art):
-        with open(os.path.join(ROOT, art)) as f:
+        with open(os.path.join(SCEN, art)) as f:
             artifact = json.load(f)
         summary = fleet.run_scenario(
             sc_mod.load_scenario(os.path.join(SCEN, scn)))
@@ -227,27 +227,27 @@ class TestCalibration:
 
     def test_overload_fixture_within_gate(self):
         score = self._score("overload", "overload_r09.json",
-                            "BENCH_overload_r09.json")
+                            "overload_r09.measured.json")
         assert score["bars_failed"] == []
         assert score["mean_rel_err"] <= C.SIM_CALIBRATION_MAX_ERR
 
     def test_multimaster_fixture_within_gate(self):
         score = self._score("multimaster", "multimaster_r14.json",
-                            "BENCH_multimaster_r14.json")
+                            "multimaster_r14.measured.json")
         assert score["bars_failed"] == []
         assert score["mean_rel_err"] <= C.SIM_CALIBRATION_MAX_ERR
 
     def test_combine_matches_committed_artifact(self):
         scores = {
             "overload": self._score("overload", "overload_r09.json",
-                                    "BENCH_overload_r09.json"),
+                                    "overload_r09.measured.json"),
             "multimaster": self._score("multimaster",
                                        "multimaster_r14.json",
-                                       "BENCH_multimaster_r14.json"),
+                                       "multimaster_r14.measured.json"),
         }
         comb = calibrate.combine(scores)
         assert comb["ok"]
-        with open(os.path.join(ROOT, "BENCH_sim_r19.json")) as f:
+        with open(os.path.join(SCEN, "sim_r19.calibration.json")) as f:
             committed = json.load(f)
         assert comb["calibration_error"] == committed["value"]
 
@@ -282,10 +282,8 @@ class TestSweep:
 
 class TestScaleSmoke:
     def test_midsize_fleet_drains_quickly(self):
-        """A 100-worker diurnal slice: the same shape as the bench's
-        1000-worker scale proof (that one lives in ``bench.py --phase
-        sim`` where its ~30s wall budget belongs), small enough for
-        the tier-1 gate."""
+        """A 100-worker diurnal slice: the same shape as the
+        1000-worker day below, instant."""
         spec = {
             "name": "scale_smoke", "seed": 7, "duration_s": 120.0,
             "traffic": [
@@ -314,6 +312,16 @@ class TestScaleSmoke:
         assert s["completion_rate"] == 1.0
         assert s["admitted_total"] > 1000
         assert s["counters"].get("worker_kills") == 1
+
+    def test_thousand_worker_day_drains(self):
+        """The committed 1000-worker diurnal day: at least 100k virtual
+        prompts, every one completed, the fleet drained (about 20 s of
+        pure Python on one core)."""
+        s = fleet.run_scenario(sc_mod.load_scenario(
+            os.path.join(SCEN, "diurnal_1k.json")))
+        assert s["admitted_total"] >= 100_000
+        assert s["drained"]
+        assert s["completion_rate"] == 1.0
 
 
 class TestCliSim:

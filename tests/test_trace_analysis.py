@@ -25,7 +25,7 @@ from comfyui_distributed_tpu.utils import trace_analysis as ta
 from comfyui_distributed_tpu.utils import trace_export as te
 from tests.test_observability import (make_prompt, run_with_client,
                                       validate_prometheus,
-                                      wait_remote_history)
+                                      wait_remote_history, wait_trace)
 
 
 @pytest.fixture(autouse=True)
@@ -517,8 +517,8 @@ class TestSimCaptureRoundTrip:
 
 
 class TestServerSurfaces:
-    def test_analysis_route_metrics_and_reset(self, tmp_path,
-                                              monkeypatch):
+    def test_analysis_route_metrics_and_reset(self, tmp_path, monkeypatch,
+                                              assert_nothing_compiled):
         # a deliberately-stale baseline: any real prompt's compute
         # blows past it, so the live plane must flag anomalies
         path = str(tmp_path / "base.json")
@@ -561,6 +561,22 @@ class TestServerSurfaces:
             val = [l for l in text.splitlines()
                    if l.startswith("dtpu_analysis_anomalies_total ")]
             assert val and float(val[0].split()[-1]) >= 1
+
+            # a served job's blame cover reconstructs its e2e exactly
+            await wait_trace(client, pid)
+            bd = ta.critical_path(tr.GLOBAL_TRACES.get(pid))
+            assert bd["e2e_s"] > 0
+            assert abs(sum(bd["categories"].values())
+                       + bd["unattributed_s"] - bd["e2e_s"]) \
+                <= 1e-3 * bd["e2e_s"]
+
+            # the armed plane never touches compiled code: a warm
+            # request compiles nothing
+            mark = tr.GLOBAL_RETRACES.mark()
+            r = await client.post("/prompt", json={
+                "prompt": make_prompt(22), "client_id": "an"})
+            await wait_remote_history(client, (await r.json())["prompt_id"])
+            assert_nothing_compiled(tr.GLOBAL_RETRACES.since(mark))
 
             # total reset clears the analytics plane too
             r = await client.post("/distributed/metrics/reset", json={})

@@ -2,6 +2,7 @@
 
 import copy
 import json
+import os
 
 import jax
 import numpy as np
@@ -12,8 +13,19 @@ from comfyui_distributed_tpu.ops.base import OpContext
 from comfyui_distributed_tpu.parallel import mesh as mesh_mod
 from comfyui_distributed_tpu.workflow import WorkflowExecutor, parse_workflow
 
-TXT2IMG = "/root/reference/workflows/distributed-txt2img.json"
-UPSCALE = "/root/reference/workflows/distributed-upscale.json"
+_TESTS = os.path.dirname(os.path.abspath(__file__))
+_WORKFLOWS = os.path.join(os.path.dirname(_TESTS), "workflows")
+TXT2IMG = os.path.join(_WORKFLOWS, "distributed-txt2img.json")
+UPSCALE = os.path.join(_WORKFLOWS, "distributed-upscale.json")
+# the txt2img graph as the ComfyUI front end saves it (UI format); no
+# workflow the repo ships is written in that format
+TXT2IMG_UI = os.path.join(_TESTS, "fixtures", "distributed-txt2img.ui.json")
+
+
+def _only(g, class_type):
+    """The id of the graph's one node of this type."""
+    (nid,) = g.find_by_type(class_type)
+    return nid
 
 
 @pytest.fixture(autouse=True)
@@ -28,38 +40,45 @@ def ctx():
 
 
 class TestParse:
+    """The saved (UI) format: ``nodes`` / ``links``, positional
+    ``widgets_values``, bypassed and muted nodes, round trip to API
+    format."""
+
     def test_txt2img_parses(self):
-        g = parse_workflow(TXT2IMG)
+        g = parse_workflow(TXT2IMG_UI)
         assert len(g.nodes) == 9
-        ks = g.nodes["8"]
-        assert ks.class_type == "KSampler"
+        ks = g.nodes[_only(g, "KSampler")]
         # widget mapping: [seed, control, steps, cfg, sampler, scheduler, den]
         assert ks.inputs["steps"] == 20
-        assert ks.inputs["cfg"] == 6
+        assert ks.inputs["cfg"] == 7.0
         assert ks.inputs["sampler_name"] == "euler"
-        assert ks.inputs["scheduler"] == "normal"
+        assert ks.inputs["scheduler"] == "karras"
         assert ks.inputs["denoise"] == 1
-        # seed widget overridden by link from DistributedSeed (node 4)
-        assert ks.inputs["seed"] == ["4", 0]
-        assert g.nodes["9"].inputs["width"] == 512
+        # seed widget overridden by the link from DistributedSeed
+        assert ks.inputs["seed"] == [_only(g, "DistributedSeed"), 0]
+        assert g.nodes[_only(g, "EmptyLatentImage")].inputs["width"] == 1024
+        # the same nodes, inputs and edges as the API file it was written
+        # from, before the bypass / mute cases below edit it
+        assert g.to_api_format() == parse_workflow(TXT2IMG).to_api_format()
 
     def test_upscale_parses(self):
         g = parse_workflow(UPSCALE)
         assert len(g.nodes) == 9
-        up = g.nodes["13"]
+        up = g.nodes[_only(g, "UltimateSDUpscaleDistributed")]
         assert up.inputs["tile_width"] == 512
         assert up.inputs["padding"] == 32
-        assert up.inputs["mask_blur"] == 16
+        assert up.inputs["mask_blur"] == 8
         assert up.inputs["force_uniform_tiles"] is True
-        assert abs(up.inputs["denoise"] - 0.24) < 1e-6
-        assert up.inputs["upscaled_image"] == ["17", 0]
+        assert abs(up.inputs["denoise"] - 0.35) < 1e-6
+        assert up.inputs["upscaled_image"] == [_only(g, "ImageScale"), 0]
 
     def test_topo_order(self):
-        g = parse_workflow(TXT2IMG)
+        g = parse_workflow(TXT2IMG_UI)
         order = g.topo_order()
-        assert order.index("7") < order.index("8")   # ckpt before sampler
-        assert order.index("8") < order.index("1")   # sampler before decode
-        assert order.index("2") < order.index("3")   # collector before preview
+        at = {g.nodes[n].class_type: order.index(n) for n in order}
+        assert at["CheckpointLoaderSimple"] < at["KSampler"]
+        assert at["KSampler"] < at["VAEDecode"]
+        assert at["DistributedCollector"] < at["PreviewImage"]
 
     def test_cycle_detection(self):
         g = parse_workflow(json.dumps({
@@ -74,38 +93,42 @@ class TestParse:
     def test_bypassed_node_passes_through(self):
         """Mode-4 (bypass) nodes are removed with links rewired through
         type-matching inputs — ComfyUI bypass semantics."""
-        doc = json.load(open(TXT2IMG))
+        whole = parse_workflow(TXT2IMG_UI)
+        doc = json.load(open(TXT2IMG_UI))
         for n in doc["nodes"]:
             if n["type"] == "DistributedCollector":
                 n["mode"] = 4
         g = parse_workflow(doc)
-        assert "2" not in g.nodes
-        # PreviewImage (3) now feeds directly from VAEDecode (1)
-        assert g.nodes["3"].inputs["images"] == ["1", 0]
+        assert _only(whole, "DistributedCollector") not in g.nodes
+        # PreviewImage now feeds directly from VAEDecode
+        assert g.nodes[_only(g, "PreviewImage")].inputs["images"] == \
+            [_only(g, "VAEDecode"), 0]
 
     def test_muted_node_drops_link(self):
-        doc = json.load(open(TXT2IMG))
+        whole = parse_workflow(TXT2IMG_UI)
+        doc = json.load(open(TXT2IMG_UI))
         for n in doc["nodes"]:
             if n["type"] == "DistributedSeed":
                 n["mode"] = 2
         g = parse_workflow(doc)
-        assert "4" not in g.nodes
+        assert _only(whole, "DistributedSeed") not in g.nodes
         # KSampler keeps its widget seed; the dead link is dropped
-        assert isinstance(g.nodes["8"].inputs["seed"], int)
+        assert isinstance(g.nodes[_only(g, "KSampler")].inputs["seed"], int)
 
     def test_api_format_round_trip(self):
-        g = parse_workflow(TXT2IMG)
+        g = parse_workflow(TXT2IMG_UI)
         api = g.to_api_format()
         g2 = parse_workflow(json.dumps(api))
         assert set(g2.nodes) == set(g.nodes)
-        assert g2.nodes["8"].inputs["steps"] == 20
+        assert g2.nodes[_only(g2, "KSampler")].inputs["steps"] == 20
 
 
 def _scaled_txt2img(width=64, height=64, steps=2, batch=1):
-    """Reference txt2img graph with sizes/steps scaled for CPU tests."""
+    """The txt2img fixture with sizes/steps scaled for CPU tests."""
     g = parse_workflow(TXT2IMG)
-    g.nodes["9"].inputs.update(width=width, height=height, batch_size=batch)
-    g.nodes["8"].inputs.update(steps=steps)
+    g.nodes[_only(g, "EmptyLatentImage")].inputs.update(
+        width=width, height=height, batch_size=batch)
+    g.nodes[_only(g, "KSampler")].inputs.update(steps=steps)
     return g
 
 
@@ -130,7 +153,7 @@ class TestTxt2ImgE2E:
         """Without DistributedSeed all participants produce the same images
         (reference parity: seed fan-out is what makes replicas differ)."""
         g = _scaled_txt2img()
-        g.nodes["8"].inputs["seed"] = 1234  # break link, plain int
+        g.nodes[_only(g, "KSampler")].inputs["seed"] = 1234  # break link
         res = WorkflowExecutor(ctx).execute(g)
         imgs = np.stack(res.images)
         assert imgs.shape[0] == 8
@@ -466,11 +489,12 @@ class TestInpaintE2E:
 
 def _scaled_upscale(tile=32, padding=8, blur=2, steps=1):
     g = parse_workflow(UPSCALE)
-    g.nodes["12"].inputs["image"] = "__missing__.png"   # synthetic test card
-    g.nodes["17"].inputs.update(width=64, height=64)
-    g.nodes["13"].inputs.update(steps=steps, tile_width=tile,
-                                tile_height=tile, padding=padding,
-                                mask_blur=blur)
+    # synthetic test card
+    g.nodes[_only(g, "LoadImage")].inputs["image"] = "__missing__.png"
+    g.nodes[_only(g, "ImageScale")].inputs.update(width=64, height=64)
+    g.nodes[_only(g, "UltimateSDUpscaleDistributed")].inputs.update(
+        steps=steps, tile_width=tile, tile_height=tile, padding=padding,
+        mask_blur=blur)
     return g
 
 
