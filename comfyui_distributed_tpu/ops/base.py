@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -162,6 +162,10 @@ class OpContext:
     # (server/lm_handover.py): the requests waiting behind this one join
     # its execution.  None outside a server: the node runs its one row.
     lm_handover: Any = None
+    # a server's count of the images the device still owes it: called at
+    # this run's deferred host edge the moment the device has produced
+    # the image (`fetch_image_array`), before the copy and the PNG
+    device_ready: Optional[Callable[[], None]] = None
 
     def check_interrupt(self):
         if self.interrupt_event is not None and self.interrupt_event.is_set():
@@ -331,19 +335,22 @@ def fanout_meta(x) -> Dict[str, Any]:
     return meta
 
 
-def fetch_image_array(x) -> np.ndarray:
+def fetch_image_array(x, ready: Optional[Callable[[], None]] = None
+                      ) -> np.ndarray:
     """:func:`as_image_array` at a deferred host edge (PNG, HTTP wire),
     as the ``d2h`` stage it always was, now told apart inside: first
     ``device_wait`` until the device has produced ``x`` (dispatch is
     asynchronous, so this is where the host meets the still-running
-    program; its end is the request's ``device_ready`` instant), then
-    ``d2h_copy`` for the copy alone."""
+    program; its end is the request's ``device_ready`` instant, and
+    ``ready`` is called there), then ``d2h_copy`` for the copy alone."""
     with trace_mod.stage("d2h"):
         dev = x.data if isinstance(x, DeviceTensor) else x
         if isinstance(dev, jax.Array):
             with trace_mod.device_wait():
                 jax.block_until_ready(dev)
         trace_mod.mark_instant("device_ready")
+        if ready is not None:
+            ready()
         with trace_mod.stage("d2h_copy"):
             return as_image_array(x)
 

@@ -4337,8 +4337,10 @@ class PreviewImage(Op):
     OUTPUT_NODE = True
 
     def execute(self, ctx: OpContext, images):
+        ready = ctx.device_ready
+
         def host_side():
-            arr = fetch_image_array(images)
+            arr = fetch_image_array(images, ready)
             return list(arr)
 
         # overlapped pipeline: the d2h fetch rides the host-IO pool (it
@@ -4368,11 +4370,11 @@ class SaveImage(Op):
         # the next job is already being set up.  Coalesced runs get one
         # metadata per MERGED PROMPT (each with its own seed values) so
         # a saved PNG dragged back into a UI reproduces ITS image.
-        output_dir = ctx.output_dir
+        output_dir, ready = ctx.output_dir, ctx.device_ready
         metas = _png_metadata_per_prompt(ctx)
 
         def host_side():
-            arr = fetch_image_array(images)
+            arr = fetch_image_array(images, ready)
             if output_dir:
                 probe = _safe_output_path(output_dir,
                                           f"{filename_prefix}_00000.png")

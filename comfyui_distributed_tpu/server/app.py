@@ -48,6 +48,7 @@ from __future__ import annotations
 import asyncio
 import base64
 import collections
+import functools
 import itertools
 import json
 import math
@@ -234,6 +235,12 @@ class ServerState:
         # what a LanguageModelGenerate node sees of this queue, and the
         # rows it ran for requests still in it (server/lm_handover.py)
         self.lm_handover = GenerateHandover(self)
+        # the head ids of the dispatched groups whose image the device
+        # still owes: in when the group's last enqueue has returned, out
+        # at its device_ready or however else it ends.  A generate node
+        # with room in its row set waits here for the set to empty
+        self._owed: set = set()            # guarded-by: self._queue_lock
+        self._drained = threading.Condition(self._queue_lock)
         self._running = False
         self._draining = False
         self._history: Dict[str, Any] = {}
@@ -623,6 +630,8 @@ class ServerState:
                 ledger=self.ledger,
                 fault_inject=self.fault_inject,
                 lm_handover=self.lm_handover,
+                device_ready=functools.partial(self._image_settled,
+                                               group[0]),
             )
             first = group[0]
             trace_mod.GLOBAL_COUNTERS.bump("exec_runs")
@@ -669,6 +678,9 @@ class ServerState:
             with self._queue_lock:
                 self._running = False
                 self._finalize_pending += 1
+                if res is not None and res.image_futures \
+                        and not group[0].get("settled"):
+                    self._owed.add(group[0]["id"])
         if self.overlap_enabled:
             # hand host-side joining to the finalizer so the next
             # group's compute starts NOW — this is the overlap
@@ -692,6 +704,18 @@ class ServerState:
         while True:
             group, res, err, t0 = self._finalize_q.get()
             self._finalize_group(group, res, err, t0)
+
+    def _image_settled(self, head: Dict[str, Any]) -> None:
+        """The device owes the group of ``head`` no image any longer: its
+        deferred host edge has met the device (``OpContext.device_ready``,
+        on the pool's thread), or the group has ended some other way
+        (`_finalize_group`).  The first call counts; it may come before
+        the group is entered, when the image was out before the graph's
+        last node returned."""
+        with self._queue_lock:
+            head["settled"] = True
+            self._owed.discard(head["id"])
+            self._drained.notify_all()
 
     def _record_queue_to_device(self, group, ready_fallback: float) -> None:
         """``queue_to_device``: enqueue to the instant the request's own
@@ -783,6 +807,9 @@ class ServerState:
                     res.wait_host()
             except Exception as e:  # noqa: BLE001 - host edge failed
                 err = e
+        # a group whose host edge raised, or that had no image to wait
+        # for, is owed nothing either
+        self._image_settled(group[0])
         k = len(group)
         done_t = time.time()
         abandoned = isinstance(err, reuse_mod.AbandonedError)
@@ -1011,6 +1038,10 @@ class ServerState:
             purged, self._queue = self._queue, []
             for item in purged:
                 self._inflight.discard(item["id"])
+            # a generate node that waits for the device is let go: what
+            # was dispatched is being cancelled
+            self._owed.clear()
+            self._drained.notify_all()
         done_t = time.time()
         for item in purged:
             self.lm_handover.drop(item["id"])

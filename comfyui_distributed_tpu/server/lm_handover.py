@@ -11,6 +11,19 @@ the queue: its class, its place and `pop_fair_group` know nothing of
 this.  Its words and its ``LM_OUTPUT`` row are kept here and handed over,
 once, when its own graph reaches the node.
 
+When the row set is closed.  Dispatch is asynchronous: the host reaches
+the node while the device still owes the images of the groups enqueued
+before it, and ``lm_generate`` cannot start until they are out.  A leader
+whose set has room therefore waits for the device to have produced the
+last of them (each group's ``device_ready``, counted by the server) and
+closes the set with whoever is queued THEN: the callers whose images came
+out meanwhile have posted again and ride along.  The wait has no limit of
+its own and needs none: it ends with work that is already enqueued and
+that the host does not feed, during which this execution could not have
+begun, and every way such a group ends lets it go (`ServerState.
+_image_settled`).  Where the device owes nothing (an empty server, no
+overlap) or the set is full when the host arrives, nothing waits.
+
 What is kept is keyed on everything the result is a function of (model,
 lengths, text, seed, temperature), read again from the values the
 follower's node is really called with: a request that was edited, or
@@ -59,10 +72,21 @@ class GenerateHandover:
             at = next((i for i, kept in enumerate(self._kept)
                        if kept[1] == key(row)), None)
             mine = self._kept.pop(at)[2] if at is not None else None
+        bump = trace_mod.GLOBAL_COUNTERS.bump
         if mine is not None:
-            trace_mod.GLOBAL_COUNTERS.bump("lm.followers_served")
+            bump("lm.followers_served")
             return mine
-        waiting = self._waiting(model, max_new_tokens, prompt_tokens)
+        # before the wait, so that only the look at the queue lies between
+        # the drain and the enqueue: the queued graphs are parsed (`_calls`
+        # keeps them) and a leader that cannot be encoded is refused
+        model.prompt_ids(row.text, prompt_tokens)
+        waiting, full = self._waiting(model, max_new_tokens, prompt_tokens)
+        if not full and self._drain_wait():
+            here = {pid for pid, _, _ in waiting}
+            waiting, _ = self._waiting(model, max_new_tokens, prompt_tokens)
+            bump("lm.drain_waits")
+            bump("lm.rows_joined_in_drain",
+                 sum(pid not in here for pid, _, _ in waiting))
         results = model.generate_rows(
             [row] + [w[1] for w in waiting], max_new_tokens, prompt_tokens,
             spans=[w[2] for w in waiting])
@@ -78,7 +102,7 @@ class GenerateHandover:
             self._kept += [m for m in made if m[0] in queued]
         gone = sum(m[0] not in queued for m in made)
         if gone:
-            trace_mod.GLOBAL_COUNTERS.bump("lm.followers_dropped", gone)
+            bump("lm.followers_dropped", gone)
         return results[0]
 
     def drop(self, pid: str) -> None:
@@ -95,10 +119,26 @@ class GenerateHandover:
         with self._lock:
             return len(self._kept)
 
+    def _drain_wait(self) -> bool:
+        """Until the device has produced every image enqueued before this
+        node was reached; False, at once, where it owes none.  The host
+        meets the device here as it does in ``lm_generate``'s wait: not
+        the host's own seconds of ``dispatch``."""
+        state = self._state
+        with state._queue_lock:
+            if not state._owed:
+                return False
+        with trace_mod.stage("lm_drain_wait"), trace_mod.device_wait(), \
+                state._queue_lock:
+            while state._owed:
+                state._drained.wait()
+        return True
+
     def _waiting(self, model: Any, max_new_tokens: int, prompt_tokens: int
-                 ) -> List[Tuple[str, LMRow, Any]]:
+                 ) -> Tuple[List[Tuple[str, LMRow, Any]], bool]:
         """``(prompt id, row, root span)`` of the queued requests' calls
-        this execution has room for, in queue order."""
+        this execution has room for, in queue order, and whether they
+        fill it."""
         state = self._state
         with state._queue_lock:
             queued = list(state._queue)
@@ -111,7 +151,7 @@ class GenerateHandover:
                 continue
             for name, row, n, p in self._calls(item):
                 if len(found) >= room:
-                    return found
+                    return found, True
                 if (name, n, p) != (model.name, max_new_tokens,
                                     prompt_tokens):
                     continue
@@ -120,7 +160,7 @@ class GenerateHandover:
                 except ValueError:
                     continue        # it fails at its own turn, alone
                 found.append((item["id"], row, item.get("span")))
-        return found
+        return found, len(found) >= room
 
     def _calls(self, item: Dict[str, Any]) -> list:
         """The generate calls a queued graph holds with literal inputs,

@@ -4,11 +4,16 @@ tiny families on the CPU: who joins, what is kept and for whom, what is
 counted, and that nothing of it touches a graph without the node."""
 
 import asyncio
+import concurrent.futures
+import functools
 import json
 import os
 import subprocess
 import sys
+import threading
+import time
 
+import numpy as np
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
@@ -18,6 +23,7 @@ from comfyui_distributed_tpu.ops.base import get_op
 from comfyui_distributed_tpu.runtime import reuse
 from comfyui_distributed_tpu.server.app import ServerState, build_app
 from comfyui_distributed_tpu.utils import trace
+from comfyui_distributed_tpu.workflow.executor import ExecutionResult
 from comfyui_distributed_tpu.workflow.graph import parse_workflow
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -314,6 +320,236 @@ def test_an_error_in_a_shared_execution_fails_the_leader_only(
     assert counters()["executions"] == 1 and counters()["rows"] == 1
 
 
+# --- the row set is closed when the device has drained --------------------------
+
+def owe(state, pid="a group dispatched earlier"):
+    """The device still owes the image of a group the executor has
+    dispatched: what `_execute_group` counts under overlap."""
+    with state._queue_lock:
+        state._owed.add(pid)
+    return {"id": pid}
+
+
+def lead(state):
+    """One turn of the executor on a thread of its own, back when the
+    leader is in the wait for the device (it holds the queue's lock until
+    it is: whoever posts next posts after the host reached the node)."""
+    there = threading.Event()
+
+    def wait(timeout=None):
+        there.set()
+        return threading.Condition.wait(state._drained, timeout)
+
+    state._drained.wait = wait
+    turn = threading.Thread(target=run_next, args=(state,), daemon=True)
+    turn.start()
+    assert there.wait(120), "the leader never waited for the device"
+    return turn
+
+
+def ended(turn):
+    turn.join(120)
+    assert not turn.is_alive(), "the leader is still waiting"
+
+
+def test_prompts_posted_while_the_device_drains_ride_along(state, encoded):
+    """The host reaches the node while an earlier image is in flight.  Two
+    callers post before it is out: ONE execution, three real rows in four,
+    each request its own words."""
+    earlier = owe(state)
+    pids = [state.enqueue_prompt(graph(TEXTS[0], seed=10), "a")]
+    turn = lead(state)
+    pids += [state.enqueue_prompt(graph(t, seed=11 + i), "bc"[i])
+             for i, t in enumerate(TEXTS[1:3])]
+    state._image_settled(earlier)
+    ended(turn)
+    got = counters()
+    assert (got["executions"], got["rows"], got["padded_rows"]) == (1, 3, 1)
+    assert got["drain_waits"] == 1 and got["rows_joined_in_drain"] == 2
+    assert state.lm_handover.kept() == 2
+    assert [run_next(state) for _ in range(2)] == [[p] for p in pids[1:]]
+    assert counters()["followers_served"] == 2
+    assert counters()["executions"] == 1
+    assert all(state._history[p]["status"] == "success" for p in pids)
+    positive = [t for t in encoded if t != "blurry, lowres, watermark"]
+    assert positive == [alone(t, 10 + i) for i, t in enumerate(TEXTS[:3])]
+
+
+def test_a_request_queued_when_the_host_arrived_is_not_one_that_joined(state):
+    earlier = owe(state)
+    for t in TEXTS[:2]:
+        state.enqueue_prompt(graph(t), "t")
+    turn = lead(state)
+    state.enqueue_prompt(graph(TEXTS[2]), "t")
+    state._image_settled(earlier)
+    ended(turn)
+    got = counters()
+    assert got["rows"] == 3 and got["drain_waits"] == 1
+    assert got["rows_joined_in_drain"] == 1
+
+
+def test_where_the_device_owes_nothing_nobody_waits(state):
+    """An empty server, a request below the knee, no overlap: the set is
+    closed when the host reaches the node, as before."""
+    for t in TEXTS[:2]:
+        state.enqueue_prompt(graph(t), "t")
+    run_next(state)
+    got = counters()
+    assert got["executions"] == 1 and got["rows"] == 2
+    assert "drain_waits" not in got and "rows_joined_in_drain" not in got
+    assert stage_count("lm_drain_wait") == 0 and not state._owed
+
+
+def test_a_set_that_is_full_when_the_host_arrives_does_not_wait(state):
+    owe(state)
+    for t in TEXTS:
+        state.enqueue_prompt(graph(t), "t")
+    turn = threading.Thread(target=run_next, args=(state,), daemon=True)
+    turn.start()
+    ended(turn)
+    got = counters()
+    assert got["rows"] == registry.LM_ROW_COUNTS[-1] == 4
+    assert "drain_waits" not in got and stage_count("lm_drain_wait") == 0
+
+
+def test_two_callers_in_a_closed_loop_wait_and_find_nobody(state):
+    """The closed loop of two, with the other caller's image in flight
+    when the host reaches the node: every leader waits for it, and its
+    caller posts again only after it is out and the set is closed."""
+    state.enqueue_prompt(graph("caller a, round 0"), "a")
+    for turn_no in range(1, 5):
+        others = owe(state, f"the other caller's denoise, turn {turn_no}")
+        turn = lead(state)
+        state._image_settled(others)
+        ended(turn)
+        state.enqueue_prompt(
+            graph(f"caller {'ab'[turn_no % 2]}, round {turn_no // 2}"),
+            "ab"[turn_no % 2])
+    got = counters()
+    assert got["rows"] / got["executions"] == 1.0 and got["rows"] == 4
+    assert got["drain_waits"] == got["executions"]
+    assert got["rows_joined_in_drain"] == 0 and got["padded_rows"] == 0
+    assert "followers_served" not in got
+
+
+def _result(*futures):
+    return ExecutionResult(outputs={}, images=[], timings={},
+                           image_futures=list(futures))
+
+
+def _raising():
+    f = concurrent.futures.Future()
+    f.set_exception(OSError("the disk is full"))
+    return f
+
+
+@pytest.mark.parametrize("way, ends", [
+    ("device_ready", None), ("a host edge that raises", "error"),
+    ("a graph without an image", "success"), ("a purge", "abandoned"),
+    ("a slot the step executor aborts", "error"),
+    ("a drain that times out", None)])
+def test_every_way_a_dispatched_group_ends_lets_the_leader_go(state, way,
+                                                              ends):
+    """A leader that could hang is worse than a padded row.  ``ends``:
+    the earlier group's history entry, where that way writes one."""
+    state.enqueue_prompt(graph("dispatched earlier"), "t")
+    group = state._pop_group()
+    owe(state, group[0]["id"])
+    pid = state.enqueue_prompt(graph("the leader"), "t")
+    turn = lead(state)
+    t0 = time.perf_counter()
+    if way == "device_ready":
+        ops_base.fetch_image_array(
+            np.zeros((1, 8, 8, 3), np.float32),
+            functools.partial(state._image_settled, group[0]))
+    elif way == "a host edge that raises":
+        state._finalize_hand(group, _result(_raising()), None, t0)
+    elif way == "a graph without an image":
+        state._finalize_hand(group, _result(), None, t0)
+    elif way == "a purge":
+        state._finalize_hand(group, None, reuse.AbandonedError("gone"), t0)
+    elif way == "a slot the step executor aborts":
+        state._finalize_hand(group, None, RuntimeError("aborted"), t0)
+    else:
+        state._exec_started = True
+        assert state.drain(timeout=0.0) is False
+    ended(turn)
+    assert not state._owed and counters()["drain_waits"] == 1
+    # let go by a drain, it runs into the drain's interrupt at its next node
+    assert state._history[pid]["status"] == \
+        ("error" if way == "a drain that times out" else "success")
+    assert state._history.get(group[0]["id"], {}).get("status") == ends
+
+
+def test_the_server_owes_what_it_dispatched_until_the_image_is_out(
+        tmp_path, monkeypatch):
+    """Under overlap the host edge is deferred: the group is owed from the
+    return of its last enqueue to its ``device_ready`` on the pool's
+    thread, before the PNG and the history entry; a graph that dispatched
+    nothing to wait for is never owed."""
+    from comfyui_distributed_tpu.ops import basic
+    state = ServerState(config_path=str(tmp_path / "cfg.json"),
+                        input_dir=str(tmp_path / "input"),
+                        output_dir=str(tmp_path / "output"),
+                        start_exec_thread=False, overlap=True)
+    let_go, ready = threading.Event(), threading.Event()
+    real = basic.fetch_image_array
+
+    def fetch(x, hook=None):
+        def hooked():
+            hook()
+            ready.set()
+        assert let_go.wait(120)
+        return real(x, hooked)
+
+    monkeypatch.setattr(basic, "fetch_image_array", fetch)
+    pid = state.enqueue_prompt(graph(TEXTS[0]), "t")
+    run_next(state)
+    assert state._owed == {pid} and pid not in state._history
+    let_go.set()
+    assert ready.wait(120)
+    assert not state._owed and pid not in state._history
+    state._finalize_group(*state._finalize_q.get(timeout=120))
+    assert state._history[pid]["status"] == "success" and not state._owed
+    # a text-only graph: nothing deferred, nothing owed
+    state.enqueue_prompt({k: v for k, v in graph(TEXTS[1]).items()
+                          if k in (LOADER, GENERATE, SEED)}, "t")
+    run_next(state)
+    assert not state._owed
+    state._finalize_group(*state._finalize_q.get(timeout=120))
+    state.host_pool.shutdown()
+
+
+def test_the_wait_is_the_leaders_span_and_not_the_hosts_own_seconds(state):
+    """``lm_drain_wait`` is a stage of the leader's own trace, with the
+    profiler off; the thread spends it in ``device_wait``, which
+    ``dispatch`` (own) leaves out."""
+    earlier = owe(state)
+    pid = state.enqueue_prompt(graph(TEXTS[0]), "t")
+    turn = lead(state)
+    state._image_settled(earlier)
+    ended(turn)
+    spans = trace.GLOBAL_TRACES.get(pid)["spans"]
+    by_id = {s["span_id"]: s for s in spans}
+    (drain,) = [s for s in spans if s["name"] == "lm_drain_wait"]
+    node = drain
+    while node.get("parent_id") in by_id:
+        node = by_id[node["parent_id"]]
+    assert node["name"] == "job" and node["attrs"]["prompt_id"] == pid
+    assert by_id[drain["parent_id"]]["name"] == "LanguageModelGenerate"
+    (inside,) = [s for s in spans if s["name"] == "device_wait"
+                 and s["parent_id"] == drain["span_id"]]
+    (dispatch,) = [s for s in spans if s["name"] == "dispatch"]
+    assert dispatch["attrs"]["device_wait_s"] >= inside["duration_s"] - 1e-5
+    (generate,) = [s for s in spans if s["name"] == "lm_generate"]
+    assert drain["end_s"] <= generate["start_s"] + 1e-5
+    stages = trace.GLOBAL_STAGES.snapshot()
+    assert stages["lm_drain_wait"]["count"] == 1
+    assert stages["dispatch_wait"]["total_s"] \
+        >= inside["duration_s"] - 1e-5
+
+
+
 # --- who does not join ---------------------------------------------------------
 
 def test_two_callers_in_a_closed_loop_never_meet_at_the_node(state):
@@ -413,6 +649,27 @@ def test_the_first_shared_execution_compiles_nothing(state):
     model = registry.load_language_model("ouro-2.6b.safetensors")
     assert sorted(model._programs[(NEW, PROMPT)]) == \
         list(registry.LM_ROW_COUNTS)
+
+
+def test_the_first_shared_execution_after_a_wait_compiles_nothing(state):
+    """The same guard where the rows came in during the wait: three real
+    rows run the program the first request compiled for four."""
+    state.enqueue_prompt(graph("the first request, alone"), "t")
+    run_next(state)
+    mark = trace.GLOBAL_RETRACES.mark()
+    earlier = owe(state)
+    state.enqueue_prompt(graph(TEXTS[0]), "t")
+    turn = lead(state)
+    for t in TEXTS[1:3]:
+        state.enqueue_prompt(graph(t), "t")
+    state._image_settled(earlier)
+    ended(turn)
+    got = counters()
+    assert got["executions"] == 2 and got["rows"] == 4
+    assert got["rows_joined_in_drain"] == 2
+    since = trace.GLOBAL_RETRACES.since(mark)
+    assert since["compiles"] == 0 and since["cache_loads"] == 0
+    assert since["lower_s"] == 0
 
 
 # --- a graph without the node ----------------------------------------------------

@@ -225,21 +225,33 @@ TEMPERATURES = [0.0, 0.0, 1.0, 0.7]
 SEEDS = [3, 4, 5, 6]
 
 
+def padded(real, rows, *per_row):
+    """Each list of ``real`` rows as `LanguageModel.generate_rows` pads an
+    execution to ``rows``: a padded row repeats the first."""
+    source = [*range(real), *[0] * (rows - real)]
+    return [[values[i] for i in source] for values in per_row]
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("rows", [2, 4])
-def test_rows_of_different_lengths_match_their_single_row_runs(rows, dtype):
+@pytest.mark.parametrize("rows, real", [(2, 2), (4, 4), (4, 3)])
+def test_rows_of_different_lengths_match_their_single_row_runs(rows, real,
+                                                               dtype):
     """Rows of different real lengths, seeds and temperatures in one
-    execution: each row's ids are those of its own single-row run, greedy
-    and sampled alike, and its logits lie inside the file's limits both
-    of that run's and of the reference teacher-forced over its own ids."""
+    execution, all of them real or three real ones in a program of four
+    (what a leader runs whose set the drain closed with two followers):
+    each row's ids are those of its own single-row run, greedy and
+    sampled alike, and its logits lie inside the file's limits both of
+    that run's and of the reference teacher-forced over its own ids."""
     cfg = dataclasses.replace(TINY, dtype=jnp.dtype(dtype))
     limits = verify.LIMITS_FP32 if dtype == "float32" else TINY_BF16_LIMITS
     p = make_params(cfg)
-    lens, temps, seeds = LENS[:rows], TEMPERATURES[:rows], SEEDS[:rows]
+    lens, temps, seeds, ids = padded(
+        real, rows, LENS, TEMPERATURES, SEEDS,
+        [prompt(b, n) for b, n in enumerate(LENS)])
     tokens, logits, exits = serve_rows(cfg, p, lens, temperatures=temps,
-                                       seeds=seeds)
+                                       seeds=seeds, ids=np.concatenate(ids))
     assert tokens.shape == (rows, NEW) and exits.shape == (rows, NEW, 4)
-    for b, n in enumerate(lens):
+    for b, n in enumerate(lens[:real]):
         alone = serve(cfg, p, prompt(b, n), n=n, temperature=temps[b],
                       seed=seeds[b])
         assert np.array_equal(tokens[b], alone[0]), (b, tokens[b], alone[0])
@@ -311,24 +323,27 @@ BREAKAGES = ["a loop dropped", "two loops share a slot",
              "the weights in 8 bits"]
 
 
-@pytest.mark.parametrize("rows", [1, 3])
+@pytest.mark.parametrize("rows, real", [(1, 1), (3, 3), (4, 3)])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("what", BREAKAGES)
-def test_each_breakage_fails_the_comparison(what, dtype, rows, monkeypatch):
-    """Under the float32 limits and under the bf16 limits alike, alone
-    and as the last of three rows of different lengths in one execution.
-    The reference always runs the model as stated, on the stated
-    weights."""
+def test_each_breakage_fails_the_comparison(what, dtype, rows, real,
+                                            monkeypatch):
+    """Under the float32 limits and under the bf16 limits alike, alone,
+    as the last of three rows of different lengths in one execution, and
+    as the last of three real rows in an execution of four.  The
+    reference always runs the model as stated, on the stated weights."""
     cfg = dataclasses.replace(TINY, dtype=jnp.dtype(dtype))
     limits = verify.LIMITS_FP32 if dtype == "float32" else TINY_BF16_LIMITS
     p = make_params(cfg)
-    lens = [5, 12, PROMPT][-rows:]
-    mine = np.concatenate([prompt(b) if n == PROMPT else prompt(b, n)
-                           for b, n in zip(range(rows - 1, -1, -1), lens)])
+    lens = [5, 12, PROMPT][-real:]
+    lens, ids = padded(real, rows, lens, [
+        prompt(b) if n == PROMPT else prompt(b, n)
+        for b, n in zip(range(real - 1, -1, -1), lens)])
+    mine = np.concatenate(ids)
 
     def last_row(cfg, p):
         tokens, logits, _ = serve_rows(cfg, p, lens, ids=mine)
-        return tokens[-1], logits[-1]
+        return tokens[real - 1], logits[real - 1]
 
     tokens, logits = last_row(cfg, p)
     want, _ = reference_rows(cfg, p, prompt(), tokens)
