@@ -46,6 +46,7 @@ what ``utils/trace.KERNEL_CLASSES`` reads out of a device trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Mapping, Tuple
 
@@ -90,6 +91,11 @@ class LoopLMConfig:
     def cache_slots(self) -> int:
         return self.total_ut_steps * self.num_hidden_layers
 
+    @property
+    def layer_applications(self) -> int:
+        """Layer applications one token passes through: every loop's."""
+        return self.cache_slots
+
 
 # ByteDance/Ouro-2.6B config.json, nothing reduced
 OURO_2_6B = LoopLMConfig(
@@ -103,6 +109,8 @@ TINY_LOOPLM = LoopLMConfig(
     vocab_size=512, hidden_size=64, num_hidden_layers=3, total_ut_steps=4,
     num_attention_heads=4, num_key_value_heads=4, head_dim=16,
     intermediate_size=176, dtype=jnp.float32)
+
+CONFIGS = {"full": OURO_2_6B, "tiny": TINY_LOOPLM}
 
 NORMS = ("input_layernorm", "input_layernorm_2", "post_attention_layernorm",
          "post_attention_layernorm_2")
@@ -167,6 +175,17 @@ def init_params(cfg: LoopLMConfig, seed) -> Dict[str, Any]:
 
     return jax.tree_util.tree_unflatten(
         tree, [leaf(p, s, k) for (p, s), k in zip(flat, keys)])
+
+
+def seeded_params(cfg: LoopLMConfig, seed) -> Dict[str, Any]:
+    """`init_params` under one ``jax.jit``: drawn on the device."""
+    return jax.jit(functools.partial(init_params, cfg))(seed)
+
+
+def load_checkpoint(path: str, cfg: LoopLMConfig) -> Dict[str, Any]:
+    from comfyui_distributed_tpu.models.checkpoints import \
+        load_looplm_checkpoint
+    return load_looplm_checkpoint(path, cfg)
 
 
 # --- the layer ------------------------------------------------------------
@@ -396,3 +415,24 @@ def make_generate(cfg: LoopLMConfig, max_new_tokens: int):
                         seed, temperature)
 
     return jax.jit(lm_generate)
+
+
+def make_program(cfg: LoopLMConfig, max_new_tokens: int):
+    """`make_generate` as ``models/registry.py`` serves every family's
+    program: ``(ids, logits, aux, stats)``, ``aux`` the per-position
+    arrays a comparison wants beside the logits (here the exit
+    probabilities), ``stats`` what the host counts from (here nothing)."""
+
+    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
+        tokens, logits, exits = generate(cfg, max_new_tokens, params,
+                                         prompt_ids, prompt_len, seed,
+                                         temperature)
+        return tokens, logits, {"exit_probs": exits}, {}
+
+    return jax.jit(lm_generate)
+
+
+def window_counters(cfg: LoopLMConfig, stats, real: int, steps: int
+                    ) -> Dict[str, int]:
+    """This family counts nothing of its own."""
+    return {}

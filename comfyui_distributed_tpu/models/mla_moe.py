@@ -1,0 +1,648 @@
+"""A latent-attention decoder with routed experts (openPangu-Ultra-MoE,
+``model_type`` ``pangu_ultra_moe``: a DeepSeek-V3-shaped stack with
+sandwich norms), served as ONE chip's share of a deployment.
+
+    x = E[ids]
+    for l in 0..L-1:                                     # every block
+      h = x + N2_l(Attn_l(N1_l(x)))                      # sandwich norms
+      x = h + N4_l(MLP_l(N3_l(h)))
+    logits = W_head N(x)
+
+    Attn (MLA), n = N1(x):
+      c_q  = RMSNorm(n W_qa)                             # rank 1536
+      q    = c_q W_qb            -> H x (d_nope + d_rope)
+      [c_kv, k_r] = n W_kva      -> 512 + 64;  c_kv = RMSNorm(c_kv)
+      [k_nope, v] = c_kv W_kvb   -> H x (d_nope + d_v)
+      q_r, k_r rotated (k_r is ONE key, shared by every head)
+      s    = (q_nope . k_nope + q_r . k_r) / sqrt(d_nope + d_rope)
+      out  = W_o concat_h(softmax(s; causal) v)
+
+    MLP of the leading dense blocks:  W_down (silu(W_gate n) * W_up n)
+    MLP of the expert blocks, n = N3(h):
+      s = sigmoid(n W_g) over ALL routed experts;  top-k;
+      w = s_topk / sum(s_topk) * routed_scaling_factor
+      y = Shared(n) + sum over the chosen experts e HELD HERE of w_e Expert_e(n)
+
+**The cache holds the latent**: ``c_kv`` and the rotated ``k_r``, 576
+values a position a layer, never the 128 heads' keys and values.  The
+prefill expands this call's latent to per-head keys and values (the
+equations as written); a decode step runs the same mathematics
+ABSORBED, directly on the cache: ``q_lat = q_nope W_UK^T`` (per head,
+512 wide; ``W_UK`` is ``W_kvb``'s key half), scores against the cached
+``c_kv`` and ``k_r``, the weighted latent through ``W_UV`` (its value
+half) and ``W_o``.  Two paths for one layer; tests hold them together.
+
+**The share.**  No chip holds an expert layer of the published model
+(24.7 GB).  ``cfg.experts_first`` / ``cfg.experts_held`` say which of
+the ``n_routed_experts`` this chip holds (expert parallelism: 16 chips
+share each layer, 16 experts to a chip); the router keeps its published
+width and its top-k, and the chip computes its own experts' part for
+the token-expert pairs routed to them, plus the shared expert.  A pair
+routed to an absent expert adds nothing here (its chip would add it);
+nothing stands in for the absent chips or their exchange, and that
+partial result goes on to the next layer.  **No pair is dropped**:
+every expert with at least one pair runs over all tokens of the call
+with the pairs' weights (zero for the tokens not routed to it), however
+uneven the routing; an expert with no pair is skipped (``lax.cond``),
+so a decode step reads only the experts it hits.
+
+Served as one jitted program, ``lm_generate``, exactly as
+``models/looplm.py``'s: the prefill of the padded prompts, then
+``max_new_tokens`` decode steps in a ``lax.scan``; rows are requests of
+different people, moved to the end of the prompt buffer so that all
+write the cache at one shared index.  The leading dense blocks and the
+expert blocks are two stacks of leaves with a leading layer axis, two
+``lax.scan`` bodies over one cache.
+
+Routing is discontinuous, so the program returns beside the logits what
+it routed by: the router's scores and its choices at every decoded
+position and expert layer (``aux``), and what the decode steps'
+routing came to (``stats``: local pairs per row, distinct local experts
+hit, pairs dropped).
+
+Precision: weights, cache and matmul operands in ``cfg.dtype``; the
+residual stream, every RMSNorm, RoPE, the softmax and the logits in
+float32; the ROUTER in float32 at the highest precision (the family's
+convention: DeepSeek-V3's gate is a float32 linear), so that a choice
+flips only on what the layers before it rounded.
+
+Every operation lies under a ``jax.named_scope`` of the published
+module's name (``PanguUltraMoE/moe_layers/self_attn/q_a_proj`` ...),
+read by ``utils/trace.KERNEL_CLASSES``.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
+    attention_path, xla_attention
+from comfyui_distributed_tpu.models.looplm import _dense, _rms_norm, \
+    _sandwich
+from comfyui_distributed_tpu.parallel import sharding as shd
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    """The shape keys of the model's ``config.json``, under its names,
+    AS HELD: ``num_hidden_layers`` and ``first_k_dense_replace`` count
+    the blocks of this share, ``vocab_size`` its slice.
+    ``n_routed_experts`` is the router's width (the published count);
+    ``experts_first`` / ``experts_held`` name this chip's experts."""
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 25.6e6
+    experts_first: int = 0
+    experts_held: int = -1          # -1: all of them
+    dtype: Any = jnp.bfloat16       # weights, cache, matmul operands
+
+    def __post_init__(self):
+        if self.experts_held < 0:
+            object.__setattr__(self, "experts_held", self.n_routed_experts)
+        if not 0 <= self.experts_first <= self.experts_first \
+                + self.experts_held <= self.n_routed_experts:
+            raise ValueError(
+                f"experts {self.experts_first}..{self.experts_first}+"
+                f"{self.experts_held} are not among the router's "
+                f"{self.n_routed_experts}")
+        if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError(
+                f"{self.first_k_dense_replace} dense blocks of "
+                f"{self.num_hidden_layers}")
+
+    @property
+    def moe_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def latent_dim(self) -> int:
+        """What the cache holds a position a layer: ``c_kv`` and ``k_r``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def layer_applications(self) -> int:
+        """Blocks one token passes through."""
+        return self.num_hidden_layers
+
+
+# FreedomIntelligence/openPangu-Ultra-MoE-718B config.json, every width as
+# published, cut to ONE chip's share of a 16-chip expert-parallel
+# deployment (benchmarks/chip/configs/pangu-ultra-moe-expand-sd15-512.json
+# has the arithmetic): 1 of the 3 leading dense blocks and 4 of the 58
+# expert blocks (further blocks lie on further chips, as pipeline
+# stages), experts 48..63 of the 256 (chip 3 of the 16), an eighth of the
+# 153,600-row vocabulary.  The multi-token-prediction module is not held.
+OPENPANGU_ULTRA_MOE_SHARE = MlaMoeConfig(
+    vocab_size=19200, hidden_size=7680, num_hidden_layers=5,
+    first_k_dense_replace=1, num_attention_heads=128, q_lora_rank=1536,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, intermediate_size=18432, moe_intermediate_size=2048,
+    n_routed_experts=256, num_experts_per_tok=8, n_shared_experts=1,
+    routed_scaling_factor=2.5, norm_topk_prob=True, rms_norm_eps=1e-5,
+    rope_theta=25.6e6, experts_first=48, experts_held=16)
+
+# the CPU tests' and the rehearsal's size (fp32: deterministic
+# comparisons): a dense block and two expert blocks, experts 4..7 of 16
+TINY_MLA_MOE = MlaMoeConfig(
+    vocab_size=512, hidden_size=64, num_hidden_layers=3,
+    first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=32,
+    kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, intermediate_size=160, moe_intermediate_size=48,
+    n_routed_experts=16, num_experts_per_tok=4, n_shared_experts=1,
+    routed_scaling_factor=2.5, experts_first=4, experts_held=4,
+    dtype=jnp.float32)
+
+CONFIGS = {"full": OPENPANGU_ULTRA_MOE_SHARE, "tiny": TINY_MLA_MOE}
+
+# N1..N4 of a block; the second and the fourth stand on a sub-layer's
+# OUTPUT (``sandwich_norm: true``), and their seeded gains are smaller
+NORMS = ("input_layernorm", "post_attention_layernorm",
+         "pre_mlp_layernorm", "post_mlp_layernorm")
+SANDWICH_NORMS = ("post_attention_layernorm", "post_mlp_layernorm")
+LATENT_NORMS = ("q_a_layernorm", "kv_a_layernorm")
+SANDWICH_GAIN = 0.5
+
+
+def param_shapes(cfg: MlaMoeConfig) -> Dict[str, Any]:
+    """The parameter tree's shapes, kernels ``[in, out]``: two stacks of
+    blocks (``dense_layers``, ``moe_layers``), each leaf with a leading
+    layer axis; an expert block's routed experts are ``experts/*`` with
+    the axis of the experts HELD behind it, ``[L, E_here, in, out]``."""
+    d, H = cfg.hidden_size, cfg.num_attention_heads
+    dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    dkv = cfg.qk_nope_head_dim + cfg.v_head_dim
+
+    def mlp(width, *lead):
+        return {"gate_proj": (*lead, d, width), "up_proj": (*lead, d, width),
+                "down_proj": (*lead, width, d)}
+
+    def block(L):
+        layers = {n: (L, d) for n in NORMS}
+        layers.update(
+            q_a_proj=(L, d, cfg.q_lora_rank),
+            q_a_layernorm=(L, cfg.q_lora_rank),
+            q_b_proj=(L, cfg.q_lora_rank, H * dq),
+            kv_a_proj_with_mqa=(L, d, cfg.latent_dim),
+            kv_a_layernorm=(L, cfg.kv_lora_rank),
+            kv_b_proj=(L, cfg.kv_lora_rank, H * dkv),
+            o_proj=(L, H * cfg.v_head_dim, d))
+        return layers
+
+    Ld, Le = cfg.first_k_dense_replace, cfg.moe_layers
+    dense = {**block(Ld), **mlp(cfg.intermediate_size, Ld)}
+    moe = {**block(Le), "gate": (Le, d, cfg.n_routed_experts),
+           "shared_experts": mlp(
+               cfg.moe_intermediate_size * cfg.n_shared_experts, Le),
+           "experts": mlp(cfg.moe_intermediate_size, Le, cfg.experts_held)}
+    return {"embed_tokens": (cfg.vocab_size, d), "dense_layers": dense,
+            "moe_layers": moe, "norm": (d,), "lm_head": (d, cfg.vocab_size)}
+
+
+def _leaves(cfg):
+    return jax.tree_util.tree_flatten_with_path(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+
+
+def param_count(cfg: MlaMoeConfig) -> int:
+    return sum(math.prod(s) for _, s in _leaves(cfg)[0])
+
+
+def _seeded_leaf(name: str, shape: tuple, dtype):
+    """The jitted maker of one leaf from its key: kernels normals scaled
+    by fan-in, embeddings unit normals, norm gains 1 + 0.1 N (a gain of
+    exactly 1 would hide a norm applied without its gain), the sandwich
+    norms' SANDWICH_GAIN of that.  A stacked leaf is drawn slice by slice
+    along its leading axis, so the float32 normals of the largest (the
+    experts', 1.0 B values) never stand whole beside it."""
+    norm = name in NORMS + LATENT_NORMS or name == "norm"
+    gain = SANDWICH_GAIN if name in SANDWICH_NORMS else 1.0
+
+    def draw(key, shape):
+        x = jax.random.normal(key, shape, jnp.float32)
+        if norm:
+            x = gain * (1.0 + 0.1 * x)
+        elif name != "embed_tokens":
+            x = x / math.sqrt(shape[-2])
+        return x.astype(dtype)
+
+    def leaf(key):
+        if len(shape) < 3:
+            return draw(key, shape)
+        return jax.lax.map(lambda k: draw(k, shape[1:]),
+                           jax.random.split(key, shape[0]))
+
+    return jax.jit(leaf)
+
+
+def seeded_params(cfg: MlaMoeConfig, seed) -> Dict[str, Any]:
+    """Seeded random weights, made on the device LEAF BY LEAF (one small
+    jitted call a leaf): the 4.9 B values of the published share are
+    9.8 GB on a 16 GB chip, and one program that drew them all could
+    hold several leaves' float32 normals at once."""
+    flat, tree = _leaves(cfg)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(flat))
+    return jax.tree_util.tree_unflatten(
+        tree, [_seeded_leaf(p[-1].key, s, jnp.dtype(cfg.dtype))(k)
+               for (p, s), k in zip(flat, keys)])
+
+
+def load_checkpoint(path: str, cfg: MlaMoeConfig):
+    raise NotImplementedError(
+        f"{path}: no reader for a pangu_ultra_moe state dict yet (this "
+        f"family is served from seeded weights: a share of 718 B "
+        f"parameters is not a file anybody has); remove the file or "
+        f"serve another model")
+
+
+# --- the layer ------------------------------------------------------------
+
+def _rope(x, positions, theta):
+    """Rotary embedding over INTERLEAVED pairs ``(2i, 2i + 1)``, in
+    float32: ``x [B, N, H, D]``, ``positions [B, N]`` (each row's own)."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape)
+
+
+def _queries(cfg: MlaMoeConfig, lp, n, positions):
+    """``q_nope [B, N, H, d_nope]`` and the rotated ``q_rope
+    [B, N, H, d_rope]``, float32, through the rank-``q_lora_rank``
+    bottleneck."""
+    B, N, _ = n.shape
+    with jax.named_scope("q_a_proj"):
+        c_q = _dense(n, lp["q_a_proj"], cfg)
+    with jax.named_scope("q_a_layernorm"):
+        c_q = _rms_norm(c_q, lp["q_a_layernorm"], cfg.rms_norm_eps)
+    with jax.named_scope("q_b_proj"):
+        q = _dense(c_q, lp["q_b_proj"], cfg).reshape(
+            B, N, cfg.num_attention_heads, -1)
+    q = shd.constrain(q, "batch", None, "heads", None)
+    q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    with jax.named_scope("rotary"):
+        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+    return q_nope, q_rope
+
+
+def _latent(cfg: MlaMoeConfig, lp, n, positions):
+    """What the cache holds of this call's positions: the normed latent
+    ``c_kv`` and the rotated shared key ``k_r``, ``[B, N, 576]`` in the
+    model's dtype."""
+    with jax.named_scope("kv_a_proj_with_mqa"):
+        c_kv, k_r = jnp.split(_dense(n, lp["kv_a_proj_with_mqa"], cfg),
+                              [cfg.kv_lora_rank], axis=-1)
+    with jax.named_scope("kv_a_layernorm"):
+        c_kv = _rms_norm(c_kv, lp["kv_a_layernorm"], cfg.rms_norm_eps)
+    with jax.named_scope("rotary"):
+        k_r = _rope(k_r[:, :, None], positions, cfg.rope_theta)[:, :, 0]
+    return jnp.concatenate([c_kv, k_r], axis=-1).astype(cfg.dtype)
+
+
+def _kv_b(cfg: MlaMoeConfig, lp):
+    """``W_kvb`` as ``[rank, H, d_nope + d_v]``: its key half is ``W_UK``,
+    its value half ``W_UV``."""
+    return lp["kv_b_proj"].reshape(cfg.kv_lora_rank,
+                                   cfg.num_attention_heads, -1)
+
+
+def _attend_expanded(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
+                     first):
+    """The equations as written: this call's latent expanded to every
+    head's keys and values, the shared ``k_r`` beside each head's
+    ``k_nope``.  -> ``[B, N, H, d_v]``."""
+    B, N, H, _ = q_nope.shape
+    c_kv, k_r = jnp.split(latent, [cfg.kv_lora_rank], axis=-1)
+    with jax.named_scope("kv_b_proj"):
+        kv = jnp.einsum("bnr,rhd->bnhd", c_kv, _kv_b(cfg, lp),
+                        preferred_element_type=jnp.float32)
+    k_nope, v = jnp.split(kv, [cfg.qk_nope_head_dim], axis=-1)
+    k_r = jnp.broadcast_to(k_r[:, :, None], (B, N, H, k_r.shape[-1]))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, k_r.astype(jnp.float32)], axis=-1)
+    q, k, v = (shd.constrain(t.astype(cfg.dtype), "batch", None, "heads",
+                             None) for t in (q, k, v))
+    ATTENTION_PATHS.bump(attention_path(jax.default_backend(), B, N, N, H,
+                                        masked=True))
+    return xla_attention(q, k, v, 1.0 / math.sqrt(q.shape[-1]), index, first)
+
+
+def _attend_absorbed(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
+                     first):
+    """The same mathematics on the latent cache ``latent [B, T, 576]``:
+    ``W_UK`` folded into the query, ``W_UV`` into the output.  The heads'
+    queries meet ONE key and one value a position (the latent has no
+    head axis), so they go to `xla_attention` as the queries of a single
+    head.  -> ``[B, N, H, d_v]``."""
+    B, N, H, _ = q_nope.shape
+    w_uk, w_uv = jnp.split(_kv_b(cfg, lp), [cfg.qk_nope_head_dim], axis=-1)
+    with jax.named_scope("absorb_q"):
+        q_lat = jnp.einsum("bnhd,rhd->bnhr", q_nope.astype(cfg.dtype), w_uk,
+                           preferred_element_type=jnp.float32)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(cfg.dtype)
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    ATTENTION_PATHS.bump("xla_decode" if N == 1 else "xla_causal")
+    o_lat = xla_attention(
+        q.reshape(B, N * H, 1, -1), latent[:, :, None],
+        latent[:, :, None, :cfg.kv_lora_rank], scale,
+        jnp.repeat(index, H), first).reshape(B, N, H, -1)
+    with jax.named_scope("absorb_v"):
+        return jnp.einsum("bnhr,rhd->bnhd", o_lat.astype(cfg.dtype), w_uv,
+                          preferred_element_type=jnp.float32)
+
+
+def _attention(cfg: MlaMoeConfig, lp, x, index, first, cache, l,
+               absorbed: bool):
+    """``h = x + N2(Attn(N1(x)))`` and the cache with this call's latent
+    written into layer ``l`` at the buffer indices ``index``.  With
+    ``absorbed`` the queries attend to the cache (a decode step), without
+    to this call's own latent, expanded (the prefill)."""
+    B, N, _ = x.shape
+    positions = index[None, :] - first[:, None]
+    with jax.named_scope("input_layernorm"):
+        n = _rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
+    with jax.named_scope("self_attn"):
+        q_nope, q_rope = _queries(cfg, lp, n, positions)
+        latent = _latent(cfg, lp, n, positions)
+        with jax.named_scope("kv_cache"):
+            cache = jax.lax.dynamic_update_slice(
+                cache, latent[None].astype(cache.dtype),
+                (l, 0, index[0], 0))
+            if absorbed:
+                latent = jax.lax.dynamic_index_in_dim(
+                    cache, l, keepdims=False).astype(cfg.dtype)
+        attend = _attend_absorbed if absorbed else _attend_expanded
+        a = attend(cfg, lp, q_nope, q_rope, latent, index, first)
+        with jax.named_scope("o_proj"):
+            a = _dense(a.reshape(B, N, -1), lp["o_proj"], cfg)
+    with jax.named_scope("post_attention_layernorm"):
+        return _sandwich(x, a, lp["post_attention_layernorm"],
+                         cfg.rms_norm_eps), cache
+
+
+def _gated_mlp(cfg: MlaMoeConfig, weights, n, scope=jax.named_scope):
+    """``W_down (silu(W_gate n) * W_up n)``: the dense blocks' MLP, the
+    shared expert and every routed expert (whose projections carry no
+    scope of their own: a trace classes them with ``experts``)."""
+    with scope("gate_proj"):
+        g = _dense(n, weights["gate_proj"], cfg)
+    with scope("up_proj"):
+        u = _dense(n, weights["up_proj"], cfg)
+    rows = ("batch", None) if n.ndim == 3 else (None,)     # [B, N] or [t]
+    h = shd.constrain(jax.nn.silu(g) * u, *rows, "mlp")
+    with scope("down_proj"):
+        return _dense(h, weights["down_proj"], cfg)
+
+
+def route(cfg: MlaMoeConfig, gate, n):
+    """The router over ALL ``n_routed_experts``, in float32 at the
+    highest precision: the scores ``[t, E]``, the chosen experts
+    ``[t, k]`` and their weights (the chosen scores over their sum,
+    times ``routed_scaling_factor``)."""
+    logits = jnp.dot(n.astype(jnp.float32), gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    top, chosen = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return scores, chosen, top * cfg.routed_scaling_factor
+
+
+def _routed(cfg: MlaMoeConfig, experts, l, x, chosen, weights):
+    """The part of the expert layer's result that THIS chip's experts
+    give for the tokens ``x [t, d]``: for each held expert with at least
+    one pair, the expert over all tokens times each token's weight for it
+    (zero where the token did not choose it); an expert nobody chose is
+    not read.  ``experts`` holds every expert block's leaves
+    ``[L, E_here, in, out]`` and is indexed in place by ``(l, e)``: a
+    slice handed to the conditional would be a copy of the weights.
+    Returns the sum ``[t, d]`` and, int32, the local pairs of each token
+    ``[t]``, the experts hit and the local pairs NOT computed (0: there
+    is no capacity to overflow)."""
+    held = cfg.experts_held
+    with jax.named_scope("dispatch"):
+        local = chosen - cfg.experts_first
+        # [t, k, E_here]: a pair to an absent expert matches no column
+        onehot = local[..., None] == jnp.arange(held)
+        combine = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
+        pairs = jnp.sum(onehot, axis=(1, 2), dtype=jnp.int32)
+        count = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+
+    def one(e, carry):
+        def run(carry):
+            y, done = carry
+            own = {name: jax.lax.dynamic_slice(
+                w, (l, e, 0, 0), (1, 1, *w.shape[2:]))[0, 0]
+                for name, w in experts.items()}
+            w_e = jax.lax.dynamic_index_in_dim(combine, e, axis=1)
+            return y + w_e * _gated_mlp(cfg, own, x, contextlib.nullcontext), \
+                done + count[e]
+        return jax.lax.cond(count[e] > 0, run, lambda carry: carry, carry)
+
+    with jax.named_scope("experts"):
+        y, done = jax.lax.fori_loop(
+            0, held, one, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
+    return y, pairs, jnp.sum(count > 0, dtype=jnp.int32), \
+        jnp.sum(count) - done
+
+
+def _moe(cfg: MlaMoeConfig, lp, experts, l, n):
+    """The expert block's MLP on ``n [B, N, d]``: the shared expert plus
+    this chip's routed part; and its routing: the router's ``(scores
+    [B, N, E], choices [B, N, k])`` and `_routed`'s counts (local pairs
+    per row ``[B]``, hits, dropped)."""
+    B, N, d = n.shape
+    x = n.reshape(B * N, d)
+    with jax.named_scope("gate"):
+        scores, chosen, weights = route(cfg, lp["gate"], x)
+    y, pairs, hits, dropped = _routed(cfg, experts, l, x, chosen, weights)
+    with jax.named_scope("shared_experts"):
+        shared = _gated_mlp(cfg, lp["shared_experts"], x)
+    with jax.named_scope("combine"):
+        out = (shared + y).reshape(B, N, d)
+    return out, ((scores.reshape(B, N, -1), chosen.reshape(B, N, -1)),
+                 (pairs.reshape(B, N).sum(axis=1), hits, dropped))
+
+
+def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
+           absorbed: bool):
+    """Every block held: the leading dense ones, then the expert ones.
+    ``cache`` is the ``[L, B, T, 576]`` latent buffer; each block writes
+    this call's entries at the buffer indices ``index [N]`` (consecutive,
+    the same for every row); row ``b``'s real entries start at
+    ``first[b]``, its position 0.  Returns the normed last state, the
+    cache, the routers' ``(scores [B, N, Le, E], choices [B, N, Le, k])``
+    and the routing counts summed over the expert blocks (local pairs
+    ``[B]``, hits, dropped)."""
+    Ld = cfg.first_k_dense_replace
+    eps = cfg.rms_norm_eps
+
+    def block(mlp, carry, lp, l):
+        x, cache = carry
+        h, cache = _attention(cfg, lp, x, index, first, cache, l, absorbed)
+        with jax.named_scope("pre_mlp_layernorm"):
+            n = _rms_norm(h, lp["pre_mlp_layernorm"], eps)
+        with jax.named_scope("mlp"):
+            m, routing = mlp(lp, l, n)
+        with jax.named_scope("post_mlp_layernorm"):
+            return (_sandwich(h, m, lp["post_mlp_layernorm"], eps),
+                    cache), routing
+
+    moe = dict(params["moe_layers"])
+    experts = moe.pop("experts")
+    with jax.named_scope("dense_layers"):
+        carry, _ = jax.lax.scan(
+            lambda c, xs: block(
+                lambda lp, l, n: (_gated_mlp(cfg, lp, n), None), c, *xs),
+            (x, cache), (params["dense_layers"], jnp.arange(Ld)))
+    with jax.named_scope("moe_layers"):
+        (x, cache), (routed, counts) = jax.lax.scan(
+            lambda c, xs: block(
+                lambda lp, l, n: _moe(cfg, lp, experts, l - Ld, n), c, *xs),
+            carry, (moe, Ld + jnp.arange(cfg.moe_layers)))
+    with jax.named_scope("final_norm"):
+        x = _rms_norm(x, params["norm"], eps)
+    pairs, hits, dropped = counts
+    return x, cache, tuple(jnp.moveaxis(r, 0, 2) for r in routed), \
+        (pairs.sum(axis=0), hits.sum(), dropped.sum())
+
+
+def _embed(params, ids):
+    with jax.named_scope("embed_tokens"):
+        return params["embed_tokens"][ids].astype(jnp.float32)
+
+
+def _head(cfg: MlaMoeConfig, params, x):
+    with jax.named_scope("lm_head"):
+        return _dense(x, params["lm_head"], cfg)
+
+
+def empty_cache(cfg: MlaMoeConfig, batch: int, length: int):
+    """The latent cache: ``c_kv`` and ``k_r`` of every position of every
+    block held, and no head axis."""
+    return jnp.zeros((cfg.num_hidden_layers, batch, length, cfg.latent_dim),
+                     cfg.dtype)
+
+
+def kv_cache_bytes(cfg: MlaMoeConfig, batch: int, length: int) -> int:
+    return cfg.num_hidden_layers * batch * length * cfg.latent_dim \
+        * jnp.dtype(cfg.dtype).itemsize
+
+
+# --- the served program ---------------------------------------------------
+
+def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
+             prompt_len, seed, temperature
+             ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array],
+                        Dict[str, jax.Array]]:
+    """Prefill, then ``max_new_tokens`` decode steps, for every row:
+    `looplm.generate`'s contract (rows, lengths, seeds, temperatures,
+    padding never attended to).  Returns the new ids ``[B, N]``, the
+    float32 logits each was drawn from ``[B, N, V]``, ``aux`` (what the
+    routers of the expert blocks scored ``router_scores [B, N, Le, E]``
+    and chose ``expert_choices [B, N, Le, k]`` where those logits were
+    computed, and what they chose over the prompt buffer,
+    ``prompt_choices [B, P, Le, k]``, a row's real positions at its end)
+    and ``stats``, int32, over the DECODE steps and the expert
+    blocks: ``expert_pairs_local [B]`` (a row's pairs routed to experts
+    held here), ``expert_hits`` (distinct local experts with at least one
+    pair, over all rows of a step) and, over the prefill too,
+    ``expert_pairs_dropped`` (0)."""
+    B, P = prompt_ids.shape
+    prompt_len, seed, temperature = (
+        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
+    first = P - prompt_len
+    keys = jax.vmap(jax.random.PRNGKey)(seed)
+
+    def draw(key, logits, temperature, i):
+        drawn = jax.random.categorical(
+            jax.random.fold_in(key, i),
+            logits / jnp.maximum(temperature, 1e-6))
+        return jnp.where(temperature > 0, drawn,
+                         jnp.argmax(logits)).astype(jnp.int32)
+
+    with jax.named_scope("PanguUltraMoE"):
+        # every row's last real id at P - 1
+        prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+        cache = empty_cache(cfg, B, P + max_new_tokens)
+        x, cache, routed, (_, _, dropped) = _stack(
+            cfg, params, _embed(params, prompt_ids), jnp.arange(P), first,
+            cache, absorbed=False)
+        logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+
+        def step(carry, i):
+            logits, routed, cache, counts = carry
+            with jax.named_scope("sample"):
+                token = jax.vmap(draw, (0, 0, 0, None))(
+                    keys, logits, temperature, i)
+            x, cache, nxt_routed, now = _stack(
+                cfg, params, _embed(params, token[:, None]), P + i[None],
+                first, cache, absorbed=True)
+            nxt = _head(cfg, params, x)[:, 0]
+            return (nxt, tuple(r[:, 0] for r in nxt_routed), cache,
+                    tuple(a + b for a, b in zip(counts, now))), \
+                (token, logits, *routed)
+
+        zero = jnp.int32(0)
+        (*_, counts), (tokens, logits, scores, choices) = jax.lax.scan(
+            step, (logits, tuple(r[:, P - 1] for r in routed), cache,
+                   (jnp.zeros((B,), jnp.int32), zero, dropped)),
+            jnp.arange(max_new_tokens))
+    pairs, hits, dropped = counts
+    return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
+            {"router_scores": scores.swapaxes(0, 1),
+             "expert_choices": choices.swapaxes(0, 1),
+             "prompt_choices": routed[1]},
+            {"expert_pairs_local": pairs, "expert_hits": hits,
+             "expert_pairs_dropped": dropped})
+
+
+def make_program(cfg: MlaMoeConfig, max_new_tokens: int):
+    """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
+    device trace) like every language model's."""
+
+    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
+        return generate(cfg, max_new_tokens, params, prompt_ids, prompt_len,
+                        seed, temperature)
+
+    return jax.jit(lm_generate)
+
+
+def window_counters(cfg: MlaMoeConfig, stats, real: int, steps: int
+                    ) -> Dict[str, int]:
+    """The ``lm.*`` window counters of one execution from its fetched
+    ``stats``: the ``real`` rows' pairs (a padded row repeats the first
+    and is nobody's request; it routes as the first does, so it adds no
+    hit)."""
+    return {
+        "lm.expert_pairs": real * steps * cfg.moe_layers
+        * cfg.num_experts_per_tok,
+        "lm.expert_pairs_local": int(stats["expert_pairs_local"][:real]
+                                     .sum()),
+        "lm.expert_hits": int(stats["expert_hits"]),
+        "lm.expert_pairs_dropped": int(stats["expert_pairs_dropped"])}

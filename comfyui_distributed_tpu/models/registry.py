@@ -1267,7 +1267,7 @@ def clear_pipeline_cache() -> None:
     gg_mod.clear_gligen_cache()
 
 
-# --- language models (models/looplm.py) ----------------------------------------
+# --- language models (models/looplm.py, models/mla_moe.py) ---------------------
 #
 # A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
 # model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
@@ -1277,27 +1277,105 @@ def clear_pipeline_cache() -> None:
 EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
                    "Prompt: {text} Detailed prompt:")
 
+# The row counts ``lm_generate`` is compiled for, all of them when the
+# first request of a length meets the model (no shape is built later:
+# a served window compiles nothing).  An execution is padded to the next
+# count with copies of its first row; the last count also bounds what is
+# kept for requests still in the queue (server/lm_handover.py): three
+# results.  Argued per family (`LMFamily.row_counts`); both take these.
+LM_ROW_COUNTS = (1, 4)
 
-def detect_lm_family(name: str) -> str:
-    """``tiny`` under ``DTPU_DEFAULT_FAMILY=tiny`` (tests, rehearsals) or
-    by name, else ``ouro`` (Ouro-2.6B's published config)."""
+
+@dataclasses.dataclass(frozen=True)
+class LMFamily:
+    """What the serving path needs of a language-model architecture: the
+    ONE place a model file is named.  ``module`` (under ``models/``) is
+    imported when a graph first names a model of the family, and gives
+    ``CONFIGS`` (``full`` and ``tiny``), ``param_count``,
+    ``seeded_params(cfg, seed)``, ``load_checkpoint(path, cfg)``,
+    ``make_program(cfg, new_tokens)`` (the jitted ``lm_generate`` ->
+    ids, logits, ``aux`` arrays per position, ``stats`` to count from),
+    ``kv_cache_bytes(cfg, rows, positions)`` and
+    ``window_counters(cfg, stats, real_rows, steps)``; its config gives
+    ``vocab_size`` and ``layer_applications`` (per token)."""
+    module: str
+    names: Tuple[str, ...]          # what a model name of it contains
+    row_counts: Tuple[int, ...]
+    what: str
+
+    def load(self):
+        import importlib
+        return importlib.import_module(
+            f"comfyui_distributed_tpu.models.{self.module}")
+
+
+LM_FAMILIES = {
+    # Why 4 rows and no more: a decode step streams 20 GB of weights for
+    # all rows and 0.2 GB of cache for each, the cache is 0.2 GB a row
+    # beside 8 GB resident on a 16 GB chip, and every count is one more
+    # program to trace, lower and load at set-up.  Why no count between:
+    # what sharing costs is the step from one row to more than one, not
+    # the rows.  On the chip an execution takes 1.93 s at 1 row and 2.18 s
+    # at 4, all of the difference in the attention sub-layer (from two
+    # rows up XLA lowers its projections another way; the MLP's stream
+    # takes the same 1.18 s), and with (1, 2, 4) the four-caller cell,
+    # whose executions carry two real rows, gained 0.5% in images a second
+    # for a third program at set-up: about 2.17 s at 2 rows (PERF.md
+    # section 6, PR 28).
+    "ouro": LMFamily(
+        "looplm", ("ouro",), LM_ROW_COUNTS,
+        "Ouro-2.6B: a dense looped decoder, a per-head KV cache with a "
+        "slot per loop and layer"),
+    # The latent cache is 5.8 KB a position a row (0.7 MB a row of 128
+    # positions, where Ouro's is 200 MB), so MEMORY no longer argues for
+    # 4: a hundred rows would fit.  What still does: the rows come from
+    # the requests waiting in one server's queue (four callers in the
+    # cell: more rows than callers are padding), every count is a program
+    # to compile at set-up, and each further row routes 8 more pairs a
+    # layer, so the experts a step reads grow with the rows (1.9 distinct
+    # local experts a layer at 4 rows) where a dense model's bytes do
+    # not.  Rows past 4 cost 0.7 MB each and are ROADMAP B7's to measure.
+    "pangu": LMFamily(
+        "mla_moe", ("pangu",), LM_ROW_COUNTS,
+        "openPangu-Ultra-MoE-718B, one chip's share: latent attention "
+        "(MLA) with a latent cache, 16 of 256 routed experts held"),
+}
+
+
+def detect_lm_family(name: str) -> Tuple[str, str]:
+    """``(family, size)`` of a model name: the family of `LM_FAMILIES`
+    whose name it contains, at size ``tiny`` under
+    ``DTPU_DEFAULT_FAMILY=tiny`` (tests, rehearsals) or by name
+    (``tiny`` / ``test``; with no family named that is the first
+    family's tiny model), else ``full`` (the published config).  A name
+    of no known family is refused: it used to be served as Ouro."""
     lowered = name.lower()
-    if os.environ.get(FAMILY_ENV, "").startswith("tiny") \
-            or "tiny" in lowered or "test" in lowered:
-        return "tiny"
-    return "ouro"
+    tiny = os.environ.get(FAMILY_ENV, "").startswith("tiny") \
+        or "tiny" in lowered or "test" in lowered
+    for family, entry in LM_FAMILIES.items():
+        if any(n in lowered for n in entry.names):
+            return family, "tiny" if tiny else "full"
+    if tiny:
+        return next(iter(LM_FAMILIES)), "tiny"
+    known = "; ".join(f"{'/'.join(e.names)} ({e.what})"
+                      for e in LM_FAMILIES.values())
+    raise ValueError(
+        f"language model {name!r} is of no family this server knows: a "
+        f"model name has to contain one of: {known}")
 
 
 @dataclasses.dataclass
 class LMOutput:
     """What a generation leaves on the device: the prompt's real ids, and
     of the execution that served it the new ids ``[B, N]``, the float32
-    logits each was drawn from ``[B, N, V]`` and the exit probabilities
-    ``[B, N, R]``; ``row`` is this request's."""
+    logits each was drawn from ``[B, N, V]`` and ``aux``, the family's
+    other per-position arrays ``[B, N, ...]`` (a looped model's exit
+    probabilities, an expert model's router scores and choices);
+    ``row`` is this request's."""
     prompt_ids: np.ndarray
     tokens: Any
     logits: Any
-    exit_probs: Any
+    aux: Dict[str, Any]
     row: int = 0
 
 
@@ -1309,31 +1387,17 @@ class LMRow:
     temperature: float = 0.0
 
 
-# The row counts ``lm_generate`` is compiled for, all of them when the
-# first request of a length meets the model (no shape is built later:
-# a served window compiles nothing).  An execution is padded to the next
-# count with copies of its first row.  Why 4 and no more: a decode step
-# streams 20 GB of weights for all rows and 0.2 GB of cache for each, the
-# cache is 0.2 GB a row beside 8 GB resident on a 16 GB chip, and every
-# count is one more program to trace, lower and load at set-up.  Why no
-# count between: what sharing costs is the step from one row to more than
-# one, not the rows.  On the chip an execution takes 1.93 s at 1 row and
-# 2.18 s at 4, all of the difference in the attention sub-layer (from two
-# rows up XLA lowers its projections another way; the MLP's stream takes
-# the same 1.18 s), and with (1, 2, 4) the four-caller cell, whose
-# executions carry two real rows, gained 0.5% in images a second for a
-# third program at set-up: about 2.17 s at 2 rows (PERF.md section 6,
-# PR 28).  The last count also bounds what is kept for requests still
-# in the queue (server/lm_handover.py): three results.
-LM_ROW_COUNTS = (1, 4)
-
-
 class LanguageModel:
-    """A looped decoder, its tokenizer and its jitted program."""
+    """A decoder of one of `LM_FAMILIES`, its tokenizer and its jitted
+    program."""
 
-    def __init__(self, name: str, cfg: Any, params: Any, tokenizer: Any):
+    def __init__(self, name: str, cfg: Any, params: Any, tokenizer: Any,
+                 family: str = "ouro"):
         self.name, self.cfg, self.params = name, cfg, params
         self.tokenizer = tokenizer
+        self.family = family
+        self.row_counts = LM_FAMILIES[family].row_counts
+        self._arch = LM_FAMILIES[family].load()
         # (new tokens, prompt positions) -> {rows: compiled lm_generate}
         self._programs: Dict[Tuple[int, int], Dict[int, Any]] = {}
         self._mesh = None
@@ -1370,12 +1434,11 @@ class LanguageModel:
 
     def _compiled(self, n: int, prompt_tokens: int) -> Dict[int, Any]:
         """``lm_generate`` for ``n`` new tokens behind ``prompt_tokens``
-        positions, compiled for every count of LM_ROW_COUNTS at once."""
-        from comfyui_distributed_tpu.models import looplm
+        positions, compiled for every count of ``row_counts`` at once."""
         with self._lock:
             programs = self._programs.get((n, prompt_tokens))
             if programs is None:
-                jitted = looplm.make_generate(self.cfg, n)
+                jitted = self._arch.make_program(self.cfg, n)
 
                 def row(dtype, *shape):
                     return jax.ShapeDtypeStruct(shape, dtype)
@@ -1385,7 +1448,7 @@ class LanguageModel:
                         self.params, row(np.int32, b, prompt_tokens),
                         row(np.int32, b), row(np.uint32, b),
                         row(np.float32, b)).compile()
-                    for b in LM_ROW_COUNTS}
+                    for b in self.row_counts}
         return programs
 
     def generate_rows(self, rows: Sequence[LMRow], max_new_tokens: int = 64,
@@ -1399,23 +1462,23 @@ class LanguageModel:
         it, so one shape runs), each row with its own length, seed and
         temperature.  The host meets the device here, in the middle of a
         graph: the ids have to be words before the text encoder can be
-        enqueued.
+        enqueued.  What the family counts of an execution (its ``stats``)
+        comes over in the same read.
 
         Counted per request served, so that tokens over stages stays the
         steps of one execution: the first row is the caller's and its
         ``lm_generate`` stage lies on the current span; row ``i`` behind
         it is a request still in the queue whose root span is
         ``spans[i - 1]``, and gets the same interval there."""
-        from comfyui_distributed_tpu.models import looplm
         self._ensure_laid_out()
         n, real = int(max_new_tokens), len(rows)
-        if n < 1 or not 1 <= real <= LM_ROW_COUNTS[-1]:
+        if n < 1 or not 1 <= real <= self.row_counts[-1]:
             raise ValueError(
                 f"{self.name}: {n} new tokens for {real} row(s) cannot be "
-                f"generated (at least 1 token, 1 to {LM_ROW_COUNTS[-1]} "
+                f"generated (at least 1 token, 1 to {self.row_counts[-1]} "
                 f"rows)")
         ids = [self.prompt_ids(r.text, prompt_tokens) for r in rows]
-        count = next(b for b in LM_ROW_COUNTS if b >= real)
+        count = next(b for b in self.row_counts if b >= real)
         # a padded row repeats the first
         source = [*range(real), *[0] * (count - real)]
         padded = np.full((count, prompt_tokens), self.tokenizer.pad_id,
@@ -1425,7 +1488,7 @@ class LanguageModel:
         program = self._compiled(n, prompt_tokens)[count]
         t0 = time.time()
         with trace_mod.stage("lm_generate"):
-            tokens, logits, exits = program(
+            tokens, logits, aux, stats = program(
                 self.params, padded,
                 np.asarray([len(ids[i]) for i in source], np.int32),
                 np.asarray([rows[i].seed & 0xFFFFFFFF for i in source],
@@ -1434,7 +1497,7 @@ class LanguageModel:
                            np.float32))
             with trace_mod.device_wait():
                 # dtpu-lint: ignore[spine-host-fetch] ids must be words before CLIP can run
-                host_tokens = np.asarray(jax.device_get(tokens))
+                host_tokens, stats = jax.device_get((tokens, stats))
         t1 = time.time()
         trace_mod.mark_instant("lm_ids_ready", at=t1)
         for span in spans:
@@ -1444,17 +1507,20 @@ class LanguageModel:
         for b in range(real):
             with trace_mod.stage("detokenize"):
                 words = self.tokenizer.decode(host_tokens[b])
-            out.append((words, LMOutput(ids[b], tokens, logits, exits, b)))
+            out.append((words, LMOutput(ids[b], tokens, logits, aux, b)))
         bump = trace_mod.GLOBAL_COUNTERS.bump
         bump("lm.prompt_tokens", sum(len(i) for i in ids))
         bump("lm.tokens_decoded", n * real)
-        bump("lm.layer_applications", n * real * self.cfg.cache_slots)
+        bump("lm.layer_applications", n * real * self.cfg.layer_applications)
         bump("lm.executions")
         bump("lm.rows", real)
         bump("lm.padded_rows", count - real)
+        for name, value in self._arch.window_counters(
+                self.cfg, stats, real, n).items():
+            bump(name, value)
         trace_mod.GLOBAL_GAUGES.set(
             "lm.kv_cache_bytes",
-            looplm.kv_cache_bytes(self.cfg, count, prompt_tokens + n))
+            self._arch.kv_cache_bytes(self.cfg, count, prompt_tokens + n))
         return out
 
     def generate(self, text: str, seed: int = 0, max_new_tokens: int = 64,
@@ -1465,38 +1531,64 @@ class LanguageModel:
                                   max_new_tokens, prompt_tokens)[0]
 
 
+def _device_free_bytes() -> Optional[int]:
+    """Bytes the first device's allocator can still give; None where it
+    does not say (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
 def load_language_model(name: str, models_dir: Optional[str] = None
                         ) -> LanguageModel:
     """Load or virtually-initialize the named language model (cached
     beside the pipelines).  A file of that name under ``models_dir`` is
     read as the model's Hugging Face safetensors; without one the
-    weights are seeded from the name and drawn ON THE DEVICE in one
-    jitted call (2.67 B values: numpy on the host would take a minute)."""
-    from comfyui_distributed_tpu.models import looplm
+    weights are seeded from the name and drawn ON THE DEVICE (billions
+    of values: numpy on the host would take a minute).
+
+    Two graphs that name two models keep BOTH resident (nothing here
+    evicts: a model that left would be made again, tens of seconds, at
+    its next request).  Where the second cannot fit beside what is
+    resident (Ouro-2.6B's 5.3 GB and openPangu's 9.8 GB share do not
+    share one 16 GB chip with a checkpoint) it is refused BY NAME, with
+    what it needs and what is resident, before the allocator fails with
+    an error that names nothing."""
     from comfyui_distributed_tpu.models.tokenizer import make_lm_tokenizer
     key = f"lm:{name}:{models_dir or ''}"
     with _pipeline_lock:
         if key in _pipeline_cache:
             return _pipeline_cache[key]
-    cfg = {"ouro": looplm.OURO_2_6B,
-           "tiny": looplm.TINY_LOOPLM}[detect_lm_family(name)]
+    family, size = detect_lm_family(name)
+    arch = LM_FAMILIES[family].load()
+    cfg = arch.CONFIGS[size]
     path = os.path.join(models_dir, name) if models_dir else None
+    need = arch.param_count(cfg) * jnp.dtype(cfg.dtype).itemsize
+    free = _device_free_bytes()
+    if free is not None and need > free:
+        with _pipeline_lock:
+            resident = sorted(k.split(":")[1] for k in _pipeline_cache
+                              if k.startswith("lm:"))
+        raise ValueError(
+            f"language model {name!r} needs {need / 1e9:.2f} GB for its "
+            f"weights and the device has {free / 1e9:.2f} GB free; "
+            f"resident language models: {resident or 'none'} (none is "
+            f"evicted: serve one language model a chip)")
     with trace_mod.stage("load_weights"):
         if path is not None and os.path.exists(path):
-            from comfyui_distributed_tpu.models.checkpoints import \
-                load_looplm_checkpoint
-            params = load_looplm_checkpoint(path, cfg)
+            params = arch.load_checkpoint(path, cfg)
             log(f"loaded language model {name} from {path}")
         else:
             seed = _name_seed(name)
-            params = jax.jit(partial(looplm.init_params, cfg))(
-                np.uint32(seed))
-            log(f"virtual language model {name!r}: no file on disk, "
-                f"{looplm.param_count(cfg) / 1e9:.3f} B seeded values made "
-                f"on the device (seed {seed})")
+            params = arch.seeded_params(cfg, np.uint32(seed))
+            log(f"virtual language model {name!r} ({family}): no file on "
+                f"disk, {arch.param_count(cfg) / 1e9:.3f} B seeded values "
+                f"made on the device (seed {seed})")
         jax.block_until_ready(params)
     model = LanguageModel(name, cfg, params,
-                          make_lm_tokenizer(models_dir, cfg.vocab_size))
+                          make_lm_tokenizer(models_dir, cfg.vocab_size),
+                          family)
     with _pipeline_lock:
         _pipeline_cache[key] = model
     return model

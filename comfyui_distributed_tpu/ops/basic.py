@@ -1002,10 +1002,15 @@ class CLIPTextEncode(Op):
 
 @register_op
 class LanguageModelLoader(Op):
-    """-> LANGUAGE_MODEL (models/looplm.py): a decoder resident beside
-    the diffusion checkpoints.  The model's safetensors and
-    ``tokenizer.json`` from the models dir if present; otherwise seeded
-    weights made on the device and the hash tokenizer pair."""
+    """-> LANGUAGE_MODEL: a decoder resident beside the diffusion
+    checkpoints, of the family its name contains
+    (``registry.LM_FAMILIES``): ``ouro`` (models/looplm.py, Ouro-2.6B, a
+    dense looped decoder) or ``pangu`` (models/mla_moe.py, one chip's
+    share of openPangu-Ultra-MoE-718B: latent attention with a latent
+    cache, routed experts).  A name of neither is refused.  The model's
+    safetensors and ``tokenizer.json`` from the models dir if present;
+    otherwise seeded weights made on the device and the hash tokenizer
+    pair."""
     TYPE = "LanguageModelLoader"
     WIDGETS = ["model_name"]
     DEFAULTS = {"model_name": "ouro-2.6b.safetensors"}
@@ -1086,9 +1091,11 @@ class LanguageModelGenerate(Op):
 @register_op
 class SaveLanguageModelOutput(Op):
     """LM_OUTPUT -> ``<filename_prefix>.npz`` in the output directory:
-    ``prompt_ids``, ``tokens [N]``, ``logits [N, V]`` float32 and
-    ``exit_probs [N, R]`` of the request's row, for comparison with a
-    reference (benchmarks/chip/verify_lm.py)."""
+    ``prompt_ids``, ``tokens [N]``, ``logits [N, V]`` float32 and the
+    family's other per-position arrays of the request's row (a looped
+    model's ``exit_probs [N, R]``; an expert model's ``router_scores
+    [N, Le, E]`` and ``expert_choices [N, Le, k]``), for comparison with
+    a reference (benchmarks/chip/verify_lm.py, verify_lm_moe.py)."""
     TYPE = "SaveLanguageModelOutput"
     WIDGETS = ["filename_prefix"]
     DEFAULTS = {"filename_prefix": "lm_output"}
@@ -1102,11 +1109,12 @@ class SaveLanguageModelOutput(Op):
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with trace_mod.device_wait():
             # dtpu-lint: ignore[spine-host-fetch] an OUTPUT node's host edge
-            tokens, logits, exits = jax.device_get(
-                (lm_output.tokens, lm_output.logits, lm_output.exit_probs))
+            tokens, logits, aux = jax.device_get(
+                (lm_output.tokens, lm_output.logits, lm_output.aux))
         row = lm_output.row
         np.savez(path, prompt_ids=lm_output.prompt_ids, tokens=tokens[row],
-                 logits=logits[row], exit_probs=exits[row])
+                 logits=logits[row],
+                 **{name: a[row] for name, a in aux.items()})
         return ()
 
 

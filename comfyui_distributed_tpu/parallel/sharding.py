@@ -20,7 +20,12 @@ tree (models/looplm.py) takes them as it is: its layers' leaves carry a
 leading ``L`` axis that is never split (a kernel ``[L, in, out]`` is
 column-split over ``tensor`` like any ``[in, out]``), everything is a full
 replica over ``data``, and its q/k/v and MLP activations name the same
-logical axes (``"heads"``, ``"mlp"``) the UNet's do.
+logical axes (``"heads"``, ``"mlp"``) the UNet's do.  The expert model's
+routed experts (models/mla_moe.py, leaves ``experts/* [L, E_here, in, out]``)
+are the one exception to "shape-driven": their second axis is the logical
+axis ``"expert"``, which no mesh the repo runs splits yet (a chip is told
+which experts it holds and runs without the exchange), and the leaf is NOT
+column-split as if ``E_here`` were a batch of kernels.
 
 Activation placement (ISSUE 16) goes through a **logical-axis rule table**
 instead of hand-built specs: model code names what a dim *is* (``"batch"``,
@@ -56,13 +61,22 @@ LOGICAL_BATCH = "batch"   # per-image rows (the reference's worker axis)
 LOGICAL_HEADS = "heads"   # attention heads (megatron: split across tensor)
 LOGICAL_MLP = "mlp"       # feed-forward hidden features (column split)
 LOGICAL_SEQ = "seq"       # token axis (ring attention / sp)
+LOGICAL_EXPERT = "expert"  # routed experts (expert parallelism)
 
 LOGICAL_AXIS_RULES = {
     LOGICAL_BATCH: DATA_AXIS,
     LOGICAL_HEADS: TENSOR_AXIS,
     LOGICAL_MLP: TENSOR_AXIS,
     LOGICAL_SEQ: SEQ_AXIS,
+    # unsplit on every mesh the repo runs today: each chip of an
+    # expert-parallel deployment is a server of its own that holds its
+    # experts whole, and no program has the all-to-all a split would need
+    LOGICAL_EXPERT: None,
 }
+
+# a parameter leaf under this key carries the expert axis behind its
+# leading layer axis: ``[L, E_here, in, out]``
+EXPERT_LEAVES = "['experts']"
 
 
 def mesh_spec(*parts: Optional[str]) -> P:
@@ -267,6 +281,9 @@ def param_spec(path: str, shape: tuple, tensor_size: int,
     """
     if tensor_size <= 1 or len(shape) < 2:
         return P()
+    if EXPERT_LEAVES in path:
+        # split by experts or not at all: never by columns
+        return logical_spec(None, LOGICAL_EXPERT, None, None)
     n = 1
     for d in shape:
         n *= d

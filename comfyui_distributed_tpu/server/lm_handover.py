@@ -28,7 +28,7 @@ What is kept is keyed on everything the result is a function of (model,
 lengths, text, seed, temperature), read again from the values the
 follower's node is really called with: a request that was edited, or
 whose inputs the graph alone did not give away, finds nothing under its
-key and runs on its own.  At most ``LM_ROW_COUNTS[-1] - 1`` results are
+key and runs on its own.  At most ``model.row_counts[-1] - 1`` results are
 kept at a time; a follower that ends any other way (purged as abandoned,
 cancelled by a drain, run by the step executor, failed before its node)
 has its result dropped when it is finalized, or is never kept if it left
@@ -41,7 +41,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
-from comfyui_distributed_tpu.models.registry import LM_ROW_COUNTS, LMRow
+from comfyui_distributed_tpu.models.registry import LMRow
 from comfyui_distributed_tpu.ops.base import get_op
 from comfyui_distributed_tpu.utils import trace as trace_mod
 from comfyui_distributed_tpu.workflow.graph import parse_workflow
@@ -144,7 +144,7 @@ class GenerateHandover:
             queued = list(state._queue)
         with self._lock:
             served = {pid for pid, _, _ in self._kept}
-        room = LM_ROW_COUNTS[-1] - 1 - len(served)
+        room = model.row_counts[-1] - 1 - len(served)
         found: List[Tuple[str, LMRow, Any]] = []
         for item in queued:
             if item["id"] in served:

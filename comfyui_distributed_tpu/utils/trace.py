@@ -235,6 +235,7 @@ OTHER = "other"
 # written in ``attn1`` itself (``.../attn1/bnhd,bmhd->bhnm/dot_general``)
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
 _UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
+_MOE = "PanguUltraMoE"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
     ("norm", None, r"GroupNorm_\d+|LayerNorm_\d+|(?:in_|out_)?norm\d*"
@@ -276,13 +277,32 @@ KERNEL_CLASSES = (
     # the layer scan's and the program's own glue (residual adds, the
     # slice that picks the prompt's last row)
     ("lm_proj", _LM, r"layers|LoopLM"),
+    # the latent-attention decoder with routed experts (models/mla_moe.py),
+    # under the same classes, and one more: ``lm_experts`` is everything
+    # routing adds (the router, the dispatch, the routed experts held
+    # here, the combine); the dense blocks' MLP and the shared expert are
+    # ``lm_mlp``.  Inside ``experts`` no scope carries a projection's
+    # name: the innermost segment decides
+    ("lm_norm", _MOE, r"(?:input|post_attention|pre_mlp|post_mlp)_layernorm"
+                      r"|(?:q_a|kv_a)_layernorm|final_norm"),
+    # the two low-rank pairs, the output projection, and absorption
+    ("lm_proj", _MOE, r"q_[ab]_proj|kv_a_proj_with_mqa|kv_b_proj|o_proj"
+                      r"|absorb_[qv]"),
+    ("lm_cache", _MOE, r"kv_cache"),                # the LATENT cache
+    ("lm_attn", _MOE, r"self_attn|rotary"),
+    ("lm_experts", _MOE, r"gate|dispatch|experts|combine"),
+    ("lm_mlp", _MOE, r"mlp|shared_experts|gate_proj|up_proj|down_proj"),
+    ("lm_head", _MOE, r"lm_head|sample"),
+    ("embed", _MOE, r"embed_tokens"),
+    ("lm_proj", _MOE, r"dense_layers|moe_layers|PanguUltraMoE"),
 )
 # the denoise programs' own operations under no module (CFG combine,
 # solver update, noise): ``core`` / ``step`` are the functions
 # models/registry.py jits
 SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
-_MODEL_OF = re.compile(r"^(UNet|VAE|CLIPTextModel|LoopLM)(?:\.\w+)?$")
+_MODEL_OF = re.compile(
+    r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 

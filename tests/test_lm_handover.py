@@ -711,3 +711,107 @@ def test_a_server_whose_graphs_hold_no_generate_node_never_imports_looplm(
     assert got["status"] == ["success", "success"]
     assert got["looplm_imported"] is False and got["counters"] == []
     assert got["handover"] == "GenerateHandover"
+
+
+# --- a second family through the same path (PR 32) ----------------------------
+
+PANGU = "openpangu-ultra-moe-718b.safetensors"
+
+
+def pangu_graph(text, seed=5):
+    g = graph(text, seed=seed)
+    g[LOADER]["inputs"]["model_name"] = PANGU
+    return g
+
+
+def test_four_prompts_of_the_expert_model_share_one_execution(tmp_path):
+    """As Ouro's test above, for the model of the other family: a held
+    executor with four requests behind it gives ONE execution, 4 rows, 3
+    followers served, through the same nodes, hand-over and counting; and
+    the family's routing counters and the latent cache's gauge are on
+    ``GET /distributed/metrics`` beside them."""
+    async def body():
+        state = ServerState(config_path=str(tmp_path / "cfg.json"),
+                            input_dir=str(tmp_path / "input"),
+                            output_dir=str(tmp_path / "output"))
+        client = TestClient(TestServer(build_app(state)))
+        await client.start_server()
+        try:
+            state._exec_gate.clear()
+            pids = []
+            for i, text in enumerate(TEXTS):
+                r = await client.post("/prompt", json={
+                    "prompt": pangu_graph(text, seed=10 + i),
+                    "client_id": "t"})
+                assert r.status == 200, await r.text()
+                pids.append((await r.json())["prompt_id"])
+            state._exec_gate.set()
+            for _ in range(2400):
+                hist = await (await client.get("/history")).json()
+                if all(p in hist for p in pids):
+                    break
+                await asyncio.sleep(0.05)
+            assert [hist[p]["status"] for p in pids] == ["success"] * 4
+            return state, await (
+                await client.get("/distributed/metrics")).json()
+        finally:
+            await client.close()
+
+    state, m = asyncio.run(body())
+    got = {k[3:]: v for k, v in m["pipeline"]["counters"].items()
+           if k.startswith("lm.")}
+    assert got["executions"] == 1 and got["rows"] == 4
+    assert got["padded_rows"] == 0 and got["followers_served"] == 3
+    assert "followers_dropped" not in got and state.lm_handover.kept() == 0
+    stages = m["pipeline"]["stages"]
+    assert stages["lm_generate"]["count"] == 4
+    assert got["tokens_decoded"] / stages["lm_generate"]["count"] == NEW
+    # tokens x blocks held (3 in the tiny model of this family)
+    assert got["layer_applications"] == 4 * NEW * 3
+    # rows x steps x expert blocks x top-k pairs, some of them local
+    assert got["expert_pairs"] == 4 * NEW * 2 * 4
+    assert 0 < got["expert_pairs_local"] < got["expert_pairs"]
+    assert 0 < got["expert_hits"] <= NEW * 2 * 4
+    assert got["expert_pairs_dropped"] == 0
+    # the LATENT cache: 3 blocks x 4 rows x positions x (16 + 8) float32
+    assert m["pipeline"]["gauges"]["lm.kv_cache_bytes"] == \
+        3 * 4 * (PROMPT + NEW) * 24 * 4
+
+
+def test_two_graphs_naming_the_two_models_keep_both_resident(state):
+    """Nothing evicts a language model: both stay, each serves its own
+    graph, and a request of one is no row of the other's execution."""
+    first = state.enqueue_prompt(graph("a red fox"), "t")
+    second = state.enqueue_prompt(pangu_graph("a red fox"), "t")
+    third = state.enqueue_prompt(graph("a harbour"), "t")
+    assert run_next(state) == [first]
+    # the leader took the other Ouro request along, not the other model's
+    assert counters()["rows"] == 2 and state.lm_handover.kept() == 1
+    assert run_next(state) == [second]
+    assert counters()["executions"] == 2 and counters()["rows"] == 3
+    assert run_next(state) == [third]
+    assert counters()["executions"] == 2
+    assert all(state._history[p]["status"] == "success"
+               for p in (first, second, third))
+    resident = {k.split(":")[1]: v for k, v in
+                registry._pipeline_cache.items() if k.startswith("lm:")}
+    assert {"ouro-2.6b.safetensors", PANGU} <= set(resident)
+    assert {m.family for m in resident.values()} >= {"ouro", "pangu"}
+
+
+def test_a_model_that_cannot_fit_beside_the_resident_one_is_refused_by_name(
+        monkeypatch):
+    """Where the device says what it has free (a TPU does; the CPU does
+    not) the second model is refused with its name, its need and what is
+    resident, before the allocator fails with an error that names
+    nothing."""
+    registry.load_language_model("ouro-2.6b.safetensors")
+    monkeypatch.setattr(registry, "_device_free_bytes", lambda: 1000)
+    with pytest.raises(ValueError) as e:
+        registry.load_language_model("openpangu-second.safetensors")
+    said = str(e.value)
+    assert "openpangu-second.safetensors" in said and "0.00 GB free" in said
+    assert "ouro-2.6b.safetensors" in said
+    # what is resident is served as before
+    assert registry.load_language_model("ouro-2.6b.safetensors").family \
+        == "ouro"

@@ -33,6 +33,9 @@ CLIP_CLASSES = {"clip_attn", "clip_mlp", "norm", "embed"}   # mid_attn
 # the looped language model's (PR 26; tests/test_looplm.py holds its rows)
 LM_CLASSES = {"lm_attn", "lm_proj", "lm_mlp", "lm_norm", "lm_cache",
               "lm_head", "embed"}
+# the expert model's (PR 32; tests/test_mla_moe.py holds its rows): the
+# same classes and one for everything routing adds
+MOE_CLASSES = LM_CLASSES | {"lm_experts"}
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
@@ -54,7 +57,7 @@ def compiled_op_names(fn):
 def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
     classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
     assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
-        | LM_CLASSES | {"vae_attn"}
+        | LM_CLASSES | MOE_CLASSES | {"vae_attn"}
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; from comfyui_distributed_tpu.utils.trace import "
