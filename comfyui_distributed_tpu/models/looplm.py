@@ -48,13 +48,15 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Mapping, Tuple
+from typing import Any, Dict, Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from comfyui_distributed_tpu.models.layers import \
+from comfyui_distributed_tpu.models.layers import _live_mesh, \
     scaled_dot_product_attention
+from comfyui_distributed_tpu.ops.pallas.fewrow_dense import LANES, \
+    fewrow_dense
 from comfyui_distributed_tpu.parallel import sharding as shd
 
 
@@ -206,11 +208,167 @@ def _cache_slot(r: int, l):
     return r, l
 
 
+# --- a product of the rows with a weight -----------------------------------
+#
+# One algorithm (stream the weight once, accumulate in float32) wants a
+# different lowering at different row counts.  With ONE row XLA writes a
+# multiply-and-reduce over the stacked leaf with the layer scan's
+# ``dynamic-slice`` fused in, at 680-705 GB/s of a v5e's 819; with a
+# prefill's 64-256 rows an ordinary matmul.  With 2-8 rows (an execution
+# shared by the requests waiting, PERF.md section 6, PR 28) what it writes
+# depends on the shape.  Most products it streams as well as one row's
+# (720-755 GB/s); but Ouro's q / k / v / o layer slices and openPangu's
+# ``q_b_proj`` slice it first COPIES out of the stacked leaf into another
+# layout, every layer of every step, and then reads again: twice to three
+# times the leaf's bytes (section 6, PR 33).  There the product goes to
+# the weight-streaming kernel (ops/pallas/fewrow_dense.py), which reads
+# its layer out of the leaf in place and matches XLA's rate on every
+# shape XLA streams well, so ONE path takes every few-row product.
+
+FEWROW_ROWS = (2, 8)            # what the kernel's resident ``x`` holds
+# under this a weight is not worth a launch (a router's or a gate's
+# vector; the tiny models' everything)
+FEWROW_MIN_WEIGHT_BYTES = 1 << 20
+
+
+def few_rows(platform: str, rows: int,
+             mesh_axes: Optional[dict] = None) -> bool:
+    """Whether a call of ``rows`` rows (batch x positions) is one whose
+    products `dense_path` may send to the few-row kernel: on a TPU, 2 to
+    8 rows, no multi-device mesh live (XLA cannot partition the custom
+    call; under a mesh every product stays with ``jnp.dot``, which it
+    can).  A layer scan reads this once: where it holds, the scan walks
+    the layer INDEX with the stacked leaves closed over, so that a
+    product can be handed its whole leaf (`Stacked`)."""
+    return platform == "tpu" \
+        and FEWROW_ROWS[0] <= rows <= FEWROW_ROWS[1] \
+        and math.prod((mesh_axes or {}).values()) == 1
+
+
+def dense_path(platform: str, rows: int, k: int, n: int, itemsize: int = 2,
+               mesh_axes: Optional[dict] = None) -> str:
+    """Which lowering a product ``[rows, k] x [k, n]`` with a resident
+    leaf takes: ``fewrow`` (the weight-streaming Pallas kernel) or
+    ``xla`` (``jnp.dot``).  A function of what the code can see at trace
+    time and nothing else: the backend's platform, the static shapes, the
+    live mesh.  The one-row program, both prefills and every other
+    backend keep ``jnp.dot``; so does a weight the kernel's blocks do not
+    divide (``k``, ``n`` multiples of 128) or that is too small to be
+    worth a launch."""
+    if few_rows(platform, rows, mesh_axes) and k % LANES == 0 \
+            and n % LANES == 0 \
+            and k * n * itemsize >= FEWROW_MIN_WEIGHT_BYTES:
+        return "fewrow"
+    return "xla"
+
+
+class Stacked(NamedTuple):
+    """A weight as it lies in the device's memory: the whole leaf
+    ``[L, in, out]`` and the layer asked for, or ``[in, out]`` and None.
+    What `_dense` needs to hand the kernel a leaf to read in place; a
+    plain array handed to `_dense` may be a slice made inside the
+    program (a routed expert's), which a custom call would materialise,
+    and always meets ``jnp.dot``."""
+    leaf: jax.Array
+    layer: Optional[jax.Array] = None
+
+
+def matrix(kernel) -> jax.Array:
+    """``[in, out]`` of a weight handed over either way."""
+    if not isinstance(kernel, Stacked):
+        return kernel
+    if kernel.layer is None:
+        return kernel.leaf
+    return jax.lax.dynamic_index_in_dim(kernel.leaf, kernel.layer,
+                                        keepdims=False)
+
+
+def layer_of(leaves, l):
+    """Layer ``l`` of a tree of stacked leaves, as a few-row scan body
+    sees it: matrices as `Stacked`, vectors (norm gains) sliced."""
+    return jax.tree_util.tree_map(
+        lambda w: Stacked(w, l) if w.ndim >= 3
+        else jax.lax.dynamic_index_in_dim(w, l, keepdims=False), leaves)
+
+
+def scan_layers(body, carry, leaves, count: int, stream: bool,
+                first: int = 0):
+    """``lax.scan`` of ``body(carry, (layer i's weights, first + i))``
+    over the ``count`` layers of the stacked ``leaves``: as ``xs`` (each
+    step handed its slices), or with ``stream`` over the index alone
+    (`layer_of`)."""
+    if stream:
+        return jax.lax.scan(
+            lambda c, i: body(c, (layer_of(leaves, i), first + i)), carry,
+            jnp.arange(count))
+    return jax.lax.scan(body, carry, (leaves, first + jnp.arange(count)))
+
+
+def _where() -> Tuple[str, Optional[dict]]:
+    """What the rule reads beside shapes: the backend's platform and the
+    live mesh's ``{axis: size}`` (None on one device)."""
+    mesh = _live_mesh()
+    return jax.default_backend(), \
+        dict(mesh.shape) if mesh is not None else None
+
+
+def few_rows_here(rows: int) -> bool:
+    """`few_rows` of a call of ``rows`` rows where this is traced."""
+    platform, mesh_axes = _where()
+    return few_rows(platform, rows, mesh_axes)
+
+
+def _streams(kernels: Sequence[Any], rows: int, cfg) -> bool:
+    """Whether these weights, all meeting the same rows, go to the
+    kernel: each a resident leaf, all of one shape that `dense_path`
+    sends there."""
+    if not all(isinstance(w, Stacked) for w in kernels):
+        return False
+    platform, mesh_axes = _where()
+    shape = kernels[0].leaf.shape
+    return all(w.leaf.shape == shape for w in kernels) and dense_path(
+        platform, rows, *shape[-2:], jnp.dtype(cfg.dtype).itemsize,
+        mesh_axes) == "fewrow"
+
+
+def _products(x, kernels: Sequence[Any], cfg, name: str = "fewrow_dense"):
+    """``x @ kernel`` for each kernel, operands in the model's dtype,
+    accumulated and returned in float32: one call of the few-row kernel
+    (named ``name``) where `_streams`, else a ``jnp.dot`` each."""
+    rows = math.prod(x.shape[:-1])
+    if not _streams(kernels, rows, cfg):
+        return [jnp.dot(x.astype(cfg.dtype), matrix(w),
+                        preferred_element_type=jnp.float32)
+                for w in kernels]
+    out = fewrow_dense(x.reshape(rows, -1).astype(cfg.dtype),
+                       [w.leaf for w in kernels], kernels[0].layer,
+                       name=name)
+    return [y.reshape(*x.shape[:-1], -1) for y in out]
+
+
 def _dense(x, kernel, cfg):
     """``x @ kernel`` with operands in the model's dtype, accumulated and
     returned in float32."""
-    return jnp.dot(x.astype(cfg.dtype), kernel,
-                   preferred_element_type=jnp.float32)
+    return _products(x, [kernel], cfg)[0]
+
+
+def dense_each(x, weights, names: Sequence[str], cfg,
+               scope=jax.named_scope):
+    """`_dense` of ``x`` with ``weights[name]`` for each name, each under
+    its name's scope.  Few rows against resident leaves of one shape
+    (q / k / v; gate / up) are ONE call of the kernel, which streams them
+    all, under the first name's scope; the kernel's own name says
+    which."""
+    kernels = [weights[name] for name in names]
+    if _streams(kernels, math.prod(x.shape[:-1]), cfg):
+        with scope(names[0]):
+            return _products(x, kernels, cfg,
+                             "fewrow_dense_" + "_".join(names))
+    out = []
+    for name, kernel in zip(names, kernels):
+        with scope(name):
+            out.append(_dense(x, kernel, cfg))
+    return out
 
 
 def _rope(x, positions, theta):
@@ -233,12 +391,8 @@ def _qkv(cfg: LoopLMConfig, lp, x, positions):
     with jax.named_scope("input_layernorm"):
         n = _rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
     with jax.named_scope("self_attn"):
-        with jax.named_scope("q_proj"):
-            q = _dense(n, lp["q_proj"], cfg).reshape(heads)
-        with jax.named_scope("k_proj"):
-            k = _dense(n, lp["k_proj"], cfg).reshape(heads)
-        with jax.named_scope("v_proj"):
-            v = _dense(n, lp["v_proj"], cfg).reshape(heads)
+        q, k, v = (t.reshape(heads) for t in dense_each(
+            n, lp, ("q_proj", "k_proj", "v_proj"), cfg))
         with jax.named_scope("rotary"):
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
@@ -262,10 +416,7 @@ def _attend_and_mlp(cfg: LoopLMConfig, lp, x, q, k, v, index, first):
     with jax.named_scope("post_attention_layernorm"):
         n = _rms_norm(x, lp["post_attention_layernorm"], cfg.rms_norm_eps)
     with jax.named_scope("mlp"):
-        with jax.named_scope("gate_proj"):
-            g = _dense(n, lp["gate_proj"], cfg)
-        with jax.named_scope("up_proj"):
-            u = _dense(n, lp["up_proj"], cfg)
+        g, u = dense_each(n, lp, ("gate_proj", "up_proj"), cfg)
         h = shd.constrain(jax.nn.silu(g) * u, "batch", None, "mlp")
         with jax.named_scope("down_proj"):
             m = _dense(h, lp["down_proj"], cfg)
@@ -285,12 +436,16 @@ def _stack(cfg: LoopLMConfig, params, x, index, first, cache,
     and never attended to.  With ``use_cache`` the queries attend to the
     slot (a decode step: one query against everything up to its index),
     without to this call's own keys (the prefill: causal among the
-    prompt).  Returns the last loop's normed state, the exit
-    probabilities ``[B, N, R]`` and the cache."""
+    prompt).  A call of few rows on a TPU (`few_rows`: a shared
+    execution's decode step) walks the layer index with the leaves closed
+    over, so that its products can stream their leaves in place.
+    Returns the last loop's normed state, the exit probabilities
+    ``[B, N, R]`` and the cache."""
     B, N, _ = x.shape
     T = cache[0].shape[3]
     slot = (1, 1, B, T, cfg.num_attention_heads, cfg.head_dim)
     positions = index[None, :] - first[:, None]
+    stream = few_rows_here(B * N)
     exits = []
     for r in range(cfg.total_ut_steps):
         def layer(carry, xs, r=r):
@@ -314,9 +469,9 @@ def _stack(cfg: LoopLMConfig, params, x, index, first, cache,
             return (x, kc, vc), None
 
         with jax.named_scope("layers"):
-            (x, *cache), _ = jax.lax.scan(
-                layer, (x, *cache),
-                (params["layers"], jnp.arange(cfg.num_hidden_layers)))
+            (x, *cache), _ = scan_layers(
+                layer, (x, *cache), params["layers"],
+                cfg.num_hidden_layers, stream)
         with jax.named_scope("final_norm"):
             x = _rms_norm(x, params["norm"], cfg.rms_norm_eps)
         with jax.named_scope("early_exit_gate"):
@@ -334,7 +489,7 @@ def _embed(params, ids):
 
 def _head(cfg: LoopLMConfig, params, x):
     with jax.named_scope("lm_head"):
-        return _dense(x, params["lm_head"], cfg)
+        return _dense(x, Stacked(params["lm_head"]), cfg)
 
 
 def empty_cache(cfg: LoopLMConfig, batch: int, length: int):

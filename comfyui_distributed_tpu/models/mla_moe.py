@@ -83,8 +83,8 @@ import jax.numpy as jnp
 
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, xla_attention
-from comfyui_distributed_tpu.models.looplm import _dense, _rms_norm, \
-    _sandwich
+from comfyui_distributed_tpu.models.looplm import Stacked, _dense, \
+    _rms_norm, _sandwich, dense_each, few_rows_here, matrix, scan_layers
 from comfyui_distributed_tpu.parallel import sharding as shd
 
 
@@ -327,8 +327,8 @@ def _latent(cfg: MlaMoeConfig, lp, n, positions):
 def _kv_b(cfg: MlaMoeConfig, lp):
     """``W_kvb`` as ``[rank, H, d_nope + d_v]``: its key half is ``W_UK``,
     its value half ``W_UV``."""
-    return lp["kv_b_proj"].reshape(cfg.kv_lora_rank,
-                                   cfg.num_attention_heads, -1)
+    return matrix(lp["kv_b_proj"]).reshape(cfg.kv_lora_rank,
+                                           cfg.num_attention_heads, -1)
 
 
 def _attend_expanded(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
@@ -409,10 +409,7 @@ def _gated_mlp(cfg: MlaMoeConfig, weights, n, scope=jax.named_scope):
     """``W_down (silu(W_gate n) * W_up n)``: the dense blocks' MLP, the
     shared expert and every routed expert (whose projections carry no
     scope of their own: a trace classes them with ``experts``)."""
-    with scope("gate_proj"):
-        g = _dense(n, weights["gate_proj"], cfg)
-    with scope("up_proj"):
-        u = _dense(n, weights["up_proj"], cfg)
+    g, u = dense_each(n, weights, ("gate_proj", "up_proj"), cfg, scope)
     rows = ("batch", None) if n.ndim == 3 else (None,)     # [B, N] or [t]
     h = shd.constrain(jax.nn.silu(g) * u, *rows, "mlp")
     with scope("down_proj"):
@@ -479,7 +476,7 @@ def _moe(cfg: MlaMoeConfig, lp, experts, l, n):
     B, N, d = n.shape
     x = n.reshape(B * N, d)
     with jax.named_scope("gate"):
-        scores, chosen, weights = route(cfg, lp["gate"], x)
+        scores, chosen, weights = route(cfg, matrix(lp["gate"]), x)
     y, pairs, hits, dropped = _routed(cfg, experts, l, x, chosen, weights)
     with jax.named_scope("shared_experts"):
         shared = _gated_mlp(cfg, lp["shared_experts"], x)
@@ -495,12 +492,15 @@ def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
     ``cache`` is the ``[L, B, T, 576]`` latent buffer; each block writes
     this call's entries at the buffer indices ``index [N]`` (consecutive,
     the same for every row); row ``b``'s real entries start at
-    ``first[b]``, its position 0.  Returns the normed last state, the
+    ``first[b]``, its position 0.  A call of few rows on a TPU walks the
+    layer index with the leaves closed over (`looplm.scan_layers`).
+    Returns the normed last state, the
     cache, the routers' ``(scores [B, N, Le, E], choices [B, N, Le, k])``
     and the routing counts summed over the expert blocks (local pairs
     ``[B]``, hits, dropped)."""
     Ld = cfg.first_k_dense_replace
     eps = cfg.rms_norm_eps
+    stream = few_rows_here(math.prod(x.shape[:2]))
 
     def block(mlp, carry, lp, l):
         x, cache = carry
@@ -516,15 +516,15 @@ def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
     moe = dict(params["moe_layers"])
     experts = moe.pop("experts")
     with jax.named_scope("dense_layers"):
-        carry, _ = jax.lax.scan(
+        carry, _ = scan_layers(
             lambda c, xs: block(
                 lambda lp, l, n: (_gated_mlp(cfg, lp, n), None), c, *xs),
-            (x, cache), (params["dense_layers"], jnp.arange(Ld)))
+            (x, cache), params["dense_layers"], Ld, stream)
     with jax.named_scope("moe_layers"):
-        (x, cache), (routed, counts) = jax.lax.scan(
+        (x, cache), (routed, counts) = scan_layers(
             lambda c, xs: block(
                 lambda lp, l, n: _moe(cfg, lp, experts, l - Ld, n), c, *xs),
-            carry, (moe, Ld + jnp.arange(cfg.moe_layers)))
+            carry, moe, cfg.moe_layers, stream, first=Ld)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["norm"], eps)
     pairs, hits, dropped = counts
@@ -539,7 +539,7 @@ def _embed(params, ids):
 
 def _head(cfg: MlaMoeConfig, params, x):
     with jax.named_scope("lm_head"):
-        return _dense(x, params["lm_head"], cfg)
+        return _dense(x, Stacked(params["lm_head"]), cfg)
 
 
 def empty_cache(cfg: MlaMoeConfig, batch: int, length: int):

@@ -1513,6 +1513,10 @@ class LanguageModel:
         bump("lm.tokens_decoded", n * real)
         bump("lm.layer_applications", n * real * self.cfg.layer_applications)
         bump("lm.executions")
+        # the program of ``count`` rows was built with the few-row kernel
+        # where its decode step is such a call (0 is written, so the pair
+        # is there whenever a model has run)
+        bump("lm.executions_fewrow", int(self._arch.few_rows_here(count)))
         bump("lm.rows", real)
         bump("lm.padded_rows", count - real)
         for name, value in self._arch.window_counters(

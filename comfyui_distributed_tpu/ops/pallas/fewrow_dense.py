@@ -1,0 +1,129 @@
+"""A few rows times a stacked weight, streamed once (Pallas TPU kernel).
+
+The path a language model's decode products take on a TPU when an
+execution carries 2 to 8 rows (``models/looplm.py:dense_path`` decides
+from the shapes).  ``x [rows, K]`` stays resident in VMEM; each weight
+``[K, N]`` is read out of its STACKED leaf ``[L, K, N]`` in place, tile
+by tile through a double-buffered ``BlockSpec`` whose index map takes the
+layer from a prefetched scalar, so no slice of a leaf is ever
+materialised for the call; a tile meets the rows on the MXU
+(``jnp.dot``, float32 accumulation) in about half the time its DMA
+takes on a v5e, so the call is bound by HBM, as the one-row
+multiply-and-reduce fusions XLA writes are.  Same operands, same
+accumulation type and same result dtype as ``jnp.dot(x, w[l],
+preferred_element_type=float32)``.
+
+Several weights of one shape that meet the same ``x`` (q / k / v;
+gate / up) go through ONE call: a launch whose first tile's DMA nothing
+hides costs a quarter of an 8 MB product.
+
+Blocks: ``block_sizes`` keeps a tile's columns whole (``[tk, N]``: one
+contiguous run of the tiled HBM layout) where ``N`` allows and walks
+``K``; a wider weight is walked over ``N`` first, ``K`` inside, the
+float32 output block resident over ``K``.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# what one weight's tile may take of VMEM; every weight of a call has a
+# tile in flight and one in use.  Sized on a v5e (flash_attention.py's
+# note on VMEM_LIMIT_BYTES holds here too).
+TILE_BYTES = 4 * 1024 * 1024
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+LANES = 128
+
+
+def _largest_divisor(total: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``total`` (a multiple of
+    128) and is at most ``cap``; 128 where ``cap`` is smaller still."""
+    units = total // LANES
+    return LANES * max((u for u in range(1, units + 1)
+                        if units % u == 0 and u * LANES <= cap), default=1)
+
+
+def block_sizes(k: int, n: int, count: int = 1,
+                itemsize: int = 2) -> Tuple[int, int]:
+    """``(tk, tn)`` for ``count`` weights ``[k, n]`` streamed together:
+    tiles of at most ``TILE_BYTES / count`` each, columns whole where a
+    128-row tile of them fits, else as wide as fits with 512 rows."""
+    budget = TILE_BYTES // count // itemsize          # elements a tile
+    if n * LANES <= budget:
+        return _largest_divisor(k, budget // n), n
+    tk = _largest_divisor(k, 512)
+    return tk, _largest_divisor(n, budget // tk)
+
+
+def _kernel(layer_ref, x_ref, *refs, count: int, k_steps: int):
+    """One (N block, K block) grid step: every weight's tile against the
+    rows.  ``refs``: ``count`` weight tiles ``[tk, tn]``, then ``count``
+    float32 output blocks ``[rows, tn]``, resident over the K steps."""
+    del layer_ref                                  # the index maps' own
+    x = x_ref[...]
+    for w_ref, o_ref in zip(refs[:count], refs[count:]):
+        part = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+        if k_steps == 1:
+            o_ref[...] = part
+        else:
+            @pl.when(pl.program_id(1) == 0)
+            def _(o_ref=o_ref, part=part):
+                o_ref[...] = part
+
+            @pl.when(pl.program_id(1) > 0)
+            def _(o_ref=o_ref, part=part):
+                o_ref[...] += part
+
+
+def fewrow_dense(x: jax.Array, leaves: Sequence[jax.Array],
+                 layer: Optional[jax.Array] = None, *,
+                 blocks: Optional[Tuple[int, int]] = None,
+                 name: str = "fewrow_dense",
+                 interpret: bool = False) -> Tuple[jax.Array, ...]:
+    """``x [rows, K]`` times layer ``layer`` of every leaf ``[L, K, N]``
+    (all of one shape and of ``x``'s dtype; a leaf ``[K, N]`` is its own
+    only layer), each ``[rows, N]`` in float32.  ``K`` and ``N`` are
+    multiples of 128; the rows are few (the whole ``x`` is one block).
+    ``blocks`` overrides `block_sizes` (tests walk K and N with small
+    ones); ``interpret=True`` runs the Pallas interpreter (CPU tests)."""
+    leaves = [w if w.ndim == 3 else w[None] for w in leaves]
+    count, (_, k, n) = len(leaves), leaves[0].shape
+    if x.ndim != 2 or x.shape[1] != k or k % LANES or n % LANES \
+            or any(w.shape != leaves[0].shape or w.dtype != x.dtype
+                   for w in leaves):
+        raise ValueError(
+            f"fewrow_dense: {x.dtype}{list(x.shape)} against "
+            f"{[f'{w.dtype}{list(w.shape)}' for w in leaves]}: one shape "
+            f"[L, K, N] and one dtype, K and N multiples of {LANES}")
+    rows = x.shape[0]
+    itemsize = jnp.dtype(x.dtype).itemsize
+    tk, tn = blocks or block_sizes(k, n, count, itemsize)
+    layer = jnp.zeros((1,), jnp.int32) if layer is None \
+        else jnp.asarray(layer, jnp.int32).reshape(1)
+    weight = pl.BlockSpec((None, tk, tn), lambda j, i, l: (l[0], i, j))
+    out = pl.BlockSpec((rows, tn), lambda j, i, l: (0, j))
+    return tuple(pl.pallas_call(
+        functools.partial(_kernel, count=count, k_steps=k // tk),
+        out_shape=[jax.ShapeDtypeStruct((rows, n), jnp.float32)] * count,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // tn, k // tk),
+            in_specs=[pl.BlockSpec((rows, tk), lambda j, i, l: (0, i)),
+                      *[weight] * count],
+            out_specs=[out] * count),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * count * rows * k * n, transcendentals=0,
+            bytes_accessed=count * (k * n * itemsize + rows * n * 4)
+            + rows * k * itemsize * (n // tn)),
+        interpret=interpret,
+        name=name,
+    )(layer, x, *leaves))

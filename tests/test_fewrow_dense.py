@@ -1,0 +1,368 @@
+"""The language model's few-row products (PR 33): the rule that sends a
+product of 2-8 rows with a resident leaf to the weight-streaming kernel
+(``looplm.dense_path``), the kernel itself against ``jnp.dot`` (Pallas
+interpreter, CPU), the two scan structures held together on the tiny
+models, and the programs of the published sizes lowered and, for a
+described v5e, compiled: the one-row program is untouched by the rule and
+no decode body of the 4-row program materialises a weight."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.models import looplm, mla_moe
+from comfyui_distributed_tpu.ops.pallas import fewrow_dense as fd
+from comfyui_distributed_tpu.utils import trace
+
+OURO, PANGU = looplm.OURO_2_6B, mla_moe.OPENPANGU_ULTRA_MOE_SHARE
+FAMILIES = {"ouro": (looplm, OURO), "pangu": (mla_moe, PANGU)}
+
+# every product `_dense` makes with a resident leaf at the published
+# sizes: (family, name, K, N, leaves streamed by one call)
+PRODUCTS = [
+    ("ouro", "q_proj+k_proj+v_proj", 2048, 2048, 3),
+    ("ouro", "o_proj", 2048, 2048, 1),
+    ("ouro", "gate_proj+up_proj", 2048, 5632, 2),
+    ("ouro", "down_proj", 5632, 2048, 1),
+    ("ouro", "lm_head", 2048, 49152, 1),
+    ("pangu", "q_a_proj", 7680, 1536, 1),
+    ("pangu", "q_b_proj", 1536, 24576, 1),
+    ("pangu", "o_proj", 16384, 7680, 1),
+    ("pangu", "dense gate_proj+up_proj", 7680, 18432, 2),
+    ("pangu", "dense down_proj", 18432, 7680, 1),
+    ("pangu", "shared gate_proj+up_proj", 7680, 2048, 2),
+    ("pangu", "shared down_proj", 2048, 7680, 1),
+    ("pangu", "lm_head", 7680, 19200, 1),
+]
+IDS = [f"{p[0]}-{p[1]}" for p in PRODUCTS]
+
+
+# --- the rule -------------------------------------------------------------------
+
+@pytest.mark.parametrize("family, name, k, n, count", PRODUCTS, ids=IDS)
+def test_the_published_shapes_take_the_kernel_at_two_to_eight_rows(
+        family, name, k, n, count):
+    for rows in range(2, 9):
+        assert looplm.dense_path("tpu", rows, k, n) == "fewrow", rows
+    # the one-row program, both prefills (4 x 64, 1 x 64), nine rows
+    for rows in (1, 9, 64, 256):
+        assert looplm.dense_path("tpu", rows, k, n) == "xla", rows
+    # every other backend; a mesh of several devices (XLA cannot
+    # partition the custom call); a mesh of one is no mesh
+    for platform in ("cpu", "gpu"):
+        assert looplm.dense_path(platform, 4, k, n) == "xla"
+    assert looplm.dense_path("tpu", 4, k, n,
+                             mesh_axes={"data": 4, "tensor": 1}) == "xla"
+    assert looplm.dense_path("tpu", 4, k, n,
+                             mesh_axes={"data": 1, "tensor": 1}) == "fewrow"
+
+
+@pytest.mark.parametrize("k, n, why", [
+    (7680, 576, "kv_a_proj_with_mqa: 576 is not a multiple of 128"),
+    (64, 176, "the tiny models' widths"),
+    (2048, 1, "the exit gate's vector"),
+    (128, 256, "aligned, and too small to be worth a launch"),
+    (2000, 2048, "K unaligned"),
+])
+def test_what_the_kernels_blocks_do_not_divide_stays_with_xla(k, n, why):
+    assert looplm.dense_path("tpu", 4, k, n) == "xla", why
+
+
+def test_a_scan_walks_the_index_only_where_the_rows_are_few():
+    assert [looplm.few_rows("tpu", r) for r in (1, 2, 4, 8, 9, 256)] \
+        == [False, True, True, True, False, False]
+    assert not looplm.few_rows("cpu", 4)
+    assert not looplm.few_rows("tpu", 4, {"data": 2})
+    # here, on the CPU, nothing takes the path
+    assert not looplm.few_rows_here(4)
+
+
+def test_a_plain_array_never_takes_the_kernel(monkeypatch):
+    """A routed expert's weights are slices made inside the program: a
+    custom call would materialise them.  Only a `Stacked` leaf can go."""
+    monkeypatch.setattr(looplm, "_where", lambda: ("tpu", None))
+    w = jnp.zeros((3, 1024, 1024), jnp.bfloat16)
+    assert looplm._streams([looplm.Stacked(w, jnp.int32(1))], 4, OURO)
+    assert looplm._streams([looplm.Stacked(w[0])], 4, OURO)
+    assert not looplm._streams([w[1]], 4, OURO)
+    assert not looplm._streams([looplm.Stacked(w, 1)], 1, OURO)
+    # leaves of two shapes do not share a call
+    assert not looplm._streams(
+        [looplm.Stacked(w, 1), looplm.Stacked(w[:, :512], 1)], 4, OURO)
+
+
+@pytest.mark.parametrize("family, name, k, n, count", PRODUCTS, ids=IDS)
+def test_the_blocks_divide_the_published_shapes_and_fit(
+        family, name, k, n, count):
+    tk, tn = fd.block_sizes(k, n, count)
+    assert k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+    # a tile in flight and one in use for every leaf of the call, well
+    # inside what the kernel asks of VMEM
+    assert 2 * count * tk * tn * 2 <= fd.VMEM_LIMIT_BYTES // 2
+    assert tk * tn * 2 * count <= fd.TILE_BYTES
+    # a tile's columns whole (one contiguous run) wherever 128 rows fit
+    if n * 128 * 2 * count <= fd.TILE_BYTES:
+        assert tn == n
+
+
+# --- the kernel -----------------------------------------------------------------
+
+def operands(rows, layers, k, n, count, seed=0):
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (rows, k), jnp.float32).astype(jnp.bfloat16)
+    leaves = [
+        (jax.random.normal(jax.random.fold_in(key, i + 1), (layers, k, n),
+                           jnp.float32) / np.sqrt(k)).astype(jnp.bfloat16)
+        for i in range(count)]
+    return x, leaves
+
+
+@pytest.mark.parametrize("rows", [2, 3, 4, 8])
+@pytest.mark.parametrize("k, n, count, blocks", [
+    (256, 384, 1, None),            # one block
+    (512, 256, 3, (128, 256)),      # q / k / v; K walked, columns whole
+    (256, 512, 2, (128, 128)),      # gate / up; N outside, K inside
+    (384, 640, 1, (384, 128)),      # N walked alone
+])
+def test_the_kernel_is_jnp_dot_on_a_layer_of_the_stacked_leaf(
+        rows, k, n, count, blocks):
+    x, leaves = operands(rows, 3, k, n, count, seed=rows)
+    for layer in (2, 0):
+        got = fd.fewrow_dense(x, leaves, jnp.int32(layer), blocks=blocks,
+                              interpret=True)
+        assert len(got) == count
+        for w, y in zip(leaves, got):
+            want = jnp.dot(x, w[layer], preferred_element_type=jnp.float32)
+            assert y.dtype == jnp.float32 and y.shape == (rows, n)
+            # equal to float32 rounding: only the order of the sum over
+            # K differs
+            np.testing.assert_allclose(y, want, rtol=0, atol=4e-6 * np.sqrt(k))
+            assert not np.array_equal(
+                y, jnp.dot(x, w[1], preferred_element_type=jnp.float32))
+
+
+def test_a_leaf_with_no_layer_axis_is_its_own_only_layer():
+    x, (w,) = operands(4, 1, 256, 256, 1)
+    (y,) = fd.fewrow_dense(x, [w[0]], interpret=True)
+    np.testing.assert_allclose(
+        y, jnp.dot(x, w[0], preferred_element_type=jnp.float32), atol=1e-4)
+
+
+def test_the_kernel_runs_under_a_scan_over_the_layer_index():
+    """As the decode body calls it: the leaf closed over, the layer a
+    traced scalar."""
+    x, (w,) = operands(4, 3, 256, 256, 1)
+
+    def body(h, l):
+        (y,) = fd.fewrow_dense(h.astype(jnp.bfloat16), [w], l,
+                               interpret=True)
+        return jnp.tanh(y), None
+
+    got, _ = jax.jit(lambda h: jax.lax.scan(body, h, jnp.arange(3)))(
+        x.astype(jnp.float32))
+    want = x.astype(jnp.float32)
+    for l in range(3):
+        want = jnp.tanh(jnp.dot(want.astype(jnp.bfloat16), w[l],
+                                preferred_element_type=jnp.float32))
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("x_shape, leaf_shape, dtype", [
+    ((4, 256), (2, 128, 256), jnp.bfloat16),    # K differs
+    ((4, 200), (2, 200, 256), jnp.bfloat16),    # unaligned
+    ((4, 256), (2, 256, 256), jnp.float32),     # another dtype than x's
+    ((2, 2, 256), (2, 256, 256), jnp.bfloat16),  # x not [rows, K]
+])
+def test_operands_the_blocks_cannot_take_are_refused_by_name(
+        x_shape, leaf_shape, dtype):
+    with pytest.raises(ValueError, match="fewrow_dense"):
+        fd.fewrow_dense(jnp.zeros(x_shape, jnp.bfloat16),
+                        [jnp.zeros(leaf_shape, dtype)], interpret=True)
+
+
+# --- the two scan structures, held together on the tiny models --------------------
+
+def tiny_run(arch, cfg, rows, where, monkeypatch):
+    monkeypatch.setattr(looplm, "_where", lambda: where)
+    params = arch.seeded_params(cfg, np.uint32(5))
+    ids = np.random.RandomState(3).randint(1, cfg.vocab_size, (rows, 8))
+    tokens, logits, aux, stats = arch.make_program(cfg, 4)(
+        params, ids.astype(np.int32), np.asarray([8, 5, 7, 3][:rows], np.int32),
+        np.arange(rows, dtype=np.uint32), np.zeros((rows,), np.float32))
+    return np.asarray(tokens), np.asarray(logits), jax.tree_util.tree_map(
+        np.asarray, (aux, stats))
+
+
+@pytest.mark.parametrize("family", ["ouro", "pangu"])
+def test_a_scan_over_the_index_gives_what_a_scan_over_the_slices_gives(
+        family, monkeypatch):
+    """With the platform read as a TPU's the 4-row decode walks the layer
+    index with the leaves closed over (`scan_layers`, `Stacked`); the tiny
+    widths are no multiples of 128, so every product is still ``jnp.dot``
+    and the CPU can run it: the same numbers as the scan over slices."""
+    arch = FAMILIES[family][0]
+    cfg = arch.CONFIGS["tiny"]
+    a = tiny_run(arch, cfg, 4, ("tpu", None), monkeypatch)
+    b = tiny_run(arch, cfg, 4, ("cpu", None), monkeypatch)
+    assert np.array_equal(a[0], b[0])
+    np.testing.assert_allclose(a[1], b[1], rtol=0, atol=1e-5)
+    for x, y in zip(jax.tree_util.tree_leaves(a[2]),
+                    jax.tree_util.tree_leaves(b[2])):
+        np.testing.assert_allclose(x, y, rtol=0, atol=1e-5)
+
+
+# --- the published sizes, lowered and compiled for the chip -----------------------
+
+def traced(family, rows, where, monkeypatch, sharding=None):
+    """``lm_generate`` of the published size at ``rows`` rows, traced
+    over shapes (no weight is made) with the rule reading ``where``."""
+    monkeypatch.setattr(looplm, "_where", lambda: where)
+    arch, cfg = FAMILIES[family]
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree_util.tree_map(
+        lambda s: spec(s, cfg.dtype), arch.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple))
+    return arch.make_program(cfg, 64).trace(
+        params, spec((rows, 64), np.int32), spec((rows,), np.int32),
+        spec((rows,), np.uint32), spec((rows,), np.float32))
+
+
+@pytest.mark.parametrize("family", ["ouro", "pangu"])
+def test_the_one_row_program_is_untouched_by_the_rule(family, monkeypatch):
+    """Its text as lowered with the platform read as a TPU's is, byte for
+    byte, its text with the rule off; and the 4-row program's is not."""
+    on = traced(family, 1, ("tpu", None), monkeypatch).lower().as_text()
+    off = traced(family, 1, ("cpu", None), monkeypatch).lower().as_text()
+    assert on == off and "custom_call" not in on
+    # (the CPU cannot lower the kernel: the 4-row program's jaxpr)
+    four = str(traced(family, 4, ("tpu", None), monkeypatch).jaxpr)
+    assert "pallas_call" in four and "fewrow_dense" in four
+    assert "pallas_call" not in str(
+        traced(family, 4, ("cpu", None), monkeypatch).jaxpr)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip (no device attached): what the chip's
+    compiler refuses, it refuses here."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - whatever libtpu raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]"
+    r"\S* (?P<op>[\w\-]+)\((?P<rest>.*)$")
+# what hands a buffer on without writing one
+PASSES_ON = {"get-tuple-element", "parameter", "bitcast", "tuple", "while",
+             "conditional", "dynamic-update-slice", "copy-start",
+             "copy-done"}
+
+
+def computations(text):
+    """``{name: [instruction lines]}`` of an HLO module's text, the entry
+    computation under ``ENTRY``."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def weight_sized_results(lines, layer_elements):
+    """The instructions of one computation that WRITE a bf16 buffer of a
+    weight's layer (or more): a ``copy``, a ``dynamic-slice``, or a fusion
+    rooted in one, whatever it is called."""
+    found = []
+    for line in lines:
+        m = INSTRUCTION.match(line)
+        # (a fusion rooted in a dynamic-update-slice writes the cache in
+        # place; Ouro's has as many values as a projection's leaf)
+        if not m or m["dtype"] != "bf16" or m["op"] in PASSES_ON \
+                or "dynamic-update-slice" in m["name"]:
+            continue
+        size = int(np.prod([int(d) for d in m["dims"].split(",") if d]))
+        if size in layer_elements:
+            found.append((m["name"], m["op"], m["dims"]))
+    return found
+
+
+@pytest.mark.parametrize("family, known", [
+    ("ouro", set()),
+    # the absorption einsums (per-head batched products, not `_dense`'s)
+    # still have XLA slice `kv_b_proj`'s layer out: PERF.md section 7
+    ("pangu", {512 * 32768}),
+])
+def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
+        family, known, one_chip, no_compile_cache, monkeypatch):
+    arch, cfg = FAMILIES[family]
+    text = traced(family, 4, ("tpu", None), monkeypatch,
+                  one_chip).lower().compile().as_text()
+    matrices = [s for s in jax.tree_util.tree_leaves(
+        arch.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+        if len(s) >= 2 and s[-1] * s[-2] >= 1 << 20]
+    layer_elements = {s[-1] * s[-2] for s in matrices} \
+        | {int(np.prod(s)) for s in matrices}
+    bodies = {name: lines for name, lines in computations(text).items()
+              if name != "ENTRY" and "fused" not in name
+              and any("fewrow_dense" in l and "custom-call(" in l
+                      for l in lines)}
+    assert bodies, "no computation but the entry's holds the kernel"
+    calls = [l for lines in bodies.values() for l in lines
+             if "custom-call(" in l and "fewrow_dense" in l]
+    # every call reads WHOLE leaves: each weight operand has a leaf's
+    # shape (the head's with a layer axis of one), none a layer's
+    whole = {tuple(s) for s in matrices} \
+        | {(1, *s) for s in matrices if len(s) == 2}
+    for call in calls:
+        constraints = call.split("operand_layout_constraints=")[1] \
+            .split("frontend_attributes")[0]
+        weights = [tuple(int(d) for d in dims) for dims in re.findall(
+            r"bf16\[(\d+),(\d+),(\d+)\]", constraints)]
+        assert weights and all(w in whole for w in weights), call[:200]
+    for name, lines in bodies.items():
+        wrote = [w for w in weight_sized_results(lines, layer_elements)
+                 if int(np.prod([int(d) for d in w[2].split(",")]))
+                 not in known]
+        assert not wrote, (name, wrote)
+    # and the kernel's operations carry the published modules' scopes
+    paths = {p for l in calls for p in re.findall(r'op_name="([^"]+)"', l)}
+    classes = {trace.classify(p) for p in paths}
+    assert classes == {"lm_proj", "lm_mlp", "lm_head"}, paths
+    segments = {seg for p in paths for seg in p.split("/")}
+    want = {"ouro": {"q_proj", "o_proj", "gate_proj", "down_proj", "lm_head",
+                     "fewrow_dense_q_proj_k_proj_v_proj",
+                     "fewrow_dense_gate_proj_up_proj"},
+            "pangu": {"q_a_proj", "q_b_proj", "o_proj", "gate_proj",
+                      "down_proj", "shared_experts", "lm_head"}}[family]
+    assert want <= segments, want - segments

@@ -144,6 +144,8 @@ def test_four_prompts_behind_a_blocked_executor_share_one_execution(
     assert got["executions"] == 1 and got["rows"] == 4
     assert got["padded_rows"] == 0 and got["followers_served"] == 3
     assert "followers_dropped" not in got and state.lm_handover.kept() == 0
+    # written, and 0: no program is built with the few-row kernel here
+    assert got["executions_fewrow"] == 0
     # counted per request: tokens over stages is one execution's steps
     stages = m["pipeline"]["stages"]
     assert stages["lm_generate"]["count"] == 4
@@ -430,6 +432,34 @@ def test_two_callers_in_a_closed_loop_wait_and_find_nobody(state):
     assert got["drain_waits"] == got["executions"]
     assert got["rows_joined_in_drain"] == 0 and got["padded_rows"] == 0
     assert "followers_served" not in got
+
+
+@pytest.mark.parametrize("name, rows, where, want", [
+    ("ouro", 1, ("tpu", None), 0),      # the one-row program, on a TPU too
+    ("ouro", 3, ("tpu", None), 1),      # padded to four: few rows
+    ("ouro", 3, ("cpu", None), 0),
+    ("ouro", 3, ("tpu", {"data": 2, "tensor": 1}), 0),      # under a mesh
+    ("openpangu", 1, ("tpu", None), 0),
+    ("openpangu", 2, ("tpu", None), 1),
+])
+def test_an_execution_built_with_the_few_row_path_is_counted(
+        name, rows, where, want, monkeypatch):
+    """``lm.executions_fewrow`` beside ``lm.executions``: how often the
+    program an execution ran was built with the few-row path (its decode
+    step's rows and where it was traced decide).  The platform is read as
+    the case says; the tiny widths keep every product with ``jnp.dot``,
+    so the CPU runs either program."""
+    from comfyui_distributed_tpu.models import looplm
+    monkeypatch.setattr(looplm, "_where", lambda: where)
+    model = registry.load_language_model(
+        f"{name}-fewrow-{rows}-{want}-{len(where[1] or ())}.safetensors")
+    out = model.generate_rows(
+        [registry.LMRow(t, i) for i, t in enumerate(TEXTS[:rows])],
+        max_new_tokens=NEW, prompt_tokens=PROMPT)
+    assert len(out) == rows
+    got = counters()
+    assert got["executions"] == 1 and got["rows"] == rows
+    assert got["executions_fewrow"] == want
 
 
 def _result(*futures):
@@ -763,6 +793,7 @@ def test_four_prompts_of_the_expert_model_share_one_execution(tmp_path):
     assert got["executions"] == 1 and got["rows"] == 4
     assert got["padded_rows"] == 0 and got["followers_served"] == 3
     assert "followers_dropped" not in got and state.lm_handover.kept() == 0
+    assert got["executions_fewrow"] == 0
     stages = m["pipeline"]["stages"]
     assert stages["lm_generate"]["count"] == 4
     assert got["tokens_decoded"] / stages["lm_generate"]["count"] == NEW

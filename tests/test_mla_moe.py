@@ -557,6 +557,19 @@ def test_every_class_of_the_expert_model_is_in_its_compiled_program(params):
     ("final_norm/mul", "lm_norm"), ("lm_head/dot_general", "lm_head"),
     ("sample/argmax", "lm_head"), ("embed_tokens/gather", "embed"),
     ("moe_layers/while/body/add", "lm_proj"),
+    # the few-row kernel's custom calls (PR 33)
+    ("while/body/closed_call/moe_layers/while/body/closed_call/self_attn/"
+     "q_a_proj/fewrow_dense/pallas_call", "lm_proj"),
+    ("while/body/closed_call/moe_layers/while/body/closed_call/self_attn/"
+     "q_b_proj/fewrow_dense/pallas_call", "lm_proj"),
+    ("while/body/closed_call/dense_layers/while/body/closed_call/self_attn/"
+     "o_proj/fewrow_dense/pallas_call", "lm_proj"),
+    ("while/body/closed_call/moe_layers/while/body/closed_call/mlp/"
+     "shared_experts/gate_proj/fewrow_dense_gate_proj_up_proj/pallas_call",
+     "lm_mlp"),
+    ("while/body/closed_call/dense_layers/while/body/closed_call/mlp/"
+     "down_proj/fewrow_dense/pallas_call", "lm_mlp"),
+    ("while/body/closed_call/lm_head/fewrow_dense/pallas_call", "lm_head"),
 ])
 def test_the_expert_models_scopes_fall_in_their_classes(path, want):
     assert trace.classify("jit(lm_generate)/PanguUltraMoE/" + path) == want

@@ -117,6 +117,14 @@ UNET = "jit(core)/while/body/closed_call/UNet/"
     ("jit(<unknown>)/CLIPTextModel/layers_3/fc2/dot_general", "clip_mlp"),
     ("jit(<unknown>)/CLIPTextModel/layers_3/ln1/mul", "norm"),
     ("jit(<unknown>)/CLIPTextModel/token_embedding/gather", "embed"),
+    # a Pallas kernel's custom call carries the scope it was called
+    # under: the few-row kernel's (PR 33) is the projection's or the MLP's
+    ("jit(lm_generate)/LoopLM/while/body/closed_call/layers/while/body/"
+     "closed_call/self_attn/q_proj/fewrow_dense_q_proj_k_proj_v_proj/"
+     "pallas_call", "lm_proj"),
+    ("jit(lm_generate)/PanguUltraMoE/while/body/closed_call/moe_layers/"
+     "while/body/closed_call/mlp/shared_experts/down_proj/fewrow_dense/"
+     "pallas_call", "lm_mlp"),
     # nothing of ours
     ("jit(<lambda>)/jit(<lambda>)/mul", "other"),
     ("reduce_sum", "other"), ("", "other"),
