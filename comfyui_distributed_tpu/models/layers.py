@@ -153,15 +153,18 @@ def _query_chunk(b: int, n: int, m: int, h: int) -> Optional[int]:
 
 def attention_path(platform: str, b: int, n: int, m: int, h: int,
                    mesh_axes: Optional[dict] = None,
-                   masked: bool = False) -> str:
+                   masked: bool = False, banded: bool = False) -> str:
     """Which implementation ``impl="xla"`` (the default) runs for q
     [b, n, h, *] against k/v [b, m, h, *]: ``fused`` (the Pallas flash
     kernel), ``xla_whole`` or ``xla_chunked`` (`xla_attention` with the
     score tensor whole or scanned over query chunks); for a ``masked``
     call (each query sees the keys up to its own position: a language
     model's) ``xla_decode`` where one query meets a cache and
-    ``xla_causal`` otherwise.  A masked call stays with `xla_attention`
-    on every platform: the kernel has not been taught a mask.
+    ``xla_causal`` otherwise; where the mask is ``banded`` besides (a
+    sliding-window layer's: a query sees its last ``window`` keys)
+    ``xla_ring`` where one query meets a ring of slots and ``xla_banded``
+    otherwise.  A masked call stays with `xla_attention` on every
+    platform: the kernel has not been taught a mask.
 
     A function of what the code can see at trace time and nothing else:
     the backend's platform, the operands' static shapes and the live
@@ -174,6 +177,8 @@ def attention_path(platform: str, b: int, n: int, m: int, h: int,
     ``seq`` axis, rows that do not divide ``data``, heads that do not
     divide ``tensor`` — would run the whole call on every peer, so there
     the call stays with XLA, which partitions it."""
+    if masked and banded:
+        return "xla_ring" if n == 1 else "xla_banded"
     if masked:
         return "xla_decode" if n == 1 else "xla_causal"
     axes = mesh_axes or {}
@@ -260,20 +265,41 @@ def _fused_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
                          check_vma=False)(q, k, v)
 
 
+def visible_keys(m: int, q_positions: jax.Array,
+                 kv_start: Optional[jax.Array] = None,
+                 kv_positions: Optional[jax.Array] = None,
+                 window: Optional[int] = None) -> jax.Array:
+    """The mask of a masked call, ``[b or 1, n, m]``: query ``i`` sees
+    the keys whose position is at most ``q_positions[i]``, in row ``b``
+    none in front of ``kv_start[b]``, and with ``window`` only the last
+    ``window`` of them, its own counted.  A key's position is its index,
+    or ``kv_positions [m]`` where the keys lie elsewhere (the slots of a
+    ring: a slot never written holds a position below every row's
+    start)."""
+    at = jnp.arange(m) if kv_positions is None else kv_positions
+    seen = at[None, :] <= q_positions[:, None]
+    if window is not None:
+        seen = seen & (at[None, :] > q_positions[:, None] - window)
+    seen = seen[None]
+    if kv_start is not None:
+        seen = seen & (at >= kv_start[:, None])[:, None, :]
+    return seen
+
+
 def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
                        scale: float,
                        q_positions: Optional[jax.Array] = None,
-                       kv_start: Optional[jax.Array] = None) -> jax.Array:
+                       kv_start: Optional[jax.Array] = None,
+                       kv_positions: Optional[jax.Array] = None,
+                       window: Optional[int] = None) -> jax.Array:
     """One materialized-score attention block (einsum -> fp32 softmax ->
-    einsum); with ``q_positions [n]`` each query's keys end at its own
-    position, and with ``kv_start [b]`` a row's begin at its own index."""
+    einsum); with ``q_positions [n]`` under the mask `visible_keys`
+    gives."""
     logits = jnp.einsum("bnhd,bmhd->bhnm", q, k,
                         preferred_element_type=jnp.float32) * scale
     if q_positions is not None:
-        at = jnp.arange(k.shape[1])
-        seen = (at[None, :] <= q_positions[:, None])[None, None]
-        if kv_start is not None:
-            seen = seen & (at >= kv_start[:, None])[:, None, None, :]
+        seen = visible_keys(k.shape[1], q_positions, kv_start, kv_positions,
+                            window)[:, None]
         logits = jnp.where(seen, logits, jnp.finfo(jnp.float32).min)
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhnm,bmhd->bnhd", weights.astype(v.dtype), v)
@@ -282,7 +308,9 @@ def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   scale: float,
                   q_positions: Optional[jax.Array] = None,
-                  kv_start: Optional[jax.Array] = None) -> jax.Array:
+                  kv_start: Optional[jax.Array] = None,
+                  kv_positions: Optional[jax.Array] = None,
+                  window: Optional[int] = None) -> jax.Array:
     """The reference attention math with a memory ceiling: the path of
     everything `attention_path` does not send to the flash kernel, and
     the oracle the kernel is checked against.
@@ -297,7 +325,8 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     B, N, H, D = q.shape
     chunk = _query_chunk(B, N, k.shape[1], H)
     if chunk is None:
-        return _attn_scores_block(q, k, v, scale, q_positions, kv_start)
+        return _attn_scores_block(q, k, v, scale, q_positions, kv_start,
+                                  kv_positions, window)
     n_chunks = N // chunk
     qr = q.reshape(B, n_chunks, chunk, H, D).transpose(1, 0, 2, 3, 4)
     pos = None if q_positions is None \
@@ -305,7 +334,8 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def body(_, qc):
         qc, pc = qc
-        return None, _attn_scores_block(qc, k, v, scale, pc, kv_start)
+        return None, _attn_scores_block(qc, k, v, scale, pc, kv_start,
+                                        kv_positions, window)
 
     _, out = jax.lax.scan(body, None, (qr, pos))
     return out.transpose(1, 0, 2, 3, 4).reshape(B, N, H, D)

@@ -292,15 +292,19 @@ def layer_of(leaves, l):
 
 
 def scan_layers(body, carry, leaves, count: int, stream: bool,
-                first: int = 0):
-    """``lax.scan`` of ``body(carry, (layer i's weights, first + i))``
-    over the ``count`` layers of the stacked ``leaves``: as ``xs`` (each
-    step handed its slices), or with ``stream`` over the index alone
-    (`layer_of`)."""
+                first: int = 0, start: int = 0):
+    """``lax.scan`` of ``body(carry, (layer start + i's weights,
+    first + i))`` over ``count`` layers of the stacked ``leaves``: as
+    ``xs`` (each step handed its slices; the whole stack only), or with
+    ``stream`` over the index alone (`layer_of`).  A run that is a part
+    of the leaves (the layers of one KIND in a stack that mixes two)
+    walks the index: a slice of the leaves as ``xs`` would be a copy of
+    those weights."""
     if stream:
         return jax.lax.scan(
-            lambda c, i: body(c, (layer_of(leaves, i), first + i)), carry,
-            jnp.arange(count))
+            lambda c, i: body(c, (layer_of(leaves, start + i), first + i)),
+            carry, jnp.arange(count))
+    assert start == 0, "a run inside a stack walks the index"
     return jax.lax.scan(body, carry, (leaves, first + jnp.arange(count)))
 
 

@@ -220,28 +220,37 @@ def param_shapes(cfg: MlaMoeConfig) -> Dict[str, Any]:
             "moe_layers": moe, "norm": (d,), "lm_head": (d, cfg.vocab_size)}
 
 
-def _leaves(cfg):
+def shape_leaves(shapes):
     return jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def count_values(shapes) -> int:
+    return sum(math.prod(s) for _, s in shape_leaves(shapes)[0])
 
 
 def param_count(cfg: MlaMoeConfig) -> int:
-    return sum(math.prod(s) for _, s in _leaves(cfg)[0])
+    return count_values(param_shapes(cfg))
 
 
-def _seeded_leaf(name: str, shape: tuple, dtype):
+def _norm_gain(name: str):
+    """A norm's seeded gain scale, None for a leaf that is no norm: the
+    sandwich norms' SANDWICH_GAIN of the others'."""
+    if name in SANDWICH_NORMS:
+        return SANDWICH_GAIN
+    return 1.0 if name in NORMS + LATENT_NORMS or name == "norm" else None
+
+
+def _seeded_leaf(name: str, shape: tuple, dtype, gain):
     """The jitted maker of one leaf from its key: kernels normals scaled
-    by fan-in, embeddings unit normals, norm gains 1 + 0.1 N (a gain of
-    exactly 1 would hide a norm applied without its gain), the sandwich
-    norms' SANDWICH_GAIN of that.  A stacked leaf is drawn slice by slice
-    along its leading axis, so the float32 normals of the largest (the
-    experts', 1.0 B values) never stand whole beside it."""
-    norm = name in NORMS + LATENT_NORMS or name == "norm"
-    gain = SANDWICH_GAIN if name in SANDWICH_NORMS else 1.0
-
+    by fan-in, embeddings unit normals, norm gains ``gain`` x (1 + 0.1 N)
+    (a gain of exactly 1 would hide a norm applied without its gain).  A
+    stacked leaf is drawn slice by slice along its leading axis, so the
+    float32 normals of the largest (the experts', 1.0 B values) never
+    stand whole beside it."""
     def draw(key, shape):
         x = jax.random.normal(key, shape, jnp.float32)
-        if norm:
+        if gain is not None:
             x = gain * (1.0 + 0.1 * x)
         elif name != "embed_tokens":
             x = x / math.sqrt(shape[-2])
@@ -256,16 +265,22 @@ def _seeded_leaf(name: str, shape: tuple, dtype):
     return jax.jit(leaf)
 
 
-def seeded_params(cfg: MlaMoeConfig, seed) -> Dict[str, Any]:
-    """Seeded random weights, made on the device LEAF BY LEAF (one small
-    jitted call a leaf): the 4.9 B values of the published share are
-    9.8 GB on a 16 GB chip, and one program that drew them all could
-    hold several leaves' float32 normals at once."""
-    flat, tree = _leaves(cfg)
+def seeded_tree(shapes, seed, dtype, norm_gain) -> Dict[str, Any]:
+    """Seeded random weights of a tree of ``shapes``, made on the device
+    LEAF BY LEAF (one small jitted call a leaf): the 4.9 B values of the
+    published share are 9.8 GB on a 16 GB chip, and one program that drew
+    them all could hold several leaves' float32 normals at once.
+    ``norm_gain(name)`` says which leaves are norms (`_norm_gain`)."""
+    flat, tree = shape_leaves(shapes)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(flat))
     return jax.tree_util.tree_unflatten(
-        tree, [_seeded_leaf(p[-1].key, s, jnp.dtype(cfg.dtype))(k)
+        tree, [_seeded_leaf(p[-1].key, s, jnp.dtype(dtype),
+                            norm_gain(p[-1].key))(k)
                for (p, s), k in zip(flat, keys)])
+
+
+def seeded_params(cfg: MlaMoeConfig, seed) -> Dict[str, Any]:
+    return seeded_tree(param_shapes(cfg), seed, cfg.dtype, _norm_gain)
 
 
 def load_checkpoint(path: str, cfg: MlaMoeConfig):

@@ -1267,13 +1267,15 @@ def clear_pipeline_cache() -> None:
     gg_mod.clear_gligen_cache()
 
 
-# --- language models (models/looplm.py, models/mla_moe.py) ---------------------
+# --- language models (models/looplm.py, models/mla_moe.py, models/swa_moe.py) --
 #
 # A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
 # model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
 # imported, made or traced until a graph names it.
 
-# the text a prompt expander is asked to continue
+# the text a prompt expander is asked to continue; an operator's
+# ``instructions`` (few-shot examples, a house style), the same in every
+# request, stand in front of it
 EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
                    "Prompt: {text} Detailed prompt:")
 
@@ -1282,7 +1284,7 @@ EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
 # a served window compiles nothing).  An execution is padded to the next
 # count with copies of its first row; the last count also bounds what is
 # kept for requests still in the queue (server/lm_handover.py): three
-# results.  Argued per family (`LMFamily.row_counts`); both take these.
+# results.  Argued per family (`LMFamily.row_counts`); all three take these.
 LM_ROW_COUNTS = (1, 4)
 
 
@@ -1295,8 +1297,10 @@ class LMFamily:
     ``seeded_params(cfg, seed)``, ``load_checkpoint(path, cfg)``,
     ``make_program(cfg, new_tokens)`` (the jitted ``lm_generate`` ->
     ids, logits, ``aux`` arrays per position, ``stats`` to count from),
-    ``kv_cache_bytes(cfg, rows, positions)`` and
-    ``window_counters(cfg, stats, real_rows, steps)``; its config gives
+    ``kv_cache_bytes(cfg, rows, positions)`` (and, where its caches are
+    of more than one geometry, ``kv_cache_bytes_by_kind`` -> the parts by
+    name) and ``window_counters(cfg, stats, real_rows, steps)``; its
+    config gives
     ``vocab_size`` and ``layer_applications`` (per token)."""
     module: str
     names: Tuple[str, ...]          # what a model name of it contains
@@ -1339,6 +1343,23 @@ LM_FAMILIES = {
         "mla_moe", ("pangu",), LM_ROW_COUNTS,
         "openPangu-Ultra-MoE-718B, one chip's share: latent attention "
         "(MLA) with a latent cache, 16 of 256 routed experts held"),
+    # A row's cache is 2.3 MB at 576 positions (four rings of 128 slots and
+    # one full layer, 4 KiB a key and value a layer) beside 7.4 GB
+    # resident: memory argues for 4 as little as openPangu's latent cache
+    # does.  What does: the rows are the requests waiting in one server's
+    # queue (four callers in the cell), every count is a program to compile
+    # at set-up (this family's prefill of 512 positions is the costliest
+    # to compile here), each further row routes 8 more pairs a layer (2.75
+    # distinct local experts a layer at 3 rows, 75 MB each, where the
+    # dense part's 2.4 GB a step does not grow), and the PREFILL is
+    # compute-bound: a row adds its 512 positions' FLOPs whole, so rows
+    # past 4 would buy the decode's shared stream with prefill seconds
+    # that nobody shares.  ROADMAP B7's to measure.
+    "exaone": LMFamily(
+        "swa_moe", ("exaone",), LM_ROW_COUNTS,
+        "K-EXAONE-236B-A23B, one chip's share: window and full attention "
+        "layers in one stack (a 128-slot ring beside a full cache, GQA "
+        "64/8), 16 of 128 routed experts held"),
 }
 
 
@@ -1381,10 +1402,13 @@ class LMOutput:
 
 @dataclasses.dataclass(frozen=True)
 class LMRow:
-    """One request's call of the generate node: a row of an execution."""
+    """One request's call of the generate node: a row of an execution.
+    ``instructions`` is the operator's text in front of the template
+    (rows of one execution may carry different ones)."""
     text: str
     seed: int = 0
     temperature: float = 0.0
+    instructions: str = ""
 
 
 class LanguageModel:
@@ -1419,11 +1443,15 @@ class LanguageModel:
             self._mesh = mesh
             self._programs.clear()
 
-    def prompt_ids(self, text: str, prompt_tokens: int) -> np.ndarray:
-        """The real ids of ``text`` under the expander's template, cut to
-        ``prompt_tokens``; refused here where the device would clamp an
-        index out of range in silence."""
-        ids = self.tokenizer.encode(EXPAND_TEMPLATE.format(text=text))
+    def prompt_ids(self, text: str, prompt_tokens: int,
+                   instructions: str = "") -> np.ndarray:
+        """The real ids of ``instructions`` and, behind them, ``text``
+        under the expander's template, cut to ``prompt_tokens``; refused
+        here where the device would clamp an index out of range in
+        silence."""
+        asked = EXPAND_TEMPLATE.format(text=text)
+        ids = self.tokenizer.encode(
+            f"{instructions} {asked}" if instructions else asked)
         ids = np.asarray(ids[:prompt_tokens], np.int32)
         if not len(ids) or ids.min() < 0 or ids.max() >= self.cfg.vocab_size:
             raise ValueError(
@@ -1477,7 +1505,8 @@ class LanguageModel:
                 f"{self.name}: {n} new tokens for {real} row(s) cannot be "
                 f"generated (at least 1 token, 1 to {self.row_counts[-1]} "
                 f"rows)")
-        ids = [self.prompt_ids(r.text, prompt_tokens) for r in rows]
+        ids = [self.prompt_ids(r.text, prompt_tokens, r.instructions)
+               for r in rows]
         count = next(b for b in self.row_counts if b >= real)
         # a padded row repeats the first
         source = [*range(real), *[0] * (count - real)]
@@ -1522,9 +1551,13 @@ class LanguageModel:
         for name, value in self._arch.window_counters(
                 self.cfg, stats, real, n).items():
             bump(name, value)
-        trace_mod.GLOBAL_GAUGES.set(
-            "lm.kv_cache_bytes",
-            self._arch.kv_cache_bytes(self.cfg, count, prompt_tokens + n))
+        shape = (self.cfg, count, prompt_tokens + n)
+        trace_mod.GLOBAL_GAUGES.set("lm.kv_cache_bytes",
+                                    self._arch.kv_cache_bytes(*shape))
+        # a family with caches of more than one geometry says each part
+        by_kind = getattr(self._arch, "kv_cache_bytes_by_kind", None)
+        for kind, nbytes in (by_kind(*shape) if by_kind else {}).items():
+            trace_mod.GLOBAL_GAUGES.set(f"lm.kv_cache_bytes_{kind}", nbytes)
         return out
 
     def generate(self, text: str, seed: int = 0, max_new_tokens: int = 64,
@@ -1556,7 +1589,8 @@ def load_language_model(name: str, models_dir: Optional[str] = None
     evicts: a model that left would be made again, tens of seconds, at
     its next request).  Where the second cannot fit beside what is
     resident (Ouro-2.6B's 5.3 GB and openPangu's 9.8 GB share do not
-    share one 16 GB chip with a checkpoint) it is refused BY NAME, with
+    share one 16 GB chip with a checkpoint, nor K-EXAONE's 7.4 GB share
+    with openPangu's) it is refused BY NAME, with
     what it needs and what is resident, before the allocator fails with
     an error that names nothing."""
     from comfyui_distributed_tpu.models.tokenizer import make_lm_tokenizer

@@ -1005,9 +1005,11 @@ class LanguageModelLoader(Op):
     """-> LANGUAGE_MODEL: a decoder resident beside the diffusion
     checkpoints, of the family its name contains
     (``registry.LM_FAMILIES``): ``ouro`` (models/looplm.py, Ouro-2.6B, a
-    dense looped decoder) or ``pangu`` (models/mla_moe.py, one chip's
+    dense looped decoder), ``pangu`` (models/mla_moe.py, one chip's
     share of openPangu-Ultra-MoE-718B: latent attention with a latent
-    cache, routed experts).  A name of neither is refused.  The model's
+    cache, routed experts) or ``exaone`` (models/swa_moe.py, one chip's
+    share of K-EXAONE-236B-A23B: window and full attention layers in one
+    stack, routed experts).  A name of none is refused.  The model's
     safetensors and ``tokenizer.json`` from the models dir if present;
     otherwise seeded weights made on the device and the hash tokenizer
     pair."""
@@ -1023,25 +1025,28 @@ class LanguageModelLoader(Op):
 
 @register_op
 class LanguageModelGenerate(Op):
-    """The prompt expander: ``text`` under the expander's template is
+    """The prompt expander: ``text`` under the expander's template,
+    behind the operator's ``instructions`` if any (few-shot examples, the
+    same in every request; the whole cut to ``prompt_tokens`` ids), is
     continued by exactly ``max_new_tokens`` tokens (greedy at
     ``temperature`` 0, else sampled from ``seed``) and the continuation
-    is appended to it.  -> (STRING for ``CLIPTextEncode.text``,
+    is appended to ``text``.  -> (STRING for ``CLIPTextEncode.text``,
     LM_OUTPUT: the ids and the float32 logits each was drawn from, left
     on the device)."""
     TYPE = "LanguageModelGenerate"
     WIDGETS = ["text", "seed", CONTROL, "max_new_tokens", "prompt_tokens",
-               "temperature"]
+               "temperature", "instructions"]
     DEFAULTS = {"seed": 0, "max_new_tokens": 64, "prompt_tokens": 64,
-                "temperature": 0.0}
+                "temperature": 0.0, "instructions": ""}
 
     def execute(self, ctx: OpContext, model, text: str, seed=0,
                 max_new_tokens: int = 64, prompt_tokens: int = 64,
-                temperature: float = 0.0):
+                temperature: float = 0.0, instructions: str = ""):
         ctx.check_interrupt()
         base = seed.base if isinstance(seed, SeedValue) else seed
         # dtpu-lint: ignore[spine-host-fetch] a widget's number, never a device value
-        row = registry.LMRow(str(text), int(base), float(temperature))
+        row = registry.LMRow(str(text), int(base), float(temperature),
+                             str(instructions))
         n, p = int(max_new_tokens), int(prompt_tokens)
         if ctx.lm_handover is None:
             words, out = model.generate_rows([row], n, p)[0]
@@ -1078,13 +1083,15 @@ class LanguageModelGenerate(Op):
             seed = seeded.inputs.get("seed")
         numbers = (seed, inputs["max_new_tokens"], inputs["prompt_tokens"],
                    inputs["temperature"])
-        if not isinstance(name, str) or not isinstance(inputs["text"], str) \
+        if not all(isinstance(x, str) for x in (
+                name, inputs["text"], inputs["instructions"])) \
                 or not all(isinstance(x, (int, float))
                            and not isinstance(x, bool) for x in numbers):
             return None
         # dtpu-lint: ignore[spine-host-fetch] a number of the graph's JSON, never a device value
         temperature = float(inputs["temperature"])
-        return (name, registry.LMRow(inputs["text"], int(seed), temperature),
+        return (name, registry.LMRow(inputs["text"], int(seed), temperature,
+                                     inputs["instructions"]),
                 int(inputs["max_new_tokens"]), int(inputs["prompt_tokens"]))
 
 

@@ -25,7 +25,7 @@ _image_settled`).  Where the device owes nothing (an empty server, no
 overlap) or the set is full when the host arrives, nothing waits.
 
 What is kept is keyed on everything the result is a function of (model,
-lengths, text, seed, temperature), read again from the values the
+lengths, instructions, text, seed, temperature), read again from the values the
 follower's node is really called with: a request that was edited, or
 whose inputs the graph alone did not give away, finds nothing under its
 key and runs on its own.  At most ``model.row_counts[-1] - 1`` results are
@@ -79,7 +79,7 @@ class GenerateHandover:
         # before the wait, so that only the look at the queue lies between
         # the drain and the enqueue: the queued graphs are parsed (`_calls`
         # keeps them) and a leader that cannot be encoded is refused
-        model.prompt_ids(row.text, prompt_tokens)
+        model.prompt_ids(row.text, prompt_tokens, row.instructions)
         waiting, full = self._waiting(model, max_new_tokens, prompt_tokens)
         if not full and self._drain_wait():
             here = {pid for pid, _, _ in waiting}
@@ -156,7 +156,8 @@ class GenerateHandover:
                                     prompt_tokens):
                     continue
                 try:
-                    model.prompt_ids(row.text, prompt_tokens)
+                    model.prompt_ids(row.text, prompt_tokens,
+                                     row.instructions)
                 except ValueError:
                     continue        # it fails at its own turn, alone
                 found.append((item["id"], row, item.get("span")))

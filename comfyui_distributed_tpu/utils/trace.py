@@ -235,7 +235,7 @@ OTHER = "other"
 # written in ``attn1`` itself (``.../attn1/bnhd,bmhd->bhnm/dot_general``)
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
 _UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
-_MOE = "PanguUltraMoE"
+_MOE, _SWA = "PanguUltraMoE", "ExaoneMoe"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
     ("norm", None, r"GroupNorm_\d+|LayerNorm_\d+|(?:in_|out_)?norm\d*"
@@ -295,14 +295,32 @@ KERNEL_CLASSES = (
     ("lm_head", _MOE, r"lm_head|sample"),
     ("embed", _MOE, r"embed_tokens"),
     ("lm_proj", _MOE, r"dense_layers|moe_layers|PanguUltraMoE"),
+    # the decoder with window and full attention layers and routed experts
+    # (models/swa_moe.py), under the same classes; its expert layer IS the
+    # one above, so the same four names are ``lm_experts``.  The two
+    # phase scopes (PHASES) are glue here: `phase_of` reads them
+    ("lm_norm", _SWA, r"post_(?:attention|feedforward)_layernorm"
+                      r"|[qk]_norm|final_norm"),
+    ("lm_proj", _SWA, r"[qkvo]_proj"),
+    ("lm_cache", _SWA, r"kv_cache"),        # the ring and the full cache
+    ("lm_attn", _SWA, r"self_attn|rotary"),
+    ("lm_experts", _SWA, r"gate|dispatch|experts|combine"),
+    ("lm_mlp", _SWA, r"mlp|shared_experts|gate_proj|up_proj|down_proj"),
+    ("lm_head", _SWA, r"lm_head|sample"),
+    ("embed", _SWA, r"embed_tokens"),
+    ("lm_proj", _SWA, r"dense_layers|moe_layers|prefill|decode|ExaoneMoe"),
 )
+# the outer scopes a program may put directly under its model's: where it
+# does, a trace summary gives its seconds by PHASE beside its seconds by
+# class (models/swa_moe.py does; a program without them has no phases)
+PHASES = ("prefill", "decode")
 # the denoise programs' own operations under no module (CFG combine,
 # solver update, noise): ``core`` / ``step`` are the functions
 # models/registry.py jits
 SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
 _MODEL_OF = re.compile(
-    r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE)(?:\.\w+)?$")
+    r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE|ExaoneMoe)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 
@@ -327,6 +345,16 @@ def classify(op_name: str) -> str:
             if (of is None or of == model) and pat.match(seg):
                 return cls
     return OTHER
+
+
+def phase_of(op_name: str) -> Optional[str]:
+    """The phase of PHASES an operation's path lies in: the scope right
+    under its model's; None where there is none."""
+    segments = op_name.split("/")
+    for i, seg in enumerate(segments[:-1]):
+        if _MODEL_OF.match(seg):
+            return segments[i + 1] if segments[i + 1] in PHASES else None
+    return None
 
 
 def _annotate(name: str, **args: Any):

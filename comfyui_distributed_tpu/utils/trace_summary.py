@@ -23,7 +23,11 @@ annotations.  The summary holds, per chip and as a mean over chips:
   class of the path the operation's metadata carries, ``other`` for an
   operation under none, ``top_other`` naming the costliest of those),
   ``gaps`` for the time inside the execution in which no operation ran.
-  The rows add up to the execution's seconds;
+  The rows add up to the execution's seconds.  Where a program's paths
+  carry a phase (``trace.PHASES``: a scope right under the model's),
+  ``phases`` has the same leaf-operation seconds by phase (without the
+  gaps, and without what lies under no phase); a program without such a
+  scope has no ``phases`` key;
 * ``idle``: the seconds *between* program executions in which no
   operation ran, by the innermost ``dtpu/`` host span over each gap
   (``none`` under none), and ``idle_under``: the same seconds under each
@@ -41,7 +45,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from comfyui_distributed_tpu.utils.trace import HOST_PREFIX, OTHER, classify
+from comfyui_distributed_tpu.utils.trace import HOST_PREFIX, OTHER, \
+    classify, phase_of
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
@@ -311,9 +316,10 @@ def _chip(plane: dict, spans) -> Dict[str, Any]:
     s, d, idx = _arrays(ops_ln)
     paths = ops_ln.get("paths") or [""] * len(ops_ln["names"])
     classes = np.asarray([classify(p) for p in paths])
+    phases = np.asarray([phase_of(p) or "" for p in paths])
     leaf = _leaves(s, d)
     ls, ld, lidx = s[leaf], d[leaf], idx[leaf]
-    lclass = classes[lidx]
+    lclass, lphase = classes[lidx], phases[lidx]
     chip["ops"] = int(leaf.sum())
     chip["names_found"] = bool(np.asarray([bool(p) for p in paths])[lidx]
                                .any())
@@ -369,6 +375,10 @@ def _chip(plane: dict, spans) -> Dict[str, Any]:
         row["mean_s"] = row["total_s"] / row["count"]
         per_class[GAPS] = row["mean_s"] - sum(per_class.values())
         row["classes"] = per_class
+        by_phase = {str(ph): float(ld[mine & (lphase == ph)].sum()) / 1e9
+                    / row["count"] for ph in np.unique(lphase[mine]) if ph}
+        if by_phase:
+            row["phases"] = by_phase
         # what ``other`` holds, by name: the path where there is one,
         # else the HLO instruction as the trace prints it
         unclassed = mine & (lclass == OTHER)
@@ -412,6 +422,8 @@ def summarize(events: dict, traced_s: float = 0.0) -> Dict[str, Any]:
             "mean_s": sum(r["mean_s"] for r in rows) / len(rows),
             "classes": _mean([r["classes"] for r in rows]),
             "top_other": rows[0]["top_other"]}
+        if all("phases" in r for r in rows):
+            programs[name]["phases"] = _mean([r["phases"] for r in rows])
     # a slice that starts or ends in an idle gap holds no device event
     # there: the time the profiler was on still counts as idle
     window_s = max(max(c["window_s"] for c in chips), float(traced_s))

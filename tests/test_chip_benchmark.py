@@ -12,6 +12,8 @@ import importlib.util
 import json
 import os
 
+import pytest
+
 _TESTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "chip", "tests")
 
@@ -38,7 +40,8 @@ _collect()
 
 
 # the cell PR 28 appended, to the cells and to the ``workloads`` of the
-# metrics it reports, and the one PR 32 appended behind it
+# metrics it reports, and the one PR 32 appended behind it (PR 34's,
+# EXAONE4, is further down, with the test of the three)
 SAT4 = "ouro_expand_sd15_512_sat4"
 PANGU4 = "pangu_expand_sd15_512_sat4"
 ROOT = _MODULES["test_span_metrics"].ROOT
@@ -79,33 +82,61 @@ def test_the_four_caller_cell_is_an_entry_with_a_mix_of_its_own():
                 assert SAT4 in cells_of, x["name"]
 
 
-# the shares of a whole that move what the new cell reports and that it
-# does not report, each with what its reader would find there
+# The three expander cells under ``closed4_unique``: each reports every
+# accepted share of a roofline or of a peak that moves what it reports (a
+# claim in a cell needs them: PR 27 was refused for ``attn_roofline_pct``),
+# but for the stated exceptions, each with what its reader would find
+# there.  Shares are told by their unit and by what they move, not by a
+# word in their name: ``denoise_flops_util_pct`` is a share of the peak
+# too.
+EXAONE4 = "exaone_expand_sd15_512_sat4"
+_ONE_CHIP = "the least busy of the chips of a mesh: one chip"
+_EXAONE_ONLY = {
+    "lm_swa_moe_decode_hbm_roofline_pct": "the bytes of a decoder with a "
+                                          "ring beside a full cache, from "
+                                          "counters only its program has",
+    "lm_prefill_flops_util_pct": "the FLOPs of a 512-position prefill under "
+                                 "a phase scope only that program has",
+}
 NOT_IN_SAT4 = {
-    "chip_busy_min_pct": "the least busy of the chips of a mesh: one chip",
+    "chip_busy_min_pct": _ONE_CHIP,
     "lm_moe_decode_hbm_roofline_pct": "the bytes of a decoder with routed "
                                       "experts: Ouro has none to count",
+    **_EXAONE_ONLY,
+}
+NOT_IN_PANGU4 = {
+    "chip_busy_min_pct": _ONE_CHIP,
+    "lm_decode_hbm_roofline_pct": "its byte count is a dense looped "
+                                  "decoder's and would be false here: "
+                                  "lm_moe_decode_hbm_roofline_pct stands "
+                                  "in its place",
+    **_EXAONE_ONLY,
+}
+NOT_IN_EXAONE4 = {
+    "chip_busy_min_pct": _ONE_CHIP,
+    "lm_decode_hbm_roofline_pct": NOT_IN_PANGU4["lm_decode_hbm_roofline_pct"],
+    "lm_moe_decode_hbm_roofline_pct": "its byte count reads q_lora_rank and "
+                                      "kv_lora_rank, a latent cache's: "
+                                      "lm_swa_moe_decode_hbm_roofline_pct "
+                                      "stands in its place",
 }
 
 
-def test_the_four_caller_cell_reports_every_share_that_moves_what_it_does():
-    """A claim in a cell needs every accepted share of a roofline or of a
-    peak that moves what the cell reports (PR 27 was refused for
-    ``attn_roofline_pct``), and a reader that finds something there.
-    Shares are told by their unit and by what they move, not by a word in
-    their name: ``denoise_flops_util_pct`` is a share of the peak too."""
-    m = _manifest()
-    reported = {x["name"] for x in m["end_to_end"]
-                if SAT4 in x.get("workloads", [SAT4])}
-    assert {"images_per_s", "tti_p50_s", "setup_s"} <= reported
-    shares = {x["name"]: x for x in m["per_layer"]
-              if x["unit"] == "%" and x["moves"] in reported}
-    assert {"attn_roofline_pct", "lm_decode_hbm_roofline_pct",
-            "denoise_flops_util_pct"} <= set(shares)
-    missing = {n for n, x in shares.items() if SAT4 not in x["workloads"]}
-    assert missing == set(NOT_IN_SAT4)
-    # the layers the cell's graph runs report what the same programs
-    # report in their own cells: SD1.5's denoise by class, the VAE, CLIP
+def _config(name: str) -> dict:
+    with open(os.path.join(os.path.dirname(_TESTS), "configs",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def _listed(m: dict, cell: str) -> set:
+    return {x["name"] for x in m["per_layer"]
+            if cell in x.get("workloads", [])}
+
+
+def _sat4_reports(m, cells):
+    """The four-caller cell: what the layers its graph runs report in
+    their own cells (SD1.5's denoise by class, the VAE, CLIP), from
+    sources its configuration has."""
     by_layer = {}
     for x in m["per_layer"]:
         if SAT4 in x.get("workloads", []):
@@ -114,15 +145,7 @@ def test_the_four_caller_cell_reports_every_share_that_moves_what_it_does():
         x["name"] for x in m["per_layer"] if x["layer"] == "Denoise"}
     assert by_layer["VAE decode"] == {"vae_device_s_per_image"}
     assert by_layer["Text encode"] == {"clip_device_ms_per_request"}
-    # the readers' sources exist in the cell's configuration: SD1.5's
-    # UNet for the attention bound and the FLOP count, a pattern for each
-    # program, the language model's shapes
-    with open(os.path.join(os.path.dirname(_TESTS), "configs",
-                           "ouro-2.6b-expand-sd15-512.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(os.path.dirname(_TESTS), "configs",
-                           "sd15-512.json")) as f:
-        sd15 = json.load(f)
+    cfg, sd15 = _config("ouro-2.6b-expand-sd15-512"), _config("sd15-512")
     kernels = _MODULES["test_span_metrics"].kernels
     bound = kernels.attention_bound(cfg, {"bf16_flops_per_s": 197e12,
                                           "hbm_bytes_per_s": 819e9})
@@ -135,68 +158,98 @@ def test_the_four_caller_cell_reports_every_share_that_moves_what_it_does():
     assert set(cfg["programs"]) == set(sd15["programs"]) | {"lm_generate"}
 
 
-# what the expert model's cell does not report of the shares that move
-# what it does: ONE stated exception beside the mesh's
-NOT_IN_PANGU4 = {
-    "chip_busy_min_pct": "the least busy of the chips of a mesh: one chip",
-    "lm_decode_hbm_roofline_pct": "its byte count is a dense looped "
-                                  "decoder's and would be false here: "
-                                  "lm_moe_decode_hbm_roofline_pct stands "
-                                  "in its place",
-}
-
-
-def test_the_expert_cell_reports_every_share_that_moves_what_it_does():
-    """The twin of the test above for the cell PR 32 appended: the
-    four-caller cell's configuration with another language model in
-    front, so it reports what that cell reports, with the one roofline
-    whose bytes are architecture-specific exchanged for its own."""
-    m = _manifest()
-    cells = {w["name"]: w for w in m["workloads"]}
-    four, pangu = cells[SAT4], cells[PANGU4]
-    assert {k: pangu[k] for k in ("traffic", "chips")} == \
-        {k: four[k] for k in ("traffic", "chips")}
-    assert pangu["config"] == "pangu-ultra-moe-expand-sd15-512"
-    assert len(pangu["why"]) <= 200
-    assert "attention sees more than its share" in pangu["why"]
-    reported = {x["name"] for x in m["end_to_end"]
-                if PANGU4 in x.get("workloads", [PANGU4])}
-    assert reported == {x["name"] for x in m["end_to_end"]
-                        if SAT4 in x.get("workloads", [SAT4])}
-    shares = {x["name"]: x for x in m["per_layer"]
-              if x["unit"] == "%" and x["moves"] in reported}
-    missing = {n for n, x in shares.items() if PANGU4 not in x["workloads"]}
-    assert missing == set(NOT_IN_PANGU4)
-    assert shares["lm_moe_decode_hbm_roofline_pct"]["workloads"] == [PANGU4]
-    # everything else the four-caller cell lists, this one lists too, and
-    # two readers of its own
-    of = {cell: {x["name"] for x in m["per_layer"]
-                 if cell in x.get("workloads", [])} for cell in (SAT4, PANGU4)}
-    assert of[PANGU4] - of[SAT4] == {"lm_experts_device_s_per_request",
-                                     "lm_moe_decode_hbm_roofline_pct"}
-    assert of[SAT4] - of[PANGU4] == {"lm_decode_hbm_roofline_pct"}
-    for x in m["per_layer"]:
-        if PANGU4 in x.get("workloads", []):
-            assert x["workloads"][-1] == PANGU4, x["name"]
-    # 7 of at most 24 cells, still one on four chips
-    assert len(m["workloads"]) == 7
-    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
-        ["sdxl_1024_fanout4"]
-    # the readers' sources exist in the cell's configuration
-    bench = os.path.dirname(_TESTS)
-    with open(os.path.join(bench, "configs", pangu["config"] + ".json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(bench, "configs",
-                           "ouro-2.6b-expand-sd15-512.json")) as f:
-        ouro = json.load(f)
+def _same_graph_but(cfg: dict, other: dict, nodes: set):
+    """``cfg`` is ``other``'s configuration with another language model
+    in front: the same image model, programs and trace slice, the graphs
+    differing in ``nodes`` alone."""
     flops = _MODULES["test_chip_benchmark"].flops
     assert flops.denoise_flops_per_image(cfg) == \
-        flops.denoise_flops_per_image(ouro)
-    assert cfg["programs"] == ouro["programs"] and cfg["unet"] == ouro["unet"]
+        flops.denoise_flops_per_image(other)
+    assert cfg["programs"] == other["programs"]
+    assert cfg["unet"] == other["unet"]
     assert cfg["trace_slice"]["after_counter"] == "lm.executions"
-    # the graph is the four-caller cell's with the loader's name changed
-    changed = {nid for nid in cfg["graph"]
-               if cfg["graph"][nid] != ouro["graph"][nid]}
-    assert changed == {"20"} and set(cfg["graph"]) == set(ouro["graph"])
+    assert set(cfg["graph"]) == set(other["graph"])
+    assert {nid for nid in cfg["graph"]
+            if cfg["graph"][nid] != other["graph"][nid]} == nodes
+
+
+def _pangu4_reports(m, cells):
+    """The cell PR 32 appended: the four-caller cell's configuration with
+    another language model in front, the one roofline whose bytes are
+    architecture-specific exchanged for its own."""
+    pangu = cells[PANGU4]
+    assert pangu["config"] == "pangu-ultra-moe-expand-sd15-512"
+    shares = {x["name"]: x for x in m["per_layer"]}
+    assert shares["lm_moe_decode_hbm_roofline_pct"]["workloads"] == [PANGU4]
+    assert _listed(m, PANGU4) - _listed(m, SAT4) == {
+        "lm_experts_device_s_per_request", "lm_moe_decode_hbm_roofline_pct"}
+    assert _listed(m, SAT4) - _listed(m, PANGU4) == {
+        "lm_decode_hbm_roofline_pct"}
+    cfg = _config(pangu["config"])
+    _same_graph_but(cfg, _config("ouro-2.6b-expand-sd15-512"), {"20"})
     assert cfg["graph"]["20"]["inputs"] == {
         "model_name": "openpangu-ultra-moe-718b.safetensors"}
+
+
+def _exaone4_reports(m, cells):
+    """The cell PR 34 appended: openPangu's with a third language model in
+    front and the operator's instructions on the generate node; it lists
+    what that cell lists but the latent cache's byte count, and three
+    readers of its own."""
+    exaone = cells[EXAONE4]
+    assert exaone["config"] == "k-exaone-236b-expand-sd15-512"
+    assert _listed(m, EXAONE4) - _listed(m, PANGU4) == {
+        "lm_prefill_device_s_per_request", *_EXAONE_ONLY}
+    assert _listed(m, PANGU4) - _listed(m, EXAONE4) == {
+        "lm_moe_decode_hbm_roofline_pct"}
+    for x in m["per_layer"]:
+        if x["name"] in _listed(m, EXAONE4) - _listed(m, PANGU4):
+            assert x["workloads"] == [EXAONE4] and x["layer"] == \
+                "Language model" and x["source"] == "device_trace"
+    cfg = _config(exaone["config"])
+    _same_graph_but(cfg, _config("pangu-ultra-moe-expand-sd15-512"),
+                    {"20", "21"})
+    assert cfg["graph"]["20"]["inputs"] == {
+        "model_name": "k-exaone-236b-a23b.safetensors"}
+    node = cfg["graph"]["21"]["inputs"]
+    assert (node["prompt_tokens"], node["max_new_tokens"],
+            node["temperature"]) == (512, 64, 0.0)
+    assert len(node["instructions"].split()) == 450
+    # 8 of at most 24 cells, still one on four chips
+    assert len(m["workloads"]) == 8
+    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
+        ["sdxl_1024_fanout4"]
+
+
+@pytest.mark.parametrize("cell, leaves_out, reports", [
+    (SAT4, NOT_IN_SAT4, _sat4_reports),
+    (PANGU4, NOT_IN_PANGU4, _pangu4_reports),
+    (EXAONE4, NOT_IN_EXAONE4, _exaone4_reports),
+], ids=[SAT4, PANGU4, EXAONE4])
+def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
+        cell, leaves_out, reports):
+    m = _manifest()
+    cells = {w["name"]: w for w in m["workloads"]}
+    four, this = cells[SAT4], cells[cell]
+    assert {k: this[k] for k in ("traffic", "chips")} == \
+        {k: four[k] for k in ("traffic", "chips")}
+    assert len(this["why"]) <= 200
+    if cell != SAT4:
+        assert "attention sees more than its share" in this["why"]
+    reported = {x["name"] for x in m["end_to_end"]
+                if cell in x.get("workloads", [cell])}
+    assert reported == {"images_per_s", "tti_p50_s", "setup_s"}
+    shares = {x["name"]: x for x in m["per_layer"]
+              if x["unit"] == "%" and x["moves"] in reported}
+    assert {"attn_roofline_pct", "lm_decode_hbm_roofline_pct",
+            "denoise_flops_util_pct"} <= set(shares)
+    missing = {n for n, x in shares.items() if cell not in x["workloads"]}
+    assert missing == set(leaves_out)
+    # appended: in every list it stands behind the cells accepted before
+    # it, in the order they were accepted
+    order = [w["name"] for w in m["workloads"]]
+    for group in ("end_to_end", "per_layer"):
+        for x in m[group]:
+            listed = x.get("workloads", [])
+            assert listed == sorted(listed, key=order.index), x["name"]
+    reports(m, cells)

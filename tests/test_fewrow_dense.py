@@ -13,12 +13,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import looplm, mla_moe
+from comfyui_distributed_tpu.models import looplm, mla_moe, swa_moe
 from comfyui_distributed_tpu.ops.pallas import fewrow_dense as fd
 from comfyui_distributed_tpu.utils import trace
 
 OURO, PANGU = looplm.OURO_2_6B, mla_moe.OPENPANGU_ULTRA_MOE_SHARE
-FAMILIES = {"ouro": (looplm, OURO), "pangu": (mla_moe, PANGU)}
+EXAONE = swa_moe.K_EXAONE_SHARE
+FAMILIES = {"ouro": (looplm, OURO), "pangu": (mla_moe, PANGU),
+            "exaone": (swa_moe, EXAONE)}
 
 # every product `_dense` makes with a resident leaf at the published
 # sizes: (family, name, K, N, leaves streamed by one call)
@@ -36,6 +38,14 @@ PRODUCTS = [
     ("pangu", "shared gate_proj+up_proj", 7680, 2048, 2),
     ("pangu", "shared down_proj", 2048, 7680, 1),
     ("pangu", "lm_head", 7680, 19200, 1),
+    ("exaone", "q_proj", 6144, 8192, 1),
+    ("exaone", "k_proj+v_proj", 6144, 1024, 2),
+    ("exaone", "o_proj", 8192, 6144, 1),
+    ("exaone", "dense gate_proj+up_proj", 6144, 18432, 2),
+    ("exaone", "dense down_proj", 18432, 6144, 1),
+    ("exaone", "shared gate_proj+up_proj", 6144, 2048, 2),
+    ("exaone", "shared down_proj", 2048, 6144, 1),
+    ("exaone", "lm_head", 6144, 19200, 1),
 ]
 IDS = [f"{p[0]}-{p[1]}" for p in PRODUCTS]
 
@@ -196,7 +206,7 @@ def tiny_run(arch, cfg, rows, where, monkeypatch):
         np.asarray, (aux, stats))
 
 
-@pytest.mark.parametrize("family", ["ouro", "pangu"])
+@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone"])
 def test_a_scan_over_the_index_gives_what_a_scan_over_the_slices_gives(
         family, monkeypatch):
     """With the platform read as a TPU's the 4-row decode walks the layer
@@ -233,7 +243,7 @@ def traced(family, rows, where, monkeypatch, sharding=None):
         spec((rows,), np.uint32), spec((rows,), np.float32))
 
 
-@pytest.mark.parametrize("family", ["ouro", "pangu"])
+@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone"])
 def test_the_one_row_program_is_untouched_by_the_rule(family, monkeypatch):
     """Its text as lowered with the platform read as a TPU's is, byte for
     byte, its text with the rule off; and the 4-row program's is not."""
@@ -322,6 +332,7 @@ def weight_sized_results(lines, layer_elements):
     # the absorption einsums (per-head batched products, not `_dense`'s)
     # still have XLA slice `kv_b_proj`'s layer out: PERF.md section 7
     ("pangu", {512 * 32768}),
+    ("exaone", set()),
 ])
 def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
         family, known, one_chip, no_compile_cache, monkeypatch):
@@ -364,5 +375,9 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
                      "fewrow_dense_q_proj_k_proj_v_proj",
                      "fewrow_dense_gate_proj_up_proj"},
             "pangu": {"q_a_proj", "q_b_proj", "o_proj", "gate_proj",
-                      "down_proj", "shared_experts", "lm_head"}}[family]
+                      "down_proj", "shared_experts", "lm_head"},
+            "exaone": {"q_proj", "o_proj", "gate_proj", "down_proj",
+                       "shared_experts", "lm_head",
+                       "fewrow_dense_k_proj_v_proj",
+                       "fewrow_dense_gate_proj_up_proj"}}[family]
     assert want <= segments, want - segments

@@ -394,3 +394,29 @@ def test_ids_outside_the_vocabulary_are_refused_on_the_host(tmp_path):
         model.generate("a cat", max_new_tokens=0, prompt_tokens=8)
     with pytest.raises(ValueError, match="a prompt of 0 ids"):
         model.generate("a cat", max_new_tokens=2, prompt_tokens=0)
+
+
+@pytest.mark.parametrize("mesh, devices, images", [
+    ("data=1", 1, 1), ("data=2,tensor=2", 4, 2)])
+def test_the_few_shot_graph_runs_with_the_third_family(mesh, devices, images,
+                                                       tmp_path):
+    """``workflows/prompt-expand-fewshot-txt2img.json`` (PR 34): the
+    operator's instructions on the generate node and a model of the
+    family with a ring beside a full cache, on one device and under a
+    mesh (weights replicated over ``data``, column-split over
+    ``tensor``): the same expansion, the ring full in every step."""
+    got = probe("prompt-expand-fewshot-txt2img.json", tmp_path, devices,
+                DTPU_MESH_SHAPE=mesh, DTPU_TP_MIN_SHARD_ELEMENTS="64")
+    assert got["images"] == images
+    assert got["lm_resident"] == ["lm:k-exaone-236b-a23b.safetensors:"]
+    assert got["text"] == "a lighthouse on a cliff at dawn, bavi dase " \
+                          "data data"
+    # a call site a run of layers of one kind, in the programs of both
+    # row counts: three sliding runs and a full one, prefill and decode
+    assert {k: got["paths"][k] for k in ("xla_banded", "xla_ring",
+                                         "xla_causal", "xla_decode")} == {
+        "xla_banded": 6, "xla_ring": 6, "xla_causal": 2, "xla_decode": 2}
+    # 4 steps x 4 sliding layers x a window of 8; 32 prompt ids, all
+    # real (the instructions fill the buffer), and what was decoded
+    assert got["counters"]["lm.keys_attended_window"] == 4 * 4 * 8
+    assert got["counters"]["lm.keys_attended_full"] == 33 + 34 + 35 + 36
