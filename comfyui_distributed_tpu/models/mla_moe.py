@@ -76,15 +76,18 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from comfyui_distributed_tpu.models import looplm
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, xla_attention
 from comfyui_distributed_tpu.models.looplm import Stacked, _dense, \
     _rms_norm, _sandwich, dense_each, few_rows_here, matrix, scan_layers
+from comfyui_distributed_tpu.ops.pallas.row_scatter_add import LANES, \
+    row_scatter_add
 from comfyui_distributed_tpu.parallel import sharding as shd
 
 
@@ -445,60 +448,158 @@ def route(cfg: MlaMoeConfig, gate, n):
     return scores, chosen, top * cfg.routed_scaling_factor
 
 
+# The rows of one product with a routed expert where a call's tokens are
+# gathered by expert: one pass of the MXU's rows (with more, an expert with
+# a few pairs too many pays for a second tile of mostly padding).  A call
+# of up to TWO tiles' tokens is not gathered: each hit expert multiplies
+# all of them, because up to the chip's ridge (a v5e's 197 TFLOP/s over
+# 819 GB/s: 240 rows) that product is bound by the expert's bytes whatever
+# its rows, and the sort, the gathers and the tiles could only add to it
+# (openPangu's prefill of 4 x 64 positions: 13.4 ms an execution whole,
+# 21.6 in tiles; PERF.md section 6, PR 35).
+EXPERT_TILE = 128
+
+
+def tile_sum_path(platform: str, d: int,
+                  mesh_axes: Optional[dict] = None) -> str:
+    """How a tile's results go back into the sum ``[t, d]``: ``kernel``
+    (`row_scatter_add`: every row's read and write in flight at once) or
+    ``xla`` (``.at[rows].add``, which walks the rows one by one).  As
+    `looplm.dense_path`, a function of what is visible at trace time: on
+    a TPU, rows of whole lanes, no multi-device mesh live (XLA cannot
+    partition the custom call)."""
+    if platform == "tpu" and d % LANES == 0 \
+            and math.prod((mesh_axes or {}).values()) == 1:
+        return "kernel"
+    return "xla"
+
+
+def _expert(experts, l, e):
+    """Expert ``e`` of expert block ``l`` out of the leaves
+    ``[L, E_here, in, out]``."""
+    return {name: jax.lax.dynamic_slice(
+        w, (l, e, 0, 0), (1, 1, *w.shape[2:]))[0, 0]
+        for name, w in experts.items()}
+
+
 def _routed(cfg: MlaMoeConfig, experts, l, x, chosen, weights):
     """The part of the expert layer's result that THIS chip's experts
-    give for the tokens ``x [t, d]``: for each held expert with at least
-    one pair, the expert over all tokens times each token's weight for it
-    (zero where the token did not choose it); an expert nobody chose is
-    not read.  ``experts`` holds every expert block's leaves
-    ``[L, E_here, in, out]`` and is indexed in place by ``(l, e)``: a
-    slice handed to the conditional would be a copy of the weights.
+    give for the tokens ``x [t, d]``: every local (token, choice) pair
+    through its expert, times its weight, added up in float32.  An expert
+    nobody chose is not read.  ``experts`` holds every expert block's
+    leaves ``[L, E_here, in, out]`` and is indexed in place by ``(l, e)``:
+    a slice handed to the conditional would be a copy of the weights.
+
+    An expert multiplies TILES of rows.  Where the call has no more
+    tokens than two of `EXPERT_TILE` (a decode step, a short prefill) the
+    one tile is the call's tokens, each with its weight for the expert
+    (zero where it did not choose it), and nothing is sorted or gathered.
+    Where it has more, the pairs are ordered by expert, and a hit expert
+    walks ``ceil(its pairs / EXPERT_TILE)`` tiles: the tile's token rows
+    gathered, the gated MLP on ``[tile, d]``, the result times each
+    pair's weight (zero for the last tile's padding rows) added to the
+    sum's rows: by the kernel `row_scatter_add` where `tile_sum_path`
+    says so (a TPU), else by XLA's scatter.  No capacity: no pair is ever
+    dropped.
+
     Returns the sum ``[t, d]`` and, int32, the local pairs of each token
-    ``[t]``, the experts hit and the local pairs NOT computed (0: there
-    is no capacity to overflow)."""
-    held = cfg.experts_held
+    ``[t]``, the experts hit, the local pairs NOT computed (0) and the
+    rows the experts multiplied (tiles x their rows)."""
+    t, d = x.shape
+    held, k = cfg.experts_held, cfg.num_experts_per_tok
+    tile = EXPERT_TILE if t > 2 * EXPERT_TILE else t
+    platform, mesh_axes = looplm._where()
+    kernel = tile < t and tile_sum_path(platform, d, mesh_axes) == "kernel"
+
+    def mlp(own, rows):
+        return _gated_mlp(cfg, own, rows, contextlib.nullcontext)
+
     with jax.named_scope("dispatch"):
         local = chosen - cfg.experts_first
         # [t, k, E_here]: a pair to an absent expert matches no column
         onehot = local[..., None] == jnp.arange(held)
-        combine = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
         pairs = jnp.sum(onehot, axis=(1, 2), dtype=jnp.int32)
         count = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)
+        if tile == t:
+            combine = jnp.sum(jnp.where(onehot, weights[..., None], 0.0),
+                              axis=1)
+        else:
+            # the pairs by expert, an expert's by token (a token chooses
+            # an expert once: a tile's rows are distinct and ascending),
+            # the absent experts' behind them all; a tile's worth of
+            # padding, so that the last tile's slice never runs out
+            _, token, weight = jax.lax.sort(
+                (jnp.where(onehot.any(-1), local, held).reshape(-1),
+                 jnp.repeat(jnp.arange(t, dtype=jnp.int32), k),
+                 weights.reshape(-1)), num_keys=2)
+            token, weight = (jnp.pad(a, (0, tile)) for a in (token, weight))
+            start = jnp.cumsum(count) - count
+            x = x.astype(cfg.dtype)
+
+    if tile == t:
+        def tiles_of(e, own, y):
+            """Expert ``e``'s one tile, the call's tokens, added to ``y``."""
+            w_e = jax.lax.dynamic_index_in_dim(combine, e, axis=1)
+            return 1, y + w_e * mlp(own, x)
+    else:
+        def tiles_of(e, own, y):
+            """Expert ``e``'s tiles of its own tokens added to ``y``."""
+            def add(i, y):
+                at = i * tile + jnp.arange(tile, dtype=jnp.int32)
+                mine, w = (jax.lax.dynamic_slice(
+                    a, (start[e] + i * tile,), (tile,))
+                    for a in (token, weight))
+                # a padding row reads and writes nothing: a row past the
+                # tokens, with no weight
+                live = at < count[e]
+                rows = jnp.where(live, mine, t + at)
+                out = jnp.where(live, w, 0.0)[:, None] * mlp(
+                    own, x.at[rows].get(
+                        mode="fill", fill_value=0, indices_are_sorted=True,
+                        unique_indices=True))
+                if kernel:
+                    return row_scatter_add(
+                        y, mine, count[e] - i * tile,
+                        out.reshape(tile, -1, LANES))
+                return y.at[rows].add(out, mode="drop",
+                                      indices_are_sorted=True,
+                                      unique_indices=True)
+            tiles = (count[e] + tile - 1) // tile
+            return tiles, jax.lax.fori_loop(0, tiles, add, y)
 
     def one(e, carry):
         def run(carry):
-            y, done = carry
-            own = {name: jax.lax.dynamic_slice(
-                w, (l, e, 0, 0), (1, 1, *w.shape[2:]))[0, 0]
-                for name, w in experts.items()}
-            w_e = jax.lax.dynamic_index_in_dim(combine, e, axis=1)
-            return y + w_e * _gated_mlp(cfg, own, x, contextlib.nullcontext), \
-                done + count[e]
+            y, done, rows = carry
+            tiles, y = tiles_of(e, _expert(experts, l, e), y)
+            return y, done + count[e], rows + tiles * tile
         return jax.lax.cond(count[e] > 0, run, lambda carry: carry, carry)
 
     with jax.named_scope("experts"):
-        y, done = jax.lax.fori_loop(
-            0, held, one, (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)))
-    return y, pairs, jnp.sum(count > 0, dtype=jnp.int32), \
-        jnp.sum(count) - done
+        # (the kernel's sum is laid out in rows of whole lanes)
+        shape = (t, d // LANES, LANES) if kernel else (t, d)
+        y, done, rows = jax.lax.fori_loop(
+            0, held, one, (jnp.zeros(shape, jnp.float32),
+                           jnp.int32(0), jnp.int32(0)))
+    return y.reshape(t, d), pairs, jnp.sum(count > 0, dtype=jnp.int32), \
+        jnp.sum(count) - done, rows
 
 
 def _moe(cfg: MlaMoeConfig, lp, experts, l, n):
     """The expert block's MLP on ``n [B, N, d]``: the shared expert plus
     this chip's routed part; and its routing: the router's ``(scores
     [B, N, E], choices [B, N, k])`` and `_routed`'s counts (local pairs
-    per row ``[B]``, hits, dropped)."""
+    per row ``[B]``, hits, dropped, rows computed)."""
     B, N, d = n.shape
     x = n.reshape(B * N, d)
     with jax.named_scope("gate"):
         scores, chosen, weights = route(cfg, matrix(lp["gate"]), x)
-    y, pairs, hits, dropped = _routed(cfg, experts, l, x, chosen, weights)
+    y, pairs, *counts = _routed(cfg, experts, l, x, chosen, weights)
     with jax.named_scope("shared_experts"):
         shared = _gated_mlp(cfg, lp["shared_experts"], x)
     with jax.named_scope("combine"):
         out = (shared + y).reshape(B, N, d)
     return out, ((scores.reshape(B, N, -1), chosen.reshape(B, N, -1)),
-                 (pairs.reshape(B, N).sum(axis=1), hits, dropped))
+                 (pairs.reshape(B, N).sum(axis=1), *counts))
 
 
 def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
@@ -512,7 +613,7 @@ def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
     Returns the normed last state, the
     cache, the routers' ``(scores [B, N, Le, E], choices [B, N, Le, k])``
     and the routing counts summed over the expert blocks (local pairs
-    ``[B]``, hits, dropped)."""
+    ``[B]``, hits, dropped, rows computed)."""
     Ld = cfg.first_k_dense_replace
     eps = cfg.rms_norm_eps
     stream = few_rows_here(math.prod(x.shape[:2]))
@@ -542,9 +643,8 @@ def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
             carry, moe, cfg.moe_layers, stream, first=Ld)
     with jax.named_scope("final_norm"):
         x = _rms_norm(x, params["norm"], eps)
-    pairs, hits, dropped = counts
     return x, cache, tuple(jnp.moveaxis(r, 0, 2) for r in routed), \
-        (pairs.sum(axis=0), hits.sum(), dropped.sum())
+        tuple(c.sum(axis=0) for c in counts)
 
 
 def _embed(params, ids):
@@ -586,7 +686,10 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
     and ``stats``, int32, over the DECODE steps and the expert
     blocks: ``expert_pairs_local [B]`` (a row's pairs routed to experts
     held here), ``expert_hits`` (distinct local experts with at least one
-    pair, over all rows of a step) and, over the prefill too,
+    pair, over all rows of a step); over the PREFILL
+    ``expert_pairs_local_prefill [B]`` (over the whole prompt buffer) and
+    ``expert_rows_computed_prefill`` (the rows the routed experts
+    multiplied for them: `_routed`'s tiles x their rows); over both
     ``expert_pairs_dropped`` (0)."""
     B, P = prompt_ids.shape
     prompt_len, seed, temperature = (
@@ -605,9 +708,10 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
         # every row's last real id at P - 1
         prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
         cache = empty_cache(cfg, B, P + max_new_tokens)
-        x, cache, routed, (_, _, dropped) = _stack(
+        x, cache, routed, counts = _stack(
             cfg, params, _embed(params, prompt_ids), jnp.arange(P), first,
             cache, absorbed=False)
+        prefill_pairs, _, dropped, prefill_rows = counts
         logits = _head(cfg, params, x[:, P - 1:])[:, 0]
 
         def step(carry, i):
@@ -615,7 +719,7 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
             with jax.named_scope("sample"):
                 token = jax.vmap(draw, (0, 0, 0, None))(
                     keys, logits, temperature, i)
-            x, cache, nxt_routed, now = _stack(
+            x, cache, nxt_routed, (*now, _) = _stack(
                 cfg, params, _embed(params, token[:, None]), P + i[None],
                 first, cache, absorbed=True)
             nxt = _head(cfg, params, x)[:, 0]
@@ -634,7 +738,9 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
              "expert_choices": choices.swapaxes(0, 1),
              "prompt_choices": routed[1]},
             {"expert_pairs_local": pairs, "expert_hits": hits,
-             "expert_pairs_dropped": dropped})
+             "expert_pairs_dropped": dropped,
+             "expert_pairs_local_prefill": prefill_pairs,
+             "expert_rows_computed_prefill": prefill_rows})
 
 
 def make_program(cfg: MlaMoeConfig, max_new_tokens: int):
@@ -653,11 +759,17 @@ def window_counters(cfg: MlaMoeConfig, stats, real: int, steps: int
     """The ``lm.*`` window counters of one execution from its fetched
     ``stats``: the ``real`` rows' pairs (a padded row repeats the first
     and is nobody's request; it routes as the first does, so it adds no
-    hit)."""
+    hit) and, over EVERY row of the program, what the prefill routed
+    here and the rows its experts multiplied for that (their ratio is
+    what the tiles waste)."""
     return {
         "lm.expert_pairs": real * steps * cfg.moe_layers
         * cfg.num_experts_per_tok,
         "lm.expert_pairs_local": int(stats["expert_pairs_local"][:real]
                                      .sum()),
         "lm.expert_hits": int(stats["expert_hits"]),
-        "lm.expert_pairs_dropped": int(stats["expert_pairs_dropped"])}
+        "lm.expert_pairs_dropped": int(stats["expert_pairs_dropped"]),
+        "lm.expert_pairs_local_prefill": int(
+            stats["expert_pairs_local_prefill"].sum()),
+        "lm.expert_rows_computed_prefill": int(
+            stats["expert_rows_computed_prefill"])}

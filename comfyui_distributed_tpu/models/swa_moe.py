@@ -373,8 +373,8 @@ def _stack(cfg: ExaoneMoeConfig, params, x, index, first, caches,
     leaves in place.  Returns the normed last state, the caches, the
     routers' ``(scores [B, N, Le, E], choices [B, N, Le, k])``, the
     routing counts summed over the expert blocks (local pairs ``[B]``,
-    hits, dropped) and the keys each row's last query saw, summed over
-    the layers of each kind (``{kind: [B]}``)."""
+    hits, dropped, rows computed) and the keys each row's last query saw,
+    summed over the layers of each kind (``{kind: [B]}``)."""
     B = x.shape[0]
     eps = cfg.rms_norm_eps
     moe = dict(params["moe_layers"])
@@ -413,9 +413,8 @@ def _stack(cfg: ExaoneMoeConfig, params, x, index, first, caches,
         x = _rms_norm(x, params["norm"], eps)
     scores, choices = (jnp.moveaxis(jnp.concatenate(r), 0, 2)
                        for r in zip(*routed))
-    pairs, hits, dropped = (jnp.concatenate(c).sum(axis=0)
-                            for c in zip(*counts))
-    return x, caches, (scores, choices), (pairs, hits, dropped), seen
+    return x, caches, (scores, choices), tuple(
+        jnp.concatenate(c).sum(axis=0) for c in zip(*counts)), seen
 
 
 def _embed(params, ids):
@@ -466,8 +465,9 @@ def generate(cfg: ExaoneMoeConfig, max_new_tokens: int, params, prompt_ids,
     ``expert_pairs_local [B]``, ``expert_hits``, ``keys_attended_window
     [B]`` and ``keys_attended_full [B]`` (what the steps' masks let a
     row's query see, summed over the layers of the kind);
-    ``expert_pairs_local_prefill [B]`` (over the whole prompt buffer);
-    ``expert_pairs_dropped`` over both (0)."""
+    ``expert_pairs_local_prefill [B]`` (over the whole prompt buffer) and
+    ``expert_rows_computed_prefill`` (the rows the routed experts
+    multiplied for them); ``expert_pairs_dropped`` over both (0)."""
     B, P = prompt_ids.shape
     prompt_len, seed, temperature = (
         jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
@@ -485,10 +485,11 @@ def generate(cfg: ExaoneMoeConfig, max_new_tokens: int, params, prompt_ids,
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
             prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
-            x, caches, routed, (prefill_pairs, _, dropped), _ = _stack(
+            x, caches, routed, counts, _ = _stack(
                 cfg, params, _embed(params, prompt_ids), jnp.arange(P),
                 first, empty_cache(cfg, B, P + max_new_tokens),
                 decode=False)
+            prefill_pairs, _, dropped, prefill_rows = counts
             logits = _head(cfg, params, x[:, P - 1:])[:, 0]
 
         def step(carry, i):
@@ -496,7 +497,7 @@ def generate(cfg: ExaoneMoeConfig, max_new_tokens: int, params, prompt_ids,
             with jax.named_scope("sample"):
                 token = jax.vmap(draw, (0, 0, 0, None))(
                     keys, logits, temperature, i)
-            x, caches, nxt_routed, (pairs, hits, dropped), seen = _stack(
+            x, caches, nxt_routed, (pairs, hits, dropped, _), seen = _stack(
                 cfg, params, _embed(params, token[:, None]), P + i[None],
                 first, caches, decode=True)
             nxt = _head(cfg, params, x)[:, 0]
@@ -519,6 +520,7 @@ def generate(cfg: ExaoneMoeConfig, max_new_tokens: int, params, prompt_ids,
             {"expert_pairs_local": pairs, "expert_hits": hits,
              "expert_pairs_dropped": dropped,
              "expert_pairs_local_prefill": prefill_pairs,
+             "expert_rows_computed_prefill": prefill_rows,
              "keys_attended_window": window_keys,
              "keys_attended_full": full_keys})
 
@@ -541,7 +543,8 @@ def window_counters(cfg: ExaoneMoeConfig, stats, real: int, steps: int
     ``real`` rows'; a padded row repeats the first and is nobody's), the
     keys the real rows' decode steps attended to by kind of layer, and
     the local pairs of the prefill over EVERY row of the program (what
-    it computed, beside its rows x prompt positions)."""
+    it computed, beside its rows x prompt positions) with the rows its
+    experts multiplied for them."""
     def real_rows(name):
         return int(stats[name][:real].sum())
 
@@ -553,5 +556,7 @@ def window_counters(cfg: ExaoneMoeConfig, stats, real: int, steps: int
         "lm.expert_pairs_dropped": int(stats["expert_pairs_dropped"]),
         "lm.expert_pairs_local_prefill": int(
             stats["expert_pairs_local_prefill"].sum()),
+        "lm.expert_rows_computed_prefill": int(
+            stats["expert_rows_computed_prefill"]),
         "lm.keys_attended_window": real_rows("keys_attended_window"),
         "lm.keys_attended_full": real_rows("keys_attended_full")}

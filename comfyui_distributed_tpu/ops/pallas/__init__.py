@@ -6,7 +6,9 @@ cost on TPU and a fused VMEM-resident kernel avoids materializing the
 [N, N] attention matrix in HBM, and because a language model's decode step
 of a few rows is bound by how its weights are streamed
 (``fewrow_dense.fewrow_dense``, imported where it is used: its name here
-would hide the module).
+would hide the module), and because the rows an expert's tile adds to a
+sum in HBM each wait out the memory's latency unless all are in flight
+at once (``row_scatter_add.row_scatter_add``, imported the same way).
 """
 
 from comfyui_distributed_tpu.ops.pallas.flash_attention import (  # noqa: F401

@@ -226,9 +226,10 @@ def test_a_scan_over_the_index_gives_what_a_scan_over_the_slices_gives(
 
 # --- the published sizes, lowered and compiled for the chip -----------------------
 
-def traced(family, rows, where, monkeypatch, sharding=None):
-    """``lm_generate`` of the published size at ``rows`` rows, traced
-    over shapes (no weight is made) with the rule reading ``where``."""
+def traced(family, rows, where, monkeypatch, sharding=None, positions=64):
+    """``lm_generate`` of the published size at ``rows`` rows of
+    ``positions`` prompt positions, traced over shapes (no weight is
+    made) with the rule reading ``where``."""
     monkeypatch.setattr(looplm, "_where", lambda: where)
     arch, cfg = FAMILIES[family]
 
@@ -239,7 +240,7 @@ def traced(family, rows, where, monkeypatch, sharding=None):
         lambda s: spec(s, cfg.dtype), arch.param_shapes(cfg),
         is_leaf=lambda x: isinstance(x, tuple))
     return arch.make_program(cfg, 64).trace(
-        params, spec((rows, 64), np.int32), spec((rows,), np.int32),
+        params, spec((rows, positions), np.int32), spec((rows,), np.int32),
         spec((rows,), np.uint32), spec((rows,), np.float32))
 
 
@@ -327,6 +328,31 @@ def weight_sized_results(lines, layer_elements):
     return found
 
 
+COMPILED = {}
+
+
+def compiled_four_rows(family, one_chip, monkeypatch, positions=64):
+    """The text of the 4-row program of the published size (a prefill of
+    4 x ``positions``, 64 steps) as compiled for the described chip, once
+    a family and length."""
+    if (family, positions) not in COMPILED:
+        COMPILED[family, positions] = traced(
+            family, 4, ("tpu", None), monkeypatch, one_chip,
+            positions).lower().compile().as_text()
+    return COMPILED[family, positions]
+
+
+def weights_of(family):
+    """The shapes of a family's large matrices, and the values in a layer
+    of each and in each whole leaf."""
+    arch, cfg = FAMILIES[family]
+    matrices = [s for s in jax.tree_util.tree_leaves(
+        arch.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+        if len(s) >= 2 and s[-1] * s[-2] >= 1 << 20]
+    return matrices, {s[-1] * s[-2] for s in matrices} \
+        | {int(np.prod(s)) for s in matrices}
+
+
 @pytest.mark.parametrize("family, known", [
     ("ouro", set()),
     # the absorption einsums (per-head batched products, not `_dense`'s)
@@ -337,13 +363,8 @@ def weight_sized_results(lines, layer_elements):
 def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
         family, known, one_chip, no_compile_cache, monkeypatch):
     arch, cfg = FAMILIES[family]
-    text = traced(family, 4, ("tpu", None), monkeypatch,
-                  one_chip).lower().compile().as_text()
-    matrices = [s for s in jax.tree_util.tree_leaves(
-        arch.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-        if len(s) >= 2 and s[-1] * s[-2] >= 1 << 20]
-    layer_elements = {s[-1] * s[-2] for s in matrices} \
-        | {int(np.prod(s)) for s in matrices}
+    text = compiled_four_rows(family, one_chip, monkeypatch)
+    matrices, layer_elements = weights_of(family)
     bodies = {name: lines for name, lines in computations(text).items()
               if name != "ENTRY" and "fused" not in name
               and any("fewrow_dense" in l and "custom-call(" in l
@@ -381,3 +402,39 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
                        "fewrow_dense_k_proj_v_proj",
                        "fewrow_dense_gate_proj_up_proj"}}[family]
     assert want <= segments, want - segments
+
+
+@pytest.mark.parametrize("family", ["pangu", "exaone"])
+def test_a_tile_of_an_experts_tokens_copies_no_weight(
+        family, one_chip, no_compile_cache, monkeypatch):
+    """A prefill of 4 x 128 positions is four tiles' worth of tokens, so a
+    hit expert walks tiles (PR 35; the 4 x 64 of the other tests is not
+    gathered).  The expert's three matrices are sliced out of the stacked
+    leaves by the conditional, once an expert; the body of the loop over
+    its TILES gathers, multiplies ``[tile, d]`` and hands the result to
+    `row_scatter_add`, which the chip's compiler takes at the published
+    widths, and writes no buffer of a weight's size nor one of the whole
+    sum's: the bytes of a hit expert are paid once, whatever its tiles,
+    and the sum is updated where it lies."""
+    text = compiled_four_rows(family, one_chip, monkeypatch, positions=128)
+    _, layer_elements = weights_of(family)
+    tile, d = mla_moe.EXPERT_TILE, FAMILIES[family][1].hidden_size
+    bodies = {name: lines for name, lines in computations(text).items()
+              if "fused" not in name and any(
+                  "/experts/" in l and "row_scatter_add" in l
+                  and "custom-call(" in l for l in lines)}
+    assert bodies, "no computation holds a tile's kernel call"
+    for name, lines in bodies.items():
+        assert not weight_sized_results(lines, layer_elements), name
+        made = [m for m in map(INSTRUCTION.match, lines)
+                if m and m["op"] not in PASSES_ON]
+        # the sum [4 x 128, d / 128, 128] leaves the kernel and nothing else
+        whole = [m["op"] for m in made if m["dtype"] == "f32"
+                 and m["dims"] == f"512,{d // 128},128"]
+        assert whole == ["custom-call"], (name, whole)
+        # a tile's gather and its products, and nothing of all the tokens
+        shapes = {(m["dtype"], m["dims"]) for m in made
+                  if "/experts/" in m["rest"] and (
+                      "gather" in m["rest"] or "dot_general" in m["rest"])}
+        assert ("bf16", f"{tile},{d}") in shapes, shapes
+        assert not any(dims.startswith("512,") for _, dims in shapes), shapes

@@ -262,7 +262,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(params):
         share = dataclasses.replace(TINY, experts_first=first)
         experts = {k: w[:, first:first + 4]
                    for k, w in full["experts"].items()}
-        part, local, hits, dropped = mla_moe._routed(
+        part, local, hits, dropped, _ = mla_moe._routed(
             share, experts, jnp.int32(1), x, chosen, weights)
         total, pairs = total + part, pairs + int(local.sum())
         assert int(dropped) == 0 and 0 <= int(hits) <= 4
@@ -276,6 +276,128 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(params):
     np.testing.assert_allclose(total, want, atol=2e-5)
     # and one share alone is NOT the layer
     assert float(jnp.abs(total - part).max()) > 1e-2
+
+
+# --- an expert multiplies its own tokens, in tiles ------------------------------
+
+def every_expert_over_all_tokens(cfg, experts, l, x, chosen, weights):
+    """What `_routed` has to give, the plain way: every held expert over
+    ALL tokens, times each token's weight for it (zero where the token
+    did not choose it).  -> the sum and the pairs of each held expert."""
+    onehot = (chosen - cfg.experts_first)[..., None] \
+        == jnp.arange(cfg.experts_held)
+    combine = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
+    y = jnp.zeros(x.shape, jnp.float32)
+    for e in range(cfg.experts_held):
+        own = {name: w[l, e] for name, w in experts.items()}
+        y = y + combine[:, e:e + 1] * mla_moe._gated_mlp(cfg, own, x)
+    return y, np.asarray(onehot.sum(axis=(0, 1))), \
+        np.asarray(onehot.sum(axis=(1, 2)))
+
+
+def choices(tokens, takers, seed=3):
+    """``chosen [tokens, 4]`` in which held expert 4 + ``e`` is chosen by
+    exactly the tokens ``takers[e]``; every other choice goes to an absent
+    expert (0..3, 8..15), a token's four all distinct."""
+    rng = np.random.default_rng(seed)
+    absent = [e for e in range(16) if not 4 <= e < 8]
+    rows = []
+    for t in range(tokens):
+        mine = [4 + e for e, who in takers.items() if t in who]
+        fill = rng.permutation(absent)[:4 - len(mine)]
+        rows.append(rng.permutation(np.concatenate([mine, fill])))
+    return jnp.asarray(np.stack(rows), jnp.int32)
+
+
+TILE = 4
+# case: (tokens, {held expert: the tokens that choose it})
+TILINGS = {
+    "an expert nobody chose": (11, {0: range(0, 11, 2), 1: [3, 7], 2: [10]}),
+    "more pairs than one tile": (11, {0: range(9), 1: [0], 2: [5], 3: [6]}),
+    "exactly a tile": (11, {0: [1, 4, 6, 9], 1: range(8), 3: [2]}),
+    "every pair to an absent expert": (11, {}),
+    "one pair in all": (11, {2: [10]}),
+    "no more tokens than a tile": (TILE, {0: [0, 3], 2: range(4)}),
+    "fewer tokens than a tile": (3, {1: [2], 3: range(3)}),
+    "two tiles' tokens, not gathered": (2 * TILE, {0: range(8), 3: [7]}),
+    "one more token than two tiles": (2 * TILE + 1, {0: range(9), 3: [7]}),
+}
+
+
+@pytest.mark.parametrize("case", TILINGS)
+def test_an_expert_multiplies_its_own_tokens_in_tiles(case, monkeypatch):
+    """The tiled `_routed` (a small tile patched in) against every expert
+    over all tokens, weighted: the same sum to float32's rounding (only
+    the order of an output row's terms differs), the same pairs and hits,
+    none dropped, and as many rows multiplied as the tiles hold."""
+    monkeypatch.setattr(mla_moe, "EXPERT_TILE", TILE)
+    tokens, takers = TILINGS[case]
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((tokens, TINY.hidden_size)),
+                    jnp.float32)
+    chosen = choices(tokens, takers)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, chosen.shape), jnp.float32)
+    experts = mla_moe.seeded_params(TINY, np.uint32(11))["moe_layers"][
+        "experts"]
+    want, count, want_pairs = every_expert_over_all_tokens(
+        TINY, experts, 1, x, chosen, weights)
+    assert list(count) == [len(takers.get(e, ())) for e in range(4)]
+    y, pairs, hits, dropped, rows = jax.jit(
+        lambda *a: mla_moe._routed(TINY, experts, jnp.int32(1), *a))(
+            x, chosen, weights)
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    assert np.array_equal(pairs, want_pairs)
+    assert int(hits) == int((count > 0).sum()) and int(dropped) == 0
+    tile = TILE if tokens > 2 * TILE else tokens
+    assert int(rows) == sum(-(-int(c) // tile) * tile for c in count)
+    if not takers:
+        assert int(rows) == 0 and not np.asarray(y).any()
+    else:
+        assert float(jnp.abs(want).max()) > 1e-2
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("tokens, tiled", [(1, False), (4, False),
+                                           (TILE * 8, False),
+                                           (TILE * 16, False),
+                                           (TILE * 16 + 1, True),
+                                           (TILE * 64, True)])
+def test_a_call_of_no_more_tokens_than_two_tiles_is_not_sorted_or_gathered(
+        tokens, tiled, monkeypatch):
+    """The rule over ``t`` and the tile, on the traced `_routed`: a decode
+    step (1 or 4 tokens) and a prefill of no more tokens than two tiles hold
+    no sort, gather or scatter and multiply ``[t, d]`` by an expert, as
+    before there were tiles; a longer call multiplies ``[tile, d]`` by an
+    expert and never ``[t, d]``."""
+    tile = TILE * 8
+    monkeypatch.setattr(mla_moe, "EXPERT_TILE", tile)
+    spec = jax.ShapeDtypeStruct
+    experts = {name: spec((2, 4, *shape[2:]), jnp.float32) for name, shape
+               in mla_moe.param_shapes(TINY)["moe_layers"]["experts"].items()}
+    jaxpr = jax.make_jaxpr(
+        lambda *a: mla_moe._routed(TINY, a[0], jnp.int32(1), *a[1:]))(
+            experts, spec((tokens, TINY.hidden_size), jnp.float32),
+            spec((tokens, 4), jnp.int32), spec((tokens, 4), jnp.float32))
+    eqns = list(_equations(jaxpr.jaxpr))
+    moved = {e.primitive.name for e in eqns} \
+        & {"sort", "gather", "scatter", "scatter-add", "scatter_add"}
+    products = {e.invars[0].aval.shape for e in eqns
+                if e.primitive.name == "dot_general"}
+    width = TINY.moe_intermediate_size
+    if tiled:
+        assert moved == {"sort", "gather", "scatter-add"}
+        assert products == {(tile, TINY.hidden_size), (tile, width)}
+    else:
+        assert not moved
+        assert products == {(tokens, TINY.hidden_size), (tokens, width)}
 
 
 # --- each breakage fails the comparison -------------------------------------
@@ -452,6 +574,13 @@ def test_the_registry_serves_it_and_counts_its_routing(monkeypatch):
     assert 0 < got["lm.expert_pairs_local"] < got["lm.expert_pairs"]
     assert 0 < got["lm.expert_hits"] <= 5 * 2 * 4
     assert got["lm.expert_pairs_dropped"] == 0
+    # the prefill's pairs over the 4 x 32 positions of the program, and
+    # the rows its experts multiplied for them: one tile (the whole
+    # buffer) a hit expert and block
+    assert 0 < got["lm.expert_pairs_local_prefill"] < 4 * 32 * 2 * 4
+    assert got["lm.expert_rows_computed_prefill"] % (4 * 32) == 0
+    assert got["lm.expert_pairs_local_prefill"] \
+        <= got["lm.expert_rows_computed_prefill"] <= 2 * 4 * 4 * 32
     assert trace.GLOBAL_GAUGES.snapshot()["lm.kv_cache_bytes"] == \
         mla_moe.kv_cache_bytes(TINY, 4, 32 + 5) == 3 * 4 * 37 * 24 * 4
     words, lm_out = out[2]
@@ -570,6 +699,19 @@ def test_every_class_of_the_expert_model_is_in_its_compiled_program(params):
     ("while/body/closed_call/dense_layers/while/body/closed_call/mlp/"
      "down_proj/fewrow_dense/pallas_call", "lm_mlp"),
     ("while/body/closed_call/lm_head/fewrow_dense/pallas_call", "lm_head"),
+    # a prefill's tiles (PR 35): the pairs' sort, a tile's gather, its
+    # products and its scatter-add, as the compiled program names them
+    ("moe_layers/while/body/closed_call/mlp/dispatch/sort", "lm_experts"),
+    ("moe_layers/while/body/closed_call/mlp/dispatch/cumsum", "lm_experts"),
+    ("moe_layers/while/body/closed_call/mlp/experts/while/body/closed_call/"
+     "cond/branch_1_fun/while/body/gather", "lm_experts"),
+    ("moe_layers/while/body/closed_call/mlp/experts/while/body/closed_call/"
+     "cond/branch_1_fun/while/body/dot_general", "lm_experts"),
+    ("moe_layers/while/body/closed_call/mlp/experts/while/body/closed_call/"
+     "cond/branch_1_fun/while/body/scatter-add", "lm_experts"),
+    ("moe_layers/while/body/closed_call/mlp/experts/while/body/closed_call/"
+     "cond/branch_1_fun/while/body/row_scatter_add/pallas_call",
+     "lm_experts"),
 ])
 def test_the_expert_models_scopes_fall_in_their_classes(path, want):
     assert trace.classify("jit(lm_generate)/PanguUltraMoE/" + path) == want

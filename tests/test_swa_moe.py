@@ -172,6 +172,12 @@ def test_prefill_then_decode_through_both_caches_match_the_reference(
         sum(n + i + 1 for i in range(NEW)) for n in LENS[:rows]]
     assert stats["expert_pairs_dropped"] == 0
     assert stats["expert_pairs_local_prefill"].shape == (rows,)
+    # a prompt buffer of rows x PAD_TO tokens is one tile: every hit expert
+    # multiplies it whole, at most 4 blocks x 4 experts of them
+    tokens = rows * PAD_TO
+    assert tokens <= mla_moe.EXPERT_TILE
+    assert stats["expert_rows_computed_prefill"] % tokens == 0
+    assert 0 < stats["expert_rows_computed_prefill"] <= 16 * tokens
 
 
 def test_a_row_of_a_shared_execution_is_its_single_row_run(params):
@@ -330,10 +336,11 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
                                     experts_held=2)
         experts = {k: w[:, first:first + 2]
                    for k, w in full["experts"].items()}
-        part, local, hits, dropped = mla_moe._routed(
+        part, local, hits, dropped, rows = mla_moe._routed(
             share, experts, jnp.int32(1), x, chosen, weights)
         total, pairs = total + part, pairs + int(local.sum())
         assert int(dropped) == 0 and 0 <= int(hits) <= 2
+        assert int(rows) == int(hits) * 14      # 14 tokens: one tile each
     # every pair was somebody's
     assert pairs == x.shape[0] * TINY.num_experts_per_tok
     ref_lp = jax.tree_util.tree_map(ref.f32, lp)
@@ -588,6 +595,14 @@ def test_the_registry_serves_it_and_counts_keys_and_routing(
     assert 0 < got["lm.expert_hits"] <= 5 * 4 * 4
     assert got["lm.expert_pairs_dropped"] == 0
     assert 0 < got["lm.expert_pairs_local_prefill"] < 4 * 32 * 4 * 4
+    # what the prefill's experts multiplied for those pairs: never fewer
+    # rows than pairs, and at most one partly filled tile an expert held,
+    # expert block and execution beyond them
+    assert got["lm.expert_pairs_local_prefill"] \
+        <= got["lm.expert_rows_computed_prefill"] \
+        < got["lm.expert_pairs_local_prefill"] \
+        + got["lm.executions"] * TINY.moe_layers * TINY.experts_held \
+        * mla_moe.EXPERT_TILE
     assert got["lm.keys_attended_window"] == 3 * 5 * 4 * 8
     real = got["lm.prompt_tokens"]                          # of three rows
     assert got["lm.keys_attended_full"] == 5 * real + 3 * (1 + 2 + 3 + 4 + 5)

@@ -198,10 +198,14 @@ def test_prefill_flops_against_hand_counts():
     assert (products / 1e12, experts / 1e12) == (
         pytest.approx(4.34, abs=0.01), pytest.approx(0.62, abs=0.01))
     assert flops / 1e12 == pytest.approx(5.0, abs=0.05)
-    # every hit expert over all 2048 tokens, as `_routed` computes them
-    # today, is 16 x the local pairs
+    # every hit expert over all 2048 tokens, as `_routed` computed them
+    # until PR 35, is 16 x the local pairs; in tiles of `EXPERT_TILE` rows
+    # (each expert's own tokens, its last tile partly padding: at most
+    # 16 experts x 4 blocks x 127 rows over the pairs) it is under 2 x
     assert 16 * 2048 * 4 * 2 * 37_748_736 / 1e12 == pytest.approx(9.9,
                                                                   abs=0.01)
+    from comfyui_distributed_tpu.models import mla_moe
+    assert (8192 + 16 * 4 * (mla_moe.EXPERT_TILE - 1)) / 8192 < 2
 
 
 # --- the readers -------------------------------------------------------------------
