@@ -539,13 +539,14 @@ def generate(cfg: LoopLMConfig, max_new_tokens: int, params, prompt_ids,
                          jnp.argmax(logits)).astype(jnp.int32)
 
     with jax.named_scope("LoopLM"):
-        # every row's last real id at P - 1
-        prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
-        cache = empty_cache(cfg, B, P + max_new_tokens)
-        x, exits, cache = _stack(cfg, params, _embed(params, prompt_ids),
-                                 jnp.arange(P), first, cache,
-                                 use_cache=False)
-        logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+        with jax.named_scope("prefill"):
+            # every row's last real id at P - 1
+            prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+            cache = empty_cache(cfg, B, P + max_new_tokens)
+            x, exits, cache = _stack(
+                cfg, params, _embed(params, prompt_ids), jnp.arange(P),
+                first, cache, use_cache=False)
+            logits = _head(cfg, params, x[:, P - 1:])[:, 0]
 
         def step(carry, i):
             logits, exits, cache = carry
@@ -558,9 +559,10 @@ def generate(cfg: LoopLMConfig, max_new_tokens: int, params, prompt_ids,
             nxt = _head(cfg, params, x)[:, 0]
             return (nxt, nxt_exits[:, 0], cache), (token, logits, exits)
 
-        _, (tokens, logits, exits) = jax.lax.scan(
-            step, (logits, exits[:, P - 1], cache),
-            jnp.arange(max_new_tokens))
+        with jax.named_scope("decode"):
+            _, (tokens, logits, exits) = jax.lax.scan(
+                step, (logits, exits[:, P - 1], cache),
+                jnp.arange(max_new_tokens))
     return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
             exits.swapaxes(0, 1))
 

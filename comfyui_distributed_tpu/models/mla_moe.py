@@ -705,14 +705,15 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
                          jnp.argmax(logits)).astype(jnp.int32)
 
     with jax.named_scope("PanguUltraMoE"):
-        # every row's last real id at P - 1
-        prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
-        cache = empty_cache(cfg, B, P + max_new_tokens)
-        x, cache, routed, counts = _stack(
-            cfg, params, _embed(params, prompt_ids), jnp.arange(P), first,
-            cache, absorbed=False)
-        prefill_pairs, _, dropped, prefill_rows = counts
-        logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+        with jax.named_scope("prefill"):
+            # every row's last real id at P - 1
+            prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+            cache = empty_cache(cfg, B, P + max_new_tokens)
+            x, cache, routed, counts = _stack(
+                cfg, params, _embed(params, prompt_ids), jnp.arange(P),
+                first, cache, absorbed=False)
+            prefill_pairs, _, dropped, prefill_rows = counts
+            logits = _head(cfg, params, x[:, P - 1:])[:, 0]
 
         def step(carry, i):
             logits, routed, cache, counts = carry
@@ -728,10 +729,11 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
                 (token, logits, *routed)
 
         zero = jnp.int32(0)
-        (*_, counts), (tokens, logits, scores, choices) = jax.lax.scan(
-            step, (logits, tuple(r[:, P - 1] for r in routed), cache,
-                   (jnp.zeros((B,), jnp.int32), zero, dropped)),
-            jnp.arange(max_new_tokens))
+        with jax.named_scope("decode"):
+            (*_, counts), (tokens, logits, scores, choices) = jax.lax.scan(
+                step, (logits, tuple(r[:, P - 1] for r in routed), cache,
+                       (jnp.zeros((B,), jnp.int32), zero, dropped)),
+                jnp.arange(max_new_tokens))
     pairs, hits, dropped = counts
     return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
             {"router_scores": scores.swapaxes(0, 1),

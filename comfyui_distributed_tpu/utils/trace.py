@@ -37,8 +37,9 @@ Here profiling is a first-class subsystem:
 
 Telemetry never touches traced code paths: spans and histograms are pure
 host-side Python around (never inside) the jitted programs, so tracing-on
-vs tracing-off must show zero retrace delta (``bench.py --phase
-observability`` proves the overhead stays within noise).
+vs tracing-off must show zero retrace delta (the no-retrace guard of
+``tests/conftest.py``; what a device trace costs while it is on is the
+chip benchmark's to measure: ``PERF.md`` §6).
 """
 
 from __future__ import annotations
@@ -202,15 +203,6 @@ class PhaseStats:
 GLOBAL_PHASES = PhaseStats()
 
 
-@contextmanager
-def phase(name: str):
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        GLOBAL_PHASES.record(name, time.perf_counter() - t0)
-
-
 # --- names on the profiler's clock -------------------------------------------
 #
 # Two kinds of name reach a device trace.  The operations carry theirs
@@ -275,8 +267,9 @@ KERNEL_CLASSES = (
     ("lm_head", _LM, r"lm_head|early_exit_gate|sample"),
     ("embed", _LM, r"embed_tokens"),
     # the layer scan's and the program's own glue (residual adds, the
-    # slice that picks the prompt's last row)
-    ("lm_proj", _LM, r"layers|LoopLM"),
+    # slice that picks the prompt's last row); the two phase scopes
+    # (PHASES) are glue in every family: `phase_of` reads them
+    ("lm_proj", _LM, r"layers|prefill|decode|LoopLM"),
     # the latent-attention decoder with routed experts (models/mla_moe.py),
     # under the same classes, and one more: ``lm_experts`` is everything
     # routing adds (the router, the dispatch, the routed experts held
@@ -294,11 +287,11 @@ KERNEL_CLASSES = (
     ("lm_mlp", _MOE, r"mlp|shared_experts|gate_proj|up_proj|down_proj"),
     ("lm_head", _MOE, r"lm_head|sample"),
     ("embed", _MOE, r"embed_tokens"),
-    ("lm_proj", _MOE, r"dense_layers|moe_layers|PanguUltraMoE"),
+    ("lm_proj", _MOE, r"dense_layers|moe_layers|prefill|decode"
+                      r"|PanguUltraMoE"),
     # the decoder with window and full attention layers and routed experts
     # (models/swa_moe.py), under the same classes; its expert layer IS the
-    # one above, so the same four names are ``lm_experts``.  The two
-    # phase scopes (PHASES) are glue here: `phase_of` reads them
+    # one above, so the same four names are ``lm_experts``
     ("lm_norm", _SWA, r"post_(?:attention|feedforward)_layernorm"
                       r"|[qk]_norm|final_norm"),
     ("lm_proj", _SWA, r"[qkvo]_proj"),
@@ -312,7 +305,11 @@ KERNEL_CLASSES = (
 )
 # the outer scopes a program may put directly under its model's: where it
 # does, a trace summary gives its seconds by PHASE beside its seconds by
-# class (models/swa_moe.py does; a program without them has no phases)
+# class (the three language models' ``generate`` do; the denoise, VAE and
+# text programs do not and have no phases).  A program that the
+# persistent compile cache LOADS carries the names of the tree that
+# compiled it (JAX keys a program without a Pallas kernel with its debug
+# info stripped): a scope named later shows once this tree has compiled
 PHASES = ("prefill", "decode")
 # the denoise programs' own operations under no module (CFG combine,
 # solver update, noise): ``core`` / ``step`` are the functions
@@ -384,8 +381,8 @@ def _end_annotation(ann) -> None:
 # (queue_wait / coalesced_batch / compute / d2h / encode / upload).
 # Separate from GLOBAL_PHASES so /distributed/metrics can expose the
 # pipeline timeline as its own coherent block: stage totals here overlap
-# in wall-clock (that is the point), so summing them against a run's
-# wall time yields the device-idle-fraction estimate bench.py reports.
+# in wall-clock (that is the point): what the device's idle time lay
+# under is read from a device trace (``trace_summary.py``), not from them.
 GLOBAL_STAGES = PhaseStats()
 
 
@@ -511,7 +508,7 @@ class CounterStats:
 
 # coalesced_batches / coalesced_prompts / exec_runs / wire_tensor_msgs /
 # wire_png_msgs / wire_bytes ... — the scheduler and wire layers bump,
-# /distributed/metrics and bench.py --phase pipeline read
+# /distributed/metrics reads (``pipeline.counters``)
 GLOBAL_COUNTERS = CounterStats()
 
 # Attention call sites by the path each took (``fused``, ``xla_whole``,

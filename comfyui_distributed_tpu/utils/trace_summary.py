@@ -27,7 +27,29 @@ annotations.  The summary holds, per chip and as a mean over chips:
   carry a phase (``trace.PHASES``: a scope right under the model's),
   ``phases`` has the same leaf-operation seconds by phase (without the
   gaps, and without what lies under no phase); a program without such a
-  scope has no ``phases`` key;
+  scope has no ``phases`` key.  Beside them ``account``, in which every
+  nanosecond of a whole execution has exactly ONE owner.  (``classes``
+  has not: it takes an operation for a container wherever another event
+  begins inside it, so one that overlaps the next in part, or has the
+  zero-duration custom call that marks a prefetch at its own start, is
+  left out whole and its seconds read as ``gaps``.)  Its operations are
+  the events of a duration that wholly contain no other (`_operations`).
+  ``by_class``: the EXCLUSIVE seconds per execution by class (where two
+  overlap the instant is the one's that began first, a tie the
+  longer's), with ``other``, and with ``idle`` for the time in which
+  nothing ran, so the rows add up to the execution's seconds by
+  construction; ``overlap_s``: what a sum of those operations' durations
+  counts twice; ``dropped_s``: the durations of those ``classes`` leaves
+  out (``classes["gaps"] + overlap_s - dropped_s == by_class["idle"]``);
+  ``by_phase`` (only where the paths carry a phase): the same seconds as
+  ``{phase: {class: s}}`` with ``none`` for what lies under no phase, an
+  idle stretch under the phase of the operation that ENDS it (the device
+  was waiting to start that; the stretch after the last operation is
+  ``none``'s), so a phase's rows add up to its wall seconds and the
+  phases to the execution's; ``top_idle``: the idle stretches that cost
+  most, by the two operations on either side (``before``, ``after``:
+  paths, else HLO names; stretches between the same two are one row),
+  ``n`` stretches and ``s`` seconds an execution;
 * ``idle``: the seconds *between* program executions in which no
   operation ran, by the innermost ``dtpu/`` host span over each gap
   (``none`` under none), and ``idle_under``: the same seconds under each
@@ -54,9 +76,11 @@ MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 EDGE_NS = 10_000        # an execution this close to the slice's edge is cut
 _MODULE_ID = re.compile(r"\(\d+\)$")
-GAPS, NONE = "gaps", "none"
+GAPS, NONE, IDLE = "gaps", "none", "idle"
 OP_NAME = "tf_op"       # the event-metadata statistic that holds op_name
 TOP_OTHER = 8           # the costliest unclassed operations a program lists
+TOP_IDLE = 8            # and the costliest idle stretches inside it
+STARTS, ENDS = "(the execution starts)", "(the execution ends)"
 
 
 # --- the event metadata, straight from the protobuf ---------------------------
@@ -304,6 +328,130 @@ def _idle_by_span(gap_s, gap_e, spans):
     return by_inner, by_under
 
 
+def _owners(start, end, floor):
+    """One owner an instant.  For operations sorted by (start, the longer
+    first), each with the start of the execution it lies in (``floor``):
+    the nanoseconds each OWNS (from where every operation that began
+    before it has ended: the instant is the one's that began first), the
+    idle nanoseconds in front of it inside its execution, the position of
+    the operation that idle stretch follows (-1: the execution's start),
+    and how far the operations up to each reach."""
+    reach = np.maximum.accumulate(end)
+    prev = np.concatenate(([np.iinfo(np.int64).min], reach[:-1]))
+    own = np.maximum(end - np.maximum(start, prev), 0)
+    wait = np.maximum(start - np.maximum(prev, floor), 0)
+    holder = np.maximum.accumulate(
+        np.where(end >= reach, np.arange(len(end)), 0))
+    before = np.concatenate(([-1], holder[:-1]))
+    before[prev <= floor] = -1
+    return own, wait, before, reach
+
+
+def _operations(start, dur):
+    """Mask of the events that themselves run on the device: of a duration,
+    and wholly containing no other such event (a ``while`` contains its
+    body's).  `_leaves` asks less: there an operation with a zero-duration
+    event at its own start (the custom call that marks a prefetch) reads
+    as a container, and two that overlap in part as one holding the
+    other; here both are operations, and `_owners` says whose an instant
+    is."""
+    real = np.flatnonzero(dur > 0)
+    order = real[np.lexsort((-dur[real], start[real]))]
+    s, e = start[order], start[order] + dur[order]
+    holds_next = np.zeros(len(s), bool)
+    holds_next[:-1] = (s[1:] < e[:-1]) & (e[1:] <= e[:-1])
+    mask = np.zeros(len(start), bool)
+    mask[order] = ~holds_next
+    return mask
+
+
+def _accounts(ops, labels, classes, phases, leaf, modules, programs):
+    """``account`` of every program of ``programs`` (module docstring):
+    the operations of the whole executions swept once in the order they
+    began, then summed by program, class and phase.  ``labels``,
+    ``classes`` and ``phases`` are per operation NAME; ``leaf`` is
+    `_leaves`' mask, which ``classes`` sums: what it leaves out of the
+    operations here is ``dropped_s``."""
+    s, d, idx = ops
+    ms, md, whole, prog_of_module = modules
+    o = np.flatnonzero(_operations(s, d))
+    o = o[np.lexsort((-d[o], s[o]))]
+    ex = np.searchsorted(ms, s[o], "right") - 1
+    keep = (ex >= 0) & (s[o] < (ms + md)[np.maximum(ex, 0)]) \
+        & whole[np.maximum(ex, 0)]
+    o, ex = o[keep], ex[keep]
+    name, dur = idx[o], d[o]
+    floor, roof = ms[ex], (ms + md)[ex]
+    own, wait, before, reach = _owners(s[o], np.minimum(s[o] + dur, roof),
+                                       floor)
+    # the stretch after an execution's last operation: the whole of an
+    # execution that holds none
+    last = np.flatnonzero(np.diff(ex, append=len(ms)) != 0)
+    tail = md.copy()
+    tail[ex[last]] = roof[last] - reach[last]
+    cls_names, cls_code = np.unique(classes, return_inverse=True)
+    # "" (no phase) first, whether or not an operation lies under none
+    ph_names, ph_code = np.unique(np.append(phases, ""), return_inverse=True)
+    cc, ph = cls_code[name], ph_code[name]
+    prog_names, prog_code = np.unique(prog_of_module, return_inverse=True)
+    pc = prog_code[ex]
+    labels = list(labels) + [ENDS, STARTS]    # [-1] is STARTS
+    width = len(labels)
+    for code, prog in enumerate(prog_names):
+        row = programs.get(str(prog))
+        if row is None:
+            continue
+        mine = pc == code
+        runs = whole & (prog_code == code)
+        per = 1e9 * row["count"]
+        tail_ns = tail[runs].sum()
+        by_class = np.bincount(cc[mine], weights=own[mine],
+                               minlength=len(cls_names))
+        account: Dict[str, Any] = {"by_class": {
+            str(c): float(ns) / per
+            for c, ns in zip(cls_names, by_class) if ns}}
+        account["by_class"][IDLE] = float(wait[mine].sum() + tail_ns) / per
+        account["overlap_s"] = float(dur[mine].sum() - own[mine].sum()) / per
+        account["dropped_s"] = float(dur[mine & ~leaf[o]].sum()) / per
+        if ph[mine].any():
+            grid = np.bincount(ph[mine] * len(cls_names) + cc[mine],
+                               weights=own[mine],
+                               minlength=len(ph_names) * len(cls_names)
+                               ).reshape(len(ph_names), len(cls_names))
+            waits = np.bincount(ph[mine], weights=wait[mine],
+                                minlength=len(ph_names))
+            waits[0] += tail_ns
+            account["by_phase"] = {}
+            for phase, sums, idle_ns in zip(ph_names, grid, waits):
+                rows = {str(c): float(ns) / per
+                        for c, ns in zip(cls_names, sums) if ns}
+                if idle_ns:
+                    rows[IDLE] = float(idle_ns) / per
+                if rows:
+                    account["by_phase"][str(phase) or NONE] = rows
+        # the idle stretches, by the two operations on either side
+        front = np.flatnonzero(mine & (wait > 0))
+        ends = last[mine[last] & (tail[ex[last]] > 0)]
+        # an execution that holds no operation is one stretch, end to end
+        empty = runs.copy()
+        empty[ex[mine]] = False
+        pair = np.concatenate((
+            np.where(before[front] < 0, -1, name[before[front]]) * width
+            + name[front],
+            name[ends] * width + width - 2,
+            np.full(int(empty.sum()), -2)))
+        ns = np.concatenate((wait[front], tail[ex[ends]], tail[empty]))
+        pairs, inv = np.unique(pair, return_inverse=True)
+        sec = np.bincount(inv, weights=ns, minlength=len(pairs))
+        n = np.bincount(inv, minlength=len(pairs))
+        account["top_idle"] = [
+            {"s": float(sec[i]) / per, "n": float(n[i]) / row["count"],
+             "before": labels[pairs[i] // width],
+             "after": labels[pairs[i] % width]}
+            for i in np.argsort(-sec, kind="stable")[:TOP_IDLE]]
+        row["account"] = account
+
+
 def _chip(plane: dict, spans) -> Dict[str, Any]:
     lines = {ln["name"]: ln for ln in plane["lines"]}
     chip: Dict[str, Any] = {"busy_s": 0.0, "window_s": 0.0, "ops": 0,
@@ -364,6 +512,9 @@ def _chip(plane: dict, spans) -> Dict[str, Any]:
         row["total_s"] += md[m] / 1e9
     names_of = np.asarray([_MODULE_ID.sub("", n)
                            for n in mod_ln["names"]])
+    # an operation's name: the path where there is one, else the HLO
+    # instruction as the trace prints it
+    labels = [p or n[:120] for p, n in zip(paths, ops_ln["names"])]
     sel = inside & whole[np.maximum(k, 0)]
     prog_of_op = names_of[midx[np.maximum(k, 0)]]
     for name, row in programs.items():
@@ -379,15 +530,15 @@ def _chip(plane: dict, spans) -> Dict[str, Any]:
                     / row["count"] for ph in np.unique(lphase[mine]) if ph}
         if by_phase:
             row["phases"] = by_phase
-        # what ``other`` holds, by name: the path where there is one,
-        # else the HLO instruction as the trace prints it
+        # what ``other`` holds, by name
         unclassed = mine & (lclass == OTHER)
         sec = np.bincount(lidx[unclassed], weights=ld[unclassed],
                           minlength=len(paths)) / 1e9 / row["count"]
         row["top_other"] = [
-            {"op": paths[i] or ops_ln["names"][i][:120],
-             "s": float(sec[i])}
+            {"op": labels[i], "s": float(sec[i])}
             for i in np.argsort(-sec)[:TOP_OTHER] if sec[i] > 0]
+    _accounts((s, d, idx), labels, classes, phases, leaf,
+              (ms, md, whole, names_of[midx]), programs)
     chip["programs"] = programs
     return chip
 
@@ -424,6 +575,17 @@ def summarize(events: dict, traced_s: float = 0.0) -> Dict[str, Any]:
             "top_other": rows[0]["top_other"]}
         if all("phases" in r for r in rows):
             programs[name]["phases"] = _mean([r["phases"] for r in rows])
+        accounts = [r["account"] for r in rows]
+        programs[name]["account"] = account = {
+            "by_class": _mean([a["by_class"] for a in accounts]),
+            "overlap_s": sum(a["overlap_s"] for a in accounts) / len(rows),
+            "dropped_s": sum(a["dropped_s"] for a in accounts) / len(rows),
+            "top_idle": accounts[0]["top_idle"]}
+        if all("by_phase" in a for a in accounts):
+            account["by_phase"] = {
+                phase: _mean([a["by_phase"].get(phase, {}) for a in accounts])
+                for phase in sorted({p for a in accounts
+                                     for p in a["by_phase"]})}
     # a slice that starts or ends in an idle gap holds no device event
     # there: the time the profiler was on still counts as idle
     window_s = max(max(c["window_s"] for c in chips), float(traced_s))

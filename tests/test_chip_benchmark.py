@@ -198,8 +198,12 @@ def _exaone4_reports(m, cells):
     readers of its own."""
     exaone = cells[EXAONE4]
     assert exaone["config"] == "k-exaone-236b-expand-sd15-512"
+    # (and PR 38's two readers of the prefill by class: only this
+    # cell's prefill weighs)
     assert _listed(m, EXAONE4) - _listed(m, PANGU4) == {
-        "lm_prefill_device_s_per_request", *_EXAONE_ONLY}
+        "lm_prefill_device_s_per_request", *_EXAONE_ONLY,
+        "lm_prefill_attn_device_s_per_request",
+        "lm_prefill_experts_device_s_per_request"}
     assert _listed(m, PANGU4) - _listed(m, EXAONE4) == {
         "lm_moe_decode_hbm_roofline_pct"}
     for x in m["per_layer"]:

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import layers, mla_moe, registry, swa_moe
+from comfyui_distributed_tpu.models import layers, looplm, mla_moe, \
+    registry, swa_moe
 from comfyui_distributed_tpu.models.swa_moe import FULL, SLIDING
 from comfyui_distributed_tpu.utils import trace
 
@@ -475,15 +476,65 @@ def compiled_text(params):
         np.zeros(4, np.uint32), np.zeros(4, np.float32)).compile().as_text()
 
 
+def _four_rows(program, params):
+    return program.lower(
+        params, jnp.zeros((4, 16), jnp.int32), np.zeros(4, np.int32) + 9,
+        np.zeros(4, np.uint32), np.zeros(4, np.float32)).compile().as_text()
+
+
+def _exaone(request):
+    cfg, p = of_dtype("float32")
+    served, _ = serve_rows(cfg, p, LENS[:1])
+    return (request.getfixturevalue("compiled_text"), LM_CLASSES_WITH_EXPERTS,
+            compare(cfg, p, served[0]))
+
+
+def _pangu(request):
+    import test_mla_moe as t
+    cfg = dataclasses.replace(t.TINY, dtype=jnp.dtype("float32"))
+    p = mla_moe.seeded_params(cfg, np.uint32(7))
+    return (_four_rows(mla_moe.make_program(cfg, 3), p),
+            LM_CLASSES_WITH_EXPERTS, t.compare(cfg, p, t.serve(cfg, p)))
+
+
+def _ouro(request):
+    import test_looplm as t
+    p = t.make_params(t.TINY)
+    tokens, logits, _ = t.serve(t.TINY, p, t.prompt())
+    want, _ = t.reference_rows(t.TINY, p, t.prompt(), tokens)
+    return (_four_rows(looplm.make_program(t.TINY, 3), p),
+            LM_CLASSES_WITH_EXPERTS - {"lm_experts"},
+            t.verify.compare_logits(logits, want, tokens,
+                                    t.verify.LIMITS_FP32))
+
+
+LM_CLASSES_WITH_EXPERTS = {"lm_proj", "lm_attn", "lm_cache", "lm_mlp",
+                           "lm_experts", "lm_norm", "lm_head", "embed"}
+
+
+@pytest.mark.parametrize("model, family", [
+    ("ExaoneMoe", _exaone), ("PanguUltraMoE", _pangu), ("LoopLM", _ouro)])
 def test_every_class_and_both_phases_are_in_the_compiled_program(
-        compiled_text):
-    names = [n for n in re.findall(r'op_name="([^"]+)"', compiled_text)
-             if "ExaoneMoe" in n]
-    classes = {trace.classify(n) for n in names}
-    assert classes >= {"lm_proj", "lm_attn", "lm_cache", "lm_mlp",
-                       "lm_experts", "lm_norm", "lm_head", "embed"}
-    assert trace.OTHER not in classes
-    assert {trace.phase_of(n) for n in names} >= {"prefill", "decode"}
+        model, family, request):
+    """Every language model's ``lm_generate`` carries ``prefill`` and
+    ``decode`` RIGHT under its model's scope (PR 34: K-EXAONE's; PR 38:
+    Ouro's and openPangu's).  The scopes are names and nothing else: an
+    operation under a phase falls in the class its path without the
+    phase falls in, and the tiny program's ids and logits are the plain
+    float32 reference's."""
+    text, classes, against_reference = family(request)
+    names = [n for n in re.findall(r'op_name="([^"]+)"', text) if model in n]
+    assert len(names) > 200
+    assert {trace.classify(n) for n in names} == classes
+    assert {trace.phase_of(n) for n in names} == {"prefill", "decode"}
+    for n in names:
+        segments = n.split("/")
+        at = segments.index(model)
+        assert segments[at + 1] in trace.PHASES, n
+        bare = "/".join(segments[:at + 1] + segments[at + 2:])
+        assert trace.phase_of(bare) is None
+        assert trace.classify(n) == trace.classify(bare), n
+    assert against_reference["correct"], against_reference
 
 
 def test_a_decode_step_copies_no_cache(compiled_text):

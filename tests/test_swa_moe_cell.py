@@ -373,12 +373,14 @@ def test_a_programs_seconds_by_phase_stand_beside_its_seconds_by_class():
 
 
 def test_a_program_without_phase_scopes_gets_no_phases():
-    """Ouro's and openPangu's programs are not touched: their summary
-    rows have the keys they had."""
+    """A program whose paths carry no phase (the denoise, the VAE, the
+    text encoders; a language model's from before PR 38): its summary row
+    has no ``phases`` and its account no ``by_phase``."""
     from comfyui_distributed_tpu.utils import trace_summary
     got = trace_summary.summarize(_events(False))["programs"]
     assert set(got["jit_lm_generate"]) == {"count", "mean_s", "classes",
-                                           "top_other"}
+                                           "top_other", "account"}
+    assert "by_phase" not in got["jit_lm_generate"]["account"]
     assert got["jit_lm_generate"]["classes"]["lm_experts"] \
         == pytest.approx(400e-6)
 

@@ -125,6 +125,21 @@ UNET = "jit(core)/while/body/closed_call/UNet/"
     ("jit(lm_generate)/PanguUltraMoE/while/body/closed_call/moe_layers/"
      "while/body/closed_call/mlp/shared_experts/down_proj/fewrow_dense/"
      "pallas_call", "lm_mlp"),
+    # an operation directly under a phase scope (PR 38: every language
+    # model has them) is the program's glue, as one directly under the
+    # model's scope is
+    ("jit(lm_generate)/LoopLM/prefill/dynamic_slice", "lm_proj"),
+    ("jit(lm_generate)/LoopLM/decode/while", "lm_proj"),
+    ("jit(lm_generate)/PanguUltraMoE/prefill/jit(_roll_static)/concatenate",
+     "lm_proj"),
+    ("jit(lm_generate)/PanguUltraMoE/decode/while/body/closed_call/add",
+     "lm_proj"),
+    ("jit(lm_generate)/ExaoneMoe/prefill/dynamic_slice", "lm_proj"),
+    # and the innermost module still decides under one
+    ("jit(lm_generate)/LoopLM/decode/while/body/closed_call/layers/while/"
+     "body/closed_call/mlp/down_proj/fewrow_dense/pallas_call", "lm_mlp"),
+    ("jit(lm_generate)/PanguUltraMoE/prefill/moe_layers/while/body/mlp/"
+     "experts/while/body/cond/branch_1_fun/dot_general", "lm_experts"),
     # nothing of ours
     ("jit(<lambda>)/jit(<lambda>)/mul", "other"),
     ("reduce_sum", "other"), ("", "other"),
@@ -449,6 +464,196 @@ def test_summary_without_names_says_so_and_counts_the_profilers_time():
     assert s["window_s"] == pytest.approx(500e-6)
     assert s["idle"]["slice_edge"] == pytest.approx(50e-6)
     assert ts.summarize({"planes": []})["chips"] == []
+
+
+# --- 4b. one owner an instant: the account (PR 38) -------------------------
+
+LM = "jit(lm_generate)/ExaoneMoe/"
+
+
+def accounted_events():
+    """One whole execution of a language model with phases, 100-300 us:
+    two operations that overlap in part (fusion.1 110-150, fusion.2
+    140-170) under ``prefill``; an idle stretch at the phase's edge
+    (170-180); a ``while`` (180-280) that holds fusion.3 (180-220) and
+    fusion.4 (230-280), which has the zero-duration custom call that marks
+    a prefetch at its own start; a copy under no phase (285-290).  And one
+    whole execution of a denoise, whose paths carry no phase."""
+    k = 1000
+    return {"planes": [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops",
+         "names": ["pad.0", "fusion.1", "fusion.2", "while.1", "fusion.3",
+                   "fusion.4", "custom-call.9", "copy.5", "fusion.6"],
+         "paths": ["jit(pad)/pad:",
+                   LM + "prefill/moe_layers/while/body/self_attn/q_proj/"
+                   "dot_general:",
+                   LM + "prefill/moe_layers/while/body/mlp/experts/"
+                   "dot_general:",
+                   LM + "decode/while",
+                   LM + "decode/while/body/dense_layers/while/body/mlp/"
+                   "gate_proj/dot_general:",
+                   LM + "decode/while/body/moe_layers/while/body/self_attn/"
+                   "bnhd,bmhd->bhnm/dot_general:",
+                   "", "",
+                   UNET + "mid_attn/blocks_0/attn1/bnhd,bmhd->bhnm/"
+                   "dot_general:"],
+         "name_idx": [0, 1, 2, 3, 4, 5, 6, 7, 8, 0],
+         "start_ns": [0, 110 * k, 140 * k, 180 * k, 180 * k, 230 * k,
+                      230 * k, 285 * k, 410 * k, 500 * k],
+         "dur_ns": [20 * k, 40 * k, 30 * k, 100 * k, 40 * k, 50 * k, 0,
+                    5 * k, 30 * k, 20 * k]},
+        {"name": "XLA Modules",
+         "names": ["jit_pad(1)", "jit_lm_generate(3)", "jit_core(7)",
+                   "jit_pad(2)"],
+         "name_idx": [0, 1, 2, 3],
+         "start_ns": [0, 100 * k, 400 * k, 500 * k],
+         "dur_ns": [20 * k, 200 * k, 50 * k, 20 * k]}]}]}
+
+
+def us(x):
+    return pytest.approx(x * 1e-6)
+
+
+def test_every_nanosecond_of_an_execution_has_one_owner():
+    s = ts.summarize(accounted_events())
+    lm = s["programs"]["jit_lm_generate"]
+    account = lm["account"]
+    # the instant two operations share is the one's that began first; the
+    # while is no operation, the marker owns nothing; fusion.4 counts
+    assert account["by_class"] == {
+        "lm_proj": us(40), "lm_experts": us(20), "lm_mlp": us(40),
+        "lm_attn": us(50), "other": us(5), "idle": us(45)}
+    assert sum(account["by_class"].values()) == pytest.approx(lm["mean_s"])
+    # ``classes`` keeps its meaning: an operation inside which another
+    # event begins is a container there, so fusion.1 (the overlap) and
+    # fusion.4 (the marker) are left out and read as gaps
+    assert lm["classes"] == {"lm_experts": us(30), "lm_mlp": us(40),
+                             "other": us(5), "gaps": us(125)}
+    assert lm["phases"] == {"prefill": us(30), "decode": us(40)}
+    assert account["overlap_s"] == us(10) and account["dropped_s"] == us(90)
+    assert lm["classes"]["gaps"] + account["overlap_s"] \
+        - account["dropped_s"] == pytest.approx(account["by_class"]["idle"])
+    # an idle stretch is the phase's whose operation ENDS it; the stretch
+    # after the last operation is nobody's: each phase's rows add up to
+    # its wall seconds (100-170, 170-280, 280-300), the phases to the whole
+    assert account["by_phase"] == {
+        "prefill": {"lm_proj": us(40), "lm_experts": us(20), "idle": us(10)},
+        "decode": {"lm_mlp": us(40), "lm_attn": us(50), "idle": us(20)},
+        "none": {"other": us(5), "idle": us(15)}}
+    wall = {p: sum(r.values()) for p, r in account["by_phase"].items()}
+    assert wall == {"prefill": us(70), "decode": us(110), "none": us(20)}
+    assert sum(wall.values()) == pytest.approx(lm["mean_s"])
+    # the stretches by the operations on either side, the costliest first
+    # (a tie in the order they began)
+    paths = accounted_events()["planes"][0]["lines"][0]["paths"]
+    assert account["top_idle"] == [
+        {"s": us(10), "n": 1.0, "before": ts.STARTS, "after": paths[1]},
+        {"s": us(10), "n": 1.0, "before": paths[2], "after": paths[4]},
+        {"s": us(10), "n": 1.0, "before": paths[4], "after": paths[5]},
+        {"s": us(10), "n": 1.0, "before": "copy.5", "after": ts.ENDS},
+        {"s": us(5), "n": 1.0, "before": paths[5], "after": "copy.5"}]
+    # a program whose paths carry no phase has none in its account either
+    core = s["programs"]["jit_core"]
+    assert "phases" not in core and "by_phase" not in core["account"]
+    assert core["account"]["by_class"] == {"attn_self": us(30),
+                                           "idle": us(20)}
+    assert core["account"]["overlap_s"] == core["account"]["dropped_s"] == 0
+    assert [(r["before"], r["after"]) for r in core["account"]["top_idle"]] \
+        == [(ts.STARTS, paths[8]), (paths[8], ts.ENDS)]
+
+
+def test_the_account_is_a_mean_over_the_chips_that_saw_the_program_whole():
+    ev = accounted_events()
+    second = json.loads(json.dumps(ev["planes"][0]))
+    second["name"] = "/device:TPU:1"
+    ops = second["lines"][0]
+    ops["dur_ns"][2] = 50_000           # fusion.2 to 190: no idle at 170-180
+    ev["planes"].append(second)
+    s = ts.summarize(ev)
+    lm = s["programs"]["jit_lm_generate"]
+    one, two = (c["programs"]["jit_lm_generate"]["account"]
+                for c in s["chips"])
+    assert two["by_class"]["lm_experts"] == us(40)      # 150-190
+    # fusion.3 began at 180, inside fusion.2: it owns from 190 on
+    assert two["by_class"]["lm_mlp"] == us(30)
+    assert two["by_phase"]["decode"]["idle"] == us(10)
+    for key in ("lm_experts", "lm_mlp", "idle"):
+        assert lm["account"]["by_class"][key] == pytest.approx(
+            (one["by_class"][key] + two["by_class"][key]) / 2)
+    assert lm["account"]["by_phase"]["decode"]["idle"] == us(15)
+    assert lm["account"]["overlap_s"] == us((10 + 20) / 2)
+    assert sum(lm["account"]["by_class"].values()) \
+        == pytest.approx(lm["mean_s"])
+    # a chip that did not see the program whole leaves no row at all
+    ev["planes"][1]["lines"][1]["start_ns"][1] = 5_000
+    ev["planes"][1]["lines"][1]["dur_ns"][1] = 295_000
+    assert "jit_lm_generate" not in ts.summarize(ev)["programs"]
+
+
+def test_an_execution_that_holds_no_operation_is_idle_from_end_to_end():
+    ev = accounted_events()
+    ops = ev["planes"][0]["lines"][0]
+    for key in ("name_idx", "start_ns", "dur_ns"):
+        del ops[key][8]                 # fusion.6, the denoise's one
+    core = ts.summarize(ev)["programs"]["jit_core"]
+    assert core["account"]["by_class"] == {"idle": us(50)}
+    assert core["account"]["top_idle"] == [
+        {"s": us(50), "n": 1.0, "before": ts.STARTS, "after": ts.ENDS}]
+
+
+@pytest.mark.parametrize("recorded, pinned", [
+    ("tpu_v5e_unet_block_scan.xplane.pb",
+     "tpu_v5e_unet_block_scan.summary_pr35.json"),
+    ("sd15_512_one_request.events.json.gz",
+     "sd15_512_one_request.summary_pr35.json")])
+def test_what_was_there_reads_as_the_parents_summary_to_the_digit(
+        recorded, pinned):
+    """``classes``, ``phases``, ``top_other``, ``idle``, ``idle_under`` of
+    a recorded chip slice and of the benchmark's recorded request (PR 22's
+    SD1.5: 88,502 operations, no paths) are what PR 35's ``summarize``
+    gave (pinned then), and every program's account adds up."""
+    if recorded.endswith(".pb"):
+        events, traced_s = ts.read_events(os.path.join(DATA, recorded)), 0.004
+    else:
+        import gzip
+        with gzip.open(os.path.join(REPO, "benchmarks", "chip", "testdata",
+                                    recorded), "rt") as f:
+            events, traced_s = json.load(f), 0.0
+    s = ts.summarize(events, traced_s)
+    with open(os.path.join(DATA, pinned), encoding="utf-8") as f:
+        want = json.load(f)
+    got = {"programs": {n: {k: v for k, v in p.items() if k != "account"}
+                        for n, p in s["programs"].items()},
+           **{k: s[k] for k in want if k != "programs"}}
+    assert json.loads(json.dumps(got)) == want
+    for name, program in s["programs"].items():
+        account = program["account"]
+        assert abs(sum(account["by_class"].values()) - program["mean_s"]) \
+            < 1e-12, name
+        assert program["classes"]["gaps"] + account["overlap_s"] \
+            - account["dropped_s"] == pytest.approx(
+                account["by_class"]["idle"], abs=1e-12), name
+        assert account["overlap_s"] >= 0 and account["dropped_s"] >= 0
+
+
+def test_what_gaps_held_in_the_recorded_request_was_operations_not_idle():
+    """PR 22's recorded SD1.5 denoise: 341 fusions have the zero-duration
+    custom call of a prefetch at their own start nanosecond, so
+    ``classes`` leaves 0.016 s of them out and calls it ``gaps``; the
+    device stood idle inside the execution for 0.0005 s."""
+    import gzip
+    with gzip.open(os.path.join(REPO, "benchmarks", "chip", "testdata",
+                                "sd15_512_one_request.events.json.gz"),
+                   "rt") as f:
+        core = ts.summarize(json.load(f))["programs"]["jit_core"]
+    assert core["classes"]["gaps"] == pytest.approx(0.016442827, abs=1e-9)
+    assert core["account"]["dropped_s"] == pytest.approx(0.015984444,
+                                                         abs=1e-9)
+    assert core["account"]["by_class"]["idle"] == pytest.approx(
+        0.000458383, abs=1e-9)
+    assert core["account"]["overlap_s"] == 0.0
+    top = core["account"]["top_idle"][0]
+    assert top["n"] == 180.0 and "dynamic_slice.83" in top["before"]
 
 
 RECORDED = os.path.join(DATA, "tpu_v5e_unet_block_scan.xplane.pb")
