@@ -15,7 +15,8 @@ upscale ``workflows/distributed-upscale.json`` as shipped (512 -> 2048,
 nothing is downloaded.  After the server has exited, a second child
 compiles the Pallas flash-attention kernel (``interpret=False``) at the
 shapes the UNets' attention rule sends it and holds its error against an
-fp32 oracle to ``xla_attention``'s.
+fp32 oracle to ``xla_attention``'s, then the fused GEGLU kernel at the
+UNets' five feed-forward shapes against the float32 expression.
 
 This process never imports JAX: a chip belongs to one process at a time,
 and a parent that touched JAX would hold it.  It talks to the server
@@ -93,6 +94,24 @@ KERNEL_SHAPES_REHEARSAL = (((1, 200, 2, 16), 200), ((2, 64, 2, 16), 77))
 # the fp32 rehearsal, where both errors are a few ulps.
 KERNEL_ERR_RATIO = 1.25
 KERNEL_ERR_FLOOR = 4e-6
+
+# x [B, T, c] against ``proj [c, 8c]`` at a CFG-stacked batch of 2: the
+# GEGLU call sites of the two benchmarked UNets, each a shape
+# `models/layers.py:geglu_path` sends to the fused kernel on a TPU
+GEGLU_SHAPES = (
+    (2, 4096, 640), (2, 1024, 1280),                    # SDXL
+    (2, 4096, 320), (2, 1024, 640), (2, 256, 1280),     # SD1.5
+)
+GEGLU_SHAPES_REHEARSAL = ((2, 64, 128),)
+# The fused GEGLU against ``a * gelu(b, approximate=False)`` of the same
+# bf16 operands in float32 at the highest matmul precision: max |diff|
+# over max |ref| at most one bf16 ulp of the largest element (2^-8).  The
+# kernel rounds ONCE, the gated fp32 accumulators to bf16: half an ulp,
+# and the rest is room for the order of the MXU's fp32 sums.  The module
+# as written rounds the projection first and the gated product again, so
+# it is held beside the kernel and may do no better: the limit is not one
+# the path it replaces would have met with more to spare.
+GEGLU_ERR_LIMIT = 2.0 ** -8
 
 
 class SmokeFailure(Exception):
@@ -408,8 +427,9 @@ def server_phases(phases, cfg: dict, out_dir: str, env: dict,
 def kernel_child(rehearse: bool) -> int:
     """Runs in its own process (it owns the chip while it lives): compile
     the Pallas kernel at each shape and compare it and ``xla_attention``
-    with an fp32 oracle.  Prints one JSON line; any refusal, or an error
-    over KERNEL_ERR_RATIO x ``xla_attention``'s, raises."""
+    with an fp32 oracle, then the GEGLU kernel (`geglu_shapes`).  Prints
+    one JSON line; any refusal, or an error over KERNEL_ERR_RATIO x
+    ``xla_attention``'s or over GEGLU_ERR_LIMIT, raises."""
     import math
 
     import jax
@@ -469,13 +489,66 @@ def kernel_child(rehearse: bool) -> int:
                      "path": attention_path(platform, b, n, m, h),
                      "rel_err": round(err, 6),
                      "rel_err_xla": round(err_xla, 6)})
+    geglu_rows = geglu_shapes(rehearse, platform, failures)
     if failures:
         raise SystemExit("kernel phase failed:\n" + "\n".join(failures))
     print(json.dumps({"device": {"platform": platform,
                                  "kind": devices[0].device_kind,
                                  "count": len(devices)},
-                      "interpret": rehearse, "shapes": rows}), flush=True)
+                      "interpret": rehearse, "shapes": rows,
+                      "geglu_shapes": geglu_rows}), flush=True)
     return 0
+
+
+def geglu_shapes(rehearse: bool, platform: str, failures: list) -> list:
+    """The fused GEGLU kernel (``ops/pallas/geglu.py``) and the module as
+    written, each against the float32 expression on the same operands, at
+    the published shapes: the kernel inside GEGLU_ERR_LIMIT, and today's
+    path no nearer the oracle than the kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.layers import geglu_path
+    from comfyui_distributed_tpu.ops.pallas.geglu import geglu, xla_geglu
+
+    def oracle(x, kernel, bias):
+        with jax.default_matmul_precision("highest"):
+            return xla_geglu(*(a.astype(jnp.float32)
+                               for a in (x, kernel, bias)))
+
+    rows = []
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    for b, t, c in GEGLU_SHAPES_REHEARSAL if rehearse else GEGLU_SHAPES:
+        rng = np.random.default_rng(t * 31 + c)
+        x = jnp.asarray(rng.standard_normal((b, t, c)), dtype)
+        kernel = jnp.asarray(rng.standard_normal((c, 8 * c)) / np.sqrt(c),
+                             dtype)
+        bias = jnp.asarray(rng.standard_normal((8 * c,)) * 0.1, dtype)
+        ref = np.asarray(jax.jit(oracle)(x, kernel, bias))
+
+        def rel_err(out):
+            return float(np.max(np.abs(np.asarray(out, np.float32) - ref))
+                         / np.max(np.abs(ref)))
+
+        err_xla = rel_err(jax.jit(xla_geglu)(x, kernel, bias))
+        try:
+            err = rel_err(jax.jit(lambda x, k, b: geglu(
+                x, k, b, rehearse))(x, kernel, bias))
+        except Exception as e:  # noqa: BLE001 - report every shape, then fail
+            failures.append(f"geglu x {(b, t, c)}: the compiler refused "
+                            f"it: {type(e).__name__}: {str(e)[:1500]}")
+            continue
+        if not (err <= GEGLU_ERR_LIMIT
+                and err <= max(err_xla, KERNEL_ERR_FLOOR)):
+            failures.append(
+                f"geglu x {(b, t, c)}: rel err {err:.6f} against the fp32 "
+                f"oracle (limit {GEGLU_ERR_LIMIT:.6f}); the module as "
+                f"written reads {err_xla:.6f}")
+        rows.append({"x": [b, t, c], "path": geglu_path(platform, b * t, c),
+                     "rel_err": round(err, 6),
+                     "rel_err_xla": round(err_xla, 6)})
+    return rows
 
 
 def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
@@ -498,8 +571,13 @@ def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
         result["device"] = report["device"]
     result["smoke_facts"]["pallas_flash_attention"] = {
         "interpret": report["interpret"], "shapes": report["shapes"]}
+    result["smoke_facts"]["pallas_geglu"] = {
+        "interpret": report["interpret"], "limit": GEGLU_ERR_LIMIT,
+        "shapes": report["geglu_shapes"]}
     say(f"kernels: {len(report['shapes'])} shape(s) within "
-        f"{KERNEL_ERR_RATIO} x xla_attention's error against fp32")
+        f"{KERNEL_ERR_RATIO} x xla_attention's error against fp32; "
+        f"{len(report['geglu_shapes'])} GEGLU shape(s) within "
+        f"{GEGLU_ERR_LIMIT} of fp32")
 
 
 # --- the language model against its reference --------------------------------

@@ -20,7 +20,7 @@ from flax import linen as nn
 from comfyui_distributed_tpu.parallel import sharding as shd
 from comfyui_distributed_tpu.utils.constants import (DATA_AXIS, SEQ_AXIS,
                                                      TENSOR_AXIS)
-from comfyui_distributed_tpu.utils.trace import ATTENTION_PATHS
+from comfyui_distributed_tpu.utils.trace import ATTENTION_PATHS, GEGLU_PATHS
 
 Dtype = Any
 
@@ -364,13 +364,97 @@ def _maybe_ring_attention(q: jax.Array, k: jax.Array,
     return ring_attention(q, k, v, mesh)
 
 
+def geglu_path(platform: str, rows: int, c: int,
+               mesh_axes: Optional[dict] = None) -> str:
+    """Which implementation `GEGLU` runs for ``rows`` tokens of width
+    ``c`` (the batch's and the sequence's together) against ``proj [c,
+    8c]``: ``fused`` (the Pallas kernel, ops/pallas/geglu.py: one product
+    with its gate on the output side) or ``xla`` (the module as written).
+
+    A function of what the code can see at trace time and nothing else,
+    as `attention_path` is: the backend's platform, the operands' static
+    shapes and the live mesh's ``{axis: size}`` (None on one device).
+    The kernel takes the call on a TPU where the rows and the ``4c``
+    columns divide its blocks.  Under a multi-device mesh each chip runs
+    it on its own rows (`_geglu_on_mesh`); a live ``tensor`` axis splits
+    the hidden columns (rule table "mlp") and a live ``seq`` axis the
+    tokens, so there, and where the rows do not divide ``data``, the call
+    stays with XLA, which partitions it."""
+    from comfyui_distributed_tpu.ops.pallas.geglu import block_sizes
+
+    axes = mesh_axes or {}
+    data = axes.get(DATA_AXIS, 1)
+    splits = (axes.get(SEQ_AXIS, 1) == 1 and axes.get(TENSOR_AXIS, 1) == 1
+              and rows % data == 0)
+    if platform == "tpu" and splits \
+            and block_sizes(rows // data, c, 4 * c) is not None:
+        return "fused"
+    return "xla"
+
+
+def _geglu_on_mesh(x: jax.Array, kernel: jax.Array,
+                   bias: Optional[jax.Array], mesh) -> jax.Array:
+    """The GEGLU kernel, each chip on its own rows: XLA cannot partition
+    a Mosaic custom call (`_fused_on_mesh`), so under a multi-device mesh
+    the call goes through ``jax.shard_map``, batch rows over ``data`` and
+    the weight whole on every chip, as it is held.  A batch that does not
+    divide ``data`` is replicated there, and stays so."""
+    from comfyui_distributed_tpu.ops.pallas.geglu import geglu
+
+    if mesh is None:
+        return geglu(x, kernel, bias)
+    data = int(mesh.shape.get(DATA_AXIS, 1))
+    rows = shd.mesh_spec(DATA_AXIS if x.shape[0] % data == 0 else None,
+                         *[None] * (x.ndim - 1))
+    whole = shd.mesh_spec()
+    return jax.shard_map(geglu, mesh=mesh, in_specs=(rows, whole, whole),
+                         out_specs=rows, check_vma=False)(x, kernel, bias)
+
+
 class GEGLU(nn.Module):
+    """``proj`` to ``2 * dim_out`` columns, then the first half times the
+    exact (erf) gelu of the second.  `geglu_path` decides per call site,
+    at trace time, whether that is one fused product (a TPU) or the
+    module as written; either way the one leaf ``proj`` keeps its
+    published name, shape and layout.  Each call site counts the path it
+    took, once (``geglu_paths`` on ``GET /distributed/metrics``)."""
     dim_out: int
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        h = nn.Dense(self.dim_out * 2, dtype=self.dtype, name="proj")(x)
+        proj = nn.Dense(self.dim_out * 2, dtype=self.dtype, name="proj")
+        c = x.shape[-1]
+        mesh = _live_mesh()
+        # the kernel reads the leaf the Dense made: while a model is being
+        # initialised there is none yet, and that trace is no call site
+        path = "xla" if self.is_initializing() or self.dim_out != 4 * c \
+            else geglu_path(jax.default_backend(), x.size // c, c,
+                            dict(mesh.shape) if mesh is not None else None)
+        if not self.is_initializing():
+            GEGLU_PATHS.bump(path)
+        if path == "fused":
+            leaf = self.get_variable("params", "proj")
+            x, kernel, bias = nn.dtypes.promote_dtype(
+                x, leaf["kernel"], leaf["bias"], dtype=self.dtype)
+            # Behind a conditional, for the convolutions' sake.  The TPU
+            # compiler rewrites the UNet's 3x3 convolutions space-to-batch
+            # (2 x 64 x 64 becomes 64 tiles of 16 x 9) and gives that up
+            # for every convolution whose result reaches a custom call
+            # through at most one product.  The residual stream runs from
+            # each ResBlock through ``proj_in`` into every GEGLU, so with a
+            # bare kernel call here the convolutions lost 0.33 s a SDXL
+            # denoise where the kernel won 0.22 (PERF.md §6, PR 39).  A
+            # conditional is opaque to that search.  Its predicate cannot
+            # be folded (a NaN is not equal to itself) and holds for every
+            # bias that is a number; the other branch is the module as
+            # written, which is as right for one that is not.
+            from comfyui_distributed_tpu.ops.pallas.geglu import xla_geglu
+            return jax.lax.cond(
+                bias[0] == bias[0],
+                lambda: _geglu_on_mesh(x, kernel, bias, mesh),
+                lambda: xla_geglu(x, kernel, bias))
+        h = proj(x)
         # column-split ffn hidden over the tensor axis (rule table "mlp");
         # the gate/value halves split at dim_out, which is also a shard
         # boundary for any tensor size dividing dim_out

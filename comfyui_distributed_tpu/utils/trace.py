@@ -28,8 +28,9 @@ Here profiling is a first-class subsystem:
   edge in the ops layer reports bytes through :func:`record_transfer`,
   attributed to the executing workflow node (:func:`node_scope`) — the
   software-measurable proxy for "tensors never leave HBM";
-- attention call sites by the path each took (:data:`ATTENTION_PATHS`),
-  counted while a program is traced;
+- attention and GEGLU call sites by the path each took
+  (:data:`ATTENTION_PATHS`, :data:`GEGLU_PATHS`), counted while a program
+  is traced;
 - retrace/compile counters (:class:`RetraceStats`) fed by
   ``jax.monitoring`` events, telling a compile from a cache load, with
   their seconds: a steady-state serving process must report ZERO new
@@ -519,6 +520,11 @@ GLOBAL_COUNTERS = CounterStats()
 # like ``retraces``.
 ATTENTION_PATHS = CounterStats()
 
+# The UNet's GEGLU call sites by the path each took (``fused``: the Pallas
+# kernel, ``xla``: the module as written), counted the same way
+# (models/layers.py:geglu_path).
+GEGLU_PATHS = CounterStats()
+
 
 class GaugeStats:
     """Named level gauges (thread-safe) — current-state values the
@@ -921,7 +927,8 @@ def counters_snapshot() -> Dict[str, Any]:
     """One payload for /distributed/metrics and bench artifacts."""
     return {"transfers": GLOBAL_TRANSFERS.snapshot(),
             "retraces": GLOBAL_RETRACES.mark(),
-            "attention_paths": ATTENTION_PATHS.snapshot()}
+            "attention_paths": ATTENTION_PATHS.snapshot(),
+            "geglu_paths": GEGLU_PATHS.snapshot()}
 
 
 # --- request-scoped distributed tracing (spans) ------------------------------

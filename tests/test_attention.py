@@ -246,9 +246,13 @@ def interpreted(monkeypatch):
     """The model stack asks for the compiled kernel; a CPU test puts the
     interpreter behind the same name, explicitly."""
     import functools
+    from comfyui_distributed_tpu.ops.pallas import geglu
     fa = _fa()
     monkeypatch.setattr(fa, "flash_attention", functools.partial(
         fa.flash_attention, interpret=True))
+    # a UNet traced as on a TPU sends its feed-forwards to a kernel too
+    monkeypatch.setattr(geglu, "geglu", functools.partial(
+        geglu.geglu, interpret=True))
     return fa
 
 

@@ -54,6 +54,13 @@ def test_rehearsal_passes_on_the_cpu(tmp_path):
         assert set(row) == {"q", "kv_len", "path", "rel_err", "rel_err_xla"}
         assert row["path"] in ("xla_whole", "xla_chunked")
         assert row["rel_err"] <= max(1.25 * row["rel_err_xla"], 4e-6)
+    # the fused GEGLU (interpreter) beside the module as written
+    assert facts["pallas_geglu"]["limit"] == 2.0 ** -8
+    assert [set(row) for row in facts["pallas_geglu"]["shapes"]] \
+        == [{"x", "path", "rel_err", "rel_err_xla"}]
+    for row in facts["pallas_geglu"]["shapes"]:
+        assert row["path"] == "xla"
+        assert row["rel_err"] <= max(row["rel_err_xla"], 4e-6)
     assert list(summary)[-1] == "claim" and summary["claim"] is None
     with open(tmp_path / "out" / "summary.json", encoding="utf-8") as f:
         assert json.load(f) == summary
@@ -124,14 +131,19 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert "not next to this script" in r.stderr
 
 
-def test_kernel_phase_holds_the_kernel_to_xla_attentions_error():
-    """On the chip the kernel child runs the shapes the rule sends to the
-    kernel: the four large self-attentions of the two benchmarked UNets
-    among them, each a shape `attention_path` answers ``fused`` for."""
+def _load_smoke():
     import importlib.util
     spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
     smoke = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(smoke)
+    return smoke
+
+
+def test_kernel_phase_holds_the_kernel_to_xla_attentions_error():
+    """On the chip the kernel child runs the shapes the rule sends to the
+    kernel: the four large self-attentions of the two benchmarked UNets
+    among them, each a shape `attention_path` answers ``fused`` for."""
+    smoke = _load_smoke()
     from comfyui_distributed_tpu.models.layers import attention_path
     fused = [(q, m) for q, m in smoke.KERNEL_SHAPES
              if attention_path("tpu", q[0], q[1], m, q[2]) == "fused"]
@@ -140,3 +152,17 @@ def test_kernel_phase_holds_the_kernel_to_xla_attentions_error():
         assert shape in fused
     assert len(fused) < len(smoke.KERNEL_SHAPES)    # and some it keeps
     assert smoke.KERNEL_ERR_RATIO == 1.25
+
+
+def test_kernel_phase_holds_the_geglu_kernel_to_one_rounding():
+    """On the chip the kernel child runs the fused GEGLU at the five
+    feed-forward shapes of the two benchmarked UNets, each a shape
+    `geglu_path` answers ``fused`` for, against a limit of one bf16 ulp."""
+    smoke = _load_smoke()
+    from comfyui_distributed_tpu.models.layers import geglu_path
+    assert set(smoke.GEGLU_SHAPES) == {
+        (2, 4096, 640), (2, 1024, 1280),
+        (2, 4096, 320), (2, 1024, 640), (2, 256, 1280)}
+    for b, t, c in smoke.GEGLU_SHAPES:
+        assert geglu_path("tpu", b * t, c) == "fused"
+    assert smoke.GEGLU_ERR_LIMIT == 2.0 ** -8

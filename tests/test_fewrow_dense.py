@@ -259,16 +259,20 @@ def test_the_one_row_program_is_untouched_by_the_rule(family, monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip (no device attached): what the chip's
-    compiler refuses, it refuses here."""
+def topo():
+    """A described 2x2 host of v5e chips (no device attached): what the
+    chip's compiler refuses, it refuses here."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 - whatever libtpu raises
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -438,3 +442,119 @@ def test_a_tile_of_an_experts_tokens_copies_no_weight(
                       "gather" in m["rest"] or "dot_general" in m["rest"])}
         assert ("bf16", f"{tile},{d}") in shapes, shapes
         assert not any(dims.startswith("512,") for _, dims in shapes), shapes
+
+
+def taken_computations(text):
+    """The instruction lines that run when `GEGLU`'s conditional takes the
+    kernel: the entry computation and the conditional's second branch
+    (``lax.cond`` puts the false branch first), each result a buffer."""
+    comps = computations(text)
+    branches = re.findall(r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}",
+                          "\n".join(comps["ENTRY"]))
+    assert branches, "no conditional in the entry computation"
+    return [comps["ENTRY"]] + [comps[true] for _, true in branches]
+
+
+@pytest.mark.parametrize("b,t,c", [(2, 4096, 640), (2, 1024, 1280),
+                                   (2, 4096, 320), (2, 1024, 640),
+                                   (2, 256, 1280)])
+def test_the_unets_feed_forward_writes_no_8c_projection(
+        b, t, c, one_chip, no_compile_cache, monkeypatch):
+    """The UNet's `FeedForward` at the five published shapes, traced as a
+    TPU traces it and compiled for the described chip (PR 39; here because
+    this file holds the fixture): GEGLU is ONE kernel call that hands
+    ``[rows, 4c]`` on, the chip's compiler takes its blocks, no
+    instruction writes the ``[.., 8c]`` projection, and none writes a
+    buffer of the ``proj`` leaf's size: the two index maps read the one
+    leaf where it lies."""
+    from comfyui_distributed_tpu.models import layers
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ff = layers.FeedForward(dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((b, t, c), jnp.float32, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(ff.init, jax.random.PRNGKey(0), x))
+    text = jax.jit(ff.apply).lower(params, x).compile().as_text()
+    taken = taken_computations(text)
+    made = [m for lines in taken for m in map(INSTRUCTION.match, lines)
+            if m and m["op"] not in PASSES_ON]
+    calls = [m for m in made if m["op"] == "custom-call"
+             and "tpu_custom_call" in m["rest"]]
+    assert [(m["dtype"], m["dims"]) for m in calls] \
+        == [("bf16", f"{b * t},{4 * c}")]
+    assert "/geglu/cond/branch_1_fun/jit(_fused_geglu)/" in calls[0]["rest"]
+    # what lies in HBM (a result in memory space 1 is a prefetch into
+    # fast memory: at c = 320 the compiler brings the 1.6 MB leaf there)
+    in_hbm = [m for m in made
+              if "S(1)}" not in m.group(0).split(" = ")[1].split(" ")[0]]
+    assert not [m for m in in_hbm if m["dims"].endswith(f",{8 * c}")]
+    assert not [m for m in in_hbm if m["dims"] == f"{c},{8 * c}"]
+
+
+@pytest.mark.parametrize("t,c", [(4096, 640), (1024, 1280)])
+def test_the_fan_out_programs_feed_forward_runs_each_chip_on_its_rows(
+        t, c, topo, no_compile_cache, monkeypatch):
+    """`sdxl_1024_fanout4`'s feed-forward (eight CFG-stacked rows over
+    ``data=4``), compiled for the described 2x2 host: XLA cannot partition
+    a Mosaic call, so it goes through ``shard_map``; each chip's kernel
+    call hands on its own ``[2t, 4c]`` and the program holds no
+    collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from comfyui_distributed_tpu.models import layers
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1, 1),
+                ("data", "tensor", "seq"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(layers, "_live_mesh", lambda: mesh)
+    ff = layers.FeedForward(dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((8, t, c), jnp.float32, sharding=NamedSharding(
+        mesh, PartitionSpec("data")))
+    whole = NamedSharding(mesh, PartitionSpec())
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=whole),
+        jax.eval_shape(ff.init, jax.random.PRNGKey(0), x))
+    text = jax.jit(ff.apply).lower(params, x).compile().as_text()
+    calls = [m for lines in taken_computations(text)
+             for m in map(INSTRUCTION.match, lines)
+             if m and "tpu_custom_call" in m["rest"]]
+    assert [(m["dtype"], m["dims"]) for m in calls] \
+        == [("bf16", f"{2 * t},{4 * c}")]
+    assert not re.search(r"all-gather|all-reduce|collective-permute|"
+                         r"all-to-all", text)
+
+
+def test_a_convolution_that_feeds_a_geglu_keeps_its_space_to_batch_form(
+        one_chip, no_compile_cache, monkeypatch):
+    """Why `GEGLU` puts its kernel behind a conditional (PR 39).  The
+    chip's compiler rewrites a 3x3 convolution over ``[2, 64, 64, c]``
+    into one over 64 tiles ``[64, 16, 9, c]``, and gives that up for a
+    convolution whose result reaches a custom call through at most one
+    product: a ResBlock in front of a transformer, traced as a TPU traces
+    it, must still compile to the tiled form (with the bare call it
+    compiled to ``[2, 64, 64, c]`` at a 2-row tile, and the UNet's
+    convolutions lost more than the kernel won)."""
+    from flax import linen as nn
+    from comfyui_distributed_tpu.models import layers
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    class Level(nn.Module):
+        @nn.compact
+        def __call__(self, x, emb, ctx):
+            x = layers.ResBlock(320, name="res_0")(x, emb)
+            return layers.SpatialTransformer(8, name="attn_0")(x, ctx)
+
+    shaped = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for s in ((2, 64, 64, 320), (2, 1280), (2, 77, 768))]
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(Level().init, jax.random.PRNGKey(0), *shaped))
+    text = jax.jit(Level().apply).lower(params, *shaped).compile().as_text()
+    assert text.count("tpu_custom_call") == 2       # attention, GEGLU
+    convs = [m for lines in computations(text).values()
+             for m in map(INSTRUCTION.match, lines)
+             if m and m["op"] == "fusion" and "kind=kOutput" in m["rest"]
+             and re.search(r"res_0/(in|out)_conv/conv_general_dilated",
+                           m["rest"])]
+    assert {m["dims"] for m in convs} == {"64,16,9,320"}, convs

@@ -80,6 +80,16 @@ UNET = "jit(core)/while/body/closed_call/UNet/"
     (UNET + "up_1_attn_2/blocks_0/attn2/to_out/add", "attn_proj"),
     (UNET + "up_1_attn_2/blocks_0/ff/geglu/proj/dot_general", "ff"),
     (UNET + "up_1_attn_2/blocks_0/ff/out/dot_general", "ff"),
+    # the fused GEGLU kernel (PR 39) is called inside the module's scope
+    # (behind a conditional), alone and under the fan-out program's
+    # shard_map: the reader of ``proj_ff_device_s_per_image`` and the
+    # account's ``ff`` own it
+    (UNET + "up_1_attn_2/blocks_0/ff/geglu/cond/branch_1_fun/"
+     "jit(_fused_geglu)/geglu/pallas_call", "ff"),
+    (UNET + "down_1_attn_0/blocks_1/ff/geglu/cond/branch_1_fun/shard_map/"
+     "jit(_fused_geglu)/geglu/pallas_call", "ff"),
+    (UNET + "up_1_attn_2/blocks_0/ff/geglu/cond", "ff"),
+    (UNET + "up_1_attn_2/blocks_0/ff/geglu/convert_element_type", "ff"),
     (UNET + "up_1_attn_2/blocks_0/norm3/mul", "norm"),
     (UNET + "up_1_attn_2/norm/GroupNorm_0/reduce_sum", "norm"),
     (UNET + "up_1_attn_2/proj_in/dot_general", "attn_proj"),
@@ -170,6 +180,32 @@ def test_every_op_of_the_compiled_denoise_falls_in_one_class(pipe):
     assert {"dot_general", "exp", "reduce_max"} <= attn
     assert not any(re.search(r"/to_(q|k|v|out)/", n)
                    for n in by_class["attn_self"] + by_class["attn_cross"])
+
+
+def test_the_fused_geglus_own_operations_are_class_ff(monkeypatch):
+    """A transformer block traced as a TPU would trace it (the kernel
+    behind the interpreter): the call sits inside ``GEGLU``'s scope,
+    behind the conditional, so every operation it lowers to carries
+    ``.../ff/geglu/...`` and, under the UNet's scope, is class ``ff``;
+    ``ff/out`` is a plain Dense beside it."""
+    import functools
+    from comfyui_distributed_tpu.models import layers
+    from comfyui_distributed_tpu.ops.pallas import geglu
+    monkeypatch.setattr(geglu, "geglu", functools.partial(
+        geglu.geglu, interpret=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    block = layers.TransformerBlock(num_heads=2, name="blocks_0")
+    x, ctx = jnp.zeros((2, 64, 128)), jnp.zeros((2, 8, 64))
+    params = block.init(jax.random.PRNGKey(0), x, ctx)
+    names = OP_NAME.findall(
+        jax.jit(block.apply).lower(params, x, ctx).compile().as_text())
+    ff = [n for n in names if "/ff/" in n]
+    fused = [n for n in ff
+             if "/ff/geglu/cond/branch_1_fun/jit(_fused_geglu)/" in n]
+    assert fused and any("/ff/out/dot_general" in n for n in ff)
+    assert not any("/ff/geglu/proj/" in n for n in ff)
+    assert {trace.classify(n.replace("jit(apply)/", UNET + "up_1_attn_2/"))
+            for n in ff} == {"ff"}
 
 
 @pytest.mark.parametrize("program, want", [("vae", VAE_CLASSES),
@@ -689,6 +725,25 @@ def test_summary_of_a_recorded_chip_slice():
     assert all(o["op"].startswith("%copy-done") for o in core["top_other"])
     assert s["idle"]["dispatch"] == pytest.approx(0.003057694, abs=1e-9)
     assert s["gaps_in_programs_s"] == pytest.approx(1.059e-06, abs=1e-10)
+
+
+def test_the_recorded_slice_shows_the_gate_in_front_of_ff_out():
+    """What XLA makes of GEGLU as written (the same PR 24 probe, c = 64):
+    the product under ``ff/geglu/proj`` writes the WHOLE ``[B, T, 8c]``
+    projection, and the fusion under ``ff/out/dot_general`` reads it: the
+    split, the erf and the gating multiply run in front of the next
+    product.  Both are class ``ff``, as the fused kernel's call is
+    (`test_classify`), so the accepted reader sees either lowering."""
+    ops = next(ln for plane in ts.read_events(RECORDED)["planes"]
+               for ln in plane["lines"] if ln["name"] == ts.OPS_LINE)
+    named = {p.rstrip(":").split("/blocks_0/")[-1]: n
+             for n, p in zip(ops["names"], ops["paths"])}
+    proj, out = named["ff/geglu/proj/dot_general"], named["ff/out/dot_general"]
+    assert proj.startswith("%convolution_add_fusion.5 = bf16[2,256,512]")
+    assert out.startswith("%add_add_fusion.2 = bf16[2,256,64]")
+    assert "bf16[2,256,512]{2,1,0:T(8,128)(2,1)S(1)} " \
+        "%convolution_add_fusion.5" in out
+    assert {trace.classify(p) for p in ops["paths"] if "/ff/" in p} == {"ff"}
 
 
 # --- 5. the executor's self times add up -----------------------------------
