@@ -48,7 +48,10 @@ its own, and the logits of that request held to the plain float32
 reference (``benchmarks/chip/verify_lm.py``, which says what is compared
 and why each limit is what it is); then four such requests sent together,
 which the server runs as the four rows of ONE execution, each row held
-to the same reference inside the same limits.
+to the same reference inside the same limits; then the state-space
+language model (granite-4.0-h-micro) the same two ways behind its
+2048-position prompt, against a reference of its own
+(``benchmarks/chip/verify_lm_ssm.py``).
 """
 
 from __future__ import annotations
@@ -582,28 +585,38 @@ def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
 
 # --- the language model against its reference --------------------------------
 
-def lm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
-    """``benchmarks/chip/verify_lm.py`` as a child: one request of the
-    prompt expander's graph at the timed size, then the reference."""
-    log_path = os.path.join(out_dir, "verify_lm.stderr.log")
+def verify_child(cfg: dict, out_dir: str, env: dict, script: str,
+                 args: list, failed: str) -> dict:
+    """``benchmarks/chip/<script>.py`` as a child (a server of its own,
+    then the plain reference): its report, checked ``ok``."""
+    log_path = os.path.join(out_dir, f"{script}.stderr.log")
     cmd = [sys.executable,
-           os.path.join(HERE, "benchmarks", "chip", "verify_lm.py"),
-           "--requests", "1", "--out", os.path.join(out_dir, "verify_lm")]
+           os.path.join(HERE, "benchmarks", "chip", f"{script}.py"), *args,
+           "--out", os.path.join(out_dir, script)]
     if cfg["rehearsal"]:
         cmd.append("--rehearse")
     with open(log_path, "wb") as log:
         proc = subprocess.run(cmd, cwd=out_dir, env=env,
                               stdout=subprocess.PIPE, stderr=log,
-                              timeout=2 * cfg["first_timeout"])
+                              timeout=3 * cfg["first_timeout"])
     with open(log_path, "rb") as f:
         tail = f.read()[-3000:].decode("utf-8", "replace")
     lines = proc.stdout.decode().strip().splitlines()
-    check(bool(lines), f"verify_lm printed nothing (exit "
+    check(bool(lines), f"{script} printed nothing (exit "
                        f"{proc.returncode}):\n{tail}")
     report = json.loads(lines[-1])
     check(proc.returncode == 0 and report["ok"],
-          f"the served logits are outside a limit, or an 8-bit reading is "
-          f"inside all of them: {json.dumps(report)}\n{tail}")
+          f"{failed}: {json.dumps(report)}\n{tail}")
+    return report
+
+
+def lm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    """``benchmarks/chip/verify_lm.py`` as a child: one request of the
+    prompt expander's graph at the timed size, then the reference."""
+    report = verify_child(
+        cfg, out_dir, env, "verify_lm", ["--requests", "1"],
+        "the served logits are outside a limit, or an 8-bit reading is "
+        "inside all of them")
     if result["device"] is None:     # an lm-only run
         dev = report["device"]
         result["device"] = {"platform": dev["platform"], "kind": dev["kind"],
@@ -618,6 +631,31 @@ def lm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
     say(f"lm: {served['positions']} positions within the limits "
         f"(mean {served['mean_over_std']:.4f}, max "
         f"{served['max_over_std']:.4f} of a standard deviation)")
+
+
+def lm_ssm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    """``benchmarks/chip/verify_lm_ssm.py`` as a child: the decoder of
+    state-space and attention layers (granite-4.0-h-micro) behind its
+    2048-position prompt, one request alone and four of unequal length as
+    the rows of one execution, each held to the plain float32 reference
+    (the recurrence position by position); a bf16 state, an 8-bit cache,
+    8-bit weights and a dropped ``D`` skip each have to fail."""
+    report = verify_child(
+        cfg, out_dir, env, "verify_lm_ssm", [],
+        "a served row is outside a limit, or a reading that has to fail "
+        "is inside all of them")
+    worst = max(report["served"], key=lambda r: r["mean_over_std"])
+    result["smoke_facts"]["language_model_ssm"] = {
+        "rows": len(report["served"]), "together": report["together"],
+        **{key: worst[key] for key in ("positions", "max_over_std",
+                                       "mean_over_std", "limits")},
+        **{key: report[key]["mean_over_std"]
+           for key in ("state_bf16", "cache_8bit", "weights_8bit",
+                       "skip_dropped")}}
+    say(f"lm (state-space): {len(report['served'])} rows within the "
+        f"limits (worst mean {worst['mean_over_std']:.4f} of a standard "
+        f"deviation; a bf16 state reads "
+        f"{report['state_bf16']['mean_over_std']:.4f})")
 
 
 LM_TOGETHER = 4      # requests sent together: one execution's rows
@@ -808,6 +846,7 @@ def main() -> int:
         if "lm" in phases:
             lm_phase(cfg, out_dir, env, summary)
             lm_together_phase(cfg, out_dir, env, summary)
+            lm_ssm_phase(cfg, out_dir, env, summary)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

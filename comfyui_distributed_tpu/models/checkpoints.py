@@ -791,3 +791,62 @@ def load_looplm_checkpoint(path: str, cfg) -> Params:
                 "bias": sd["model.early_exit_gate.bias"].reshape(())},
             "lm_head": leaf("lm_head.weight")}
     return jax.tree_util.tree_map(lambda a: jnp.asarray(a, cfg.dtype), tree)
+
+
+# --- the state-space / attention hybrid (models/ssm_hybrid.py) -----------------
+
+_GRANITE_BLOCK_KEYS = {
+    "input_layernorm": "input_layernorm.weight",
+    "post_attention_layernorm": "post_attention_layernorm.weight",
+    "input_linear": "shared_mlp.input_linear.weight",
+    "output_linear": "shared_mlp.output_linear.weight",
+}
+_GRANITE_ATTENTION_KEYS = {
+    "q_proj": "self_attn.q_proj.weight", "k_proj": "self_attn.k_proj.weight",
+    "v_proj": "self_attn.v_proj.weight", "o_proj": "self_attn.o_proj.weight",
+}
+_GRANITE_MAMBA_KEYS = {
+    "conv1d_bias": "mamba.conv1d.bias", "dt_bias": "mamba.dt_bias",
+    "A_log": "mamba.A_log", "D": "mamba.D", "norm": "mamba.norm.weight",
+    "out_proj": "mamba.out_proj.weight",
+}
+
+
+def load_granite_hybrid_checkpoint(path: str, cfg) -> Params:
+    """The model's Hugging Face state dict (``granitemoehybrid``:
+    ``model.layers.<l>.mamba...`` / ``.self_attn...`` /
+    ``.shared_mlp...``, linear weights ``[out, in]``) as the tree
+    ``models/ssm_hybrid.py`` serves: kernels ``[in, out]``, the blocks of
+    each kind stacked on a leading axis in the order ``layer_types``
+    gives them, ``mamba.in_proj`` split behind its z | xBC columns into
+    ``in_proj_zx`` and ``in_proj_dt``, the convolution's ``[channels, 1,
+    taps]`` as ``[taps, channels]``, no ``lm_head`` (tied), everything in
+    the model's dtype."""
+    import jax
+    import jax.numpy as jnp
+    sd = load_state_dict(path)
+
+    def leaf(key: str):
+        w = sd[key]
+        return t_lin(w) if w.ndim == 2 and "embed_tokens" not in key else w
+
+    def stacked(kind: str, keys: Dict[str, str]):
+        at = [l for l, k in enumerate(cfg.layer_types) if k == kind]
+        return {name: np.stack([leaf(f"model.layers.{l}.{key}") for l in at])
+                for name, key in keys.items()}, at
+
+    mamba, at = stacked("mamba", {**_GRANITE_BLOCK_KEYS,
+                                  **_GRANITE_MAMBA_KEYS})
+    in_proj = np.stack([leaf(f"model.layers.{l}.mamba.in_proj.weight")
+                        for l in at])
+    split = cfg.d_inner + cfg.conv_dim
+    mamba["in_proj_zx"], mamba["in_proj_dt"] = \
+        in_proj[..., :split], in_proj[..., split:]
+    mamba["conv1d_weight"] = np.stack([
+        sd[f"model.layers.{l}.mamba.conv1d.weight"][:, 0, :].T for l in at])
+    attention, _ = stacked("attention", {**_GRANITE_BLOCK_KEYS,
+                                         **_GRANITE_ATTENTION_KEYS})
+    tree = {"embed_tokens": leaf("model.embed_tokens.weight"),
+            "mamba_layers": mamba, "attention_layers": attention,
+            "norm": leaf("model.norm.weight")}
+    return jax.tree_util.tree_map(lambda a: jnp.asarray(a, cfg.dtype), tree)

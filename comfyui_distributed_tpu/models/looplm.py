@@ -56,8 +56,9 @@ import jax.numpy as jnp
 from comfyui_distributed_tpu.models.layers import _live_mesh, \
     scaled_dot_product_attention
 from comfyui_distributed_tpu.ops.pallas.fewrow_dense import LANES, \
-    fewrow_dense
+    fewrow_dense, fewrow_dense_t
 from comfyui_distributed_tpu.parallel import sharding as shd
+from comfyui_distributed_tpu.utils.trace import DENSE_PATHS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -335,12 +336,23 @@ def _streams(kernels: Sequence[Any], rows: int, cfg) -> bool:
         mesh_axes) == "fewrow"
 
 
+def _count_sites(path: str, rows: int, sites: int = 1) -> None:
+    """``dense_paths`` on ``GET /distributed/metrics``: the products with
+    a weight by the lowering each took and the rows that met it
+    (``one``, ``few``: 2 to 8, ``many``), counted per call site while a
+    program is TRACED, as ``attention_paths`` are."""
+    met = "one" if rows == 1 else "few" if rows <= FEWROW_ROWS[1] else "many"
+    DENSE_PATHS.bump(f"{path}_{met}", sites)
+
+
 def _products(x, kernels: Sequence[Any], cfg, name: str = "fewrow_dense"):
     """``x @ kernel`` for each kernel, operands in the model's dtype,
     accumulated and returned in float32: one call of the few-row kernel
     (named ``name``) where `_streams`, else a ``jnp.dot`` each."""
     rows = math.prod(x.shape[:-1])
-    if not _streams(kernels, rows, cfg):
+    streams = _streams(kernels, rows, cfg)
+    _count_sites("fewrow" if streams else "xla", rows, len(kernels))
+    if not streams:
         return [jnp.dot(x.astype(cfg.dtype), matrix(w),
                         preferred_element_type=jnp.float32)
                 for w in kernels]
@@ -354,6 +366,27 @@ def _dense(x, kernel, cfg):
     """``x @ kernel`` with operands in the model's dtype, accumulated and
     returned in float32."""
     return _products(x, [kernel], cfg)[0]
+
+
+def dense_tied(x, leaf, cfg):
+    """``x @ leaf.T`` for a TIED embedding ``leaf [V, d]`` read as the
+    head, operands in the model's dtype, accumulated and returned in
+    float32.  The leaf stays as the lookup wants it (a row an id,
+    contiguous); where `dense_path` sends a ``[d, V]`` weight to the
+    few-row kernel its transposed form streams the rows as they lie,
+    elsewhere a ``dot_general`` contracts the second axis of both: no
+    path makes a transposed copy of the leaf."""
+    rows = math.prod(x.shape[:-1])
+    platform, mesh_axes = _where()
+    path = dense_path(platform, rows, leaf.shape[1], leaf.shape[0],
+                      jnp.dtype(cfg.dtype).itemsize, mesh_axes)
+    _count_sites(f"{path}_tied", rows)
+    if path == "fewrow":
+        out = fewrow_dense_t(x.reshape(rows, -1).astype(cfg.dtype), leaf)
+        return out.reshape(*x.shape[:-1], -1)
+    return jax.lax.dot_general(
+        x.astype(cfg.dtype), leaf, (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def dense_each(x, weights, names: Sequence[str], cfg,

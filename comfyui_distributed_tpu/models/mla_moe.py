@@ -244,14 +244,18 @@ def _norm_gain(name: str):
     return 1.0 if name in NORMS + LATENT_NORMS or name == "norm" else None
 
 
-def _seeded_leaf(name: str, shape: tuple, dtype, gain):
+def _seeded_leaf(name: str, shape: tuple, dtype, gain, own=None):
     """The jitted maker of one leaf from its key: kernels normals scaled
     by fan-in, embeddings unit normals, norm gains ``gain`` x (1 + 0.1 N)
-    (a gain of exactly 1 would hide a norm applied without its gain).  A
+    (a gain of exactly 1 would hide a norm applied without its gain);
+    ``own(key, shape)`` -> float32 where the family draws the leaf its
+    own way.  A
     stacked leaf is drawn slice by slice along its leading axis, so the
     float32 normals of the largest (the experts', 1.0 B values) never
     stand whole beside it."""
     def draw(key, shape):
+        if own is not None:
+            return own(key, shape).astype(dtype)
         x = jax.random.normal(key, shape, jnp.float32)
         if gain is not None:
             x = gain * (1.0 + 0.1 * x)
@@ -268,17 +272,21 @@ def _seeded_leaf(name: str, shape: tuple, dtype, gain):
     return jax.jit(leaf)
 
 
-def seeded_tree(shapes, seed, dtype, norm_gain) -> Dict[str, Any]:
+def seeded_tree(shapes, seed, dtype, norm_gain, draws=None
+                ) -> Dict[str, Any]:
     """Seeded random weights of a tree of ``shapes``, made on the device
     LEAF BY LEAF (one small jitted call a leaf): the 4.9 B values of the
     published share are 9.8 GB on a 16 GB chip, and one program that drew
     them all could hold several leaves' float32 normals at once.
-    ``norm_gain(name)`` says which leaves are norms (`_norm_gain`)."""
+    ``norm_gain(name)`` says which leaves are norms (`_norm_gain`);
+    ``draws`` maps the names of leaves that are neither norms nor
+    matrices to their own makers (`_seeded_leaf`)."""
     flat, tree = shape_leaves(shapes)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(flat))
     return jax.tree_util.tree_unflatten(
         tree, [_seeded_leaf(p[-1].key, s, jnp.dtype(dtype),
-                            norm_gain(p[-1].key))(k)
+                            norm_gain(p[-1].key),
+                            (draws or {}).get(p[-1].key))(k)
                for (p, s), k in zip(flat, keys)])
 
 

@@ -1267,7 +1267,8 @@ def clear_pipeline_cache() -> None:
     gg_mod.clear_gligen_cache()
 
 
-# --- language models (models/looplm.py, models/mla_moe.py, models/swa_moe.py) --
+# --- language models (models/looplm.py, models/mla_moe.py, models/swa_moe.py,
+# models/ssm_hybrid.py) ---------------------------------------------------------
 #
 # A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
 # model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
@@ -1284,7 +1285,7 @@ EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
 # a served window compiles nothing).  An execution is padded to the next
 # count with copies of its first row; the last count also bounds what is
 # kept for requests still in the queue (server/lm_handover.py): three
-# results.  Argued per family (`LMFamily.row_counts`); all three take these.
+# results.  Argued per family (`LMFamily.row_counts`); all four take these.
 LM_ROW_COUNTS = (1, 4)
 
 
@@ -1297,11 +1298,15 @@ class LMFamily:
     ``seeded_params(cfg, seed)``, ``load_checkpoint(path, cfg)``,
     ``make_program(cfg, new_tokens)`` (the jitted ``lm_generate`` ->
     ids, logits, ``aux`` arrays per position, ``stats`` to count from),
-    ``kv_cache_bytes(cfg, rows, positions)`` (and, where its caches are
-    of more than one geometry, ``kv_cache_bytes_by_kind`` -> the parts by
-    name) and ``window_counters(cfg, stats, real_rows, steps)``; its
-    config gives
-    ``vocab_size`` and ``layer_applications`` (per token)."""
+    ``kv_cache_bytes(cfg, rows, positions)`` (the state indexed by
+    POSITION: keys and values, a latent), ``window_counters(cfg, stats,
+    real_rows, steps)`` and ``few_rows_here`` (`looplm`'s); where it
+    keeps a RECURRENT state as well, overwritten in place and no function
+    of the positions, ``state_bytes(cfg, rows)``; where its state is of
+    more than one geometry or kind, ``kv_cache_bytes_by_kind`` -> the
+    parts by name (a ring and a full cache; recurrent and positional).
+    Its config gives ``vocab_size`` and ``layer_applications`` (per
+    token)."""
     module: str
     names: Tuple[str, ...]          # what a model name of it contains
     row_counts: Tuple[int, ...]
@@ -1360,6 +1365,24 @@ LM_FAMILIES = {
         "K-EXAONE-236B-A23B, one chip's share: window and full attention "
         "layers in one stack (a 128-slot ring beside a full cache, GQA "
         "64/8), 16 of 128 routed experts held"),
+    # A row's state is 76.4 MB whatever its length (36 Mamba layers x 2.1
+    # MB of float32 and a 26 KB tail) and 17.3 MB of keys and values at
+    # 2112 positions (4 attention layers): 0.37 GB at 4 rows beside 6.4 GB
+    # resident, so memory would take a dozen rows.  What argues for 4 is
+    # what argues for it in the other three: the rows are the requests
+    # waiting in one server's queue (four callers in the cell), every
+    # count is a program to compile at set-up, and the PREFILL (2048
+    # positions a row here, as much of an execution as the decode) is
+    # compute-bound, so a row adds its positions' FLOPs whole while only
+    # the decode's weight stream is shared.  The state a step reads and
+    # writes grows with the rows (151 MB a row a step, where a dense
+    # model's cache at 128 positions is a few MB): at 4 rows 0.6 GB beside
+    # the weights' 6.4, at 16 it would be a third of the step.
+    "granite": LMFamily(
+        "ssm_hybrid", ("granite",), LM_ROW_COUNTS,
+        "granite-4.0-h-micro, whole: Mamba-2 state-space layers with an "
+        "attention layer every ten (a recurrent state beside a key-value "
+        "cache), a tied embedding"),
 }
 
 
@@ -1558,6 +1581,11 @@ class LanguageModel:
         by_kind = getattr(self._arch, "kv_cache_bytes_by_kind", None)
         for kind, nbytes in (by_kind(*shape) if by_kind else {}).items():
             trace_mod.GLOBAL_GAUGES.set(f"lm.kv_cache_bytes_{kind}", nbytes)
+        # and one with a recurrent state, what that takes beside the cache
+        recurrent = getattr(self._arch, "state_bytes", None)
+        if recurrent is not None:
+            trace_mod.GLOBAL_GAUGES.set("lm.state_bytes",
+                                        recurrent(self.cfg, count))
         return out
 
     def generate(self, text: str, seed: int = 0, max_new_tokens: int = 64,
@@ -1590,7 +1618,8 @@ def load_language_model(name: str, models_dir: Optional[str] = None
     its next request).  Where the second cannot fit beside what is
     resident (Ouro-2.6B's 5.3 GB and openPangu's 9.8 GB share do not
     share one 16 GB chip with a checkpoint, nor K-EXAONE's 7.4 GB share
-    with openPangu's) it is refused BY NAME, with
+    with openPangu's, nor granite-4.0-h-micro's 6.4 GB with either
+    share) it is refused BY NAME, with
     what it needs and what is resident, before the allocator fails with
     an error that names nothing."""
     from comfyui_distributed_tpu.models.tokenizer import make_lm_tokenizer

@@ -289,9 +289,10 @@ def _qkv(cfg: ExaoneMoeConfig, kind: str, lp, x, positions):
                                None) for t in (q, k, v))
 
 
-def _attend(q, k, v, q_positions, **mask):
+def _attend(q, k, v, q_positions, scale=None, **mask):
     """``q [B, N, H, D]`` at ``q_positions [N]`` against ``k``, ``v
-    [B, M, G, D]`` under the rest of `visible_keys`' ``mask``: the
+    [B, M, G, D]`` under the rest of `visible_keys`' ``mask``, the scores
+    times ``scale`` (default ``1 / sqrt(D)``): the
     ``H / G`` query heads of a group meet the group's one key-value head
     as ``H / G`` queries a position of a ``G``-headed call, head ``h`` in
     group ``h // (H / G)``.  -> ``[B, N, H * D]``."""
@@ -299,7 +300,8 @@ def _attend(q, k, v, q_positions, **mask):
     G = k.shape[2]
     grouped = q.reshape(B, N, G, H // G, D).swapaxes(2, 3)
     out = xla_attention(
-        grouped.reshape(B, N * (H // G), G, D), k, v, 1.0 / math.sqrt(D),
+        grouped.reshape(B, N * (H // G), G, D), k, v,
+        1.0 / math.sqrt(D) if scale is None else scale,
         jnp.repeat(q_positions, H // G), **mask)
     return out.reshape(B, N, H // G, G, D).swapaxes(2, 3).reshape(B, N, -1)
 

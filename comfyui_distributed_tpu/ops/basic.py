@@ -1007,9 +1007,12 @@ class LanguageModelLoader(Op):
     (``registry.LM_FAMILIES``): ``ouro`` (models/looplm.py, Ouro-2.6B, a
     dense looped decoder), ``pangu`` (models/mla_moe.py, one chip's
     share of openPangu-Ultra-MoE-718B: latent attention with a latent
-    cache, routed experts) or ``exaone`` (models/swa_moe.py, one chip's
+    cache, routed experts), ``exaone`` (models/swa_moe.py, one chip's
     share of K-EXAONE-236B-A23B: window and full attention layers in one
-    stack, routed experts).  A name of none is refused.  The model's
+    stack, routed experts) or ``granite`` (models/ssm_hybrid.py,
+    granite-4.0-h-micro whole: Mamba-2 state-space layers with an
+    attention layer every ten, a recurrent state beside a key-value
+    cache).  A name of none is refused.  The model's
     safetensors and ``tokenizer.json`` from the models dir if present;
     otherwise seeded weights made on the device and the hash tokenizer
     pair."""
@@ -1101,8 +1104,9 @@ class SaveLanguageModelOutput(Op):
     ``prompt_ids``, ``tokens [N]``, ``logits [N, V]`` float32 and the
     family's other per-position arrays of the request's row (a looped
     model's ``exit_probs [N, R]``; an expert model's ``router_scores
-    [N, Le, E]`` and ``expert_choices [N, Le, k]``), for comparison with
-    a reference (benchmarks/chip/verify_lm.py, verify_lm_moe.py)."""
+    [N, Le, E]`` and ``expert_choices [N, Le, k]``; the state-space
+    model's none), for comparison with a reference
+    (benchmarks/chip/verify_lm.py, verify_lm_moe.py, verify_lm_ssm.py)."""
     TYPE = "SaveLanguageModelOutput"
     WIDGETS = ["filename_prefix"]
     DEFAULTS = {"filename_prefix": "lm_output"}

@@ -17,6 +17,11 @@ Several weights of one shape that meet the same ``x`` (q / k / v;
 gate / up) go through ONE call: a launch whose first tile's DMA nothing
 hides costs a quarter of an 8 MB product.
 
+A TIED embedding ``[V, K]`` read as the head is the same product with the
+weight transposed: `fewrow_dense_t` streams it in blocks of whole rows
+(``[tv, K]``: contiguous as it lies) and contracts the second axis of
+both operands on the MXU, so no transposed copy of the leaf is made.
+
 Blocks: ``block_sizes`` keeps a tile's columns whole (``[tk, N]``: one
 contiguous run of the tiled HBM layout) where ``N`` allows and walks
 ``K``; a wider weight is walked over ``N`` first, ``K`` inside, the
@@ -127,3 +132,49 @@ def fewrow_dense(x: jax.Array, leaves: Sequence[jax.Array],
         interpret=interpret,
         name=name,
     )(layer, x, *leaves))
+
+
+def _kernel_t(x_ref, w_ref, o_ref):
+    """One grid step: a block of the weight's ROWS against the rows of
+    ``x``, both contracted over their second axis."""
+    o_ref[...] = jax.lax.dot_general(
+        x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def fewrow_dense_t(x: jax.Array, leaf: jax.Array, *,
+                   block_rows: Optional[int] = None,
+                   name: str = "fewrow_dense_t",
+                   interpret: bool = False) -> jax.Array:
+    """``x [rows, K]`` times the TRANSPOSE of ``leaf [N, K]`` (of ``x``'s
+    dtype), ``[rows, N]`` in float32: what ``jnp.dot(x, leaf.T,
+    preferred_element_type=float32)`` gives, the leaf read once, in
+    blocks of ``block_rows`` whole rows (default: as many as
+    ``TILE_BYTES`` hold).  ``K`` and ``N`` are multiples of 128."""
+    n, k = leaf.shape
+    if x.ndim != 2 or x.shape[1] != k or k % LANES or n % LANES \
+            or leaf.dtype != x.dtype:
+        raise ValueError(
+            f"fewrow_dense_t: {x.dtype}{list(x.shape)} against the "
+            f"transpose of {leaf.dtype}{list(leaf.shape)}: one dtype, "
+            f"[rows, K] and [N, K], K and N multiples of {LANES}")
+    rows = x.shape[0]
+    itemsize = jnp.dtype(x.dtype).itemsize
+    tn = block_rows or _largest_divisor(n, TILE_BYTES // itemsize // k)
+    return pl.pallas_call(
+        _kernel_t,
+        out_shape=jax.ShapeDtypeStruct((rows, n), jnp.float32),
+        grid=(n // tn,),
+        in_specs=[pl.BlockSpec((rows, k), lambda j: (0, 0)),
+                  pl.BlockSpec((tn, k), lambda j: (j, 0))],
+        out_specs=pl.BlockSpec((rows, tn), lambda j: (0, j)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows * k * n, transcendentals=0,
+            bytes_accessed=k * n * itemsize + rows * n * 4
+            + rows * k * itemsize),
+        interpret=interpret,
+        name=name,
+    )(x, leaf)

@@ -29,8 +29,9 @@ Here profiling is a first-class subsystem:
   attributed to the executing workflow node (:func:`node_scope`) — the
   software-measurable proxy for "tensors never leave HBM";
 - attention and GEGLU call sites by the path each took
-  (:data:`ATTENTION_PATHS`, :data:`GEGLU_PATHS`), counted while a program
-  is traced;
+  (:data:`ATTENTION_PATHS`, :data:`GEGLU_PATHS`) and a language model's
+  weight products by lowering (:data:`DENSE_PATHS`), counted while a
+  program is traced;
 - retrace/compile counters (:class:`RetraceStats`) fed by
   ``jax.monitoring`` events, telling a compile from a cache load, with
   their seconds: a steady-state serving process must report ZERO new
@@ -228,9 +229,12 @@ OTHER = "other"
 # written in ``attn1`` itself (``.../attn1/bnhd,bmhd->bhnm/dot_general``)
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
 _UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
-_MOE, _SWA = "PanguUltraMoE", "ExaoneMoe"
+_MOE, _SWA, _SSM = "PanguUltraMoE", "ExaoneMoe", "GraniteMoeHybrid"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
+    # the Mamba mixer's gated RMSNorm is published as ``mamba/norm``: ahead
+    # of the row below, which would take the name for the image models'
+    ("lm_ssm", _SSM, r"norm"),
     ("norm", None, r"GroupNorm_\d+|LayerNorm_\d+|(?:in_|out_)?norm\d*"
                    r"|ln\d+|ln_final"),
     ("attn_proj", _UNET, r"to_q|to_k|to_v|to_out|proj_in|proj_out"),
@@ -303,10 +307,29 @@ KERNEL_CLASSES = (
     ("lm_head", _SWA, r"lm_head|sample"),
     ("embed", _SWA, r"embed_tokens"),
     ("lm_proj", _SWA, r"dense_layers|moe_layers|prefill|decode|ExaoneMoe"),
+    # the decoder of state-space (Mamba-2) and attention layers
+    # (models/ssm_hybrid.py), under the same classes, and two more.
+    # ``lm_ssm`` is everything in a Mamba mixer that is no product with a
+    # weight: the convolution, the discretisation, the chunked scan or the
+    # state's step, the ``D`` skip, the gated norm (the first row of
+    # all).  ``lm_state`` is the recurrent state's and the convolution
+    # tail's read and write, as ``lm_cache`` is the positional cache's.
+    # The mixer's two projections are ``lm_proj``, as q / k / v / o are
+    ("lm_norm", _SSM, r"(?:input|post_attention)_layernorm|final_norm"),
+    ("lm_proj", _SSM, r"[qkvo]_proj|in_proj|out_proj"),
+    ("lm_cache", _SSM, r"kv_cache"),
+    ("lm_state", _SSM, r"ssm_state|conv_state"),
+    ("lm_ssm", _SSM, r"mamba|conv1d|ssm"),
+    ("lm_attn", _SSM, r"self_attn"),
+    ("lm_mlp", _SSM, r"shared_mlp|input_linear|output_linear"),
+    ("lm_head", _SSM, r"lm_head|sample"),
+    ("embed", _SSM, r"embed_tokens"),
+    ("lm_proj", _SSM, r"mamba_layers|attention_layers|prefill|decode"
+                      r"|GraniteMoeHybrid"),
 )
 # the outer scopes a program may put directly under its model's: where it
 # does, a trace summary gives its seconds by PHASE beside its seconds by
-# class (the three language models' ``generate`` do; the denoise, VAE and
+# class (the four language models' ``generate`` do; the denoise, VAE and
 # text programs do not and have no phases).  A program that the
 # persistent compile cache LOADS carries the names of the tree that
 # compiled it (JAX keys a program without a Pallas kernel with its debug
@@ -318,7 +341,8 @@ PHASES = ("prefill", "decode")
 SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
 _MODEL_OF = re.compile(
-    r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE|ExaoneMoe)(?:\.\w+)?$")
+    r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE|ExaoneMoe"
+    r"|GraniteMoeHybrid)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 
@@ -524,6 +548,13 @@ ATTENTION_PATHS = CounterStats()
 # kernel, ``xla``: the module as written), counted the same way
 # (models/layers.py:geglu_path).
 GEGLU_PATHS = CounterStats()
+
+# A language model's products with a resident weight by the lowering each
+# took and the rows that met it (``fewrow_few``: the weight-streaming
+# Pallas kernel; ``xla_one`` / ``xla_few`` / ``xla_many``: ``jnp.dot``;
+# ``*_tied_*``: a tied embedding read as the head), counted the same way
+# (models/looplm.py:dense_path).
+DENSE_PATHS = CounterStats()
 
 
 class GaugeStats:
@@ -928,7 +959,8 @@ def counters_snapshot() -> Dict[str, Any]:
     return {"transfers": GLOBAL_TRANSFERS.snapshot(),
             "retraces": GLOBAL_RETRACES.mark(),
             "attention_paths": ATTENTION_PATHS.snapshot(),
-            "geglu_paths": GEGLU_PATHS.snapshot()}
+            "geglu_paths": GEGLU_PATHS.snapshot(),
+            "dense_paths": DENSE_PATHS.snapshot()}
 
 
 # --- request-scoped distributed tracing (spans) ------------------------------

@@ -98,11 +98,21 @@ _EXAONE_ONLY = {
     "lm_prefill_flops_util_pct": "the FLOPs of a 512-position prefill under "
                                  "a phase scope only that program has",
 }
+# (PR 40: the two shares of the decoder of state-space and attention layers)
+GRANITE4 = "granite_expand_sd15_512_sat4"
+_GRANITE_ONLY = {
+    "lm_ssm_decode_hbm_roofline_pct": "the bytes of a decoder that reads "
+                                      "and writes a recurrent state, from "
+                                      "counters only its program has",
+    "lm_ssm_prefill_flops_util_pct": "the FLOPs of a prefill with a "
+                                     "recurrence, over positions only that "
+                                     "program counts",
+}
 NOT_IN_SAT4 = {
     "chip_busy_min_pct": _ONE_CHIP,
     "lm_moe_decode_hbm_roofline_pct": "the bytes of a decoder with routed "
                                       "experts: Ouro has none to count",
-    **_EXAONE_ONLY,
+    **_EXAONE_ONLY, **_GRANITE_ONLY,
 }
 NOT_IN_PANGU4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -110,7 +120,7 @@ NOT_IN_PANGU4 = {
                                   "decoder's and would be false here: "
                                   "lm_moe_decode_hbm_roofline_pct stands "
                                   "in its place",
-    **_EXAONE_ONLY,
+    **_EXAONE_ONLY, **_GRANITE_ONLY,
 }
 NOT_IN_EXAONE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -119,6 +129,15 @@ NOT_IN_EXAONE4 = {
                                       "kv_lora_rank, a latent cache's: "
                                       "lm_swa_moe_decode_hbm_roofline_pct "
                                       "stands in its place",
+    **_GRANITE_ONLY,
+}
+NOT_IN_GRANITE4 = {
+    "chip_busy_min_pct": _ONE_CHIP,
+    "lm_decode_hbm_roofline_pct": NOT_IN_PANGU4["lm_decode_hbm_roofline_pct"],
+    "lm_moe_decode_hbm_roofline_pct": "it has no routed expert and no "
+                                      "latent cache",
+    **{name: "it has no routed expert, no ring and counts no local pairs: "
+             "its own two stand in their place" for name in _EXAONE_ONLY},
 }
 
 
@@ -208,8 +227,11 @@ def _exaone4_reports(m, cells):
         "lm_moe_decode_hbm_roofline_pct"}
     for x in m["per_layer"]:
         if x["name"] in _listed(m, EXAONE4) - _listed(m, PANGU4):
-            assert x["workloads"] == [EXAONE4] and x["layer"] == \
-                "Language model" and x["source"] == "device_trace"
+            # (PR 40's cell stands behind it where the reader is
+            # family-neutral)
+            assert x["workloads"] in ([EXAONE4], [EXAONE4, GRANITE4]) \
+                and x["layer"] == "Language model" \
+                and x["source"] == "device_trace"
     cfg = _config(exaone["config"])
     _same_graph_but(cfg, _config("pangu-ultra-moe-expand-sd15-512"),
                     {"20", "21"})
@@ -219,8 +241,38 @@ def _exaone4_reports(m, cells):
     assert (node["prompt_tokens"], node["max_new_tokens"],
             node["temperature"]) == (512, 64, 0.0)
     assert len(node["instructions"].split()) == 450
-    # 8 of at most 24 cells, still one on four chips
-    assert len(m["workloads"]) == 8
+    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
+        ["sdxl_1024_fanout4"]
+
+
+def _granite4_reports(m, cells):
+    """The cell PR 40 appended: K-EXAONE's with a fourth language model in
+    front (whole: nothing reduced) behind a 2048-id prompt; it lists what
+    that cell lists but the four readers whose counts are K-EXAONE's, and
+    four readers of its own."""
+    granite = cells[GRANITE4]
+    assert granite["config"] == "granite-4.0-h-micro-expand-sd15-512"
+    own = _listed(m, GRANITE4) - _listed(m, EXAONE4)
+    assert own == {*_GRANITE_ONLY, "lm_ssm_device_s_per_request",
+                   "lm_prefill_ssm_device_s_per_request"}
+    assert _listed(m, EXAONE4) - _listed(m, GRANITE4) == {
+        *_EXAONE_ONLY, "lm_experts_device_s_per_request",
+        "lm_prefill_experts_device_s_per_request"}
+    for x in m["per_layer"]:
+        if x["name"] in own:
+            assert x["workloads"] == [GRANITE4] and x["layer"] == \
+                "Language model" and x["source"] == "device_trace"
+    cfg = _config(granite["config"])
+    _same_graph_but(cfg, _config("k-exaone-236b-expand-sd15-512"),
+                    {"20", "21"})
+    assert cfg["reduced"] == [] and cfg["graph"]["20"]["inputs"] == {
+        "model_name": "granite-4.0-h-micro.safetensors"}
+    node = cfg["graph"]["21"]["inputs"]
+    assert (node["prompt_tokens"], node["max_new_tokens"],
+            node["temperature"]) == (2048, 64, 0.0)
+    assert len(node["instructions"].split()) == 1950
+    # 9 of at most 24 cells, still one on four chips
+    assert len(m["workloads"]) == 9
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
         ["sdxl_1024_fanout4"]
 
@@ -229,7 +281,8 @@ def _exaone4_reports(m, cells):
     (SAT4, NOT_IN_SAT4, _sat4_reports),
     (PANGU4, NOT_IN_PANGU4, _pangu4_reports),
     (EXAONE4, NOT_IN_EXAONE4, _exaone4_reports),
-], ids=[SAT4, PANGU4, EXAONE4])
+    (GRANITE4, NOT_IN_GRANITE4, _granite4_reports),
+], ids=[SAT4, PANGU4, EXAONE4, GRANITE4])
 def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
         cell, leaves_out, reports):
     m = _manifest()
@@ -238,7 +291,7 @@ def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
     assert {k: this[k] for k in ("traffic", "chips")} == \
         {k: four[k] for k in ("traffic", "chips")}
     assert len(this["why"]) <= 200
-    if cell != SAT4:
+    if cell in (PANGU4, EXAONE4):        # one chip's share of the experts
         assert "attention sees more than its share" in this["why"]
     reported = {x["name"] for x in m["end_to_end"]
                 if cell in x.get("workloads", [cell])}

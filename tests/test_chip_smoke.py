@@ -97,6 +97,20 @@ def test_lm_phase_holds_the_served_logits_to_the_reference(tmp_path):
         assert row["positions"] == 4 and row["argmax_agree"] == 1.0
         assert row["mean_over_std"] <= rows["limits"]["mean_over_std"]
         assert row["max_over_std"] <= rows["limits"]["max_over_std"]
+    # and the state-space model (PR 40), alone and four rows of unequal
+    # length together, against a reference of its own; the four readings
+    # that have to fail do
+    ssm = summary["smoke_facts"]["language_model_ssm"]
+    assert ssm["rows"] == 5 and ssm["positions"] == 4
+    assert ssm["together"]["executions"] == 1 \
+        and ssm["together"]["rows"] == 4
+    assert ssm["mean_over_std"] <= ssm["limits"]["mean_over_std"]
+    for reading in ("state_bf16", "cache_8bit", "weights_8bit",
+                    "skip_dropped"):
+        assert ssm[reading] > ssm["limits"]["mean_over_std"], reading
+    with open(tmp_path / "out" / "verify_lm_ssm"
+              / "verify_lm_ssm.json") as f:
+        assert json.load(f)["ok"] is True
 
 
 def test_default_mode_refuses_a_cpu_pinned_jax(tmp_path):

@@ -13,14 +13,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import looplm, mla_moe, swa_moe
+from comfyui_distributed_tpu.models import looplm, mla_moe, ssm_hybrid, \
+    swa_moe
 from comfyui_distributed_tpu.ops.pallas import fewrow_dense as fd
 from comfyui_distributed_tpu.utils import trace
 
 OURO, PANGU = looplm.OURO_2_6B, mla_moe.OPENPANGU_ULTRA_MOE_SHARE
 EXAONE = swa_moe.K_EXAONE_SHARE
+GRANITE = ssm_hybrid.GRANITE_4_0_H_MICRO
 FAMILIES = {"ouro": (looplm, OURO), "pangu": (mla_moe, PANGU),
-            "exaone": (swa_moe, EXAONE)}
+            "exaone": (swa_moe, EXAONE), "granite": (ssm_hybrid, GRANITE)}
 
 # every product `_dense` makes with a resident leaf at the published
 # sizes: (family, name, K, N, leaves streamed by one call)
@@ -46,6 +48,14 @@ PRODUCTS = [
     ("exaone", "shared gate_proj+up_proj", 6144, 2048, 2),
     ("exaone", "shared down_proj", 2048, 6144, 1),
     ("exaone", "lm_head", 6144, 19200, 1),
+    # (PR 40; its head is the tied embedding, transposed: further down)
+    ("granite", "in_proj_zx", 2048, 8448, 1),
+    ("granite", "out_proj", 4096, 2048, 1),
+    ("granite", "input_linear", 2048, 16384, 1),
+    ("granite", "output_linear", 8192, 2048, 1),
+    ("granite", "q_proj", 2048, 2048, 1),
+    ("granite", "k_proj+v_proj", 2048, 512, 2),
+    ("granite", "o_proj", 2048, 2048, 1),
 ]
 IDS = [f"{p[0]}-{p[1]}" for p in PRODUCTS]
 
@@ -193,6 +203,53 @@ def test_operands_the_blocks_cannot_take_are_refused_by_name(
                         [jnp.zeros(leaf_shape, dtype)], interpret=True)
 
 
+# --- a tied embedding read as the head (PR 40) ------------------------------------
+
+@pytest.mark.parametrize("rows", [2, 4, 8])
+@pytest.mark.parametrize("n, k, block_rows", [
+    (384, 256, None),               # one block
+    (640, 256, 128),                # the rows of the leaf walked
+])
+def test_the_transposed_kernel_is_jnp_dot_with_the_leaf_transposed(
+        rows, n, k, block_rows):
+    x, (w,) = operands(rows, 1, n, k, 1, seed=rows)    # w[0]: [n, k]
+    leaf = w[0]
+    x = x[:, :k] if n >= k else jnp.tile(x, (1, -(-k // n)))[:, :k]
+    y = fd.fewrow_dense_t(x, leaf, block_rows=block_rows, interpret=True)
+    want = jnp.dot(x, leaf.T, preferred_element_type=jnp.float32)
+    assert y.dtype == jnp.float32 and y.shape == (rows, n)
+    np.testing.assert_allclose(y, want, rtol=0, atol=4e-6 * np.sqrt(k))
+    with pytest.raises(ValueError, match="fewrow_dense_t"):
+        fd.fewrow_dense_t(x[:, :k - 56], leaf[:, :k - 56], interpret=True)
+
+
+def test_the_tied_head_takes_the_kernel_where_a_head_would(monkeypatch):
+    """`dense_tied` asks `dense_path` about the ``[d, V]`` weight the
+    leaf is the transpose of: the kernel at 2 to 8 rows on a TPU, a
+    ``dot_general`` over the second axis of both everywhere else; both
+    give ``x @ leaf.T``."""
+    leaf = (jax.random.normal(jax.random.PRNGKey(1), (1024, 512),
+                              jnp.float32) / 16).astype(jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(2), (4, 1, 512), jnp.float32)
+    want = jnp.dot(x.astype(jnp.bfloat16), leaf.T,
+                   preferred_element_type=jnp.float32)
+    np.testing.assert_allclose(looplm.dense_tied(x, leaf, GRANITE), want,
+                               atol=1e-4)
+    monkeypatch.setattr(looplm, "_where", lambda: ("tpu", None))
+    calls = []
+    monkeypatch.setattr(looplm, "fewrow_dense_t", lambda x, leaf: (
+        calls.append(x.shape), fd.fewrow_dense_t(x, leaf, interpret=True))[1])
+    before = trace.DENSE_PATHS.snapshot().get("fewrow_tied_few", 0)
+    np.testing.assert_allclose(looplm.dense_tied(x, leaf, GRANITE), want,
+                               atol=1e-4)
+    assert calls == [(4, 512)]
+    assert trace.DENSE_PATHS.snapshot()["fewrow_tied_few"] == before + 1
+    looplm.dense_tied(x[:1], leaf, GRANITE)
+    assert calls == [(4, 512)]                       # one row: no kernel
+    tn = fd._largest_divisor(100352, fd.TILE_BYTES // 2 // 2048)
+    assert tn == 1024 and 100352 % tn == 0
+
+
 # --- the two scan structures, held together on the tiny models --------------------
 
 def tiny_run(arch, cfg, rows, where, monkeypatch):
@@ -206,7 +263,7 @@ def tiny_run(arch, cfg, rows, where, monkeypatch):
         np.asarray, (aux, stats))
 
 
-@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone"])
+@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite"])
 def test_a_scan_over_the_index_gives_what_a_scan_over_the_slices_gives(
         family, monkeypatch):
     """With the platform read as a TPU's the 4-row decode walks the layer
@@ -244,7 +301,7 @@ def traced(family, rows, where, monkeypatch, sharding=None, positions=64):
         spec((rows,), np.uint32), spec((rows,), np.float32))
 
 
-@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone"])
+@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite"])
 def test_the_one_row_program_is_untouched_by_the_rule(family, monkeypatch):
     """Its text as lowered with the platform read as a TPU's is, byte for
     byte, its text with the rule off; and the 4-row program's is not."""
@@ -363,6 +420,7 @@ def weights_of(family):
     # still have XLA slice `kv_b_proj`'s layer out: PERF.md section 7
     ("pangu", {512 * 32768}),
     ("exaone", set()),
+    ("granite", set()),
 ])
 def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
         family, known, one_chip, no_compile_cache, monkeypatch):
@@ -381,6 +439,8 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
     whole = {tuple(s) for s in matrices} \
         | {(1, *s) for s in matrices if len(s) == 2}
     for call in calls:
+        if "fewrow_dense_t" in call:     # a tied leaf [V, d]: further down
+            continue
         constraints = call.split("operand_layout_constraints=")[1] \
             .split("frontend_attributes")[0]
         weights = [tuple(int(d) for d in dims) for dims in re.findall(
@@ -395,6 +455,8 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
     paths = {p for l in calls for p in re.findall(r'op_name="([^"]+)"', l)}
     classes = {trace.classify(p) for p in paths}
     assert classes == {"lm_proj", "lm_mlp", "lm_head"}, paths
+    if family == "granite":
+        _granite_decode_keeps_its_state_in_place(text, bodies)
     segments = {seg for p in paths for seg in p.split("/")}
     want = {"ouro": {"q_proj", "o_proj", "gate_proj", "down_proj", "lm_head",
                      "fewrow_dense_q_proj_k_proj_v_proj",
@@ -404,8 +466,36 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
             "exaone": {"q_proj", "o_proj", "gate_proj", "down_proj",
                        "shared_experts", "lm_head",
                        "fewrow_dense_k_proj_v_proj",
-                       "fewrow_dense_gate_proj_up_proj"}}[family]
+                       "fewrow_dense_gate_proj_up_proj"},
+            "granite": {"in_proj", "out_proj", "input_linear",
+                        "output_linear", "q_proj", "o_proj", "lm_head",
+                        "fewrow_dense_k_proj_v_proj",
+                        "fewrow_dense_t"}}[family]
     assert want <= segments, want - segments
+
+
+def _granite_decode_keeps_its_state_in_place(text, bodies):
+    """The tied leaf is read where it lies (the transposed kernel's
+    operand is the ``[V, d]`` parameter), and no computation that holds a
+    kernel call (a decode step's own or a layer scan's body) writes a
+    buffer of the whole recurrent state or of a whole cache but by an
+    in-place ``dynamic-update-slice`` (or a fusion rooted in one): 302 MB
+    of state at 4 rows would be 0.7 ms a step to copy."""
+    tied = [l for l in text.splitlines()
+            if "custom-call(" in l and "fewrow_dense_t" in l]
+    assert tied and all(
+        "bf16[100352,2048]{1,0}" in l.split(
+            "operand_layout_constraints=")[1] for l in tied)
+    state = {"f32[36,4,64,64,128]", "bf16[4,4,128,8,64]"}
+    for name, lines in bodies.items():
+        for line in lines:
+            m = INSTRUCTION.match(line)
+            if not m or m["op"] in PASSES_ON \
+                    or "dynamic-update-slice" in m["name"] \
+                    or "dynamic_update_slice" in line:
+                continue
+            assert f"{m['dtype']}[{m['dims']}]" not in state, \
+                (name, line.strip()[:200])
 
 
 @pytest.mark.parametrize("family", ["pangu", "exaone"])

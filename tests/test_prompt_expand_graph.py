@@ -398,6 +398,35 @@ def test_ids_outside_the_vocabulary_are_refused_on_the_host(tmp_path):
 
 @pytest.mark.parametrize("mesh, devices, images", [
     ("data=1", 1, 1), ("data=2,tensor=2", 4, 2)])
+def test_the_long_shot_graph_runs_with_the_fourth_family(mesh, devices,
+                                                         images, tmp_path):
+    """``workflows/prompt-expand-longshot-txt2img.json`` (PR 40): a model
+    of the family with a recurrent state beside a key-value cache behind
+    the same nodes, on one device and under a mesh (weights replicated
+    over ``data``, column-split over ``tensor``): the same expansion, no
+    family-specific line in the nodes or the executor."""
+    got = probe("prompt-expand-longshot-txt2img.json", tmp_path, devices,
+                DTPU_MESH_SHAPE=mesh, DTPU_TP_MIN_SHARD_ELEMENTS="64")
+    assert got["images"] == images
+    assert got["lm_resident"] == ["lm:granite-4.0-h-micro.safetensors:"]
+    assert got["text"].startswith("a lighthouse on a cliff at dawn, ")
+    assert len(got["text"].split()) == 7 + 4
+    # a call site a run of attention layers, in the programs of both row
+    # counts: two runs, prefill and decode
+    assert {k: got["paths"][k] for k in ("xla_causal", "xla_decode")} == {
+        "xla_causal": 4, "xla_decode": 4}
+    # 32 prompt positions in 4 chunks of 8 and 4 steps, 4 Mamba blocks;
+    # 32 prompt ids, all real (the instructions fill the buffer), and
+    # what was decoded, over 2 attention blocks
+    counters = got["counters"]
+    assert counters["lm.prefill_positions"] == 32
+    assert counters["lm.scan_chunks"] == 4 * 4
+    assert counters["lm.state_steps"] == 4 * 4
+    assert counters["lm.keys_attended_full"] == 2 * (33 + 34 + 35 + 36)
+
+
+@pytest.mark.parametrize("mesh, devices, images", [
+    ("data=1", 1, 1), ("data=2,tensor=2", 4, 2)])
 def test_the_few_shot_graph_runs_with_the_third_family(mesh, devices, images,
                                                        tmp_path):
     """``workflows/prompt-expand-fewshot-txt2img.json`` (PR 34): the

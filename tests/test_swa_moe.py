@@ -693,7 +693,7 @@ def test_a_model_name_names_the_third_family(name, want, monkeypatch):
     monkeypatch.delenv("DTPU_DEFAULT_FAMILY", raising=False)
     assert registry.detect_lm_family(name) == want
     with pytest.raises(ValueError) as e:
-        registry.detect_lm_family("granite-4.0-h-micro.safetensors")
+        registry.detect_lm_family("a-decoder-of-no-family-7b.safetensors")
     assert "exaone" in str(e.value)
 
 

@@ -36,6 +36,10 @@ LM_CLASSES = {"lm_attn", "lm_proj", "lm_mlp", "lm_norm", "lm_cache",
 # the expert model's (PR 32; tests/test_mla_moe.py holds its rows): the
 # same classes and one for everything routing adds
 MOE_CLASSES = LM_CLASSES | {"lm_experts"}
+# the state-space hybrid's (PR 40; tests/test_ssm_hybrid.py holds its
+# rows): the same classes and two for the Mamba mixer's own work and its
+# recurrent state
+SSM_CLASSES = LM_CLASSES | {"lm_ssm", "lm_state"}
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
@@ -57,7 +61,7 @@ def compiled_op_names(fn):
 def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
     classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
     assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
-        | LM_CLASSES | MOE_CLASSES | {"vae_attn"}
+        | LM_CLASSES | MOE_CLASSES | SSM_CLASSES | {"vae_attn"}
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; from comfyui_distributed_tpu.utils.trace import "
