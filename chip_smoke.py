@@ -51,7 +51,13 @@ which the server runs as the four rows of ONE execution, each row held
 to the same reference inside the same limits; then the state-space
 language model (granite-4.0-h-micro) the same two ways behind its
 2048-position prompt, against a reference of its own
-(``benchmarks/chip/verify_lm_ssm.py``).
+(``benchmarks/chip/verify_lm_ssm.py``; its requests share the operator's
+1,950 instruction ids, so what is served there starts from their
+snapshot), and a child of this script (`lm_prefix_child`) that holds
+four rows started from that snapshot to the float32 reference of the
+WHOLE prompt (logits, the state behind the prompt, the greedy ids) and
+to the full path over the same prompts.  ``lm_ssm`` runs those last two
+alone.
 """
 
 from __future__ import annotations
@@ -73,7 +79,7 @@ import uuid
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DEFAULT_PHASES = ("sdxl", "upscale", "kernels")
-PHASES = DEFAULT_PHASES + ("lm",)
+PHASES = DEFAULT_PHASES + ("lm", "lm_ssm")
 SDXL_SEEDS = (777, 100777, 200777)   # far apart: fan-out replica r adds r
 
 # (q [B, N, H, D], kv length M) at a CFG-stacked batch of 2: the
@@ -554,22 +560,30 @@ def geglu_shapes(rehearse: bool, platform: str, failures: list) -> list:
     return rows
 
 
-def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
-    log_path = os.path.join(out_dir, "kernels.stderr.log")
-    cmd = [sys.executable, os.path.abspath(__file__), "--kernel-child"]
-    if cfg["rehearsal"]:
-        cmd.append("--rehearse")
+def child_report(cfg: dict, out_dir: str, env: dict, what: str, cmd: list,
+                 timeouts: float = 1.0) -> tuple:
+    """A child that owns the device while it lives and prints its report
+    as its last line: ``(exit code, report, the end of its stderr)``,
+    the stderr kept in ``<out>/<what>.stderr.log``."""
+    log_path = os.path.join(out_dir, f"{what}.stderr.log")
     with open(log_path, "wb") as log:
-        proc = subprocess.run(cmd, cwd=out_dir, env=env,
-                              stdout=subprocess.PIPE, stderr=log,
-                              timeout=cfg["first_timeout"])
+        proc = subprocess.run(
+            cmd + (["--rehearse"] if cfg["rehearsal"] else []), cwd=out_dir,
+            env=env, stdout=subprocess.PIPE, stderr=log,
+            timeout=timeouts * cfg["first_timeout"])
     with open(log_path, "rb") as f:
         tail = f.read()[-3000:].decode("utf-8", "replace")
-    check(proc.returncode == 0,
-          f"kernel child exited with code {proc.returncode}:\n{tail}")
     lines = proc.stdout.decode().strip().splitlines()
-    check(bool(lines), f"kernel child printed nothing:\n{tail}")
-    report = json.loads(lines[-1])
+    check(bool(lines), f"the {what} child printed nothing (exit "
+                       f"{proc.returncode}):\n{tail}")
+    return proc.returncode, json.loads(lines[-1]), tail
+
+
+def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    code, report, tail = child_report(
+        cfg, out_dir, env, "kernels",
+        [sys.executable, os.path.abspath(__file__), "--kernel-child"])
+    check(code == 0, f"kernel child exited with code {code}:\n{tail}")
     if result["device"] is None:     # a kernels-only run
         result["device"] = report["device"]
     result["smoke_facts"]["pallas_flash_attention"] = {
@@ -589,23 +603,12 @@ def verify_child(cfg: dict, out_dir: str, env: dict, script: str,
                  args: list, failed: str) -> dict:
     """``benchmarks/chip/<script>.py`` as a child (a server of its own,
     then the plain reference): its report, checked ``ok``."""
-    log_path = os.path.join(out_dir, f"{script}.stderr.log")
-    cmd = [sys.executable,
-           os.path.join(HERE, "benchmarks", "chip", f"{script}.py"), *args,
-           "--out", os.path.join(out_dir, script)]
-    if cfg["rehearsal"]:
-        cmd.append("--rehearse")
-    with open(log_path, "wb") as log:
-        proc = subprocess.run(cmd, cwd=out_dir, env=env,
-                              stdout=subprocess.PIPE, stderr=log,
-                              timeout=3 * cfg["first_timeout"])
-    with open(log_path, "rb") as f:
-        tail = f.read()[-3000:].decode("utf-8", "replace")
-    lines = proc.stdout.decode().strip().splitlines()
-    check(bool(lines), f"{script} printed nothing (exit "
-                       f"{proc.returncode}):\n{tail}")
-    report = json.loads(lines[-1])
-    check(proc.returncode == 0 and report["ok"],
+    code, report, tail = child_report(
+        cfg, out_dir, env, script,
+        [sys.executable,
+         os.path.join(HERE, "benchmarks", "chip", f"{script}.py"), *args,
+         "--out", os.path.join(out_dir, script)], 3)
+    check(code == 0 and report["ok"],
           f"{failed}: {json.dumps(report)}\n{tail}")
     return report
 
@@ -656,6 +659,247 @@ def lm_ssm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
         f"limits (worst mean {worst['mean_over_std']:.4f} of a standard "
         f"deviation; a bf16 state reads "
         f"{report['state_bf16']['mean_over_std']:.4f})")
+
+
+# --- rows started from a prefix's snapshot --------------------------------------
+
+# the user's words of the four rows: prompts of unequal length behind the
+# same instructions (the last is one word: fewer ids than the convolution
+# has taps of tail only where the template is cut too, which the CPU
+# tests hold; here the rows differ in their padding)
+PREFIX_ROW_WORDS = (12, 3, 20, 1)
+PREFIX_SEED = 4100000041
+# a row's state behind the prompt may differ from the reference's, in the
+# mean, by at most this many times what the full path's does (the path
+# it replaces: `KERNEL_ERR_RATIO`'s rule)
+STATE_ERR_RATIO = 1.25
+STATE_ERR_FLOOR = 1e-5      # the float32 rehearsal: both are a few ulps
+
+
+def lm_prefix_child(rehearse: bool) -> int:
+    """Runs in its own process (it owns the chip while it lives).  The
+    cell's configuration (granite-4.0-h-micro at its published widths,
+    the operator's 1,950 instruction ids, a 2048-position prompt, 64
+    greedy tokens; the rehearsal's tiny sizes with ``rehearse``), four
+    rows of unequal length:
+
+    * SERVED: `LanguageModel.generate_rows`, which finds the shared
+      prefix, makes its snapshot and runs the program that prefills the
+      ids behind it;
+    * the FULL path: ``lm_generate`` over the same four whole prompts;
+    * the plain float32 REFERENCE of each row's whole prompt, block by
+      block (``benchmarks/chip/reference/ssm_hybrid.py``), teacher-forced
+      over the served ids for the logits and over the prompt alone for
+      the state behind it.
+
+    Held: each served row's logits to the reference inside
+    ``verify_lm_ssm.py``'s limits (its greedy ids by the margin among
+    them); served against full path inside the same limits: the logits
+    at every step up to the first id that differs (greedy ids part
+    where a margin is under bf16's rounding, and the logits behind that
+    step are of other sequences), the tail and the keys behind the
+    prompt.  The recurrent STATE behind the prompt is held by its mean
+    error: against the reference inside the limit and at most
+    STATE_ERR_RATIO times what the full path's own state reads there,
+    and against the full path inside the limit.  Its largest error is
+    reported and not held: the state is heavy-tailed (most of its 18.9 M
+    values a row lie near 0, a few are hundreds of standard deviations
+    out), the accepted full path itself reads 5 to 8 standard deviations
+    at its worst element (my chip run, PR 41), and what a wrong state
+    does to a request is in the 64 steps of logits held above.  Prints
+    one JSON line; exit code 0 only if all of it holds."""
+    bench = os.path.join(HERE, "benchmarks", "chip")
+    sys.path[:0] = [HERE, bench]
+    if rehearse:
+        os.environ["DTPU_DEFAULT_FAMILY"] = "tiny"
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import run as chipbench
+    import verify_lm_ssm as verify
+    from reference import ssm_hybrid as ref
+
+    from comfyui_distributed_tpu.models import registry, ssm_hybrid
+    from comfyui_distributed_tpu.runtime.manager import \
+        enable_persistent_compile_cache
+    from comfyui_distributed_tpu.utils import trace
+
+    device = jax.devices()[0]
+    if device.platform != ("cpu" if rehearse else "tpu"):
+        raise SystemExit(f"lm prefix phase: platform is {device.platform!r}")
+    enable_persistent_compile_cache()
+    config = chipbench.load_json(os.path.join(
+        bench, "configs", "granite-4.0-h-micro-expand-sd15-512.json"))
+    if rehearse:
+        config = chipbench.rehearsal_config(config)
+    nodes = {n["class_type"]: n["inputs"] for n in config["graph"].values()}
+    node = nodes["LanguageModelGenerate"]
+    P, N = node["prompt_tokens"], node["max_new_tokens"]
+    model = registry.load_language_model(
+        nodes["LanguageModelLoader"]["model_name"])
+    cfg, params = model.cfg, model.params
+    fp32 = cfg.dtype == jnp.float32
+    limits = verify.LIMITS_FP32 if fp32 else verify.LIMITS
+    lm = {k: v for k, v in dataclasses.asdict(cfg).items()
+          if k not in ("dtype", "state_dtype")} if rehearse else config["lm"]
+
+    with open(os.path.join(bench, "traffic", "words.txt"),
+              encoding="utf-8") as f:
+        words = [w.strip() for w in f if w.strip()]
+    rng = np.random.default_rng(PREFIX_SEED)
+    rows = [registry.LMRow(" ".join(rng.choice(words, n)), seed=i,
+                           instructions=node["instructions"])
+            for i, n in enumerate(PREFIX_ROW_WORDS)]
+    ids = [model.prompt_ids(r.text, P, r.instructions) for r in rows]
+    prefix = model.shared_prefix(rows, P, ids)
+    if prefix is None:
+        raise SystemExit("lm prefix phase: the rule found no shared prefix")
+    K, B = len(prefix), len(rows)
+
+    before = trace.GLOBAL_COUNTERS.snapshot()
+    t0 = time.monotonic()
+    served = model.generate_rows(rows, N, P)
+    served_s = time.monotonic() - t0
+    counted = {k: v - before.get(k, 0)
+               for k, v in trace.GLOBAL_COUNTERS.snapshot().items()
+               if k.startswith("lm.") and v != before.get(k, 0)}
+    out0 = served[0][1]
+    tokens, logits = np.asarray(out0.tokens), np.asarray(out0.logits)
+
+    # the full path over the same whole prompts, and both paths' state
+    # behind the prompt
+    def buffer(held):
+        padded = np.full((B, P - held), model.tokenizer.pad_id, np.int32)
+        for b, i in enumerate(ids):
+            padded[b, :len(i) - held] = i[held:]
+        return padded, np.asarray([len(i) - held for i in ids], np.int32)
+
+    full_tokens, full_logits, _, _ = ssm_hybrid.make_program(cfg, N)(
+        params, *buffer(0), np.arange(B, dtype=np.uint32),
+        np.zeros(B, np.float32))
+    full_tokens, full_logits = map(np.asarray, (full_tokens, full_logits))
+    snapshot = model._snapshot(prefix)
+    behind = jax.jit(lambda p, i, n, s=None: ssm_hybrid.prefill(
+        cfg, p, i, n, P + N, s)[1])
+    state = jax.tree_util.tree_map(np.asarray,
+                                   behind(params, *buffer(K), snapshot))
+    full_state = jax.tree_util.tree_map(np.asarray,
+                                        behind(params, *buffer(0)))
+
+    @functools.partial(jax.jit, static_argnums=(0,))
+    def block(kind, stack, l, x):
+        lp = {name: ref.f32(jax.lax.dynamic_index_in_dim(
+            leaf, l, keepdims=False)) for name, leaf in stack.items()}
+        return ref.block(lm, kind, lp, x)
+
+    def reference_state(row_ids):
+        """The Mamba layers' states behind ``row_ids``' last id."""
+        table = jax.jit(ref.f32)(params["embed_tokens"])
+        x, at, states = ref.embed(lm, table, row_ids), {}, []
+        for kind in lm["layer_types"]:
+            stack = params[ssm_hybrid.STACKS[kind]]
+            x, last = block(kind, stack, jnp.int32(at.get(kind, 0)), x)
+            at[kind] = at.get(kind, 0) + 1
+            if last is not None:
+                states.append(np.asarray(last))
+        return np.stack(states)
+
+    def over_std(got, want, held=("max_over_std", "mean_over_std")):
+        std = float(np.std(want))
+        diff = np.abs(np.asarray(got, np.float64) - want)
+        reading = {"max_over_std": float(diff.max()) / std,
+                   "mean_over_std": float(diff.mean()) / std,
+                   "max_over_max": float(diff.max())
+                   / float(np.abs(want).max())}
+        reading["correct"] = all(reading[k] <= limits[k] for k in held)
+        return reading
+
+    mean_only = ("mean_over_std",)
+
+    report = {"device": {"platform": device.platform,
+                         "kind": device.device_kind},
+              "prefix_ids": K, "prompt_tokens": P, "new_tokens": N,
+              "served_s": served_s, "counted": counted, "limits": limits,
+              "rows": []}
+    for b in range(B):
+        n = len(ids[b])
+        row = {"prompt_ids": n, "own_ids": n - K}
+        this = {"prompt_ids": ids[b], "tokens": tokens[b],
+                "logits": logits[b]}
+        teacher, at = verify.rows_of(this)
+        want = np.asarray(verify.reference_logits(lm, params, teacher, at))
+        row["against_reference"] = verify.compare_logits(
+            logits[b], want, tokens[b], limits)
+        want_state = reference_state(ids[b])
+        row["state_against_reference"] = over_std(
+            state["ssm"][:, b], want_state, mean_only)
+        row["full_path_state_against_reference"] = over_std(
+            full_state["ssm"][:, b], want_state, mean_only)
+        row["state_against_reference"]["correct"] &= (
+            row["state_against_reference"]["mean_over_std"] <= max(
+                STATE_ERR_RATIO * row["full_path_state_against_reference"][
+                    "mean_over_std"], STATE_ERR_FLOOR))
+        # served against the full path: alike as far as their ids are
+        differ = np.flatnonzero(tokens[b] != full_tokens[b])
+        alike = int(differ[0]) + 1 if len(differ) else N
+        first = P - n            # the full path's cache: padding | prompt
+        row["against_full_path"] = {
+            "ids_alike": int((tokens[b] == full_tokens[b]).sum()),
+            "steps_compared": alike,
+            "logits": over_std(logits[b, :alike], full_logits[b, :alike]),
+            "ssm": over_std(state["ssm"][:, b], full_state["ssm"][:, b],
+                            mean_only),
+            "conv": over_std(state["conv"][:, b].astype(np.float32),
+                             full_state["conv"][:, b].astype(np.float32)),
+            "keys": over_std(
+                state["keys"][:, b, first:P].astype(np.float32),
+                full_state["keys"][:, b, first:P].astype(np.float32))}
+        row["correct"] = row["against_reference"]["correct"] \
+            and row["state_against_reference"]["correct"] \
+            and all(r["correct"] for r in row["against_full_path"].values()
+                    if isinstance(r, dict))
+        report["rows"].append(row)
+    want = {"lm.prefix_hits": B, "lm.prefix_misses": 1,
+            "lm.prefix_positions_served": B * K,
+            "lm.prefill_positions": B * (P - K)}
+    report["ok"] = all(r["correct"] for r in report["rows"]) \
+        and {k: counted.get(k) for k in want} == want
+    print(json.dumps(report), flush=True)
+    return 0 if report["ok"] else 1
+
+
+def lm_prefix_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    code, report, tail = child_report(
+        cfg, out_dir, env, "lm_prefix",
+        [sys.executable, os.path.abspath(__file__), "--lm-prefix-child"], 2)
+    with open(os.path.join(out_dir, "lm_prefix.json"), "w",
+              encoding="utf-8") as f:
+        json.dump(report, f, indent=1)
+    check(code == 0 and report["ok"],
+          f"a row started from the prefix's snapshot is outside a limit, "
+          f"or the counters are not the snapshot path's: "
+          f"{json.dumps(report)}\n{tail}")
+    if result["device"] is None:
+        result["device"] = {**report["device"], "count": 1}
+    worst = max(report["rows"],
+                key=lambda r: r["against_reference"]["mean_over_std"])
+    result["smoke_facts"]["language_model_ssm_prefix"] = {
+        key: report[key] for key in ("prefix_ids", "prompt_tokens",
+                                     "new_tokens", "counted", "limits")} | {
+        "rows": [{k: r[k] for k in ("own_ids", "against_reference",
+                                    "state_against_reference",
+                                    "full_path_state_against_reference",
+                                    "against_full_path")}
+                 for r in report["rows"]]}
+    say(f"lm (state-space, from a snapshot of {report['prefix_ids']} ids): "
+        f"{len(report['rows'])} rows within the limits against the "
+        f"reference of the whole prompt (worst mean "
+        f"{worst['against_reference']['mean_over_std']:.4f}) and against "
+        f"the full path")
 
 
 LM_TOGETHER = 4      # requests sent together: one execution's rows
@@ -809,9 +1053,13 @@ def main() -> int:
                     help=f"comma-separated subset of {','.join(PHASES)}")
     ap.add_argument("--kernel-child", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--lm-prefix-child", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.kernel_child:
         return kernel_child(args.rehearse)
+    if args.lm_prefix_child:
+        return lm_prefix_child(args.rehearse)
 
     phases = [p for p in args.phases.split(",") if p]
     for missing in ("comfyui_distributed_tpu", "workflows"):
@@ -846,7 +1094,9 @@ def main() -> int:
         if "lm" in phases:
             lm_phase(cfg, out_dir, env, summary)
             lm_together_phase(cfg, out_dir, env, summary)
+        if "lm" in phases or "lm_ssm" in phases:
             lm_ssm_phase(cfg, out_dir, env, summary)
+            lm_prefix_phase(cfg, out_dir, env, summary)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -1304,7 +1304,13 @@ class LMFamily:
     keeps a RECURRENT state as well, overwritten in place and no function
     of the positions, ``state_bytes(cfg, rows)``; where its state is of
     more than one geometry or kind, ``kv_cache_bytes_by_kind`` -> the
-    parts by name (a ring and a full cache; recurrent and positional).
+    parts by name (a ring and a full cache; recurrent and positional);
+    where the state behind a prompt's first ids can stand for them
+    (nothing in it depends on where in the buffer a row lies),
+    ``make_prefix_program(cfg)`` (the jitted ``lm_prefix_state``: ids
+    ``[K]`` -> the snapshot, which ``lm_generate`` then takes as a sixth
+    argument with the ids behind the prefix as its prompt) and
+    ``prefix_bytes(cfg, K)``.
     Its config gives ``vocab_size`` and ``layer_applications`` (per
     token)."""
     module: str
@@ -1377,7 +1383,10 @@ LM_FAMILIES = {
     # the decode's weight stream is shared.  The state a step reads and
     # writes grows with the rows (151 MB a row a step, where a dense
     # model's cache at 128 positions is a few MB): at 4 rows 0.6 GB beside
-    # the weights' 6.4, at 16 it would be a third of the step.
+    # the weights' 6.4, at 16 it would be a third of the step.  (Since
+    # PR 41 rows that share their instructions start from a snapshot of
+    # the state behind them and prefill what follows: 97 positions a row
+    # in the cell.  The argument stands for prompts that share nothing.)
     "granite": LMFamily(
         "ssm_hybrid", ("granite",), LM_ROW_COUNTS,
         "granite-4.0-h-micro, whole: Mamba-2 state-space layers with an "
@@ -1445,8 +1454,16 @@ class LanguageModel:
         self.family = family
         self.row_counts = LM_FAMILIES[family].row_counts
         self._arch = LM_FAMILIES[family].load()
-        # (new tokens, prompt positions) -> {rows: compiled lm_generate}
-        self._programs: Dict[Tuple[int, int], Dict[int, Any]] = {}
+        # (new tokens, prompt positions, of them a snapshot's) -> {rows:
+        # compiled lm_generate}
+        self._programs: Dict[Tuple[int, int, int], Dict[int, Any]] = {}
+        # positions -> compiled lm_prefix_state
+        self._prefix_makers: Dict[int, Any] = {}
+        # a prefix's ids -> its snapshot on the device, least recently
+        # used first: as many as an execution has rows (each could bring
+        # its own), 76.4 MB + 8 KiB a position each at the published size
+        self._prefixes: "collections.OrderedDict[bytes, Any]" = \
+            collections.OrderedDict()
         self._mesh = None
         self._lock = threading.Lock()
 
@@ -1465,6 +1482,8 @@ class LanguageModel:
             jax.block_until_ready(self.params)
             self._mesh = mesh
             self._programs.clear()
+            self._prefix_makers.clear()
+            self._prefixes.clear()
 
     def prompt_ids(self, text: str, prompt_tokens: int,
                    instructions: str = "") -> np.ndarray:
@@ -1483,22 +1502,77 @@ class LanguageModel:
                 f"generated from a vocabulary of {self.cfg.vocab_size}")
         return ids
 
-    def _compiled(self, n: int, prompt_tokens: int) -> Dict[int, Any]:
-        """``lm_generate`` for ``n`` new tokens behind ``prompt_tokens``
-        positions, compiled for every count of ``row_counts`` at once."""
+    def shared_prefix(self, rows: Sequence[LMRow], prompt_tokens: int,
+                      ids: Optional[Sequence[np.ndarray]] = None
+                      ) -> Optional[np.ndarray]:
+        """The ids a snapshot can stand for in an execution of ``rows``,
+        or None: the family can start a row from the state behind a
+        prefix, every row carries the same non-empty ``instructions``,
+        and their ids are a true prefix of each row's ``ids``
+        (`prompt_ids`' where None) with at least one id behind them."""
+        instructions = rows[0].instructions
+        if not hasattr(self._arch, "make_prefix_program") \
+                or not instructions \
+                or any(r.instructions != instructions for r in rows):
+            return None
+        prefix = np.asarray(self.tokenizer.encode(instructions), np.int32)
+        if ids is None:
+            ids = [self.prompt_ids(r.text, prompt_tokens, r.instructions)
+                   for r in rows]
+        K = len(prefix)
+        if any(len(i) <= K or not np.array_equal(i[:K], prefix)
+               for i in ids):
+            return None
+        return prefix
+
+    def _snapshot(self, prefix: np.ndarray) -> Any:
+        """The state behind ``prefix`` on the device: kept from an earlier
+        execution, else made now (the maker compiled at a length's first
+        use) with the least recently used one let go."""
+        bump, key = trace_mod.GLOBAL_COUNTERS.bump, prefix.tobytes()
         with self._lock:
-            programs = self._programs.get((n, prompt_tokens))
+            snapshot = self._prefixes.get(key)
+            if snapshot is not None:
+                self._prefixes.move_to_end(key)
+                return snapshot
+            maker = self._prefix_makers.get(len(prefix))
+            if maker is None:
+                maker = self._prefix_makers[len(prefix)] = \
+                    self._arch.make_prefix_program(self.cfg).lower(
+                        self.params, jax.ShapeDtypeStruct(prefix.shape,
+                                                          np.int32)).compile()
+            with trace_mod.stage("lm_prefix_state"):
+                snapshot = self._prefixes[key] = jax.block_until_ready(
+                    maker(self.params, prefix))
+            bump("lm.prefix_misses")
+            while len(self._prefixes) > self.row_counts[-1]:
+                self._prefixes.popitem(last=False)
+                bump("lm.prefix_evictions")
+            trace_mod.GLOBAL_GAUGES.set("lm.prefix_bytes", sum(
+                self._arch.prefix_bytes(self.cfg, len(k) // prefix.itemsize)
+                for k in self._prefixes))
+        return snapshot
+
+    def _compiled(self, n: int, prompt_tokens: int, *snapshot: Any
+                  ) -> Dict[int, Any]:
+        """``lm_generate`` for ``n`` new tokens behind ``prompt_tokens``
+        positions, compiled for every count of ``row_counts`` at once;
+        with a ``snapshot``, for rows that start from one of its length
+        and prefill the positions behind it."""
+        held = snapshot[0]["keys"].shape[1] if snapshot else 0
+        with self._lock:
+            programs = self._programs.get((n, prompt_tokens, held))
             if programs is None:
                 jitted = self._arch.make_program(self.cfg, n)
 
                 def row(dtype, *shape):
                     return jax.ShapeDtypeStruct(shape, dtype)
 
-                programs = self._programs[(n, prompt_tokens)] = {
+                programs = self._programs[(n, prompt_tokens, held)] = {
                     b: jitted.lower(
-                        self.params, row(np.int32, b, prompt_tokens),
+                        self.params, row(np.int32, b, prompt_tokens - held),
                         row(np.int32, b), row(np.uint32, b),
-                        row(np.float32, b)).compile()
+                        row(np.float32, b), *snapshot).compile()
                     for b in self.row_counts}
         return programs
 
@@ -1516,6 +1590,12 @@ class LanguageModel:
         enqueued.  What the family counts of an execution (its ``stats``)
         comes over in the same read.
 
+        Where `shared_prefix` finds one, every row starts from its
+        snapshot (made at the first execution that brings these
+        instructions, kept for the next) and the program prefills the ids
+        behind it only; every other execution runs the whole prompt, as
+        it did.
+
         Counted per request served, so that tokens over stages stays the
         steps of one execution: the first row is the caller's and its
         ``lm_generate`` stage lies on the current span; row ``i`` behind
@@ -1531,22 +1611,26 @@ class LanguageModel:
         ids = [self.prompt_ids(r.text, prompt_tokens, r.instructions)
                for r in rows]
         count = next(b for b in self.row_counts if b >= real)
+        prefix = self.shared_prefix(rows, prompt_tokens, ids)
+        held = 0 if prefix is None else len(prefix)
+        # the program's sixth argument, where its rows start from one
+        snapshot = () if prefix is None else (self._snapshot(prefix),)
         # a padded row repeats the first
         source = [*range(real), *[0] * (count - real)]
-        padded = np.full((count, prompt_tokens), self.tokenizer.pad_id,
-                         np.int32)
+        padded = np.full((count, prompt_tokens - held),
+                         self.tokenizer.pad_id, np.int32)
         for b, i in enumerate(source):
-            padded[b, :len(ids[i])] = ids[i]
-        program = self._compiled(n, prompt_tokens)[count]
+            padded[b, :len(ids[i]) - held] = ids[i][held:]
+        program = self._compiled(n, prompt_tokens, *snapshot)[count]
         t0 = time.time()
         with trace_mod.stage("lm_generate"):
             tokens, logits, aux, stats = program(
                 self.params, padded,
-                np.asarray([len(ids[i]) for i in source], np.int32),
+                np.asarray([len(ids[i]) - held for i in source], np.int32),
                 np.asarray([rows[i].seed & 0xFFFFFFFF for i in source],
                            np.uint32),
                 np.asarray([rows[i].temperature for i in source],
-                           np.float32))
+                           np.float32), *snapshot)
             with trace_mod.device_wait():
                 # dtpu-lint: ignore[spine-host-fetch] ids must be words before CLIP can run
                 host_tokens, stats = jax.device_get((tokens, stats))
@@ -1571,6 +1655,9 @@ class LanguageModel:
         bump("lm.executions_fewrow", int(self._arch.few_rows_here(count)))
         bump("lm.rows", real)
         bump("lm.padded_rows", count - real)
+        if prefix is not None:
+            bump("lm.prefix_hits", real)
+            bump("lm.prefix_positions_served", real * held)
         for name, value in self._arch.window_counters(
                 self.cfg, stats, real, n).items():
             bump(name, value)

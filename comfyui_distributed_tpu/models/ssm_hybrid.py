@@ -292,17 +292,28 @@ def load_checkpoint(path: str, cfg: GraniteHybridConfig):
 
 # --- the Mamba-2 mixer ------------------------------------------------------
 
-def causal_conv(xbc, weight, bias, tail=None):
+def causal_conv(xbc, weight, bias, tail=None, at=None):
     """The depthwise causal convolution over positions, float32: ``xbc
     [B, T, C]`` behind ``tail [B, taps - 1, C]`` (zeros where None: the
-    front of a sequence), taps ``weight [taps, C]``.  Returns the
-    convolution at the ``T`` positions and the new tail (the last ``taps
-    - 1`` inputs)."""
+    front of a sequence), taps ``weight [taps, C]``.  With ``at [B]`` row
+    ``b``'s sequence starts at position ``at[b]`` (what lies in front is a
+    right-aligned row's padding, zeros) and the tail lies directly in
+    front of THAT position, not of position 0.  Returns the convolution
+    at the ``T`` positions and the new tail (the last ``taps - 1``
+    inputs: of a row shorter than that, the end of the old tail and the
+    row)."""
     B, T, C = xbc.shape
     taps = weight.shape[0]
-    if tail is None:
-        tail = jnp.zeros((B, taps - 1, C), xbc.dtype)
-    seen = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    front = jnp.zeros((B, taps - 1, C), xbc.dtype)
+    tail = front if tail is None else tail.astype(xbc.dtype)
+    if at is None:
+        seen = jnp.concatenate([tail, xbc], axis=1)
+    else:
+        # position j of ``xbc`` is entry j + taps - 1 of ``seen``
+        seen = jnp.concatenate([front, xbc], axis=1)
+        for b in range(B):
+            seen = jax.lax.dynamic_update_slice(seen, tail[b:b + 1],
+                                                (b, at[b], 0))
     wide = seen.astype(jnp.float32)
     out = bias.astype(jnp.float32) + sum(
         weight[k].astype(jnp.float32) * wide[:, k:k + T]
@@ -310,19 +321,21 @@ def causal_conv(xbc, weight, bias, tail=None):
     return out, seen[:, T:]
 
 
-def chunked_scan(x, dt, A, Bm, Cm, chunk: int, dtype):
+def chunked_scan(x, dt, A, Bm, Cm, chunk: int, dtype, start=None):
     """The recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
-    ``y_t = S_t C_t`` from ``S = 0``, over ``T`` positions in chunks of
-    ``chunk`` (Mamba-2's state-space-dual form): ``x [B, T, h, p]``, ``dt
-    [B, T, h]`` float32 (0 where a position is padding), ``A [h]``
-    float32, negative, ``Bm``, ``Cm [B, T, n]``.  Returns ``y [B, T, h,
-    p]`` and the state behind the last position ``[B, h, p, n]``, float32.
+    ``y_t = S_t C_t`` from ``S = start [B, h, p, n]`` (float32; 0 where
+    None), over ``T`` positions in chunks of ``chunk`` (Mamba-2's
+    state-space-dual form): ``x [B, T, h, p]``, ``dt [B, T, h]`` float32
+    (0 where a position is padding), ``A [h]`` float32, negative, ``Bm``,
+    ``Cm [B, T, n]``.  Returns ``y [B, T, h, p]`` and the state behind
+    the last position ``[B, h, p, n]``, float32.
 
     Inside a chunk position ``q`` reads position ``s <= q`` through
     ``(C_q . B_s) exp(sum_{s < r <= q} dt_r A) dt_s``: one masked ``[Q,
     Q]`` matrix a head, times the chunk's ``x``.  What came before the
-    chunk reaches it through the state at the chunk's start.  Products
-    take operands in ``dtype`` and accumulate in float32; every decay,
+    chunk (``start``, for the first) reaches it through the state at the
+    chunk's start.  Products take operands in ``dtype`` and accumulate in
+    float32; every decay,
     ``dt`` and the state between chunks are float32 (the state is
     rounded to ``dtype`` only as the operand of the product that reads
     it).  ``T`` need not be a multiple of ``chunk``: the end is padded
@@ -367,8 +380,10 @@ def chunked_scan(x, dt, A, Bm, Cm, chunk: int, dtype):
         decay, own = xs
         return decay[..., None, None] * S + own, S
 
+    if start is None:
+        start = jnp.zeros((B, h, p, Bm.shape[-1]), f32)
     last, starts = jax.lax.scan(
-        carry_over, jnp.zeros((B, h, p, Bm.shape[-1]), f32),
+        carry_over, start.astype(f32),
         (whole.swapaxes(0, 1), local.swapaxes(0, 1)))
     starts = starts.swapaxes(0, 1)                           # [B, c, h, p, n]
     y = y + jnp.einsum("bcqn,bchpn->bcqhp", Cd, starts.astype(dtype),
@@ -393,10 +408,13 @@ def _real_only(real, a):
 
 def _mamba(cfg: GraniteHybridConfig, lp, u, real, ssm, conv, l,
            decode: bool):
-    """The mixer over ``u [B, N, d]`` (normed), the recurrent state
-    ``ssm`` and the tails ``conv`` with layer ``l`` of each overwritten.
-    ``real [B, N]`` says which positions of the prefill are a row's own;
-    a decode step (``N`` = 1) reads layer ``l``'s state and tail."""
+    """The mixer over ``u [B, N, d]`` (normed) FROM layer ``l`` of the
+    recurrent state ``ssm`` and of the tails ``conv`` (zeros in front of
+    a whole sequence, a prefix's where the rows start behind one), each
+    then overwritten.  ``real [B, N]`` says which positions of the
+    prefill are a row's own (its padding lies in front: the tail stands
+    directly in front of its first real position); a decode step (``N`` =
+    1) has none."""
     B, N, _ = u.shape
     heads, p, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     f32 = jnp.float32
@@ -408,13 +426,12 @@ def _mamba(cfg: GraniteHybridConfig, lp, u, real, ssm, conv, l,
             zx = _dense(u, lp["in_proj_zx"], cfg).astype(cfg.dtype)
             dt = _dense(u, lp["in_proj_dt"], cfg)
         z, xbc = zx[..., :cfg.d_inner], zx[..., cfg.d_inner:]
-        tail = None
-        if decode:
-            with jax.named_scope("conv_state"):
-                tail = jax.lax.dynamic_index_in_dim(conv, l, keepdims=False)
+        with jax.named_scope("conv_state"):
+            tail = jax.lax.dynamic_index_in_dim(conv, l, keepdims=False)
         with jax.named_scope("conv1d"):
-            xbc, tail = causal_conv(xbc, matrix(lp["conv1d_weight"]),
-                                    lp["conv1d_bias"], tail)
+            xbc, tail = causal_conv(
+                xbc, matrix(lp["conv1d_weight"]), lp["conv1d_bias"], tail,
+                None if decode else jnp.sum(~real, axis=1))
             xbc = jax.nn.silu(xbc)
         with jax.named_scope("conv_state"):
             conv = jax.lax.dynamic_update_slice(
@@ -425,10 +442,9 @@ def _mamba(cfg: GraniteHybridConfig, lp, u, real, ssm, conv, l,
             Cm = xbc[..., cfg.d_inner + n:]
             dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
             A = -jnp.exp(lp["A_log"].astype(f32))
-        if decode:
-            with jax.named_scope("ssm_state"):
-                S = jax.lax.dynamic_index_in_dim(
-                    ssm, l, keepdims=False).astype(f32)
+        with jax.named_scope("ssm_state"):
+            S = jax.lax.dynamic_index_in_dim(
+                ssm, l, keepdims=False).astype(f32)
         with jax.named_scope("ssm"):
             if decode:
                 y, S = state_step(S, x[:, 0], dt[:, 0], A, Bm[:, 0],
@@ -436,7 +452,7 @@ def _mamba(cfg: GraniteHybridConfig, lp, u, real, ssm, conv, l,
                 y = y[:, None]
             else:
                 y, S = chunked_scan(x, _real_only(real, dt), A, Bm, Cm,
-                                    cfg.mamba_chunk_size, cfg.dtype)
+                                    cfg.mamba_chunk_size, cfg.dtype, S)
             y = y + lp["D"].astype(f32)[:, None] * x
         with jax.named_scope("ssm_state"):
             ssm = jax.lax.dynamic_update_slice(
@@ -452,12 +468,15 @@ def _mamba(cfg: GraniteHybridConfig, lp, u, real, ssm, conv, l,
 # --- the attention mixer and the MLP ------------------------------------------
 
 def _attention(cfg: GraniteHybridConfig, lp, u, index, first, kc, vc, l,
-               decode: bool):
+               decode: bool, prefix: int = 0):
     """The mixer over ``u [B, N, d]`` (normed), the cache with this
     call's keys and values written into layer ``l`` at the buffer indices
-    ``index [N]``, and the keys each row's LAST query saw ``[B]``.
-    Without ``decode`` the queries attend to this call's own keys, with
-    it to the cache.  No position reaches a query or a key."""
+    ``index [N]``, and the keys each row's LAST query saw ``[B]``.  A
+    prefill from the front of the buffer attends to this call's own keys
+    (they ARE the cache's); one behind ``prefix`` positions to the cache
+    as far as its own last index, a decode step to all of it.  No
+    position reaches a query or a key, so a key is the same wherever in
+    the buffer it lies."""
     B, N, _ = u.shape
     D = cfg.head_dim
     mask = {"kv_start": first, "window": None}
@@ -469,13 +488,19 @@ def _attention(cfg: GraniteHybridConfig, lp, u, index, first, kc, vc, l,
         q, k, v = (shd.constrain(t.astype(cfg.dtype), "batch", None,
                                  "heads", None) for t in (q, k, v))
         with jax.named_scope("kv_cache"):
+            if prefix and not decode:
+                own = index[None, :] >= first[:, None] + prefix
+                k, v = (_own_entries(own, t, c, l, index[0])
+                        for c, t in ((kc, k), (vc, v)))
             kc, vc = (jax.lax.dynamic_update_slice(
                 c, t[None].astype(c.dtype), (l, 0, index[0], 0, 0))
                 for c, t in ((kc, k), (vc, v)))
-            if decode:
+            if decode or prefix:
                 k, v = (jax.lax.dynamic_index_in_dim(
                     c, l, keepdims=False).astype(cfg.dtype)
                     for c in (kc, vc))
+            if not decode and prefix:
+                k, v = k[:, :prefix + N], v[:, :prefix + N]
         ATTENTION_PATHS.bump(attention_path(
             jax.default_backend(), B, N, k.shape[1],
             cfg.num_attention_heads, masked=True))
@@ -484,6 +509,16 @@ def _attention(cfg: GraniteHybridConfig, lp, u, index, first, kc, vc, l,
         a = _attend(q, k, v, index, scale=cfg.attention_multiplier, **mask)
         with jax.named_scope("o_proj"):
             return _dense(a, lp["o_proj"], cfg), kc, vc, seen
+
+
+def _own_entries(own, new, cache, l, at):
+    """``new [B, N, G, D]``, to be written into layer ``l`` of ``cache``
+    at index ``at``, with what the cache HOLDS there wherever a position
+    is not a row's ``own [B, N]``: behind a prefix a row's padded
+    positions lie where the end of its prefix's keys stands (padding |
+    prefix | own ids)."""
+    held = jax.lax.dynamic_slice(cache, (l, 0, at, 0, 0), (1, *new.shape))[0]
+    return jnp.where(own[..., None, None], new, held.astype(new.dtype))
 
 
 def _mlp(cfg: GraniteHybridConfig, lp, u):
@@ -496,19 +531,21 @@ def _mlp(cfg: GraniteHybridConfig, lp, u):
 
 
 def _stack(cfg: GraniteHybridConfig, params, x, index, first, state,
-           decode: bool):
+           decode: bool, prefix: int = 0):
     """Every block, run by run (`GraniteHybridConfig.runs`): one
     ``lax.scan`` a run over the index of its layers with the stacked
     leaves closed over (`looplm.scan_layers`), the state of its kind in
-    the carry and the other not touched.  ``state`` is `empty_state`'s;
-    every block writes this call's entries at the buffer indices ``index
-    [N]`` (consecutive, the same for every row); row ``b``'s real entries
-    start at ``first[b]``.  Returns the normed last state of the stream,
+    the carry and the other not touched.  ``state`` is `empty_state`'s
+    or, behind a shared prefix, `from_prefix`'s; every block writes this
+    call's entries at the buffer indices ``index [N]`` (consecutive, the
+    same for every row); row ``b``'s real entries start at ``first[b]``,
+    the first ``prefix`` of them in ``state`` already.  Returns the
+    normed last state of the stream,
     ``state``, and the keys each row's last query saw, summed over the
     attention layers ``[B]``."""
     B = x.shape[0]
     eps, res = cfg.rms_norm_eps, cfg.residual_multiplier
-    real = None if decode else index[None, :] >= first[:, None]
+    real = None if decode else index[None, :] >= first[:, None] + prefix
     state = dict(state)
     seen = jnp.zeros((B,), jnp.int32)
 
@@ -525,7 +562,7 @@ def _stack(cfg: GraniteHybridConfig, params, x, index, first, state,
                 keys = jnp.zeros((B,), jnp.int32)
             else:
                 m, s0, s1, keys = _attention(cfg, lp, u, index, first, s0,
-                                             s1, l, decode)
+                                             s1, l, decode, prefix)
             h = x + res * m
             with jax.named_scope("post_attention_layernorm"):
                 u = _rms_norm(h, lp["post_attention_layernorm"], eps)
@@ -592,38 +629,109 @@ def kv_cache_bytes_by_kind(cfg: GraniteHybridConfig, batch: int, length: int
             "positional": kv_cache_bytes(cfg, batch, length)}
 
 
+# --- a prefix shared between requests ----------------------------------------
+#
+# What a prompt's first K ids leave behind is ONE recurrent state and tail
+# a Mamba layer, whatever K, and K keys and values an attention layer: the
+# SNAPSHOT (`make_prefix_program`), the state of one row with no axis of
+# rows, at the stored widths.  Rows whose prompts start with those ids
+# start from copies of it and prefill their own suffix only.  Legal
+# because nothing here depends on a position's index: the recurrence has
+# none and the attention layers rotate nothing, so the prefix's keys can
+# stand wherever a row's own offset puts them.
+
+def prefix_bytes(cfg: GraniteHybridConfig, positions: int) -> int:
+    """Bytes of the snapshot behind ``positions`` ids."""
+    return state_bytes(cfg, 1) + kv_cache_bytes(cfg, 1, positions)
+
+
+def make_prefix_program(cfg: GraniteHybridConfig):
+    """The jitted maker of a snapshot, ``lm_prefix_state`` (NOT
+    ``lm_generate``: what is counted and timed an execution is the served
+    program's): ``prefix_ids [K]``, one row and no padding, through every
+    block as a prefill -> ``ssm``, ``conv``, ``keys``, ``values`` behind
+    id ``K - 1``."""
+
+    def lm_prefix_state(params, prefix_ids):
+        K, = prefix_ids.shape
+        with jax.named_scope("GraniteMoeHybrid"), \
+                jax.named_scope("prefill"):
+            _, state, _ = _stack(
+                cfg, params, _embed(cfg, params, prefix_ids[None]),
+                jnp.arange(K), jnp.zeros((1,), jnp.int32),
+                empty_state(cfg, 1, K), decode=False)
+        return {name: a[:, 0] for name, a in state.items()}
+
+    return jax.jit(lm_prefix_state)
+
+
+def from_prefix(state, prefix, first):
+    """`empty_state`'s ``state`` with every row started from the snapshot
+    ``prefix``: its recurrent state and tail copied a row, its K keys and
+    values written at row ``b``'s own offset ``first[b]``, directly in
+    front of where that row's suffix will be written (the padding lies in
+    front of both, so the mask stays ``kv_start = first`` with no hole)."""
+    B = state["ssm"].shape[1]
+    new = {}
+    for name, scope in (("ssm", "ssm_state"), ("conv", "conv_state")):
+        with jax.named_scope(scope):
+            new[name] = jnp.broadcast_to(
+                prefix[name][:, None], state[name].shape
+            ).astype(state[name].dtype)
+    with jax.named_scope("kv_cache"):
+        for name in ("keys", "values"):
+            cache = state[name]
+            for b in range(B):
+                cache = jax.lax.dynamic_update_slice(
+                    cache, prefix[name][:, None].astype(cache.dtype),
+                    (0, b, first[b], 0, 0))
+            new[name] = cache
+    return new
+
+
 # --- the served program ---------------------------------------------------
 
 def prefill(cfg: GraniteHybridConfig, params, prompt_ids, prompt_len,
-            length: int):
-    """The prompt buffer ``[B, P]`` (row ``b``'s ``prompt_len[b]`` real
+            length: int, prefix=None):
+    """The prompt buffer ``[B, S]`` (row ``b``'s ``prompt_len[b]`` real
     ids in front, padding behind) through every block: the logits behind
     each row's last real id ``[B, V]``, the state of both kinds with room
-    for ``length`` positions, and ``first [B]``."""
-    B, P = prompt_ids.shape
+    for ``length`` positions, and ``first [B]``.  With a snapshot
+    ``prefix`` of K ids the buffer holds what FOLLOWS them in every row:
+    each row starts from the snapshot (`from_prefix`) and the blocks run
+    over the ``S`` positions behind it; the cache is laid out ``padding |
+    prefix | row's own ids``, its last id at ``K + S - 1`` whatever the
+    row."""
+    B, S = prompt_ids.shape
+    K = 0 if prefix is None else prefix["keys"].shape[1]
     with jax.named_scope("prefill"):
-        # every row's last real id at P - 1
-        first = P - prompt_len
+        # every row's last real id at the buffer's end
+        first = S - prompt_len
         prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+        state = empty_state(cfg, B, length)
+        if prefix is not None:
+            state = from_prefix(state, prefix, first)
         x, state, _ = _stack(cfg, params, _embed(cfg, params, prompt_ids),
-                             jnp.arange(P), first,
-                             empty_state(cfg, B, length), decode=False)
-        return _head(cfg, params, x[:, P - 1:])[:, 0], state, first
+                             K + jnp.arange(S), first, state, decode=False,
+                             prefix=K)
+        return _head(cfg, params, x[:, S - 1:])[:, 0], state, first
 
 
 def generate(cfg: GraniteHybridConfig, max_new_tokens: int, params,
-             prompt_ids, prompt_len, seed, temperature
+             prompt_ids, prompt_len, seed, temperature, prefix=None
              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Prefill, then ``max_new_tokens`` decode steps, for every row:
     `looplm.generate`'s contract (rows, lengths, seeds, temperatures; a
     row's numbers do not depend on what the other rows hold, nor on its
-    padding).  Returns the new ids ``[B, N]``, the float32 logits each
-    was drawn from ``[B, N, V]`` and ``stats``, int32: what the program
-    computed (``prefill_positions``, ``scan_chunks``, ``state_steps``:
-    every row's, padded ones too) and ``keys_attended_full [B]`` (what
-    the decode steps' masks let a row's query see, summed over the
-    attention layers)."""
-    B, P = prompt_ids.shape
+    padding), with `prefill`'s ``prefix``.  Returns the new ids ``[B,
+    N]``, the float32 logits each was drawn from ``[B, N, V]`` and
+    ``stats``, int32: what the program COMPUTED (``prefill_positions``,
+    ``scan_chunks``, ``state_steps``: every row's, padded ones too; of a
+    prefix served from a snapshot nothing) and ``keys_attended_full [B]``
+    (what the decode steps' masks let a row's query see, a prefix's keys
+    among them, summed over the attention layers)."""
+    B, S = prompt_ids.shape
+    P = S + (0 if prefix is None else prefix["keys"].shape[1])
     prompt_len, seed, temperature = (
         jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
     keys = jax.vmap(jax.random.PRNGKey)(seed)
@@ -637,7 +745,7 @@ def generate(cfg: GraniteHybridConfig, max_new_tokens: int, params,
 
     with jax.named_scope("GraniteMoeHybrid"):
         logits, state, first = prefill(cfg, params, prompt_ids, prompt_len,
-                                       P + max_new_tokens)
+                                       P + max_new_tokens, prefix)
 
         def step(carry, i):
             logits, state, seen = carry
@@ -655,9 +763,9 @@ def generate(cfg: GraniteHybridConfig, max_new_tokens: int, params,
                 step, (logits, state, jnp.zeros((B,), jnp.int32)),
                 jnp.arange(max_new_tokens))
     Lm = cfg.layers_of(MAMBA)
-    chunks = -(-P // min(cfg.mamba_chunk_size, P))
+    chunks = -(-S // min(cfg.mamba_chunk_size, S))
     return tokens.swapaxes(0, 1), logits.swapaxes(0, 1), {
-        "prefill_positions": jnp.int32(B * P),
+        "prefill_positions": jnp.int32(B * S),
         "scan_chunks": jnp.int32(B * Lm * chunks),
         "state_steps": jnp.int32(B * Lm * max_new_tokens),
         "keys_attended_full": seen}
@@ -667,12 +775,14 @@ def make_program(cfg: GraniteHybridConfig, max_new_tokens: int):
     """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
     device trace) like every language model's: ``(ids, logits, aux,
     stats)``, ``aux`` empty (this family has no per-position array
-    beside the logits)."""
+    beside the logits).  With a sixth argument, `make_prefix_program`'s
+    snapshot, ``prompt_ids`` holds what follows the prefix."""
 
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
+    def lm_generate(params, prompt_ids, prompt_len, seed, temperature,
+                    prefix=None):
         tokens, logits, stats = generate(cfg, max_new_tokens, params,
                                          prompt_ids, prompt_len, seed,
-                                         temperature)
+                                         temperature, prefix)
         return tokens, logits, {}, stats
 
     return jax.jit(lm_generate)

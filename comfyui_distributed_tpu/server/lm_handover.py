@@ -6,8 +6,11 @@ row's cache and nothing else.  So when a request reaches its
 ``LanguageModelGenerate`` node, ``lm_generate`` runs ONCE over that
 request (the leader) and over the requests still waiting in the server's
 queue whose graphs hold a generate call of the same model and lengths
-with literal inputs (the followers).  A follower stays where it is in
-the queue: its class, its place and `pop_fair_group` know nothing of
+with literal inputs (the followers); where the leader's rows start from
+the snapshot of its ``instructions`` (`LanguageModel.shared_prefix`),
+only those that carry the same, so that the execution keeps the short
+prefill the snapshot buys; the others run at their own turn.  A follower
+stays where it is in the queue: its class, its place and `pop_fair_group` know nothing of
 this.  Its words and its ``LM_OUTPUT`` row are kept here and handed over,
 once, when its own graph reaches the node.
 
@@ -79,11 +82,15 @@ class GenerateHandover:
         # before the wait, so that only the look at the queue lies between
         # the drain and the enqueue: the queued graphs are parsed (`_calls`
         # keeps them) and a leader that cannot be encoded is refused
-        model.prompt_ids(row.text, prompt_tokens, row.instructions)
-        waiting, full = self._waiting(model, max_new_tokens, prompt_tokens)
+        ids = model.prompt_ids(row.text, prompt_tokens, row.instructions)
+        shared = None if model.shared_prefix([row], prompt_tokens, [ids]) \
+            is None else row.instructions
+        waiting, full = self._waiting(model, max_new_tokens, prompt_tokens,
+                                      shared)
         if not full and self._drain_wait():
             here = {pid for pid, _, _ in waiting}
-            waiting, _ = self._waiting(model, max_new_tokens, prompt_tokens)
+            waiting, _ = self._waiting(model, max_new_tokens, prompt_tokens,
+                                       shared)
             bump("lm.drain_waits")
             bump("lm.rows_joined_in_drain",
                  sum(pid not in here for pid, _, _ in waiting))
@@ -134,11 +141,13 @@ class GenerateHandover:
                 state._drained.wait()
         return True
 
-    def _waiting(self, model: Any, max_new_tokens: int, prompt_tokens: int
+    def _waiting(self, model: Any, max_new_tokens: int, prompt_tokens: int,
+                 shared: Optional[str] = None
                  ) -> Tuple[List[Tuple[str, LMRow, Any]], bool]:
         """``(prompt id, row, root span)`` of the queued requests' calls
         this execution has room for, in queue order, and whether they
-        fill it."""
+        fill it.  ``shared``: the instructions whose snapshot the leader
+        starts from; a call with others does not join."""
         state = self._state
         with state._queue_lock:
             queued = list(state._queue)
@@ -153,7 +162,8 @@ class GenerateHandover:
                 if len(found) >= room:
                     return found, True
                 if (name, n, p) != (model.name, max_new_tokens,
-                                    prompt_tokens):
+                                    prompt_tokens) \
+                        or shared not in (None, row.instructions):
                     continue
                 try:
                     model.prompt_ids(row.text, prompt_tokens,

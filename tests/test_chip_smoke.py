@@ -111,6 +111,25 @@ def test_lm_phase_holds_the_served_logits_to_the_reference(tmp_path):
     with open(tmp_path / "out" / "verify_lm_ssm"
               / "verify_lm_ssm.json") as f:
         assert json.load(f)["ok"] is True
+    # and four rows started from the snapshot of their shared
+    # instructions (PR 41): against the reference of the WHOLE prompt
+    # (logits, greedy ids, the state behind the prompt) and against the
+    # full path over the same prompts
+    shared = summary["smoke_facts"]["language_model_ssm_prefix"]
+    assert (shared["prefix_ids"], shared["prompt_tokens"]) == (14, 48)
+    assert shared["counted"]["lm.prefix_hits"] == 4
+    assert shared["counted"]["lm.prefix_misses"] == 1
+    assert shared["counted"]["lm.prefill_positions"] == 4 * (48 - 14)
+    assert len({row["own_ids"] for row in shared["rows"]}) == 4
+    for row in shared["rows"]:
+        assert row["against_reference"]["correct"]
+        assert row["against_reference"]["argmax_agree"] == 1.0
+        assert row["state_against_reference"]["correct"]
+        against = row["against_full_path"]
+        assert against["ids_alike"] == against["steps_compared"] == 4
+        for part in ("logits", "ssm", "conv", "keys"):
+            assert against[part]["max_over_std"] \
+                <= shared["limits"]["max_over_std"]
 
 
 def test_default_mode_refuses_a_cpu_pinned_jax(tmp_path):

@@ -474,7 +474,7 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
     assert want <= segments, want - segments
 
 
-def _granite_decode_keeps_its_state_in_place(text, bodies):
+def _granite_decode_keeps_its_state_in_place(text, bodies, positions=128):
     """The tied leaf is read where it lies (the transposed kernel's
     operand is the ``[V, d]`` parameter), and no computation that holds a
     kernel call (a decode step's own or a layer scan's body) writes a
@@ -486,7 +486,7 @@ def _granite_decode_keeps_its_state_in_place(text, bodies):
     assert tied and all(
         "bf16[100352,2048]{1,0}" in l.split(
             "operand_layout_constraints=")[1] for l in tied)
-    state = {"f32[36,4,64,64,128]", "bf16[4,4,128,8,64]"}
+    state = {"f32[36,4,64,64,128]", f"bf16[4,4,{positions},8,64]"}
     for name, lines in bodies.items():
         for line in lines:
             m = INSTRUCTION.match(line)
@@ -496,6 +496,55 @@ def _granite_decode_keeps_its_state_in_place(text, bodies):
                 continue
             assert f"{m['dtype']}[{m['dims']}]" not in state, \
                 (name, line.strip()[:200])
+
+
+def test_the_programs_of_a_shared_prefix_compile_for_the_chip(
+        one_chip, no_compile_cache, monkeypatch):
+    """PR 41 at the published widths and the cell's lengths: the maker
+    over the 1,951 shared ids, and the 4-row ``lm_generate`` that starts
+    every row from its snapshot and prefills the 97 positions behind it.
+    The chip's compiler takes both; the decode steps still stream every
+    large leaf through the kernel and keep the state of both kinds in
+    place (the SAME decode as the full program's, behind another
+    prefill); and without a 4 x 2048 prefill the program's temporaries
+    are a quarter of the full one's 2.4 GB."""
+    monkeypatch.setattr(looplm, "_where", lambda: ("tpu", None))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda s: spec(s, GRANITE.dtype), ssm_hybrid.param_shapes(GRANITE),
+        is_leaf=lambda x: isinstance(x, tuple))
+    held, own = 1951, 97
+    maker = ssm_hybrid.make_prefix_program(GRANITE)
+    made = maker.lower(params, spec((held,), np.int32)).compile()
+    assert "jit_lm_prefix_state" in made.as_text()[:200]
+    snapshot = {k: spec(v.shape, v.dtype) for k, v in jax.eval_shape(
+        maker, params, spec((held,), np.int32)).items()}
+    assert sum(int(np.prod(v.shape)) * v.dtype.itemsize
+               for v in snapshot.values()) \
+        == ssm_hybrid.prefix_bytes(GRANITE, held) == 76_437_504 + held * 8192
+    program = ssm_hybrid.make_program(GRANITE, 64).lower(
+        params, spec((4, own), np.int32), spec((4,), np.int32),
+        spec((4,), np.uint32), spec((4,), np.float32), snapshot).compile()
+    assert program.memory_analysis().temp_size_in_bytes < 0.7e9
+    text = program.as_text()
+    assert "jit_lm_generate" in text[:200]
+    bodies = {name: lines for name, lines in computations(text).items()
+              if name != "ENTRY" and "fused" not in name
+              and any("fewrow_dense" in l and "custom-call(" in l
+                      for l in lines)}
+    assert bodies, "no computation but the entry's holds the kernel"
+    _granite_decode_keeps_its_state_in_place(text, bodies,
+                                             held + own + 64)
+    _, layer_elements = weights_of("granite")
+    for name, lines in bodies.items():
+        assert not weight_sized_results(lines, layer_elements), name
+    phases = {trace.phase_of(p)
+              for p in re.findall(r'op_name="([^"]+)"', text)
+              if "GraniteMoeHybrid" in p}
+    assert phases == {"prefill", "decode"}
 
 
 @pytest.mark.parametrize("family", ["pangu", "exaone"])

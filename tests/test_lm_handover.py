@@ -94,6 +94,22 @@ def encoded(monkeypatch):
     return texts
 
 
+@pytest.fixture
+def generated(monkeypatch):
+    """The STRING every ``LanguageModelGenerate`` node gives, in order."""
+    outs = []
+    cls = ops_base.NODE_CLASS_MAPPINGS["LanguageModelGenerate"]
+    real = cls.execute
+
+    def execute(self, ctx, **kw):
+        out = real(self, ctx, **kw)
+        outs.append(out[0])
+        return out
+
+    monkeypatch.setattr(cls, "execute", execute)
+    return outs
+
+
 def alone(text, seed=5, temperature=0.0):
     model = registry.load_language_model("ouro-2.6b.safetensors")
     words, _ = model.generate(text, seed=seed, max_new_tokens=NEW,
@@ -171,30 +187,17 @@ def test_four_prompts_behind_a_blocked_executor_share_one_execution(
     assert positive == [alone(t, 10 + i) for i, t in enumerate(TEXTS)]
 
 
-def test_rows_keep_their_own_seed_and_temperature(state):
+def test_rows_keep_their_own_seed_and_temperature(state, generated):
     """Two sampled followers of one text differ by their seeds, as their
     own runs do; the greedy leader ignores its seed."""
     for seed, t in ((1, 0.0), (2, 1.0), (3, 1.0)):
         state.enqueue_prompt(graph("a tower", seed=seed, temperature=t), "t")
-    outs = []
-    cls = ops_base.NODE_CLASS_MAPPINGS["LanguageModelGenerate"]
-    real = cls.execute
-
-    def execute(self, ctx, **kw):
-        out = real(self, ctx, **kw)
-        outs.append(out[0])
-        return out
-
-    cls.execute = execute
-    try:
-        for _ in range(3):
-            run_next(state)
-    finally:
-        cls.execute = real
+    for _ in range(3):
+        run_next(state)
     assert counters()["executions"] == 1 and counters()["padded_rows"] == 1
-    assert outs == [alone("a tower", 1), alone("a tower", 2, 1.0),
-                    alone("a tower", 3, 1.0)]
-    assert outs[1] != outs[2] != outs[0]
+    assert generated == [alone("a tower", 1), alone("a tower", 2, 1.0),
+                         alone("a tower", 3, 1.0)]
+    assert generated[1] != generated[2] != generated[0]
 
 
 def test_a_follower_stays_where_it_was_in_the_queue(state):
@@ -626,6 +629,54 @@ def test_only_a_call_of_the_same_model_and_lengths_joins(state, change,
     assert state.lm_handover.kept() == 0
 
 
+GRANITE = "granite-4.0-h-micro.safetensors"
+GUIDES = {"A": "draw in the style of a woodcut",
+          "B": "draw in the style of a fresco", " ": ""}
+
+
+@pytest.mark.parametrize("model_name, queued, executions", [
+    # the leader's rows start from the snapshot of A: the two behind it
+    # that carry A ride along, past B and the one without, which lead
+    # their own at their turn (B from a snapshot of its own, alone)
+    (GRANITE, "AAB A", [("A", 3), ("B", 1), (" ", 1)]),
+    (GRANITE, "ABB", [("A", 1), ("B", 2)]),
+    # a leader that scans its whole prompt takes whoever waits, as before
+    (GRANITE, " AB", [(" ", 3)]),
+    # and so does a family that offers no snapshot
+    ("ouro-2.6b.safetensors", "AB A", [("A", 4)]),
+])
+def test_behind_a_leader_on_a_snapshot_only_its_instructions_ride_along(
+        state, generated, model_name, queued, executions):
+    """Who joins follows from what the leader's execution is: a short
+    prefill behind one snapshot.  Every request gets the words of its own
+    single-row run, whoever led it."""
+    for i, which in enumerate(queued):
+        g = graph(f"a tower number {i}", seed=i, instructions=GUIDES[which])
+        g[LOADER]["inputs"]["model_name"] = model_name
+        state.enqueue_prompt(g, "t")
+    seen = []
+    for _ in queued:
+        before = counters()
+        run_next(state)
+        now = counters()
+        if now["executions"] != before.get("executions", 0):
+            seen.append((now["rows"] - before.get("rows", 0),
+                         now.get("prefix_hits", 0)
+                         - before.get("prefix_hits", 0)))
+    on_snapshot = model_name == GRANITE
+    assert seen == [(rows, rows if GUIDES[which] and on_snapshot else 0)
+                    for which, rows in executions]
+    assert counters()["followers_served"] == len(queued) - len(executions)
+    assert state.lm_handover.kept() == 0
+    assert all(h["status"] == "success" for h in state._history.values())
+    model = registry.load_language_model(model_name)
+    for i, which in enumerate(queued):
+        (words, _), = model.generate_rows(
+            [registry.LMRow(f"a tower number {i}", i,
+                            instructions=GUIDES[which])], NEW, PROMPT)
+        assert generated[i] == f"a tower number {i}, {words}"
+
+
 @pytest.mark.parametrize("edit, want", [
     (lambda g: None, ("ouro-2.6b.safetensors", "x", 5, 0.0, NEW, PROMPT)),
     (lambda g: g[GENERATE]["inputs"].update(seed=9, temperature=1),
@@ -677,7 +728,7 @@ def test_the_first_shared_execution_compiles_nothing(state):
     assert counters()["executions"] == 2 and counters()["rows"] == 5
     assert trace.GLOBAL_RETRACES.since(mark)["compiles"] == 0
     model = registry.load_language_model("ouro-2.6b.safetensors")
-    assert sorted(model._programs[(NEW, PROMPT)]) == \
+    assert sorted(model._programs[(NEW, PROMPT, 0)]) == \
         list(registry.LM_ROW_COUNTS)
 
 

@@ -314,6 +314,8 @@ g = json.load(open(sys.argv[1])); g.pop("__doc__")
 g["5"]["inputs"].update(width=64, height=64); g["3"]["inputs"]["steps"] = 2
 if "21" in g:
     g["21"]["inputs"].update(max_new_tokens=4, prompt_tokens=32)
+    g["21"]["inputs"].update(json.loads(os.environ.get("PROBE_GENERATE",
+                                                       "{}")))
 rt = get_runtime()
 res = WorkflowExecutor(OpContext(runtime=rt,
                                  output_dir=tempfile.mkdtemp())).execute(g)
@@ -423,6 +425,44 @@ def test_the_long_shot_graph_runs_with_the_fourth_family(mesh, devices,
     assert counters["lm.scan_chunks"] == 4 * 4
     assert counters["lm.state_steps"] == 4 * 4
     assert counters["lm.keys_attended_full"] == 2 * (33 + 34 + 35 + 36)
+
+
+@pytest.fixture(scope="module")
+def whole_prompt_text(tmp_path_factory):
+    """The long-shot graph with no instructions: the whole prompt buffer
+    scanned, on one device."""
+    return probe("prompt-expand-longshot-txt2img.json",
+                 tmp_path_factory.mktemp("whole"), 1, DTPU_MESH_SHAPE="data=1",
+                 PROBE_GENERATE=json.dumps(
+                     {"prompt_tokens": 48, "instructions": ""}))
+
+
+@pytest.mark.parametrize("mesh, devices, images", [
+    ("data=1", 1, 1), ("data=2,tensor=2", 4, 2)])
+def test_behind_instructions_that_fit_the_fourth_family_starts_from_a_snapshot(
+        mesh, devices, images, tmp_path, whole_prompt_text):
+    """The same graph with instructions that leave room for the user's
+    words (14 ids of 48): the request makes the snapshot and is served
+    through it, on one device and under a mesh (the snapshot laid out as
+    the maker's program leaves it, the served program compiled for
+    that): 34 positions computed, 14 served."""
+    guide = "example prompt a red fox detailed prompt a red fox on fresh snow"
+    got = probe("prompt-expand-longshot-txt2img.json", tmp_path, devices,
+                DTPU_MESH_SHAPE=mesh, DTPU_TP_MIN_SHARD_ELEMENTS="64",
+                PROBE_GENERATE=json.dumps(
+                    {"prompt_tokens": 48, "instructions": guide}))
+    assert got["images"] == images
+    assert len(got["text"].split()) == 7 + 4
+    counters = got["counters"]
+    assert counters["lm.prefill_positions"] == 48 - 14
+    assert counters["lm.scan_chunks"] == 4 * 5
+    assert (counters["lm.prefix_misses"], counters["lm.prefix_hits"],
+            counters["lm.prefix_positions_served"]) == (1, 1, 14)
+    assert "lm_prefix_state" in got["stages"]
+    # without instructions the whole prompt is scanned, and counted
+    assert whole_prompt_text["counters"]["lm.prefill_positions"] == 48
+    assert "lm.prefix_hits" not in whole_prompt_text["counters"]
+    assert "lm_prefix_state" not in whole_prompt_text["stages"]
 
 
 @pytest.mark.parametrize("mesh, devices, images", [

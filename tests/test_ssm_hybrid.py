@@ -213,6 +213,257 @@ def test_the_chunked_scan_is_the_sequential_recurrence(T):
         np.testing.assert_allclose(S1[b], want_last, rtol=0, atol=2e-5)
 
 
+@pytest.mark.parametrize("cut", [1, 5, 8, 13, 23])
+def test_the_chunked_scan_from_a_state_is_the_scan_of_the_whole(cut):
+    """24 positions in chunks of 8, cut behind ``cut`` of them (inside a
+    chunk, at a chunk's end, one position from either end): the scan of
+    what follows FROM the state behind the cut gives the outputs and the
+    last state of the scan of the whole, and of the recurrence; so the
+    first chunk's outputs read the state they start from."""
+    rng = np.random.default_rng(cut)
+    B, T, h, p, n = 2, 24, 3, 4, 5
+    x = rng.standard_normal((B, T, h, p)).astype(np.float32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.5), (B, T, h))
+                ).astype(np.float32)
+    A = -rng.uniform(1, 16, h).astype(np.float32)
+    Bm, Cm = (rng.standard_normal((B, T, n)).astype(np.float32)
+              for _ in range(2))
+
+    def scan(lo, hi, start=None):
+        return ssm_hybrid.chunked_scan(
+            *(jnp.asarray(a[:, lo:hi]) for a in (x, dt)), jnp.asarray(A),
+            *(jnp.asarray(a[:, lo:hi]) for a in (Bm, Cm)), 8, jnp.float32,
+            start)
+
+    whole_y, whole_last = scan(0, T)
+    _, behind = scan(0, cut)
+    y, last = scan(cut, T, behind)
+    np.testing.assert_allclose(y, whole_y[:, cut:], rtol=0, atol=2e-5)
+    np.testing.assert_allclose(last, whole_last, rtol=0, atol=2e-5)
+    assert float(jnp.abs(y - scan(cut, T)[0]).max()) > 1e-2
+    for b in range(B):
+        want_y, want_last = ref.recurrence(*map(
+            jnp.asarray, (x[b], dt[b], A, Bm[b], Cm[b])))
+        np.testing.assert_allclose(y[b], want_y[cut:], rtol=0, atol=2e-5)
+        np.testing.assert_allclose(last[b], want_last, rtol=0, atol=2e-5)
+
+
+@pytest.mark.parametrize("own", [1, 2, 3, 7])
+def test_the_tail_stands_in_front_of_a_rows_own_first_position(own):
+    """A buffer of 7 positions whose last ``own`` are the row's (zeros in
+    front, as the mixer's input is at padding) behind a tail of 3: the
+    taps at the row's first positions see the TAIL, not the padding, and
+    a row shorter than the tail leaves a new tail that spans both."""
+    rng = np.random.default_rng(own)
+    T, C, taps = 7, 6, 4
+    w = rng.standard_normal((taps, C)).astype(np.float32)
+    bias = rng.standard_normal(C).astype(np.float32)
+    before = rng.standard_normal((2, taps - 1, C)).astype(np.float32)
+    rows = rng.standard_normal((2, own, C)).astype(np.float32)
+    xbc = np.zeros((2, T, C), np.float32)
+    xbc[:, T - own:] = rows
+    out, tail = ssm_hybrid.causal_conv(
+        jnp.asarray(xbc), jnp.asarray(w), jnp.asarray(bias),
+        jnp.asarray(before), jnp.asarray([T - own] * 2))
+    for b in range(2):
+        whole = np.concatenate([before[b], rows[b]])
+        want = ref.convolution(jnp.asarray(w), jnp.asarray(bias),
+                               jnp.asarray(whole))
+        np.testing.assert_allclose(out[b, T - own:], want[taps - 1:],
+                                   rtol=0, atol=1e-6)
+        np.testing.assert_array_equal(tail[b], whole[-(taps - 1):])
+    # without the offset the tail lies in front of the padding
+    if own < T:
+        off, _ = ssm_hybrid.causal_conv(
+            jnp.asarray(xbc), jnp.asarray(w), jnp.asarray(bias),
+            jnp.asarray(before))
+        assert float(jnp.abs(off - out)[:, T - own].max()) > 1e-2
+
+
+# --- a prefix shared between requests -------------------------------------------
+#
+# Every row's prompt is the same 13 ids and then its own: 8, 1, 2 and 5 ids
+# behind them in a suffix buffer of 8 (one row fills it, one is a single
+# id, one is shorter than the convolution's 3 taps of tail).  The whole
+# prompt is 21 positions at most, the buffer the tests above use.
+
+PREFIX = 13
+OWN = [8, 1, 2, 5]
+
+
+def shared_prompts():
+    """Per row the whole prompt: `prompt(9)`'s first 13 ids, then the
+    row's own."""
+    head = prompt(9)[0, :PREFIX]
+    return [np.concatenate([head, prompt(b + 20, n)[0, :n]])
+            for b, n in enumerate(OWN)]
+
+
+def snapshot_of(cfg, params):
+    return ssm_hybrid.make_prefix_program(cfg)(
+        params, jnp.asarray(shared_prompts()[0][:PREFIX]))
+
+
+def buffers(picked, held):
+    """The prompt buffer of the rows ``picked`` with their first ``held``
+    ids left out (a snapshot stands for them), and the lengths."""
+    whole = shared_prompts()
+    ids = np.zeros((len(picked), PAD_TO - held), np.int32)
+    for b, i in enumerate(picked):
+        ids[b, :len(whole[i]) - held] = whole[i][held:]
+    return ids, np.asarray([len(whole[i]) - held for i in picked], np.int32)
+
+
+def serve_shared(cfg, params, picked=(0, 1, 2, 3), snapshot="made",
+                 temperature=0.7):
+    """One execution over the rows ``picked`` of `shared_prompts`: from
+    the snapshot of the 13 ids (made here where "made"), or with None
+    the whole prompts through the full path."""
+    if isinstance(snapshot, str):
+        snapshot = snapshot_of(cfg, params)
+    ids, lens = buffers(picked, 0 if snapshot is None else PREFIX)
+    tokens, logits, _, stats = ssm_hybrid.make_program(cfg, NEW)(
+        params, jnp.asarray(ids), lens,
+        np.asarray(picked, np.uint32) + 3,
+        np.asarray([temperature] * len(picked), np.float32),
+        *(() if snapshot is None else (snapshot,)))
+    whole = shared_prompts()
+    rows = [{"prompt_ids": whole[i], "tokens": np.asarray(tokens[b]),
+             "logits": np.asarray(logits[b])} for b, i in enumerate(picked)]
+    return rows, {k: np.asarray(v) for k, v in stats.items()}
+
+
+@pytest.mark.parametrize("picked", [(0,), (1,), (0, 1, 2, 3), (3, 2, 2, 2)])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_rows_started_from_a_snapshot_are_the_full_paths_and_the_references(
+        picked, temperature, params):
+    """Alone or four of unequal length (a row that fills the suffix
+    buffer, a single id, two ids where the tail holds three, a padded
+    execution whose last rows repeat one), greedy or sampled with seeds:
+    the ids of the full path over the whole prompt, its logits to
+    float32's rounding, and the reference's (the recurrence over ALL 21
+    positions, no snapshot, no chunk) inside the limits every served
+    path is held to."""
+    served, stats = serve_shared(TINY, params, picked, temperature=temperature)
+    full, full_stats = serve_shared(TINY, params, picked, None,
+                                    temperature=temperature)
+    for got, want in zip(served, full):
+        assert np.array_equal(got["tokens"], want["tokens"])
+        np.testing.assert_allclose(
+            got["logits"], want["logits"], rtol=0,
+            atol=2e-6 * np.abs(want["logits"]).max())
+        limits = LIMITS if temperature else verify.LIMITS_FP32
+        reading = compare(TINY, params, got, limits)
+        assert reading["correct"], reading
+    # what the program COMPUTED: the 8 positions behind the prefix (one
+    # chunk), where the full path computed 21 (three)
+    B, Lm, La = len(picked), 4, 2
+    assert (stats["prefill_positions"], full_stats["prefill_positions"]) \
+        == (B * (PAD_TO - PREFIX), B * PAD_TO)
+    assert (stats["scan_chunks"], full_stats["scan_chunks"]) \
+        == (B * Lm * 1, B * Lm * 3)
+    assert stats["state_steps"] == full_stats["state_steps"] == B * Lm * NEW
+    # the prefix's keys are READ by every decode step all the same
+    assert list(stats["keys_attended_full"]) \
+        == list(full_stats["keys_attended_full"]) == [
+        La * (NEW * (PREFIX + OWN[i]) + NEW * (NEW + 1) // 2)
+        for i in picked]
+
+
+@pytest.mark.parametrize("pad", ["as seeded", "a large pad embedding"])
+def test_a_row_behind_a_snapshot_is_its_single_row_run(pad, params):
+    """Four rows of unequal length from one snapshot give, each, what
+    they give alone through the 1-row program (whose suffix buffer they
+    do not fill either: the padding lies BETWEEN nothing, in front of
+    prefix and suffix both); also where the pad id's embedding is
+    large."""
+    if pad != "as seeded":
+        table = params["embed_tokens"]
+        params = {**params, "embed_tokens": table.at[0].set(
+            50.0 * jnp.sign(table[0]))}
+    snapshot = snapshot_of(TINY, params)
+    served, _ = serve_shared(TINY, params, snapshot=snapshot)
+    for b in range(4):
+        (alone,), _ = serve_shared(TINY, params, (b,), snapshot)
+        assert np.array_equal(alone["tokens"], served[b]["tokens"]), b
+        np.testing.assert_allclose(
+            served[b]["logits"], alone["logits"], rtol=0,
+            atol=2e-6 * np.abs(alone["logits"]).max())
+
+
+def test_the_snapshot_is_what_the_full_prefill_leaves_behind_the_prefix(
+        params):
+    """At the stored widths (a float32 state, the tail and the keys in
+    the model's type), one row and no axis of rows: the reference's state
+    behind id 13, the last three inputs of every Mamba layer's
+    convolution and 13 keys and values an attention layer, the ones the
+    full prefill of a longer prompt writes at those positions."""
+    snapshot = snapshot_of(TINY, params)
+    assert {k: (v.shape, v.dtype) for k, v in snapshot.items()} == {
+        "ssm": ((4, 4, 32, 16), jnp.float32),
+        "conv": ((4, 3, 160), TINY.dtype),
+        "keys": ((2, PREFIX, 2, 16), TINY.dtype),
+        "values": ((2, PREFIX, 2, 16), TINY.dtype)}
+    assert sum(v.nbytes for v in snapshot.values()) \
+        == ssm_hybrid.prefix_bytes(TINY, PREFIX)
+    whole = shared_prompts()[0]
+    _, want = ref.forward(hf(TINY), params, whole[:PREFIX])
+    np.testing.assert_allclose(snapshot["ssm"], want, rtol=0,
+                               atol=1e-5 * float(np.abs(want).max()))
+    ids, lens = buffers((0,), 0)
+    _, state, _ = ssm_hybrid.prefill(TINY, params, jnp.asarray(ids), lens,
+                                     PAD_TO + NEW)
+    for name in ("keys", "values"):
+        want = np.asarray(state[name][:, 0, :PREFIX])
+        np.testing.assert_allclose(snapshot[name], want, rtol=0,
+                                   atol=1e-5 * float(np.abs(want).max()))
+    # and behind the suffix prefill: the state behind each row's LAST id
+    ids, lens = buffers((0, 1, 2, 3), PREFIX)
+    _, state, first = jax.jit(
+        lambda p, i, n, s: ssm_hybrid.prefill(TINY, p, i, n, PAD_TO + NEW, s))(
+        params, jnp.asarray(ids), lens, snapshot)
+    assert list(first) == [8 - n for n in OWN]
+    for b, row in enumerate(shared_prompts()):
+        _, want = ref.forward(hf(TINY), params, row)
+        np.testing.assert_allclose(state["ssm"][:, b], want, rtol=0,
+                                   atol=1e-5 * float(np.abs(want).max()))
+    # the row of a single id: two inputs of its new tail are the prefix's
+    np.testing.assert_array_equal(state["conv"][:, 1, :2],
+                                  snapshot["conv"][:, 1:])
+    # the cache: padding | prefix | own ids, each row at its own offset
+    for b, n in enumerate(OWN):
+        at = 8 - n
+        np.testing.assert_array_equal(
+            state["keys"][:, b, at:at + PREFIX], snapshot["keys"])
+        assert float(jnp.abs(state["keys"][:, b, :at]).max(initial=0)) == 0
+
+
+def test_the_maker_is_not_the_served_program_and_the_phases_stay(params):
+    """The maker is ``lm_prefix_state``: the cells' pattern for the
+    served program (``^jit_lm_generate$``) does not match it, so its
+    seconds are no execution's.  The program that starts from a snapshot
+    is still ``lm_generate``, with every class and both phases."""
+    maker = ssm_hybrid.make_prefix_program(TINY).lower(
+        params, jnp.zeros((PREFIX,), jnp.int32))
+    assert "jit_lm_prefix_state" in maker.as_text()[:200]
+    assert not re.match("^jit_lm_generate$", "jit_lm_prefix_state")
+    ids, lens = buffers((0, 1, 2, 3), PREFIX)
+    lowered = ssm_hybrid.make_program(TINY, 3).lower(
+        params, jnp.asarray(ids), lens, np.zeros(4, np.uint32),
+        np.zeros(4, np.float32), snapshot_of(TINY, params))
+    assert "jit_lm_generate" in lowered.as_text()[:200]
+    names = [n for n in re.findall(r'op_name="([^"]+)"',
+                                   lowered.compile().as_text())
+             if "GraniteMoeHybrid" in n]
+    assert {trace.classify(n) for n in names} == LM_CLASSES
+    assert {trace.phase_of(n) for n in names} == {"prefill", "decode"}
+    # the rows' start from the snapshot is the state's and the cache's
+    copies = {trace.classify(f"jit(lm_generate)/GraniteMoeHybrid/prefill/"
+                             f"{scope}/x")
+              for scope in ("ssm_state", "conv_state", "kv_cache")}
+    assert copies == {"lm_state", "lm_cache"}
+
+
 # --- what the comparison has to see -------------------------------------------
 
 def _bf16_state(monkeypatch, params):
@@ -228,8 +479,8 @@ def _dropped_skip(monkeypatch, params):
 def _tail_off_by_one(monkeypatch, params):
     real = ssm_hybrid.causal_conv
 
-    def shifted(xbc, weight, bias, tail=None):
-        out, new = real(xbc, weight, bias, tail)
+    def shifted(xbc, weight, bias, tail=None, at=None):
+        out, new = real(xbc, weight, bias, tail, at)
         # keeps the three inputs in front of the last: a step too old
         return out, jnp.concatenate([jnp.zeros_like(new[:, :1]),
                                      new[:, :-1]], axis=1)
@@ -252,6 +503,67 @@ def _bf16_decay(monkeypatch, params):
                     Bm, Cm)
     monkeypatch.setattr(ssm_hybrid, "state_step", rounded)
     return TINY, params
+
+
+def _tail_in_front_of_the_padding(monkeypatch, params):
+    real = ssm_hybrid.causal_conv
+    monkeypatch.setattr(
+        ssm_hybrid, "causal_conv",
+        lambda xbc, weight, bias, tail=None, at=None: real(xbc, weight, bias,
+                                                           tail))
+    return TINY, params
+
+
+def _keys_at_the_buffers_front(monkeypatch, params):
+    real = ssm_hybrid.from_prefix
+    monkeypatch.setattr(ssm_hybrid, "from_prefix",
+                        lambda state, prefix, first: real(state, prefix,
+                                                          0 * first))
+    return TINY, params
+
+
+def _padding_over_the_prefixs_end(monkeypatch, params):
+    """Every position of the suffix buffer writes its keys, a row's
+    padded ones too: over the last keys of its prefix."""
+    monkeypatch.setattr(ssm_hybrid, "_own_entries",
+                        lambda own, new, cache, l, at: new)
+    return TINY, params
+
+
+def _a_snapshot_in_bf16(monkeypatch, params):
+    real = ssm_hybrid.make_prefix_program
+
+    def rounded(cfg):
+        made = real(cfg)
+        return lambda p, ids: {
+            k: v.astype(jnp.bfloat16).astype(v.dtype) if k == "ssm" else v
+            for k, v in made(p, ids).items()}
+    monkeypatch.setattr(ssm_hybrid, "make_prefix_program", rounded)
+    return TINY, params
+
+
+SHARED_BREAKAGES = {
+    "the tail in front of the padding": _tail_in_front_of_the_padding,
+    "the prefix's keys at the buffer's front": _keys_at_the_buffers_front,
+    "padding written over the prefix's end": _padding_over_the_prefixs_end,
+    "a snapshot whose state is bf16": _a_snapshot_in_bf16}
+
+
+@pytest.mark.parametrize("what", SHARED_BREAKAGES)
+def test_each_breakage_behind_a_snapshot_fails_the_comparison(
+        what, params, monkeypatch):
+    """The traps of a prefix in front of right-aligned rows, and a
+    snapshot stored narrower than the state: the comparison with the
+    reference of the WHOLE prompt refuses each, in a padded row; the row
+    that fills its buffer has no padding for the first three to go wrong
+    in."""
+    cfg, broken = SHARED_BREAKAGES[what](monkeypatch, params)
+    served, _ = serve_shared(cfg, broken)
+    monkeypatch.undo()
+    readings = [compare(TINY, params, row) for row in served]
+    assert not all(r["correct"] for r in readings[1:]), readings
+    if "bf16" not in what:
+        assert readings[0]["correct"], readings[0]
 
 
 BREAKAGES = {"a bf16 state": (_bf16_state, 1),
@@ -502,6 +814,7 @@ def counters():
 @pytest.fixture
 def model(monkeypatch):
     monkeypatch.setenv("DTPU_DEFAULT_FAMILY", "tiny")
+    trace.install_jax_monitoring()      # the guards below count compiles
     return registry.load_language_model("granite-4.0-h-micro.safetensors")
 
 
@@ -510,7 +823,10 @@ def test_the_registry_serves_it_and_counts_positions_chunks_and_steps(
     """`load_language_model` by name -> `generate_rows`: the ``lm.*``
     counters of PR 28-34 keep their meaning, what the program computed
     comes over in the same read, and two gauges say the state of each
-    kind; a second execution of the shape compiles nothing."""
+    kind; a second execution of the shape compiles nothing.  The rows
+    carry the same instructions (7 ids with the first), so each starts
+    from their snapshot and the program computes the 25 positions behind
+    it."""
     assert model.family == "granite" and model.cfg == TINY
     assert model.row_counts == (1, 4)
     rows = [registry.LMRow(f"a lighthouse at dawn number {i}", i,
@@ -527,13 +843,20 @@ def test_the_registry_serves_it_and_counts_positions_chunks_and_steps(
     assert got["lm.padded_rows"] == 1 and got["lm.tokens_decoded"] == 15
     assert got["lm.layer_applications"] == 15 * 6
     # every row of the program, the padded one too
-    assert got["lm.prefill_positions"] == 4 * 32
-    assert got["lm.scan_chunks"] == 4 * 4 * 4              # rows x Lm x 32/8
+    assert got["lm.prefill_positions"] == 4 * (32 - 7)
+    assert got["lm.scan_chunks"] == 4 * 4 * 4              # rows x Lm x 25/8
     assert got["lm.state_steps"] == 4 * 4 * 5              # rows x Lm x steps
     real = got["lm.prompt_tokens"]                          # of three rows
+    assert real > 3 * 7
+    # the prefix's keys are read in every step
     assert got["lm.keys_attended_full"] == 2 * (
         5 * real + 3 * (1 + 2 + 3 + 4 + 5))
     assert "lm.expert_pairs" not in got
+    # the three real rows, from the snapshot the first request made
+    assert got["lm.prefix_hits"] == 3
+    assert got["lm.prefix_positions_served"] == 3 * 7
+    assert got.get("lm.prefix_misses", 0) == 0 and before[
+        "lm.prefix_misses"] >= 1
     gauges = trace.GLOBAL_GAUGES.snapshot()
     assert gauges["lm.state_bytes"] == ssm_hybrid.state_bytes(TINY, 4) \
         == gauges["lm.kv_cache_bytes_recurrent"] \
@@ -544,6 +867,130 @@ def test_the_registry_serves_it_and_counts_positions_chunks_and_steps(
     words, lm_out = out[2]
     assert lm_out.row == 2 and lm_out.aux == {}
     assert len(words.split()) <= 5
+
+
+def lm_delta(before):
+    after = counters()
+    return {k[3:]: after[k] - before.get(k, 0) for k in after
+            if k.startswith("lm.") and after[k] != before.get(k, 0)}
+
+
+def asked(model, rows, **kw):
+    before = counters()
+    out = model.generate_rows(rows, max_new_tokens=3, prompt_tokens=32, **kw)
+    return [words for words, _ in out], lm_delta(before)
+
+
+def guide(i, words=6):
+    """Instructions of ``words`` words (``words`` + 1 ids)."""
+    return " ".join(["style", "guide", "number", str(i), "of", "many",
+                     "more", "words"][:words])
+
+
+def test_rows_from_a_snapshot_get_the_words_of_the_whole_prompt(
+        model, monkeypatch):
+    """Through the registry, three rows of one set of instructions: the
+    words of the same rows with the whole prompt scanned (the rule held
+    off), and of each row alone."""
+    rows = [registry.LMRow(f"a walled garden in june number {i}", i, 0.7 * i,
+                           instructions=guide(0)) for i in range(3)]
+    words, got = asked(model, rows)
+    assert got["prefix_hits"] == 3 and got["prefill_positions"] == 4 * 25
+    for i, row in enumerate(rows):
+        assert asked(model, [row])[0] == [words[i]]
+    monkeypatch.setattr(registry.LanguageModel, "shared_prefix",
+                        lambda self, *a: None)
+    whole, got = asked(model, rows)
+    assert whole == words and len(set(words)) == 3
+    assert "prefix_hits" not in got and got["prefill_positions"] == 4 * 32
+
+
+def test_snapshots_are_made_once_kept_and_the_least_recently_used_let_go(
+        model, assert_nothing_compiled):
+    """As many snapshots as an execution has rows: a fifth set of
+    instructions lets the least recently USED go, which is made again at
+    its next request; other instructions of a known length compile
+    nothing, nor does anything already seen."""
+    model._prefixes.clear()
+    row = lambda i, words=6: registry.LMRow(            # noqa: E731
+        "a harbour at night", 5, instructions=guide(i, words))
+    assert asked(model, [row(0)])[1]["prefix_misses"] == 1
+    mark = trace.GLOBAL_RETRACES.mark()
+    for i in (1, 2, 3):
+        got = asked(model, [row(i)])[1]
+        assert (got["prefix_misses"], got["prefix_hits"],
+                got["prefix_positions_served"]) == (1, 1, 7)
+        assert "prefix_evictions" not in got
+    one = ssm_hybrid.prefix_bytes(TINY, 7)
+    assert one == 4 * (4 * 32 * 16 * 4 + 3 * 160 * 4) \
+        + 2 * 2 * 7 * 2 * 16 * 4
+    assert trace.GLOBAL_GAUGES.snapshot()["lm.prefix_bytes"] == 4 * one
+    # the oldest is used again, so the second oldest is the one to go
+    got = asked(model, [row(0)])[1]
+    assert "prefix_misses" not in got and got["prefix_hits"] == 1
+    got = asked(model, [row(4)])[1]
+    assert (got["prefix_misses"], got["prefix_evictions"]) == (1, 1)
+    assert [np.frombuffer(k, np.int32)[4] for k in model._prefixes] == [
+        model.tokenizer.encode(str(i))[1] for i in (2, 3, 0, 4)]
+    assert trace.GLOBAL_GAUGES.snapshot()["lm.prefix_bytes"] == 4 * one
+    got = asked(model, [row(1)])[1]
+    assert (got["prefix_misses"], got["prefix_evictions"]) == (1, 1)
+    assert_nothing_compiled(trace.GLOBAL_RETRACES.since(mark))
+    # a new LENGTH is a maker and a program a row count
+    mark = trace.GLOBAL_RETRACES.mark()
+    got = asked(model, [row(0, 8)])[1]
+    assert got["prefix_positions_served"] == 9
+    assert got["prefill_positions"] == 32 - 9
+    assert trace.GLOBAL_RETRACES.since(mark)["compiles"] == 3
+    assert sorted(model._prefix_makers) == [7, 9]
+    assert {k for k in model._programs if k[:2] == (3, 32)} >= {
+        (3, 32, 7), (3, 32, 9)}
+
+
+@pytest.mark.parametrize("what, rows, tokens", [
+    ("no instructions", [("a cat", "")] * 2, 32),
+    ("instructions that differ between the rows",
+     [("a cat", guide(0)), ("a dog", guide(1))], 32),
+    ("one row without", [("a cat", guide(0)), ("a dog", "")], 32),
+    ("a prompt cut inside the instructions", [("a cat", guide(0, 8))], 6),
+    ("instructions that fill the buffer", [("a cat", guide(0))] * 2, 7),
+])
+def test_what_the_rule_does_not_find_runs_the_whole_prompt(what, rows,
+                                                           tokens, model):
+    """The rule reads its input: the same non-empty instructions in every
+    row, their ids in front of at least one id of each row.  Everything
+    else is the execution it was: every position computed, no snapshot
+    made, none counted."""
+    rows = [registry.LMRow(text, i, instructions=instructions)
+            for i, (text, instructions) in enumerate(rows)]
+    assert model.shared_prefix(rows, tokens) is None
+    before = counters()
+    model.generate_rows(rows, max_new_tokens=3, prompt_tokens=tokens)
+    got = lm_delta(before)
+    count = 1 if len(rows) == 1 else 4
+    assert got["prefill_positions"] == count * tokens
+    assert not [k for k in got if k.startswith("prefix_")]
+    # one more position, or the same instructions in both: it finds one
+    if what.startswith(("a prompt cut", "instructions that fill")):
+        assert len(model.shared_prefix(rows, 32)) in (7, 9)
+
+
+def test_a_family_that_offers_no_snapshot_runs_as_it_did(monkeypatch):
+    """K-EXAONE's rows share instructions too; its keys are rotated by
+    their position, the family offers no maker, and nothing of this is
+    counted for it."""
+    monkeypatch.setenv("DTPU_DEFAULT_FAMILY", "tiny")
+    other = registry.load_language_model("k-exaone-236b-a23b.safetensors")
+    rows = [registry.LMRow(f"a cat number {i}", i, instructions=guide(0))
+            for i in range(2)]
+    assert other.shared_prefix(rows, 32) is None
+    before = counters()
+    other.generate_rows(rows, max_new_tokens=3, prompt_tokens=32)
+    got = lm_delta(before)
+    assert got["executions"] == 1 and got["rows"] == 2
+    assert not [k for k in got if k.startswith("prefix_")]
+    assert "prefill_positions" not in got
+    assert not any(k[2] for k in other._programs)
 
 
 @pytest.mark.parametrize("name, want", [

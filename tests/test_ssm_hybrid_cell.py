@@ -435,9 +435,16 @@ def test_the_cell_rehearses_on_the_cpu(tmp_path):
     counters = run["window_counters"]
     assert counters["lm.executions"] >= 2
     rows = counters["lm.rows"] + counters["lm.padded_rows"]
-    # 48 prompt positions in 6 chunks of 8, 4 new tokens, 4 Mamba blocks
-    assert counters["lm.prefill_positions"] == rows * 48
-    assert counters["lm.scan_chunks"] == rows * 4 * 6
+    # the instructions' 14 ids (13 words behind the first id) are every
+    # request's: each row starts from their snapshot, made at warm-up, and
+    # the program computes the 34 of 48 prompt positions behind it in 5
+    # chunks of 8; 4 new tokens, 4 Mamba blocks
+    assert counters["lm.prefill_positions"] == rows * (48 - 14)
+    assert counters["lm.scan_chunks"] == rows * 4 * 5
     assert counters["lm.state_steps"] == rows * 4 * 4
+    assert counters["lm.prefix_hits"] == counters["lm.rows"]
+    assert counters["lm.prefix_positions_served"] == counters["lm.rows"] * 14
+    assert counters.get("lm.prefix_misses", 0) == 0
+    assert counters.get("lm.prefix_evictions", 0) == 0
     assert counters["lm.keys_attended_full"] > 0
     assert "lm.expert_pairs" not in counters
