@@ -56,8 +56,12 @@ language model (granite-4.0-h-micro) the same two ways behind its
 snapshot), and a child of this script (`lm_prefix_child`) that holds
 four rows started from that snapshot to the float32 reference of the
 WHOLE prompt (logits, the state behind the prompt, the greedy ids) and
-to the full path over the same prompts.  ``lm_ssm`` runs those last two
-alone.
+to the full path over the same prompts; then the language model of
+Keye-VL-2.0-30B-A3B (a learned index picks 2,048 keys a query) behind its
+8192-position prompt, against a reference forced to the program's expert
+choices and key selections (``benchmarks/chip/verify_lm_dsa_moe.py``).
+``lm_ssm`` runs the state-space model's two alone, ``lm_dsa`` that last
+one.
 """
 
 from __future__ import annotations
@@ -79,7 +83,7 @@ import uuid
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DEFAULT_PHASES = ("sdxl", "upscale", "kernels")
-PHASES = DEFAULT_PHASES + ("lm", "lm_ssm")
+PHASES = DEFAULT_PHASES + ("lm", "lm_ssm", "lm_dsa")
 SDXL_SEEDS = (777, 100777, 200777)   # far apart: fan-out replica r adds r
 
 # (q [B, N, H, D], kv length M) at a CFG-stacked batch of 2: the
@@ -661,6 +665,35 @@ def lm_ssm_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
         f"{report['state_bf16']['mean_over_std']:.4f})")
 
 
+def lm_dsa_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
+    """``benchmarks/chip/verify_lm_dsa_moe.py`` as a child: the decoder
+    with a learned key selection and routed experts (Keye-VL-2.0-30B-A3B's
+    language model, one pipeline stage) behind its 8192-position prompt,
+    one request alone and four as the rows of one execution, each held to
+    the plain float32 reference under the program's expert choices and key
+    selections; 8-bit caches, 8-bit weights and six breakages of the
+    mechanism each have to fail."""
+    report = verify_child(
+        cfg, out_dir, env, "verify_lm_dsa_moe", [],
+        "a served row is outside a limit, or a reading that has to fail "
+        "is inside all of them")
+    worst = max(report["served"], key=lambda r: r["mean_over_std"])
+    result["smoke_facts"]["language_model_dsa"] = {
+        "rows": len(report["served"]), "together": report["together"],
+        **{key: worst[key] for key in (
+            "positions", "max_over_std", "mean_over_std", "limits",
+            "selection_agree", "selection_worst_margin", "free")},
+        **{key: report[key]["mean_over_std"]
+           for key in ("cache_8bit", "weights_8bit", "no_selection",
+                       "last_topk", "no_relu", "no_head_weights",
+                       "top7_of_8", "no_renormalisation")}}
+    say(f"lm (selected keys): {len(report['served'])} rows within the "
+        f"limits (worst mean {worst['mean_over_std']:.4f} of a standard "
+        f"deviation; the program selects {worst['selection_agree']:.4f} "
+        f"of the reference's keys; 8-bit caches read "
+        f"{report['cache_8bit']['mean_over_std']:.4f})")
+
+
 # --- rows started from a prefix's snapshot --------------------------------------
 
 # the user's words of the four rows: prompts of unequal length behind the
@@ -1097,6 +1130,8 @@ def main() -> int:
         if "lm" in phases or "lm_ssm" in phases:
             lm_ssm_phase(cfg, out_dir, env, summary)
             lm_prefix_phase(cfg, out_dir, env, summary)
+        if "lm" in phases or "lm_dsa" in phases:
+            lm_dsa_phase(cfg, out_dir, env, summary)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

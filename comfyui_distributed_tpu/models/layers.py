@@ -152,19 +152,19 @@ def _query_chunk(b: int, n: int, m: int, h: int) -> Optional[int]:
 
 
 def attention_path(platform: str, b: int, n: int, m: int, h: int,
-                   mesh_axes: Optional[dict] = None,
-                   masked: bool = False, banded: bool = False) -> str:
+                   mesh_axes: Optional[dict] = None, masked: bool = False,
+                   banded: bool = False, selected: bool = False) -> str:
     """Which implementation ``impl="xla"`` (the default) runs for q
     [b, n, h, *] against k/v [b, m, h, *]: ``fused`` (the Pallas flash
-    kernel), ``xla_whole`` or ``xla_chunked`` (`xla_attention` with the
-    score tensor whole or scanned over query chunks); for a ``masked``
-    call (each query sees the keys up to its own position: a language
-    model's) ``xla_decode`` where one query meets a cache and
-    ``xla_causal`` otherwise; where the mask is ``banded`` besides (a
-    sliding-window layer's: a query sees its last ``window`` keys)
-    ``xla_ring`` where one query meets a ring of slots and ``xla_banded``
-    otherwise.  A masked call stays with `xla_attention` on every
-    platform: the kernel has not been taught a mask.
+    kernel), ``xla_whole`` / ``xla_chunked`` (`xla_attention`, the scores
+    whole or scanned over query chunks); for a ``masked`` call (a language
+    model's: a query sees the keys up to its own position) ``xla_decode``
+    where one query meets a cache, else ``xla_causal``; ``banded`` besides
+    (a query sees its last ``window`` keys) ``xla_ring`` where one query
+    meets a ring of slots, else ``xla_banded``; ``selected`` besides (a
+    query sees the keys a learned index chose) ``xla_gathered`` where one
+    query meets the keys gathered for it, else ``xla_selected``.  A masked
+    call stays with `xla_attention` everywhere: the kernel knows no mask.
 
     A function of what the code can see at trace time and nothing else:
     the backend's platform, the operands' static shapes and the live
@@ -177,10 +177,10 @@ def attention_path(platform: str, b: int, n: int, m: int, h: int,
     ``seq`` axis, rows that do not divide ``data``, heads that do not
     divide ``tensor`` — would run the whole call on every peer, so there
     the call stays with XLA, which partitions it."""
-    if masked and banded:
-        return "xla_ring" if n == 1 else "xla_banded"
     if masked:
-        return "xla_decode" if n == 1 else "xla_causal"
+        kind = ("ring", "banded") if banded else ("gathered", "selected") \
+            if selected else ("decode", "causal")
+        return "xla_" + kind[n != 1]
     axes = mesh_axes or {}
     splits = (axes.get(SEQ_AXIS, 1) == 1 and b % axes.get(DATA_AXIS, 1) == 0
               and h % axes.get(TENSOR_AXIS, 1) == 0)
@@ -268,14 +268,17 @@ def _fused_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
 def visible_keys(m: int, q_positions: jax.Array,
                  kv_start: Optional[jax.Array] = None,
                  kv_positions: Optional[jax.Array] = None,
-                 window: Optional[int] = None) -> jax.Array:
+                 window: Optional[int] = None,
+                 selected: Optional[jax.Array] = None) -> jax.Array:
     """The mask of a masked call, ``[b or 1, n, m]``: query ``i`` sees
     the keys whose position is at most ``q_positions[i]``, in row ``b``
     none in front of ``kv_start[b]``, and with ``window`` only the last
     ``window`` of them, its own counted.  A key's position is its index,
     or ``kv_positions [m]`` where the keys lie elsewhere (the slots of a
     ring: a slot never written holds a position below every row's
-    start)."""
+    start).  With ``selected [b, n or 1, m]`` a query sees of those keys
+    the ones a selection made from the DATA names for it (a learned
+    index's best), and no other."""
     at = jnp.arange(m) if kv_positions is None else kv_positions
     seen = at[None, :] <= q_positions[:, None]
     if window is not None:
@@ -283,7 +286,7 @@ def visible_keys(m: int, q_positions: jax.Array,
     seen = seen[None]
     if kv_start is not None:
         seen = seen & (at >= kv_start[:, None])[:, None, :]
-    return seen
+    return seen if selected is None else seen & selected
 
 
 def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -291,18 +294,40 @@ def _attn_scores_block(q: jax.Array, k: jax.Array, v: jax.Array,
                        q_positions: Optional[jax.Array] = None,
                        kv_start: Optional[jax.Array] = None,
                        kv_positions: Optional[jax.Array] = None,
-                       window: Optional[int] = None) -> jax.Array:
+                       window: Optional[int] = None,
+                       selected: Optional[jax.Array] = None) -> jax.Array:
     """One materialized-score attention block (einsum -> fp32 softmax ->
     einsum); with ``q_positions [n]`` under the mask `visible_keys`
-    gives."""
+    gives; with ``selected`` and no positions under the selection alone
+    (the keys were gathered for the query: each is one it may see).
+
+    Under a selection the softmax is normalised BEHIND the product with
+    the values (``exp(s - max) v`` summed, then divided by the sum of the
+    exponentials, once a query and head and not once a key).  Written the
+    usual way, a prefill's ``[4, 4, 1024, 8192]`` scores come out of the
+    TPU compiler with the keys on the sublanes and the row maximum as a
+    ``reduce-window`` 2 M - 1 wide over them: 21 ms a block where the
+    bytes take 2 (PERF.md section 6, PR 42); this way the maximum is an
+    output of the score product's own fusion, the exponential is
+    recomputed inside the value product's, and the scores are written
+    once and read twice.  Every other call keeps the arithmetic it had."""
     logits = jnp.einsum("bnhd,bmhd->bhnm", q, k,
                         preferred_element_type=jnp.float32) * scale
+    seen = selected
     if q_positions is not None:
         seen = visible_keys(k.shape[1], q_positions, kv_start, kv_positions,
-                            window)[:, None]
-        logits = jnp.where(seen, logits, jnp.finfo(jnp.float32).min)
-    weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.einsum("bhnm,bmhd->bnhd", weights.astype(v.dtype), v)
+                            window, selected)
+    if seen is not None:
+        logits = jnp.where(seen[:, None], logits,
+                           jnp.finfo(jnp.float32).min)
+    if selected is None:
+        weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        return jnp.einsum("bhnm,bmhd->bnhd", weights.astype(v.dtype), v)
+    weights = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
+    out = jnp.einsum("bhnm,bmhd->bnhd", weights.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return (out / jnp.sum(weights, axis=-1).swapaxes(1, 2)[..., None]
+            ).astype(v.dtype)
 
 
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -310,7 +335,8 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   q_positions: Optional[jax.Array] = None,
                   kv_start: Optional[jax.Array] = None,
                   kv_positions: Optional[jax.Array] = None,
-                  window: Optional[int] = None) -> jax.Array:
+                  window: Optional[int] = None,
+                  selected: Optional[jax.Array] = None) -> jax.Array:
     """The reference attention math with a memory ceiling: the path of
     everything `attention_path` does not send to the flash kernel, and
     the oracle the kernel is checked against.
@@ -326,18 +352,22 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     chunk = _query_chunk(B, N, k.shape[1], H)
     if chunk is None:
         return _attn_scores_block(q, k, v, scale, q_positions, kv_start,
-                                  kv_positions, window)
+                                  kv_positions, window, selected)
     n_chunks = N // chunk
     qr = q.reshape(B, n_chunks, chunk, H, D).transpose(1, 0, 2, 3, 4)
     pos = None if q_positions is None \
         else q_positions.reshape(n_chunks, chunk)
+    # a selection is a mask a QUERY: it is walked with the queries
+    sel = None if selected is None else jnp.broadcast_to(
+        selected, (B, N, k.shape[1])).reshape(B, n_chunks, chunk, -1
+                                              ).swapaxes(0, 1)
 
     def body(_, qc):
-        qc, pc = qc
+        qc, pc, sc = qc
         return None, _attn_scores_block(qc, k, v, scale, pc, kv_start,
-                                        kv_positions, window)
+                                        kv_positions, window, sc)
 
-    _, out = jax.lax.scan(body, None, (qr, pos))
+    _, out = jax.lax.scan(body, None, (qr, pos, sel))
     return out.transpose(1, 0, 2, 3, 4).reshape(B, N, H, D)
 
 

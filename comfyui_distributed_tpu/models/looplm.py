@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from comfyui_distributed_tpu.models.layers import _live_mesh, \
     scaled_dot_product_attention
 from comfyui_distributed_tpu.ops.pallas.fewrow_dense import LANES, \
-    fewrow_dense, fewrow_dense_t
+    block_sizes, fewrow_dense, fewrow_dense_t
 from comfyui_distributed_tpu.parallel import sharding as shd
 from comfyui_distributed_tpu.utils.trace import DENSE_PATHS
 
@@ -254,11 +254,14 @@ def dense_path(platform: str, rows: int, k: int, n: int, itemsize: int = 2,
     time and nothing else: the backend's platform, the static shapes, the
     live mesh.  The one-row program, both prefills and every other
     backend keep ``jnp.dot``; so does a weight the kernel's blocks do not
-    divide (``k``, ``n`` multiples of 128) or that is too small to be
-    worth a launch."""
+    divide (``k``, ``n`` multiples of 128), one that is too small to be
+    worth a launch, and one whose columns the blocks could only walk a
+    lane group at a time (a 151,936-row head: 1187 x 128 with 1187 prime,
+    so a tile would be 256-byte runs of the leaf, thousands of them)."""
     if few_rows(platform, rows, mesh_axes) and k % LANES == 0 \
             and n % LANES == 0 \
-            and k * n * itemsize >= FEWROW_MIN_WEIGHT_BYTES:
+            and k * n * itemsize >= FEWROW_MIN_WEIGHT_BYTES \
+            and block_sizes(k, n, 1, itemsize)[1] >= min(n, 2 * LANES):
         return "fewrow"
     return "xla"
 

@@ -115,6 +115,7 @@ class MlaMoeConfig:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
     rms_norm_eps: float = 1e-5
     rope_theta: float = 25.6e6
     experts_first: int = 0
@@ -444,12 +445,14 @@ def _gated_mlp(cfg: MlaMoeConfig, weights, n, scope=jax.named_scope):
 
 def route(cfg: MlaMoeConfig, gate, n):
     """The router over ALL ``n_routed_experts``, in float32 at the
-    highest precision: the scores ``[t, E]``, the chosen experts
-    ``[t, k]`` and their weights (the chosen scores over their sum,
-    times ``routed_scaling_factor``)."""
+    highest precision: the scores ``[t, E]`` (``scoring_func``: each
+    expert's ``sigmoid``, or a ``softmax`` over them all), the chosen
+    experts ``[t, k]`` and their weights (with ``norm_topk_prob`` the
+    chosen scores over their sum; times ``routed_scaling_factor``)."""
     logits = jnp.dot(n.astype(jnp.float32), gate.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.softmax(logits, axis=-1) \
+        if cfg.scoring_func == "softmax" else jax.nn.sigmoid(logits)
     top, chosen = jax.lax.top_k(scores, cfg.num_experts_per_tok)
     if cfg.norm_topk_prob:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
@@ -593,19 +596,22 @@ def _routed(cfg: MlaMoeConfig, experts, l, x, chosen, weights):
 
 
 def _moe(cfg: MlaMoeConfig, lp, experts, l, n):
-    """The expert block's MLP on ``n [B, N, d]``: the shared expert plus
-    this chip's routed part; and its routing: the router's ``(scores
-    [B, N, E], choices [B, N, k])`` and `_routed`'s counts (local pairs
-    per row ``[B]``, hits, dropped, rows computed)."""
+    """The expert block's MLP on ``n [B, N, d]``: the shared expert
+    (where the block has one) plus this chip's routed part; and its
+    routing: the router's ``(scores [B, N, E], choices [B, N, k])`` and
+    `_routed`'s counts (local pairs per row ``[B]``, hits, dropped, rows
+    computed)."""
     B, N, d = n.shape
     x = n.reshape(B * N, d)
     with jax.named_scope("gate"):
         scores, chosen, weights = route(cfg, matrix(lp["gate"]), x)
     y, pairs, *counts = _routed(cfg, experts, l, x, chosen, weights)
-    with jax.named_scope("shared_experts"):
-        shared = _gated_mlp(cfg, lp["shared_experts"], x)
+    shared = None
+    if "shared_experts" in lp:
+        with jax.named_scope("shared_experts"):
+            shared = _gated_mlp(cfg, lp["shared_experts"], x)
     with jax.named_scope("combine"):
-        out = (shared + y).reshape(B, N, d)
+        out = (y if shared is None else shared + y).reshape(B, N, d)
     return out, ((scores.reshape(B, N, -1), chosen.reshape(B, N, -1)),
                  (pairs.reshape(B, N).sum(axis=1), *counts))
 

@@ -1268,7 +1268,7 @@ def clear_pipeline_cache() -> None:
 
 
 # --- language models (models/looplm.py, models/mla_moe.py, models/swa_moe.py,
-# models/ssm_hybrid.py) ---------------------------------------------------------
+# models/ssm_hybrid.py, models/dsa_moe.py) --------------------------------------
 #
 # A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
 # model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
@@ -1285,7 +1285,7 @@ EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
 # a served window compiles nothing).  An execution is padded to the next
 # count with copies of its first row; the last count also bounds what is
 # kept for requests still in the queue (server/lm_handover.py): three
-# results.  Argued per family (`LMFamily.row_counts`); all four take these.
+# results.  Argued per family (`LMFamily.row_counts`); all five take these.
 LM_ROW_COUNTS = (1, 4)
 
 
@@ -1304,7 +1304,8 @@ class LMFamily:
     keeps a RECURRENT state as well, overwritten in place and no function
     of the positions, ``state_bytes(cfg, rows)``; where its state is of
     more than one geometry or kind, ``kv_cache_bytes_by_kind`` -> the
-    parts by name (a ring and a full cache; recurrent and positional);
+    parts by name (a ring and a full cache; recurrent and positional;
+    keys and values and the index keys that choose among them);
     where the state behind a prompt's first ids can stand for them
     (nothing in it depends on where in the buffer a row lies),
     ``make_prefix_program(cfg)`` (the jitted ``lm_prefix_state``: ids
@@ -1392,6 +1393,26 @@ LM_FAMILIES = {
         "granite-4.0-h-micro, whole: Mamba-2 state-space layers with an "
         "attention layer every ten (a recurrent state beside a key-value "
         "cache), a tied embedding"),
+    # A row's caches are 13,056 B a position over the six blocks (keys and
+    # values 2 KiB a block, the index key 128 B): 107.8 MB at 8,256
+    # positions, 0.43 GB at 4 rows beside 8.75 GB resident and SD1.5, and a
+    # prefill of 4 x 8192 positions holds a gigabyte of index scores and
+    # attention blocks at a time besides: here MEMORY argues for 4 and no
+    # more, as it did for Ouro.  So does what argued in the others: the
+    # rows are the requests waiting in one server's queue (four callers in
+    # the cell), every count is a program to compile at set-up (this
+    # family's prefill, twelve selecting chunks a block, is the costliest
+    # of all), the PREFILL is compute-bound (a row adds its positions'
+    # FLOPs whole) and each further row routes 8 more pairs a block over
+    # 128 held experts, 9.4 MB each: a step's bytes grow with the rows
+    # where a dense model's do not.  Why not fewer: one weight stream
+    # (0.9 GB of non-expert weights and the head a step) serves every row.
+    "keye": LMFamily(
+        "dsa_moe", ("keye",), LM_ROW_COUNTS,
+        "Keye-VL-2.0-30B-A3B's language model, one pipeline stage of 8: a "
+        "learned index picks 2,048 keys a query (an index-key cache beside "
+        "the key-value cache, GQA 32/4), all 128 routed experts held; the "
+        "vision tower is not"),
 }
 
 
@@ -1706,7 +1727,8 @@ def load_language_model(name: str, models_dir: Optional[str] = None
     resident (Ouro-2.6B's 5.3 GB and openPangu's 9.8 GB share do not
     share one 16 GB chip with a checkpoint, nor K-EXAONE's 7.4 GB share
     with openPangu's, nor granite-4.0-h-micro's 6.4 GB with either
-    share) it is refused BY NAME, with
+    share, nor Keye-VL-2.0's 8.75 GB stage with any) it is refused BY
+    NAME, with
     what it needs and what is resident, before the allocator fails with
     an error that names nothing."""
     from comfyui_distributed_tpu.models.tokenizer import make_lm_tokenizer

@@ -115,6 +115,7 @@ class ExaoneMoeConfig:
     num_shared_experts: int = 1
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
     rms_norm_eps: float = 1e-5
     rope_theta: float = 1e6
     experts_first: int = 0
@@ -289,20 +290,25 @@ def _qkv(cfg: ExaoneMoeConfig, kind: str, lp, x, positions):
                                None) for t in (q, k, v))
 
 
-def _attend(q, k, v, q_positions, scale=None, **mask):
+def _attend(q, k, v, q_positions, scale=None, selected=None, **mask):
     """``q [B, N, H, D]`` at ``q_positions [N]`` against ``k``, ``v
     [B, M, G, D]`` under the rest of `visible_keys`' ``mask``, the scores
     times ``scale`` (default ``1 / sqrt(D)``): the
     ``H / G`` query heads of a group meet the group's one key-value head
     as ``H / G`` queries a position of a ``G``-headed call, head ``h`` in
-    group ``h // (H / G)``.  -> ``[B, N, H * D]``."""
+    group ``h // (H / G)``; a position's ``selected [B, N or 1, M]`` keys
+    (and, with no ``q_positions``, that selection alone) are all its
+    heads'.  -> ``[B, N, H * D]``."""
     B, N, H, D = q.shape
     G = k.shape[2]
     grouped = q.reshape(B, N, G, H // G, D).swapaxes(2, 3)
+    if selected is not None and selected.shape[1] > 1:
+        selected = jnp.repeat(selected, H // G, axis=1)
     out = xla_attention(
         grouped.reshape(B, N * (H // G), G, D), k, v,
         1.0 / math.sqrt(D) if scale is None else scale,
-        jnp.repeat(q_positions, H // G), **mask)
+        None if q_positions is None else jnp.repeat(q_positions, H // G),
+        selected=selected, **mask)
     return out.reshape(B, N, H // G, G, D).swapaxes(2, 3).reshape(B, N, -1)
 
 

@@ -230,6 +230,7 @@ OTHER = "other"
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
 _UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
 _MOE, _SWA, _SSM = "PanguUltraMoE", "ExaoneMoe", "GraniteMoeHybrid"
+_DSA = "KeyeVL2"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
     # the Mamba mixer's gated RMSNorm is published as ``mamba/norm``: ahead
@@ -326,10 +327,31 @@ KERNEL_CLASSES = (
     ("embed", _SSM, r"embed_tokens"),
     ("lm_proj", _SSM, r"mamba_layers|attention_layers|prefill|decode"
                       r"|GraniteMoeHybrid"),
+    # the decoder with a learned key selection and routed experts
+    # (models/dsa_moe.py), under the same classes, and one more:
+    # ``lm_index`` is everything the selection adds to an attention (the
+    # indexer's three projections, its key's norm and rotation, the index
+    # scores, the search for the ``topk`` best, the gather of the keys
+    # chosen, the record of the choice); the index keys' WRITE lies in
+    # ``kv_cache`` with the keys' and values'.  Its expert layer IS
+    # ``mla_moe``'s, so the same four names are ``lm_experts``
+    ("lm_norm", _DSA, r"(?:input|post_attention)_layernorm|[qk]_norm"
+                      r"|final_norm"),
+    ("lm_index", _DSA, r"indexer|wq|wk|k_layernorm|index_rotary"
+                       r"|weights_proj|index_scores|topk|gather"
+                       r"|selection_record"),
+    ("lm_proj", _DSA, r"[qkvo]_proj"),
+    ("lm_cache", _DSA, r"kv_cache"),    # keys, values AND index keys
+    ("lm_attn", _DSA, r"self_attn|rotary"),
+    ("lm_experts", _DSA, r"gate|dispatch|experts|combine"),
+    ("lm_mlp", _DSA, r"mlp|gate_proj|up_proj|down_proj"),
+    ("lm_head", _DSA, r"lm_head|sample"),
+    ("embed", _DSA, r"embed_tokens"),
+    ("lm_proj", _DSA, r"layers|prefill|decode|KeyeVL2"),
 )
 # the outer scopes a program may put directly under its model's: where it
 # does, a trace summary gives its seconds by PHASE beside its seconds by
-# class (the four language models' ``generate`` do; the denoise, VAE and
+# class (the five language models' ``generate`` do; the denoise, VAE and
 # text programs do not and have no phases).  A program that the
 # persistent compile cache LOADS carries the names of the tree that
 # compiled it (JAX keys a program without a Pallas kernel with its debug
@@ -342,7 +364,7 @@ SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
 _MODEL_OF = re.compile(
     r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE|ExaoneMoe"
-    r"|GraniteMoeHybrid)(?:\.\w+)?$")
+    r"|GraniteMoeHybrid|KeyeVL2)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 

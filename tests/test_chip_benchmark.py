@@ -108,11 +108,22 @@ _GRANITE_ONLY = {
                                      "recurrence, over positions only that "
                                      "program counts",
 }
+# (PR 42: the two shares of the decoder with a learned key selection)
+KEYE4 = "keye_expand_sd15_512_sat4"
+_KEYE_ONLY = {
+    "lm_dsa_decode_hbm_roofline_pct": "the bytes of a decoder that scores "
+                                      "an index-key cache and gathers the "
+                                      "keys chosen, from counters only its "
+                                      "program has",
+    "lm_dsa_prefill_flops_util_pct": "the FLOPs of a prefill with index "
+                                     "scores and attention over a "
+                                     "selection, from the same counters",
+}
 NOT_IN_SAT4 = {
     "chip_busy_min_pct": _ONE_CHIP,
     "lm_moe_decode_hbm_roofline_pct": "the bytes of a decoder with routed "
                                       "experts: Ouro has none to count",
-    **_EXAONE_ONLY, **_GRANITE_ONLY,
+    **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY,
 }
 NOT_IN_PANGU4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -120,7 +131,7 @@ NOT_IN_PANGU4 = {
                                   "decoder's and would be false here: "
                                   "lm_moe_decode_hbm_roofline_pct stands "
                                   "in its place",
-    **_EXAONE_ONLY, **_GRANITE_ONLY,
+    **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY,
 }
 NOT_IN_EXAONE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -129,7 +140,7 @@ NOT_IN_EXAONE4 = {
                                       "kv_lora_rank, a latent cache's: "
                                       "lm_swa_moe_decode_hbm_roofline_pct "
                                       "stands in its place",
-    **_GRANITE_ONLY,
+    **_GRANITE_ONLY, **_KEYE_ONLY,
 }
 NOT_IN_GRANITE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -138,6 +149,16 @@ NOT_IN_GRANITE4 = {
                                       "latent cache",
     **{name: "it has no routed expert, no ring and counts no local pairs: "
              "its own two stand in their place" for name in _EXAONE_ONLY},
+    **_KEYE_ONLY,
+}
+NOT_IN_KEYE4 = {
+    "chip_busy_min_pct": _ONE_CHIP,
+    "lm_decode_hbm_roofline_pct": NOT_IN_PANGU4["lm_decode_hbm_roofline_pct"],
+    "lm_moe_decode_hbm_roofline_pct": "it has no latent cache",
+    **{name: "it has no ring, and what a step reads of its cache is chosen "
+             "by an index: its own two stand in their place"
+       for name in _EXAONE_ONLY},
+    **_GRANITE_ONLY,
 }
 
 
@@ -229,7 +250,10 @@ def _exaone4_reports(m, cells):
         if x["name"] in _listed(m, EXAONE4) - _listed(m, PANGU4):
             # (PR 40's cell stands behind it where the reader is
             # family-neutral)
-            assert x["workloads"] in ([EXAONE4], [EXAONE4, GRANITE4]) \
+            # (and PR 42's behind that, and behind the experts' reader)
+            assert x["workloads"] in ([EXAONE4], [EXAONE4, GRANITE4],
+                                      [EXAONE4, KEYE4],
+                                      [EXAONE4, GRANITE4, KEYE4]) \
                 and x["layer"] == "Language model" \
                 and x["source"] == "device_trace"
     cfg = _config(exaone["config"])
@@ -271,8 +295,47 @@ def _granite4_reports(m, cells):
     assert (node["prompt_tokens"], node["max_new_tokens"],
             node["temperature"]) == (2048, 64, 0.0)
     assert len(node["instructions"].split()) == 1950
-    # 9 of at most 24 cells, still one on four chips
-    assert len(m["workloads"]) == 9
+    # 9 of at most 24 cells with this one, still one on four chips
+    assert len(m["workloads"][:9]) == 9 and m["workloads"][8] == granite
+    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
+        ["sdxl_1024_fanout4"]
+
+
+def _keye4_reports(m, cells):
+    """The cell PR 42 appended: granite's with a fifth language model in
+    front (one pipeline stage: depth alone reduced) behind an 8192-id
+    prompt; it lists what granite's lists but the state-space readers,
+    the experts' two readers, and four readers of its own."""
+    keye = cells[KEYE4]
+    assert keye["config"] == "keye-vl-2.0-30b-a3b-expand-sd15-512"
+    own = _listed(m, KEYE4) - _listed(m, GRANITE4)
+    assert own == {*_KEYE_ONLY, "lm_index_device_s_per_request",
+                   "lm_prefill_index_device_s_per_request",
+                   "lm_experts_device_s_per_request",
+                   "lm_prefill_experts_device_s_per_request"}
+    assert _listed(m, GRANITE4) - _listed(m, KEYE4) == {
+        *_GRANITE_ONLY, "lm_ssm_device_s_per_request",
+        "lm_prefill_ssm_device_s_per_request"}
+    for x in m["per_layer"]:
+        if x["name"] in _KEYE_ONLY or "_index_" in x["name"]:
+            assert x["workloads"] == [KEYE4] and x["layer"] == \
+                "Language model" and x["source"] == "device_trace"
+    assert [x["name"] for x in m["per_layer"][-4:]] == [
+        "lm_index_device_s_per_request",
+        "lm_prefill_index_device_s_per_request", *_KEYE_ONLY]
+    cfg = _config(keye["config"])
+    _same_graph_but(cfg, _config("granite-4.0-h-micro-expand-sd15-512"),
+                    {"20", "21"})
+    assert cfg["reduced"] == ["num_hidden_layers"] \
+        and cfg["graph"]["20"]["inputs"] == {
+            "model_name": "keye-vl-2.0-30b-a3b.safetensors"}
+    node = cfg["graph"]["21"]["inputs"]
+    assert (node["prompt_tokens"], node["max_new_tokens"],
+            node["temperature"]) == (8192, 64, 0.0)
+    assert len(node["instructions"].split()) == 8100
+    # 10 of at most 24 cells, 7 configurations, still one on four chips
+    assert len(m["workloads"]) == 10 and len(m["configs"]) == 7
+    assert m["workloads"][-1] == keye
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
         ["sdxl_1024_fanout4"]
 
@@ -282,7 +345,8 @@ def _granite4_reports(m, cells):
     (PANGU4, NOT_IN_PANGU4, _pangu4_reports),
     (EXAONE4, NOT_IN_EXAONE4, _exaone4_reports),
     (GRANITE4, NOT_IN_GRANITE4, _granite4_reports),
-], ids=[SAT4, PANGU4, EXAONE4, GRANITE4])
+    (KEYE4, NOT_IN_KEYE4, _keye4_reports),
+], ids=[SAT4, PANGU4, EXAONE4, GRANITE4, KEYE4])
 def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
         cell, leaves_out, reports):
     m = _manifest()

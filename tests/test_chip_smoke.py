@@ -74,7 +74,7 @@ def test_lm_phase_holds_the_served_logits_to_the_reference(tmp_path):
     request of the prompt expander's graph, then the plain reference),
     tiny on the CPU; the 8-bit readings have to be refused."""
     r = _run([SMOKE, "--rehearse", "--phases", "lm", "--out",
-              str(tmp_path / "out")], tmp_path, 600)
+              str(tmp_path / "out")], tmp_path, 900)
     assert r.returncode == 0, r.stderr[-3000:]
     summary, last = map(json.loads, r.stdout.strip().splitlines())
     assert last == {"ok": True, "device": summary["device"]}
@@ -110,6 +110,23 @@ def test_lm_phase_holds_the_served_logits_to_the_reference(tmp_path):
         assert ssm[reading] > ssm["limits"]["mean_over_std"], reading
     with open(tmp_path / "out" / "verify_lm_ssm"
               / "verify_lm_ssm.json") as f:
+        assert json.load(f)["ok"] is True
+    # and the model that selects its keys (PR 42), alone and four rows
+    # together, the reference forced to the program's choices and
+    # selections; the eight readings that have to fail do
+    dsa = summary["smoke_facts"]["language_model_dsa"]
+    assert dsa["rows"] == 5 and dsa["positions"] == 4
+    assert dsa["together"]["executions"] == 1 \
+        and dsa["together"]["rows"] == 4
+    assert dsa["mean_over_std"] <= dsa["limits"]["mean_over_std"]
+    assert dsa["selection_agree"] == 1.0
+    assert dsa["free"]["expert_choices_agree"] == 1.0
+    for reading in ("cache_8bit", "weights_8bit", "no_selection",
+                    "last_topk", "no_relu", "no_head_weights", "top7_of_8",
+                    "no_renormalisation"):
+        assert dsa[reading] > dsa["limits"]["mean_over_std"], reading
+    with open(tmp_path / "out" / "verify_lm_dsa_moe"
+              / "verify_lm_dsa_moe.json") as f:
         assert json.load(f)["ok"] is True
     # and four rows started from the snapshot of their shared
     # instructions (PR 41): against the reference of the WHOLE prompt

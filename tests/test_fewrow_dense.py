@@ -13,16 +13,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import looplm, mla_moe, ssm_hybrid, \
-    swa_moe
+from comfyui_distributed_tpu.models import dsa_moe, looplm, mla_moe, \
+    ssm_hybrid, swa_moe
 from comfyui_distributed_tpu.ops.pallas import fewrow_dense as fd
 from comfyui_distributed_tpu.utils import trace
 
 OURO, PANGU = looplm.OURO_2_6B, mla_moe.OPENPANGU_ULTRA_MOE_SHARE
 EXAONE = swa_moe.K_EXAONE_SHARE
 GRANITE = ssm_hybrid.GRANITE_4_0_H_MICRO
+KEYE = dsa_moe.KEYE_VL2_STAGE
 FAMILIES = {"ouro": (looplm, OURO), "pangu": (mla_moe, PANGU),
-            "exaone": (swa_moe, EXAONE), "granite": (ssm_hybrid, GRANITE)}
+            "exaone": (swa_moe, EXAONE), "granite": (ssm_hybrid, GRANITE),
+            "keye": (dsa_moe, KEYE)}
 
 # every product `_dense` makes with a resident leaf at the published
 # sizes: (family, name, K, N, leaves streamed by one call)
@@ -56,6 +58,11 @@ PRODUCTS = [
     ("granite", "q_proj", 2048, 2048, 1),
     ("granite", "k_proj+v_proj", 2048, 512, 2),
     ("granite", "o_proj", 2048, 2048, 1),
+    # (PR 42; its head of 151,936 columns is further down)
+    ("keye", "q_proj", 2048, 4096, 1),
+    ("keye", "k_proj+v_proj", 2048, 512, 2),
+    ("keye", "o_proj", 4096, 2048, 1),
+    ("keye", "indexer wq", 2048, 1024, 1),
 ]
 IDS = [f"{p[0]}-{p[1]}" for p in PRODUCTS]
 
@@ -82,6 +89,11 @@ def test_the_published_shapes_take_the_kernel_at_two_to_eight_rows(
 
 @pytest.mark.parametrize("k, n, why", [
     (7680, 576, "kv_a_proj_with_mqa: 576 is not a multiple of 128"),
+    (2048, 64, "the indexer's one key: half a lane group"),
+    (2048, 16, "the indexer's head weights"),
+    (2048, 151936, "a head of 1187 x 128 columns, 1187 prime: the blocks "
+                   "could only walk it a lane group at a time (256-byte "
+                   "runs of the leaf)"),
     (64, 176, "the tiny models' widths"),
     (2048, 1, "the exit gate's vector"),
     (128, 256, "aligned, and too small to be worth a launch"),
@@ -263,7 +275,8 @@ def tiny_run(arch, cfg, rows, where, monkeypatch):
         np.asarray, (aux, stats))
 
 
-@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite"])
+@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite",
+                                    "keye"])
 def test_a_scan_over_the_index_gives_what_a_scan_over_the_slices_gives(
         family, monkeypatch):
     """With the platform read as a TPU's the 4-row decode walks the layer
@@ -301,7 +314,8 @@ def traced(family, rows, where, monkeypatch, sharding=None, positions=64):
         spec((rows,), np.uint32), spec((rows,), np.float32))
 
 
-@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite"])
+@pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite",
+                                    "keye"])
 def test_the_one_row_program_is_untouched_by_the_rule(family, monkeypatch):
     """Its text as lowered with the platform read as a TPU's is, byte for
     byte, its text with the rule off; and the 4-row program's is not."""
@@ -421,6 +435,7 @@ def weights_of(family):
     ("pangu", {512 * 32768}),
     ("exaone", set()),
     ("granite", set()),
+    ("keye", set()),
 ])
 def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
         family, known, one_chip, no_compile_cache, monkeypatch):
@@ -454,9 +469,14 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
     # and the kernel's operations carry the published modules' scopes
     paths = {p for l in calls for p in re.findall(r'op_name="([^"]+)"', l)}
     classes = {trace.classify(p) for p in paths}
-    assert classes == {"lm_proj", "lm_mlp", "lm_head"}, paths
+    # (keye: no MLP that is no expert's, the indexer's query projection,
+    # and a head that stays with XLA)
+    assert classes == ({"lm_proj", "lm_index"} if family == "keye" else
+                       {"lm_proj", "lm_mlp", "lm_head"}), paths
     if family == "granite":
         _granite_decode_keeps_its_state_in_place(text, bodies)
+    if family == "keye":
+        _keye_decode_keeps_its_caches_and_experts_in_place(text, bodies)
     segments = {seg for p in paths for seg in p.split("/")}
     want = {"ouro": {"q_proj", "o_proj", "gate_proj", "down_proj", "lm_head",
                      "fewrow_dense_q_proj_k_proj_v_proj",
@@ -470,8 +490,46 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
             "granite": {"in_proj", "out_proj", "input_linear",
                         "output_linear", "q_proj", "o_proj", "lm_head",
                         "fewrow_dense_k_proj_v_proj",
-                        "fewrow_dense_t"}}[family]
+                        "fewrow_dense_t"},
+            "keye": {"q_proj", "o_proj", "indexer", "wq",
+                     "fewrow_dense_k_proj_v_proj"}}[family]
     assert want <= segments, want - segments
+
+
+def _keye_decode_keeps_its_caches_and_experts_in_place(text, bodies,
+                                                       positions=64):
+    """Both caches are written in place and the wide one is READ by
+    index: no computation that holds a kernel call (a decode step's own
+    or the layer scan's body) writes a buffer of a whole cache (keys or
+    values ``[6, 4, T, 4, 128]``, index keys ``[6, 4, T, 64]``), of one
+    layer of the keys and values, or of a whole expert leaf
+    (``[6, 128, ...]``: a slice handed to the conditional would be a
+    copy) but by an in-place ``dynamic-update-slice``; and the head, which
+    the rule leaves to XLA, is read where it lies."""
+    T = positions + 64
+    state = {f"bf16[6,4,{T},4,128]", f"bf16[6,4,{T},64]",
+             f"bf16[4,{T},4,128]", "bf16[6,128,2048,768]",
+             "bf16[6,128,768,2048]", "bf16[128,2048,768]",
+             "bf16[128,768,2048]", "bf16[2048,151936]",
+             "bf16[151936,2048]"}
+    seen = set()
+    for name, lines in bodies.items():
+        for line in lines:
+            m = INSTRUCTION.match(line)
+            if not m:
+                continue
+            seen.add(f"{m['dtype']}[{m['dims']}]")
+            if m["op"] in PASSES_ON or "dynamic-update-slice" in m["name"] \
+                    or "dynamic_update_slice" in line:
+                continue
+            assert f"{m['dtype']}[{m['dims']}]" not in state, \
+                (name, line.strip()[:200])
+    # (the caches and the expert leaves do pass through those bodies)
+    assert {f"bf16[6,4,{T},4,128]", f"bf16[6,4,{T},64]",
+            "bf16[6,128,2048,768]"} <= seen
+    # a step gathers the keys chosen, it does not slice the layer
+    assert any("gather(" in l and "indexer/gather" in l
+               for lines in computations(text).values() for l in lines)
 
 
 def _granite_decode_keeps_its_state_in_place(text, bodies, positions=128):

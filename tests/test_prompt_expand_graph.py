@@ -427,6 +427,39 @@ def test_the_long_shot_graph_runs_with_the_fourth_family(mesh, devices,
     assert counters["lm.keys_attended_full"] == 2 * (33 + 34 + 35 + 36)
 
 
+@pytest.mark.parametrize("mesh, devices, images", [
+    ("data=1", 1, 1), ("data=2,tensor=2", 4, 2)])
+def test_the_system_prompt_graph_runs_with_the_fifth_family(mesh, devices,
+                                                            images, tmp_path):
+    """``workflows/prompt-expand-sysprompt-txt2img.json`` (PR 42): a model
+    of the family whose queries select their keys (an index-key cache
+    beside the key-value cache, a top-k and a gather in every decode step)
+    behind the same nodes, on one device and under a mesh: the same
+    expansion, no family-specific line in the nodes or the executor."""
+    got = probe("prompt-expand-sysprompt-txt2img.json", tmp_path, devices,
+                DTPU_MESH_SHAPE=mesh, DTPU_TP_MIN_SHARD_ELEMENTS="64")
+    assert got["images"] == images
+    assert got["lm_resident"] == ["lm:keye-vl-2.0-30b-a3b.safetensors:"]
+    assert got["text"].startswith("a lighthouse on a cliff at dawn, ")
+    assert len(got["text"].split()) == 7 + 4
+    # in the programs of both row counts: a prefill of 32 positions in
+    # chunks of 4, the first two within topk = 8 and six selecting; a
+    # decode step gathers
+    assert {k: got["paths"][k] for k in (
+        "xla_causal", "xla_selected", "xla_gathered")} == {
+        "xla_causal": 4, "xla_selected": 12, "xla_gathered": 2}
+    # 32 prompt ids, all real (the instructions fill the buffer); 4 steps
+    # over 3 blocks: every cached index key scored, 8 keys attended to
+    counters = got["counters"]
+    assert counters["lm.prefill_positions"] == 32
+    assert counters["lm.keys_scored_decode"] == 3 * (33 + 34 + 35 + 36)
+    assert counters["lm.keys_attended"] == counters["lm.keys_selected"] \
+        == 4 * 3 * 8
+    assert counters["lm.expert_pairs_local"] == counters["lm.expert_pairs"] \
+        == 4 * 3 * 2
+    assert counters["lm.expert_pairs_dropped"] == 0
+
+
 @pytest.fixture(scope="module")
 def whole_prompt_text(tmp_path_factory):
     """The long-shot graph with no instructions: the whole prompt buffer

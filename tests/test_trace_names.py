@@ -40,6 +40,9 @@ MOE_CLASSES = LM_CLASSES | {"lm_experts"}
 # rows): the same classes and two for the Mamba mixer's own work and its
 # recurrent state
 SSM_CLASSES = LM_CLASSES | {"lm_ssm", "lm_state"}
+# the decoder with a learned key selection (PR 42): what the selection
+# adds to an attention
+DSA_CLASSES = MOE_CLASSES | {"lm_index"}
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
@@ -61,7 +64,8 @@ def compiled_op_names(fn):
 def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
     classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
     assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
-        | LM_CLASSES | MOE_CLASSES | SSM_CLASSES | {"vae_attn"}
+        | LM_CLASSES | MOE_CLASSES | SSM_CLASSES | DSA_CLASSES \
+        | {"vae_attn"}
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; from comfyui_distributed_tpu.utils.trace import "
