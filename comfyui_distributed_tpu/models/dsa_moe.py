@@ -390,47 +390,57 @@ def _indices_of(mask, count: int):
 
 
 def _attend_selected(cfg: KeyeConfig, q, k, v, qi, ki, w, first,
-                     gathered: int):
-    """The prefill's attention: the ``P`` queries of this call against its
-    own keys, a chunk of ``q_chunk_size`` queries at a time, each chunk
-    against the keys up to its end.  A chunk that ends within the first
-    ``topk`` positions attends to every key it may see; a later one scores
-    its index queries against the index keys, selects (`select_keys`) and
-    attends under that mask.  Returns ``[B, P, H * D]``, the selection of
-    every query packed (`_pack`, ``[B, P, ceil(P / 32)]``), the last
-    query's as indices ``[B, gathered]`` (-1 behind the last) and, int32
-    ``[B]``: the index keys scored, the keys selected (by the queries that
-    see more than ``topk``) and the keys attended to (by all)."""
+                     gathered: int, own=None):
+    """The prefill's attention: the ``P`` queries of this call against the
+    ``M`` keys ``k`` / ``v`` / ``ki`` hold, its own the LAST ``P`` of them
+    (behind a snapshot the ``M - P`` in front are the cache's), a chunk of
+    ``q_chunk_size`` queries at a time, each chunk against the keys up to
+    its end.  A chunk that ends within the first ``topk`` positions
+    attends to every key it may see; a later one scores its index queries
+    against the index keys, selects (`select_keys`) and attends under
+    that mask.  A query that is not a row's ``own [B, P]`` (behind a
+    snapshot a shorter row's first slots, where the end of its prefix
+    stands) sees nothing, as a padded one.  Returns ``[B, P, H * D]``, the
+    selection of every query packed (`_pack`, ``[B, P, ceil(M / 32)]``),
+    the last query's as indices ``[B, gathered]`` (-1 behind the last)
+    and, int32 ``[B]``: the index keys scored, the keys selected (by the
+    queries that see more than ``topk``) and the keys attended to (by
+    all)."""
     B, P = q.shape[:2]
+    M = k.shape[1]
+    held = M - P
     C, K, H = cfg.q_chunk_size, cfg.topk, cfg.num_attention_heads
-    words = -(-P // 32)
+    words = -(-M // 32)
     out, packed = [], []
     scored = selected = attended = jnp.zeros((B,), jnp.int32)
     for start in range(0, P, C):
         stop = min(start + C, P)
-        seen = visible_keys(stop, jnp.arange(start, stop), first)
-        if stop > K:
+        end = held + stop
+        seen = visible_keys(end, jnp.arange(held + start, end), first)
+        if own is not None:
+            seen = seen & own[:, start:stop, None]
+        if end > K:
             sees = jnp.sum(seen, axis=-1, dtype=jnp.int32)
             with jax.named_scope("indexer"):
                 with jax.named_scope("index_scores"):
                     scores = index_scores(qi[:, start:stop], w[:, start:stop],
-                                          ki[:, :stop])
+                                          ki[:, :end])
                 with jax.named_scope("topk"):
                     seen = select_keys(scores, seen, K)
             scored = scored + jnp.sum(sees, axis=-1)
             selected = selected + jnp.sum(jnp.where(sees > K, K, 0), axis=-1)
         ATTENTION_PATHS.bump(attention_path(
-            jax.default_backend(), B, stop - start, stop, H, masked=True,
-            selected=stop > K))
+            jax.default_backend(), B, stop - start, end, H, masked=True,
+            selected=end > K))
         # (a chunk that selects nothing attends under the same call: its
         # selection is every key it may see)
-        out.append(_attend(q[:, start:stop], k[:, :stop], v[:, :stop], None,
+        out.append(_attend(q[:, start:stop], k[:, :end], v[:, :end], None,
                            selected=seen))
         attended = attended + jnp.sum(seen, axis=(1, 2), dtype=jnp.int32)
         with jax.named_scope("selection_record"):
             packed.append(_pack(seen, words))
     with jax.named_scope("selection_record"):
-        last = _indices_of(jnp.pad(seen[:, -1], ((0, 0), (0, P - stop))),
+        last = _indices_of(jnp.pad(seen[:, -1], ((0, 0), (0, M - end))),
                            gathered)
     return jnp.concatenate(out, axis=1), jnp.concatenate(packed, axis=1), \
         last, (scored, selected, attended)
@@ -467,14 +477,31 @@ def _attend_gathered(cfg: KeyeConfig, q, qi, w, kc, vc, ic, l, at, first,
          jnp.sum(live, axis=-1, dtype=jnp.int32))
 
 
+def _own_entries(own, new, cache, l, at):
+    """``new [B, N, ...]``, to be written into layer ``l`` of ``cache`` at
+    index ``at``, with what the cache HOLDS there wherever a position is
+    not a row's ``own [B, N]`` (`ssm_hybrid._own_entries`' rule, for a
+    cache of any width)."""
+    tail = (0,) * (new.ndim - 2)
+    held = jax.lax.dynamic_slice(cache, (l, 0, at, *tail),
+                                 (1, *new.shape))[0]
+    return jnp.where(jnp.expand_dims(own, tuple(range(2, new.ndim))), new,
+                     held.astype(new.dtype))
+
+
 def _attention(cfg: KeyeConfig, lp, x, positions, index, first, caches, l,
-               decode: bool):
+               decode: bool, prefix: int = 0):
     """``h = x + Attn(N1(x))``, the three caches with this call's keys,
     values and index keys written into layer ``l`` at the buffer indices
     ``index``, and what was selected (`_attend_selected`'s or
-    `_attend_gathered`'s)."""
+    `_attend_gathered`'s).  A prefill from the front of the buffer attends
+    to this call's own keys (they ARE the cache's); one behind ``prefix``
+    positions that the caches hold already (`from_prefix`) writes a row's
+    own entries alone and attends to the caches as far as its last
+    index."""
     kc, vc, ic = caches
     gathered = min(cfg.topk, ic.shape[2])
+    own = index[None, :] >= first[:, None] + prefix if prefix else None
     with jax.named_scope("input_layernorm"):
         u = _rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
     with jax.named_scope("self_attn"):
@@ -482,38 +509,48 @@ def _attention(cfg: KeyeConfig, lp, x, positions, index, first, caches, l,
         with jax.named_scope("indexer"):
             qi, ki, w = _index(cfg, lp["indexer"], u, positions)
         with jax.named_scope("kv_cache"):
+            if prefix:
+                k, v, ki = (_own_entries(own, t, c, l, index[0])
+                            for c, t in zip((kc, vc, ic), (k, v, ki)))
             kc, vc = (jax.lax.dynamic_update_slice(
                 c, t[None].astype(c.dtype), (l, 0, index[0], 0, 0))
                 for c, t in zip((kc, vc), (k, v)))
             ic = jax.lax.dynamic_update_slice(
                 ic, ki[None].astype(ic.dtype), (l, 0, index[0], 0))
+            if prefix:
+                k, v, ki = (jax.lax.dynamic_index_in_dim(
+                    c, l, keepdims=False)[:, :prefix + len(index)].astype(
+                        cfg.dtype) for c in (kc, vc, ic))
         if decode:
             a, *chose = _attend_gathered(cfg, q, qi, w, kc, vc, ic, l,
                                          index[0], first, gathered)
         else:
             a, *chose = _attend_selected(cfg, q, k, v, qi, ki, w, first,
-                                         gathered)
+                                         gathered, own)
         with jax.named_scope("o_proj"):
             a = _dense(a, lp["o_proj"], cfg)
     return x + a, (kc, vc, ic), chose
 
 
 def _stack(cfg: KeyeConfig, params, x, positions, index, first, caches,
-           decode: bool):
-    """Every block held.  ``caches`` is `empty_cache`'s triple; each block
-    writes this call's entries at the buffer indices ``index [N]``
-    (consecutive, the same for every row); row ``b``'s real entries start
-    at ``first[b]``; ``positions [3, B, N]`` are the tokens' position
-    triples.  The layer index is walked with the stacked leaves closed
-    over (`looplm.scan_layers`), so a few-row call's products stream
-    their leaves in place and no expert leaf is sliced.  Returns the
+           decode: bool, prefix: int = 0):
+    """Every block held.  ``caches`` is `empty_cache`'s triple or, behind
+    a shared prefix, `from_prefix`'s; each block writes this call's
+    entries at the buffer indices ``index [N]`` (consecutive, the same
+    for every row); row ``b``'s real entries start at ``first[b]``, the
+    first ``prefix`` of them in ``caches`` already; ``positions
+    [3, B, N]`` are the tokens' position triples.  The layer index is
+    walked with the stacked leaves closed over (`looplm.scan_layers`), so
+    a few-row call's products stream their leaves in place and no expert
+    leaf is sliced.  Returns the
     normed last state, the caches and a dict: the routers' ``scores
     [B, L, E]`` at the LAST position and ``chosen [B, N, L, k]``, the
     routing ``counts`` summed over the blocks (local pairs ``[B]``, hits,
     dropped, rows computed), ``selection [B, L, gathered]`` (the last
     query's keys by index), ``keys`` (``[B]`` each: scored, selected,
     attended, summed over the blocks) and, of a prefill, ``packed
-    [B, N, L, words]`` (every query's selection)."""
+    [B, N, L, words]`` (every query's selection over the buffer as far
+    as the call's last index)."""
     layers = dict(params["layers"])
     experts = layers.pop("experts")
 
@@ -521,7 +558,7 @@ def _stack(cfg: KeyeConfig, params, x, positions, index, first, caches,
         x, *caches = carry
         lp, l = xs
         h, caches, chose = _attention(cfg, lp, x, positions, index, first,
-                                      caches, l, decode)
+                                      caches, l, decode, prefix)
         with jax.named_scope("post_attention_layernorm"):
             n = _rms_norm(h, lp["post_attention_layernorm"],
                           cfg.rms_norm_eps)
@@ -577,15 +614,130 @@ def kv_cache_bytes(cfg: KeyeConfig, batch: int, length: int) -> int:
     return sum(kv_cache_bytes_by_kind(cfg, batch, length).values())
 
 
+# --- a prefix shared between requests ----------------------------------------
+#
+# What a prompt's first K ids leave behind is K keys, values and index
+# keys a block: the SNAPSHOT (`make_prefix_program`), the caches of one row
+# with no axis of rows, beside what the blocks CHOSE over those K
+# positions (`generate`'s ``aux`` records the whole prompt's).  Rows whose
+# prompts start with those ids start from copies of it and prefill their
+# own suffix only.  Legal because a token's position counts from its
+# row's first real id (`text_positions`): a prefix's key is rotated the
+# same in every row, wherever the row's padding pushes it in the buffer,
+# so the snapshot can stand at each row's own offset.
+
+PREFIX_CACHES = ("keys", "values", "index_keys")
+
+
+def prefix_bytes(cfg: KeyeConfig, positions: int) -> int:
+    """Bytes of the snapshot behind ``positions`` ids: the three caches
+    and the records (the experts chosen, int32; every query's selection,
+    a bit a key)."""
+    records = cfg.num_hidden_layers * positions * 4 \
+        * (cfg.num_experts_per_tok + -(-positions // 32))
+    return kv_cache_bytes(cfg, 1, positions) + records
+
+
+def make_prefix_program(cfg: KeyeConfig):
+    """The jitted maker of a snapshot, ``lm_prefix_state`` (NOT
+    ``lm_generate``: what is counted and timed an execution is the served
+    program's): ``prefix_ids [K]``, one row and no padding, through every
+    block as a prefill -> ``keys``, ``values`` ``[L, K, G, D]`` and
+    ``index_keys`` ``[L, K, D_I]`` as the caches hold them, ``choices
+    [K, L, k]`` (the experts chosen) and ``selected [K, L, ceil(K / 32)]``
+    (`_pack`: every query's selection by position in the ROW, which is
+    the buffer's index here)."""
+
+    def lm_prefix_state(params, prefix_ids):
+        K, = prefix_ids.shape
+        index, first = jnp.arange(K), jnp.zeros((1,), jnp.int32)
+        with jax.named_scope("KeyeVL2"), jax.named_scope("prefill"):
+            _, caches, out = _stack(
+                cfg, params, _embed(params, prefix_ids[None]),
+                text_positions(index, first), index, first,
+                empty_cache(cfg, 1, K), decode=False)
+        return {**{name: c[:, 0] for name, c in zip(PREFIX_CACHES, caches)},
+                "choices": out["chosen"][0], "selected": out["packed"][0]}
+
+    return jax.jit(lm_prefix_state)
+
+
+def from_prefix(caches, prefix, first):
+    """`empty_cache`'s ``caches`` with every row started from the snapshot
+    ``prefix``: its K keys, values and index keys written at row ``b``'s
+    own offset ``first[b]``, directly in front of where that row's suffix
+    will be written (the padding lies in front of both, so the mask stays
+    ``kv_start = first`` with no hole)."""
+    out = []
+    with jax.named_scope("kv_cache"):
+        for cache, name in zip(caches, PREFIX_CACHES):
+            tail = (0,) * (cache.ndim - 3)
+            for b in range(cache.shape[1]):
+                cache = jax.lax.dynamic_update_slice(
+                    cache, prefix[name][:, None].astype(cache.dtype),
+                    (0, b, first[b], *tail))
+            out.append(cache)
+    return tuple(out)
+
+
+def _shift_up(words, by, width: int):
+    """Packed masks ``words [..., w]`` (`_pack`) with every key moved up
+    ``by`` positions (a traced int32), in ``width >= w`` words: bit ``s``
+    of a mask is bit ``s + by`` of the result, what would lie behind the
+    last word is dropped.  A funnel shift: word ``j`` takes the low bits
+    of word ``j - by // 32`` and the high bits of the one below."""
+    pad = [(0, 0)] * (words.ndim - 1)
+    words = jnp.pad(words, pad + [(0, width - words.shape[-1])])
+    bits = (by % 32).astype(jnp.uint32)
+    # (a shift by 32 is no shift of 0 bits: nothing is carried)
+    carried = jnp.where(bits > 0, words >> ((32 - bits) % 32), 0)
+    moved = (words << bits) | jnp.pad(carried, pad + [(1, 0)])[..., :-1]
+    return jax.lax.dynamic_slice_in_dim(
+        jnp.pad(moved, pad + [(width, 0)]), width - by // 32, width, axis=-1)
+
+
+def _prefix_records(prefix, first, chosen, packed):
+    """The records of the WHOLE prompt buffer behind a snapshot: ``chosen
+    [B, S, L, k]`` and ``packed [B, S, L, words]`` of the suffix's call
+    stand behind the snapshot's K positions, and row ``b``'s
+    ``first[b] ... first[b] + K - 1`` hold the snapshot's own (over a
+    shorter row's first suffix slots too: they are its prefix's end), its
+    selections moved up to the buffer's indices (`_shift_up`)."""
+    K = prefix["choices"].shape[0]
+
+    def behind(rows, record):
+        """``rows [B, S, ...]`` behind K positions, ``record(b) [K, ...]``
+        written at row ``b``'s offset."""
+        rows = jnp.pad(rows, ((0, 0), (K, 0), (0, 0), (0, 0)))
+        for b in range(rows.shape[0]):
+            rows = jax.lax.dynamic_update_slice(
+                rows, record(b)[None], (b, first[b], 0, 0))
+        return rows
+
+    with jax.named_scope("gate"):
+        chosen = behind(chosen, lambda b: prefix["choices"])
+    with jax.named_scope("selection_record"):
+        packed = behind(packed, lambda b: _shift_up(
+            prefix["selected"], first[b], packed.shape[-1]))
+    return chosen, packed
+
+
 # --- the served program ---------------------------------------------------
 
 def generate(cfg: KeyeConfig, max_new_tokens: int, params, prompt_ids,
-             prompt_len, seed, temperature
+             prompt_len, seed, temperature, prefix=None
              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array],
                         Dict[str, jax.Array]]:
     """Prefill, then ``max_new_tokens`` decode steps, for every row:
     `looplm.generate`'s contract (rows, lengths, seeds, temperatures,
-    padding never attended to).  Returns the new ids ``[B, N]``, the
+    padding never attended to).  With a snapshot ``prefix`` of K ids
+    (`make_prefix_program`) ``prompt_ids [B, S]`` holds what FOLLOWS them
+    in every row: each row starts from the snapshot (`from_prefix`) and
+    the blocks run over the ``S`` positions behind it; the buffer of
+    ``P = K + S`` positions is laid out ``padding | prefix | row's own
+    ids``, a row's last id at ``P - 1`` as without one, so the decode
+    steps and every buffer index below are what they are without.
+    Returns the new ids ``[B, N]``, the
     float32 logits each was drawn from ``[B, N, V]``, ``aux`` as
     `mla_moe.generate`'s (``router_scores``, ``expert_choices``,
     ``prompt_choices``) with, where those logits were computed, the keys
@@ -598,12 +750,16 @@ def generate(cfg: KeyeConfig, max_new_tokens: int, params, prompt_ids,
     than ``topk``) and ``keys_attended [B]`` (what attention read),
     summed over the blocks; over the PREFILL the same four with
     ``_prefill`` behind the name, ``expert_rows_computed_prefill`` and
-    ``prefill_positions`` (every position of every row); over both
+    ``prefill_positions`` (every position of every row THE BLOCKS RAN
+    OVER: of a prefix served from a snapshot nothing is computed and
+    nothing counted, while its records stand in ``aux``); over both
     ``expert_pairs_dropped`` (0)."""
-    B, P = prompt_ids.shape
+    B, S = prompt_ids.shape
+    K = 0 if prefix is None else prefix["keys"].shape[1]
+    P = K + S
     prompt_len, seed, temperature = (
         jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
-    first = P - prompt_len
+    first = S - prompt_len
     keys = jax.vmap(jax.random.PRNGKey)(seed)
 
     def draw(key, logits, temperature, i):
@@ -621,12 +777,18 @@ def generate(cfg: KeyeConfig, max_new_tokens: int, params, prompt_ids,
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
             prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
-            index = jnp.arange(P)
-            x, caches, prefill = _stack(
-                cfg, params, _embed(params, prompt_ids),
-                text_positions(index, first), index, first,
-                empty_cache(cfg, B, P + max_new_tokens), decode=False)
-            logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+            index = jnp.arange(K, P)
+            x, positions = _embed(params, prompt_ids), \
+                text_positions(index, first)
+            caches = empty_cache(cfg, B, P + max_new_tokens)
+            if prefix is not None:
+                caches = from_prefix(caches, prefix, first)
+            x, caches, prefill = _stack(cfg, params, x, positions, index,
+                                        first, caches, decode=False, prefix=K)
+            logits = _head(cfg, params, x[:, S - 1:])[:, 0]
+            if prefix is not None:
+                prefill["chosen"], prefill["packed"] = _prefix_records(
+                    prefix, first, prefill["chosen"], prefill["packed"])
 
         def step(carry, i):
             logits, chosen, caches, counts = carry
@@ -665,18 +827,21 @@ def generate(cfg: KeyeConfig, max_new_tokens: int, params, prompt_ids,
              "keys_attended": attended,
              "expert_pairs_local_prefill": prefill_pairs,
              "expert_rows_computed_prefill": prefill_rows,
-             "prefill_positions": jnp.int32(B * P),
+             "prefill_positions": jnp.int32(B * S),
              **{f"keys_{name}_prefill": count for name, count in zip(
                  ("scored", "selected", "attended"), prefill["keys"])}})
 
 
 def make_program(cfg: KeyeConfig, max_new_tokens: int):
     """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
-    device trace) like every language model's."""
+    device trace) like every language model's.  With a sixth argument,
+    `make_prefix_program`'s snapshot, ``prompt_ids`` holds what follows
+    the prefix."""
 
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
+    def lm_generate(params, prompt_ids, prompt_len, seed, temperature,
+                    prefix=None):
         return generate(cfg, max_new_tokens, params, prompt_ids, prompt_len,
-                        seed, temperature)
+                        seed, temperature, prefix)
 
     return jax.jit(lm_generate)
 

@@ -18,6 +18,7 @@ breakage the issue names has to fail it where the served path passes.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import os
 import re
@@ -212,6 +213,250 @@ def test_padding_is_never_selected(params):
         assert (bits[first:].sum(axis=-1) == want[:, None]).all()
         chosen = row["key_selections"]
         assert chosen[chosen >= 0].min() >= first
+
+
+# --- a prefix shared between requests --------------------------------------------
+#
+# Every row's prompt is the same 37 ids (no multiple of 32 or of the chunk
+# of 4 queries: the prefix's record ends inside a word and inside a chunk)
+# and then its own: 40, 39, 9 and 1 ids behind them in a suffix buffer of
+# 40, ten chunks (one row fills it, one is a single id; their offsets in
+# the buffer are 0, 1, 31 and 39: no shift, one bit, a word less a bit, a
+# word and seven bits).  The whole prompt is 77 positions.
+
+PREFIX, SUFFIX = 37, 40
+OWN = [40, 39, 9, 1]
+
+
+def shared_prompts():
+    """Per row the whole prompt: the same 37 ids, then the row's own."""
+    rng = np.random.RandomState(12)
+    head = rng.randint(1, TINY.vocab_size, PREFIX)
+    return [np.concatenate([head, rng.randint(1, TINY.vocab_size, n)]
+                           ).astype(np.int32) for n in OWN]
+
+
+@functools.lru_cache(maxsize=None)
+def shared_programs(cfg):
+    """The maker and the served program, jitted once a configuration."""
+    return dsa_moe.make_prefix_program(cfg), dsa_moe.make_program(cfg, NEW)
+
+
+@pytest.fixture(scope="module")
+def snapshot(params):
+    return shared_programs(TINY)[0](
+        params, jnp.asarray(shared_prompts()[0][:PREFIX]))
+
+
+def buffers(picked, held):
+    """The prompt buffer of the rows ``picked`` with their first ``held``
+    ids left out (a snapshot stands for them), and the lengths."""
+    whole = shared_prompts()
+    ids = np.zeros((len(picked), PREFIX + SUFFIX - held), np.int32)
+    for b, i in enumerate(picked):
+        ids[b, :len(whole[i]) - held] = whole[i][held:]
+    return ids, np.asarray([len(whole[i]) - held for i in picked], np.int32)
+
+
+def serve_shared(cfg, params, snapshot, picked=(0, 1, 2, 3), temperature=0.0):
+    """One execution over the rows ``picked`` of `shared_prompts`: from
+    the ``snapshot`` of the 37 ids, or with None the whole prompts through
+    the five-argument program."""
+    ids, lens = buffers(picked, 0 if snapshot is None else PREFIX)
+    tokens, logits, aux, stats = shared_programs(cfg)[1](
+        params, ids, lens, np.asarray(picked, np.uint32) + 3,
+        np.asarray([temperature] * len(picked), np.float32),
+        *(() if snapshot is None else (snapshot,)))
+    whole = shared_prompts()
+    return [{"prompt_ids": whole[i], "tokens": np.asarray(tokens[b]),
+             "logits": np.asarray(logits[b]),
+             **{k: np.asarray(v[b]) for k, v in aux.items()}}
+            for b, i in enumerate(picked)], jax.tree_util.tree_map(
+                np.asarray, stats)
+
+
+@pytest.mark.parametrize("picked", [(0,), (3,), (0, 1, 2, 3), (2, 1, 1, 1)])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_rows_started_from_a_snapshot_are_the_full_paths_and_the_references(
+        picked, temperature, params, snapshot):
+    """Alone or four of unequal length (a row that fills the suffix
+    buffer, a single id, a padded execution whose last rows repeat one),
+    greedy or sampled with seeds: the ids of the program over the whole
+    prompt, its logits to float32's rounding, and over each row's REAL
+    positions the same record of the experts chosen and of the keys every
+    query selected, by buffer index; and the reference forced to that
+    record agrees (verify_lm_dsa_moe's comparison, which reads all of
+    it)."""
+    served, stats = serve_shared(TINY, params, snapshot, picked, temperature)
+    full, full_stats = serve_shared(TINY, params, None, picked, temperature)
+    P = PREFIX + SUFFIX
+    for got, want, i in zip(served, full, picked):
+        first = SUFFIX - OWN[i]
+        assert np.array_equal(got["tokens"], want["tokens"])
+        np.testing.assert_allclose(got["logits"], want["logits"], rtol=0,
+                                   atol=2e-5)
+        assert got["prompt_choices"].shape == (P, 3, 2)
+        assert got["prompt_selected"].shape == (P, 3, 3)
+        for name in ("prompt_choices", "prompt_selected"):
+            assert np.array_equal(got[name][first:], want[name][first:]), name
+        for name in ("key_selections", "expert_choices"):
+            assert np.array_equal(got[name], want[name]), name
+        np.testing.assert_allclose(got["router_scores"],
+                                   want["router_scores"], rtol=0, atol=1e-6)
+        reading = compare(TINY, params, got, free=False,
+                          sampled=temperature > 0)
+        assert reading["correct"] and reading["selection_agree"] == 1.0, \
+            reading
+    # what the program COMPUTED: the 40 positions behind the prefix, whose
+    # queries (a row's own) score the prefix's index keys too and attend to
+    # 8 keys each; the decode steps read what they read without a snapshot
+    B = len(picked)
+    assert (stats["prefill_positions"], full_stats["prefill_positions"]) \
+        == (B * SUFFIX, B * P)
+    for b, i in enumerate(picked):
+        n = OWN[i]
+        assert stats["keys_scored_prefill"][b] == 3 * sum(
+            PREFIX + j + 1 for j in range(n))
+        assert stats["keys_selected_prefill"][b] \
+            == stats["keys_attended_prefill"][b] == 3 * 8 * n
+        assert stats["expert_pairs_local_prefill"][b] == SUFFIX * 3 * 2
+        assert full_stats["expert_pairs_local_prefill"][b] == P * 3 * 2
+        assert full_stats["keys_attended_prefill"][b] == 3 * sum(
+            min(t + 1, 8) for t in range(PREFIX + n))
+    for name in ("keys_scored", "keys_selected", "keys_attended",
+                 "expert_pairs_local", "expert_hits",
+                 "expert_pairs_dropped"):
+        assert np.array_equal(stats[name], full_stats[name]), name
+
+
+def test_a_row_behind_a_snapshot_is_its_single_row_run(params, snapshot):
+    """Four rows of unequal length from one snapshot give, each, what
+    they give alone through the 1-row program (whose suffix buffer they do
+    not fill either: the padding lies in front of prefix and suffix
+    both)."""
+    served, _ = serve_shared(TINY, params, snapshot)
+    for b in range(4):
+        (alone,), _ = serve_shared(TINY, params, snapshot, (b,))
+        assert np.array_equal(alone["tokens"], served[b]["tokens"]), b
+        np.testing.assert_allclose(served[b]["logits"], alone["logits"],
+                                   rtol=0, atol=2e-5)
+        for name in ("key_selections", "prompt_selected", "prompt_choices"):
+            assert np.array_equal(alone[name], served[b][name]), (b, name)
+
+
+def _prefilled(cfg, params, snapshot=None):
+    """The three caches behind the prefill of `shared_prompts`' four rows
+    and ``first``: the whole prompts from empty caches, or their suffixes
+    behind ``snapshot``."""
+    K = 0 if snapshot is None else PREFIX
+    ids, lens = buffers((0, 1, 2, 3), K)
+
+    def run(params, ids, lens, snapshot):
+        S = ids.shape[1]
+        first = S - lens
+        index = jnp.arange(K, K + S)
+        caches = dsa_moe.empty_cache(cfg, 4, K + S + NEW)
+        if snapshot is not None:
+            caches = dsa_moe.from_prefix(caches, snapshot, first)
+        _, caches, _ = dsa_moe._stack(
+            cfg, params, dsa_moe._embed(params, jax.vmap(jnp.roll)(ids, first)),
+            dsa_moe.text_positions(index, first), index, first, caches,
+            decode=False, prefix=K)
+        return caches, first
+
+    return jax.jit(run)(params, ids, lens, snapshot)
+
+
+def test_the_snapshot_is_what_the_full_prefill_leaves_behind_the_prefix(
+        params, snapshot):
+    """At the caches' widths, one row and no axis of rows: 37 keys, values
+    and index keys a block, the ones the full prefill of a longer prompt
+    writes at those positions of each row, with the record of what the 37
+    positions chose; behind the suffix's prefill all three caches are the
+    full prefill's over every real position, each row's prefix at its own
+    offset and nothing in front of it."""
+    words = -(-PREFIX // 32)
+    assert {k: (v.shape, v.dtype) for k, v in snapshot.items()} == {
+        "keys": ((3, PREFIX, 2, 16), TINY.dtype),
+        "values": ((3, PREFIX, 2, 16), TINY.dtype),
+        "index_keys": ((3, PREFIX, 8), TINY.dtype),
+        "choices": ((PREFIX, 3, 2), jnp.int32),
+        "selected": ((PREFIX, 3, words), jnp.uint32)}
+    assert sum(v.nbytes for v in snapshot.values()) \
+        == dsa_moe.prefix_bytes(TINY, PREFIX) \
+        == dsa_moe.kv_cache_bytes(TINY, 1, PREFIX) \
+        + PREFIX * 3 * 4 * (2 + words)
+    whole, first = _prefilled(TINY, params)
+    behind, same = _prefilled(TINY, params, snapshot)
+    assert list(first) == list(same) == [SUFFIX - n for n in OWN]
+    for name, full, got in zip(dsa_moe.PREFIX_CACHES, whole, behind):
+        for b, at in enumerate(first):
+            np.testing.assert_allclose(
+                full[:, b, at:at + PREFIX], snapshot[name], rtol=0, atol=1e-5)
+            np.testing.assert_array_equal(got[:, b, at:at + PREFIX],
+                                          snapshot[name])
+            np.testing.assert_allclose(
+                got[:, b, at:PREFIX + SUFFIX], full[:, b, at:PREFIX + SUFFIX],
+                rtol=0, atol=1e-5)
+            assert float(jnp.abs(got[:, b, :at]).max(initial=0)) == 0
+    # the published size: 106 MB of caches and 51 MB of records behind the
+    # cell's 8,101 ids
+    full = dsa_moe.KEYE_VL2_STAGE
+    assert dsa_moe.kv_cache_bytes(full, 1, 8101) == 13_056 * 8101
+    assert dsa_moe.prefix_bytes(full, 8101) - 13_056 * 8101 \
+        == 8101 * 6 * 4 * (8 + 254) == 50_939_088
+
+
+@pytest.mark.parametrize("positions", [37, 64, 5])
+@pytest.mark.parametrize("by", [0, 1, 31, 32, 33, 39, 95])
+def test_the_packed_record_moves_up_by_a_rows_offset(by, positions):
+    """A funnel shift over uint32 words: bit ``s`` of a snapshot's record
+    (a key by its position in the row) is bit ``s + first`` of the buffer's
+    (``first % 32`` is rarely 0, and 37 keys end inside a word); no bit is
+    lost within the buffer's words and none appears."""
+    rng = np.random.RandomState(by)
+    mask = rng.rand(4, 3, positions) < 0.5
+    mask[0, 0, :] = True
+    width = -(-(positions + 96) // 32)
+    packed = dsa_moe._pack(jnp.asarray(mask), -(-positions // 32))
+    moved = np.asarray(jax.jit(dsa_moe._shift_up, static_argnums=2)(
+        packed, jnp.int32(by), width))
+    assert moved.shape == (4, 3, width) and moved.dtype == np.uint32
+    bits = np.unpackbits(moved.view(np.uint8), axis=-1, bitorder="little")
+    want = np.zeros((4, 3, 32 * width), bool)
+    want[..., by:by + positions] = mask
+    np.testing.assert_array_equal(bits.astype(bool), want)
+
+
+def test_the_maker_is_not_the_served_program_and_the_phases_stay(
+        params, snapshot):
+    """The maker is ``lm_prefix_state``: the cells' pattern for the served
+    program (``^jit_lm_generate$``) does not match it, so its seconds are
+    no execution's.  The program that starts from a snapshot is still
+    ``lm_generate``, with every class and both phases; the rows' start
+    from the snapshot is the caches' and the records' assembly is the
+    index's and the router's."""
+    maker = dsa_moe.make_prefix_program(TINY).lower(
+        params, jnp.zeros((PREFIX,), jnp.int32))
+    assert "jit_lm_prefix_state" in maker.as_text()[:200]
+    assert not re.match("^jit_lm_generate$", "jit_lm_prefix_state")
+    ids, lens = buffers((0, 1, 2, 3), PREFIX)
+    lowered = dsa_moe.make_program(TINY, 3).lower(
+        params, jnp.asarray(ids), lens, np.zeros(4, np.uint32),
+        np.zeros(4, np.float32), snapshot)
+    assert "jit_lm_generate" in lowered.as_text()[:200]
+    names = [n for n in re.findall(r'op_name="([^"]+)"',
+                                   lowered.compile().as_text())
+             if "KeyeVL2" in n]
+    assert {trace.classify(n) for n in names} == {
+        "lm_proj", "lm_attn", "lm_cache", "lm_index", "lm_experts",
+        "lm_mlp", "lm_norm", "lm_head", "embed"}
+    assert {trace.phase_of(n) for n in names} == {"prefill", "decode"}
+    copies = [n for n in names if re.search(
+        r"prefill/(kv_cache|selection_record|gate)/", n)]
+    assert {(trace.classify(n), trace.phase_of(n)) for n in copies} == {
+        ("lm_cache", "prefill"), ("lm_index", "prefill"),
+        ("lm_experts", "prefill")}
 
 
 # --- the rotation over position triples ------------------------------------------
@@ -662,7 +907,9 @@ def test_the_registry_serves_it_and_counts_what_a_step_reads(
     counters of PR 28-32 keep their meaning, what the index scored and
     what attention read come over in the same read (8 keys a row a block
     a step, not the cache's length), the gauge says both caches; a second
-    execution of the shape compiles nothing."""
+    execution of the shape compiles nothing.  The rows carry the same
+    instructions (7 ids with the first), so each starts from their
+    snapshot and the program computes the 25 positions behind it."""
     assert model.family == "keye" and model.cfg == TINY
     assert model.row_counts == (1, 4)
     rows = [registry.LMRow(f"a lighthouse at dawn number {i}", i,
@@ -681,10 +928,16 @@ def test_the_registry_serves_it_and_counts_what_a_step_reads(
     assert got["lm.expert_pairs"] == 3 * 5 * 3 * 2         # rows x steps x L x k
     # every expert is held: every pair is local, none dropped
     assert got["lm.expert_pairs_local"] == got["lm.expert_pairs"]
-    assert got["lm.expert_pairs_local_prefill"] == 4 * 32 * 3 * 2
+    assert got["lm.expert_pairs_local_prefill"] == 4 * (32 - 7) * 3 * 2
     assert got["lm.expert_pairs_dropped"] == 0
     assert 0 < got["lm.expert_hits"] <= 5 * 3 * 8
-    assert got["lm.prefill_positions"] == 4 * 32
+    # every row of the program, the padded one too
+    assert got["lm.prefill_positions"] == 4 * (32 - 7)
+    # the three real rows, from the snapshot the first request made
+    assert got["lm.prefix_hits"] == 3
+    assert got["lm.prefix_positions_served"] == 3 * 7
+    assert got.get("lm.prefix_misses", 0) == 0 and before[
+        "lm.prefix_misses"] >= 1
     real = got["lm.prompt_tokens"]                          # of three rows
     assert real > 3 * 8
     # a step a row a block: every visible index key scored, 8 attended to
@@ -694,9 +947,9 @@ def test_the_registry_serves_it_and_counts_what_a_step_reads(
         == 3 * 5 * 3 * 8
     assert got["lm.keys_attended_prefill"] > got["lm.keys_selected_prefill"] \
         > 0 < got["lm.keys_scored_prefill"]
-    assert not any(k in got for k in ("lm.state_steps",
-                                      "lm.keys_attended_window",
-                                      "lm.keys_attended_full"))
+    assert not any(got.get(k) for k in ("lm.state_steps",
+                                        "lm.keys_attended_window",
+                                        "lm.keys_attended_full"))
     gauges = trace.GLOBAL_GAUGES.snapshot()
     assert gauges["lm.kv_cache_bytes"] == \
         dsa_moe.kv_cache_bytes(TINY, 4, 37) == \
@@ -708,7 +961,76 @@ def test_the_registry_serves_it_and_counts_what_a_step_reads(
     assert lm_out.row == 2 and set(lm_out.aux) == {
         "router_scores", "expert_choices", "prompt_choices",
         "key_selections", "prompt_selected"}
+    # the records cover the WHOLE prompt buffer, the snapshot's positions too
+    assert lm_out.aux["prompt_choices"].shape == (4, 32, 3, 2)
+    assert lm_out.aux["prompt_selected"].shape == (4, 32, 3, 1)
     assert len(words.split()) <= 5
+
+
+def lm_delta(before):
+    after = counters()
+    return {k[3:]: after[k] - before.get(k, 0) for k in after
+            if k.startswith("lm.") and after[k] != before.get(k, 0)}
+
+
+def asked(model, rows, **kw):
+    before = counters()
+    out = model.generate_rows(rows, max_new_tokens=3, prompt_tokens=32, **kw)
+    return [words for words, _ in out], lm_delta(before)
+
+
+GUIDE = "style guide number 0 of many"          # 7 ids with the first
+
+
+def test_the_rule_finds_this_familys_prefix_and_its_snapshot_is_made_once(
+        model, monkeypatch):
+    """`shared_prefix` finds the instructions' ids for this family (its
+    rotation counts from a row's first real id: the snapshot stands at
+    any offset); two executions make the snapshot once and count a hit a
+    row; the words are those of the whole prompt scanned (the rule held
+    off) and of each row alone."""
+    model._prefixes.clear()
+    rows = [registry.LMRow(f"a walled garden in june number {i}", i, 0.7 * i,
+                           instructions=GUIDE) for i in range(3)]
+    found = model.shared_prefix(rows, 32)
+    assert list(found) == model.tokenizer.encode(GUIDE) and len(found) == 7
+    words, got = asked(model, rows)
+    assert (got["prefix_misses"], got["prefix_hits"],
+            got["prefix_positions_served"]) == (1, 3, 3 * 7)
+    assert got["prefill_positions"] == 4 * 25
+    assert trace.GLOBAL_GAUGES.snapshot()["lm.prefix_bytes"] \
+        == dsa_moe.prefix_bytes(TINY, 7)
+    again, got = asked(model, rows[:2])
+    assert again == words[:2] and "prefix_misses" not in got
+    assert got["prefix_hits"] == 2 and got["prefill_positions"] == 4 * 25
+    for i, row in enumerate(rows):
+        alone, got = asked(model, [row])
+        assert alone == [words[i]] and got["prefill_positions"] == 25
+    monkeypatch.setattr(registry.LanguageModel, "shared_prefix",
+                        lambda self, *a: None)
+    whole, got = asked(model, rows)
+    assert whole == words and len(set(words)) == 3
+    assert "prefix_hits" not in got and got["prefill_positions"] == 4 * 32
+
+
+@pytest.mark.parametrize("what, rows", [
+    ("no instructions", [("a cat", "")] * 2),
+    ("instructions that differ between the rows",
+     [("a cat", GUIDE), ("a dog", GUIDE.replace("0", "1"))]),
+    ("one row without", [("a cat", GUIDE), ("a dog", "")]),
+])
+def test_rows_that_share_no_instructions_run_the_whole_prompt(what, rows,
+                                                              model):
+    """The rule reads its input: everything but the same non-empty
+    instructions in every row is the execution it was, every position
+    computed, no snapshot made, none counted."""
+    rows = [registry.LMRow(text, i, instructions=instructions)
+            for i, (text, instructions) in enumerate(rows)]
+    assert model.shared_prefix(rows, 32) is None
+    _, got = asked(model, rows)
+    assert got["prefill_positions"] == 4 * 32
+    assert got["expert_pairs_local_prefill"] == 4 * 32 * 3 * 2
+    assert not [k for k in got if k.startswith("prefix_")]
 
 
 @pytest.mark.parametrize("name, want", [

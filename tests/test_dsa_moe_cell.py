@@ -528,13 +528,80 @@ def test_the_cell_rehearses_on_the_cpu(tmp_path):
     counters = run["window_counters"]
     assert counters["lm.executions"] >= 2
     rows = counters["lm.rows"] + counters["lm.padded_rows"]
-    # every row's 48 prompt positions are computed (no snapshot for a
-    # family whose keys are rotated); 4 new tokens, 3 blocks, topk 8
-    assert counters["lm.prefill_positions"] == rows * 48
-    assert counters["lm.expert_pairs_local_prefill"] == rows * 48 * 3 * 2
+    # every row starts from the snapshot of the rehearsal's instructions
+    # (14 ids with the first; this family's rotation counts from a row's
+    # first real id, so the snapshot stands at each row's offset) and the
+    # 34 positions behind them are computed; 4 new tokens, 3 blocks, topk 8
+    held = 14
+    assert counters["lm.prefix_hits"] == counters["lm.rows"]
+    assert counters["lm.prefix_positions_served"] \
+        == counters["lm.rows"] * held
+    assert "lm.prefix_misses" not in counters      # made by the warm-ups
+    assert counters["lm.prefill_positions"] == rows * (48 - held)
+    assert counters["lm.expert_pairs_local_prefill"] \
+        == rows * (48 - held) * 3 * 2
     assert counters["lm.expert_pairs_dropped"] == 0
     assert counters["lm.keys_attended"] == counters["lm.keys_selected"] \
         == counters["lm.rows"] * 4 * 3 * 8
     assert counters["lm.keys_scored_decode"] > 4 * counters["lm.keys_attended"]
-    assert "lm.prefix_hits" not in counters
+    assert counters["lm.keys_scored_prefill"] \
+        > counters["lm.keys_attended_prefill"] > 0
     assert "lm.state_steps" not in counters
+
+
+def test_the_verify_script_rehearses_behind_a_snapshot(tmp_path):
+    """``verify_lm_dsa_moe.py --rehearse`` (the benchmark's, unedited): its
+    served requests carry the rehearsal's instructions, so each starts
+    from their snapshot, and the reference is forced to the program's
+    record of the WHOLE prompt, the snapshot's 14 positions too: one
+    request alone and four as the rows of one execution inside every
+    limit, every reading that has to fail outside one."""
+    env = {k: v for k, v in os.environ.items() if not k.startswith("DTPU_")}
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "verify_lm_dsa_moe.py"),
+         "--rehearse", "--out", str(tmp_path)],
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=900)
+    assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    assert got["ok"] is True and len(got["served"]) == 5
+    for row in got["served"]:
+        assert row["correct"] and row["selection_agree"] == 1.0
+        assert 14 < row["prompt_ids"] <= 48
+    assert got["together"]["executions"] == 1 \
+        and got["together"]["rows"] == 4
+    for reading in ("weights_8bit", "cache_8bit", "no_selection",
+                    "last_topk", "no_relu", "no_head_weights", "top7_of_8",
+                    "no_renormalisation"):
+        assert got[reading]["correct"] is False, reading
+
+
+def test_the_cells_instructions_are_a_true_prefix_of_every_request():
+    """What `LanguageModel.shared_prefix` needs of the CELL's traffic, at
+    the published vocabulary: the operator's instructions encode to 8,101
+    ids (the start id and 8,100 words) that are the first ids of every
+    request's prompt, with 1 to 91 ids of the row's own behind them inside
+    the 8192 positions.  A silent fall-back to the whole prompt would
+    fail here, not only on the chip."""
+    import numpy as np
+    from comfyui_distributed_tpu.models import dsa_moe, registry, tokenizer
+    node = config()["graph"]["21"]["inputs"]
+    model = registry.LanguageModel(
+        "keye-vl-2.0-30b-a3b.safetensors", dsa_moe.KEYE_VL2_STAGE, None,
+        tokenizer.make_lm_tokenizer(None, 151936), "keye")
+    with open(os.path.join(BENCH, "traffic", "words.txt")) as f:
+        words = [w.strip() for w in f if w.strip()]
+    rng = random.Random(7)
+    rows = [registry.LMRow(" ".join(rng.choice(words) for _ in range(12)),
+                           i, instructions=node["instructions"])
+            for i in range(4)]
+    prefix = model.shared_prefix(rows, node["prompt_tokens"])
+    assert prefix is not None and len(prefix) == 8101
+    for row in rows:
+        ids = model.prompt_ids(row.text, node["prompt_tokens"],
+                               row.instructions)
+        assert np.array_equal(ids[:8101], prefix)
+        assert 1 <= len(ids) - 8101 <= node["prompt_tokens"] - 8101 == 91
+    # one row with other instructions, and the execution runs whole
+    other = [*rows[:3], registry.LMRow("a cat", 3, instructions="draw it")]
+    assert model.shared_prefix(other, node["prompt_tokens"]) is None
+    assert dsa_moe.prefix_bytes(model.cfg, 8101) == 156_705_744
