@@ -16,7 +16,9 @@ nothing is downloaded.  After the server has exited, a second child
 compiles the Pallas flash-attention kernel (``interpret=False``) at the
 shapes the UNets' attention rule sends it and holds its error against an
 fp32 oracle to ``xla_attention``'s, then the fused GEGLU kernel at the
-UNets' five feed-forward shapes against the float32 expression.
+UNets' five feed-forward shapes against the float32 expression, then the
+grouped few-row product at the routed experts' shapes against ``jnp.dot``
+(and prints the rate at which it streams their weights).
 
 This process never imports JAX: a chip belongs to one process at a time,
 and a parent that touched JAX would hold it.  It talks to the server
@@ -125,6 +127,30 @@ GEGLU_SHAPES_REHEARSAL = ((2, 64, 128),)
 # it is held beside the kernel and may do no better: the limit is not one
 # the path it replaces would have met with more to spare.
 GEGLU_ERR_LIMIT = 2.0 ** -8
+
+# The grouped few-row product (``ops/pallas/fewrow_dense.py``
+# `fewrow_grouped`) at the routed experts' published shapes: (name,
+# experts in a block, K, N, leaves of one call, slots, live slots), the
+# live slots a decode step's mean (Keye: 21 of 128 hit a block; the two
+# 16-expert shares 1-2) and every slot
+GROUPED_SHAPES = (
+    ("keye gate_proj+up_proj", 128, 2048, 768, 2, 32, 21),
+    ("keye down_proj", 128, 768, 2048, 1, 32, 21),
+    ("keye gate_proj+up_proj, every slot", 128, 2048, 768, 2, 32, 32),
+    ("pangu gate_proj+up_proj", 16, 7680, 2048, 2, 16, 2),
+    ("pangu down_proj", 16, 2048, 7680, 1, 16, 2),
+    ("pangu down_proj, every slot", 16, 2048, 7680, 1, 16, 16),
+)
+GROUPED_SHAPES_REHEARSAL = (("tiny gate_proj+up_proj", 4, 256, 128, 2, 4, 3),
+                            ("tiny down_proj", 4, 128, 256, 1, 4, 0))
+# Against ``jnp.dot`` of the same bf16 operands in float32 at the highest
+# precision: max |diff| over max |ref|.  Nothing is rounded to bf16 on the
+# way (bf16 x bf16 is exact in float32, the sums and the result are
+# float32), so only the ORDER of the float32 sums over K differs: a few
+# float32 ulps times sqrt(K).  2^-14 is a thousand float32 ulps and a
+# sixty-fourth of one bf16 ulp: a result that passed through bf16, or a
+# slot that read another expert, cannot meet it.
+GROUPED_ERR_LIMIT = 2.0 ** -14
 
 
 class SmokeFailure(Exception):
@@ -440,9 +466,10 @@ def server_phases(phases, cfg: dict, out_dir: str, env: dict,
 def kernel_child(rehearse: bool) -> int:
     """Runs in its own process (it owns the chip while it lives): compile
     the Pallas kernel at each shape and compare it and ``xla_attention``
-    with an fp32 oracle, then the GEGLU kernel (`geglu_shapes`).  Prints
-    one JSON line; any refusal, or an error over KERNEL_ERR_RATIO x
-    ``xla_attention``'s or over GEGLU_ERR_LIMIT, raises."""
+    with an fp32 oracle, then the GEGLU kernel (`geglu_shapes`) and the
+    grouped few-row product (`grouped_shapes`).  Prints one JSON line;
+    any refusal, or an error over KERNEL_ERR_RATIO x ``xla_attention``'s,
+    over GEGLU_ERR_LIMIT or over GROUPED_ERR_LIMIT, raises."""
     import math
 
     import jax
@@ -503,13 +530,15 @@ def kernel_child(rehearse: bool) -> int:
                      "rel_err": round(err, 6),
                      "rel_err_xla": round(err_xla, 6)})
     geglu_rows = geglu_shapes(rehearse, platform, failures)
+    grouped_rows = grouped_shapes(rehearse, failures)
     if failures:
         raise SystemExit("kernel phase failed:\n" + "\n".join(failures))
     print(json.dumps({"device": {"platform": platform,
                                  "kind": devices[0].device_kind,
                                  "count": len(devices)},
                       "interpret": rehearse, "shapes": rows,
-                      "geglu_shapes": geglu_rows}), flush=True)
+                      "geglu_shapes": geglu_rows,
+                      "grouped_shapes": grouped_rows}), flush=True)
     return 0
 
 
@@ -564,6 +593,93 @@ def geglu_shapes(rehearse: bool, platform: str, failures: list) -> list:
     return rows
 
 
+def grouped_shapes(rehearse: bool, failures: list) -> list:
+    """The grouped few-row product against ``jnp.dot`` on
+    ``leaf[l, ids[s]]`` for every live slot, inside GROUPED_ERR_LIMIT, at
+    the routed experts' shapes and 4 rows; and the rate at which it
+    streams the live slots' weights (16 calls in one program, each over
+    other experts: the bytes of the experts read over the seconds of a
+    call)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.ops.pallas.fewrow_dense import \
+        fewrow_grouped
+
+    rows, layers, calls = [], 2, 16
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    for name, held, k, n, count, slots, live in \
+            GROUPED_SHAPES_REHEARSAL if rehearse else GROUPED_SHAPES:
+        rng = np.random.default_rng(k * 31 + n + live)
+        keys = jax.random.split(jax.random.PRNGKey(k + n), count)
+        leaves = [(jax.random.normal(key, (layers, held, k, n), jnp.float32)
+                   / np.sqrt(k)).astype(dtype) for key in keys]
+        shared = count == 2            # gate / up meet the call's rows
+        x = jnp.asarray(rng.standard_normal(
+            (4, k) if shared else (slots, 4, k)), dtype)
+        # ascending distinct experts a call, as `_routed` hands them over
+        ids = jnp.asarray(np.stack([
+            np.sort(rng.permutation(held)[:slots]) for _ in range(calls)]),
+            jnp.int32)
+
+        def call(x, leaves, l, ids):
+            return fewrow_grouped(x, leaves, l, ids, live,
+                                  interpret=rehearse)
+
+        try:
+            out = jax.jit(call)(x, leaves, 1, ids[0])
+            worst = 0.0
+            for o, w in zip(out, leaves):
+                for s in range(live):
+                    ref = np.asarray(jnp.dot(
+                        (x if shared else x[s]).astype(jnp.float32),
+                        w[1, ids[0, s]].astype(jnp.float32),
+                        precision="highest"))
+                    worst = max(worst, float(
+                        np.max(np.abs(np.asarray(o[s]) - ref))
+                        / np.max(np.abs(ref))))
+
+            @jax.jit
+            def many(x, leaves, ids):
+                def one(total, own):
+                    out = call(x, leaves, own[0] % layers, own)
+                    return total + sum(
+                        jnp.sum(o[:live]) for o in out), None
+                return jax.lax.scan(one, jnp.float32(0), ids)[0]
+
+            seconds = None         # a rate is the chip's to give
+            if not rehearse:
+                many(x, leaves, ids).block_until_ready()
+                t0 = time.perf_counter()
+                many(x, leaves, ids).block_until_ready()
+                seconds = (time.perf_counter() - t0) / calls
+        except Exception as e:  # noqa: BLE001 - report every shape, then fail
+            failures.append(f"fewrow_grouped {name}: the compiler refused "
+                            f"it: {type(e).__name__}: {str(e)[:1500]}")
+            continue
+        if not worst <= GROUPED_ERR_LIMIT:
+            failures.append(
+                f"fewrow_grouped {name}: rel err {worst:.3g} against "
+                f"jnp.dot at the highest precision (limit "
+                f"{GROUPED_ERR_LIMIT:.3g})")
+        moved = live * count * k * n * jnp.dtype(dtype).itemsize
+        row = {"name": name, "leaf": [layers, held, k, n], "leaves": count,
+               "slots": slots, "live": live,
+               "rel_err": float(f"{worst:.3g}")}
+        if seconds:
+            row.update(us_a_call=round(seconds * 1e6, 1),
+                       gb_per_s=round(moved / seconds / 1e9, 1))
+        rows.append(row)
+        say(f"fewrow_grouped {name}: {live} of {slots} slots, rel err "
+            f"{worst:.3g}" + (
+                f", {moved / 1e6:.1f} MB in {seconds * 1e6:.1f} us = "
+                f"{moved / seconds / 1e9:.1f} GB/s" if seconds else ""))
+    return rows
+
+
 def child_report(cfg: dict, out_dir: str, env: dict, what: str, cmd: list,
                  timeouts: float = 1.0) -> tuple:
     """A child that owns the device while it lives and prints its report
@@ -595,10 +711,15 @@ def kernel_phase(cfg: dict, out_dir: str, env: dict, result: dict) -> None:
     result["smoke_facts"]["pallas_geglu"] = {
         "interpret": report["interpret"], "limit": GEGLU_ERR_LIMIT,
         "shapes": report["geglu_shapes"]}
+    result["smoke_facts"]["pallas_fewrow_grouped"] = {
+        "interpret": report["interpret"], "limit": GROUPED_ERR_LIMIT,
+        "shapes": report["grouped_shapes"]}
     say(f"kernels: {len(report['shapes'])} shape(s) within "
         f"{KERNEL_ERR_RATIO} x xla_attention's error against fp32; "
         f"{len(report['geglu_shapes'])} GEGLU shape(s) within "
-        f"{GEGLU_ERR_LIMIT} of fp32")
+        f"{GEGLU_ERR_LIMIT} of fp32; {len(report['grouped_shapes'])} "
+        f"grouped few-row shape(s) within {GROUPED_ERR_LIMIT:.3g} of "
+        f"jnp.dot")
 
 
 # --- the language model against its reference --------------------------------

@@ -85,7 +85,9 @@ from comfyui_distributed_tpu.models import looplm
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, xla_attention
 from comfyui_distributed_tpu.models.looplm import Stacked, _dense, \
-    _rms_norm, _sandwich, dense_each, few_rows_here, matrix, scan_layers
+    _rms_norm, _sandwich, dense_each, dense_path, few_rows_here, matrix, \
+    scan_layers
+from comfyui_distributed_tpu.ops.pallas.fewrow_dense import fewrow_grouped
 from comfyui_distributed_tpu.ops.pallas.row_scatter_add import LANES, \
     row_scatter_add
 from comfyui_distributed_tpu.parallel import sharding as shd
@@ -485,6 +487,32 @@ def tile_sum_path(platform: str, d: int,
     return "xla"
 
 
+def routed_path(platform: str, t: int, d: int, width: int, held: int,
+                k: int, itemsize: int = 2,
+                mesh_axes: Optional[dict] = None) -> str:
+    """How the hit experts of a call of ``t`` tokens (one tile of them)
+    are walked: ``grouped`` (`fewrow_grouped`: the hit experts, ascending,
+    through ONE weight stream) or ``loop`` (a conditional an expert held,
+    three ``jnp.dot`` inside a hit one).  A function of what is visible
+    at trace time: a call whose experts' matrices ``[d, width]`` and
+    ``[width, d]`` `looplm.dense_path` would stream through the few-row
+    kernel (a TPU, 2 to 8 rows, no multi-device mesh live, blocks that
+    divide, worth a launch), where the ``held`` experts outnumber the
+    ``t x k`` pairs the call can route.  Only there does the loop walk
+    conditionals that no grouped slot stands for (Keye's 128 for at most
+    32 hits: 18 us a hit where its bytes are 11.5).  With 16 held experts
+    the loop's conditionals cost ~14 us a block and the grouped path's two
+    launches, its slots behind the hits and its combine 40-57: even at the
+    1.3-2.2 experts a block the two accepted shares hit (PERF.md section
+    6, PR 44), so they keep the loop, as do the one-row program, every
+    prefill and every other backend."""
+    if held > t * k and all(
+            dense_path(platform, t, a, b, itemsize, mesh_axes) == "fewrow"
+            for a, b in ((d, width), (width, d))):
+        return "grouped"
+    return "loop"
+
+
 def _expert(experts, l, e):
     """Expert ``e`` of expert block ``l`` out of the leaves
     ``[L, E_here, in, out]``."""
@@ -500,6 +528,11 @@ def _routed(cfg: MlaMoeConfig, experts, l, x, chosen, weights):
     nobody chose is not read.  ``experts`` holds every expert block's
     leaves ``[L, E_here, in, out]`` and is indexed in place by ``(l, e)``:
     a slice handed to the conditional would be a copy of the weights.
+
+    A few-row call on a TPU (`routed_path`: the shared decode step) runs
+    its hit experts, ascending, through `_grouped`: the same operands,
+    the same float32 sum in the same order, one weight stream.  Every
+    other call walks the experts held, a conditional each:
 
     An expert multiplies TILES of rows.  Where the call has no more
     tokens than two of `EXPERT_TILE` (a decode step, a short prefill) the
@@ -547,6 +580,12 @@ def _routed(cfg: MlaMoeConfig, experts, l, x, chosen, weights):
             start = jnp.cumsum(count) - count
             x = x.astype(cfg.dtype)
 
+    if tile == t and routed_path(
+            platform, t, d, experts["gate_proj"].shape[-1], held, k,
+            jnp.dtype(cfg.dtype).itemsize, mesh_axes) == "grouped":
+        looplm._count_sites("fewrow_grouped", t, len(experts))
+        y, hits, done = _grouped(cfg, experts, l, x, count, combine)
+        return y, pairs, hits, jnp.sum(count) - done, hits * t
     if tile == t:
         def tiles_of(e, own, y):
             """Expert ``e``'s one tile, the call's tokens, added to ``y``."""
@@ -593,6 +632,49 @@ def _routed(cfg: MlaMoeConfig, experts, l, x, chosen, weights):
                            jnp.int32(0), jnp.int32(0)))
     return y.reshape(t, d), pairs, jnp.sum(count > 0, dtype=jnp.int32), \
         jnp.sum(count) - done, rows
+
+
+def _grouped(cfg: MlaMoeConfig, experts, l, x, count, combine):
+    """`_routed`'s sum for a few rows ``x [t, d]`` by the grouped kernel:
+    ``min(experts_held, t x num_experts_per_tok)`` static slots (no more
+    experts can be hit), the hit experts in ascending order in the first
+    of them; `gate_proj` and `up_proj` of every slot in one call,
+    `down_proj` in a second over the slots' ``silu(g) * u`` in the
+    model's dtype, each slot's result times its expert's column of
+    ``combine [t, E_here]`` added up in float32 in that order, a dead
+    slot's (never written) left out.  A call that hits nothing launches
+    nothing.  -> the sum, the experts hit, the pairs they computed."""
+    t, d = x.shape
+    held = cfg.experts_held
+    slots = min(held, t * cfg.num_experts_per_tok)
+    with jax.named_scope("dispatch"):
+        hit = count > 0
+        hits = jnp.sum(hit, dtype=jnp.int32)
+        # [slots, E_here]: slot s takes the s-th hit expert
+        taken = hit & (jnp.cumsum(hit) - 1
+                       == jnp.arange(slots, dtype=jnp.int32)[:, None])
+        ids = jnp.sum(jnp.where(taken, jnp.arange(held, dtype=jnp.int32),
+                                0), axis=1)
+        weight = jnp.sum(jnp.where(taken[:, None], combine[None], 0.0),
+                         axis=-1)
+        done = jnp.sum(jnp.where(taken, count, 0))
+
+    def run():
+        g, u = fewrow_grouped(
+            x.astype(cfg.dtype), [experts["gate_proj"], experts["up_proj"]],
+            l, ids, hits, name="fewrow_grouped_gate_proj_up_proj")
+        (o,) = fewrow_grouped(
+            (jax.nn.silu(g) * u).astype(cfg.dtype), [experts["down_proj"]],
+            l, ids, hits, name="fewrow_grouped_down_proj")
+        y = jnp.zeros((t, d), jnp.float32)
+        for s in range(slots):
+            y = y + jnp.where(s < hits, weight[s][:, None] * o[s], 0.0)
+        return y
+
+    with jax.named_scope("experts"):
+        y = jax.lax.cond(hits > 0, run,
+                         lambda: jnp.zeros((t, d), jnp.float32))
+    return y, hits, done
 
 
 def _moe(cfg: MlaMoeConfig, lp, experts, l, n):

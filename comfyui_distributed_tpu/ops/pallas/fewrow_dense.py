@@ -22,6 +22,15 @@ weight transposed: `fewrow_dense_t` streams it in blocks of whole rows
 (``[tv, K]``: contiguous as it lies) and contracts the second axis of
 both operands on the MXU, so no transposed copy of the leaf is made.
 
+The routed experts of a block are the same product one axis more:
+`fewrow_grouped` walks ``S`` static SLOTS, slot ``s`` reading expert
+``ids[s]`` of layer ``l`` out of leaves ``[L, E, K, N]`` in place (both
+prefetched scalars in the weight's index map), so the double buffer
+carries ONE stream from an expert's last tile into the next expert's
+first.  Only the first ``hits`` slots are live: a dead slot's index maps
+stand still on the last live slot's last blocks (the pipeline copies
+nothing for a block index that did not change) and its product is skipped.
+
 Blocks: ``block_sizes`` keeps a tile's columns whole (``[tk, N]``: one
 contiguous run of the tiled HBM layout) where ``N`` allows and walks
 ``K``; a wider weight is walked over ``N`` first, ``K`` inside, the
@@ -66,24 +75,30 @@ def block_sizes(k: int, n: int, count: int = 1,
     return tk, _largest_divisor(n, budget // tk)
 
 
-def _kernel(layer_ref, x_ref, *refs, count: int, k_steps: int):
-    """One (N block, K block) grid step: every weight's tile against the
-    rows.  ``refs``: ``count`` weight tiles ``[tk, tn]``, then ``count``
-    float32 output blocks ``[rows, tn]``, resident over the K steps."""
-    del layer_ref                                  # the index maps' own
+def _tiles(x_ref, refs, count: int, k_steps: int, k_step):
+    """One grid step's products: every weight's tile against the rows.
+    ``refs``: ``count`` weight tiles ``[tk, tn]``, then ``count`` float32
+    output blocks ``[rows, tn]``, resident over the K steps; ``k_step()``
+    gives the step along K."""
     x = x_ref[...]
     for w_ref, o_ref in zip(refs[:count], refs[count:]):
         part = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
         if k_steps == 1:
             o_ref[...] = part
         else:
-            @pl.when(pl.program_id(1) == 0)
+            @pl.when(k_step() == 0)
             def _(o_ref=o_ref, part=part):
                 o_ref[...] = part
 
-            @pl.when(pl.program_id(1) > 0)
+            @pl.when(k_step() > 0)
             def _(o_ref=o_ref, part=part):
                 o_ref[...] += part
+
+
+def _kernel(layer_ref, x_ref, *refs, count: int, k_steps: int):
+    """One (N block, K block) grid step of `fewrow_dense`."""
+    del layer_ref                                  # the index maps' own
+    _tiles(x_ref, refs, count, k_steps, functools.partial(pl.program_id, 1))
 
 
 def fewrow_dense(x: jax.Array, leaves: Sequence[jax.Array],
@@ -132,6 +147,94 @@ def fewrow_dense(x: jax.Array, leaves: Sequence[jax.Array],
         interpret=interpret,
         name=name,
     )(layer, x, *leaves))
+
+
+def fewrow_grouped(x: jax.Array, leaves: Sequence[jax.Array], layer,
+                   ids: jax.Array, hits, *,
+                   blocks: Optional[Tuple[int, int]] = None,
+                   name: str = "fewrow_grouped",
+                   interpret: bool = False) -> Tuple[jax.Array, ...]:
+    """For each of the first ``hits`` of ``S = len(ids)`` slots, ``x``
+    (``[rows, K]``, the same for every slot, or ``[S, rows, K]``, a slot
+    its own) times expert ``ids[s]`` of layer ``layer`` of every leaf
+    ``[L, E, K, N]`` (all of one shape and of ``x``'s dtype): each
+    ``[S, rows, N]`` in float32, what ``jnp.dot(x_s, leaf[layer, ids[s]],
+    preferred_element_type=float32)`` gives.  A slot behind ``hits`` reads
+    no weight (``ids[s]`` is not looked at) and writes nothing: its part
+    of the result is whatever the buffer held, and the caller masks it.
+    ``K``, ``N``, ``blocks`` and ``interpret`` as `fewrow_dense`'s."""
+    count, (_, _, k, n) = len(leaves), leaves[0].shape
+    slots, shared = ids.shape[0], x.ndim == 2
+    if x.ndim not in (2, 3) or x.shape[-1] != k or k % LANES or n % LANES \
+            or not (shared or x.shape[0] == slots) \
+            or any(w.shape != leaves[0].shape or w.dtype != x.dtype
+                   for w in leaves):
+        raise ValueError(
+            f"fewrow_grouped: {x.dtype}{list(x.shape)} over {slots} slots "
+            f"against {[f'{w.dtype}{list(w.shape)}' for w in leaves]}: "
+            f"[rows, K] or [slots, rows, K], one shape [L, E, K, N] and "
+            f"one dtype, K and N multiples of {LANES}")
+    rows = x.shape[-2]
+    itemsize = jnp.dtype(x.dtype).itemsize
+    tk, tn = blocks or block_sizes(k, n, count, itemsize)
+    k_steps, n_steps = k // tk, n // tn
+    meta = jnp.asarray([layer, hits], jnp.int32)
+
+    def at(s, j, i, meta):
+        """The slot, N block and K block a grid step reads and writes:
+        its own while the slot is live, behind that the last live
+        step's, so that nothing is copied in or out."""
+        live = s < meta[1]
+        return (jnp.where(live, s, jnp.maximum(meta[1] - 1, 0)),
+                jnp.where(live, j, n_steps - 1),
+                jnp.where(live, i, k_steps - 1))
+
+    def x_map(s, j, i, ids, meta):
+        s, _, i = at(s, j, i, meta)
+        return (0, i) if shared else (s, 0, i)
+
+    def w_map(s, j, i, ids, meta):
+        s, j, i = at(s, j, i, meta)
+        return meta[0], ids[s], i, j
+
+    def o_map(s, j, i, ids, meta):
+        s, j, _ = at(s, j, i, meta)
+        return s, 0, j
+
+    def kernel(ids_ref, meta_ref, x_ref, *refs):
+        """One (slot, N block, K block) grid step."""
+        del ids_ref                                # the index maps' own
+        # (read here: the interpreter knows the grid at a kernel's top
+        # level, not under a ``pl.when``)
+        k_step = pl.program_id(2)
+
+        @pl.when(pl.program_id(0) < meta_ref[1])
+        def _():
+            _tiles(x_ref, refs, count, k_steps, lambda: k_step)
+
+    weight = pl.BlockSpec((None, None, tk, tn), w_map)
+    out = pl.BlockSpec((None, rows, tn), o_map)
+    return tuple(pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((slots, rows, n), jnp.float32)]
+        * count,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots, n_steps, k_steps),
+            in_specs=[pl.BlockSpec((rows, tk) if shared
+                                   else (None, rows, tk), x_map),
+                      *[weight] * count],
+            out_specs=[out] * count),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * slots * count * rows * k * n, transcendentals=0,
+            bytes_accessed=slots * (count * (k * n * itemsize + rows * n * 4)
+                                    + rows * k * itemsize * n_steps)),
+        interpret=interpret,
+        name=name,
+    )(jnp.asarray(ids, jnp.int32), meta, x, *leaves))
 
 
 def _kernel_t(x_ref, w_ref, o_ref):

@@ -140,6 +140,76 @@ def test_the_blocks_divide_the_published_shapes_and_fit(
         assert tn == n
 
 
+# the routed experts' matrices at the published sizes: (family, experts
+# held, d, moe_intermediate_size, experts a token)
+EXPERTS = [("pangu", 16, 7680, 2048, 8), ("exaone", 16, 6144, 2048, 8),
+           ("keye", 128, 2048, 768, 8)]
+EXPERT_IDS = [e[0] for e in EXPERTS]
+
+
+@pytest.mark.parametrize("rows", [2, 3, 4, 8])
+@pytest.mark.parametrize("family, held, d, width, k", EXPERTS, ids=EXPERT_IDS)
+def test_experts_that_outnumber_a_few_rows_pairs_take_the_grouped_path(
+        family, held, d, width, k, rows):
+    """Keye's 128 held experts at 2 to 8 rows; the two shares of 16 held
+    experts keep the loop at every row count (8 pairs a row: two rows
+    already route as many pairs as experts are held)."""
+    cfg = FAMILIES[family][1]
+    assert (cfg.experts_held, cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.num_experts_per_tok) == (held, d, width, k)
+    want = "grouped" if family == "keye" else "loop"
+    assert mla_moe.routed_path("tpu", rows, d, width, held, k) == want
+    assert mla_moe.routed_path(
+        "tpu", rows, d, width, held, k,
+        mesh_axes={"data": 1, "tensor": 1}) == want
+    # the same matrices with more experts held than pairs routed, and fewer
+    assert mla_moe.routed_path("tpu", rows, d, width, rows * k + 1, k) \
+        == "grouped"
+    assert mla_moe.routed_path("tpu", rows, d, width, rows * k, k) == "loop"
+
+
+@pytest.mark.parametrize("platform, rows, mesh_axes, why", [
+    ("tpu", 1, None, "the one-row program"),
+    ("tpu", 9, None, "more rows than the kernel's resident x holds"),
+    ("tpu", 64, None, "a one-row prefill of 64 positions"),
+    ("tpu", 256, None, "a prefill of 4 x 64 positions: two tiles' tokens"),
+    ("tpu", 4, {"data": 4, "tensor": 1}, "a live mesh of several devices"),
+    ("tpu", 4, {"data": 2, "tensor": 2}, "a live mesh of several devices"),
+    ("cpu", 4, None, "here, on the CPU"),
+    ("gpu", 4, None, "every other backend"),
+])
+@pytest.mark.parametrize("family, held, d, width, k", EXPERTS, ids=EXPERT_IDS)
+def test_what_is_no_few_row_call_keeps_the_loop_of_conditionals(
+        family, held, d, width, k, platform, rows, mesh_axes, why):
+    # (with experts enough that the pairs would not decide)
+    assert mla_moe.routed_path(platform, rows, d, width, 4096, k,
+                               mesh_axes=mesh_axes) == "loop", why
+
+
+@pytest.mark.parametrize("d, width, why", [
+    (64, 48, "the tiny models' widths"),
+    (2048, 200, "a width the blocks do not divide"),
+    (2000, 768, "d unaligned"),
+    (256, 128, "aligned, and too small to be worth a launch"),
+])
+def test_experts_the_blocks_do_not_divide_keep_the_loop(d, width, why):
+    assert mla_moe.routed_path("tpu", 4, d, width, 128, 8) == "loop", why
+
+
+@pytest.mark.parametrize("family, held, d, width, k", EXPERTS, ids=EXPERT_IDS)
+def test_the_blocks_divide_the_published_experts_and_fit(
+        family, held, d, width, k):
+    """Both calls of a block: gate / up together (two leaves), down alone;
+    a tile in flight and one in use for every leaf, the slots' float32
+    output blocks beside them, inside what the kernel asks of VMEM."""
+    for kk, nn, count in ((d, width, 2), (width, d, 1)):
+        tk, tn = fd.block_sizes(kk, nn, count)
+        assert kk % tk == 0 and nn % tn == 0 and tn == nn
+        assert tk * tn * 2 * count <= fd.TILE_BYTES
+        assert 2 * count * (tk * tn * 2 + 8 * tn * 4) + 2 * 8 * tk * 2 \
+            <= fd.VMEM_LIMIT_BYTES // 2
+
+
 # --- the kernel -----------------------------------------------------------------
 
 def operands(rows, layers, k, n, count, seed=0):
@@ -213,6 +283,114 @@ def test_operands_the_blocks_cannot_take_are_refused_by_name(
     with pytest.raises(ValueError, match="fewrow_dense"):
         fd.fewrow_dense(jnp.zeros(x_shape, jnp.bfloat16),
                         [jnp.zeros(leaf_shape, dtype)], interpret=True)
+
+
+# --- the grouped product: a slot an expert (PR 44) ---------------------------------
+
+def grouped_operands(rows, slots, layers, held, k, n, count, shared, seed=0):
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (rows, k) if shared else (slots, rows, k),
+                          jnp.float32).astype(jnp.bfloat16)
+    leaves = [
+        (jax.random.normal(jax.random.fold_in(key, i + 1),
+                           (layers, held, k, n), jnp.float32)
+         / np.sqrt(k)).astype(jnp.bfloat16) for i in range(count)]
+    return x, leaves
+
+
+# slots 6 over 7 experts held: (case, ids, live slots)
+GROUPED = {
+    "nothing hit": ([0, 0, 0, 0, 0, 0], 0),
+    "one hit": ([5, 5, 5, 5, 5, 5], 1),
+    "every slot hit": ([0, 1, 3, 4, 5, 6], 6),
+    "the last hit repeated behind the hits": ([1, 2, 6, 6, 6, 6], 3),
+    "ids behind the hits are not looked at": ([2, 4, 0, 3, 1, 5], 2),
+}
+
+
+@pytest.mark.parametrize("case", GROUPED)
+@pytest.mark.parametrize("k, n, count, shared, blocks", [
+    (256, 384, 1, True, None),             # one block an expert
+    (512, 256, 2, True, (128, 256)),       # gate / up; K walked
+    (256, 512, 1, False, (128, 128)),      # down: x a slot; N outside, K inside
+    (384, 640, 2, False, (384, 128)),      # N walked alone
+])
+def test_the_grouped_kernel_is_jnp_dot_on_each_live_slots_expert(
+        k, n, count, shared, blocks, case):
+    """Every live slot: ``jnp.dot`` on ``leaf[l, ids[s]]``.  A dead slot is
+    never written: the interpreter leaves what no grid step wrote as it
+    made it, NaN (with nothing hit, the first slot's last block alone is
+    handed back as it was fetched)."""
+    ids, hits = GROUPED[case]
+    rows, slots = 4, len(ids)
+    x, leaves = grouped_operands(rows, slots, 3, 7, k, n, count, shared,
+                                 seed=hits)
+    for layer in (2, 0):
+        got = fd.fewrow_grouped(
+            x, leaves, jnp.int32(layer), jnp.asarray(ids, jnp.int32),
+            jnp.int32(hits), blocks=blocks, interpret=True)
+        assert len(got) == count
+        for w, y in zip(leaves, got):
+            assert y.dtype == jnp.float32 and y.shape == (slots, rows, n)
+            for s in range(hits):
+                want = jnp.dot(x if shared else x[s], w[layer, ids[s]],
+                               preferred_element_type=jnp.float32)
+                np.testing.assert_allclose(y[s], want, rtol=0,
+                                           atol=4e-6 * np.sqrt(k))
+                other = jnp.dot(x if shared else x[s],
+                                w[1, (ids[s] + 1) % 7],
+                                preferred_element_type=jnp.float32)
+                assert not np.allclose(y[s], other, atol=1e-3)
+            assert np.isnan(np.asarray(y[max(hits, 1):])).all()
+
+
+def test_the_grouped_kernel_runs_under_a_scan_with_traced_ids_and_hits():
+    """As the decode body calls it: the leaves closed over, the layer, the
+    ids and the number hit traced values; gate / up in one call, down in a
+    second over each slot's own rows."""
+    x, (gate, up) = grouped_operands(4, 3, 2, 5, 256, 128, 2, True)
+    _, (down,) = grouped_operands(4, 3, 2, 5, 128, 256, 1, True, seed=1)
+    ids = jnp.asarray([[0, 2, 4], [1, 3, 3]], jnp.int32)
+    hits = jnp.asarray([3, 2], jnp.int32)
+
+    def body(h, own):
+        l, ids, hits = own
+        g, u = fd.fewrow_grouped(h.astype(jnp.bfloat16), [gate, up], l, ids,
+                                 hits, interpret=True)
+        (o,) = fd.fewrow_grouped((g * u).astype(jnp.bfloat16), [down], l,
+                                 ids, hits, interpret=True)
+        live = (jnp.arange(3) < hits)[:, None, None]
+        return jnp.tanh(jnp.sum(jnp.where(live, o, 0.0), axis=0)), None
+
+    got, _ = jax.jit(lambda h: jax.lax.scan(
+        body, h, (jnp.arange(2), ids, hits)))(x.astype(jnp.float32))
+    want = x.astype(jnp.float32)
+    for l in range(2):
+        total = 0.0
+        for s in range(int(hits[l])):
+            e = int(ids[l, s])
+            g, u = (jnp.dot(want.astype(jnp.bfloat16), w[l, e],
+                            preferred_element_type=jnp.float32)
+                    for w in (gate, up))
+            total = total + jnp.dot((g * u).astype(jnp.bfloat16), down[l, e],
+                                    preferred_element_type=jnp.float32)
+        want = jnp.tanh(total)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("x_shape, leaf_shape, dtype", [
+    ((4, 256), (2, 5, 128, 256), jnp.bfloat16),     # K differs
+    ((4, 200), (2, 5, 200, 256), jnp.bfloat16),     # unaligned
+    ((4, 256), (2, 5, 256, 256), jnp.float32),      # another dtype than x's
+    ((2, 4, 256), (2, 5, 256, 256), jnp.bfloat16),  # x for 2 of 3 slots
+    ((256,), (2, 5, 256, 256), jnp.bfloat16),       # x with no rows
+])
+def test_operands_the_grouped_blocks_cannot_take_are_refused_by_name(
+        x_shape, leaf_shape, dtype):
+    with pytest.raises(ValueError, match="fewrow_grouped"):
+        fd.fewrow_grouped(jnp.zeros(x_shape, jnp.bfloat16),
+                          [jnp.zeros(leaf_shape, dtype)], 0,
+                          jnp.zeros((3,), jnp.int32), 1, interpret=True)
 
 
 # --- a tied embedding read as the head (PR 40) ------------------------------------
@@ -477,6 +655,10 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
         _granite_decode_keeps_its_state_in_place(text, bodies)
     if family == "keye":
         _keye_decode_keeps_its_caches_and_experts_in_place(text, bodies)
+    if family == "keye":
+        _decode_runs_its_hit_experts_through_one_stream(text, family)
+    if family in ("pangu", "exaone"):
+        _decode_walks_its_sixteen_experts_in_the_loop(text)
     segments = {seg for p in paths for seg in p.split("/")}
     want = {"ouro": {"q_proj", "o_proj", "gate_proj", "down_proj", "lm_head",
                      "fewrow_dense_q_proj_k_proj_v_proj",
@@ -494,6 +676,55 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
             "keye": {"q_proj", "o_proj", "indexer", "wq",
                      "fewrow_dense_k_proj_v_proj"}}[family]
     assert want <= segments, want - segments
+
+
+def _decode_runs_its_hit_experts_through_one_stream(text, family):
+    """PR 44: a decode step's routed experts are two calls of the grouped
+    kernel a block (gate / up, then down) whose weight operands are the
+    WHOLE expert leaves ``[L, E, K, N]``, under the ``experts`` scope of
+    the decode phase, inside ONE conditional (a block that hits nothing);
+    no loop over the experts held is left in the decode, and the prefill
+    (4 x 64 tokens: no few-row call) keeps its own."""
+    arch, cfg = FAMILIES[family]
+    leaves = {tuple(shape) for shape in
+              arch.param_shapes(cfg)["moe_layers" if family != "keye"
+                                     else "layers"]["experts"].values()}
+    assert all(len(shape) == 4 and shape[1] == cfg.experts_held
+               for shape in leaves), leaves
+    lines = [l for body in computations(text).values() for l in body]
+    calls = [l for l in lines if "custom-call(" in l and "fewrow_grouped" in l]
+    assert sorted("down_proj" in l for l in calls) == [False, True], \
+        [c[:120] for c in calls]
+    for call in calls:
+        count = 1 if "down_proj" in call else 2
+        constraints = call.split("operand_layout_constraints=")[1] \
+            .split("frontend_attributes")[0]
+        weights = [tuple(int(d) for d in dims) for dims in re.findall(
+            r"bf16\[(\d+),(\d+),(\d+),(\d+)\]", constraints)]
+        assert len(weights) == count and set(weights) <= leaves, call[:300]
+        (path,) = re.findall(r'op_name="([^"]+)"', call)
+        assert trace.classify(path) == "lm_experts" \
+            and trace.phase_of(path) == "decode" \
+            and "/experts/cond/" in path, path
+    paths = re.findall(r'op_name="([^"]+)"', text)
+    assert not any("/decode/" in p and "/experts/while" in p for p in paths)
+    assert any("/prefill/" in p and "/experts/while" in p for p in paths)
+    # the loop's conditionals (one an expert held) are the prefill's alone
+    conditionals = [l for l in lines if " conditional(" in l
+                    and "/experts/" in l]
+    assert sum("/decode/" in l for l in conditionals) == 1, conditionals
+
+
+def _decode_walks_its_sixteen_experts_in_the_loop(text):
+    """PR 44's rule leaves the two shares of 16 held experts where they
+    were (4 rows route 32 pairs: no more conditionals than slots): no
+    grouped call, the decode's experts under their loop."""
+    # (instructions, not the text: the module's table of stack frames
+    # names every function a process has traced)
+    assert not [l for l in text.splitlines()
+                if "custom-call(" in l and "fewrow_grouped" in l]
+    paths = re.findall(r'op_name="([^"]+)"', text)
+    assert any("/decode/" in p and "/experts/while" in p for p in paths)
 
 
 def _keye_decode_keeps_its_caches_and_experts_in_place(text, bodies,

@@ -14,6 +14,7 @@ to fail it where the served path passes.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import math
 import os
@@ -25,6 +26,7 @@ import numpy as np
 import pytest
 
 from comfyui_distributed_tpu.models import mla_moe, registry
+from comfyui_distributed_tpu.ops.pallas import fewrow_dense as fd
 from comfyui_distributed_tpu.parallel import sharding as shd
 from comfyui_distributed_tpu.utils import trace
 
@@ -354,6 +356,113 @@ def test_an_expert_multiplies_its_own_tokens_in_tiles(case, monkeypatch):
         assert int(rows) == 0 and not np.asarray(y).any()
     else:
         assert float(jnp.abs(want).max()) > 1e-2
+
+
+# --- a few rows on a TPU: the hit experts through the grouped kernel (PR 44) ------
+
+def _family(name):
+    """A family's tiny configuration (all three share `_routed`, which
+    reads the fields it needs under one name in each)."""
+    from comfyui_distributed_tpu.models import dsa_moe, swa_moe
+    return {"pangu": mla_moe, "exaone": swa_moe,
+            "keye": dsa_moe}[name].CONFIGS["tiny"]
+
+
+def _routing(cfg, router_width, tokens, seed, takers=None):
+    """``x``, ``chosen`` and ``weights`` of a call of ``tokens`` tokens:
+    each token's distinct choices among the router's experts (the first
+    token's first the last expert held), or (with ``takers``) none to an
+    expert held here."""
+    rng = np.random.default_rng(seed)
+    last = cfg.experts_first + cfg.experts_held - 1
+    absent = [e for e in range(router_width) if not
+              cfg.experts_first <= e <= last]
+    chosen = np.stack([
+        rng.permutation(absent if takers == "nobody" else router_width)[
+            :cfg.num_experts_per_tok] for _ in range(tokens)])
+    if takers is None and last not in chosen[0]:
+        chosen[0, 0] = last
+    return (jnp.asarray(rng.standard_normal((tokens, 128)), jnp.float32),
+            jnp.asarray(chosen, jnp.int32),
+            jnp.asarray(rng.uniform(0.1, 1.0, chosen.shape), jnp.float32))
+
+
+@pytest.mark.parametrize("tokens", [2, 4, 8])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", ["pangu", "exaone", "keye"])
+def test_the_grouped_path_gives_the_loops_sum_and_its_four_counts(
+        family, dtype, tokens, monkeypatch):
+    """`_routed` of a few rows with the rule read as a TPU's (the kernel in
+    the Pallas interpreter, small blocks so that K is walked) against the
+    loop of conditionals on the same operands: the same sum to float32's
+    rounding (the order of a product's sum over K alone differs), the
+    same pairs a token, hits, none dropped, rows multiplied; and
+    ``dense_paths`` names the path its three call sites took."""
+    cfg = _family(family)
+    cfg = dataclasses.replace(cfg, dtype=jnp.dtype(dtype))
+    router_width = getattr(cfg, "n_routed_experts", None) or cfg.num_experts
+    rng = np.random.default_rng(5)
+    experts = {name: jnp.asarray(
+        rng.standard_normal((2, cfg.experts_held, *shape)) / 12, cfg.dtype)
+        for name, shape in (("gate_proj", (128, 256)), ("up_proj", (128, 256)),
+                            ("down_proj", (256, 128)))}
+    x, chosen, weights = _routing(cfg, router_width, tokens, seed=tokens)
+
+    def routed():
+        return jax.jit(lambda *a: mla_moe._routed(
+            cfg, experts, jnp.int32(1), *a))(x, chosen, weights)
+
+    want = routed()
+    assert int(want[2]) > 0 and float(jnp.abs(want[0]).max()) > 1e-2
+    monkeypatch.setattr(mla_moe, "routed_path", lambda *a, **kw: "grouped")
+    monkeypatch.setattr(mla_moe, "fewrow_grouped", functools.partial(
+        fd.fewrow_grouped, interpret=True, blocks=(128, 128)))
+    before = trace.DENSE_PATHS.snapshot().get("fewrow_grouped_few", 0)
+    got = routed()
+    assert trace.DENSE_PATHS.snapshot()["fewrow_grouped_few"] == before + 3
+    np.testing.assert_allclose(
+        got[0], want[0], rtol=0,
+        atol=2e-5 if dtype == "float32" else 1e-2 * float(
+            jnp.abs(want[0]).max()))
+    for a, b in zip(got[1:], want[1:]):
+        assert np.array_equal(a, b)
+    assert int(got[3]) == 0 and int(got[4]) == int(got[2]) * tokens
+
+
+@pytest.mark.parametrize("family", ["pangu", "exaone"])
+def test_a_few_rows_that_hit_no_expert_held_launch_nothing(family,
+                                                           monkeypatch):
+    """Every pair to an absent expert: both kernel calls lie in the hit
+    branch of ONE conditional, which is not taken; the sum is zero and the
+    four counts are."""
+    cfg = _family(family)
+    monkeypatch.setattr(mla_moe, "routed_path", lambda *a, **kw: "grouped")
+    monkeypatch.setattr(mla_moe, "fewrow_grouped", functools.partial(
+        fd.fewrow_grouped, interpret=True))
+    experts = {name: jnp.ones((2, cfg.experts_held, *shape), cfg.dtype)
+               for name, shape in (("gate_proj", (128, 256)),
+                                   ("up_proj", (128, 256)),
+                                   ("down_proj", (256, 128)))}
+    x, chosen, weights = _routing(
+        cfg, getattr(cfg, "n_routed_experts", None) or cfg.num_experts, 4,
+        seed=1, takers="nobody")
+
+    def routed(*a):
+        return mla_moe._routed(cfg, experts, jnp.int32(0), *a)
+
+    jaxpr = jax.make_jaxpr(routed)(x, chosen, weights).jaxpr
+    eqns = list(_equations(jaxpr))
+    # (a kernel's own ``pl.when`` is a conditional inside its call)
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1 and not any(
+        e.primitive.name == "while" for e in eqns)
+    inside = [e.primitive.name for e in _equations(
+        conds[0].params["branches"][1].jaxpr)]
+    assert inside.count("pallas_call") == 2 \
+        == sum(e.primitive.name == "pallas_call" for e in eqns)
+    y, pairs, hits, dropped, rows = jax.jit(routed)(x, chosen, weights)
+    assert not np.asarray(y).any() and not np.asarray(pairs).any()
+    assert (int(hits), int(dropped), int(rows)) == (0, 0, 0)
 
 
 def _equations(jaxpr):
