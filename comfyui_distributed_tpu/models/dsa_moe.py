@@ -66,6 +66,7 @@ self_attn/indexer/wq`` ...), read by ``utils/trace.KERNEL_CLASSES``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Any, Dict, Tuple
 
@@ -73,12 +74,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from comfyui_distributed_tpu.models import lm_decode
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, visible_keys
-from comfyui_distributed_tpu.models.looplm import Stacked, _dense, \
+from comfyui_distributed_tpu.models.looplm import _dense, _embed, _head, \
     _rms_norm, dense_each, few_rows_here, scan_layers  # noqa: F401
 from comfyui_distributed_tpu.models.mla_moe import _moe, count_values, \
-    seeded_tree
+    routing_counters, seeded_tree
 from comfyui_distributed_tpu.models.swa_moe import _attend
 from comfyui_distributed_tpu.parallel import sharding as shd
 
@@ -477,18 +479,6 @@ def _attend_gathered(cfg: KeyeConfig, q, qi, w, kc, vc, ic, l, at, first,
          jnp.sum(live, axis=-1, dtype=jnp.int32))
 
 
-def _own_entries(own, new, cache, l, at):
-    """``new [B, N, ...]``, to be written into layer ``l`` of ``cache`` at
-    index ``at``, with what the cache HOLDS there wherever a position is
-    not a row's ``own [B, N]`` (`ssm_hybrid._own_entries`' rule, for a
-    cache of any width)."""
-    tail = (0,) * (new.ndim - 2)
-    held = jax.lax.dynamic_slice(cache, (l, 0, at, *tail),
-                                 (1, *new.shape))[0]
-    return jnp.where(jnp.expand_dims(own, tuple(range(2, new.ndim))), new,
-                     held.astype(new.dtype))
-
-
 def _attention(cfg: KeyeConfig, lp, x, positions, index, first, caches, l,
                decode: bool, prefix: int = 0):
     """``h = x + Attn(N1(x))``, the three caches with this call's keys,
@@ -510,7 +500,7 @@ def _attention(cfg: KeyeConfig, lp, x, positions, index, first, caches, l,
             qi, ki, w = _index(cfg, lp["indexer"], u, positions)
         with jax.named_scope("kv_cache"):
             if prefix:
-                k, v, ki = (_own_entries(own, t, c, l, index[0])
+                k, v, ki = (lm_decode.own_entries(own, t, c, l, index[0])
                             for c, t in zip((kc, vc, ic), (k, v, ki)))
             kc, vc = (jax.lax.dynamic_update_slice(
                 c, t[None].astype(c.dtype), (l, 0, index[0], 0, 0))
@@ -580,16 +570,6 @@ def _stack(cfg: KeyeConfig, params, x, positions, index, first, caches,
     if packed:
         out["packed"] = jnp.moveaxis(packed[0], 0, 2)
     return x, tuple(caches), out
-
-
-def _embed(params, ids):
-    with jax.named_scope("embed_tokens"):
-        return params["embed_tokens"][ids].astype(jnp.float32)
-
-
-def _head(cfg: KeyeConfig, params, x):
-    with jax.named_scope("lm_head"):
-        return _dense(x, Stacked(params["lm_head"]), cfg)
 
 
 def empty_cache(cfg: KeyeConfig, batch: int, length: int):
@@ -668,16 +648,9 @@ def from_prefix(caches, prefix, first):
     own offset ``first[b]``, directly in front of where that row's suffix
     will be written (the padding lies in front of both, so the mask stays
     ``kv_start = first`` with no hole)."""
-    out = []
     with jax.named_scope("kv_cache"):
-        for cache, name in zip(caches, PREFIX_CACHES):
-            tail = (0,) * (cache.ndim - 3)
-            for b in range(cache.shape[1]):
-                cache = jax.lax.dynamic_update_slice(
-                    cache, prefix[name][:, None].astype(cache.dtype),
-                    (0, b, first[b], *tail))
-            out.append(cache)
-    return tuple(out)
+        return tuple(lm_decode.write_at_offsets(cache, prefix[name], first)
+                     for cache, name in zip(caches, PREFIX_CACHES))
 
 
 def _shift_up(words, by, width: int):
@@ -706,16 +679,14 @@ def _prefix_records(prefix, first, chosen, packed):
     K = prefix["choices"].shape[0]
 
     def behind(rows, record):
-        """``rows [B, S, ...]`` behind K positions, ``record(b) [K, ...]``
-        written at row ``b``'s offset."""
-        rows = jnp.pad(rows, ((0, 0), (K, 0), (0, 0), (0, 0)))
-        for b in range(rows.shape[0]):
-            rows = jax.lax.dynamic_update_slice(
-                rows, record(b)[None], (b, first[b], 0, 0))
-        return rows
+        """``rows [B, S, ...]`` behind K positions, ``record`` (``[K,
+        ...]``, or row ``b``'s from ``b``) written at each row's offset."""
+        return lm_decode.write_at_offsets(
+            jnp.pad(rows, ((0, 0), (K, 0), (0, 0), (0, 0))), record, first,
+            rows=0)
 
     with jax.named_scope("gate"):
-        chosen = behind(chosen, lambda b: prefix["choices"])
+        chosen = behind(chosen, prefix["choices"])
     with jax.named_scope("selection_record"):
         packed = behind(packed, lambda b: _shift_up(
             prefix["selected"], first[b], packed.shape[-1]))
@@ -755,72 +726,52 @@ def generate(cfg: KeyeConfig, max_new_tokens: int, params, prompt_ids,
     nothing counted, while its records stand in ``aux``); over both
     ``expert_pairs_dropped`` (0)."""
     B, S = prompt_ids.shape
-    K = 0 if prefix is None else prefix["keys"].shape[1]
+    K = lm_decode.prefix_length(prefix)
     P = K + S
-    prompt_len, seed, temperature = (
-        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
-    first = S - prompt_len
-    keys = jax.vmap(jax.random.PRNGKey)(seed)
-
-    def draw(key, logits, temperature, i):
-        drawn = jax.random.categorical(
-            jax.random.fold_in(key, i),
-            logits / jnp.maximum(temperature, 1e-6))
-        return jnp.where(temperature > 0, drawn,
-                         jnp.argmax(logits)).astype(jnp.int32)
+    first = S - jnp.broadcast_to(prompt_len, (B,))
 
     def at_last(out):
         """What the blocks chose at a call's last position."""
         return out["scores"], out["chosen"][:, -1], out["selection"]
 
-    with jax.named_scope("KeyeVL2"):
+    def prefill():
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
-            prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+            ids = jax.vmap(jnp.roll)(prompt_ids, first)
             index = jnp.arange(K, P)
-            x, positions = _embed(params, prompt_ids), \
-                text_positions(index, first)
+            x, positions = _embed(params, ids), text_positions(index, first)
             caches = empty_cache(cfg, B, P + max_new_tokens)
             if prefix is not None:
                 caches = from_prefix(caches, prefix, first)
-            x, caches, prefill = _stack(cfg, params, x, positions, index,
-                                        first, caches, decode=False, prefix=K)
+            x, caches, out = _stack(cfg, params, x, positions, index, first,
+                                    caches, decode=False, prefix=K)
             logits = _head(cfg, params, x[:, S - 1:])[:, 0]
             if prefix is not None:
-                prefill["chosen"], prefill["packed"] = _prefix_records(
-                    prefix, first, prefill["chosen"], prefill["packed"])
+                out["chosen"], out["packed"] = _prefix_records(
+                    prefix, first, out["chosen"], out["packed"])
+            rows = jnp.zeros((B,), jnp.int32)
+            return (logits, at_last(out), caches,
+                    (rows, jnp.int32(0), out["counts"][2], rows, rows, rows),
+                    out)
 
-        def step(carry, i):
-            logits, chosen, caches, counts = carry
-            with jax.named_scope("sample"):
-                token = jax.vmap(draw, (0, 0, 0, None))(
-                    keys, logits, temperature, i)
-            index = P + i[None]
-            x, caches, out = _stack(
-                cfg, params, _embed(params, token[:, None]),
-                text_positions(index, first), index, first, caches,
-                decode=True)
-            nxt = _head(cfg, params, x)[:, 0]
-            now = (*out["counts"][:3], *out["keys"])
-            return (nxt, at_last(out), caches,
-                    tuple(a + b for a, b in zip(counts, now))), \
-                (token, logits, *chosen)
+    def step(token, i, caches):
+        index = P + i[None]
+        x, caches, out = _stack(
+            cfg, params, _embed(params, token[:, None]),
+            text_positions(index, first), index, first, caches, decode=True)
+        return (_head(cfg, params, x)[:, 0], at_last(out), caches,
+                (*out["counts"][:3], *out["keys"]))
 
-        rows, zero = jnp.zeros((B,), jnp.int32), jnp.int32(0)
-        with jax.named_scope("decode"):
-            (*_, counts), (tokens, logits, scores, choices, selections) = \
-                jax.lax.scan(
-                    step, (logits, at_last(prefill), caches,
-                           (rows, zero, prefill["counts"][2], rows, rows,
-                            rows)), jnp.arange(max_new_tokens))
-    pairs, hits, dropped, scored, selected, attended = counts
-    prefill_pairs, _, _, prefill_rows = prefill["counts"]
-    return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
-            {"router_scores": scores.swapaxes(0, 1),
-             "expert_choices": choices.swapaxes(0, 1),
-             "prompt_choices": prefill["chosen"],
-             "key_selections": selections.swapaxes(0, 1),
-             "prompt_selected": prefill["packed"]},
+    tokens, logits, (scores, choices, selections), \
+        (pairs, hits, dropped, scored, selected, attended), prefilled = \
+        lm_decode.generate("KeyeVL2", B, prefill, step, max_new_tokens, seed,
+                           temperature)
+    prefill_pairs, _, _, prefill_rows = prefilled["counts"]
+    return (tokens, logits,
+            {"router_scores": scores, "expert_choices": choices,
+             "prompt_choices": prefilled["chosen"],
+             "key_selections": selections,
+             "prompt_selected": prefilled["packed"]},
             {"expert_pairs_local": pairs, "expert_hits": hits,
              "expert_pairs_dropped": dropped,
              "keys_scored": scored, "keys_selected": selected,
@@ -829,7 +780,7 @@ def generate(cfg: KeyeConfig, max_new_tokens: int, params, prompt_ids,
              "expert_rows_computed_prefill": prefill_rows,
              "prefill_positions": jnp.int32(B * S),
              **{f"keys_{name}_prefill": count for name, count in zip(
-                 ("scored", "selected", "attended"), prefill["keys"])}})
+                 ("scored", "selected", "attended"), prefilled["keys"])}})
 
 
 def make_program(cfg: KeyeConfig, max_new_tokens: int):
@@ -837,36 +788,23 @@ def make_program(cfg: KeyeConfig, max_new_tokens: int):
     device trace) like every language model's.  With a sixth argument,
     `make_prefix_program`'s snapshot, ``prompt_ids`` holds what follows
     the prefix."""
-
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature,
-                    prefix=None):
-        return generate(cfg, max_new_tokens, params, prompt_ids, prompt_len,
-                        seed, temperature, prefix)
-
-    return jax.jit(lm_generate)
+    return lm_decode.make_program(
+        functools.partial(generate, cfg, max_new_tokens))
 
 
 def window_counters(cfg: KeyeConfig, stats, real: int, steps: int
                     ) -> Dict[str, int]:
     """The ``lm.*`` window counters of one execution from its fetched
-    ``stats``: the routing counters as `mla_moe.window_counters`', the
-    index keys the ``real`` rows' decode steps scored and the keys they
-    selected and attended to (a padded row repeats the first and is
-    nobody's), and what the prefill computed for EVERY row of the program
-    (its positions, the pairs it scored, selected and attended to)."""
+    ``stats``: `mla_moe.routing_counters`', the index keys the ``real``
+    rows' decode steps scored and the keys they selected and attended to
+    (a padded row repeats the first and is nobody's), and what the
+    prefill computed for EVERY row of the program (its positions, the
+    pairs it scored, selected and attended to)."""
     def real_rows(name):
         return int(stats[name][:real].sum())
 
     return {
-        "lm.expert_pairs": real * steps * cfg.moe_layers
-        * cfg.num_experts_per_tok,
-        "lm.expert_pairs_local": real_rows("expert_pairs_local"),
-        "lm.expert_hits": int(stats["expert_hits"]),
-        "lm.expert_pairs_dropped": int(stats["expert_pairs_dropped"]),
-        "lm.expert_pairs_local_prefill": int(
-            stats["expert_pairs_local_prefill"].sum()),
-        "lm.expert_rows_computed_prefill": int(
-            stats["expert_rows_computed_prefill"]),
+        **routing_counters(cfg, stats, real, steps),
         "lm.prefill_positions": int(stats["prefill_positions"]),
         "lm.keys_scored_decode": real_rows("keys_scored"),
         "lm.keys_selected": real_rows("keys_selected"),

@@ -53,6 +53,7 @@ from typing import Any, Dict, Mapping, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from comfyui_distributed_tpu.models import lm_decode
 from comfyui_distributed_tpu.models.layers import _live_mesh, \
     scaled_dot_product_attention
 from comfyui_distributed_tpu.ops.pallas.fewrow_dense import LANES, \
@@ -549,7 +550,8 @@ def kv_cache_bytes(cfg: LoopLMConfig, batch: int, length: int) -> int:
 def generate(cfg: LoopLMConfig, max_new_tokens: int, params, prompt_ids,
              prompt_len, seed, temperature
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Prefill, then ``max_new_tokens`` decode steps, for every row.
+    """Prefill, then ``max_new_tokens`` decode steps, for every row
+    (`lm_decode.generate` around this family's blocks).
 
     ``prompt_ids [B, P]`` holds in row ``b`` ``prompt_len[b]`` real ids
     and padding behind them; ``prompt_len``, ``seed`` and ``temperature``
@@ -562,56 +564,35 @@ def generate(cfg: LoopLMConfig, max_new_tokens: int, params, prompt_ids,
     was drawn from ``[B, N, V]`` and the exit probabilities ``[B, N, R]``.
     """
     B, P = prompt_ids.shape
-    prompt_len, seed, temperature = (
-        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
-    first = P - prompt_len
-    keys = jax.vmap(jax.random.PRNGKey)(seed)
+    first = P - jnp.broadcast_to(prompt_len, (B,))
 
-    def draw(key, logits, temperature, i):
-        drawn = jax.random.categorical(
-            jax.random.fold_in(key, i),
-            logits / jnp.maximum(temperature, 1e-6))
-        return jnp.where(temperature > 0, drawn,
-                         jnp.argmax(logits)).astype(jnp.int32)
-
-    with jax.named_scope("LoopLM"):
+    def prefill():
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
-            prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+            ids = jax.vmap(jnp.roll)(prompt_ids, first)
             cache = empty_cache(cfg, B, P + max_new_tokens)
             x, exits, cache = _stack(
-                cfg, params, _embed(params, prompt_ids), jnp.arange(P),
-                first, cache, use_cache=False)
+                cfg, params, _embed(params, ids), jnp.arange(P), first,
+                cache, use_cache=False)
             logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+            return logits, (exits[:, P - 1],), cache, (), ()
 
-        def step(carry, i):
-            logits, exits, cache = carry
-            with jax.named_scope("sample"):
-                token = jax.vmap(draw, (0, 0, 0, None))(
-                    keys, logits, temperature, i)
-            x, nxt_exits, cache = _stack(
-                cfg, params, _embed(params, token[:, None]), P + i[None],
-                first, cache, use_cache=True)
-            nxt = _head(cfg, params, x)[:, 0]
-            return (nxt, nxt_exits[:, 0], cache), (token, logits, exits)
+    def step(token, i, cache):
+        x, exits, cache = _stack(
+            cfg, params, _embed(params, token[:, None]), P + i[None], first,
+            cache, use_cache=True)
+        return _head(cfg, params, x)[:, 0], (exits[:, 0],), cache, ()
 
-        with jax.named_scope("decode"):
-            _, (tokens, logits, exits) = jax.lax.scan(
-                step, (logits, exits[:, P - 1], cache),
-                jnp.arange(max_new_tokens))
-    return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
-            exits.swapaxes(0, 1))
+    tokens, logits, (exits,), _, _ = lm_decode.generate(
+        "LoopLM", B, prefill, step, max_new_tokens, seed, temperature)
+    return tokens, logits, exits
 
 
 def make_generate(cfg: LoopLMConfig, max_new_tokens: int):
-    """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
+    """`generate` jitted, named ``lm_generate`` (``jit_lm_generate`` in a
     device trace) whatever its configuration."""
-
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
-        return generate(cfg, max_new_tokens, params, prompt_ids, prompt_len,
-                        seed, temperature)
-
-    return jax.jit(lm_generate)
+    return lm_decode.make_program(
+        functools.partial(generate, cfg, max_new_tokens))
 
 
 def make_program(cfg: LoopLMConfig, max_new_tokens: int):
@@ -620,13 +601,11 @@ def make_program(cfg: LoopLMConfig, max_new_tokens: int):
     arrays a comparison wants beside the logits (here the exit
     probabilities), ``stats`` what the host counts from (here nothing)."""
 
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
-        tokens, logits, exits = generate(cfg, max_new_tokens, params,
-                                         prompt_ids, prompt_len, seed,
-                                         temperature)
+    def served(*args):
+        tokens, logits, exits = generate(cfg, max_new_tokens, *args)
         return tokens, logits, {"exit_probs": exits}, {}
 
-    return jax.jit(lm_generate)
+    return lm_decode.make_program(served)
 
 
 def window_counters(cfg: LoopLMConfig, stats, real: int, steps: int

@@ -75,16 +75,17 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from comfyui_distributed_tpu.models import looplm
+from comfyui_distributed_tpu.models import lm_decode, looplm
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, xla_attention
-from comfyui_distributed_tpu.models.looplm import Stacked, _dense, \
+from comfyui_distributed_tpu.models.looplm import _dense, _embed, _head, \
     _rms_norm, _sandwich, dense_each, dense_path, few_rows_here, matrix, \
     scan_layers
 from comfyui_distributed_tpu.ops.pallas.fewrow_dense import fewrow_grouped
@@ -256,7 +257,7 @@ def _seeded_leaf(name: str, shape: tuple, dtype, gain, own=None):
     stacked leaf is drawn slice by slice along its leading axis, so the
     float32 normals of the largest (the experts', 1.0 B values) never
     stand whole beside it."""
-    def draw(key, shape):
+    def made(key, shape):
         if own is not None:
             return own(key, shape).astype(dtype)
         x = jax.random.normal(key, shape, jnp.float32)
@@ -268,8 +269,8 @@ def _seeded_leaf(name: str, shape: tuple, dtype, gain, own=None):
 
     def leaf(key):
         if len(shape) < 3:
-            return draw(key, shape)
-        return jax.lax.map(lambda k: draw(k, shape[1:]),
+            return made(key, shape)
+        return jax.lax.map(lambda k: made(k, shape[1:]),
                            jax.random.split(key, shape[0]))
 
     return jax.jit(leaf)
@@ -743,16 +744,6 @@ def _stack(cfg: MlaMoeConfig, params, x, index, first, cache,
         tuple(c.sum(axis=0) for c in counts)
 
 
-def _embed(params, ids):
-    with jax.named_scope("embed_tokens"):
-        return params["embed_tokens"][ids].astype(jnp.float32)
-
-
-def _head(cfg: MlaMoeConfig, params, x):
-    with jax.named_scope("lm_head"):
-        return _dense(x, Stacked(params["lm_head"]), cfg)
-
-
 def empty_cache(cfg: MlaMoeConfig, batch: int, length: int):
     """The latent cache: ``c_kv`` and ``k_r`` of every position of every
     block held, and no head axis."""
@@ -788,53 +779,35 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
     multiplied for them: `_routed`'s tiles x their rows); over both
     ``expert_pairs_dropped`` (0)."""
     B, P = prompt_ids.shape
-    prompt_len, seed, temperature = (
-        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
-    first = P - prompt_len
-    keys = jax.vmap(jax.random.PRNGKey)(seed)
+    first = P - jnp.broadcast_to(prompt_len, (B,))
 
-    def draw(key, logits, temperature, i):
-        drawn = jax.random.categorical(
-            jax.random.fold_in(key, i),
-            logits / jnp.maximum(temperature, 1e-6))
-        return jnp.where(temperature > 0, drawn,
-                         jnp.argmax(logits)).astype(jnp.int32)
-
-    with jax.named_scope("PanguUltraMoE"):
+    def prefill():
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
-            prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+            ids = jax.vmap(jnp.roll)(prompt_ids, first)
             cache = empty_cache(cfg, B, P + max_new_tokens)
             x, cache, routed, counts = _stack(
-                cfg, params, _embed(params, prompt_ids), jnp.arange(P),
-                first, cache, absorbed=False)
-            prefill_pairs, _, dropped, prefill_rows = counts
+                cfg, params, _embed(params, ids), jnp.arange(P), first,
+                cache, absorbed=False)
             logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+            return (logits, tuple(r[:, P - 1] for r in routed), cache,
+                    (jnp.zeros((B,), jnp.int32), jnp.int32(0), counts[2]),
+                    (routed[1], counts))
 
-        def step(carry, i):
-            logits, routed, cache, counts = carry
-            with jax.named_scope("sample"):
-                token = jax.vmap(draw, (0, 0, 0, None))(
-                    keys, logits, temperature, i)
-            x, cache, nxt_routed, (*now, _) = _stack(
-                cfg, params, _embed(params, token[:, None]), P + i[None],
-                first, cache, absorbed=True)
-            nxt = _head(cfg, params, x)[:, 0]
-            return (nxt, tuple(r[:, 0] for r in nxt_routed), cache,
-                    tuple(a + b for a, b in zip(counts, now))), \
-                (token, logits, *routed)
+    def step(token, i, cache):
+        x, cache, routed, (*now, _) = _stack(
+            cfg, params, _embed(params, token[:, None]), P + i[None], first,
+            cache, absorbed=True)
+        return (_head(cfg, params, x)[:, 0], tuple(r[:, 0] for r in routed),
+                cache, now)
 
-        zero = jnp.int32(0)
-        with jax.named_scope("decode"):
-            (*_, counts), (tokens, logits, scores, choices) = jax.lax.scan(
-                step, (logits, tuple(r[:, P - 1] for r in routed), cache,
-                       (jnp.zeros((B,), jnp.int32), zero, dropped)),
-                jnp.arange(max_new_tokens))
-    pairs, hits, dropped = counts
-    return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
-            {"router_scores": scores.swapaxes(0, 1),
-             "expert_choices": choices.swapaxes(0, 1),
-             "prompt_choices": routed[1]},
+    tokens, logits, (scores, choices), (pairs, hits, dropped), \
+        (prompt_choices, (prefill_pairs, _, _, prefill_rows)) = \
+        lm_decode.generate("PanguUltraMoE", B, prefill, step,
+                           max_new_tokens, seed, temperature)
+    return (tokens, logits,
+            {"router_scores": scores, "expert_choices": choices,
+             "prompt_choices": prompt_choices},
             {"expert_pairs_local": pairs, "expert_hits": hits,
              "expert_pairs_dropped": dropped,
              "expert_pairs_local_prefill": prefill_pairs,
@@ -844,22 +817,18 @@ def generate(cfg: MlaMoeConfig, max_new_tokens: int, params, prompt_ids,
 def make_program(cfg: MlaMoeConfig, max_new_tokens: int):
     """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
     device trace) like every language model's."""
-
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
-        return generate(cfg, max_new_tokens, params, prompt_ids, prompt_len,
-                        seed, temperature)
-
-    return jax.jit(lm_generate)
+    return lm_decode.make_program(
+        functools.partial(generate, cfg, max_new_tokens))
 
 
-def window_counters(cfg: MlaMoeConfig, stats, real: int, steps: int
-                    ) -> Dict[str, int]:
-    """The ``lm.*`` window counters of one execution from its fetched
-    ``stats``: the ``real`` rows' pairs (a padded row repeats the first
-    and is nobody's request; it routes as the first does, so it adds no
-    hit) and, over EVERY row of the program, what the prefill routed
-    here and the rows its experts multiplied for that (their ratio is
-    what the tiles waste)."""
+def routing_counters(cfg, stats, real: int, steps: int) -> Dict[str, int]:
+    """The routing part of the ``lm.*`` window counters of one execution
+    from its fetched ``stats``, for every family whose blocks are
+    `_moe`'s: the ``real`` rows' pairs (a padded row repeats the first and
+    is nobody's request; it routes as the first does, so it adds no hit)
+    and, over EVERY row of the program, what the prefill routed here and
+    the rows its experts multiplied for that (their ratio is what the
+    tiles waste)."""
     return {
         "lm.expert_pairs": real * steps * cfg.moe_layers
         * cfg.num_experts_per_tok,
@@ -871,3 +840,8 @@ def window_counters(cfg: MlaMoeConfig, stats, real: int, steps: int
             stats["expert_pairs_local_prefill"].sum()),
         "lm.expert_rows_computed_prefill": int(
             stats["expert_rows_computed_prefill"])}
+
+
+# the ``lm.*`` window counters of one execution (`registry.LMFamily`): this
+# family counts its routing and nothing else
+window_counters = routing_counters

@@ -1285,7 +1285,8 @@ EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
 # a served window compiles nothing).  An execution is padded to the next
 # count with copies of its first row; the last count also bounds what is
 # kept for requests still in the queue (server/lm_handover.py): three
-# results.  Argued per family (`LMFamily.row_counts`); all five take these.
+# results.  Argued per family (the comments in `LM_FAMILIES`); all five
+# take these.
 LM_ROW_COUNTS = (1, 4)
 
 
@@ -1297,7 +1298,11 @@ class LMFamily:
     ``CONFIGS`` (``full`` and ``tiny``), ``param_count``,
     ``seeded_params(cfg, seed)``, ``load_checkpoint(path, cfg)``,
     ``make_program(cfg, new_tokens)`` (the jitted ``lm_generate`` ->
-    ids, logits, ``aux`` arrays per position, ``stats`` to count from),
+    ids, logits, ``aux`` arrays per position, ``stats`` to count from:
+    `lm_decode.make_program` around the family's ``generate``, which is
+    its ``prefill`` and ``step`` closures, its own state and ONE call of
+    `lm_decode.generate`; the loop, the sampler and the prefix writer
+    are there, not in the family),
     ``kv_cache_bytes(cfg, rows, positions)`` (the state indexed by
     POSITION: keys and values, a latent), ``window_counters(cfg, stats,
     real_rows, steps)`` and ``few_rows_here`` (`looplm`'s); where it
@@ -1309,14 +1314,15 @@ class LMFamily:
     where the state behind a prompt's first ids can stand for them
     (nothing in it depends on where in the buffer a row lies),
     ``make_prefix_program(cfg)`` (the jitted ``lm_prefix_state``: ids
-    ``[K]`` -> the snapshot, which ``lm_generate`` then takes as a sixth
-    argument with the ids behind the prefix as its prompt) and
-    ``prefix_bytes(cfg, K)``.
+    ``[K]`` -> the snapshot, a dict whose ``keys`` are ``[L, K, ...]``
+    (`lm_decode.prefix_length`), which ``lm_generate`` then takes as a
+    sixth argument with the ids behind the prefix as its prompt: the
+    family's ``from_prefix`` hook writes it at each row's own offset
+    with `lm_decode.write_at_offsets`) and ``prefix_bytes(cfg, K)``.
     Its config gives ``vocab_size`` and ``layer_applications`` (per
     token)."""
     module: str
     names: Tuple[str, ...]          # what a model name of it contains
-    row_counts: Tuple[int, ...]
     what: str
 
     def load(self):
@@ -1339,7 +1345,7 @@ LM_FAMILIES = {
     # for a third program at set-up: about 2.17 s at 2 rows (PERF.md
     # section 6, PR 28).
     "ouro": LMFamily(
-        "looplm", ("ouro",), LM_ROW_COUNTS,
+        "looplm", ("ouro",),
         "Ouro-2.6B: a dense looped decoder, a per-head KV cache with a "
         "slot per loop and layer"),
     # The latent cache is 5.8 KB a position a row (0.7 MB a row of 128
@@ -1352,7 +1358,7 @@ LM_FAMILIES = {
     # local experts a layer at 4 rows) where a dense model's bytes do
     # not.  Rows past 4 cost 0.7 MB each and are ROADMAP B7's to measure.
     "pangu": LMFamily(
-        "mla_moe", ("pangu",), LM_ROW_COUNTS,
+        "mla_moe", ("pangu",),
         "openPangu-Ultra-MoE-718B, one chip's share: latent attention "
         "(MLA) with a latent cache, 16 of 256 routed experts held"),
     # A row's cache is 2.3 MB at 576 positions (four rings of 128 slots and
@@ -1368,7 +1374,7 @@ LM_FAMILIES = {
     # past 4 would buy the decode's shared stream with prefill seconds
     # that nobody shares.  ROADMAP B7's to measure.
     "exaone": LMFamily(
-        "swa_moe", ("exaone",), LM_ROW_COUNTS,
+        "swa_moe", ("exaone",),
         "K-EXAONE-236B-A23B, one chip's share: window and full attention "
         "layers in one stack (a 128-slot ring beside a full cache, GQA "
         "64/8), 16 of 128 routed experts held"),
@@ -1389,7 +1395,7 @@ LM_FAMILIES = {
     # the state behind them and prefill what follows: 97 positions a row
     # in the cell.  The argument stands for prompts that share nothing.)
     "granite": LMFamily(
-        "ssm_hybrid", ("granite",), LM_ROW_COUNTS,
+        "ssm_hybrid", ("granite",),
         "granite-4.0-h-micro, whole: Mamba-2 state-space layers with an "
         "attention layer every ten (a recurrent state beside a key-value "
         "cache), a tied embedding"),
@@ -1408,7 +1414,7 @@ LM_FAMILIES = {
     # where a dense model's do not.  Why not fewer: one weight stream
     # (0.9 GB of non-expert weights and the head a step) serves every row.
     "keye": LMFamily(
-        "dsa_moe", ("keye",), LM_ROW_COUNTS,
+        "dsa_moe", ("keye",),
         "Keye-VL-2.0-30B-A3B's language model, one pipeline stage of 8: a "
         "learned index picks 2,048 keys a query (an index-key cache beside "
         "the key-value cache, GQA 32/4), all 128 routed experts held; the "
@@ -1467,13 +1473,13 @@ class LMRow:
 class LanguageModel:
     """A decoder of one of `LM_FAMILIES`, its tokenizer and its jitted
     program."""
+    row_counts = LM_ROW_COUNTS
 
     def __init__(self, name: str, cfg: Any, params: Any, tokenizer: Any,
                  family: str = "ouro"):
         self.name, self.cfg, self.params = name, cfg, params
         self.tokenizer = tokenizer
         self.family = family
-        self.row_counts = LM_FAMILIES[family].row_counts
         self._arch = LM_FAMILIES[family].load()
         # (new tokens, prompt positions, of them a snapshot's) -> {rows:
         # compiled lm_generate}
@@ -1580,7 +1586,8 @@ class LanguageModel:
         positions, compiled for every count of ``row_counts`` at once;
         with a ``snapshot``, for rows that start from one of its length
         and prefill the positions behind it."""
-        held = snapshot[0]["keys"].shape[1] if snapshot else 0
+        from comfyui_distributed_tpu.models import lm_decode
+        held = lm_decode.prefix_length(snapshot[0]) if snapshot else 0
         with self._lock:
             programs = self._programs.get((n, prompt_tokens, held))
             if programs is None:

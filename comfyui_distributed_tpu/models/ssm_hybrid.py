@@ -78,6 +78,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from comfyui_distributed_tpu.models import lm_decode
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, visible_keys
 from comfyui_distributed_tpu.models.looplm import _dense, _rms_norm, \
@@ -490,7 +491,7 @@ def _attention(cfg: GraniteHybridConfig, lp, u, index, first, kc, vc, l,
         with jax.named_scope("kv_cache"):
             if prefix and not decode:
                 own = index[None, :] >= first[:, None] + prefix
-                k, v = (_own_entries(own, t, c, l, index[0])
+                k, v = (lm_decode.own_entries(own, t, c, l, index[0])
                         for c, t in ((kc, k), (vc, v)))
             kc, vc = (jax.lax.dynamic_update_slice(
                 c, t[None].astype(c.dtype), (l, 0, index[0], 0, 0))
@@ -509,16 +510,6 @@ def _attention(cfg: GraniteHybridConfig, lp, u, index, first, kc, vc, l,
         a = _attend(q, k, v, index, scale=cfg.attention_multiplier, **mask)
         with jax.named_scope("o_proj"):
             return _dense(a, lp["o_proj"], cfg), kc, vc, seen
-
-
-def _own_entries(own, new, cache, l, at):
-    """``new [B, N, G, D]``, to be written into layer ``l`` of ``cache``
-    at index ``at``, with what the cache HOLDS there wherever a position
-    is not a row's ``own [B, N]``: behind a prefix a row's padded
-    positions lie where the end of its prefix's keys stands (padding |
-    prefix | own ids)."""
-    held = jax.lax.dynamic_slice(cache, (l, 0, at, 0, 0), (1, *new.shape))[0]
-    return jnp.where(own[..., None, None], new, held.astype(new.dtype))
 
 
 def _mlp(cfg: GraniteHybridConfig, lp, u):
@@ -671,7 +662,6 @@ def from_prefix(state, prefix, first):
     values written at row ``b``'s own offset ``first[b]``, directly in
     front of where that row's suffix will be written (the padding lies in
     front of both, so the mask stays ``kv_start = first`` with no hole)."""
-    B = state["ssm"].shape[1]
     new = {}
     for name, scope in (("ssm", "ssm_state"), ("conv", "conv_state")):
         with jax.named_scope(scope):
@@ -680,12 +670,8 @@ def from_prefix(state, prefix, first):
             ).astype(state[name].dtype)
     with jax.named_scope("kv_cache"):
         for name in ("keys", "values"):
-            cache = state[name]
-            for b in range(B):
-                cache = jax.lax.dynamic_update_slice(
-                    cache, prefix[name][:, None].astype(cache.dtype),
-                    (0, b, first[b], 0, 0))
-            new[name] = cache
+            new[name] = lm_decode.write_at_offsets(state[name], prefix[name],
+                                                   first)
     return new
 
 
@@ -703,7 +689,7 @@ def prefill(cfg: GraniteHybridConfig, params, prompt_ids, prompt_len,
     prefix | row's own ids``, its last id at ``K + S - 1`` whatever the
     row."""
     B, S = prompt_ids.shape
-    K = 0 if prefix is None else prefix["keys"].shape[1]
+    K = lm_decode.prefix_length(prefix)
     with jax.named_scope("prefill"):
         # every row's last real id at the buffer's end
         first = S - prompt_len
@@ -731,40 +717,29 @@ def generate(cfg: GraniteHybridConfig, max_new_tokens: int, params,
     (what the decode steps' masks let a row's query see, a prefix's keys
     among them, summed over the attention layers)."""
     B, S = prompt_ids.shape
-    P = S + (0 if prefix is None else prefix["keys"].shape[1])
-    prompt_len, seed, temperature = (
-        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
-    keys = jax.vmap(jax.random.PRNGKey)(seed)
+    P = S + lm_decode.prefix_length(prefix)
+    prompt_len = jnp.broadcast_to(prompt_len, (B,))
+    first = None                    # `prefill`'s, which the steps read
 
-    def draw(key, logits, temperature, i):
-        drawn = jax.random.categorical(
-            jax.random.fold_in(key, i),
-            logits / jnp.maximum(temperature, 1e-6))
-        return jnp.where(temperature > 0, drawn,
-                         jnp.argmax(logits)).astype(jnp.int32)
-
-    with jax.named_scope("GraniteMoeHybrid"):
+    def start():
+        nonlocal first
         logits, state, first = prefill(cfg, params, prompt_ids, prompt_len,
                                        P + max_new_tokens, prefix)
+        with jax.named_scope("prefill"):
+            return logits, (), state, (jnp.zeros((B,), jnp.int32),), ()
 
-        def step(carry, i):
-            logits, state, seen = carry
-            with jax.named_scope("sample"):
-                token = jax.vmap(draw, (0, 0, 0, None))(
-                    keys, logits, temperature, i)
-            x, state, keys_now = _stack(
-                cfg, params, _embed(cfg, params, token[:, None]),
-                P + i[None], first, state, decode=True)
-            return (_head(cfg, params, x)[:, 0], state, seen + keys_now), \
-                (token, logits)
+    def step(token, i, state):
+        x, state, seen = _stack(
+            cfg, params, _embed(cfg, params, token[:, None]), P + i[None],
+            first, state, decode=True)
+        return _head(cfg, params, x)[:, 0], (), state, (seen,)
 
-        with jax.named_scope("decode"):
-            (_, _, seen), (tokens, logits) = jax.lax.scan(
-                step, (logits, state, jnp.zeros((B,), jnp.int32)),
-                jnp.arange(max_new_tokens))
+    tokens, logits, _, (seen,), _ = lm_decode.generate(
+        "GraniteMoeHybrid", B, start, step, max_new_tokens, seed,
+        temperature)
     Lm = cfg.layers_of(MAMBA)
     chunks = -(-S // min(cfg.mamba_chunk_size, S))
-    return tokens.swapaxes(0, 1), logits.swapaxes(0, 1), {
+    return tokens, logits, {
         "prefill_positions": jnp.int32(B * S),
         "scan_chunks": jnp.int32(B * Lm * chunks),
         "state_steps": jnp.int32(B * Lm * max_new_tokens),
@@ -778,14 +753,11 @@ def make_program(cfg: GraniteHybridConfig, max_new_tokens: int):
     beside the logits).  With a sixth argument, `make_prefix_program`'s
     snapshot, ``prompt_ids`` holds what follows the prefix."""
 
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature,
-                    prefix=None):
-        tokens, logits, stats = generate(cfg, max_new_tokens, params,
-                                         prompt_ids, prompt_len, seed,
-                                         temperature, prefix)
+    def served(*args):
+        tokens, logits, stats = generate(cfg, max_new_tokens, *args)
         return tokens, logits, {}, stats
 
-    return jax.jit(lm_generate)
+    return lm_decode.make_program(served)
 
 
 def window_counters(cfg: GraniteHybridConfig, stats, real: int, steps: int

@@ -65,18 +65,21 @@ moe_layers/self_attn/q_proj`` ...), read by ``utils/trace.KERNEL_CLASSES``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from comfyui_distributed_tpu.models import lm_decode
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, visible_keys, xla_attention
-from comfyui_distributed_tpu.models.looplm import Stacked, _dense, _rms_norm, \
-    _rope, _sandwich, dense_each, few_rows_here, scan_layers  # noqa: F401
+from comfyui_distributed_tpu.models.looplm import _dense, _embed, _head, \
+    _rms_norm, _rope, _sandwich, dense_each, few_rows_here, \
+    scan_layers  # noqa: F401
 from comfyui_distributed_tpu.models.mla_moe import _gated_mlp, _moe, \
-    count_values, seeded_tree
+    count_values, routing_counters, seeded_tree
 from comfyui_distributed_tpu.parallel import sharding as shd
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -425,16 +428,6 @@ def _stack(cfg: ExaoneMoeConfig, params, x, index, first, caches,
         jnp.concatenate(c).sum(axis=0) for c in zip(*counts)), seen
 
 
-def _embed(params, ids):
-    with jax.named_scope("embed_tokens"):
-        return params["embed_tokens"][ids].astype(jnp.float32)
-
-
-def _head(cfg: ExaoneMoeConfig, params, x):
-    with jax.named_scope("lm_head"):
-        return _dense(x, Stacked(params["lm_head"]), cfg)
-
-
 def empty_cache(cfg: ExaoneMoeConfig, batch: int, length: int):
     """``{kind: (keys, values)}``: a ring of ``sliding_window`` slots for
     the sliding layers, ``length`` positions for the full ones."""
@@ -477,54 +470,36 @@ def generate(cfg: ExaoneMoeConfig, max_new_tokens: int, params, prompt_ids,
     ``expert_rows_computed_prefill`` (the rows the routed experts
     multiplied for them); ``expert_pairs_dropped`` over both (0)."""
     B, P = prompt_ids.shape
-    prompt_len, seed, temperature = (
-        jnp.broadcast_to(a, (B,)) for a in (prompt_len, seed, temperature))
-    first = P - prompt_len
-    keys = jax.vmap(jax.random.PRNGKey)(seed)
+    first = P - jnp.broadcast_to(prompt_len, (B,))
 
-    def draw(key, logits, temperature, i):
-        drawn = jax.random.categorical(
-            jax.random.fold_in(key, i),
-            logits / jnp.maximum(temperature, 1e-6))
-        return jnp.where(temperature > 0, drawn,
-                         jnp.argmax(logits)).astype(jnp.int32)
-
-    with jax.named_scope("ExaoneMoe"):
+    def prefill():
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
-            prompt_ids = jax.vmap(jnp.roll)(prompt_ids, first)
+            ids = jax.vmap(jnp.roll)(prompt_ids, first)
             x, caches, routed, counts, _ = _stack(
-                cfg, params, _embed(params, prompt_ids), jnp.arange(P),
-                first, empty_cache(cfg, B, P + max_new_tokens),
-                decode=False)
-            prefill_pairs, _, dropped, prefill_rows = counts
+                cfg, params, _embed(params, ids), jnp.arange(P), first,
+                empty_cache(cfg, B, P + max_new_tokens), decode=False)
             logits = _head(cfg, params, x[:, P - 1:])[:, 0]
+            rows = jnp.zeros((B,), jnp.int32)
+            return (logits, tuple(r[:, P - 1] for r in routed), caches,
+                    (rows, jnp.int32(0), counts[2], rows, rows),
+                    (routed[1], counts))
 
-        def step(carry, i):
-            logits, routed, caches, counts = carry
-            with jax.named_scope("sample"):
-                token = jax.vmap(draw, (0, 0, 0, None))(
-                    keys, logits, temperature, i)
-            x, caches, nxt_routed, (pairs, hits, dropped, _), seen = _stack(
-                cfg, params, _embed(params, token[:, None]), P + i[None],
-                first, caches, decode=True)
-            nxt = _head(cfg, params, x)[:, 0]
-            now = (pairs, hits, dropped, seen[SLIDING], seen[FULL])
-            return (nxt, tuple(r[:, 0] for r in nxt_routed), caches,
-                    tuple(a + b for a, b in zip(counts, now))), \
-                (token, logits, *routed)
+    def step(token, i, caches):
+        x, caches, routed, (pairs, hits, dropped, _), seen = _stack(
+            cfg, params, _embed(params, token[:, None]), P + i[None], first,
+            caches, decode=True)
+        return (_head(cfg, params, x)[:, 0], tuple(r[:, 0] for r in routed),
+                caches, (pairs, hits, dropped, seen[SLIDING], seen[FULL]))
 
-        rows, zero = jnp.zeros((B,), jnp.int32), jnp.int32(0)
-        with jax.named_scope("decode"):
-            (*_, counts), (tokens, logits, scores, choices) = jax.lax.scan(
-                step, (logits, tuple(r[:, P - 1] for r in routed), caches,
-                       (rows, zero, dropped, rows, rows)),
-                jnp.arange(max_new_tokens))
-    pairs, hits, dropped, window_keys, full_keys = counts
-    return (tokens.swapaxes(0, 1), logits.swapaxes(0, 1),
-            {"router_scores": scores.swapaxes(0, 1),
-             "expert_choices": choices.swapaxes(0, 1),
-             "prompt_choices": routed[1]},
+    tokens, logits, (scores, choices), \
+        (pairs, hits, dropped, window_keys, full_keys), \
+        (prompt_choices, (prefill_pairs, _, _, prefill_rows)) = \
+        lm_decode.generate("ExaoneMoe", B, prefill, step, max_new_tokens,
+                           seed, temperature)
+    return (tokens, logits,
+            {"router_scores": scores, "expert_choices": choices,
+             "prompt_choices": prompt_choices},
             {"expert_pairs_local": pairs, "expert_hits": hits,
              "expert_pairs_dropped": dropped,
              "expert_pairs_local_prefill": prefill_pairs,
@@ -536,35 +511,18 @@ def generate(cfg: ExaoneMoeConfig, max_new_tokens: int, params, prompt_ids,
 def make_program(cfg: ExaoneMoeConfig, max_new_tokens: int):
     """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
     device trace) like every language model's."""
-
-    def lm_generate(params, prompt_ids, prompt_len, seed, temperature):
-        return generate(cfg, max_new_tokens, params, prompt_ids, prompt_len,
-                        seed, temperature)
-
-    return jax.jit(lm_generate)
+    return lm_decode.make_program(
+        functools.partial(generate, cfg, max_new_tokens))
 
 
 def window_counters(cfg: ExaoneMoeConfig, stats, real: int, steps: int
                     ) -> Dict[str, int]:
     """The ``lm.*`` window counters of one execution from its fetched
-    ``stats``: the routing counters as `mla_moe.window_counters`' (the
-    ``real`` rows'; a padded row repeats the first and is nobody's), the
-    keys the real rows' decode steps attended to by kind of layer, and
-    the local pairs of the prefill over EVERY row of the program (what
-    it computed, beside its rows x prompt positions) with the rows its
-    experts multiplied for them."""
-    def real_rows(name):
-        return int(stats[name][:real].sum())
-
+    ``stats``: `mla_moe.routing_counters`' and the keys the ``real`` rows'
+    decode steps attended to by kind of layer (a padded row repeats the
+    first and is nobody's)."""
     return {
-        "lm.expert_pairs": real * steps * cfg.moe_layers
-        * cfg.num_experts_per_tok,
-        "lm.expert_pairs_local": real_rows("expert_pairs_local"),
-        "lm.expert_hits": int(stats["expert_hits"]),
-        "lm.expert_pairs_dropped": int(stats["expert_pairs_dropped"]),
-        "lm.expert_pairs_local_prefill": int(
-            stats["expert_pairs_local_prefill"].sum()),
-        "lm.expert_rows_computed_prefill": int(
-            stats["expert_rows_computed_prefill"]),
-        "lm.keys_attended_window": real_rows("keys_attended_window"),
-        "lm.keys_attended_full": real_rows("keys_attended_full")}
+        **routing_counters(cfg, stats, real, steps),
+        **{f"lm.keys_attended_{kind}": int(
+            stats[f"keys_attended_{kind}"][:real].sum())
+           for kind in ("window", "full")}}

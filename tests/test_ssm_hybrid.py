@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import looplm, registry, ssm_hybrid
+from comfyui_distributed_tpu.models import lm_decode, looplm, registry, \
+    ssm_hybrid
 from comfyui_distributed_tpu.models.ssm_hybrid import ATTENTION, MAMBA
 from comfyui_distributed_tpu.utils import trace
 
@@ -525,7 +526,7 @@ def _keys_at_the_buffers_front(monkeypatch, params):
 def _padding_over_the_prefixs_end(monkeypatch, params):
     """Every position of the suffix buffer writes its keys, a row's
     padded ones too: over the last keys of its prefix."""
-    monkeypatch.setattr(ssm_hybrid, "_own_entries",
+    monkeypatch.setattr(lm_decode, "own_entries",
                         lambda own, new, cache, l, at: new)
     return TINY, params
 
