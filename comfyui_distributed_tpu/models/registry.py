@@ -1268,7 +1268,7 @@ def clear_pipeline_cache() -> None:
 
 
 # --- language models (models/looplm.py, models/mla_moe.py, models/swa_moe.py,
-# models/ssm_hybrid.py, models/dsa_moe.py) --------------------------------------
+# models/ssm_hybrid.py, models/dsa_moe.py, models/sambay.py) --------------------
 #
 # A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
 # model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
@@ -1285,7 +1285,7 @@ EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
 # a served window compiles nothing).  An execution is padded to the next
 # count with copies of its first row; the last count also bounds what is
 # kept for requests still in the queue (server/lm_handover.py): three
-# results.  Argued per family (the comments in `LM_FAMILIES`); all five
+# results.  Argued per family (the comments in `LM_FAMILIES`); all six
 # take these.
 LM_ROW_COUNTS = (1, 4)
 
@@ -1310,7 +1310,8 @@ class LMFamily:
     of the positions, ``state_bytes(cfg, rows)``; where its state is of
     more than one geometry or kind, ``kv_cache_bytes_by_kind`` -> the
     parts by name (a ring and a full cache; recurrent and positional;
-    keys and values and the index keys that choose among them);
+    keys and values and the index keys that choose among them;
+    recurrent, rings and the one cache that several layers read);
     where the state behind a prompt's first ids can stand for them
     (nothing in it depends on where in the buffer a row lies),
     ``make_prefix_program(cfg)`` (the jitted ``lm_prefix_state``: ids
@@ -1419,6 +1420,27 @@ LM_FAMILIES = {
         "learned index picks 2,048 keys a query (an index-key cache beside "
         "the key-value cache, GQA 32/4), all 128 routed experts held; the "
         "vision tower is not"),
+    # A row's state at 8,256 positions is 66.5 MB (the ONE cache that
+    # eight layers read, 5,120 B a position: 42.3 MB; eight rings of 512
+    # slots: 21.0 MB; nine float32 states and tails: 3.2 MB) where 32
+    # layers of the same heads would hold 1.35 GB: 0.27 GB at 4 rows
+    # beside 7.71 GB resident and SD1.5, so memory would take dozens of
+    # rows.  What argues for 4 is what argued in the others: the rows are
+    # the requests waiting in one server's queue (four callers in the
+    # cell), every count is a program to compile at set-up, and the
+    # PREFILL's front half (17 layers over every one of a row's 8,192
+    # positions, the selective scan 8,192 sequential steps a layer
+    # whatever the rows) is compute- and latency-bound: a row adds its
+    # positions' FLOPs whole, while only the decode's 7.7 GB weight
+    # stream is shared.  A step also reads the one cache once for each of
+    # its eight readers: 0.34 GB a row at 8,200 positions, 1.34 GB at 4
+    # rows beside the weights' 7.7, at 16 rows two thirds of the step.
+    "phi4flash": LMFamily(
+        "sambay", ("phi-4-mini-flash", "phi4flash"),
+        "Phi-4-mini-flash-reasoning, whole: a decoder-hybrid-decoder, "
+        "Mamba-1 and window-512 differential attention in front (float32 "
+        "states, rings), ONE key-value cache and ONE state-space memory "
+        "shared by the 14 layers behind, a tied embedding"),
 }
 
 

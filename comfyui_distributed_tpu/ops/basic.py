@@ -1009,10 +1009,16 @@ class LanguageModelLoader(Op):
     share of openPangu-Ultra-MoE-718B: latent attention with a latent
     cache, routed experts), ``exaone`` (models/swa_moe.py, one chip's
     share of K-EXAONE-236B-A23B: window and full attention layers in one
-    stack, routed experts) or ``granite`` (models/ssm_hybrid.py,
+    stack, routed experts), ``granite`` (models/ssm_hybrid.py,
     granite-4.0-h-micro whole: Mamba-2 state-space layers with an
     attention layer every ten, a recurrent state beside a key-value
-    cache).  A name of none is refused.  The model's
+    cache), ``keye`` (models/dsa_moe.py, one pipeline stage of
+    Keye-VL-2.0-30B-A3B's language model: a learned index picks the
+    keys a query attends to, routed experts) or ``phi-4-mini-flash``
+    (models/sambay.py, Phi-4-mini-flash-reasoning whole: Mamba-1 and
+    window differential attention in front, ONE key-value cache and ONE
+    state-space memory shared by the layers behind).  A name of none is
+    refused.  The model's
     safetensors and ``tokenizer.json`` from the models dir if present;
     otherwise seeded weights made on the device and the hash tokenizer
     pair."""

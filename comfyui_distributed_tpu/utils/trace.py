@@ -230,7 +230,7 @@ OTHER = "other"
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
 _UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
 _MOE, _SWA, _SSM = "PanguUltraMoE", "ExaoneMoe", "GraniteMoeHybrid"
-_DSA = "KeyeVL2"
+_DSA, _SBY = "KeyeVL2", "Phi4Flash"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
     # the Mamba mixer's gated RMSNorm is published as ``mamba/norm``: ahead
@@ -348,10 +348,33 @@ KERNEL_CLASSES = (
     ("lm_head", _DSA, r"lm_head|sample"),
     ("embed", _DSA, r"embed_tokens"),
     ("lm_proj", _DSA, r"layers|prefill|decode|KeyeVL2"),
+    # the decoder-hybrid-decoder (models/sambay.py), under the same
+    # classes (``lm_ssm`` the convolution and the selective scan with its
+    # ``D`` skip and gate, ``lm_state`` the states' and tails' read and
+    # write, ``lm_attn`` a window or full layer's two maps a head pair),
+    # and two more.  ``lm_gmu`` is a ``gmu`` layer's gate with the memory
+    # (its two products are ``lm_proj``'s); ``lm_cross`` a ``cross``
+    # layer's attention over the cache another layer wrote, its read of
+    # that cache with it
+    ("lm_norm", _SBY, r"(?:input|post_attention|final)_layernorm|subln"),
+    ("lm_proj", _SBY, r"in_proj|x_proj|dt_proj|out_proj|Wqkv"),
+    ("lm_cache", _SBY, r"kv_cache"),    # the rings and the one cache
+    ("lm_state", _SBY, r"ssm_state|conv_state"),
+    ("lm_ssm", _SBY, r"conv1d|selective_scan"),
+    ("lm_gmu", _SBY, r"gate"),
+    ("lm_cross", _SBY, r"inner_cross_attn"),
+    ("lm_attn", _SBY, r"inner_attn"),
+    ("lm_mlp", _SBY, r"mlp|fc1|fc2"),
+    ("lm_head", _SBY, r"lm_head|sample"),
+    ("embed", _SBY, r"embed_tokens"),
+    # a mixer's and a layer's own glue (splits, residual adds), the six
+    # kinds of layer
+    ("lm_proj", _SBY, r"attn|layers|mamba|swa|memory|full|gmu|cross"
+                      r"|prefill|decode|Phi4Flash"),
 )
 # the outer scopes a program may put directly under its model's: where it
 # does, a trace summary gives its seconds by PHASE beside its seconds by
-# class (the five language models' ``generate`` do; the denoise, VAE and
+# class (the six language models' ``generate`` do; the denoise, VAE and
 # text programs do not and have no phases).  A program that the
 # persistent compile cache LOADS carries the names of the tree that
 # compiled it (JAX keys a program without a Pallas kernel with its debug
@@ -364,7 +387,7 @@ SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
 _MODEL_OF = re.compile(
     r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE|ExaoneMoe"
-    r"|GraniteMoeHybrid|KeyeVL2)(?:\.\w+)?$")
+    r"|GraniteMoeHybrid|KeyeVL2|Phi4Flash)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 

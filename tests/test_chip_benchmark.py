@@ -119,11 +119,22 @@ _KEYE_ONLY = {
                                      "scores and attention over a "
                                      "selection, from the same counters",
 }
+# (PR 46: the two shares of the decoder-hybrid-decoder)
+PHI4 = "phi4flash_expand_sd15_512_sat4"
+_PHI_ONLY = {
+    "lm_sambay_decode_hbm_roofline_pct": "the bytes of a decoder whose one "
+                                         "cache is read by eight layers and "
+                                         "whose states by nine, from "
+                                         "counters only its program has",
+    "lm_sambay_prefill_flops_util_pct": "the FLOPs of a prefill whose back "
+                                        "half runs on one position a row, "
+                                        "from the same counters",
+}
 NOT_IN_SAT4 = {
     "chip_busy_min_pct": _ONE_CHIP,
     "lm_moe_decode_hbm_roofline_pct": "the bytes of a decoder with routed "
                                       "experts: Ouro has none to count",
-    **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY,
+    **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY,
 }
 NOT_IN_PANGU4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -131,7 +142,7 @@ NOT_IN_PANGU4 = {
                                   "decoder's and would be false here: "
                                   "lm_moe_decode_hbm_roofline_pct stands "
                                   "in its place",
-    **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY,
+    **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY,
 }
 NOT_IN_EXAONE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -140,7 +151,7 @@ NOT_IN_EXAONE4 = {
                                       "kv_lora_rank, a latent cache's: "
                                       "lm_swa_moe_decode_hbm_roofline_pct "
                                       "stands in its place",
-    **_GRANITE_ONLY, **_KEYE_ONLY,
+    **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY,
 }
 NOT_IN_GRANITE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -149,7 +160,7 @@ NOT_IN_GRANITE4 = {
                                       "latent cache",
     **{name: "it has no routed expert, no ring and counts no local pairs: "
              "its own two stand in their place" for name in _EXAONE_ONLY},
-    **_KEYE_ONLY,
+    **_KEYE_ONLY, **_PHI_ONLY,
 }
 NOT_IN_KEYE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -158,7 +169,15 @@ NOT_IN_KEYE4 = {
     **{name: "it has no ring, and what a step reads of its cache is chosen "
              "by an index: its own two stand in their place"
        for name in _EXAONE_ONLY},
-    **_GRANITE_ONLY,
+    **_GRANITE_ONLY, **_PHI_ONLY,
+}
+NOT_IN_PHI4 = {
+    "chip_busy_min_pct": _ONE_CHIP,
+    "lm_decode_hbm_roofline_pct": NOT_IN_PANGU4["lm_decode_hbm_roofline_pct"],
+    "lm_moe_decode_hbm_roofline_pct": "it has no latent cache and no expert",
+    **{name: "its rings stand beside states and ONE cache that eight "
+             "layers read: its own two stand in their place"
+       for name in {**_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY}},
 }
 
 
@@ -251,9 +270,11 @@ def _exaone4_reports(m, cells):
             # (PR 40's cell stands behind it where the reader is
             # family-neutral)
             # (and PR 42's behind that, and behind the experts' reader)
+            # (and PR 46's behind the three)
             assert x["workloads"] in ([EXAONE4], [EXAONE4, GRANITE4],
                                       [EXAONE4, KEYE4],
-                                      [EXAONE4, GRANITE4, KEYE4]) \
+                                      [EXAONE4, GRANITE4, KEYE4],
+                                      [EXAONE4, GRANITE4, KEYE4, PHI4]) \
                 and x["layer"] == "Language model" \
                 and x["source"] == "device_trace"
     cfg = _config(exaone["config"])
@@ -284,8 +305,11 @@ def _granite4_reports(m, cells):
         "lm_prefill_experts_device_s_per_request"}
     for x in m["per_layer"]:
         if x["name"] in own:
-            assert x["workloads"] == [GRANITE4] and x["layer"] == \
-                "Language model" and x["source"] == "device_trace"
+            # (PR 46's cell stands behind it in the two state-space
+            # readers, which read a class both families have)
+            assert x["workloads"] in ([GRANITE4], [GRANITE4, PHI4]) \
+                and x["layer"] == "Language model" \
+                and x["source"] == "device_trace"
     cfg = _config(granite["config"])
     _same_graph_but(cfg, _config("k-exaone-236b-expand-sd15-512"),
                     {"20", "21"})
@@ -320,7 +344,7 @@ def _keye4_reports(m, cells):
         if x["name"] in _KEYE_ONLY or "_index_" in x["name"]:
             assert x["workloads"] == [KEYE4] and x["layer"] == \
                 "Language model" and x["source"] == "device_trace"
-    assert [x["name"] for x in m["per_layer"][-4:]] == [
+    assert [x["name"] for x in m["per_layer"][-8:-4]] == [
         "lm_index_device_s_per_request",
         "lm_prefill_index_device_s_per_request", *_KEYE_ONLY]
     cfg = _config(keye["config"])
@@ -333,9 +357,44 @@ def _keye4_reports(m, cells):
     assert (node["prompt_tokens"], node["max_new_tokens"],
             node["temperature"]) == (8192, 64, 0.0)
     assert len(node["instructions"].split()) == 8100
-    # 10 of at most 24 cells, 7 configurations, still one on four chips
-    assert len(m["workloads"]) == 10 and len(m["configs"]) == 7
-    assert m["workloads"][-1] == keye
+    # 10 of at most 24 cells with this one, 7 configurations
+    assert len(m["workloads"][:10]) == 10 and len(m["configs"][:7]) == 7
+    assert m["workloads"][9] == keye
+    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
+        ["sdxl_1024_fanout4"]
+
+
+def _phi4_reports(m, cells):
+    """The cell PR 46 appended: Keye's with a sixth language model in
+    front (whole: nothing reduced) behind the SAME 8192-id prompt; it
+    lists what Keye's and granite's both list, the two state-space
+    readers, and four readers of its own."""
+    phi = cells[PHI4]
+    assert phi["config"] == "phi-4-mini-flash-expand-sd15-512"
+    own = {*_PHI_ONLY, "lm_gmu_device_s_per_request",
+           "lm_cross_device_s_per_request"}
+    assert _listed(m, PHI4) == (_listed(m, KEYE4) & _listed(m, GRANITE4)) \
+        | own | {"lm_ssm_device_s_per_request",
+                 "lm_prefill_ssm_device_s_per_request"}
+    for x in m["per_layer"]:
+        if x["name"] in own:
+            assert x["workloads"] == [PHI4] and x["layer"] == \
+                "Language model" and x["source"] == "device_trace" \
+                and x["moves"] == "images_per_s"
+    assert [x["name"] for x in m["per_layer"][-4:]] == [
+        "lm_gmu_device_s_per_request", "lm_cross_device_s_per_request",
+        *_PHI_ONLY]
+    cfg = _config(phi["config"])
+    keye = _config("keye-vl-2.0-30b-a3b-expand-sd15-512")
+    _same_graph_but(cfg, keye, {"20"})
+    assert cfg["reduced"] == [] and cfg["graph"]["20"]["inputs"] == {
+        "model_name": "phi-4-mini-flash-reasoning.safetensors"}
+    # two families behind one prompt: the same instructions bit for bit
+    assert cfg["graph"]["21"] == keye["graph"]["21"]
+    assert len(cfg["graph"]["21"]["inputs"]["instructions"].split()) == 8100
+    # 11 of at most 24 cells, 8 configurations, still one on four chips
+    assert len(m["workloads"]) == 11 and len(m["configs"]) == 8
+    assert m["workloads"][-1] == phi
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
         ["sdxl_1024_fanout4"]
 
@@ -346,7 +405,8 @@ def _keye4_reports(m, cells):
     (EXAONE4, NOT_IN_EXAONE4, _exaone4_reports),
     (GRANITE4, NOT_IN_GRANITE4, _granite4_reports),
     (KEYE4, NOT_IN_KEYE4, _keye4_reports),
-], ids=[SAT4, PANGU4, EXAONE4, GRANITE4, KEYE4])
+    (PHI4, NOT_IN_PHI4, _phi4_reports),
+], ids=[SAT4, PANGU4, EXAONE4, GRANITE4, KEYE4, PHI4])
 def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
         cell, leaves_out, reports):
     m = _manifest()

@@ -218,25 +218,26 @@ def test_the_program_serves_what_the_configuration_states():
 def test_the_manifest_gained_one_configuration_one_cell_and_four_readers():
     m = manifest()
     (entry,) = [c for c in m["configs"] if c["name"] == CONFIG]
-    assert entry == m["configs"][-1]
+    assert entry == m["configs"][6]
     assert entry["reduced"] == ["num_hidden_layers"] == config()["reduced"]
     assert entry["file"] == f"benchmarks/chip/configs/{CONFIG}.json"
     assert entry["source"] == config()["source"]
     assert len(entry["why"]) <= 200
     (cell,) = [w for w in m["workloads"] if w["name"] == CELL]
-    assert cell == m["workloads"][-1]
+    assert cell == m["workloads"][9]
     assert {k: cell[k] for k in ("config", "traffic", "chips")} == {
         "config": CONFIG, "traffic": "closed4_unique", "chips": 1}
     assert len(cell["why"]) <= 200 and "8192-id prefill" in cell["why"]
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
         ["sdxl_1024_fanout4"]
-    assert len(m["workloads"]) == 10 and len(m["configs"]) == 7
+    # (what PR 46 added stands behind them)
+    assert len(m["workloads"]) >= 10 and len(m["configs"]) >= 7
     assert {x["name"] for x in m["end_to_end"]
             if CELL in x.get("workloads", [CELL])} == {
         "images_per_s", "tti_p50_s", "setup_s"}
     new = [x for x in m["per_layer"] if x["name"] in NEW_READERS]
     assert [x["name"] for x in new] == NEW_READERS == \
-        [x["name"] for x in m["per_layer"][-4:]]
+        [x["name"] for x in m["per_layer"][45:49]]
     for x in new:
         assert x["workloads"] == [CELL] and x["layer"] == "Language model" \
             and x["source"] == "device_trace"
@@ -273,8 +274,9 @@ def test_the_cell_is_appended_where_the_reader_is_family_neutral():
     for group in ("end_to_end", "per_layer"):
         for x in m[group]:
             cells = x.get("workloads", [])
-            if CELL in cells:
-                assert cells[-1] == CELL, x["name"]
+            if CELL in cells:       # only a later PR's cell behind it
+                assert cells[cells.index(CELL) + 1:] in (
+                    [], ["phi4flash_expand_sd15_512_sat4"]), x["name"]
     neutral = {"lm_device_s_per_request", "lm_decode_ms_per_token",
                "lm_share_of_busy_pct", "lm_mlp_device_s_per_request",
                "lm_attn_device_s_per_request",

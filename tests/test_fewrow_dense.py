@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from comfyui_distributed_tpu.models import dsa_moe, looplm, mla_moe, \
-    ssm_hybrid, swa_moe
+    sambay, ssm_hybrid, swa_moe
 from comfyui_distributed_tpu.ops.pallas import fewrow_dense as fd
 from comfyui_distributed_tpu.utils import trace
 
@@ -22,9 +22,10 @@ OURO, PANGU = looplm.OURO_2_6B, mla_moe.OPENPANGU_ULTRA_MOE_SHARE
 EXAONE = swa_moe.K_EXAONE_SHARE
 GRANITE = ssm_hybrid.GRANITE_4_0_H_MICRO
 KEYE = dsa_moe.KEYE_VL2_STAGE
+PHI = sambay.PHI_4_MINI_FLASH
 FAMILIES = {"ouro": (looplm, OURO), "pangu": (mla_moe, PANGU),
             "exaone": (swa_moe, EXAONE), "granite": (ssm_hybrid, GRANITE),
-            "keye": (dsa_moe, KEYE)}
+            "keye": (dsa_moe, KEYE), "phi4flash": (sambay, PHI)}
 
 # every product `_dense` makes with a resident leaf at the published
 # sizes: (family, name, K, N, leaves streamed by one call)
@@ -63,6 +64,15 @@ PRODUCTS = [
     ("keye", "k_proj+v_proj", 2048, 512, 2),
     ("keye", "o_proj", 4096, 2048, 1),
     ("keye", "indexer wq", 2048, 1024, 1),
+    # (PR 46; its head is the tied embedding, transposed; x_proj (192
+    # columns) and dt_proj (160 rows) stay with XLA)
+    ("phi4flash", "mamba in_proj", 2560, 10240, 1),
+    ("phi4flash", "mamba out_proj", 5120, 2560, 1),
+    ("phi4flash", "Wqkv", 2560, 5120, 1),
+    ("phi4flash", "cross Wqkv, out_proj", 2560, 2560, 1),
+    ("phi4flash", "gmu in_proj", 2560, 5120, 1),
+    ("phi4flash", "fc1", 2560, 20480, 1),
+    ("phi4flash", "fc2", 10240, 2560, 1),
 ]
 IDS = [f"{p[0]}-{p[1]}" for p in PRODUCTS]
 
@@ -90,6 +100,8 @@ def test_the_published_shapes_take_the_kernel_at_two_to_eight_rows(
 @pytest.mark.parametrize("k, n, why", [
     (7680, 576, "kv_a_proj_with_mqa: 576 is not a multiple of 128"),
     (2048, 64, "the indexer's one key: half a lane group"),
+    (5120, 192, "Mamba-1's x_proj: 192 is not a multiple of 128"),
+    (160, 5120, "Mamba-1's dt_proj: 160 rows"),
     (2048, 16, "the indexer's head weights"),
     (2048, 151936, "a head of 1187 x 128 columns, 1187 prime: the blocks "
                    "could only walk it a lane group at a time (256-byte "
@@ -454,7 +466,7 @@ def tiny_run(arch, cfg, rows, where, monkeypatch):
 
 
 @pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite",
-                                    "keye"])
+                                    "keye", "phi4flash"])
 def test_a_scan_over_the_index_gives_what_a_scan_over_the_slices_gives(
         family, monkeypatch):
     """With the platform read as a TPU's the 4-row decode walks the layer
@@ -493,7 +505,7 @@ def traced(family, rows, where, monkeypatch, sharding=None, positions=64):
 
 
 @pytest.mark.parametrize("family", ["ouro", "pangu", "exaone", "granite",
-                                    "keye"])
+                                    "keye", "phi4flash"])
 def test_the_one_row_program_is_untouched_by_the_rule(family, monkeypatch):
     """Its text as lowered with the platform read as a TPU's is, byte for
     byte, its text with the rule off; and the 4-row program's is not."""
@@ -614,6 +626,7 @@ def weights_of(family):
     ("exaone", set()),
     ("granite", set()),
     ("keye", set()),
+    ("phi4flash", set()),
 ])
 def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
         family, known, one_chip, no_compile_cache, monkeypatch):
@@ -674,7 +687,10 @@ def test_no_decode_body_of_the_four_row_program_materialises_a_weight(
                         "fewrow_dense_k_proj_v_proj",
                         "fewrow_dense_t"},
             "keye": {"q_proj", "o_proj", "indexer", "wq",
-                     "fewrow_dense_k_proj_v_proj"}}[family]
+                     "fewrow_dense_k_proj_v_proj"},
+            "phi4flash": {"in_proj", "out_proj", "Wqkv", "fc1", "fc2",
+                          "mamba", "swa", "memory", "full", "gmu", "cross",
+                          "lm_head", "fewrow_dense_t"}}[family]
     assert want <= segments, want - segments
 
 

@@ -1,6 +1,6 @@
 """The one decode driver and the one prefix writer (ISSUE 45,
 ``models/lm_decode.py``): the driver alone around a toy family defined
-here, the five families of `registry.LM_FAMILIES` reaching it, and the
+here, the six families of `registry.LM_FAMILIES` reaching it, and the
 prefix seam against numpy loops.  What each family's blocks compute is in
 its own test file; what is held here is what they no longer each hold."""
 
@@ -134,7 +134,7 @@ def test_the_scopes_are_the_drivers():
         "/Toy/decode/while/body/closed_call/sample/" in n for n in inside)
 
 
-# --- the five families reach it ------------------------------------------
+# --- the six families reach it - ------------------------------------------
 
 @pytest.fixture(params=list(registry.LM_FAMILIES))
 def family(request):

@@ -316,6 +316,8 @@ if "21" in g:
     g["21"]["inputs"].update(max_new_tokens=4, prompt_tokens=32)
     g["21"]["inputs"].update(json.loads(os.environ.get("PROBE_GENERATE",
                                                        "{}")))
+    g["20"]["inputs"].update(json.loads(os.environ.get("PROBE_LOADER",
+                                                       "{}")))
 rt = get_runtime()
 res = WorkflowExecutor(OpContext(runtime=rt,
                                  output_dir=tempfile.mkdtemp())).execute(g)
@@ -458,6 +460,43 @@ def test_the_system_prompt_graph_runs_with_the_fifth_family(mesh, devices,
     assert counters["lm.expert_pairs_local"] == counters["lm.expert_pairs"] \
         == 4 * 3 * 2
     assert counters["lm.expert_pairs_dropped"] == 0
+
+
+@pytest.mark.parametrize("mesh, devices, images", [
+    ("data=1", 1, 1), ("data=2,tensor=2", 4, 2)])
+def test_the_system_prompt_graph_runs_with_the_sixth_family(mesh, devices,
+                                                            images, tmp_path):
+    """The same workflow with the loader's ``model_name`` changed (PR 46):
+    a model of the decoder-hybrid-decoder family (states and rings in
+    front, ONE cache and ONE memory shared by the layers behind; its
+    prefill runs the back half for the last position only) behind the
+    same nodes, on one device and under a mesh: the same expansion, no
+    family-specific line in the nodes or the executor."""
+    name = "phi-4-mini-flash-reasoning.safetensors"
+    got = probe("prompt-expand-sysprompt-txt2img.json", tmp_path, devices,
+                DTPU_MESH_SHAPE=mesh, DTPU_TP_MIN_SHARD_ELEMENTS="64",
+                PROBE_LOADER=json.dumps({"model_name": name}))
+    assert got["images"] == images
+    assert got["lm_resident"] == [f"lm:{name}:"]
+    assert got["text"].startswith("a lighthouse on a cliff at dawn, ")
+    assert len(got["text"].split()) == 7 + 4
+    # in the programs of both row counts (a call site a scan body): the
+    # window layers' chunk of the prefill (banded) and their ring in a
+    # decode step; the full layer's and the cross layers' one query over
+    # the cache, in the prefill and in a decode step; no causal square
+    assert {k: got["paths"].get(k, 0) for k in (
+        "xla_banded", "xla_ring", "xla_decode", "xla_causal")} == {
+        "xla_banded": 2, "xla_ring": 2, "xla_decode": 8, "xla_causal": 0}
+    # 32 prompt ids, all real, walked as 36 positions in 6 chunks of 6;
+    # the back half on ONE position; 4 steps, 3 Mamba layers, a window of
+    # 8 in two layers, one cache read by two
+    counters = got["counters"]
+    assert counters["lm.prefill_positions"] == 36
+    assert counters["lm.cross_positions"] == 1
+    assert counters["lm.scan_chunks"] == 3 * 6
+    assert counters["lm.state_steps"] == 3 * 4
+    assert counters["lm.keys_attended_ring"] == 2 * 4 * 8
+    assert counters["lm.keys_attended_full"] == 2 * (33 + 34 + 35 + 36)
 
 
 @pytest.fixture(scope="module")

@@ -43,6 +43,11 @@ SSM_CLASSES = LM_CLASSES | {"lm_ssm", "lm_state"}
 # the decoder with a learned key selection (PR 42): what the selection
 # adds to an attention
 DSA_CLASSES = MOE_CLASSES | {"lm_index"}
+# the decoder-hybrid-decoder's (PR 46; tests/test_sambay.py holds its
+# rows): the state-space hybrid's and two for the layers that own no
+# state: a gate with another layer's memory, an attention over another
+# layer's cache
+SAMBAY_CLASSES = SSM_CLASSES | {"lm_gmu", "lm_cross"}
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
@@ -65,7 +70,7 @@ def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
     classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
     assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
         | LM_CLASSES | MOE_CLASSES | SSM_CLASSES | DSA_CLASSES \
-        | {"vae_attn"}
+        | SAMBAY_CLASSES | {"vae_attn"}
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; from comfyui_distributed_tpu.utils.trace import "
