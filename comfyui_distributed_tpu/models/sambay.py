@@ -70,6 +70,16 @@ of the published heads).
 mixer's input is zeroed at a row's padded positions and ``dt`` forced to
 0 there; the attention masks hide padded keys.
 
+**A prefix shared between requests.**  What a prompt's first ``K`` ids
+leave behind is one state and tail a Mamba layer, the last ``W`` keys and
+values a window layer and ``K`` of the one cache: the SNAPSHOT
+(`make_prefix_program`), one row's and with no axis of rows.  Rows whose
+prompts start with those ids start from it and the front walks what
+follows them alone (`prefill`'s ``prefix``).  Legal because nothing here
+reads a position's index: no positional encoding, and every mask counts
+from a row's first real id, so the snapshot stands wherever a row's own
+offset puts it.
+
 Precision: weights, caches, tails and matmul operands in ``cfg.dtype``;
 the residual stream, every norm, the softmax, the logits, ``dt``, every
 ``exp`` of a decay and the recurrent state in float32.
@@ -91,7 +101,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from comfyui_distributed_tpu.models import lm_decode
+from comfyui_distributed_tpu.models import lm_decode, ssm_hybrid
 from comfyui_distributed_tpu.models.layers import ATTENTION_PATHS, \
     attention_path, visible_keys
 from comfyui_distributed_tpu.models.looplm import Stacked, _dense, \
@@ -373,7 +383,9 @@ def _mamba(cfg: Phi4FlashConfig, lp, v, real, s, tail):
     these positions): its output, the MEMORY ``y`` (the scan's output
     with the ``D`` skip, before the gate), the new state and tail.
     ``real [B, Q]`` says which positions of a prefill are a row's own
-    (None: a decode step, every row's)."""
+    (None: a decode step, every row's): what lies in front of them is
+    padding, and the tail stands directly in front of a row's FIRST own
+    position (a chunk in which a row has not begun hands its tail on)."""
     C, N, R = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
     f32 = jnp.float32
     with jax.named_scope("attn"):
@@ -383,8 +395,9 @@ def _mamba(cfg: Phi4FlashConfig, lp, v, real, s, tail):
             uz = _dense(v, lp["in_proj"], cfg).astype(cfg.dtype)
         u, z = uz[..., :C], uz[..., C:]
         with jax.named_scope("conv1d"):
-            u, tail = causal_conv(u, matrix(lp["conv1d_weight"]),
-                                  lp["conv1d_bias"], tail)
+            u, tail = causal_conv(
+                u, matrix(lp["conv1d_weight"]), lp["conv1d_bias"], tail,
+                None if real is None else jnp.sum(~real, axis=1))
             u = jax.nn.silu(u)
         with jax.named_scope("x_proj"):
             rbc = _dense(u, lp["x_proj"], cfg)
@@ -475,17 +488,41 @@ def _differential(cfg: Phi4FlashConfig, lp, q, k, v, l, q_positions,
         return _biased(o.reshape(B, Q, -1), lp, "out_proj", cfg)
 
 
-def _window(cfg: Phi4FlashConfig, lp, u, l, at, first, held):
+def _behind_prefix(held, last, slot):
+    """``held [B, M, ...]`` with, wherever ``slot [B, M]`` names one of
+    the ``W`` slots of ``last [W, ...]`` (a prefix's last ``W`` entries in
+    position order), that entry: a row's prefix stands in front of its own
+    ids, and those do not begin at the same index in every row."""
+    W = last.shape[0]
+    inside = (slot >= 0) & (slot < W)
+    return jnp.where(inside[..., None, None],
+                     last[jnp.clip(slot, 0, W - 1)].astype(held.dtype), held)
+
+
+def _window(cfg: Phi4FlashConfig, lp, u, l, at, first, held, prefix=None):
     """A window layer's mixer over one CHUNK of a prefill: ``u [B, Q, d]``
     at the positions ``at [Q]`` against ``held`` (the ``W`` keys and
     values in front of the chunk, ``(k, v)`` each ``[B, W, G / 2, 2 D]``)
-    and its own.  Returns the output and the last ``W`` of both."""
+    and its own.  Behind a shared prefix, ``prefix`` is the layer's keys
+    and values of the prefix's last ``W`` positions ``[W, G / 2, 2 D]``
+    and ``own [B]``, the position at which row ``b``'s own ids begin: they
+    stand at ``own[b] - W .. own[b] - 1``, over the zeros in front of the
+    first chunk and over what the chunk made of a row's padding (a row's
+    own query at ``p`` sees no key in front of ``p - W + 1``, so ``W`` of
+    them are all it can ask for).  Returns the output and the last ``W``
+    of both."""
     W = cfg.sliding_window
     with jax.named_scope("attn"):
         q, k, v = _qkv(cfg, lp, u)
         with jax.named_scope("kv_cache"):
             k, v = (jnp.concatenate([h, t], axis=1)
                     for h, t in zip(held, (k, v)))
+            if prefix is not None:
+                *last, own = prefix
+                # entry n stands at position at[0] - W + n
+                slot = at[0] + jnp.arange(k.shape[1]) - own[:, None]
+                k, v = (_behind_prefix(t, p, slot)
+                        for t, p in zip((k, v), last))
         out = _differential(
             cfg, lp, q, k, v, l, at, kv_start=first, window=W,
             kv_positions=at[0] - W + jnp.arange(k.shape[1]))
@@ -541,11 +578,12 @@ def _unchunked(a):
     return a.swapaxes(0, 1).reshape(B, c * Q, *a.shape[3:])
 
 
-def _mamba_prefill(cfg: Phi4FlashConfig, lp, x, real):
-    """A Mamba layer over a whole prefill ``x [B, S, d]``, chunk by chunk
-    from an empty state: the stream behind it, the memory of each row's
-    LAST position ``[B, 1, C]``, the state and the tail behind it."""
-    B, Q = x.shape[0], cfg.prefill_chunk
+def _mamba_prefill(cfg: Phi4FlashConfig, lp, x, real, Q: int, s, tail):
+    """A Mamba layer over a whole prefill ``x [B, S, d]``, ``Q``
+    positions a chunk, FROM the state ``s`` and the tail ``tail`` (zeros
+    in front of a whole prompt, a snapshot's behind a shared prefix): the
+    stream behind it, the memory of each row's LAST position ``[B, 1,
+    C]``, the state and the tail behind it."""
     lp = _resident(lp)
 
     def chunk(carry, xs):
@@ -555,35 +593,46 @@ def _mamba_prefill(cfg: Phi4FlashConfig, lp, x, real):
         return tuple(carry), (x, y[:, -1:])
 
     (s, tail), (x, memory) = jax.lax.scan(
-        chunk, (jnp.zeros((B, cfg.mamba_d_state, cfg.d_inner), jnp.float32),
-                jnp.zeros((B, cfg.mamba_d_conv - 1, cfg.d_inner),
-                          cfg.dtype)),
-        (_chunks(x, Q), _chunks(real, Q)))
+        chunk, (s.astype(jnp.float32), tail), (_chunks(x, Q),
+                                               _chunks(real, Q)))
     return _unchunked(x), memory[-1], s, tail
 
 
-def _window_prefill(cfg: Phi4FlashConfig, lp, x, l, first):
-    """A window layer over a whole prefill, chunk by chunk: the stream
-    behind it and the last ``W`` keys and values."""
+def _window_prefill(cfg: Phi4FlashConfig, lp, x, l, first, Q: int,
+                    start=0, prefix=None):
+    """A window layer over a whole prefill whose first position is
+    ``start``, ``Q`` positions a chunk (`_window`, its ``prefix``): the
+    stream behind it and the last ``W`` keys and values."""
     B, S, _ = x.shape
-    Q, W = cfg.prefill_chunk, cfg.sliding_window
+    W = cfg.sliding_window
     lp = _resident(lp)
 
     def chunk(held, xs):
         x, at = xs
-        x, held = _layer(
-            cfg, lp, x, lambda u: _window(cfg, lp, u, l, at, first, held))
+        x, held = _layer(cfg, lp, x, lambda u: _window(
+            cfg, lp, u, l, at, first, held, prefix))
         return held, x
 
     empty = jnp.zeros((B, W, cfg.kv_pairs, 2 * cfg.head_dim), cfg.dtype)
-    held, x = jax.lax.scan(chunk, (empty, empty),
-                           (_chunks(x, Q), jnp.arange(S).reshape(-1, Q)))
+    held, x = jax.lax.scan(
+        chunk, (empty, empty),
+        (_chunks(x, Q), start + jnp.arange(S).reshape(-1, Q)))
     return _unchunked(x), held
 
 
 def _set_layer(stack, l, value):
     return jax.lax.dynamic_update_slice(
         stack, value[None].astype(stack.dtype), (l,) + (0,) * value.ndim)
+
+
+def _mamba_state(state, i):
+    """Mamba layer ``i``'s recurrent state and tail of ``state``."""
+    with jax.named_scope("ssm_state"):
+        s = jax.lax.dynamic_index_in_dim(state["ssm"], i, keepdims=False)
+    with jax.named_scope("conv_state"):
+        tail = jax.lax.dynamic_index_in_dim(state["conv"], i,
+                                            keepdims=False)
+    return s, tail
 
 
 def _with_mamba_state(state, i, s, tail):
@@ -725,41 +774,122 @@ def kv_cache_bytes(cfg: Phi4FlashConfig, batch: int, length: int) -> int:
     return by_kind["ring"] + by_kind["full"]
 
 
+# --- a prefix shared between requests ----------------------------------------
+
+RINGS = ("ring_keys", "ring_values")
+
+
+def prefix_bytes(cfg: Phi4FlashConfig, positions: int) -> int:
+    """Bytes of the snapshot behind ``positions`` ids: the states and
+    tails, the rings, and the one cache's part."""
+    return state_bytes(cfg, 1) + kv_cache_bytes(cfg, 1, positions)
+
+
+def make_prefix_program(cfg: Phi4FlashConfig):
+    """The jitted maker of a snapshot, ``lm_prefix_state`` (NOT
+    ``lm_generate``: what is counted and timed an execution is the served
+    program's): ``prefix_ids [K]``, one row and no padding, through the
+    FRONT as a prefill and through layer ``n/2 + 1``'s key-value
+    projection (the back half holds nothing to keep) -> ``ssm``, ``conv``
+    behind id ``K - 1``; ``ring_keys``, ``ring_values`` ``[L_s, W, ..]``,
+    the last ``W`` positions IN POSITION ORDER (slot ``i`` is position ``K
+    - W + i``; zeros in front where ``K < W``: which slot of a row's ring a
+    position falls in is the row's offset's to say); ``keys``, ``values``
+    ``[1, K, ..]``, THE cache's."""
+    W = cfg.sliding_window
+
+    def lm_prefix_state(params, prefix_ids):
+        K, = prefix_ids.shape
+        with jax.named_scope("Phi4Flash"):
+            _, _, state = _front_prefill(
+                cfg, params, prefix_ids[None], jnp.zeros((1,), jnp.int32), K)
+        held = jnp.arange(W)[:, None, None] >= W - K
+        rings = {n: jnp.where(held, jnp.roll(state[n][:, 0], -((K - W) % W),
+                                             axis=1), 0) for n in RINGS}
+        return {"ssm": state["ssm"][:, 0], "conv": state["conv"][:, 0],
+                **rings, "keys": state["keys"], "values": state["values"]}
+
+    return jax.jit(lm_prefix_state)
+
+
+def from_prefix(state, prefix, first):
+    """`empty_state`'s ``state`` with every row started from the snapshot
+    ``prefix``, as `ssm_hybrid.from_prefix` starts its rows (THE cache is
+    a stack of one): the states and tails copied a row (no position in
+    them), the K keys and values written at row ``b``'s own offset
+    ``first[b]``, directly in front of where that row's suffix will be
+    written (the padding lies in front of both, so the mask stays
+    ``kv_start = first`` with no hole).  The rings are `_window`'s to
+    fill: a slot is named by the buffer index, which the suffix moves."""
+    cache = {name: state[name][None] for name in ("keys", "values")}
+    new = ssm_hybrid.from_prefix({**state, **cache}, prefix, first)
+    return {**state, **new, **{name: new[name][0] for name in cache}}
+
+
 # --- the served program ---------------------------------------------------
 
-def prefill(cfg: Phi4FlashConfig, params, prompt_ids, first, length: int):
+def chunk_of(cfg: Phi4FlashConfig, S: int, prefix=None) -> int:
+    """Positions a chunk of the front's walk over a buffer of ``S``:
+    ``prefill_chunk``, and behind a snapshot the buffer's own length
+    where that is shorter (what is left of a prompt is short)."""
+    return cfg.prefill_chunk if prefix is None \
+        else min(cfg.prefill_chunk, S)
+
+
+def _front_prefill(cfg: Phi4FlashConfig, params, prompt_ids, first,
+                   length: int, prefix=None):
     """The prompt buffer ``[B, S]`` (row ``b``'s real ids in front,
     ``first[b]`` positions of padding behind) through the FRONT at every
-    position and through the back at each row's last: the logits behind
-    each row's last real id ``[B, V]`` and the state with room for
-    ``length`` positions.  The front walks a multiple of
-    ``prefill_chunk`` positions: what is missing is more padding in
-    front, which no row's state sees."""
+    position and through layer ``n/2 + 1``'s key-value projection: the
+    stream ``[B, 1, d]`` and the memory ``[B, 1, C]`` at each row's last
+    id, and the state with room for ``length`` positions.  The front
+    walks a multiple of `chunk_of` positions: what is missing is more
+    padding in front, which no row's state sees.
+
+    With a snapshot ``prefix`` of K ids the buffer holds what FOLLOWS
+    them in every row: each row starts from the snapshot (`from_prefix`,
+    `_window`'s ``prefix``) and the front walks the ``S`` positions
+    behind it; the buffer is laid out ``padding | prefix | row's own
+    ids``, its last id at ``K + S - 1`` whatever the row."""
     B, S = prompt_ids.shape
-    Q, W = cfg.prefill_chunk, cfg.sliding_window
+    K = lm_decode.prefix_length(prefix)
+    W = cfg.sliding_window
+    Q = chunk_of(cfg, S, prefix)
     extra = -S % Q
     with jax.named_scope("prefill"):
         # every row's last real id at the buffer's end
         ids = jnp.pad(jax.vmap(jnp.roll)(prompt_ids, first),
                       ((0, 0), (extra, 0)))
-        at = jnp.arange(S + extra)
-        real = at[None, :] >= (first + extra)[:, None]
+        # positions count from ``extra`` in front of the buffer: row b's
+        # real ids begin at ``begins[b]``, its OWN at ``own[b]``, and the
+        # front walks ``K .. K + S + extra - 1``
+        begins = first + extra
+        own = begins + K
+        real = jnp.arange(S + extra)[None, :] >= begins[:, None]
+        state = empty_state(cfg, B, length)
+        if prefix is not None:
+            state = from_prefix(state, prefix, first)
 
         def step(kind, lp, x, i, state):
             if kind == MAMBA:
-                x, memory, s, tail = _mamba_prefill(cfg, lp, x, real)
+                x, memory, s, tail = _mamba_prefill(
+                    cfg, lp, x, real, Q, *_mamba_state(state, i))
                 return x, _with_mamba_state(state, i, s, tail), memory
-            x, held = _window_prefill(cfg, lp, x, 2 * i + 1, first + extra)
+            last = None if prefix is None else (*(
+                jax.lax.dynamic_index_in_dim(prefix[n], i, keepdims=False)
+                for n in RINGS), own)
+            x, held = _window_prefill(cfg, lp, x, 2 * i + 1, begins, Q, K,
+                                      last)
             with jax.named_scope("kv_cache"):
-                # slot i of ``held`` is buffer index S - W + i: each to
-                # the slot its index names
+                # slot i of ``held`` is buffer index K + S - W + i: each
+                # to the slot its index names
                 rk, rv = (_set_layer(state[n], i,
-                                     jnp.roll(t, (S - W) % W, axis=1))
-                          for n, t in zip(("ring_keys", "ring_values"), held))
+                                     jnp.roll(t, (K + S - W) % W, axis=1))
+                          for n, t in zip(RINGS, held))
             return x, {**state, "ring_keys": rk, "ring_values": rv}
 
-        x, state, memory = _front(cfg, params, _embed(params, ids),
-                                  empty_state(cfg, B, length), step)
+        x, state, memory = _front(cfg, params, _embed(params, ids), state,
+                                  step)
         # THE cache: layer n/2 + 1's keys and values of every position
         with jax.named_scope("layers"), jax.named_scope(FULL):
             lp = layer_of(params[STACKS[FULL]], jnp.int32(0))
@@ -768,13 +898,27 @@ def prefill(cfg: Phi4FlashConfig, params, prompt_ids, first, length: int):
                 kv = _some_columns(cfg, lp, u, slice(
                     cfg.num_attention_heads * cfg.head_dim, None))
                 with jax.named_scope("kv_cache"):
-                    state.update({n: jax.lax.dynamic_update_slice(
-                        state[n],
-                        _heads(cfg, t, 2 * cfg.head_dim)[:, extra:],
-                        (0, 0, 0, 0)) for n, t in zip(
-                            ("keys", "values"), jnp.split(kv, 2, axis=-1))})
-        x, state = _back(cfg, params, x[:, -1:], memory,
-                         jnp.full((1,), S - 1), first, state, held=True)
+                    for n, t in zip(("keys", "values"),
+                                    jnp.split(kv, 2, axis=-1)):
+                        t = _heads(cfg, t, 2 * cfg.head_dim)[:, extra:]
+                        if prefix is not None:
+                            t = lm_decode.own_entries(
+                                real[:, extra:], t, state[n][None], 0, K)
+                        state[n] = jax.lax.dynamic_update_slice(
+                            state[n], t, (0, K, 0, 0))
+        return x[:, -1:], memory, state
+
+
+def prefill(cfg: Phi4FlashConfig, params, prompt_ids, first, length: int,
+            prefix=None):
+    """`_front_prefill`, then the back at each row's last position: the
+    logits behind each row's last real id ``[B, V]`` and the state."""
+    last = lm_decode.prefix_length(prefix) + prompt_ids.shape[1] - 1
+    x, memory, state = _front_prefill(cfg, params, prompt_ids, first,
+                                      length, prefix)
+    with jax.named_scope("prefill"):
+        x, state = _back(cfg, params, x, memory, jnp.full((1,), last),
+                         first, state, held=True)
         return _head(cfg, params, x)[:, 0], state
 
 
@@ -785,12 +929,7 @@ def decode_step(cfg: Phi4FlashConfig, params, token, index, first, state):
     cache, each summed over the layers that read it ``[B]``."""
     def step(kind, lp, x, i, state):
         if kind == MAMBA:
-            with jax.named_scope("ssm_state"):
-                s = jax.lax.dynamic_index_in_dim(state["ssm"], i,
-                                                 keepdims=False)
-            with jax.named_scope("conv_state"):
-                tail = jax.lax.dynamic_index_in_dim(state["conv"], i,
-                                                    keepdims=False)
+            s, tail = _mamba_state(state, i)
             x, memory, s, tail = _layer(
                 cfg, lp, x, lambda v: _mamba(cfg, lp, v, None, s, tail))
             return x, _with_mamba_state(state, i, s, tail), memory
@@ -816,41 +955,45 @@ def decode_step(cfg: Phi4FlashConfig, params, token, index, first, state):
 
 
 def generate(cfg: Phi4FlashConfig, max_new_tokens: int, params, prompt_ids,
-             prompt_len, seed, temperature
+             prompt_len, seed, temperature, prefix=None
              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Prefill, then ``max_new_tokens`` decode steps, for every row:
     `looplm.generate`'s contract (rows, lengths, seeds, temperatures; a
     row's numbers do not depend on what the other rows hold, nor on its
-    padding).  Returns the new ids ``[B, N]``, the float32 logits each was
-    drawn from ``[B, N, V]`` and ``stats``, int32: what the program
-    COMPUTED (``prefill_positions`` through the front, ``cross_positions``
-    through layer ``n/2 + 1``'s attention and the layers behind it in the
-    prefill, ``scan_chunks``, ``state_steps``: every row's, padded ones
-    too) and ``keys_attended_ring``, ``keys_attended_full [B]`` (what the
-    decode steps' masks let a row's queries see, summed over the layers
-    that read the rings, and over the ``1 + cross`` layers that read the
-    one cache)."""
+    padding), with `_front_prefill`'s ``prefix``.  Returns the new ids
+    ``[B, N]``, the float32 logits each was drawn from ``[B, N, V]`` and
+    ``stats``, int32: what the program COMPUTED (``prefill_positions``
+    through the front, ``cross_positions`` through layer ``n/2 + 1``'s
+    attention and the layers behind it in the prefill, ``scan_chunks``,
+    ``state_steps``: every row's, padded ones too; of a prefix served
+    from a snapshot nothing) and ``keys_attended_ring``,
+    ``keys_attended_full [B]`` (what the decode steps' masks let a row's
+    queries see, a prefix's keys among them, summed over the layers that
+    read the rings, and over the ``1 + cross`` layers that read the one
+    cache)."""
     B, S = prompt_ids.shape
+    P = S + lm_decode.prefix_length(prefix)
     first = S - jnp.broadcast_to(prompt_len, (B,))
 
     def start():
         logits, state = prefill(cfg, params, prompt_ids, first,
-                                S + max_new_tokens)
+                                P + max_new_tokens, prefix)
         with jax.named_scope("prefill"):
             rows = jnp.zeros((B,), jnp.int32)
             return logits, (), state, (rows, rows), ()
 
     def step(token, i, state):
-        logits, state, seen = decode_step(cfg, params, token, S + i[None],
+        logits, state, seen = decode_step(cfg, params, token, P + i[None],
                                           first, state)
         return logits, (), state, seen
 
     tokens, logits, _, (ring_keys, full_keys), _ = lm_decode.generate(
         "Phi4Flash", B, start, step, max_new_tokens, seed, temperature)
     Lm = cfg.layers_of(MAMBA) + 1
-    chunks = -(-S // cfg.prefill_chunk)
+    chunk = chunk_of(cfg, S, prefix)
+    chunks = -(-S // chunk)
     return tokens, logits, {
-        "prefill_positions": jnp.int32(B * chunks * cfg.prefill_chunk),
+        "prefill_positions": jnp.int32(B * chunks * chunk),
         "cross_positions": jnp.int32(B),
         "scan_chunks": jnp.int32(B * Lm * chunks),
         "state_steps": jnp.int32(B * Lm * max_new_tokens),
@@ -860,7 +1003,9 @@ def generate(cfg: Phi4FlashConfig, max_new_tokens: int, params, prompt_ids,
 def make_program(cfg: Phi4FlashConfig, max_new_tokens: int):
     """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
     device trace) like every language model's: ``(ids, logits, aux,
-    stats)``, ``aux`` empty."""
+    stats)``, ``aux`` empty.  With a sixth argument,
+    `make_prefix_program`'s snapshot, ``prompt_ids`` holds what follows
+    the prefix."""
 
     def served(*args):
         tokens, logits, stats = generate(cfg, max_new_tokens, *args)

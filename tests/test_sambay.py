@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import registry, sambay
+from comfyui_distributed_tpu.models import lm_decode, registry, sambay
 from comfyui_distributed_tpu.utils import trace
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -377,10 +377,8 @@ def test_the_family_offers_what_the_serving_path_reads():
     for name in ("CONFIGS", "param_count", "seeded_params",
                  "load_checkpoint", "make_program", "kv_cache_bytes",
                  "kv_cache_bytes_by_kind", "state_bytes", "window_counters",
-                 "few_rows_here"):
+                 "few_rows_here", "make_prefix_program", "prefix_bytes"):
         assert hasattr(arch, name), name
-    # no snapshot of a shared prefix in this family yet
-    assert not hasattr(arch, "make_prefix_program")
     assert FULL.layer_applications == 32 and FULL.vocab_size == 200064
 
 
@@ -430,6 +428,403 @@ def test_the_served_model_counts_what_the_program_computed(monkeypatch):
     assert gauges["lm.kv_cache_bytes_full"] == by_kind["full"]
     assert gauges["lm.kv_cache_bytes_recurrent"] == by_kind["recurrent"]
     assert gauges["lm.state_bytes"] == sambay.state_bytes(TINY, 4)
+
+
+# --- a prefix shared between requests -------------------------------------------
+#
+# Every row's prompt is the same K ids and then its own, in a suffix buffer
+# the longest row fills.  Window 8, chunks of 6:
+
+@dataclasses.dataclass(frozen=True)
+class Shared:
+    prefix: int         # K, the ids every row starts with
+    own: tuple          # each row's own ids behind them
+    new: int = 6
+
+    @property
+    def buffer(self):
+        return max(self.own)
+
+    @property
+    def first(self):
+        return [self.buffer - n for n in self.own]
+
+
+SHARED = {
+    # 13 ids: longer than the window, no multiple of the chunk; a suffix
+    # buffer of 8 (two chunks behind 4 padded positions) that one row
+    # fills, one of a single id, one shorter than the convolution's tail;
+    # the rows' offsets 0, 7, 6, 3 differ mod 8
+    "13 ids, a window and more": Shared(13, (8, 1, 2, 5)),
+    # 5 ids: the rings hold zeros in front of them
+    "5 ids, less than a window": Shared(5, (8, 1, 2, 5)),
+    # 24 ids, four chunks and three windows; a suffix of 16 (three chunks
+    # behind 2 padded positions), offsets 0, 15, 9, 5: a row's first own
+    # position lies in the first, the last and the middle chunk
+    "24 ids, a suffix of three chunks": Shared(24, (16, 1, 7, 11)),
+    # a suffix buffer of ONE id: a chunk of one position
+    "a suffix of one id": Shared(13, (1, 1)),
+}
+A = SHARED["13 ids, a window and more"]
+
+
+def shared_prompts(case):
+    """Per row the whole prompt: `prompt(9)`'s first K ids, then the
+    row's own."""
+    head = prompt(9, case.prefix, case.prefix)[0]
+    return [np.concatenate([head, prompt(b + 20, n, n)[0]])
+            for b, n in enumerate(case.own)]
+
+
+def snapshot_of(cfg, params, case):
+    return sambay.make_prefix_program(cfg)(
+        params, jnp.asarray(shared_prompts(case)[0][:case.prefix]))
+
+
+def buffers(case, picked, held):
+    """The prompt buffer of the rows ``picked`` with their first ``held``
+    ids left out (a snapshot stands for them), and the lengths."""
+    whole = shared_prompts(case)
+    ids = np.zeros((len(picked), case.prefix + case.buffer - held), np.int32)
+    for b, i in enumerate(picked):
+        ids[b, :len(whole[i]) - held] = whole[i][held:]
+    return ids, np.asarray([len(whole[i]) - held for i in picked], np.int32)
+
+
+def serve_shared(cfg, params, case, picked=None, snapshot="made",
+                 temperature=0.7, program=None):
+    """One execution over the rows ``picked`` of `shared_prompts`: from
+    the snapshot of the K ids (made here where "made"), or with None the
+    whole prompts through the five-argument program.  (A ``program`` kept
+    by the caller compiles a shape once; a fresh one traces whatever a
+    test has patched.)"""
+    picked = tuple(range(len(case.own))) if picked is None else picked
+    if isinstance(snapshot, str):
+        snapshot = snapshot_of(cfg, params, case)
+    ids, lens = buffers(case, picked, 0 if snapshot is None else case.prefix)
+    tokens, logits, _, stats = (program or sambay.make_program(
+        cfg, case.new))(
+        params, jnp.asarray(ids), lens, np.asarray(picked, np.uint32) + 3,
+        np.asarray([temperature] * len(picked), np.float32),
+        *(() if snapshot is None else (snapshot,)))
+    whole = shared_prompts(case)
+    rows = [{"prompt_ids": whole[i], "tokens": np.asarray(tokens[b]),
+             "logits": np.asarray(logits[b])} for b, i in enumerate(picked)]
+    return rows, {k: np.asarray(v) for k, v in stats.items()}
+
+
+def close(got, want, scale=2e-6):
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=scale * float(np.abs(want).max()))
+
+
+@pytest.mark.parametrize("case", SHARED)
+def test_rows_started_from_a_snapshot_are_the_full_paths_and_the_references(
+        case, params):
+    """Rows of unequal length from one snapshot, sampled with seeds: the
+    ids of the five-argument program over the whole prompt, its logits to
+    float32's rounding, and the reference's (every layer at every one of
+    the whole prompt's positions, no snapshot, no chunk) inside the
+    limits every served path is held to."""
+    case = SHARED[case]
+    served, stats = serve_shared(TINY, params, case)
+    full, full_stats = serve_shared(TINY, params, case, snapshot=None)
+    for b, (got, want) in enumerate(zip(served, full)):
+        assert np.array_equal(got["tokens"], want["tokens"])
+        close(got["logits"], want["logits"])
+        # (the reference walks every position in Python: of the later
+        # cases the row of ONE own id, which reads most of the snapshot)
+        if case is A or b == case.own.index(1):
+            reading = compare(TINY, params, got)
+            assert reading["correct"], reading
+    # what the program COMPUTED: the suffix buffer, in chunks of 6 or in
+    # one of its own length where it is shorter, where the full path
+    # walked the whole buffer in chunks of 6
+    B, Lm, S = len(case.own), 3, case.buffer
+    walked = -(-S // min(6, S)) * min(6, S)
+    whole = -(-(case.prefix + S) // 6) * 6
+    assert (stats["prefill_positions"], full_stats["prefill_positions"]) \
+        == (B * walked, B * whole)
+    assert (stats["scan_chunks"], full_stats["scan_chunks"]) \
+        == (B * Lm * walked // min(6, S), B * Lm * whole // 6)
+    assert stats["cross_positions"] == full_stats["cross_positions"] == B
+    assert stats["state_steps"] == full_stats["state_steps"] \
+        == B * Lm * case.new
+    # the prefix's keys are READ by every decode step all the same
+    for kind in ("ring", "full"):
+        assert list(stats[f"keys_attended_{kind}"]) \
+            == list(full_stats[f"keys_attended_{kind}"])
+    assert list(stats["keys_attended_full"]) == [
+        2 * (case.new * (case.prefix + n) + case.new * (case.new + 1) // 2)
+        for n in case.own]
+
+
+@pytest.mark.parametrize("pad", ["as seeded", "a large pad embedding"])
+def test_a_row_behind_a_snapshot_is_its_single_row_run(pad, params):
+    """Four rows of unequal length from one snapshot give, each, what
+    they give alone through the 1-row program (whose suffix buffer they
+    do not fill either: the padding lies in front of prefix and suffix
+    both), whatever the other rows hold; also where the pad id's
+    embedding is large."""
+    if pad != "as seeded":
+        table = np.asarray(params["embed_tokens"]).copy()
+        table[0] = 50.0 * np.random.default_rng(1).normal(size=table.shape[1])
+        params = {**params, "embed_tokens": jnp.asarray(table)}
+    snapshot = snapshot_of(TINY, params, A)
+    program = sambay.make_program(TINY, A.new)
+    served, _ = serve_shared(TINY, params, A, snapshot=snapshot)
+    for b in range(4):
+        (alone,), _ = serve_shared(TINY, params, A, (b,), snapshot,
+                                   program=program)
+        assert np.array_equal(alone["tokens"], served[b]["tokens"]), b
+        np.testing.assert_allclose(served[b]["logits"], alone["logits"],
+                                   atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", SHARED)
+def test_the_snapshot_is_what_the_full_prefill_leaves_behind_the_prefix(
+        case, params):
+    """At the stored widths, one row and no axis of rows.  Against the
+    five-argument prefill of the PREFIX alone, each row behind its own
+    padding: the nine... here three states and tails; each ring SLOT FOR
+    SLOT once a row's offset has rotated the snapshot's position order
+    (prefix position ``j`` of row ``b`` in slot ``(first[b] + j) mod
+    W``); the cache's part at ``first[b]``.  Then the state behind the
+    suffix's prefill against the five-argument prefill of the WHOLE
+    prompts: what a decode step reads is the same."""
+    case = SHARED[case]
+    K, W, B = case.prefix, TINY.sliding_window, len(case.own)
+    snapshot = snapshot_of(TINY, params, case)
+    Lm, Ls, pair = 3, 2, (1, 32)
+    assert {k: (v.shape, v.dtype) for k, v in snapshot.items()} == {
+        "ssm": ((Lm, 4, 128), jnp.float32),
+        "conv": ((Lm, 3, 128), TINY.dtype),
+        "ring_keys": ((Ls, W, *pair), TINY.dtype),
+        "ring_values": ((Ls, W, *pair), TINY.dtype),
+        "keys": ((1, K, *pair), TINY.dtype),
+        "values": ((1, K, *pair), TINY.dtype)}
+    assert lm_decode.prefix_length(snapshot) == K
+    assert sum(v.nbytes for v in snapshot.values()) \
+        == sambay.prefix_bytes(TINY, K)
+    if K < W:
+        assert float(jnp.abs(snapshot["ring_keys"][:, :W - K]).max()) == 0
+    # the prefix alone, every row behind the padding its suffix gives it
+    first = np.asarray(case.first)
+    head = shared_prompts(case)[0][:K]
+    ids = np.zeros((B, K + case.buffer), np.int32)
+    ids[:, :K] = head
+    _, state = jax.jit(lambda p, i, f: sambay.prefill(
+        TINY, p, i, f, K + case.buffer))(params, jnp.asarray(ids),
+                                         case.buffer + 0 * first)
+    for b in range(B):
+        for name in ("ssm", "conv"):
+            close(snapshot[name], state[name][:, b], 1e-5)
+    # (that prefill put row b's prefix at ``buffer``, not at ``first[b]``:
+    # one offset for every row; the rotation is the offset's)
+    at = case.buffer
+    held = range(max(0, K - W), K)
+    for name, ring in (("keys", "ring_keys"), ("values", "ring_values")):
+        want = np.asarray(state[ring][:, 0])
+        for j in held:
+            close(snapshot[ring][:, j - (K - W)], want[:, (at + j) % W], 1e-5)
+        close(snapshot[name][0], state[name][0, at:at + K], 1e-5)
+    # behind the suffix: every row at its OWN offset, against the whole
+    # prompts through the five-argument prefill
+    P = K + case.buffer
+    run = jax.jit(lambda p, i, f, *s: sambay.prefill(
+        TINY, p, i, f, P + case.new, *s)[1])
+    got = run(params, jnp.asarray(buffers(case, range(B), K)[0]), first,
+              snapshot)
+    want = run(params, jnp.asarray(buffers(case, range(B), 0)[0]), first)
+    for b, n in enumerate(case.own):
+        for name in ("ssm", "conv"):
+            close(got[name][:, b], want[name][:, b], 1e-5)
+        # the slots a decode step can see: the last W real positions
+        seen = [i % W for i in range(max(first[b], P - W), P)]
+        for name in sambay.RINGS:
+            close(got[name][:, b, seen], want[name][:, b, seen], 1e-5)
+            # prefix position j of row b stands in slot (first[b] + j) mod W
+            for j in range(max(0, K - W, K + n - W), K):
+                np.testing.assert_array_equal(
+                    got[name][:, b, (first[b] + j) % W],
+                    snapshot[name][:, j - (K - W)])
+        # the cache: padding | prefix | own ids
+        for name in ("keys", "values"):
+            np.testing.assert_array_equal(
+                got[name][b, first[b]:first[b] + K], snapshot[name][0])
+            close(got[name][b, first[b]:P], want[name][b, first[b]:P], 1e-5)
+            assert float(jnp.abs(got[name][b, :first[b]]).max(initial=0)) == 0
+    # the row of a single id: two inputs of its new tail are the prefix's
+    one = case.own.index(1)
+    np.testing.assert_array_equal(got["conv"][:, one, :2],
+                                  snapshot["conv"][:, 1:])
+
+
+def test_the_maker_is_not_the_served_program_and_the_phases_stay(params):
+    """The maker is ``lm_prefix_state``: the cells' pattern for the
+    served program (``^jit_lm_generate$``) does not match it, so its
+    seconds are no execution's.  It runs the FRONT and the cache's
+    projection, nothing behind.  The program that starts from a snapshot
+    is still ``lm_generate``, with every class and both phases."""
+    maker = sambay.make_prefix_program(TINY).lower(
+        params, jnp.zeros((A.prefix,), jnp.int32))
+    assert "jit_lm_prefix_state" in maker.as_text()[:200]
+    assert not re.match("^jit_lm_generate$", "jit_lm_prefix_state")
+    made = set(re.findall(r'op_name="([^"]+)"', maker.compile().as_text()))
+    assert any("/Phi4Flash/prefill/layers/" in n for n in made)
+    assert not [n for n in made if re.search(
+        r"/(gmu|cross)/|lm_head|final_layernorm|/decode/", n)]
+    ids, lens = buffers(A, range(4), A.prefix)
+    lowered = sambay.make_program(TINY, 3).lower(
+        params, jnp.asarray(ids), lens, np.zeros(4, np.uint32),
+        np.zeros(4, np.float32), snapshot_of(TINY, params, A))
+    assert "jit_lm_generate" in lowered.as_text()[:200]
+    names = [n for n in re.findall(r'op_name="([^"]+)"',
+                                   lowered.compile().as_text())
+             if "/Phi4Flash/" in n]
+    classes = {"lm_proj", "lm_attn", "lm_cross", "lm_gmu", "lm_ssm",
+               "lm_state", "lm_cache", "lm_mlp", "lm_norm", "lm_head",
+               "embed"}
+    assert {trace.classify(n) for n in names} == classes
+    assert {trace.phase_of(n) for n in names} == {"prefill", "decode"}
+    # the rows' start from the snapshot is the state's and the cache's
+    copies = {trace.classify(f"jit(lm_generate)/Phi4Flash/prefill/{scope}/x")
+              for scope in ("ssm_state", "conv_state", "kv_cache")}
+    assert copies == {"lm_state", "lm_cache"}
+
+
+def _a_ring_left_unrotated(monkeypatch):
+    """The prefix's last keys where row 0's offset puts them, in every
+    row."""
+    real = sambay._behind_prefix
+    monkeypatch.setattr(
+        sambay, "_behind_prefix",
+        lambda held, last, slot: real(held, last, slot[:1] + 0 * slot))
+
+
+def _a_tail_taken_from_the_padding(monkeypatch):
+    """The snapshot's tail in front of the CHUNK, where a row's padding
+    stands, not in front of its first own id."""
+    real = sambay.causal_conv
+    monkeypatch.setattr(
+        sambay, "causal_conv",
+        lambda u, weight, bias, tail=None, at=None: real(u, weight, bias,
+                                                         tail))
+
+
+def _padding_over_the_prefixs_end(monkeypatch):
+    """Every position of the suffix buffer writes its key into the one
+    cache, a row's padded ones too: over the last keys of its prefix."""
+    monkeypatch.setattr(lm_decode, "own_entries",
+                        lambda own, new, cache, l, at: new)
+
+
+def _no_keys_behind_the_prefix(monkeypatch):
+    """The window layers start the suffix from empty rings."""
+    monkeypatch.setattr(sambay, "_behind_prefix",
+                        lambda held, last, slot: held)
+
+
+SHARED_BREAKAGES = {
+    "a ring left unrotated": _a_ring_left_unrotated,
+    "a tail taken from the padding": _a_tail_taken_from_the_padding,
+    "padding written over the prefix's end": _padding_over_the_prefixs_end,
+    "rings that start empty": _no_keys_behind_the_prefix}
+# 9 ids (a window and one more) and a suffix buffer of one chunk, which row
+# 0 fills: it has no padding for a trap of the padding to go wrong in
+TRAPS = Shared(9, (6, 1, 4), new=3)
+
+
+@pytest.mark.parametrize("what", SHARED_BREAKAGES)
+def test_each_breakage_behind_a_snapshot_fails_the_comparison(
+        what, params, monkeypatch):
+    """The traps of a prefix in front of right-aligned rows whose state
+    is of three geometries: the comparison with the reference of the
+    WHOLE prompt refuses each, in a padded row; the row that fills its
+    buffer passes the three that are the padding's."""
+    SHARED_BREAKAGES[what](monkeypatch)
+    served, _ = serve_shared(TINY, params, TRAPS)
+    monkeypatch.undo()
+    readings = [compare(TINY, params, row) for row in served]
+    assert not any(r["correct"] for r in readings[1:]), readings
+    assert readings[0]["correct"] == (what != "rings that start empty"), \
+        readings[0]
+
+
+def test_rows_that_share_no_instructions_run_the_program_as_it_was(
+        params, monkeypatch):
+    """The five-argument program does not know of the sixth: nothing of
+    the snapshot's path is on its way (each piece would raise here), its
+    chunk is ``prefill_chunk`` whatever the buffer (behind a snapshot a
+    shorter buffer is one chunk of its own length), and its ids and
+    logits are the unpatched program's bit for bit.  (Against a ``git
+    archive`` of the parent's tree they are the parent's bit for bit, by
+    hand: PERF.md section 6, PR 47.)"""
+    ids, lens = buffers(A, range(4), 0)
+    args = (params, jnp.asarray(ids), lens, np.arange(4, dtype=np.uint32),
+            np.full(4, 0.7, np.float32))
+    want_tokens, want_logits, _, _ = sambay.make_program(TINY, 4)(*args)
+
+    def never(*a, **kw):
+        raise AssertionError("the snapshot's path without a snapshot")
+
+    for module, name in ((sambay, "_behind_prefix"), (sambay, "from_prefix"),
+                         (lm_decode, "own_entries"),
+                         (lm_decode, "write_at_offsets")):
+        monkeypatch.setattr(module, name, never)
+    tokens, logits, _, _ = sambay.make_program(TINY, 4)(*args)
+    np.testing.assert_array_equal(tokens, want_tokens)
+    np.testing.assert_array_equal(logits, want_logits)
+    assert sambay.chunk_of(TINY, 3) == TINY.prefill_chunk == 6
+    assert (sambay.chunk_of(TINY, 3, {}), sambay.chunk_of(TINY, 8, {})) \
+        == (3, 6)
+
+
+# --- through the registry -------------------------------------------------------
+
+def lm_delta(before):
+    after = trace.GLOBAL_COUNTERS.snapshot()
+    return {k[3:]: after[k] - before.get(k, 0) for k in after
+            if k.startswith("lm.") and after[k] != before.get(k, 0)}
+
+
+def asked(model, rows, **kw):
+    before = dict(trace.GLOBAL_COUNTERS.snapshot())
+    out = model.generate_rows(rows, max_new_tokens=3, prompt_tokens=32, **kw)
+    return [words for words, _ in out], lm_delta(before)
+
+
+def test_rows_from_a_snapshot_get_the_words_of_the_whole_prompt(monkeypatch):
+    """Through `LanguageModel.generate_rows`, three rows of one set of
+    instructions (7 ids with the first): each starts from their snapshot,
+    the program walks the 25 positions behind it (5 chunks of 6 behind 5
+    padded positions), and the words are those of the same rows with the
+    whole prompt scanned (the rule held off), and of each row alone."""
+    monkeypatch.setenv("DTPU_DEFAULT_FAMILY", "tiny")
+    model = registry.load_language_model(
+        "phi-4-mini-flash-reasoning.safetensors")
+    guide = "style guide number 0 of many"
+    rows = [registry.LMRow(f"a walled garden in june number {i}", i, 0.7 * i,
+                           instructions=guide) for i in range(3)]
+    words, got = asked(model, rows)
+    assert got["prefix_hits"] == 3 and got["prefix_positions_served"] == 21
+    assert got["prefill_positions"] == 4 * 30 and got["cross_positions"] == 4
+    assert got["scan_chunks"] == 4 * 3 * 5
+    assert trace.GLOBAL_GAUGES.snapshot()["lm.prefix_bytes"] \
+        == sambay.prefix_bytes(TINY, 7) * len(model._prefixes)
+    for i, row in enumerate(rows):
+        assert asked(model, [row])[0] == [words[i]]
+    # rows with DIFFERENT instructions: the whole prompt, nothing counted
+    other = [*rows[:2], registry.LMRow("a cat", 2, instructions="draw it")]
+    assert model.shared_prefix(other, 32) is None
+    got = asked(model, other)[1]
+    assert got["prefill_positions"] == 4 * 36
+    assert not [k for k in got if k.startswith("prefix_")]
+    monkeypatch.setattr(registry.LanguageModel, "shared_prefix",
+                        lambda self, *a: None)
+    whole, got = asked(model, rows)
+    assert whole == words and len(set(words)) == 3
+    assert "prefix_hits" not in got and got["prefill_positions"] == 4 * 36
 
 
 # --- names in a compiled program ----------------------------------------------------

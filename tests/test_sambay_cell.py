@@ -466,7 +466,9 @@ def test_the_cell_rehearses_on_the_cpu(tmp_path):
     """``run.py --rehearse`` of the new cell: a tiny model of THIS family
     behind the same nodes, hand-over and drain wait, every request
     served, nothing compiled in the window, the program's counters on the
-    window's record: the back half ran on one position a row."""
+    window's record: every row started from the snapshot of the
+    rehearsal's instructions, the front walked what follows them, the
+    back half ran on one position a row."""
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", CELL,
          "--seed", str(2 ** 31 + 46), "--seconds", "4", "--trace", "0",
@@ -482,24 +484,75 @@ def test_the_cell_rehearses_on_the_cpu(tmp_path):
     counters = run["window_counters"]
     assert counters["lm.executions"] >= 2
     rows = counters["lm.rows"] + counters["lm.padded_rows"]
-    # 48 prompt positions in 8 chunks of 6, 4 new tokens, 3 Mamba layers;
-    # no snapshot in this family: the whole prompt is computed
-    assert counters["lm.prefill_positions"] == rows * 48
+    # every row starts from the snapshot of the rehearsal's instructions
+    # (14 ids with the first; nothing here reads a position's index, so
+    # it stands at each row's offset) and the front walks the 34 of 48
+    # positions behind them: 6 chunks of 6 behind 2 padded positions; 4
+    # new tokens, 3 Mamba layers
+    held, walked = 14, 36
+    assert counters["lm.prefix_hits"] == counters["lm.rows"]
+    assert counters["lm.prefix_positions_served"] \
+        == counters["lm.rows"] * held
+    assert "lm.prefix_misses" not in counters      # made by the warm-ups
+    assert "lm.prefix_evictions" not in counters
+    assert counters["lm.prefill_positions"] == rows * walked
     assert counters["lm.cross_positions"] == rows
-    assert counters["lm.scan_chunks"] == rows * 3 * 8
+    assert counters["lm.scan_chunks"] == rows * 3 * 6
     assert counters["lm.state_steps"] == rows * 3 * 4
-    assert "lm.prefix_hits" not in counters
     # a window of 8 in two layers; one cache read by two
     assert counters["lm.keys_attended_ring"] == counters["lm.rows"] * 4 * 2 * 8
     assert counters["lm.keys_attended_full"] \
         > counters["lm.keys_attended_ring"]
 
 
+def test_the_cells_instructions_are_a_true_prefix_of_every_request():
+    """What `LanguageModel.shared_prefix` needs of the CELL's traffic, at
+    the published vocabulary: the operator's instructions encode to 8,101
+    ids (the start id and 8,100 words) that are the first ids of every
+    request's prompt, with 1 to 91 ids of the row's own behind them inside
+    the 8192 positions.  A silent fall-back to the whole prompt would
+    fail here, not only on the chip.  And what stays resident for them."""
+    import random
+    import numpy as np
+    from comfyui_distributed_tpu.models import registry, sambay, tokenizer
+    node = config()["graph"]["21"]["inputs"]
+    model = registry.LanguageModel(
+        "phi-4-mini-flash-reasoning.safetensors", sambay.PHI_4_MINI_FLASH,
+        None, tokenizer.make_lm_tokenizer(None, 200064), "phi4flash")
+    with open(os.path.join(BENCH, "traffic", "words.txt")) as f:
+        words = [w.strip() for w in f if w.strip()]
+    rng = random.Random(7)
+    rows = [registry.LMRow(" ".join(rng.choice(words) for _ in range(12)),
+                           i, instructions=node["instructions"])
+            for i in range(4)]
+    prefix = model.shared_prefix(rows, node["prompt_tokens"])
+    assert prefix is not None and len(prefix) == 8101
+    for row in rows:
+        ids = model.prompt_ids(row.text, node["prompt_tokens"],
+                               row.instructions)
+        assert np.array_equal(ids[:8101], prefix)
+        assert 1 <= len(ids) - 8101 <= node["prompt_tokens"] - 8101 == 91
+    # 91 positions are one chunk of the front's walk, not 512
+    assert sambay.chunk_of(model.cfg, 91, {}) == 91
+    # one row with other instructions, and the execution runs whole
+    other = [*rows[:3], registry.LMRow("a cat", 3, instructions="draw it")]
+    assert model.shared_prefix(other, node["prompt_tokens"]) is None
+    # nine states and tails 3.2 MB, eight rings' worth 21.0, the cache's
+    # part 41.5: 65.7 MB, where a row's whole state at 8,256 is 66.5
+    sizes = config()["sizes"]
+    assert sambay.prefix_bytes(model.cfg, 8101) == 65_674_240 \
+        == sizes["recurrent_state_bytes_a_row_9_layers"] \
+        + sizes["ring_bytes_a_row_8_layers_x_512_slots"] \
+        + 8101 * sizes["full_cache_bytes_a_position_a_row"]
+
+
 def test_the_verify_script_rehearses(tmp_path):
-    """``verify_lm_sambay.py --rehearse``: one request alone and four as
-    the rows of one execution inside every limit against the reference of
-    ALL layers at EVERY position, every reading that has to fail outside
-    one."""
+    """``verify_lm_sambay.py --rehearse`` (the benchmark's, unedited): its
+    served requests carry the rehearsal's instructions, so each starts
+    from their snapshot; one request alone and four as the rows of one
+    execution inside every limit against the reference of ALL layers at
+    EVERY position of the WHOLE prompt, every reading that has to fail
+    outside one."""
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "verify_lm_sambay.py"),
          "--rehearse", "--out", str(tmp_path)],
