@@ -19,7 +19,7 @@ import os
 import threading
 import time
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1513,6 +1513,19 @@ class LanguageModel:
         # its own), 76.4 MB + 8 KiB a position each at the published size
         self._prefixes: "collections.OrderedDict[bytes, Any]" = \
             collections.OrderedDict()
+        # what the tokenizer has made, least recently used first: a set of
+        # instructions' ids under its text (as many sets as `_prefixes`
+        # keeps snapshots) and a row's under everything they are a function
+        # of (text, prompt_tokens, instructions; the rows of four
+        # executions), so that the hand-over and `generate_rows` may ask
+        # for a request's ids as often as they like and the tokenizer
+        # walks them once.  Under a lock of their own: `_lock` is held
+        # through a compilation
+        self._instruction_ids: "collections.OrderedDict[str, np.ndarray]" = \
+            collections.OrderedDict()
+        self._row_ids: "collections.OrderedDict[tuple, np.ndarray]" = \
+            collections.OrderedDict()
+        self._ids_lock = threading.RLock()
         self._mesh = None
         self._lock = threading.Lock()
 
@@ -1534,16 +1547,62 @@ class LanguageModel:
             self._prefix_makers.clear()
             self._prefixes.clear()
 
+    def _pass(self, encode: Callable[[str], Sequence[int]], text: str
+              ) -> np.ndarray:
+        """One pass of the tokenizer over ``text``, counted."""
+        ids = np.asarray(encode(text), np.int32)
+        trace_mod.GLOBAL_COUNTERS.bump("lm.prompt_encodes")
+        trace_mod.GLOBAL_COUNTERS.bump("lm.prompt_encode_ids", len(ids))
+        return ids
+
+    def _kept_ids(self, memo: "collections.OrderedDict", key: Any, room: int,
+                  make: Callable[[], np.ndarray]) -> np.ndarray:
+        """``memo[key]``: kept from an earlier call, else what ``make``
+        gives, with the least recently used entry beyond ``room`` let go.
+        Made under the lock and read-only: whoever asks, and two threads
+        that ask at once, get the one array."""
+        with self._ids_lock:
+            ids = memo.get(key)
+            if ids is not None:
+                memo.move_to_end(key)
+                return ids
+            ids = memo[key] = make()
+            ids.setflags(write=False)
+            while len(memo) > room:
+                memo.popitem(last=False)
+        return ids
+
+    def instruction_ids(self, instructions: str) -> np.ndarray:
+        """The tokenizer's ids of an operator's ``instructions`` alone,
+        made once per set of instructions."""
+        return self._kept_ids(
+            self._instruction_ids, instructions, self.row_counts[-1],
+            lambda: self._pass(self.tokenizer.encode, instructions))
+
     def prompt_ids(self, text: str, prompt_tokens: int,
                    instructions: str = "") -> np.ndarray:
         """The real ids of ``instructions`` and, behind them, ``text``
-        under the expander's template, cut to ``prompt_tokens``; refused
-        here where the device would clamp an index out of range in
-        silence."""
-        asked = EXPAND_TEMPLATE.format(text=text)
-        ids = self.tokenizer.encode(
-            f"{instructions} {asked}" if instructions else asked)
-        ids = np.asarray(ids[:prompt_tokens], np.int32)
+        under the expander's template, cut to ``prompt_tokens``: what
+        ``tokenizer.encode`` gives for the whole, to the id, made once per
+        request (where the tokenizer says what a text adds behind a space,
+        from the instructions' kept ids and a pass over the rest alone).
+        Refused here, at every call, where the device would clamp an index
+        out of range in silence."""
+        def make() -> np.ndarray:
+            asked = EXPAND_TEMPLATE.format(text=text)
+            behind = getattr(self.tokenizer, "encode_behind_space", None)
+            if instructions and behind is not None:
+                ids = np.concatenate([self.instruction_ids(instructions),
+                                      self._pass(behind, asked)])
+            else:
+                ids = self._pass(
+                    self.tokenizer.encode,
+                    f"{instructions} {asked}" if instructions else asked)
+            return ids[:prompt_tokens]
+
+        ids = self._kept_ids(
+            self._row_ids, (text, int(prompt_tokens), instructions),
+            4 * self.row_counts[-1], make)
         if not len(ids) or ids.min() < 0 or ids.max() >= self.cfg.vocab_size:
             raise ValueError(
                 f"{self.name}: a prompt of {len(ids)} ids in "
@@ -1564,7 +1623,7 @@ class LanguageModel:
                 or not instructions \
                 or any(r.instructions != instructions for r in rows):
             return None
-        prefix = np.asarray(self.tokenizer.encode(instructions), np.int32)
+        prefix = self.instruction_ids(instructions)
         if ids is None:
             ids = [self.prompt_ids(r.text, prompt_tokens, r.instructions)
                    for r in rows]
@@ -1628,7 +1687,8 @@ class LanguageModel:
 
     def generate_rows(self, rows: Sequence[LMRow], max_new_tokens: int = 64,
                       prompt_tokens: int = 64,
-                      spans: Sequence[Any] = ()
+                      spans: Sequence[Any] = (),
+                      ids: Optional[Sequence[np.ndarray]] = None
                       ) -> List[Tuple[str, LMOutput]]:
         """The continuations of the rows' texts under the expander's
         template: ONE execution of ``lm_generate`` for all of them (each
@@ -1650,7 +1710,8 @@ class LanguageModel:
         steps of one execution: the first row is the caller's and its
         ``lm_generate`` stage lies on the current span; row ``i`` behind
         it is a request still in the queue whose root span is
-        ``spans[i - 1]``, and gets the same interval there."""
+        ``spans[i - 1]``, and gets the same interval there.  ``ids``: the
+        rows' `prompt_ids`, where the caller holds them already."""
         self._ensure_laid_out()
         n, real = int(max_new_tokens), len(rows)
         if n < 1 or not 1 <= real <= self.row_counts[-1]:
@@ -1658,8 +1719,9 @@ class LanguageModel:
                 f"{self.name}: {n} new tokens for {real} row(s) cannot be "
                 f"generated (at least 1 token, 1 to {self.row_counts[-1]} "
                 f"rows)")
-        ids = [self.prompt_ids(r.text, prompt_tokens, r.instructions)
-               for r in rows]
+        if ids is None:
+            ids = [self.prompt_ids(r.text, prompt_tokens, r.instructions)
+                   for r in rows]
         count = next(b for b in self.row_counts if b >= real)
         prefix = self.shared_prefix(rows, prompt_tokens, ids)
         held = 0 if prefix is None else len(prefix)

@@ -301,6 +301,15 @@ class HashLMTokenizer:
         return [self.bos_id] + [self._FIRST + _word_hash(w) % span
                                 for w in _WORDS.findall(text.lower())]
 
+    def encode_behind_space(self, text: str) -> List[int]:
+        """What ``text`` adds behind a space: ``encode(a + " " + text)`` is
+        ``encode(a)`` followed by this, whatever ``a``.  A word's id is a
+        function of the word alone and no word holds a space, so this
+        tokenizer can say so; one that merges across a space (the model's
+        own ``tokenizer.json``) has no such method, and its caller encodes
+        the whole."""
+        return self.encode(text)[1:]
+
     def word(self, token: int) -> str:
         n, out = int(token), []
         while True:

@@ -25,7 +25,11 @@ its own and needs none: it ends with work that is already enqueued and
 that the host does not feed, during which this execution could not have
 begun, and every way such a group ends lets it go (`ServerState.
 _image_settled`).  Where the device owes nothing (an empty server, no
-overlap) or the set is full when the host arrives, nothing waits.
+overlap) or the set is full when the host arrives, nothing waits.  The
+device is idle from the drain's end to the enqueue, so what can be done
+before the wait is: the queued graphs are parsed and their rows encoded
+then (`LanguageModel.prompt_ids` keeps a request's ids), and the second
+look at the queue walks only a row that joined meanwhile.
 
 What is kept is keyed on everything the result is a function of (model,
 lengths, instructions, text, seed, temperature), read again from the values the
@@ -42,7 +46,7 @@ leader's: nothing is kept, and each follower runs alone at its turn.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from comfyui_distributed_tpu.models.registry import LMRow
 from comfyui_distributed_tpu.ops.base import get_op
@@ -50,6 +54,14 @@ from comfyui_distributed_tpu.utils import trace as trace_mod
 from comfyui_distributed_tpu.workflow.graph import parse_workflow
 
 NODE = "LanguageModelGenerate"
+
+
+class Waiting(NamedTuple):
+    """A queued request's generate call that an execution has room for."""
+    pid: str
+    row: LMRow
+    span: Any           # the request's root span
+    ids: Any            # `LanguageModel.prompt_ids` of the row
 
 
 class GenerateHandover:
@@ -81,24 +93,26 @@ class GenerateHandover:
             return mine
         # before the wait, so that only the look at the queue lies between
         # the drain and the enqueue: the queued graphs are parsed (`_calls`
-        # keeps them) and a leader that cannot be encoded is refused
+        # keeps them), their rows encoded (the model keeps the ids) and a
+        # leader that cannot be encoded is refused
         ids = model.prompt_ids(row.text, prompt_tokens, row.instructions)
         shared = None if model.shared_prefix([row], prompt_tokens, [ids]) \
             is None else row.instructions
         waiting, full = self._waiting(model, max_new_tokens, prompt_tokens,
                                       shared)
         if not full and self._drain_wait():
-            here = {pid for pid, _, _ in waiting}
+            here = {w.pid for w in waiting}
             waiting, _ = self._waiting(model, max_new_tokens, prompt_tokens,
                                        shared)
             bump("lm.drain_waits")
             bump("lm.rows_joined_in_drain",
-                 sum(pid not in here for pid, _, _ in waiting))
+                 sum(w.pid not in here for w in waiting))
         results = model.generate_rows(
-            [row] + [w[1] for w in waiting], max_new_tokens, prompt_tokens,
-            spans=[w[2] for w in waiting])
-        made = [(pid, key(theirs), result) for (pid, theirs, _), result
-                in zip(waiting, results[1:])]
+            [row] + [w.row for w in waiting], max_new_tokens, prompt_tokens,
+            spans=[w.span for w in waiting],
+            ids=[ids] + [w.ids for w in waiting])
+        made = [(w.pid, key(w.row), result)
+                for w, result in zip(waiting, results[1:])]
         # a follower that left the queue while the execution ran (a drain
         # that timed out) was finalized before this was made, and nobody
         # would drop it later.  Under the lock `drop` takes: a purge lands
@@ -143,18 +157,18 @@ class GenerateHandover:
 
     def _waiting(self, model: Any, max_new_tokens: int, prompt_tokens: int,
                  shared: Optional[str] = None
-                 ) -> Tuple[List[Tuple[str, LMRow, Any]], bool]:
-        """``(prompt id, row, root span)`` of the queued requests' calls
-        this execution has room for, in queue order, and whether they
-        fill it.  ``shared``: the instructions whose snapshot the leader
-        starts from; a call with others does not join."""
+                 ) -> Tuple[List[Waiting], bool]:
+        """The queued requests' calls this execution has room for, in
+        queue order, and whether they fill it.  ``shared``: the
+        instructions whose snapshot the leader starts from; a call with
+        others does not join."""
         state = self._state
         with state._queue_lock:
             queued = list(state._queue)
         with self._lock:
             served = {pid for pid, _, _ in self._kept}
         room = model.row_counts[-1] - 1 - len(served)
-        found: List[Tuple[str, LMRow, Any]] = []
+        found: List[Waiting] = []
         for item in queued:
             if item["id"] in served:
                 continue
@@ -166,11 +180,11 @@ class GenerateHandover:
                         or shared not in (None, row.instructions):
                     continue
                 try:
-                    model.prompt_ids(row.text, prompt_tokens,
-                                     row.instructions)
+                    ids = model.prompt_ids(row.text, prompt_tokens,
+                                           row.instructions)
                 except ValueError:
                     continue        # it fails at its own turn, alone
-                found.append((item["id"], row, item.get("span")))
+                found.append(Waiting(item["id"], row, item.get("span"), ids))
         return found, len(found) >= room
 
     def _calls(self, item: Dict[str, Any]) -> list:

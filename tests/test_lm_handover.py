@@ -25,6 +25,7 @@ from comfyui_distributed_tpu.server.app import ServerState, build_app
 from comfyui_distributed_tpu.utils import trace
 from comfyui_distributed_tpu.workflow.executor import ExecutionResult
 from comfyui_distributed_tpu.workflow.graph import parse_workflow
+from tests.test_lm_prompt_ids import Counting
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKFLOW = os.path.join(REPO, "workflows", "prompt-expand-txt2img.json")
@@ -675,6 +676,92 @@ def test_behind_a_leader_on_a_snapshot_only_its_instructions_ride_along(
             [registry.LMRow(f"a tower number {i}", i,
                             instructions=GUIDES[which])], NEW, PROMPT)
         assert generated[i] == f"a tower number {i}, {words}"
+
+
+@pytest.mark.parametrize("instructions", ["", GUIDES["A"]])
+@pytest.mark.parametrize("model_name", [GRANITE, "ouro-2.6b.safetensors"])
+def test_a_requests_ids_are_made_once_and_never_while_the_device_waits(
+        state, monkeypatch, model_name, instructions):
+    """Four prompts behind an executor that waits for the device, two of
+    them posted during the wait, twice over: ONE execution each time, every
+    row encoded once, the instructions once for both executions, and after
+    the drain no pass for a row that was queued before it and one, over
+    its own words alone, for a row that joined.  Each request gets the
+    words of its own single-row run and the tokenizer's own ids."""
+    model = registry.load_language_model(model_name)
+    drained = threading.Event()
+    tok = Counting(model.tokenizer, drained.is_set)
+    monkeypatch.setattr(model, "tokenizer", tok)
+    model._row_ids.clear()
+    model._instruction_ids.clear()
+    real = state.lm_handover._drain_wait
+
+    def drain_wait():
+        out = real()
+        drained.set()
+        return out
+
+    monkeypatch.setattr(state.lm_handover, "_drain_wait", drain_wait)
+    outs = []
+    cls = ops_base.NODE_CLASS_MAPPINGS["LanguageModelGenerate"]
+    execute = cls.execute
+    monkeypatch.setattr(cls, "execute", lambda self, ctx, **kw: (
+        outs.append(execute(self, ctx, **kw)) or outs[-1]))
+
+    def post(text, seed):
+        g = graph(text, seed=seed, instructions=instructions)
+        g[LOADER]["inputs"]["model_name"] = model_name
+        # the language model's nodes alone: no image is made
+        return state.enqueue_prompt(
+            {k: g[k] for k in (LOADER, GENERATE, SEED)}, "t")
+
+    asked = [(f"{t}, round {rnd}", 10 * rnd + i)
+             for rnd in range(2) for i, t in enumerate(TEXTS)]
+    for rnd in range(2):
+        drained.clear()
+        earlier = owe(state, f"dispatched before round {rnd}")
+        for text, seed in asked[4 * rnd:4 * rnd + 2]:
+            post(text, seed)
+        turn = lead(state)
+        for text, seed in asked[4 * rnd + 2:4 * rnd + 4]:
+            post(text, seed)
+        state._image_settled(earlier)
+        ended(turn)
+        for _ in range(3):
+            run_next(state)
+    got = counters()
+    assert (got["executions"], got["rows"], got["padded_rows"]) == (2, 8, 0)
+    assert got["drain_waits"] == 2 and got["rows_joined_in_drain"] == 4
+    assert got["followers_served"] == 6 and state.lm_handover.kept() == 0
+    assert got.get("prefix_hits", 0) == \
+        (8 if instructions and model_name == GRANITE else 0)
+    assert all(h["status"] == "success" for h in state._history.values())
+    # every row once and the instructions once, whatever the executions
+    assert sum(text == instructions for text, _, _ in tok.passes) == \
+        bool(instructions)
+    assert len(tok.passes) == 8 + bool(instructions)
+    assert (got["prompt_encodes"], got["prompt_encode_ids"]) == \
+        (len(tok.passes), sum(n for _, n, _ in tok.passes))
+    # after the drain: the two that joined, each over its own words (with
+    # instructions too: the hash tokenizer says what a text adds to them)
+    assert [text for text, _, after in tok.passes if after] == [
+        registry.EXPAND_TEMPLATE.format(text=text) for rnd in range(2)
+        for text, _ in asked[4 * rnd + 2:4 * rnd + 4]]
+    # the words of each request's own run, the ids the tokenizer gives
+    for (text, seed), (said, out) in zip(asked, outs):
+        (words, alone_out), = model.generate_rows(
+            [registry.LMRow(text, seed, instructions=instructions)],
+            NEW, PROMPT)
+        assert said == f"{text}, {words}"
+        whole = registry.EXPAND_TEMPLATE.format(text=text)
+        whole = tok.inner.encode(
+            f"{instructions} {whole}" if instructions else whole)[:PROMPT]
+        assert np.array_equal(out.prompt_ids, whole)
+        assert np.array_equal(alone_out.prompt_ids, whole)
+        assert np.array_equal(np.asarray(out.tokens)[out.row],
+                              np.asarray(alone_out.tokens)[0])
+    # and those runs found every row's ids kept
+    assert len(tok.passes) == 8 + bool(instructions)
 
 
 @pytest.mark.parametrize("edit, want", [
