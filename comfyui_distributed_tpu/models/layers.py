@@ -368,7 +368,8 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                         kv_positions, window, sc)
 
     _, out = jax.lax.scan(body, None, (qr, pos, sel))
-    return out.transpose(1, 0, 2, 3, 4).reshape(B, N, H, D)
+    # (values may be narrower than the keys: a latent attention's 128 to 192)
+    return out.transpose(1, 0, 2, 3, 4).reshape(B, N, H, -1)
 
 
 def _maybe_ring_attention(q: jax.Array, k: jax.Array,

@@ -2,18 +2,19 @@
 driver, the sampler, the writer of a shared prefix and the jitted closure.
 
 A family (`registry.LM_FAMILIES`: ``looplm``, ``mla_moe``, ``swa_moe``,
-``ssm_hybrid``, ``dsa_moe``, ``sambay``) supplies what differs, as two
-closures over its own arguments: ``prefill()``, the prompt through its
-blocks (or, where the layers behind a shared cache own no state, through
-half of them: only the logits behind the last prompt id are asked for),
-and ``step(token, i, state)``, one new position; its state (a cache, a
-latent, a ring beside a cache, a recurrence beside a cache, an index-key
-cache beside a cache, recurrences and rings in front of one cache that
-several layers read) is its own and opaque here.  `generate` is the loop around
-them, written once, so a mechanism of the loop (a chunk of steps, a row
-that joins, a state slot) is made in one place.  A family whose state
-behind a prompt's first ids can stand for them adds a ``from_prefix`` hook
-over `write_at_offsets` and `own_entries`.
+``ssm_hybrid``, ``dsa_moe``, ``sambay``, ``mla_scmoe``) supplies what
+differs, as two closures over its own arguments: ``prefill()``, the prompt
+through its blocks (or, where the layers behind a shared cache own no
+state, through half of them: only the logits behind the last prompt id are
+asked for), and ``step(token, i, state)``, one new position; its state (a
+cache, a latent, a ring beside a cache, a recurrence beside a cache, an
+index-key cache beside a cache, recurrences and rings in front of one
+cache that several layers read, two latent slots a layer) is its own and
+opaque here.  `generate` is the loop around them, written once, so a
+mechanism of the loop (a chunk of steps, a row that joins, a state slot)
+is made in one place.  A family whose state behind a prompt's first ids
+can stand for them adds a ``from_prefix`` hook over `write_at_offsets` and
+`own_entries`.
 
 This module imports ``jax`` and nothing of the families; they import it.
 """

@@ -321,10 +321,11 @@ def _rope(x, positions, theta):
                      axis=-1).reshape(x.shape)
 
 
-def _queries(cfg: MlaMoeConfig, lp, n, positions):
+def _queries(cfg, lp, n, positions, scale: float = 1.0):
     """``q_nope [B, N, H, d_nope]`` and the rotated ``q_rope
     [B, N, H, d_rope]``, float32, through the rank-``q_lora_rank``
-    bottleneck."""
+    bottleneck; both halves times ``scale`` where a family scales the
+    expanded query (`models/mla_scmoe.py`; 1: no operation is added)."""
     B, N, _ = n.shape
     with jax.named_scope("q_a_proj"):
         c_q = _dense(n, lp["q_a_proj"], cfg)
@@ -333,6 +334,8 @@ def _queries(cfg: MlaMoeConfig, lp, n, positions):
     with jax.named_scope("q_b_proj"):
         q = _dense(c_q, lp["q_b_proj"], cfg).reshape(
             B, N, cfg.num_attention_heads, -1)
+        if scale != 1.0:
+            q = q * scale
     q = shd.constrain(q, "batch", None, "heads", None)
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
     with jax.named_scope("rotary"):
@@ -340,15 +343,19 @@ def _queries(cfg: MlaMoeConfig, lp, n, positions):
     return q_nope, q_rope
 
 
-def _latent(cfg: MlaMoeConfig, lp, n, positions):
+def _latent(cfg, lp, n, positions, scale: float = 1.0):
     """What the cache holds of this call's positions: the normed latent
-    ``c_kv`` and the rotated shared key ``k_r``, ``[B, N, 576]`` in the
-    model's dtype."""
+    ``c_kv`` (times ``scale`` where a family scales it: the cache then
+    holds the SCALED latent, which both ways of attending read; the
+    rotary key is never scaled) and the rotated shared key ``k_r``,
+    ``[B, N, 576]`` in the model's dtype."""
     with jax.named_scope("kv_a_proj_with_mqa"):
         c_kv, k_r = jnp.split(_dense(n, lp["kv_a_proj_with_mqa"], cfg),
                               [cfg.kv_lora_rank], axis=-1)
     with jax.named_scope("kv_a_layernorm"):
         c_kv = _rms_norm(c_kv, lp["kv_a_layernorm"], cfg.rms_norm_eps)
+        if scale != 1.0:
+            c_kv = c_kv * scale
     with jax.named_scope("rotary"):
         k_r = _rope(k_r[:, :, None], positions, cfg.rope_theta)[:, :, 0]
     return jnp.concatenate([c_kv, k_r], axis=-1).astype(cfg.dtype)
@@ -406,30 +413,37 @@ def _attend_absorbed(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
                           preferred_element_type=jnp.float32)
 
 
+def _self_attn(cfg, lp, n, positions, index, first, cache, l,
+               absorbed: bool, q_scale: float = 1.0, kv_scale: float = 1.0):
+    """Latent attention of the normed ``n [B, N, d]`` at ``positions
+    [B, N]``, and the cache with this call's latent written into slot
+    ``l`` at the buffer indices ``index``.  With ``absorbed`` the queries
+    attend to the cache (a decode step), without to this call's own
+    latent, expanded (the prefill).  -> ``W_o``'s output ``[B, N, d]``."""
+    B, N, _ = n.shape
+    q_nope, q_rope = _queries(cfg, lp, n, positions, q_scale)
+    latent = _latent(cfg, lp, n, positions, kv_scale)
+    with jax.named_scope("kv_cache"):
+        cache = jax.lax.dynamic_update_slice(
+            cache, latent[None].astype(cache.dtype), (l, 0, index[0], 0))
+        if absorbed:
+            latent = jax.lax.dynamic_index_in_dim(
+                cache, l, keepdims=False).astype(cfg.dtype)
+    attend = _attend_absorbed if absorbed else _attend_expanded
+    a = attend(cfg, lp, q_nope, q_rope, latent, index, first)
+    with jax.named_scope("o_proj"):
+        return _dense(a.reshape(B, N, -1), lp["o_proj"], cfg), cache
+
+
 def _attention(cfg: MlaMoeConfig, lp, x, index, first, cache, l,
                absorbed: bool):
-    """``h = x + N2(Attn(N1(x)))`` and the cache with this call's latent
-    written into layer ``l`` at the buffer indices ``index``.  With
-    ``absorbed`` the queries attend to the cache (a decode step), without
-    to this call's own latent, expanded (the prefill)."""
-    B, N, _ = x.shape
+    """``h = x + N2(Attn(N1(x)))`` and the cache (`_self_attn`)."""
     positions = index[None, :] - first[:, None]
     with jax.named_scope("input_layernorm"):
         n = _rms_norm(x, lp["input_layernorm"], cfg.rms_norm_eps)
     with jax.named_scope("self_attn"):
-        q_nope, q_rope = _queries(cfg, lp, n, positions)
-        latent = _latent(cfg, lp, n, positions)
-        with jax.named_scope("kv_cache"):
-            cache = jax.lax.dynamic_update_slice(
-                cache, latent[None].astype(cache.dtype),
-                (l, 0, index[0], 0))
-            if absorbed:
-                latent = jax.lax.dynamic_index_in_dim(
-                    cache, l, keepdims=False).astype(cfg.dtype)
-        attend = _attend_absorbed if absorbed else _attend_expanded
-        a = attend(cfg, lp, q_nope, q_rope, latent, index, first)
-        with jax.named_scope("o_proj"):
-            a = _dense(a.reshape(B, N, -1), lp["o_proj"], cfg)
+        a, cache = _self_attn(cfg, lp, n, positions, index, first, cache, l,
+                              absorbed)
     with jax.named_scope("post_attention_layernorm"):
         return _sandwich(x, a, lp["post_attention_layernorm"],
                          cfg.rms_norm_eps), cache
@@ -446,17 +460,24 @@ def _gated_mlp(cfg: MlaMoeConfig, weights, n, scope=jax.named_scope):
         return _dense(h, weights["down_proj"], cfg)
 
 
-def route(cfg: MlaMoeConfig, gate, n):
-    """The router over ALL ``n_routed_experts``, in float32 at the
-    highest precision: the scores ``[t, E]`` (``scoring_func``: each
-    expert's ``sigmoid``, or a ``softmax`` over them all), the chosen
-    experts ``[t, k]`` and their weights (with ``norm_topk_prob`` the
-    chosen scores over their sum; times ``routed_scaling_factor``)."""
+def route(cfg, gate, n, bias=None):
+    """The router over ALL its outputs, in float32 at the highest
+    precision: the scores ``[t, E]`` (``scoring_func``: each expert's
+    ``sigmoid``, or a ``softmax`` over them all), the chosen experts
+    ``[t, k]`` and their weights (with ``norm_topk_prob`` the chosen
+    scores over their sum; times ``routed_scaling_factor``).  With a
+    score-correction ``bias [E]`` the experts are chosen by ``scores +
+    bias`` and weighted by the scores alone."""
     logits = jnp.dot(n.astype(jnp.float32), gate.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.softmax(logits, axis=-1) \
         if cfg.scoring_func == "softmax" else jax.nn.sigmoid(logits)
-    top, chosen = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    if bias is None:
+        top, chosen = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    else:
+        _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                  cfg.num_experts_per_tok)
+        top = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     return scores, chosen, top * cfg.routed_scaling_factor

@@ -1268,7 +1268,8 @@ def clear_pipeline_cache() -> None:
 
 
 # --- language models (models/looplm.py, models/mla_moe.py, models/swa_moe.py,
-# models/ssm_hybrid.py, models/dsa_moe.py, models/sambay.py) --------------------
+# models/ssm_hybrid.py, models/dsa_moe.py, models/sambay.py,
+# models/mla_scmoe.py) -------------------------------------------------------
 #
 # A LANGUAGE_MODEL is resident beside the diffusion checkpoints in the one
 # model-asset cache (``clear_pipeline_cache`` frees both).  Nothing of it is
@@ -1285,7 +1286,7 @@ EXPAND_TEMPLATE = ("Rewrite this image prompt with more visual detail. "
 # a served window compiles nothing).  An execution is padded to the next
 # count with copies of its first row; the last count also bounds what is
 # kept for requests still in the queue (server/lm_handover.py): three
-# results.  Argued per family (the comments in `LM_FAMILIES`); all six
+# results.  Argued per family (the comments in `LM_FAMILIES`); all seven
 # take these.
 LM_ROW_COUNTS = (1, 4)
 
@@ -1441,6 +1442,27 @@ LM_FAMILIES = {
         "Mamba-1 and window-512 differential attention in front (float32 "
         "states, rings), ONE key-value cache and ONE state-space memory "
         "shared by the 14 layers behind, a tied embedding"),
+    # A row's cache is 9,216 B a position over the eight attentions (two
+    # latent slots a layer, 576 bf16 values each): 19.5 MB at 2,112
+    # positions, 78 MB at 4 rows beside 10.35 GB resident and SD1.5, so the
+    # CACHE would take a hundred rows.  What argues for 4 and no more here
+    # is the prefill: 2,048 positions a row of compute-bound work through
+    # 5.1 GB of dense weights (11 TFLOP a row, a row adds it whole) whose
+    # float32 temporaries stand beside 13 GB resident on a 16 GB chip: the
+    # fifth row would not fit.  And what argued in the others: the rows
+    # are the requests waiting in one server's queue (four callers in the
+    # cell), every count is a program to compile at set-up, each further
+    # row routes 12 more pairs a layer (of which a third are zero experts
+    # and cost nothing, and 0.25 hit one of the 16 experts held, 75 MB
+    # each).  Why not fewer: one stream of 5.3 GB of non-expert weights a
+    # step serves every row.
+    "longcat": LMFamily(
+        "mla_scmoe", ("longcat",),
+        "LongCat-Flash-Omni's language model, one chip's share of 32: a "
+        "layer of two latent attentions (two latent cache slots) and two "
+        "dense MLPs with a shortcut-connected expert layer between them, "
+        "16 of 512 routed experts held, 256 zero-compute experts in the "
+        "router's 768 outputs; the audio and vision towers are not held"),
 }
 
 

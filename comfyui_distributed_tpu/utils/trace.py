@@ -230,7 +230,7 @@ OTHER = "other"
 # is attn_self, and a GroupNorm inside a ResBlock is norm.
 _UNET, _VAE, _CLIP, _LM = "UNet", "VAE", "CLIPTextModel", "LoopLM"
 _MOE, _SWA, _SSM = "PanguUltraMoE", "ExaoneMoe", "GraniteMoeHybrid"
-_DSA, _SBY = "KeyeVL2", "Phi4Flash"
+_DSA, _SBY, _SCM = "KeyeVL2", "Phi4Flash", "LongcatFlash"
 _BLOCK = r"(?:down_\d+|up_\d+|mid)"
 KERNEL_CLASSES = (
     # the Mamba mixer's gated RMSNorm is published as ``mamba/norm``: ahead
@@ -371,10 +371,30 @@ KERNEL_CLASSES = (
     # kinds of layer
     ("lm_proj", _SBY, r"attn|layers|mamba|swa|memory|full|gmu|cross"
                       r"|prefill|decode|Phi4Flash"),
+    # the latent-attention decoder whose layer is two attentions and two
+    # dense MLPs around a shortcut-connected expert layer
+    # (models/mla_scmoe.py), under ``mla_moe``'s classes (its attention IS
+    # that family's; ``lm_mlp`` the two dense MLPs, ``mlps_0`` and
+    # ``mlps_1``; ``lm_experts`` the router, the dispatch, the experts held
+    # and the expert layer's own glue under ``mlp``), and one more:
+    # ``lm_zero`` is the identity experts' scaled add of the layer's input
+    # (which choices are zero experts, the sum of their weights, the add)
+    ("lm_norm", _SCM, r"(?:input|post_attention)_layernorm_[01]"
+                      r"|(?:q_a|kv_a)_layernorm|final_norm"),
+    ("lm_proj", _SCM, r"q_[ab]_proj|kv_a_proj_with_mqa|kv_b_proj|o_proj"
+                      r"|absorb_[qv]"),
+    ("lm_cache", _SCM, r"kv_cache"),        # two latent slots a layer
+    ("lm_attn", _SCM, r"self_attn_[01]|rotary"),
+    ("lm_zero", _SCM, r"zero_experts"),
+    ("lm_experts", _SCM, r"mlp|router|dispatch|experts"),
+    ("lm_mlp", _SCM, r"mlps_[01]|gate_proj|up_proj|down_proj"),
+    ("lm_head", _SCM, r"lm_head|sample"),
+    ("embed", _SCM, r"embed_tokens"),
+    ("lm_proj", _SCM, r"layers|prefill|decode|LongcatFlash"),
 )
 # the outer scopes a program may put directly under its model's: where it
 # does, a trace summary gives its seconds by PHASE beside its seconds by
-# class (the six language models' ``generate`` do; the denoise, VAE and
+# class (the seven language models' ``generate`` do; the denoise, VAE and
 # text programs do not and have no phases).  A program that the
 # persistent compile cache LOADS carries the names of the tree that
 # compiled it (JAX keys a program without a Pallas kernel with its debug
@@ -387,7 +407,7 @@ SAMPLER = "sampler"
 _SAMPLER_PROGRAM = re.compile(r"(?:^|/)jit\((?:core|step)\)(?:/|$)")
 _MODEL_OF = re.compile(
     r"^(UNet|VAE|CLIPTextModel|LoopLM|PanguUltraMoE|ExaoneMoe"
-    r"|GraniteMoeHybrid|KeyeVL2|Phi4Flash)(?:\.\w+)?$")
+    r"|GraniteMoeHybrid|KeyeVL2|Phi4Flash|LongcatFlash)(?:\.\w+)?$")
 _ROWS = tuple((cls, model, re.compile(f"(?:{pat})$"))
               for cls, model, pat in KERNEL_CLASSES)
 

@@ -28,22 +28,26 @@ PHASE_READERS = ["lm_decode_step_ms", "lm_prefill_attn_device_s_per_request",
 READERS = DENOISE_READERS + PHASE_READERS
 # (PR 40's cell stands behind K-EXAONE's wherever the reader is
 # family-neutral: it has no routed expert; PR 42's stands last, and its
-# model has experts; PR 46's stands behind that, and has none)
+# model has experts; PR 46's stands behind that, and has none; PR 49's
+# last, with experts)
 DENOISE_CELLS = ["sdxl_1024_sat", "sd15_512_sat", "ouro_expand_sd15_512_sat4",
                  "pangu_expand_sd15_512_sat4", "exaone_expand_sd15_512_sat4",
                  "granite_expand_sd15_512_sat4", "keye_expand_sd15_512_sat4",
-                 "phi4flash_expand_sd15_512_sat4"]
+                 "phi4flash_expand_sd15_512_sat4",
+                 "longcat_expand_sd15_512_sat4"]
 EXPANDER_CELLS = ["ouro_expand_sd15_512_sat", "ouro_expand_sd15_512_sat4",
                   "pangu_expand_sd15_512_sat4", "exaone_expand_sd15_512_sat4",
                   "granite_expand_sd15_512_sat4", "keye_expand_sd15_512_sat4",
-                  "phi4flash_expand_sd15_512_sat4"]
+                  "phi4flash_expand_sd15_512_sat4",
+                  "longcat_expand_sd15_512_sat4"]
 CELLS = {"denoise_gaps_s_per_image": DENOISE_CELLS,
          "denoise_other_s_per_image": DENOISE_CELLS,
          "denoise_glue_s_per_image": DENOISE_CELLS,
          "lm_decode_step_ms": EXPANDER_CELLS,
-         "lm_prefill_attn_device_s_per_request": EXPANDER_CELLS[-4:],
-         "lm_prefill_experts_device_s_per_request": [EXPANDER_CELLS[-4],
-                                                     EXPANDER_CELLS[-2]]}
+         "lm_prefill_attn_device_s_per_request": EXPANDER_CELLS[-5:],
+         "lm_prefill_experts_device_s_per_request": [EXPANDER_CELLS[-5],
+                                                     EXPANDER_CELLS[-3],
+                                                     EXPANDER_CELLS[-1]]}
 
 
 def _load(name, path):
@@ -224,8 +228,9 @@ def test_the_manifests_entry(manifest, name):
 
 def test_the_six_are_appended_and_nothing_that_was_there_moved(manifest):
     names = [m["name"] for m in manifest["per_layer"]]
-    # (PR 40 appended four behind them, PR 42 four more, PR 46 four)
-    assert names[35:41] == READERS and len(names) == len(set(names)) == 53
+    # (PR 40 appended four behind them, PR 42 four more, PR 46 four, PR 49
+    # three)
+    assert names[35:41] == READERS and len(names) == len(set(names)) == 56
     assert names[34] == "lm_prefill_flops_util_pct"
     # the denoise readers sit where ``norm_device_s_per_image`` does
     (norm,) = [m for m in manifest["per_layer"]

@@ -130,11 +130,24 @@ _PHI_ONLY = {
                                         "half runs on one position a row, "
                                         "from the same counters",
 }
+# (PR 49: the two shares of the decoder with a shortcut-connected expert
+# layer and zero-compute experts)
+LONGCAT4 = "longcat_expand_sd15_512_sat4"
+_LONGCAT_ONLY = {
+    "lm_scmoe_decode_hbm_roofline_pct": "the bytes of a decoder with two "
+                                        "latent attentions and two dense "
+                                        "MLPs a layer and nothing for its "
+                                        "zero experts, from counters only "
+                                        "its program has",
+    "lm_scmoe_prefill_flops_util_pct": "the FLOPs of that block's prefill, "
+                                       "from the same counters",
+}
 NOT_IN_SAT4 = {
     "chip_busy_min_pct": _ONE_CHIP,
     "lm_moe_decode_hbm_roofline_pct": "the bytes of a decoder with routed "
                                       "experts: Ouro has none to count",
     **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY,
+    **_LONGCAT_ONLY,
 }
 NOT_IN_PANGU4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -143,6 +156,7 @@ NOT_IN_PANGU4 = {
                                   "lm_moe_decode_hbm_roofline_pct stands "
                                   "in its place",
     **_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY,
+    **_LONGCAT_ONLY,
 }
 NOT_IN_EXAONE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -151,7 +165,7 @@ NOT_IN_EXAONE4 = {
                                       "kv_lora_rank, a latent cache's: "
                                       "lm_swa_moe_decode_hbm_roofline_pct "
                                       "stands in its place",
-    **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY,
+    **_GRANITE_ONLY, **_KEYE_ONLY, **_PHI_ONLY, **_LONGCAT_ONLY,
 }
 NOT_IN_GRANITE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -160,7 +174,7 @@ NOT_IN_GRANITE4 = {
                                       "latent cache",
     **{name: "it has no routed expert, no ring and counts no local pairs: "
              "its own two stand in their place" for name in _EXAONE_ONLY},
-    **_KEYE_ONLY, **_PHI_ONLY,
+    **_KEYE_ONLY, **_PHI_ONLY, **_LONGCAT_ONLY,
 }
 NOT_IN_KEYE4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -169,7 +183,7 @@ NOT_IN_KEYE4 = {
     **{name: "it has no ring, and what a step reads of its cache is chosen "
              "by an index: its own two stand in their place"
        for name in _EXAONE_ONLY},
-    **_GRANITE_ONLY, **_PHI_ONLY,
+    **_GRANITE_ONLY, **_PHI_ONLY, **_LONGCAT_ONLY,
 }
 NOT_IN_PHI4 = {
     "chip_busy_min_pct": _ONE_CHIP,
@@ -178,6 +192,21 @@ NOT_IN_PHI4 = {
     **{name: "its rings stand beside states and ONE cache that eight "
              "layers read: its own two stand in their place"
        for name in {**_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY}},
+    **_LONGCAT_ONLY,
+}
+NOT_IN_LONGCAT4 = {
+    "chip_busy_min_pct": _ONE_CHIP,
+    "lm_decode_hbm_roofline_pct": NOT_IN_PANGU4["lm_decode_hbm_roofline_pct"],
+    "lm_moe_decode_hbm_roofline_pct": "it counts ONE attention, a shared "
+                                      "expert and sandwich norms a block "
+                                      "and would be false here: two "
+                                      "attentions, two dense MLPs, no "
+                                      "shared expert, zero experts that "
+                                      "move nothing",
+    **{name: "its block is another (two latent slots a layer, experts "
+             "beside dense MLPs): its own two stand in their place"
+       for name in {**_EXAONE_ONLY, **_GRANITE_ONLY, **_KEYE_ONLY,
+                    **_PHI_ONLY}},
 }
 
 
@@ -271,10 +300,11 @@ def _exaone4_reports(m, cells):
             # family-neutral)
             # (and PR 42's behind that, and behind the experts' reader)
             # (and PR 46's behind the three)
+            # (and PR 49's behind them, the experts' reader too)
             assert x["workloads"] in ([EXAONE4], [EXAONE4, GRANITE4],
-                                      [EXAONE4, KEYE4],
-                                      [EXAONE4, GRANITE4, KEYE4],
-                                      [EXAONE4, GRANITE4, KEYE4, PHI4]) \
+                                      [EXAONE4, KEYE4, LONGCAT4],
+                                      [EXAONE4, GRANITE4, KEYE4, PHI4,
+                                       LONGCAT4]) \
                 and x["layer"] == "Language model" \
                 and x["source"] == "device_trace"
     cfg = _config(exaone["config"])
@@ -344,7 +374,7 @@ def _keye4_reports(m, cells):
         if x["name"] in _KEYE_ONLY or "_index_" in x["name"]:
             assert x["workloads"] == [KEYE4] and x["layer"] == \
                 "Language model" and x["source"] == "device_trace"
-    assert [x["name"] for x in m["per_layer"][-8:-4]] == [
+    assert [x["name"] for x in m["per_layer"][-11:-7]] == [
         "lm_index_device_s_per_request",
         "lm_prefill_index_device_s_per_request", *_KEYE_ONLY]
     cfg = _config(keye["config"])
@@ -381,7 +411,7 @@ def _phi4_reports(m, cells):
             assert x["workloads"] == [PHI4] and x["layer"] == \
                 "Language model" and x["source"] == "device_trace" \
                 and x["moves"] == "images_per_s"
-    assert [x["name"] for x in m["per_layer"][-4:]] == [
+    assert [x["name"] for x in m["per_layer"][-7:-3]] == [
         "lm_gmu_device_s_per_request", "lm_cross_device_s_per_request",
         *_PHI_ONLY]
     cfg = _config(phi["config"])
@@ -392,9 +422,50 @@ def _phi4_reports(m, cells):
     # two families behind one prompt: the same instructions bit for bit
     assert cfg["graph"]["21"] == keye["graph"]["21"]
     assert len(cfg["graph"]["21"]["inputs"]["instructions"].split()) == 8100
-    # 11 of at most 24 cells, 8 configurations, still one on four chips
-    assert len(m["workloads"]) == 11 and len(m["configs"]) == 8
-    assert m["workloads"][-1] == phi
+    # 11 of at most 24 cells with this one, 8 configurations
+    assert len(m["workloads"][:11]) == 11 and len(m["configs"][:8]) == 8
+    assert m["workloads"][10] == phi
+    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
+        ["sdxl_1024_fanout4"]
+
+
+def _longcat4_reports(m, cells):
+    """The cell PR 49 appended: granite's with a seventh language model in
+    front (one chip's share of 32: depth, experts and vocabulary reduced)
+    behind the SAME 2048-id prompt; it lists what Keye's lists (an expert
+    family behind a long prompt) but the readers of Keye's index and of
+    its counts, and three readers of its own."""
+    longcat = cells[LONGCAT4]
+    assert longcat["config"] == "longcat-flash-omni-expand-sd15-512"
+    own = {*_LONGCAT_ONLY, "lm_zero_device_s_per_request"}
+    assert _listed(m, LONGCAT4) == (_listed(m, KEYE4) - {
+        *_KEYE_ONLY, "lm_index_device_s_per_request",
+        "lm_prefill_index_device_s_per_request"}) | own
+    assert {"lm_experts_device_s_per_request",
+            "lm_prefill_device_s_per_request",
+            "lm_prefill_attn_device_s_per_request",
+            "lm_prefill_experts_device_s_per_request"} \
+        <= _listed(m, LONGCAT4)
+    for x in m["per_layer"]:
+        if x["name"] in own:
+            assert x["workloads"] == [LONGCAT4] and x["layer"] == \
+                "Language model" and x["source"] == "device_trace" \
+                and x["moves"] == "images_per_s"
+    assert [x["name"] for x in m["per_layer"][-3:]] == [
+        *_LONGCAT_ONLY, "lm_zero_device_s_per_request"]
+    cfg = _config(longcat["config"])
+    granite = _config("granite-4.0-h-micro-expand-sd15-512")
+    _same_graph_but(cfg, granite, {"20"})
+    assert cfg["reduced"] == ["num_layers", "n_routed_experts",
+                              "vocab_size"] \
+        and cfg["graph"]["20"]["inputs"] == {
+            "model_name": "longcat-flash-omni.safetensors"}
+    # two families behind one prompt: the same instructions bit for bit
+    assert cfg["graph"]["21"] == granite["graph"]["21"]
+    assert len(cfg["graph"]["21"]["inputs"]["instructions"].split()) == 1950
+    # 12 of at most 24 cells, 9 configurations, still one on four chips
+    assert len(m["workloads"]) == 12 and len(m["configs"]) == 9
+    assert m["workloads"][-1] == longcat
     assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == \
         ["sdxl_1024_fanout4"]
 
@@ -406,7 +477,8 @@ def _phi4_reports(m, cells):
     (GRANITE4, NOT_IN_GRANITE4, _granite4_reports),
     (KEYE4, NOT_IN_KEYE4, _keye4_reports),
     (PHI4, NOT_IN_PHI4, _phi4_reports),
-], ids=[SAT4, PANGU4, EXAONE4, GRANITE4, KEYE4, PHI4])
+    (LONGCAT4, NOT_IN_LONGCAT4, _longcat4_reports),
+], ids=[SAT4, PANGU4, EXAONE4, GRANITE4, KEYE4, PHI4, LONGCAT4])
 def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
         cell, leaves_out, reports):
     m = _manifest()
@@ -417,6 +489,8 @@ def test_an_expander_cell_reports_every_share_that_moves_what_it_does(
     assert len(this["why"]) <= 200
     if cell in (PANGU4, EXAONE4):        # one chip's share of the experts
         assert "attention sees more than its share" in this["why"]
+    if cell == LONGCAT4:
+        assert "dense sees more" in this["why"]
     reported = {x["name"] for x in m["end_to_end"]
                 if cell in x.get("workloads", [cell])}
     assert reported == {"images_per_s", "tti_p50_s", "setup_s"}

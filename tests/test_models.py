@@ -306,8 +306,14 @@ class TestAdvancedOps:
     def _pipe(self):
         return registry.load_pipeline("adv-ops.ckpt")
 
-    def test_clip_set_last_layer(self):
+    def test_clip_set_last_layer(self, monkeypatch):
         from comfyui_distributed_tpu.ops.base import OpContext, get_op
+        from comfyui_distributed_tpu.parallel import mesh
+        # a multi-device runtime that an earlier file of this xdist worker
+        # left live lays the base's and the clone's weights out apart, and
+        # "shared, not copied" below is about the unsharded trees (which
+        # files share a worker moves with every file a PR adds: PR 49)
+        monkeypatch.setattr(mesh, "_runtime", None)
         pipe = self._pipe()
         op = get_op("CLIPSetLastLayer")
         (skip2,) = op.execute(OpContext(), pipe, -2)

@@ -367,8 +367,8 @@ def test_a_model_name_names_the_sixth_family(name, want, monkeypatch):
         registry.detect_lm_family("a-decoder-of-no-family-7b.safetensors")
     assert "phi-4-mini-flash" in str(e.value) \
         and "ONE key-value cache" in str(e.value)
-    assert list(registry.LM_FAMILIES) == ["ouro", "pangu", "exaone",
-                                          "granite", "keye", "phi4flash"]
+    assert list(registry.LM_FAMILIES)[:6] == ["ouro", "pangu", "exaone",
+                                              "granite", "keye", "phi4flash"]
 
 
 def test_the_family_offers_what_the_serving_path_reads():

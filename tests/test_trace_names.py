@@ -48,6 +48,10 @@ DSA_CLASSES = MOE_CLASSES | {"lm_index"}
 # state: a gate with another layer's memory, an attention over another
 # layer's cache
 SAMBAY_CLASSES = SSM_CLASSES | {"lm_gmu", "lm_cross"}
+# the decoder with a shortcut-connected expert layer and zero-compute
+# experts (PR 49; its rows are below): the expert model's and one for the
+# identity experts' scaled add
+SCMOE_CLASSES = MOE_CLASSES | {"lm_zero"}
 
 
 @pytest.fixture(scope="module", params=["tiny", "tiny_sdxl"])
@@ -70,7 +74,7 @@ def test_the_vocabulary_is_the_issues_and_classify_needs_no_jax():
     classes = {row[0] for row in trace.KERNEL_CLASSES} | {trace.SAMPLER}
     assert classes == DENOISE_CLASSES | VAE_CLASSES | CLIP_CLASSES \
         | LM_CLASSES | MOE_CLASSES | SSM_CLASSES | DSA_CLASSES \
-        | SAMBAY_CLASSES | {"vae_attn"}
+        | SAMBAY_CLASSES | SCMOE_CLASSES | {"vae_attn"}
     r = subprocess.run(
         [sys.executable, "-c",
          "import sys; from comfyui_distributed_tpu.utils.trace import "
@@ -836,3 +840,60 @@ def test_executor_self_times_add_up_and_requests_carry_their_instants(
     names = {s["name"] for s in rec["spans"]}
     assert {"dispatch", "device_wait", "d2h", "d2h_copy", "history_write",
             "queue_to_device", "execute"} <= names
+
+
+# --- the seventh language model's scopes (PR 49) -------------------------------
+
+@pytest.mark.parametrize("path, want, phase", [
+    ("prefill/layers/while/body/closed_call/input_layernorm_0/rsqrt",
+     "lm_norm", "prefill"),
+    ("prefill/layers/while/body/closed_call/self_attn_0/q_a_proj/dot_general",
+     "lm_proj", "prefill"),
+    ("prefill/layers/while/body/self_attn_1/q_b_proj/mul", "lm_proj",
+     "prefill"),
+    ("decode/while/body/layers/while/body/self_attn_1/kv_a_layernorm/mul",
+     "lm_norm", "decode"),
+    ("decode/while/body/layers/while/body/self_attn_0/kv_cache/"
+     "dynamic_update_slice", "lm_cache", "decode"),
+    ("decode/while/body/layers/while/body/self_attn_1/absorb_q/dot_general",
+     "lm_proj", "decode"),
+    ("prefill/layers/while/body/self_attn_0/kv_b_proj/dot_general",
+     "lm_proj", "prefill"),
+    ("prefill/layers/while/body/self_attn_0/rotary/cos", "lm_attn",
+     "prefill"),
+    ("prefill/layers/while/body/self_attn_1/while/body/bnhd,bmhd->bhnm/"
+     "dot_general", "lm_attn", "prefill"),
+    ("decode/while/body/layers/while/body/self_attn_0/o_proj/fewrow_dense/"
+     "pallas_call", "lm_proj", "decode"),
+    ("prefill/layers/while/body/post_attention_layernorm_0/mul", "lm_norm",
+     "prefill"),
+    ("prefill/layers/while/body/mlps_0/gate_proj/dot_general", "lm_mlp",
+     "prefill"),
+    ("decode/while/body/layers/while/body/mlps_1/gate_proj/"
+     "fewrow_dense_gate_proj_up_proj/pallas_call", "lm_mlp", "decode"),
+    ("decode/while/body/layers/while/body/mlps_1/add", "lm_mlp", "decode"),
+    ("prefill/layers/while/body/mlp/router/top_k", "lm_experts", "prefill"),
+    ("prefill/layers/while/body/mlp/dispatch/sort", "lm_experts", "prefill"),
+    ("decode/while/body/layers/while/body/mlp/experts/while/body/cond/"
+     "branch_1_fun/dot_general", "lm_experts", "decode"),
+    ("decode/while/body/layers/while/body/mlp/reshape", "lm_experts",
+     "decode"),
+    ("decode/while/body/layers/while/body/mlp/zero_experts/reduce_sum",
+     "lm_zero", "decode"),
+    ("prefill/layers/while/body/mlp/zero_experts/add", "lm_zero", "prefill"),
+    ("prefill/layers/while/body/add", "lm_proj", "prefill"),
+    ("decode/while/body/final_norm/mul", "lm_norm", "decode"),
+    ("decode/while/body/lm_head/fewrow_dense/pallas_call", "lm_head",
+     "decode"),
+    ("decode/while/body/sample/argmax", "lm_head", "decode"),
+    ("prefill/embed_tokens/gather", "embed", "prefill"),
+])
+def test_the_seventh_models_scopes_fall_in_their_classes_and_phases(
+        path, want, phase):
+    name = "jit(lm_generate)/LongcatFlash/" + path
+    assert trace.classify(name) == want
+    assert trace.phase_of(name) == phase
+    # under another family's model the zero experts' scope is nobody's
+    if want == "lm_zero":
+        assert trace.classify(name.replace("LongcatFlash", "PanguUltraMoE")) \
+            != "lm_zero"
