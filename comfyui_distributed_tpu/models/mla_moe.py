@@ -390,12 +390,17 @@ def _attend_expanded(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
 
 
 def _attend_absorbed(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
-                     first):
+                     first, own=None):
     """The same mathematics on the latent cache ``latent [B, T, 576]``:
     ``W_UK`` folded into the query, ``W_UV`` into the output.  The heads'
     queries meet ONE key and one value a position (the latent has no
     head axis), so they go to `xla_attention` as the queries of a single
-    head.  -> ``[B, N, H, d_v]``."""
+    head.  With ``own [B, N]`` (a call behind a shared prefix) a query
+    that is not its row's own sees nothing, as a padded one: a selection,
+    so `xla_attention` normalises behind the product with the values and
+    the ``[B, N H, T]`` scores' row maximum is no ``reduce-window`` (2.31
+    ms against 2.97 at 4 x 97 queries on 2,112 latents: PERF.md section
+    6, PR 50).  -> ``[B, N, H, d_v]``."""
     B, N, H, _ = q_nope.shape
     w_uk, w_uv = jnp.split(_kv_b(cfg, lp), [cfg.qk_nope_head_dim], axis=-1)
     with jax.named_scope("absorb_q"):
@@ -404,33 +409,46 @@ def _attend_absorbed(cfg: MlaMoeConfig, lp, q_nope, q_rope, latent, index,
     q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(cfg.dtype)
     scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
     ATTENTION_PATHS.bump("xla_decode" if N == 1 else "xla_causal")
+    mask = {} if own is None \
+        else {"selected": jnp.repeat(own, H, axis=1)[..., None]}
     o_lat = xla_attention(
         q.reshape(B, N * H, 1, -1), latent[:, :, None],
         latent[:, :, None, :cfg.kv_lora_rank], scale,
-        jnp.repeat(index, H), first).reshape(B, N, H, -1)
+        jnp.repeat(index, H), first, **mask).reshape(B, N, H, -1)
     with jax.named_scope("absorb_v"):
         return jnp.einsum("bnhr,rhd->bnhd", o_lat.astype(cfg.dtype), w_uv,
                           preferred_element_type=jnp.float32)
 
 
 def _self_attn(cfg, lp, n, positions, index, first, cache, l,
-               absorbed: bool, q_scale: float = 1.0, kv_scale: float = 1.0):
+               absorbed: bool, q_scale: float = 1.0, kv_scale: float = 1.0,
+               prefix: int = 0):
     """Latent attention of the normed ``n [B, N, d]`` at ``positions
     [B, N]``, and the cache with this call's latent written into slot
     ``l`` at the buffer indices ``index``.  With ``absorbed`` the queries
     attend to the cache (a decode step), without to this call's own
-    latent, expanded (the prefill).  -> ``W_o``'s output ``[B, N, d]``."""
+    latent, expanded (the prefill).  Behind ``prefix`` positions a row that
+    the cache holds already (a family's ``from_prefix``; 0: no operation
+    is added) a call is ``absorbed`` (its keys are the cache's), writes a
+    row's OWN entries alone (`lm_decode.own_entries`) and lets only a
+    row's own queries see.  -> ``W_o``'s output ``[B, N, d]``."""
     B, N, _ = n.shape
     q_nope, q_rope = _queries(cfg, lp, n, positions, q_scale)
     latent = _latent(cfg, lp, n, positions, kv_scale)
+    own = index[None, :] >= first[:, None] + prefix if prefix else None
     with jax.named_scope("kv_cache"):
+        if prefix:
+            latent = lm_decode.own_entries(own, latent, cache, l, index[0])
         cache = jax.lax.dynamic_update_slice(
             cache, latent[None].astype(cache.dtype), (l, 0, index[0], 0))
         if absorbed:
             latent = jax.lax.dynamic_index_in_dim(
                 cache, l, keepdims=False).astype(cfg.dtype)
-    attend = _attend_absorbed if absorbed else _attend_expanded
-    a = attend(cfg, lp, q_nope, q_rope, latent, index, first)
+    if absorbed:
+        a = _attend_absorbed(cfg, lp, q_nope, q_rope, latent, index, first,
+                             own)
+    else:
+        a = _attend_expanded(cfg, lp, q_nope, q_rope, latent, index, first)
     with jax.named_scope("o_proj"):
         return _dense(a.reshape(B, N, -1), lp["o_proj"], cfg), cache
 

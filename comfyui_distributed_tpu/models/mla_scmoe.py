@@ -58,7 +58,10 @@ shared expert.  The zero experts are ONE scaled add of the layer's input
 ``zero_experts``: no gather, no loop over experts, no product.
 
 Served as one jitted program, ``lm_generate`` (`lm_decode.generate`
-around the ``prefill`` and ``step`` closures below).  Routing is
+around the ``prefill`` and ``step`` closures below).  Rows whose prompts
+start with the same ids (an operator's instructions) start from a resident
+SNAPSHOT of what those ids leave in the cache slots and prefill what
+follows them only (`make_prefix_program`, `from_prefix`).  Routing is
 discontinuous, so the program returns beside the logits what it routed
 by (``aux``): the scores it SELECTED by (``p + b``), its choices and the
 weights it gave them.
@@ -306,14 +309,15 @@ def load_checkpoint(path: str, cfg: LongcatFlashConfig):
 # --- the layer ------------------------------------------------------------
 
 def _attention(cfg: LongcatFlashConfig, sp, s: int, x, positions, index,
-               first, cache, slot, absorbed: bool):
+               first, cache, slot, absorbed: bool, prefix: int = 0):
     """``x + MLA_s(N_in_s(x))`` and the cache with this call's latent in
     ``slot``: `mla_moe._self_attn` with this family's two scales."""
     with jax.named_scope(f"input_layernorm_{s}"):
         n = _rms_norm(x, sp["input_layernorm"], cfg.rms_norm_eps)
     with jax.named_scope(f"self_attn_{s}"):
         a, cache = _self_attn(cfg, sp, n, positions, index, first, cache,
-                              slot, absorbed, cfg.q_scale, cfg.kv_scale)
+                              slot, absorbed, cfg.q_scale, cfg.kv_scale,
+                              prefix)
     return x + a, cache
 
 
@@ -344,11 +348,14 @@ def _moe(cfg: LongcatFlashConfig, rp, experts, l, u):
 
 
 def _stack(cfg: LongcatFlashConfig, params, x, index, first, cache,
-           absorbed: bool):
+           absorbed: bool, prefix: int = 0):
     """Every layer held.  ``cache`` is the ``[2 L, B, T, 576]`` latent
-    buffer; each attention writes this call's entries into its own slot at
-    the buffer indices ``index [N]`` (consecutive, the same for every
-    row); row ``b``'s real entries start at ``first[b]``, its position 0.
+    buffer (behind a shared prefix `from_prefix`'s, which holds the first
+    ``prefix`` of a row's real entries already: a call behind them writes a
+    row's own entries alone); each attention writes this call's entries
+    into its own slot at the buffer indices ``index [N]`` (consecutive, the
+    same for every row); row ``b``'s real entries start at ``first[b]``,
+    its position 0.
     The scan walks the layer INDEX with the stacked leaves closed over
     (`looplm.layer_of`: a few-row product streams its leaf in place; the
     two sub-layers of a layer are two indices into one leaf).  Returns the
@@ -363,7 +370,7 @@ def _stack(cfg: LongcatFlashConfig, params, x, index, first, cache,
         x, cache = carry
         s0, s1 = (layer_of(params["sublayers"], 2 * l + s) for s in (0, 1))
         h, cache = _attention(cfg, s0, 0, x, positions, index, first, cache,
-                              2 * l, absorbed)
+                              2 * l, absorbed, prefix)
         with jax.named_scope("post_attention_layernorm_0"):
             u = _rms_norm(h, s0["post_attention_layernorm"], eps)
         with jax.named_scope("mlp"):
@@ -372,7 +379,7 @@ def _stack(cfg: LongcatFlashConfig, params, x, index, first, cache,
         with jax.named_scope("mlps_0"):
             h = h + _gated_mlp(cfg, s0, u)
         h2, cache = _attention(cfg, s1, 1, h, positions, index, first, cache,
-                               2 * l + 1, absorbed)
+                               2 * l + 1, absorbed, prefix)
         with jax.named_scope("post_attention_layernorm_1"):
             n = _rms_norm(h2, s1["post_attention_layernorm"], eps)
         with jax.named_scope("mlps_1"):
@@ -388,10 +395,15 @@ def _stack(cfg: LongcatFlashConfig, params, x, index, first, cache,
         tuple(c.sum(axis=0) for c in counts)
 
 
-def keys_seen(cfg: LongcatFlashConfig, length: int, index, first):
+def keys_seen(cfg: LongcatFlashConfig, length: int, index, first,
+              prefix: int = 0):
     """The keys the mask of this call lets each row's queries at ``index``
-    see among ``length``, summed over the attentions held: ``[B]``."""
+    see among ``length``, summed over the attentions held: ``[B]``.
+    Behind ``prefix`` positions the cache holds already, a row's OWN
+    queries': a slot where the end of its prefix stands is nobody's."""
     seen = visible_keys(length, index, kv_start=first)
+    if prefix:
+        seen = seen & (index[None, :] >= first[:, None] + prefix)[..., None]
     return cfg.sublayers * seen.sum(axis=(1, 2), dtype=jnp.int32)
 
 
@@ -429,15 +441,93 @@ def kv_cache_bytes(cfg: LongcatFlashConfig, batch: int, length: int) -> int:
         * jnp.dtype(cfg.dtype).itemsize
 
 
+# --- a prefix shared between requests ----------------------------------------
+#
+# What a prompt's first K ids leave behind is K latents and rotary keys in
+# each of the ``2 L`` cache slots: the SNAPSHOT (`make_prefix_program`), the
+# cache of one row with no axis of rows, beside what the routers CHOSE over
+# those K positions (`generate`'s ``aux`` records the whole prompt's).
+# Rows whose prompts start with those ids start from copies of it and
+# prefill their own suffix only.  Legal because a token's position counts
+# from its row's first real id (`_stack`'s ``positions``): a prefix's
+# rotary key is rotated the same in every row, wherever the row's padding
+# pushes it in the buffer, so the snapshot can stand at each row's own
+# offset; and nothing else of the state depends on where an entry lies.
+
+def prefix_bytes(cfg: LongcatFlashConfig, positions: int) -> int:
+    """Bytes of the snapshot behind ``positions`` ids: the latent slots'
+    part and the record of the experts chosen (int32)."""
+    return kv_cache_bytes(cfg, 1, positions) \
+        + positions * cfg.num_layers * cfg.moe_topk * 4
+
+
+def make_prefix_program(cfg: LongcatFlashConfig):
+    """The jitted maker of a snapshot, ``lm_prefix_state`` (NOT
+    ``lm_generate``: what is counted and timed an execution is the served
+    program's): ``prefix_ids [K]``, one row and no padding, through every
+    layer as a prefill -> ``keys [2 L, K, 576]``, the scaled latents and
+    the rotated keys as the cache slots hold them, and ``choices
+    [K, L, k]``, the experts the routers chose there."""
+
+    def lm_prefix_state(params, prefix_ids):
+        K, = prefix_ids.shape
+        # The row stands behind padding of its own, in a buffer of a
+        # multiple of 128 positions: `xla_attention` walks its score block
+        # in chunks that DIVIDE the buffer, and the cell's 1,951 ids are a
+        # prime (one query a chunk: 281 ms an attention on a v5e where
+        # 2,048 positions take 7.6, PERF.md section 6, PR 50).
+        pad = -K % 128
+        first = jnp.full((1,), pad, jnp.int32)
+        ids = jnp.pad(prefix_ids, (pad, 0))[None]
+        with jax.named_scope("LongcatFlash"), jax.named_scope("prefill"):
+            _, cache, routed, _ = _stack(
+                cfg, params, _embed(params, ids), jnp.arange(pad + K), first,
+                empty_cache(cfg, 1, pad + K), absorbed=False)
+        return {"keys": cache[:, 0, pad:], "choices": routed[1][0, pad:]}
+
+    return jax.jit(lm_prefix_state)
+
+
+def from_prefix(cache, prefix, first):
+    """`empty_cache`'s ``cache`` with every row started from the snapshot
+    ``prefix``: its K entries of every slot written at row ``b``'s own
+    offset ``first[b]``, directly in front of where that row's suffix will
+    be written (the padding lies in front of both, so the mask stays
+    ``kv_start = first`` with no hole)."""
+    with jax.named_scope("kv_cache"):
+        return lm_decode.write_at_offsets(cache, prefix["keys"], first)
+
+
+def _prefix_choices(prefix, first, chosen):
+    """The routers' choices over the WHOLE prompt buffer behind a
+    snapshot: ``chosen [B, S, L, k]`` of the suffix's call stands behind
+    the snapshot's K positions, and row ``b``'s ``first[b] ... first[b] +
+    K - 1`` hold the snapshot's own (over a shorter row's first suffix
+    slots too: they are its prefix's end)."""
+    K = prefix["choices"].shape[0]
+    with jax.named_scope("router"):
+        return lm_decode.write_at_offsets(
+            jnp.pad(chosen, ((0, 0), (K, 0), (0, 0), (0, 0))),
+            prefix["choices"], first, rows=0)
+
+
 # --- the served program ---------------------------------------------------
 
 def generate(cfg: LongcatFlashConfig, max_new_tokens: int, params,
-             prompt_ids, prompt_len, seed, temperature
+             prompt_ids, prompt_len, seed, temperature, prefix=None
              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array],
                         Dict[str, jax.Array]]:
     """Prefill, then ``max_new_tokens`` decode steps, for every row:
     `looplm.generate`'s contract (rows, lengths, seeds, temperatures,
-    padding never attended to).  Returns the new ids ``[B, N]``, the
+    padding never attended to).  With a snapshot ``prefix`` of K ids
+    (`make_prefix_program`) ``prompt_ids [B, S]`` holds what FOLLOWS them
+    in every row: each row starts from the snapshot (`from_prefix`) and
+    the layers run over the ``S`` positions behind it, their queries
+    absorbed onto the cache slots as a decode step's are; the buffer of
+    ``P = K + S`` positions is laid out ``padding | prefix | row's own
+    ids``, a row's last id at ``P - 1`` as without one, so the decode
+    steps and every buffer index below are what they are without.
+    Returns the new ids ``[B, N]``, the
     float32 logits each was drawn from ``[B, N, V]``, ``aux`` (where those
     logits were computed: what the routers selected by, ``router_scores
     [B, N, L, E + Z]`` = ``p + b``, chose, ``expert_choices [B, N, L, k]``,
@@ -450,31 +540,39 @@ def generate(cfg: LongcatFlashConfig, max_new_tokens: int, params,
     (distinct local experts with at least one pair, over all rows of a
     step), ``keys_attended [B]`` (what the steps' masks let a row's query
     see, over the ``2 L`` attentions).  Over the PREFILL:
-    ``prefill_positions`` (every position of every row the blocks ran),
+    ``prefill_positions`` (every position of every row THE BLOCKS RAN
+    OVER: of a prefix served from a snapshot nothing is computed and
+    nothing counted, while its choices stand in ``aux``),
     ``expert_pairs_local_prefill [B]``, ``expert_pairs_zero_prefill [B]``
-    (over the whole prompt buffer), ``expert_rows_computed_prefill`` (the
-    rows the experts multiplied: `_routed`'s tiles x their rows) and
-    ``keys_attended_prefill [B]``.  Over both ``expert_pairs_dropped``
-    (0)."""
-    B, P = prompt_ids.shape
-    first = P - jnp.broadcast_to(prompt_len, (B,))
+    (over the buffer the blocks ran over), ``expert_rows_computed_prefill``
+    (the rows the experts multiplied: `_routed`'s tiles x their rows) and
+    ``keys_attended_prefill [B]`` (behind a snapshot a row's own queries'
+    keys: its prefix's and its own causal part).  Over both
+    ``expert_pairs_dropped`` (0)."""
+    B, S = prompt_ids.shape
+    K = lm_decode.prefix_length(prefix)
+    P = K + S
+    first = S - jnp.broadcast_to(prompt_len, (B,))
+    index = jnp.arange(K, P)
 
     def blocks(ids, first):
-        """The prompt buffers ``ids [b, P]`` through the blocks: the state
+        """The prompt buffers ``ids [b, S]`` through the blocks: the state
         behind each row's last id, the rows' cache, what the routers
         recorded there and chose over the buffer, the counts."""
+        cache = empty_cache(cfg, ids.shape[0], P + max_new_tokens)
+        if prefix is not None:
+            cache = from_prefix(cache, prefix, first)
         x, cache, routed, counts = _stack(
-            cfg, params, _embed(params, ids), jnp.arange(P), first,
-            empty_cache(cfg, ids.shape[0], P + max_new_tokens),
-            absorbed=False)
-        return (x[:, P - 1:], cache, tuple(r[:, P - 1] for r in routed),
+            cfg, params, _embed(params, ids), index, first, cache,
+            absorbed=prefix is not None, prefix=K)
+        return (x[:, S - 1:], cache, tuple(r[:, S - 1] for r in routed),
                 routed[1], counts)
 
     def prefill():
         with jax.named_scope("prefill"):
             # every row's last real id at P - 1
             ids = jax.vmap(jnp.roll)(prompt_ids, first)
-            b = rows_a_pass(B, P)
+            b = rows_a_pass(B, S)
             if b == B:
                 x, cache, chosen, prompt_choices, counts = blocks(ids, first)
             else:
@@ -482,18 +580,21 @@ def generate(cfg: LongcatFlashConfig, max_new_tokens: int, params,
                     return a.reshape(B, *a.shape[2:])
                 x, cache, chosen, prompt_choices, counts = jax.lax.map(
                     lambda group: blocks(*group),
-                    (ids.reshape(B // b, b, P), first.reshape(B // b, b)))
+                    (ids.reshape(B // b, b, S), first.reshape(B // b, b)))
                 x, prompt_choices = rows(x), rows(prompt_choices)
                 chosen = tuple(rows(c) for c in chosen)
                 cache = jnp.moveaxis(cache, 0, 1).reshape(
                     cfg.sublayers, B, *cache.shape[3:])
                 counts = tuple(rows(c) if c.ndim == 2 else c.sum()
                                for c in counts)
+            if prefix is not None:
+                prompt_choices = _prefix_choices(prefix, first,
+                                                 prompt_choices)
             zeros = jnp.zeros((B,), jnp.int32)
             return (_head(cfg, params, x)[:, 0], chosen, cache,
                     (zeros, zeros, jnp.int32(0), counts[3], zeros),
                     (prompt_choices, counts,
-                     keys_seen(cfg, P, jnp.arange(P), first)))
+                     keys_seen(cfg, P, index, first, K)))
 
     def step(token, i, cache):
         index = P + i[None]
@@ -515,7 +616,7 @@ def generate(cfg: LongcatFlashConfig, max_new_tokens: int, params,
             {"expert_pairs_local": pairs, "expert_pairs_zero": zeros,
              "expert_hits": hits, "expert_pairs_dropped": dropped,
              "keys_attended": keys,
-             "prefill_positions": jnp.int32(B * P),
+             "prefill_positions": jnp.int32(B * S),
              "expert_pairs_local_prefill": prefill_pairs,
              "expert_pairs_zero_prefill": prefill_zeros,
              "expert_rows_computed_prefill": prefill_rows,
@@ -524,7 +625,9 @@ def generate(cfg: LongcatFlashConfig, max_new_tokens: int, params,
 
 def make_program(cfg: LongcatFlashConfig, max_new_tokens: int):
     """The jitted program, named ``lm_generate`` (``jit_lm_generate`` in a
-    device trace) like every language model's."""
+    device trace) like every language model's.  With a sixth argument,
+    `make_prefix_program`'s snapshot, ``prompt_ids`` holds what follows
+    the prefix."""
     return lm_decode.make_program(
         functools.partial(generate, cfg, max_new_tokens))
 

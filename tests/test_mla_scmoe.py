@@ -236,6 +236,292 @@ def test_absorbed_equals_expanded_with_both_scales():
                                out[True][1][0, ..., 16:], atol=1e-6)
 
 
+# --- a prefix shared between requests ---------------------------------------
+#
+# Every row's prompt is the same 37 ids (a prime: no multiple of anything a
+# buffer is cut by, the tokenizer's words among them) and then its own: 12,
+# 11, 5 and 1 ids behind them in a suffix buffer of 12 (one row fills it,
+# one is a single id; their offsets in the buffer are 0, 1, 7 and 11).  The
+# whole prompt is 49 positions.
+
+PREFIX, SUFFIX = 37, 12
+OWN = [12, 11, 5, 1]
+
+
+def shared_prompts():
+    """Per row the whole prompt: the same 37 ids, then the row's own."""
+    rng = np.random.RandomState(12)
+    head = rng.randint(3, TINY.vocab_size, PREFIX)
+    return [np.concatenate([head, rng.randint(3, TINY.vocab_size, n)]
+                           ).astype(np.int32) for n in OWN]
+
+
+@functools.lru_cache(maxsize=None)
+def shared_programs(dtype):
+    """The maker and the served program, jitted once a dtype."""
+    cfg, _ = of_dtype(dtype)
+    return mla_scmoe.make_prefix_program(cfg), mla_scmoe.make_program(cfg, NEW)
+
+
+@functools.lru_cache(maxsize=None)
+def snapshot_of(dtype):
+    return shared_programs(dtype)[0](
+        of_dtype(dtype)[1], jnp.asarray(shared_prompts()[0][:PREFIX]))
+
+
+def buffers(picked, held):
+    """The prompt buffer of the rows ``picked`` with their first ``held``
+    ids left out (a snapshot stands for them), and the lengths."""
+    whole = shared_prompts()
+    ids = np.zeros((len(picked), PREFIX + SUFFIX - held), np.int32)
+    for b, i in enumerate(picked):
+        ids[b, :len(whole[i]) - held] = whole[i][held:]
+    return ids, np.asarray([len(whole[i]) - held for i in picked], np.int32)
+
+
+def serve_shared(dtype, snapshot, picked=(0, 1, 2, 3), temperature=0.0,
+                 program=None):
+    """One execution over the rows ``picked`` of `shared_prompts`: from
+    the ``snapshot`` of the 37 ids, or with None the whole prompts through
+    the five-argument program."""
+    ids, lens = buffers(picked, 0 if snapshot is None else PREFIX)
+    tokens, logits, aux, stats = (program or shared_programs(dtype)[1])(
+        of_dtype(dtype)[1], ids, lens, np.asarray(picked, np.uint32) + 3,
+        np.asarray([temperature] * len(picked), np.float32),
+        *(() if snapshot is None else (snapshot,)))
+    whole = shared_prompts()
+    return [{"prompt_ids": whole[i], "tokens": np.asarray(tokens[b]),
+             "logits": np.asarray(logits[b]),
+             **{k: np.asarray(v[b]) for k, v in aux.items()}}
+            for b, i in enumerate(picked)], {
+                k: np.asarray(v) for k, v in stats.items()}
+
+
+@pytest.mark.parametrize("picked", [(0,), (3,), (0, 1, 2, 3), (2, 1, 1, 1)])
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_rows_started_from_a_snapshot_are_the_whole_prompts_rows(
+        picked, temperature):
+    """Alone or four of unequal length (a row that fills the suffix
+    buffer, a single id, a padded execution whose last rows repeat one),
+    greedy or sampled with seeds: the ids of the program over the whole
+    prompt, its logits to float32's rounding (the suffix's queries run
+    ABSORBED on the cache slots, the whole prompt's expanded), over each
+    row's REAL positions the same record of the experts chosen; and the
+    reference forced to that record agrees (the fourfold comparison, which
+    reads all of it)."""
+    cfg, p = of_dtype("float32")
+    snapshot = snapshot_of("float32")
+    served, stats = serve_shared("float32", snapshot, picked, temperature)
+    full, full_stats = serve_shared("float32", None, picked, temperature)
+    P = PREFIX + SUFFIX
+    for got, want, i in zip(served, full, picked):
+        first = SUFFIX - OWN[i]
+        assert np.array_equal(got["tokens"], want["tokens"])
+        np.testing.assert_allclose(got["logits"], want["logits"], rtol=0,
+                                   atol=2e-5)
+        assert got["prompt_choices"].shape == (P, 2, 4)
+        assert np.array_equal(got["prompt_choices"][first:],
+                              want["prompt_choices"][first:])
+        assert np.array_equal(got["expert_choices"], want["expert_choices"])
+        for name in ("router_scores", "expert_weights"):
+            np.testing.assert_allclose(got[name], want[name], rtol=0,
+                                       atol=5e-6)
+        if temperature == 0:        # (a sampled id's margin is no reading)
+            reading = compare(cfg, p, got)
+            assert reading["correct"] and reading["flipped"] == 0, reading
+    # what the program COMPUTED: the 12 positions behind the prefix, whose
+    # queries (a row's own) attend to the prefix's 37 keys and their own
+    # causal part in each of four attentions; the decode steps read what
+    # they read without a snapshot
+    B = len(picked)
+    assert (stats["prefill_positions"], full_stats["prefill_positions"]) \
+        == (B * SUFFIX, B * P)
+    for b, i in enumerate(picked):
+        n = OWN[i]
+        assert stats["keys_attended_prefill"][b] == 4 * sum(
+            PREFIX + j + 1 for j in range(n))
+        assert full_stats["keys_attended_prefill"][b] \
+            == 4 * (PREFIX + n) * (PREFIX + n + 1) // 2
+        for kind in ("local", "zero"):
+            name = f"expert_pairs_{kind}_prefill"
+            assert stats[name][b] <= full_stats[name][b] <= P * 2 * 4
+        assert stats["expert_pairs_local_prefill"][b] \
+            + stats["expert_pairs_zero_prefill"][b] <= SUFFIX * 2 * 4
+    for name in ("keys_attended", "expert_pairs_local", "expert_pairs_zero",
+                 "expert_hits", "expert_pairs_dropped"):
+        assert np.array_equal(stats[name], full_stats[name]), name
+
+
+def test_bf16_rows_behind_a_snapshot_are_the_references():
+    """In the configuration's precision the absorbed suffix and the
+    expanded whole prompt round differently, so the two paths' ids may
+    part; each is held to the reference under its OWN choices by the
+    limits the whole-prompt path is held to."""
+    cfg, p = of_dtype("bfloat16")
+    served, _ = serve_shared("bfloat16", snapshot_of("bfloat16"))
+    assert snapshot_of("bfloat16")["keys"].dtype == jnp.bfloat16
+    for b, row in enumerate(served):
+        got = compare(cfg, p, row)
+        assert got["correct"] and got["mean_over_std"] > 1e-4, (b, got)
+
+
+@pytest.mark.parametrize("b", range(4))
+def test_a_row_behind_a_snapshot_is_its_single_row_run(b):
+    """Four rows of unequal length from one snapshot give, each, what
+    they give alone through the 1-row program (whose suffix buffer they do
+    not fill either: the padding lies in front of prefix and suffix
+    both), whatever the other rows hold."""
+    snapshot = snapshot_of("float32")
+    served, _ = serve_shared("float32", snapshot)
+    (alone,), _ = serve_shared("float32", snapshot, (b,))
+    assert np.array_equal(alone["tokens"], served[b]["tokens"])
+    np.testing.assert_allclose(served[b]["logits"], alone["logits"], rtol=0,
+                               atol=2e-5)
+    assert np.array_equal(alone["expert_choices"],
+                          served[b]["expert_choices"])
+    first = SUFFIX - OWN[b]
+    assert np.array_equal(alone["prompt_choices"][first:],
+                          served[b]["prompt_choices"][first:])
+
+
+def _prefilled(snapshot=None):
+    """The cache behind the prefill of `shared_prompts`' four rows, the
+    routers' choices over the buffer and ``first``: the whole prompts from
+    an empty cache, or their suffixes behind ``snapshot``."""
+    cfg, p = of_dtype("float32")
+    K = 0 if snapshot is None else PREFIX
+    ids, lens = buffers((0, 1, 2, 3), K)
+
+    def run(params, ids, lens, snapshot):
+        S = ids.shape[1]
+        first = S - lens
+        cache = mla_scmoe.empty_cache(cfg, 4, K + S + NEW)
+        if snapshot is not None:
+            cache = mla_scmoe.from_prefix(cache, snapshot, first)
+        _, cache, routed, _ = mla_scmoe._stack(
+            cfg, params,
+            mla_scmoe._embed(params, jax.vmap(jnp.roll)(ids, first)),
+            jnp.arange(K, K + S), first, cache, absorbed=K > 0, prefix=K)
+        return cache, routed[1], first
+
+    return jax.jit(run)(p, ids, lens, snapshot)
+
+
+def test_the_snapshot_is_what_the_whole_prefill_leaves_behind_the_prefix():
+    """At the cache's width, one row and no axis of rows: 37 latents and
+    rotary keys in each of the ``2 L`` slots, the ones the whole prefill of
+    a longer prompt writes at those positions of each row (whatever its
+    padding: a rotary key is rotated by the row's position), with the
+    record of what the 37 positions chose; behind the suffix's prefill all
+    four slots are the whole prefill's over every real position, each row's
+    prefix at its own offset and nothing in front of it."""
+    snapshot = snapshot_of("float32")
+    assert {k: (v.shape, v.dtype) for k, v in snapshot.items()} == {
+        "keys": ((4, PREFIX, 24), TINY.dtype),
+        "choices": ((PREFIX, 2, 4), jnp.int32)}
+    assert sum(v.nbytes for v in snapshot.values()) \
+        == mla_scmoe.prefix_bytes(TINY, PREFIX) \
+        == 4 * PREFIX * (16 + 8) * 4 + PREFIX * 2 * 4 * 4
+    whole, whole_chosen, first = _prefilled()
+    behind, chosen, same = _prefilled(snapshot)
+    assert list(first) == list(same) == [SUFFIX - n for n in OWN]
+    P = PREFIX + SUFFIX
+    for b, at in enumerate(first):
+        np.testing.assert_allclose(whole[:, b, at:at + PREFIX],
+                                   snapshot["keys"], rtol=0, atol=1e-5)
+        np.testing.assert_array_equal(behind[:, b, at:at + PREFIX],
+                                      snapshot["keys"])
+        np.testing.assert_allclose(behind[:, b, at:P], whole[:, b, at:P],
+                                   rtol=0, atol=1e-5)
+        assert float(jnp.abs(behind[:, b, :at]).max(initial=0)) == 0
+        assert float(jnp.abs(behind[:, b, P:]).max()) == 0
+        np.testing.assert_array_equal(whole_chosen[b, at:at + PREFIX],
+                                      snapshot["choices"])
+        # (the suffix's call records its own S positions alone)
+        np.testing.assert_array_equal(chosen[b, at:],
+                                      whole_chosen[b, PREFIX + at:])
+    # the published size: 9,216 B a position and 192 B of choices behind
+    # the cell's 1,951 ids
+    full = mla_scmoe.LONGCAT_FLASH_OMNI_SHARE
+    assert mla_scmoe.kv_cache_bytes(full, 1, 1951) == 1951 * 9216 \
+        == 17_980_416
+    assert mla_scmoe.prefix_bytes(full, 1951) - 17_980_416 \
+        == 1951 * 4 * 12 * 4 == 374_592
+
+
+def test_the_maker_is_not_the_served_program_and_the_phases_stay():
+    """The maker is ``lm_prefix_state``: the cells' pattern for the served
+    program (``^jit_lm_generate$``) does not match it, so its seconds are
+    no execution's.  The program that starts from a snapshot is still
+    ``lm_generate``, with every class and both phases; the rows' start
+    from the snapshot is the cache's and the choices' assembly the
+    router's."""
+    cfg, p = of_dtype("float32")
+    maker = mla_scmoe.make_prefix_program(cfg).lower(
+        p, jnp.zeros((PREFIX,), jnp.int32))
+    assert "jit_lm_prefix_state" in maker.as_text()[:200]
+    assert not re.match("^jit_lm_generate$", "jit_lm_prefix_state")
+    ids, lens = buffers((0, 1, 2, 3), PREFIX)
+    lowered = mla_scmoe.make_program(cfg, 3).lower(
+        p, jnp.asarray(ids), lens, np.zeros(4, np.uint32),
+        np.zeros(4, np.float32), snapshot_of("float32"))
+    assert "jit_lm_generate" in lowered.as_text()[:200]
+    names = [n for n in re.findall(r'op_name="([^"]+)"',
+                                   lowered.compile().as_text())
+             if "LongcatFlash" in n]
+    assert {trace.classify(n) for n in names} == LM_CLASSES
+    assert {trace.phase_of(n) for n in names} == {"prefill", "decode"}
+    copies = [n for n in names
+              if re.search(r"prefill/(kv_cache|router)/", n)]
+    assert {(trace.classify(n), trace.phase_of(n)) for n in copies} == {
+        ("lm_cache", "prefill"), ("lm_experts", "prefill")}
+
+
+def _rotated_from_the_buffers_index(monkeypatch):
+    """A snapshot whose rotary keys were rotated from where row 2's prefix
+    stands in the BUFFER (its offset 7 on) instead of from the row's own
+    positions 0 .. 36: no row's suffix meets the keys it left."""
+    cfg, p = of_dtype("float32")
+    off, ids = SUFFIX - OWN[2], jnp.asarray(shared_prompts()[0][:PREFIX])
+    _, cache, routed, _ = mla_scmoe._stack(
+        cfg, p, mla_scmoe._embed(p, ids[None]), jnp.arange(off, off + PREFIX),
+        jnp.zeros((1,), jnp.int32),
+        mla_scmoe.empty_cache(cfg, 1, off + PREFIX), absorbed=False)
+    return {"keys": cache[:, 0, off:], "choices": routed[1][0]}, (0, 1, 2, 3)
+
+
+def _padded_slots_written(monkeypatch):
+    """A shorter row's padded slots written over the end of its prefix
+    (`lm_decode.own_entries` lets every new entry through): the row that
+    fills its buffer has no padded slot and cannot tell."""
+    monkeypatch.setattr(mla_scmoe.lm_decode, "own_entries",
+                        lambda own, new, cache, l, at: new)
+    return snapshot_of("float32"), (1, 2, 3)
+
+
+@pytest.mark.parametrize("breakage", [_rotated_from_the_buffers_index,
+                                      _padded_slots_written])
+def test_a_snapshot_path_with_the_mechanism_broken_is_refused(breakage,
+                                                              monkeypatch):
+    """Prefix keys rotated from the buffer's index, and a padded slot
+    overwriting the prefix's last entries: every row the breakage reaches
+    fails the comparison the served path passes, the others are what they
+    were."""
+    cfg, p = of_dtype("float32")
+    snapshot, broken = breakage(monkeypatch)
+    # (a program of its own: the jitted one was traced with the mechanism)
+    served, _ = serve_shared("float32", snapshot,
+                             program=mla_scmoe.make_program(cfg, NEW))
+    good, _ = serve_shared("float32", snapshot_of("float32"))
+    for b in range(4):
+        if b in broken:
+            got = compare(cfg, p, served[b])
+            assert not got["correct"] and got["max_over_std"] > 0.01, (b, got)
+        else:
+            np.testing.assert_allclose(served[b]["logits"], good[b]["logits"],
+                                       rtol=0, atol=2e-5)
+
+
 # --- each wrong program fails the comparison ----------------------------------
 
 def test_the_verify_script_refuses_every_departure_the_reference_names():
@@ -544,13 +830,23 @@ def test_the_registry_serves_it_and_counts_the_three_kinds_of_pair(
     assert 0 <= got["lm.expert_hits"] <= 5 * 2 * 4
     assert got["lm.expert_pairs_dropped"] == 0
     assert got["lm.expert_pairs_local_prefill"] \
-        + got["lm.expert_pairs_zero_prefill"] <= 4 * 32 * 2 * 4
+        + got["lm.expert_pairs_zero_prefill"] <= 4 * (32 - 7) * 2 * 4
     assert got["lm.expert_pairs_local_prefill"] \
         <= got["lm.expert_rows_computed_prefill"]
-    assert got["lm.prefill_positions"] == 4 * 32        # every program row
+    # every row of the program, the padded one too, behind the snapshot
+    assert got["lm.prefill_positions"] == 4 * (32 - 7)
+    # the three real rows, from the snapshot the first request made
+    assert got["lm.prefix_hits"] == 3
+    assert got["lm.prefix_positions_served"] == 3 * 7
+    assert got.get("lm.prefix_misses", 0) == 0 and before[
+        "lm.prefix_misses"] >= 1
     real = got["lm.prompt_tokens"]                      # of three rows
     assert got["lm.keys_attended"] == 4 * (5 * real + 3 * (1 + 2 + 3 + 4 + 5))
-    assert got["lm.keys_attended_prefill"] > 0
+    # a row's n own queries see the 7 prefix keys and their causal part
+    # (the padded row repeats the first)
+    own = [len(i) - 7 for _, o in out for i in [o.prompt_ids]]
+    assert got["lm.keys_attended_prefill"] == 4 * sum(
+        7 * n + n * (n + 1) // 2 for n in own + own[:1])
     gauges = trace.GLOBAL_GAUGES.snapshot()
     assert gauges["lm.kv_cache_bytes"] == \
         mla_scmoe.kv_cache_bytes(TINY, 4, 37) == 4 * 4 * 37 * 24 * 4
@@ -558,7 +854,78 @@ def test_the_registry_serves_it_and_counts_the_three_kinds_of_pair(
     assert lm_out.row == 2 and set(lm_out.aux) == {
         "router_scores", "expert_choices", "expert_weights",
         "prompt_choices"}
+    # the record covers the WHOLE prompt buffer, the snapshot's positions too
+    assert lm_out.aux["prompt_choices"].shape == (4, 32, 2, 4)
     assert len(words.split()) <= 5
+
+
+def lm_delta(before):
+    after = counters()
+    return {k[3:]: after[k] - before.get(k, 0) for k in after
+            if k.startswith("lm.") and after[k] != before.get(k, 0)}
+
+
+def asked(model, rows, **kw):
+    before = counters()
+    out = model.generate_rows(rows, max_new_tokens=3, prompt_tokens=32, **kw)
+    return [words for words, _ in out], lm_delta(before)
+
+
+GUIDE = "style guide number 0 of many"          # 7 ids with the first
+
+
+def test_the_rule_finds_this_familys_prefix_and_its_snapshot_is_made_once(
+        model, monkeypatch):
+    """`shared_prefix` finds the instructions' ids for this family (a
+    rotary key is rotated from a row's first real id: the snapshot stands
+    at any offset); two executions make the snapshot once and count a hit
+    a row; the words are those of the whole prompt prefilled (the rule
+    held off) and of each row alone."""
+    model._prefixes.clear()
+    rows = [registry.LMRow(f"a walled garden in june number {i}", i, 0.7 * i,
+                           instructions=GUIDE) for i in range(3)]
+    found = model.shared_prefix(rows, 32)
+    assert list(found) == model.tokenizer.encode(GUIDE) and len(found) == 7
+    words, got = asked(model, rows)
+    assert (got["prefix_misses"], got["prefix_hits"],
+            got["prefix_positions_served"]) == (1, 3, 3 * 7)
+    assert got["prefill_positions"] == 4 * 25
+    assert trace.GLOBAL_GAUGES.snapshot()["lm.prefix_bytes"] \
+        == mla_scmoe.prefix_bytes(TINY, 7) == 7 * (4 * 24 * 4 + 2 * 4 * 4)
+    again, got = asked(model, rows[:2])
+    assert again == words[:2] and "prefix_misses" not in got
+    assert got["prefix_hits"] == 2 and got["prefill_positions"] == 4 * 25
+    for i, row in enumerate(rows):
+        alone, got = asked(model, [row])
+        assert alone == [words[i]] and got["prefill_positions"] == 25
+    monkeypatch.setattr(registry.LanguageModel, "shared_prefix",
+                        lambda self, *a: None)
+    whole, got = asked(model, rows)
+    assert whole == words and len(set(words)) == 3
+    assert "prefix_hits" not in got and got["prefill_positions"] == 4 * 32
+
+
+@pytest.mark.parametrize("what, rows", [
+    ("no instructions", [("a cat", "")] * 2),
+    ("instructions that differ between the rows",
+     [("a cat", GUIDE), ("a dog", GUIDE.replace("0", "1"))]),
+    ("one row without", [("a cat", GUIDE), ("a dog", "")]),
+])
+def test_rows_that_share_no_instructions_run_the_whole_prompt(what, rows,
+                                                              model):
+    """The rule reads its input: everything but the same non-empty
+    instructions in every row is the execution it was, every position
+    computed, no snapshot made, none counted."""
+    rows = [registry.LMRow(text, i, instructions=instructions)
+            for i, (text, instructions) in enumerate(rows)]
+    assert model.shared_prefix(rows, 32) is None
+    _, got = asked(model, rows)
+    assert got["prefill_positions"] == 4 * 32
+    assert got["keys_attended_prefill"] == 4 * sum(
+        n * (n + 1) // 2 for n in [
+            len(model.prompt_ids(r.text, 32, r.instructions))
+            for r in rows + rows[:1] * 2])
+    assert not [k for k in got if k.startswith("prefix_")]
 
 
 @pytest.mark.parametrize("name, want", [

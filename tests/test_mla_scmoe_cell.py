@@ -5,12 +5,16 @@ entries by membership, ``lib/lm_scmoe_bytes.py`` against hand counts, the
 three readers on a made-up context (with the program's counters, classes
 and phases, and on the other six families' programs, which have none of
 them, as the parent), the accepted readers on the new program, and the
-cell's rehearsal on the CPU.
+cell's rehearsal on the CPU.  Since PR 50 the cell's rows start from a
+resident snapshot of the operator's instructions: the cell's traffic
+against `LanguageModel.shared_prefix`, the rehearsal's window counters,
+and the two accepted readers on a window that prefilled 97 positions a row.
 """
 
 import importlib.util
 import json
 import os
+import random
 import subprocess
 import sys
 
@@ -398,11 +402,45 @@ def test_the_utilisation_reader_counts_what_the_program_counted():
     assert reader("lm_scmoe_prefill_flops_util_pct")(ctx) \
         == pytest.approx(want)
     assert 40 < want < 43
-    # a later resident prefix: a tenth of the positions computed is a
-    # tenth of the products, whatever `prompt_tokens` says
+    # a resident prefix: a tenth of the positions computed is a tenth of
+    # the products, whatever `prompt_tokens` says
     counters = ctx.metrics_window["pipeline"]["counters"]
     counters["lm.prefill_positions"] //= 10
     assert reader("lm_scmoe_prefill_flops_util_pct")(ctx) < 0.2 * want
+
+
+def test_both_accepted_readers_read_a_window_behind_a_snapshot():
+    """The window's counters as the cell's executions leave them since
+    PR 50 (4 program rows x 97 positions, a row's ~39 own queries against
+    the 1,951 prefix keys and their causal part in eight attentions, the
+    routers over 388 positions) under a prefill of a twentieth of the
+    seconds: both readers divide by what the PROGRAM counted, so neither
+    falls silent and neither passes 100."""
+    ctx = context()
+    counters = ctx.metrics_window["pipeline"]["counters"]
+    executions = counters["lm.executions"]
+    keys = 4 * 8 * sum(1951 + j + 1 for j in range(39))
+    counters.update({
+        "lm.prefill_positions": executions * 4 * 97,
+        "lm.keys_attended_prefill": executions * keys,
+        "lm.expert_pairs_local_prefill": executions * 388,
+        "lm.expert_pairs_zero_prefill": executions * 6200,
+        "lm.expert_rows_computed_prefill": executions * 2048,
+        "lm.prefix_hits": counters["lm.rows"],
+        "lm.prefix_positions_served": counters["lm.rows"] * 1951})
+    program = ctx.metrics_window["profile"]["programs"]["jit_lm_generate"]
+    program["phases"]["prefill"] = 0.026
+    program["account"]["by_phase"]["prefill"] = {
+        "lm_proj": 0.006, "lm_mlp": 0.012, "lm_attn": 0.004,
+        "lm_experts": 0.002, "lm_cache": 0.001, "idle": 0.001}
+    util = reader("lm_scmoe_prefill_flops_util_pct")(ctx)
+    flops = scmoe_bytes.prefill_flops(ctx.config["lm"], 4 * 97, 4.0, keys,
+                                      388)
+    assert util == pytest.approx(100.0 * flops / 0.026 / 197e12)
+    assert 0 < util < 100
+    # 2.0 TFLOP: 388 positions x 2.55 G values x 2 and the attention's part
+    assert 2.0e12 < flops < 2.3e12
+    assert 0 < reader("lm_scmoe_decode_hbm_roofline_pct")(ctx) < 100
 
 
 @pytest.mark.parametrize("other", OTHER_CONFIGS)
@@ -490,11 +528,57 @@ def test_the_cell_rehearses_on_the_cpu(tmp_path):
     assert counters["lm.expert_pairs_local"] \
         + counters["lm.expert_pairs_zero"] <= counters["lm.expert_pairs"]
     assert counters["lm.expert_pairs_dropped"] == 0
-    assert counters["lm.prefill_positions"] == rows * 48
+    # every row starts from the snapshot of the rehearsal's instructions
+    # (14 ids with the first; a rotary key is rotated from a row's first
+    # real id, so the snapshot stands at each row's offset) and the 34
+    # positions behind them are computed, not 48
+    held = 14
+    assert counters["lm.prefix_hits"] == counters["lm.rows"]
+    assert counters["lm.prefix_positions_served"] \
+        == counters["lm.rows"] * held
+    assert "lm.prefix_misses" not in counters      # made by the warm-ups
+    assert "lm.prefix_evictions" not in counters
+    assert counters["lm.prefill_positions"] == rows * (48 - held)
     assert counters["lm.expert_pairs_local_prefill"] \
-        + counters["lm.expert_pairs_zero_prefill"] <= rows * 48 * 2 * 4
+        + counters["lm.expert_pairs_zero_prefill"] \
+        <= rows * (48 - held) * 2 * 4
     real = counters["lm.prompt_tokens"]
     assert counters["lm.keys_attended"] == 4 * (
         4 * real + counters["lm.rows"] * (1 + 2 + 3 + 4))
-    assert counters["lm.keys_attended_prefill"] > 0
-    assert "lm.prefix_hits" not in counters     # this family keeps no snapshot
+    # a row's n own queries see the 14 prefix keys and their causal part:
+    # at least the real rows' (a padded row repeats one of them)
+    assert counters["lm.keys_attended_prefill"] >= 4 * held * (
+        real - counters["lm.rows"] * held)
+
+
+def test_the_cells_instructions_are_a_true_prefix_of_every_request():
+    """What `LanguageModel.shared_prefix` needs of the CELL's traffic, at
+    the published vocabulary slice: the operator's instructions encode to
+    1,951 ids (the start id and 1,950 words) that are the first ids of
+    every request's prompt, with 1 to 97 ids of the row's own behind them
+    inside the 2048 positions.  A silent fall-back to the whole prompt
+    would fail here, not only on the chip."""
+    import numpy as np
+    from comfyui_distributed_tpu.models import mla_scmoe, registry, tokenizer
+    node = config()["graph"]["21"]["inputs"]
+    model = registry.LanguageModel(
+        "longcat-flash-omni.safetensors", mla_scmoe.LONGCAT_FLASH_OMNI_SHARE,
+        None, tokenizer.make_lm_tokenizer(None, 16384), "longcat")
+    with open(os.path.join(BENCH, "traffic", "words.txt")) as f:
+        words = [w.strip() for w in f if w.strip()]
+    rng = random.Random(7)
+    rows = [registry.LMRow(" ".join(rng.choice(words) for _ in range(12)),
+                           i, instructions=node["instructions"])
+            for i in range(4)]
+    prefix = model.shared_prefix(rows, node["prompt_tokens"])
+    assert prefix is not None and len(prefix) == 1951
+    for row in rows:
+        ids = model.prompt_ids(row.text, node["prompt_tokens"],
+                               row.instructions)
+        assert np.array_equal(ids[:1951], prefix)
+        assert 1 <= len(ids) - 1951 <= node["prompt_tokens"] - 1951 == 97
+    # one row with other instructions, and the execution runs whole
+    other = [*rows[:3], registry.LMRow("a cat", 3, instructions="draw it")]
+    assert model.shared_prefix(other, node["prompt_tokens"]) is None
+    assert mla_scmoe.prefix_bytes(model.cfg, 1951) == 18_355_008 \
+        == 1951 * (9216 + 4 * 12 * 4)
