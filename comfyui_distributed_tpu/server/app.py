@@ -241,6 +241,12 @@ class ServerState:
         # with room in its row set waits here for the set to empty
         self._owed: set = set()            # guarded-by: self._queue_lock
         self._drained = threading.Condition(self._queue_lock)
+        # when each hand-over to another thread was last announced
+        # (perf_counter_ns; trace.woke on the waiter's side names the wait)
+        self._drained_ns = 0               # guarded-by: self._queue_lock
+        self._queued_ns = 0
+        self._finalized_ns = 0
+        trace_mod.install_gc_monitoring()
         self._running = False
         self._draining = False
         self._history: Dict[str, Any] = {}
@@ -488,6 +494,7 @@ class ServerState:
         # dormant log, so ownership transfers by re-logging them here.
         if self.durable is not None and (not _recovered or _absorbed):
             self.durable.log_enqueue(pid, prompt, client_id, extra_data)
+        self._queued_ns = trace_mod.now_ns()
         self._queue_event.set()
         return pid
 
@@ -595,10 +602,13 @@ class ServerState:
         return group
 
     def _exec_loop(self) -> None:
+        trace_mod.thread_role(trace_mod.EXECUTOR)
         while True:
             with trace_mod.stage("exec_idle"):
+                began_ns = trace_mod.now_ns()
                 self._queue_event.wait()
                 self._exec_gate.wait()
+                trace_mod.woke("queue", self._queued_ns, began_ns)
             self._purge_abandoned()
             group = self._pop_group()
             if group is None:
@@ -684,6 +694,7 @@ class ServerState:
         if self.overlap_enabled:
             # hand host-side joining to the finalizer so the next
             # group's compute starts NOW — this is the overlap
+            self._finalized_ns = trace_mod.now_ns()
             self._finalize_q.put((group, res, err, t0))
         else:
             self._finalize_group(group, res, err, t0)
@@ -696,13 +707,17 @@ class ServerState:
         with self._queue_lock:
             self._finalize_pending += 1
         if self.overlap_enabled:
+            self._finalized_ns = trace_mod.now_ns()
             self._finalize_q.put((group, res, err, t0))
         else:
             self._finalize_group(group, res, err, t0)
 
     def _finalize_loop(self) -> None:
+        trace_mod.thread_role(trace_mod.FINALIZER)
         while True:
+            began_ns = trace_mod.now_ns()
             group, res, err, t0 = self._finalize_q.get()
+            trace_mod.woke("finalize", self._finalized_ns, began_ns)
             self._finalize_group(group, res, err, t0)
 
     def _image_settled(self, head: Dict[str, Any]) -> None:
@@ -715,6 +730,7 @@ class ServerState:
         with self._queue_lock:
             head["settled"] = True
             self._owed.discard(head["id"])
+            self._drained_ns = trace_mod.now_ns()
             self._drained.notify_all()
 
     def _record_queue_to_device(self, group, ready_fallback: float) -> None:
@@ -1041,6 +1057,7 @@ class ServerState:
             # a generate node that waits for the device is let go: what
             # was dispatched is being cancelled
             self._owed.clear()
+            self._drained_ns = trace_mod.now_ns()
             self._drained.notify_all()
         done_t = time.time()
         for item in purged:
@@ -1080,6 +1097,7 @@ def build_app(state: Optional[ServerState] = None) -> web.Application:
 
     async def on_startup(app):
         state.loop = asyncio.get_running_loop()
+        trace_mod.thread_role(trace_mod.HTTP)
         # recovery resume off the event loop: it health-polls the
         # workers and may enqueue several prompts.  Needs state.port
         # (the recovery redispatch graphs embed this master's URL) —
@@ -2469,6 +2487,12 @@ def build_app(state: Optional[ServerState] = None) -> web.Application:
         return web.json_response(state.shard.merge_gossip(data))
 
     async def post_prompt(request):
+        # the handler from the body's read to the response, on the event
+        # loop's thread (the admission itself runs off it, under the GIL)
+        with trace_mod.stage("http_prompt"):
+            return await _post_prompt(request)
+
+    async def _post_prompt(request):
         data = await request.json()
         prompt = data.get("prompt")
         if not isinstance(prompt, dict) or not prompt:
@@ -2795,6 +2819,7 @@ def build_app(state: Optional[ServerState] = None) -> web.Application:
                 # client gone = cancellation signal: flag the job; the
                 # CB driver's boundary scan / queue purge finalizes it
                 bus.abandon(pid)
+                state._queued_ns = trace_mod.now_ns()
                 state._queue_event.set()
                 if pid in state._history:
                     # finalize raced the disconnect: the job settled

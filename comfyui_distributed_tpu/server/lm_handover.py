@@ -146,13 +146,18 @@ class GenerateHandover:
         meets the device here as it does in ``lm_generate``'s wait: not
         the host's own seconds of ``dispatch``."""
         state = self._state
+        began_ns = trace_mod.now_ns()
         with state._queue_lock:
             if not state._owed:
                 return False
-        with trace_mod.stage("lm_drain_wait"), trace_mod.device_wait(), \
-                state._queue_lock:
-            while state._owed:
-                state._drained.wait()
+        with trace_mod.stage("lm_drain_wait"), trace_mod.device_wait():
+            with state._queue_lock:
+                while state._owed:
+                    state._drained.wait()
+                drained_ns = state._drained_ns
+            # from the LAST image's notify on the host pool's thread to
+            # here: the set was not empty at `began_ns`, so one came since
+            trace_mod.woke("drain", drained_ns, began_ns)
         return True
 
     def _waiting(self, model: Any, max_new_tokens: int, prompt_tokens: int,

@@ -188,16 +188,20 @@ class HostIOPool:
     def __init__(self, max_workers: Optional[int] = None,
                  max_pending: Optional[int] = None):
         import concurrent.futures
+        import functools
         import os
 
         from comfyui_distributed_tpu.utils import constants as C
+        from comfyui_distributed_tpu.utils import trace as trace_mod
         max_workers = max_workers or int(os.environ.get(
             C.HOSTIO_THREADS_ENV, C.HOSTIO_THREADS_DEFAULT))
         max_pending = max_pending or int(os.environ.get(
             C.HOSTIO_PENDING_ENV, C.HOSTIO_PENDING_DEFAULT))
         self._pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=max(1, max_workers),
-            thread_name_prefix="dtpu-hostio")
+            thread_name_prefix="dtpu-hostio",
+            initializer=functools.partial(trace_mod.thread_role,
+                                          trace_mod.HOST_POOL))
         self._slots = threading.BoundedSemaphore(max(1, max_pending))
         self._pending = 0  # guarded-by: self._idle
         self._idle = threading.Condition(threading.Lock())
@@ -222,8 +226,10 @@ class HostIOPool:
         self._slots.acquire()
         with self._idle:
             self._pending += 1
+        submitted_ns = trace_mod.now_ns()
 
         def run():
+            trace_mod.woke("pool", submitted_ns)
             try:
                 with trace_mod.transfer_context(captured), \
                         trace_mod.use_span(captured_span):

@@ -23,7 +23,13 @@ Here profiling is a first-class subsystem:
   on the profiler's clock, and ``stop`` ends by reducing the trace to a
   summary (``trace_summary.py``): device seconds per jitted program by
   kernel class (:data:`KERNEL_CLASSES`, read from the module paths the
-  operations carry), idle seconds by the host span over them;
+  operations carry), idle seconds by the host span over them; beside
+  the annotations a **host timeline** the profiler cannot cut (every
+  timed interval by thread role on ``perf_counter_ns``, the hand-overs
+  between threads as ``wake_*``, the collector's pauses as ``gc_pause``),
+  written by ``stop`` as ``host_timeline.json`` and laid on the trace's
+  clock by two ``dtpu/clock_sync`` markers, so that every idle second
+  BETWEEN programs has one owner: the executor's interval at that instant;
 - host<->device transfer accounting (:class:`TransferStats`): every device
   edge in the ops layer reports bytes through :func:`record_transfer`,
   attributed to the executing workflow node (:func:`node_scope`) — the
@@ -47,11 +53,14 @@ chip benchmark's to measure: ``PERF.md`` §6).
 from __future__ import annotations
 
 import contextvars
+import gc
+import json
 import os
 import re
 import threading
 import time
-from collections import OrderedDict
+import weakref
+from collections import OrderedDict, deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -465,6 +474,227 @@ def _end_annotation(ann) -> None:
         ann.__exit__(None, None, None)
 
 
+# --- the host timeline ---------------------------------------------------------
+#
+# What the device's idle time BETWEEN programs is held against
+# (``trace_summary.py``: ``idle_by_executor``).  Every interval the program
+# times (stage / span / device_wait, the caller-measured record_stage /
+# event_span, the hand-overs of :func:`woke`, the collector's pauses) is
+# also an entry of its thread's LANE: ``(name, start_ns, depth)`` on
+# ``time.perf_counter_ns`` while it is open, a row of the ring once it has
+# closed.  With the profiler off that is an append and a pop; the ring is
+# armed by :func:`start_device_trace` alone.  The lanes' open entries are
+# reachable from there and from :func:`stop_device_trace`, so an interval
+# that began before the slice, or is still open at its end, is written
+# clipped to it where the profiler drops the annotation whole.
+
+TIMELINE_RING = 1 << 16         # closed intervals kept a slice, oldest out
+TIMELINE_FILE = "host_timeline.json"
+CLOCK_SYNC = "clock_sync"       # the annotation that carries perf_counter_ns
+# what a thread says it is (:func:`thread_role`); a thread that never said
+# is ``other``.  The executor is the thread whose next enqueue the device
+# waits for
+EXECUTOR, FINALIZER, HOST_POOL, HTTP = (
+    "executor", "finalizer", "host_pool", "http")
+NO_ROLE = "other"
+GC_PAUSE = "gc_pause"
+WAKE_PREFIX = "wake_"
+MEASURED = -1                   # the depth of an interval its caller measured:
+                                # not of the thread's stack, owns no idle time
+
+_now_ns = time.perf_counter_ns
+
+
+def now_ns() -> int:
+    """The timeline's clock: what a notifier stamps for :func:`woke`."""
+    return _now_ns()
+
+
+class _Lane:
+    """One thread's intervals: its role, and the ones open now."""
+
+    __slots__ = ("role", "thread", "open", "__weakref__")
+
+    def __init__(self, thread: str):
+        self.role, self.thread = NO_ROLE, thread
+        self.open: List[tuple] = []     # (name, start_ns, depth), outermost first
+
+
+_lane_state = threading.local()
+# a lane lives as long as its thread (the thread's locals hold it)
+_lanes: "weakref.WeakSet[_Lane]" = weakref.WeakSet()  # guarded-by: _lanes_lock
+_lanes_lock = threading.Lock()
+_ring: "deque[tuple]" = deque(maxlen=TIMELINE_RING)   # (lane, entry, end_ns)
+_ring_armed = False
+# rows since the ring was armed (what it lost: this less its length).
+# Re-entrant: the collector's callback keeps its pause from whatever thread
+# it interrupts, `_keep` itself among them
+_ring_added = 0                 # guarded-by: _ring_lock
+_ring_lock = threading.RLock()
+
+
+def _lane() -> _Lane:
+    lane = getattr(_lane_state, "lane", None)
+    if lane is None:
+        lane = _lane_state.lane = _Lane(threading.current_thread().name)
+        with _lanes_lock:
+            _lanes.add(lane)
+    return lane
+
+
+def thread_role(role: str) -> None:
+    """Said once by a thread that is the executor, the finaliser, of the
+    host pool or the HTTP loop."""
+    _lane().role = role
+
+
+def _open(name: str, start_ns: int):
+    lane = _lane()
+    entry = (name, start_ns, len(lane.open))
+    lane.open.append(entry)
+    return lane, entry
+
+
+def _close(lane: _Lane, entry: tuple, end_ns: int) -> None:
+    opened = lane.open
+    if opened and opened[-1] is entry:
+        opened.pop()
+    elif entry in opened:       # two handlers of one event loop interleave
+        opened.remove(entry)
+    if _ring_armed:
+        _keep(lane, entry, end_ns)
+
+
+def _keep(lane: Optional[_Lane], entry: tuple, end_ns: int) -> None:
+    global _ring_added
+    with _ring_lock:
+        _ring.append((lane, entry, end_ns))
+        _ring_added += 1
+
+
+def _keep_wall(name: str, start_s: float, end_s: float) -> None:
+    """An interval with wall-clock bounds, onto the timeline's clock."""
+    if _ring_armed:
+        now_ns, now_s = _now_ns(), time.time()
+        _keep(_lane(), (name, now_ns - int((now_s - start_s) * 1e9), MEASURED),
+              now_ns - int((now_s - end_s) * 1e9))
+
+
+def woke(what: str, stamp_ns: int, since_ns: int = 0) -> None:
+    """The waiter's half of a hand-over between threads, called when its
+    wait has returned: ``wake_<what>`` from the notifier's stamp
+    (``perf_counter_ns``, read under the lock the notifier already holds)
+    to now, a stage and an interval of THIS thread.  A stamp from before
+    ``since_ns`` (when the waiter began to wait) woke nobody: what it
+    announced was there already."""
+    if stamp_ns > since_ns:
+        name, end_ns = WAKE_PREFIX + what, _now_ns()
+        GLOBAL_STAGES.record(name, (end_ns - stamp_ns) / 1e9)
+        if _ring_armed:
+            lane = _lane()
+            _keep(lane, (name, stamp_ns, len(lane.open)), end_ns)
+
+
+# The collector stops every thread.  Its callback runs on whichever thread
+# allocated last, possibly inside one of this module's locks (a histogram's
+# snapshot builds a dict under its own), so it takes none: a pause goes to
+# a deque, and `_fold_gc` makes stages and counters of them from a thread
+# that holds nothing (the end of a stage; every read of the aggregates).
+_gc_pauses: "deque[tuple]" = deque(maxlen=4096)     # (start_ns, end_ns, gen)
+_gc_start_ns = 0
+_gc_installed = False
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    global _gc_start_ns
+    if phase == "start":
+        _gc_start_ns = _now_ns()
+        return
+    end_ns = _now_ns()
+    _gc_pauses.append((_gc_start_ns, end_ns, info.get("generation", 0)))
+    if _ring_armed:
+        lane = getattr(_lane_state, "lane", None)
+        _keep(lane, (GC_PAUSE, _gc_start_ns,
+                     len(lane.open) if lane is not None else 0), end_ns)
+
+
+def install_gc_monitoring() -> None:
+    """Time every collection (``gc.callbacks``; idempotent): stage
+    ``gc_pause``, counters ``gc.collections`` and ``gc.collections_gen2``,
+    and an interval of the thread it ran on."""
+    global _gc_installed
+    if not _gc_installed:
+        _gc_installed = True
+        gc.callbacks.append(_on_gc)
+
+
+def _fold_gc() -> None:
+    while _gc_pauses:
+        try:
+            start_ns, end_ns, generation = _gc_pauses.popleft()
+        except IndexError:      # another thread folded it
+            return
+        GLOBAL_STAGES.record(GC_PAUSE, (end_ns - start_ns) / 1e9)
+        GLOBAL_COUNTERS.bump("gc.collections")
+        if generation == 2:
+            GLOBAL_COUNTERS.bump("gc.collections_gen2")
+
+
+def _clock_sync() -> int:
+    """A ``dtpu/clock_sync`` annotation that carries ``perf_counter_ns``
+    as a statistic: where the timeline's clock stands on the profiler's.
+    The first annotation after a pause pays the wrapper's cold path (5-25
+    us seen), so one without a name of ours goes in front; and a thread
+    taken off its core between the clock's read and the annotation would
+    put the two clocks that far apart, so a marker that took long is
+    made again (the reader takes the one whose value the timeline
+    names)."""
+    import jax
+    for _ in range(3):
+        with jax.profiler.TraceAnnotation(CLOCK_SYNC + "_warm"):
+            pass
+        ns = _now_ns()
+        with jax.profiler.TraceAnnotation(HOST_PREFIX + CLOCK_SYNC,
+                                          perf_counter_ns=ns):
+            pass
+        if _now_ns() - ns < 50_000:
+            break
+    return ns
+
+
+def _timeline_slice(start_ns: int, stop_ns: int) -> Dict[str, Any]:
+    """The slice ``[start_ns, stop_ns]`` of the timeline: the ring's rows
+    and what is open on any lane now, clipped to it, times from its
+    start."""
+    with _lanes_lock:
+        lanes = list(_lanes)
+    still_open = [(lane, entry) for lane in lanes for entry in list(lane.open)]
+    rows = list(_ring)
+    closed = {id(entry) for _, entry, _ in rows}
+    rows += [(lane, entry, stop_ns) for lane, entry in still_open
+             if id(entry) not in closed]
+    lane_no: Dict[int, int] = {}
+    lane_rows, names, intervals = [], {}, []
+    for lane, (name, t0, depth), t1 in rows:
+        t0, t1 = max(t0, start_ns), min(t1, stop_ns)
+        if t1 <= t0:
+            continue
+        key = id(lane)
+        if key not in lane_no:
+            lane_no[key] = len(lane_rows)
+            lane_rows.append({"role": lane.role if lane else NO_ROLE,
+                              "thread": lane.thread if lane else ""})
+        intervals.append([lane_no[key], names.setdefault(name, len(names)),
+                          t0 - start_ns, t1 - start_ns, depth])
+    intervals.sort(key=lambda r: (r[2], r[4]))
+    return {"clock": "perf_counter_ns", "start_ns": start_ns,
+            "stop_ns": stop_ns, "ring": TIMELINE_RING,
+            "dropped": max(_ring_added - len(_ring), 0),
+            "lanes": lane_rows, "names": list(names),
+            "columns": ["lane", "name", "start_ns", "end_ns", "depth"],
+            "intervals": intervals}
+
+
 # --- pipeline stage timeline -------------------------------------------------
 
 # Per-job stage wall-clock for the overlapped serving pipeline
@@ -507,12 +737,16 @@ def stage(name: str, own: bool = False):
     ``<name>_wait``.  Its span keeps the whole interval, carries the
     difference as ``device_wait_s``, and is added beside the spans opened
     inside the block (they stay children of the current span) when the
-    block ends."""
-    t0 = time.perf_counter()
+    block ends.
+
+    It is an entry of this thread's lane of the host timeline too (and so
+    is a :func:`span`), from the same two clock reads."""
+    t0_ns = _now_ns()
     wall0 = time.time()
     w0 = _waited_s()
     sp = None if own else _begin_span(name)
     ann = _annotate(name)
+    lane, entry = _open(name, t0_ns)
     try:
         yield
     except BaseException:
@@ -521,7 +755,11 @@ def stage(name: str, own: bool = False):
         raise
     finally:
         _end_annotation(ann)
-        dur = time.perf_counter() - t0
+        t1_ns = _now_ns()
+        _close(lane, entry, t1_ns)
+        dur = (t1_ns - t0_ns) / 1e9
+        if _gc_pauses:
+            _fold_gc()
         if own:
             # ``device_wait`` sums every thread's waits; this is the
             # share that lay inside this stage
@@ -570,6 +808,8 @@ def record_stage(name: str, start_s: float, end_s: float,
     GLOBAL_STAGES.record(name, end_s - start_s)
     if parent is not None:
         event_span(name, start_s, end_s, parent=parent)
+    else:
+        _keep_wall(name, start_s, end_s)
 
 
 class CounterStats:
@@ -656,6 +896,7 @@ GLOBAL_GAUGES = GaugeStats()
 
 def pipeline_snapshot() -> Dict[str, Any]:
     """The serving-pipeline block of /distributed/metrics."""
+    _fold_gc()
     return {"stages": GLOBAL_STAGES.snapshot(),
             "counters": GLOBAL_COUNTERS.snapshot(),
             "gauges": GLOBAL_GAUGES.snapshot()}
@@ -666,6 +907,7 @@ def pipeline_snapshot() -> Dict[str, Any]:
 _trace_lock = threading.Lock()
 _trace_dir: Optional[str] = None
 _trace_t0 = 0.0
+_trace_sync_ns = 0      # perf_counter_ns of the slice's first clock marker
 # the program's own reduction of its last device trace (trace_summary.py);
 # kept until the next one, on /distributed/metrics as "profile"
 _profile: Optional[Dict[str, Any]] = None
@@ -673,8 +915,10 @@ SUMMARY_TIMEOUT_S = 900.0
 
 
 def start_device_trace(out_dir: Optional[str] = None) -> str:
-    """Begin a ``jax.profiler`` trace (TensorBoard/Perfetto format)."""
-    global _trace_dir, _trace_t0
+    """Begin a ``jax.profiler`` trace (TensorBoard/Perfetto format), arm
+    the host timeline's ring and mark where its clock stands on the
+    profiler's."""
+    global _trace_dir, _trace_t0, _trace_sync_ns, _ring_armed, _ring_added
     import jax
     with _trace_lock:
         if _trace_dir is not None:
@@ -683,16 +927,21 @@ def start_device_trace(out_dir: Optional[str] = None) -> str:
             os.getcwd(), "traces", time.strftime("%Y%m%d-%H%M%S"))
         os.makedirs(out_dir, exist_ok=True)
         jax.profiler.start_trace(out_dir)
+        _ring.clear()
+        _ring_added, _ring_armed = 0, True
         _trace_dir, _trace_t0 = out_dir, time.time()
+        _trace_sync_ns = _clock_sync()
         log(f"device trace started -> {out_dir}")
         return out_dir
 
 
 def stop_device_trace() -> str:
-    """Stop the trace, then reduce the ``.xplane.pb`` it wrote
-    (:func:`_summarize`): the summary is kept for :func:`profile_summary`
-    and written beside the trace as ``summary.json``."""
-    global _trace_dir, _profile
+    """Mark the clocks again, stop the trace, write the timeline's part
+    of the slice beside the ``.xplane.pb`` (``host_timeline.json``), then
+    reduce both (:func:`_summarize`): the summary is kept for
+    :func:`profile_summary` and written beside the trace as
+    ``summary.json``."""
+    global _trace_dir, _profile, _ring_armed
     import jax
     with _trace_lock:
         if _trace_dir is None:
@@ -700,14 +949,24 @@ def stop_device_trace() -> str:
         out = _trace_dir
         t_stop = time.time()
         try:
+            timeline = _timeline_slice(_trace_sync_ns, _clock_sync())
+            # the profiler takes many seconds to write a slice out: what
+            # the threads time meanwhile is not the slice's
+            _ring_armed = False
             jax.profiler.stop_trace()
         finally:
             # a raising stop_trace must still clear the state: leaving
             # _trace_dir set would wedge every later start_device_trace
             # with "trace already running" for the life of the process
-            _trace_dir = None
+            _trace_dir, _ring_armed = None, False
+            _ring.clear()
         written_s = time.time() - t_stop
         log(f"device trace stopped -> {out} (written in {written_s:.1f}s)")
+    found = _find_xplane(out)
+    if found is not None:   # a profiler that wrote nothing leaves no file
+        with open(os.path.join(os.path.dirname(found), TIMELINE_FILE), "w",
+                  encoding="utf-8") as f:
+            json.dump(timeline, f, separators=(",", ":"))
     summary = _summarize(out, t_stop - _trace_t0)
     if summary is not None:
         summary["stop_trace_s"] = round(written_s, 3)
@@ -716,20 +975,24 @@ def stop_device_trace() -> str:
     return out
 
 
+def _find_xplane(trace_dir: str) -> Optional[str]:
+    """The newest ``.xplane.pb`` under ``trace_dir``."""
+    found = [os.path.join(base, f) for base, _, files in os.walk(trace_dir)
+             for f in files if f.endswith(".xplane.pb")]
+    return max(found, key=os.path.getmtime) if found else None
+
+
 def _summarize(trace_dir: str, traced_s: float) -> Optional[Dict[str, Any]]:
     """Reduce the trace under ``trace_dir`` in a child process
     (``JAX_PLATFORMS=cpu``: reading the protobuf imports JAX, and this
     process's GIL and chip are busy serving).  None where the profiler
     left no ``.xplane.pb`` or the child failed (logged, never raised:
     the trace itself is on disk either way)."""
-    import json
     import subprocess
     import sys
-    found = [os.path.join(base, f) for base, _, files in os.walk(trace_dir)
-             for f in files if f.endswith(".xplane.pb")]
-    if not found:
+    path = _find_xplane(trace_dir)
+    if path is None:
         return None
-    path = max(found, key=os.path.getmtime)
     out_path = os.path.join(trace_dir, "summary.json")
     t0 = time.time()
     try:
@@ -1193,6 +1456,7 @@ def span(name: str, **attrs: Any):
     None (and records nothing) when no trace is active."""
     sp = _begin_span(name, **attrs)
     ann = _annotate(name)
+    lane, entry = _open(name, _now_ns())
     try:
         yield sp
     except BaseException as e:
@@ -1201,6 +1465,7 @@ def span(name: str, **attrs: Any):
         raise
     finally:
         _end_annotation(ann)
+        _close(lane, entry, _now_ns())
         _end_span(sp)
 
 
@@ -1241,6 +1506,7 @@ def event_span(name: str, start_s: float, end_s: float,
     if _trace_dir is not None:
         _end_annotation(_annotate(name, start_s=round(start_s, 6),
                                   end_s=round(end_s, 6)))
+    _keep_wall(name, start_s, end_s)
     return _add_event_span(name, start_s, end_s, parent, trace_id,
                            parent_id, attrs, status)
 
@@ -1630,6 +1896,7 @@ def prometheus_text(extra: Optional[List[Tuple[str, str, str,
     caller families as ``(name, type, help, [(labels, value), ...])`` —
     the server layer appends its prompt/image counters and queue gauge."""
     lines: List[str] = []
+    _fold_gc()
     _render_histogram_family(
         lines, "dtpu_stage_seconds",
         "Serving-pipeline stage wall-clock (overlapping stages).",
@@ -1713,6 +1980,7 @@ def reset_aggregate_metrics() -> Dict[str, Any]:
     telemetry.  Retrace counters are monotonic observations of
     jax.monitoring and are NOT reset (readers diff marks); the flight
     recorder keeps its per-job history unless asked."""
+    _fold_gc()      # a pause that ended before the reset is not the next window's
     before = {"phases": len(GLOBAL_PHASES.snapshot()),
               "stages": len(GLOBAL_STAGES.snapshot()),
               "nodes": len(GLOBAL_NODES.snapshot()),

@@ -55,20 +55,41 @@ annotations.  The summary holds, per chip and as a mean over chips:
   (``none`` under none), and ``idle_under``: the same seconds under each
   span name at any depth; ``gaps_in_programs_s``: the idle seconds
   inside executions, which no host span can answer for;
+* where the trace has a host timeline beside it (``host_timeline.json``,
+  written by ``trace.stop_device_trace`` since PR 51, and the two
+  ``dtpu/clock_sync`` annotations that say where its clock stands on the
+  trace's: ``clock_drift_ns`` is what the second disagrees by, and past
+  MAX_DRIFT_NS the summary carries ``timeline_error`` and none of the
+  following): ``idle_by_executor``, the same seconds between executions
+  and the slice's two edges with them (``idle_between_s``), by the ONE
+  thread whose next enqueue the device waits for: cut at the edges of the
+  ``executor`` role's intervals, each piece is the executor's innermost
+  interval's at that instant (an interval the slice's edge cuts counts,
+  clipped), a collector pause on any thread wins over it, ``unowned``
+  where the executor has none, so the rows add up to ``idle_between_s``
+  by construction; ``idle_by_executor_class``, the same seconds in the
+  six rows of EXEC_CLASSES; ``idle_under_executor``, the same seconds
+  under each of the executor's intervals at ANY depth (``dispatch`` is
+  never the innermost: a node's span always lies in it), as
+  ``idle_under`` is to ``idle``; ``top_idle_between``, the stretches that
+  cost most by their owner and the programs on either side (``s``
+  seconds, ``n`` stretches, ``owner``, ``before``, ``after``);
 * ``names_found``: whether any operation carried a path at all.
 """
 
 from __future__ import annotations
 
 import json
+import os
 import re
 import sys
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from comfyui_distributed_tpu.utils.trace import HOST_PREFIX, OTHER, \
-    classify, phase_of
+from comfyui_distributed_tpu.utils.trace import CLOCK_SYNC, EXECUTOR, \
+    GC_PAUSE, HOST_PREFIX, OTHER, TIMELINE_FILE, WAKE_PREFIX, classify, \
+    phase_of
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
@@ -81,6 +102,15 @@ OP_NAME = "tf_op"       # the event-metadata statistic that holds op_name
 TOP_OTHER = 8           # the costliest unclassed operations a program lists
 TOP_IDLE = 8            # and the costliest idle stretches inside it
 STARTS, ENDS = "(the execution starts)", "(the execution ends)"
+SLICE_STARTS, SLICE_ENDS = "(the slice starts)", "(the slice ends)"
+MAX_DRIFT_NS = 100_000  # the two clock markers may disagree by this much
+UNOWNED = "unowned"
+# who the idle time between programs waited for, by the executor's
+# interval over it: its own code, a hand-over from another thread
+# (``wake_*``), a request, the device, the collector, nothing it timed
+EXEC_CLASSES = ("host", "wake", "wait_request", "wait_device", "gc", UNOWNED)
+WAIT_REQUEST = ("exec_idle",)
+WAIT_DEVICE = ("device_wait", "lm_drain_wait")
 
 
 # --- the event metadata, straight from the protobuf ---------------------------
@@ -194,6 +224,7 @@ def read_events(path: str) -> Dict[str, Any]:
     data = ProfileData.from_file(path)
     planes = []
     stat_names: set = set()
+    clock_sync = []
     for plane in data.planes:
         is_device = bool(DEVICE_PLANE.match(plane.name))
         if not is_device and plane.name != HOST_PLANE:
@@ -209,6 +240,13 @@ def read_events(path: str) -> Dict[str, Any]:
             for ev in line.events:
                 name = ev.name
                 if not is_device and not name.startswith(HOST_PREFIX):
+                    continue
+                if not is_device and name == HOST_PREFIX + CLOCK_SYNC:
+                    # no span of the program's: where the timeline's
+                    # clock stood when this annotation began
+                    perf_ns = dict(ev.stats).get("perf_counter_ns")
+                    if perf_ns is not None:
+                        clock_sync.append([int(ev.start_ns), int(perf_ns)])
                     continue
                 i = names.get(name)
                 if i is None:
@@ -229,7 +267,14 @@ def read_events(path: str) -> Dict[str, Any]:
                     stat_names.update(table.get(n, {}))
             lines.append(row)
         planes.append({"name": plane.name, "lines": lines})
-    return {"planes": planes, "op_stat_names": sorted(stat_names)}
+    events = {"planes": planes, "op_stat_names": sorted(stat_names)}
+    beside = os.path.join(os.path.dirname(path), TIMELINE_FILE)
+    if clock_sync and os.path.isfile(beside):
+        with open(beside, encoding="utf-8") as f:
+            events.update(clock_sync=sorted(clock_sync),
+                          timeline=json.load(f))
+        events["timeline"]["bytes"] = os.path.getsize(beside)
+    return events
 
 
 def _arrays(line: dict):
@@ -326,6 +371,103 @@ def _idle_by_span(gap_s, gap_e, spans):
     by_under = {n: float(dur[under[i]].sum()) / 1e9
                 for n, i in code.items() if under[i].any()}
     return by_inner, by_under
+
+
+def timeline_on_trace(events: dict) -> Optional[Dict[str, Any]]:
+    """The host timeline on the trace's nanoseconds, by the first clock
+    marker: the slice's bounds, the ``executor`` role's intervals that are
+    of its stack (names, starts, ends, depths) and every thread's
+    collector pauses; ``clock_drift_ns``, what the second marker
+    disagrees by.  None without a timeline or the marker it names;
+    ``{"error": ...}`` where the clocks drifted past MAX_DRIFT_NS."""
+    timeline = events.get("timeline")
+    on_trace = {perf: at for at, perf in events.get("clock_sync") or []}
+    if not timeline or timeline["start_ns"] not in on_trace:
+        return None
+    t0 = on_trace[timeline["start_ns"]]
+    t1 = t0 + timeline["stop_ns"] - timeline["start_ns"]
+    drift = on_trace.get(timeline["stop_ns"], t1) - t1
+    if abs(drift) > MAX_DRIFT_NS:
+        return {"error": f"the clock markers disagree by {drift} ns over "
+                         f"{t1 - t0} ns, more than {MAX_DRIFT_NS}: the "
+                         f"timeline cannot be laid on this trace"}
+    roles = [lane["role"] for lane in timeline["lanes"]]
+    names = timeline["names"]
+    executor, pauses = [], []
+    for lane, name, start, end, depth in timeline["intervals"]:
+        if names[name] == GC_PAUSE:
+            pauses.append((t0 + start, t0 + end))
+        elif roles[lane] == EXECUTOR and depth >= 0:
+            executor.append((names[name], t0 + start, t0 + end, depth))
+    cols = list(zip(*executor)) or [(), (), (), ()]
+    gc_cols = list(zip(*pauses)) or [(), ()]
+    return {"t0": t0, "t1": t1, "clock_drift_ns": int(drift),
+            "intervals": len(timeline["intervals"]),
+            "dropped": int(timeline.get("dropped", 0)),
+            "bytes": int(timeline.get("bytes", 0)),
+            "names": list(cols[0]),
+            "start": np.asarray(cols[1], np.int64),
+            "end": np.asarray(cols[2], np.int64),
+            "depth": np.asarray(cols[3], np.int64),
+            "gc_start": np.asarray(gc_cols[0], np.int64),
+            "gc_end": np.asarray(gc_cols[1], np.int64)}
+
+
+def owner_class(owner: str) -> str:
+    """The row of EXEC_CLASSES an owner of ``idle_by_executor`` falls in."""
+    if owner == GC_PAUSE:
+        return "gc"
+    if owner == UNOWNED:
+        return UNOWNED
+    if owner.startswith(WAKE_PREFIX):
+        return "wake"
+    if owner in WAIT_REQUEST:
+        return "wait_request"
+    return "wait_device" if owner in WAIT_DEVICE else "host"
+
+
+def _idle_by_executor(gap_s, gap_e, before, after, timeline):
+    """The gaps (sorted, apart; ``before`` / ``after``: what ran on
+    either side of each) by the executor's innermost interval over each
+    instant: seconds by owner, seconds under each of its intervals at any
+    depth, and the stretches that cost most.  One owner an instant: the
+    gaps are cut at every interval's edges, the deepest interval of the
+    executor's stack over a piece has it, a collector pause of any thread
+    before that, UNOWNED under none."""
+    names = timeline["names"]
+    start, end = timeline["start"], timeline["end"]
+    gcs, gce = timeline["gc_start"], timeline["gc_end"]
+    ps, pe = _cut_at(gap_s, gap_e, np.concatenate((start, end, gcs, gce)))
+    mid, dur = (ps + pe) // 2, pe - ps
+    owners = sorted(set(names)) + [GC_PAUSE, UNOWNED]
+    code = {n: i for i, n in enumerate(owners)}
+    owner = np.full(len(ps), code[UNOWNED], np.int64)
+    under = np.zeros((len(owners), len(ps)), bool)
+    first = np.searchsorted(mid, start, "left")
+    last = np.searchsorted(mid, end, "left")
+    # the shallower first, so that what it holds wins over it
+    for k in np.lexsort((start, timeline["depth"])):
+        if last[k] > first[k]:
+            owner[first[k]:last[k]] = code[names[k]]
+            under[code[names[k]], first[k]:last[k]] = True
+    for a, b in zip(np.searchsorted(mid, gcs, "left"),
+                    np.searchsorted(mid, gce, "left")):
+        owner[a:b] = code[GC_PAUSE]
+    ns = np.bincount(owner, weights=dur, minlength=len(owners))
+    by_owner = {n: float(ns[i]) / 1e9 for n, i in code.items() if ns[i]}
+    by_under = {n: float(dur[under[i]].sum()) / 1e9
+                for n, i in code.items() if under[i].any()}
+    # the stretches between the same two programs under one owner are a row
+    gap = np.searchsorted(gap_s, mid, "right") - 1
+    rows: Dict[tuple, list] = {}
+    for g, o, d in zip(gap.tolist(), owner.tolist(), dur.tolist()):
+        row = rows.setdefault((before[g], after[g], owners[o]), [0, set()])
+        row[0] += d
+        row[1].add(g)
+    top = sorted(rows.items(), key=lambda kv: -kv[1][0])[:TOP_IDLE]
+    return by_owner, by_under, [
+        {"s": total / 1e9, "n": len(gaps), "owner": who, "before": b,
+         "after": a} for (b, a, who), (total, gaps) in top]
 
 
 def _owners(start, end, floor):
@@ -452,7 +594,7 @@ def _accounts(ops, labels, classes, phases, leaf, modules, programs):
         row["account"] = account
 
 
-def _chip(plane: dict, spans) -> Dict[str, Any]:
+def _chip(plane: dict, spans, timeline=None) -> Dict[str, Any]:
     lines = {ln["name"]: ln for ln in plane["lines"]}
     chip: Dict[str, Any] = {"busy_s": 0.0, "window_s": 0.0, "ops": 0,
                             "gaps_in_programs_s": 0.0, "programs": {},
@@ -499,6 +641,22 @@ def _chip(plane: dict, spans) -> Dict[str, Any]:
         (gap_e - gap_s)[within].sum()) / 1e9
     chip["idle"], chip["idle_under"] = _idle_by_span(
         gap_s[~within], gap_e[~within], spans)
+    names_of = np.asarray([_MODULE_ID.sub("", n)
+                           for n in mod_ln["names"]])
+    if timeline is not None:
+        # the same gaps, and the slice's two edges, which the timeline can
+        # answer for: the profiler was on and no program had begun, or
+        # none was left
+        idle_s, idle_e = gap_s[~within], gap_e[~within]
+        idle_s = np.concatenate(([min(timeline["t0"], t0)], idle_s, [t1]))
+        idle_e = np.concatenate(([t0], idle_e, [max(timeline["t1"], t1)]))
+        idle_s, idle_e = idle_s[idle_e > idle_s], idle_e[idle_e > idle_s]
+        k = np.searchsorted(ms, (idle_s + idle_e) // 2, "right") - 1
+        ran = np.concatenate((names_of[midx], [SLICE_ENDS, SLICE_STARTS]))
+        chip["idle_between_s"] = float((idle_e - idle_s).sum()) / 1e9
+        chip["idle_by_executor"], chip["idle_under_executor"], \
+            chip["top_idle_between"] = _idle_by_executor(
+                idle_s, idle_e, ran[k], ran[k + 1], timeline)
     whole = (ms > t0 + EDGE_NS) & (ms + md < t1 - EDGE_NS)
     # the execution each leaf operation started in
     k = np.searchsorted(ms, ls, "right") - 1
@@ -510,8 +668,6 @@ def _chip(plane: dict, spans) -> Dict[str, Any]:
                                          "classes": {}})
         row["count"] += 1
         row["total_s"] += md[m] / 1e9
-    names_of = np.asarray([_MODULE_ID.sub("", n)
-                           for n in mod_ln["names"]])
     # an operation's name: the path where there is one, else the HLO
     # instruction as the trace prints it
     labels = [p or n[:120] for p, n in zip(paths, ops_ln["names"])]
@@ -550,17 +706,26 @@ def _mean(rows: List[Dict[str, float]]) -> Dict[str, float]:
 
 def summarize(events: dict, traced_s: float = 0.0) -> Dict[str, Any]:
     spans = _host_spans(events)
+    timeline = timeline_on_trace(events)
+    error = (timeline or {}).get("error")
     chips = []
     for plane in events["planes"]:
         m = DEVICE_PLANE.match(plane["name"])
         if m:
-            chips.append({"chip": int(m.group(1)), **_chip(plane, spans)})
+            chips.append({"chip": int(m.group(1)), **_chip(
+                plane, spans, None if error else timeline)})
     chips.sort(key=lambda c: c["chip"])
     out: Dict[str, Any] = {
         "traced_s": float(traced_s), "chips": chips,
         "names_found": any(c["names_found"] for c in chips),
         "host_spans": sorted(set(spans[0])),
         "op_stat_names": events.get("op_stat_names", [])}
+    if error:
+        out["timeline_error"] = error
+    elif timeline is not None:
+        out["clock_drift_ns"] = timeline["clock_drift_ns"]
+        out["host_timeline"] = {k: timeline[k]
+                                for k in ("intervals", "dropped", "bytes")}
     if not chips:
         return out
     # a program counts where every chip saw it whole
@@ -600,6 +765,19 @@ def summarize(events: dict, traced_s: float = 0.0) -> Dict[str, Any]:
                                   for c in chips) / len(chips),
         "programs": programs, "idle": idle,
         "idle_under": _mean([c["idle_under"] for c in chips])})
+    if all("idle_by_executor" in c for c in chips):
+        by_owner = _mean([c["idle_by_executor"] for c in chips])
+        by_class = dict.fromkeys(EXEC_CLASSES, 0.0)
+        for owner, sec in by_owner.items():
+            by_class[owner_class(owner)] += sec
+        out.update({
+            "idle_between_s": sum(c["idle_between_s"]
+                                  for c in chips) / len(chips),
+            "idle_by_executor": by_owner,
+            "idle_by_executor_class": by_class,
+            "idle_under_executor": _mean([c["idle_under_executor"]
+                                          for c in chips]),
+            "top_idle_between": chips[0]["top_idle_between"]})
     return out
 
 

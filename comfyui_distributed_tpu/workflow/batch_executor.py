@@ -1384,6 +1384,7 @@ class ContinuousBatchExecutor:
         self._mirror_stats()
 
     def _drive(self) -> None:
+        trace_mod.thread_role(trace_mod.EXECUTOR)  # the step thread
         st = self.state
         batch_started = None
         while not self._stop:

@@ -229,8 +229,8 @@ def test_the_manifests_entry(manifest, name):
 def test_the_six_are_appended_and_nothing_that_was_there_moved(manifest):
     names = [m["name"] for m in manifest["per_layer"]]
     # (PR 40 appended four behind them, PR 42 four more, PR 46 four, PR 49
-    # three)
-    assert names[35:41] == READERS and len(names) == len(set(names)) == 56
+    # three, PR 51 nine)
+    assert names[35:41] == READERS and len(names) == len(set(names)) == 65
     assert names[34] == "lm_prefill_flops_util_pct"
     # the denoise readers sit where ``norm_device_s_per_image`` does
     (norm,) = [m for m in manifest["per_layer"]

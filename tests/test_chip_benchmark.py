@@ -374,7 +374,7 @@ def _keye4_reports(m, cells):
         if x["name"] in _KEYE_ONLY or "_index_" in x["name"]:
             assert x["workloads"] == [KEYE4] and x["layer"] == \
                 "Language model" and x["source"] == "device_trace"
-    assert [x["name"] for x in m["per_layer"][-11:-7]] == [
+    assert [x["name"] for x in m["per_layer"][45:49]] == [
         "lm_index_device_s_per_request",
         "lm_prefill_index_device_s_per_request", *_KEYE_ONLY]
     cfg = _config(keye["config"])
@@ -411,7 +411,7 @@ def _phi4_reports(m, cells):
             assert x["workloads"] == [PHI4] and x["layer"] == \
                 "Language model" and x["source"] == "device_trace" \
                 and x["moves"] == "images_per_s"
-    assert [x["name"] for x in m["per_layer"][-7:-3]] == [
+    assert [x["name"] for x in m["per_layer"][49:53]] == [
         "lm_gmu_device_s_per_request", "lm_cross_device_s_per_request",
         *_PHI_ONLY]
     cfg = _config(phi["config"])
@@ -451,7 +451,7 @@ def _longcat4_reports(m, cells):
             assert x["workloads"] == [LONGCAT4] and x["layer"] == \
                 "Language model" and x["source"] == "device_trace" \
                 and x["moves"] == "images_per_s"
-    assert [x["name"] for x in m["per_layer"][-3:]] == [
+    assert [x["name"] for x in m["per_layer"][53:56]] == [
         *_LONGCAT_ONLY, "lm_zero_device_s_per_request"]
     cfg = _config(longcat["config"])
     granite = _config("granite-4.0-h-micro-expand-sd15-512")

@@ -438,6 +438,26 @@ def test_two_callers_in_a_closed_loop_wait_and_find_nobody(state):
     assert "followers_served" not in got
 
 
+def test_every_drain_wait_is_one_wake_drain_on_the_leaders_thread(state):
+    """The hand-over by name (PR 51): from the pool thread's notify under
+    the queue's lock to the leader past its wait, once a drain wait, so
+    ``wake_drain.count`` is ``lm.drain_waits``; a leader that found
+    nothing owed waited for nobody."""
+    state.enqueue_prompt(graph("nothing is owed"), "a")
+    run_next(state)
+    assert stage_count("wake_drain") == 0 and "drain_waits" not in counters()
+    for turn_no in range(3):
+        others = owe(state, f"an image in flight, turn {turn_no}")
+        state.enqueue_prompt(graph(f"caller b, round {turn_no}"), "b")
+        turn = lead(state)
+        before = state._drained_ns
+        state._image_settled(others)
+        assert state._drained_ns > before       # stamped under the lock
+        ended(turn)
+    assert stage_count("wake_drain") == counters()["drain_waits"] == 3
+    assert stage_count("lm_drain_wait") == 3
+
+
 @pytest.mark.parametrize("name, rows, where, want", [
     ("ouro", 1, ("tpu", None), 0),      # the one-row program, on a TPU too
     ("ouro", 3, ("tpu", None), 1),      # padded to four: few rows
