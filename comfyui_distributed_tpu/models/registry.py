@@ -279,7 +279,9 @@ class DiffusionPipeline:
         # LRU-bounded: every (resolution, batch, sampler...) combination is
         # its own compiled executable; an unbounded dict leaks one per shape
         # seen.  16 live entries cover a realistic session (clip×2, vae×2,
-        # and a dozen sample configs); evictions are logged.
+        # and a dozen sample configs; beside them the sampler inputs'
+        # program and the placeholders, one a batch size, and an SDXL size
+        # embedding a resolution); evictions are logged.
         self._jit_cache: "collections.OrderedDict[Any, Any]" = \
             collections.OrderedDict()
         self._jit_cache_cap = int(os.environ.get("DTPU_JIT_CACHE_CAP", "16"))
@@ -557,12 +559,17 @@ class DiffusionPipeline:
                guidance: str = "dual",
                c_concat=None,
                gligen_objs=None,
-               donate_latents: bool = False) -> jnp.ndarray:
+               donate_latents: bool = False,
+               keys=None) -> jnp.ndarray:
         """Full ksampler: schedule -> noise -> scan-sampler -> latents.
 
         ``seeds``: per-sample host seed array [B] (64-bit ok; replica offsets
         already applied by the distributed layer).  ``sample_idx``: optional
         per-sample fold-in indices (replica-local positions in SPMD runs).
+        ``keys``: the per-sample PRNG keys of exactly these seeds and
+        indices, when the caller made them in the one program that made
+        the request's other inputs (``sampler_inputs``); made here, by
+        the same program, when not given.
         ``start_step``/``end_step`` run a window of the schedule (ComfyUI's
         KSamplerAdvanced): noise scales by the window's FIRST sigma, and
         stopping early returns a still-noisy latent for a later stage
@@ -625,12 +632,14 @@ class DiffusionPipeline:
                 # latent passes through unchanged (same precedent as the
                 # degenerate KSamplerAdvanced window below)
                 return latents
-            sigmas = jnp.asarray(sig_np)
+            sigmas = sig_np
             steps = int(sig_np.shape[0]) - 1
             start, end = 0, steps
         else:
-            sigmas = jnp.asarray(sch.compute_sigmas(
-                self.schedule, scheduler, steps, denoise))
+            # host values all the way to the call: the schedule and its
+            # window enqueue no program of their own
+            sigmas = np.asarray(sch.compute_sigmas(
+                self.schedule, scheduler, steps, denoise), np.float32)
             start = max(int(start_step), 0)
             end = steps if end_step is None else min(int(end_step), steps)
             if start >= end:
@@ -638,10 +647,12 @@ class DiffusionPipeline:
                 # ComfyUI returns the latent unchanged rather than erroring
                 return latents
             if start > 0 or end < steps:
-                sigmas = sigmas[start:end + 1]
+                sigmas = sigmas[start:end + 1].copy()
                 if force_full_denoise:
-                    sigmas = sigmas.at[-1].set(0.0)
-        keys = smp.sample_keys(seeds, sample_idx)
+                    sigmas[-1] = 0.0
+        if keys is None:
+            keys = sampler_inputs(self, latents.shape[0], seeds,
+                                  sample_idx)[0]
 
         from comfyui_distributed_tpu.runtime.interrupt import polling_enabled
 
@@ -913,26 +924,24 @@ class DiffusionPipeline:
             return jax.jit(core, donate_argnums=(1,))
 
         core = self._cache_get_or_make(static_key, make_core)
+        absent = self._sample_placeholders(latents.shape[0])
         if y is None:
-            y_arg = jnp.zeros((latents.shape[0], 1))
+            y_arg = absent["y"]
         elif isinstance(y, (list, tuple)):
             y_arg = [jnp.asarray(v) for v in y]
         else:
             y_arg = y
-        mask_arg = noise_mask if noise_mask is not None \
-            else jnp.ones((1, 1, 1, 1))
+        mask_arg = noise_mask if noise_mask is not None else absent["one"]
         cn_params_arg = [c[1] for c in control] if control is not None \
             else [{}]
         hint_arg = [c[2] for c in control] if control is not None \
-            else [jnp.zeros((1, 8, 8, 3))]
+            else [absent["hint"]]
         ctx_list = [jnp.asarray(c) for c, _, _, _ in conds + unconds]
-        area_list = [jnp.asarray(m) if m is not None
-                     else jnp.ones((1, 1, 1, 1))
+        area_list = [jnp.asarray(m) if m is not None else absent["one"]
                      for _, m, _, _ in conds + unconds]
-        concat_arg = c_concat if c_concat is not None \
-            else jnp.zeros((1, 1, 1, 1))
+        concat_arg = c_concat if c_concat is not None else absent["concat"]
         objs_arg = gligen_objs[:2] if gligen_objs is not None \
-            else (jnp.zeros((1, 1, 1)), jnp.zeros((1, 1, 1)))
+            else absent["objs"]
         lat_arg = jnp.asarray(latents)
         if not donate_latents:
             # core always donates its latent arg; protect a buffer the
@@ -948,6 +957,20 @@ class DiffusionPipeline:
                    cn_params_arg, hint_arg, concat_arg, objs_arg)
         note_denoise(out)
         return out
+
+    def _sample_placeholders(self, batch: int) -> Dict[str, Any]:
+        """What ``core`` is given in place of what a request does not
+        carry: no ADM vector (``y``), no area or noise mask (``one``), no
+        control hint, no inpaint channels (``concat``), no grounding
+        tokens (``objs``).  Made once a batch size and kept in the LRU:
+        made anew they were eight tiny programs a request."""
+        def make():
+            return {"y": jnp.zeros((batch, 1)),
+                    "one": jnp.ones((1, 1, 1, 1)),
+                    "hint": jnp.zeros((1, 8, 8, 3)),
+                    "concat": jnp.zeros((1, 1, 1, 1)),
+                    "objs": (jnp.zeros((1, 1, 1)), jnp.zeros((1, 1, 1)))}
+        return self._cache_get_or_make(("sample_placeholders", batch), make)
 
     # --- warmup -------------------------------------------------------------
 
@@ -995,14 +1018,24 @@ class DiffusionPipeline:
         total = batch * (rt.num_participants if mesh is not None else 1)
 
         lh, lw = max(int(height) // 8, 1), max(int(width) // 8, 1)
-        context = jnp.repeat(ctx1, total, axis=0)
-        uncond = jnp.repeat(ctx1, total, axis=0)
-        y = None
-        if self.family.unet.adm_in_channels is not None:
-            from comfyui_distributed_tpu.ops.basic import _sdxl_vector_cond
-            y = _sdxl_vector_cond(
-                self, Conditioning(context=ctx1, pooled=pooled),
-                total, lh * 8, lw * 8)
+        # the inputs as a txt2img request's sampler node makes them
+        # (ops/basic.py _prepare_sample_inputs): the same program is warm
+        seeds = np.zeros((total,), np.uint64)
+        adm = self.family.unet.adm_in_channels is not None
+        unclip = getattr(self.family, "adm_kind", "sdxl") == "unclip"
+        cond = Conditioning(context=ctx1, pooled=pooled)
+        vectors = []
+        if adm and not unclip:   # one a CFG side, as the node asks
+            from comfyui_distributed_tpu.ops.basic import _sdxl_vector_source
+            vectors = [_sdxl_vector_source(self, cond, lh * 8, lw * 8)] * 2
+        keys, ys, (context, uncond) = sampler_inputs(
+            self, total, seeds,
+            np.tile(np.arange(batch, dtype=np.uint32), total // batch),
+            vectors, [ctx1, ctx1])
+        y = ys[0] if ys else None
+        if adm and unclip:
+            from comfyui_distributed_tpu.ops.basic import _unclip_vector_cond
+            y = _unclip_vector_cond(self, cond, total)
         lat = jnp.zeros((total, lh, lw, self.family.latent_channels),
                         jnp.float32)
         if mesh is not None:
@@ -1012,12 +1045,11 @@ class DiffusionPipeline:
             if y is not None:
                 y = coll.shard_batch(y, mesh)
         t0 = _time.perf_counter()
-        out = self.sample(lat, context, uncond,
-                          np.zeros((total,), np.uint64),
+        out = self.sample(lat, context, uncond, seeds,
                           steps=int(steps), cfg=float(cfg),
                           sampler_name=str(sampler_name),
                           scheduler=str(scheduler), denoise=float(denoise),
-                          y=y, donate_latents=True)
+                          y=y, donate_latents=True, keys=keys)
         jax.block_until_ready(out)
         timings["sample_s"] = _time.perf_counter() - t0
 
@@ -1053,17 +1085,114 @@ class DiffusionPipeline:
             return fn
 
 
+def _uncached(key, make):
+    return make()
+
+
+def sampler_inputs(pipe, total: int, seeds=None, sample_idx=None,
+                   vectors: Sequence = (), contexts: Sequence = ()):
+    """What a request's denoise takes beside its latent, made by ONE
+    cached jitted program fed with host values (made eagerly it was
+    twenty tiny programs an SD1.5 request and fifty an SDXL one, each
+    enqueued from Python with the device dry between them).
+
+    ``seeds`` (host, 64-bit ok) and ``sample_idx`` (default: the batch
+    position) give ``keys [total, 2]`` (``samplers.fold_keys``).  Each of
+    ``vectors``, ``(pooled [1, P] or None, sizes)``, gives an SDXL ADM
+    vector ``[total, adm_in_channels]``: the pooled text embedding and
+    the size scalars' embeddings, zero-padded or cut to the UNet's
+    width.  Each of ``contexts`` comes back at ``total`` rows; one that
+    has them already is passed as it is.
+
+    The keys ride in the same program unless a device input is committed
+    (a mesh's towers made it): then they get a call of their own, host
+    values in, so they stay uncommitted, which is what ``core`` was
+    compiled to take beside a batch laid over the mesh.
+
+    The program lives in the pipeline's LRU under a key of the row count,
+    the ADM width and every input's shape.  A ``pipe`` without that cache
+    (a test's stand-in) is served uncached.
+    Returns ``(keys or None, [y, ...], [context, ...])``."""
+    cached = getattr(pipe, "_cache_get_or_make", _uncached)
+    want = pipe.family.unet.adm_in_channels
+    pooled = [np.zeros((1, 1280), np.float32) if p is None else p
+              for p, _ in vectors]
+    embs = [_size_embedding(cached, sizes) for _, sizes in vectors]
+    out_ctx = list(contexts)
+    short = [i for i, c in enumerate(out_ctx) if c.shape[0] != total]
+    ctx_in = [out_ctx[i] for i in short]
+    words = None
+    if seeds is not None:
+        lo, hi = smp.seed_words(seeds)
+        words = (lo, hi, np.arange(lo.shape[0], dtype=np.uint32)
+                 if sample_idx is None else sample_idx)
+
+    def run(words, pooled, embs, ctx_in):
+        if words is None and not pooled and not ctx_in:
+            return None, [], []
+        key = ("sampler_inputs", total, want, words is not None,
+               tuple(tuple(np.shape(a)) for a in pooled + embs + ctx_in))
+        return cached(key, lambda: _make_sampler_inputs(total, want))(
+            words, pooled, embs, ctx_in)
+
+    if words is not None and any(getattr(a, "committed", False)
+                                 for a in pooled + ctx_in):
+        keys = run(words, [], [], [])[0]
+        _, ys, ctx_out = run(None, pooled, embs, ctx_in)
+    else:
+        keys, ys, ctx_out = run(words, pooled, embs, ctx_in)
+    for i, c in zip(short, ctx_out):
+        out_ctx[i] = c
+    return keys, ys, out_ctx
+
+
+def _size_embedding(cached, sizes: Tuple[float, ...]):
+    """SDXL's size conditioning, ``[1, 256 * len(sizes)]``: the scalars'
+    sinusoidal embeddings side by side.  Computed as it always was, a
+    dozen eager operations, but once a tuple of scalars and kept (a
+    server sees a handful of sizes): under the inputs' jit XLA would fold
+    ``exp`` of the constant frequencies on the compiling host, whose
+    ``exp`` is not the chip's, and the image would move by a last bit."""
+    def make():
+        from comfyui_distributed_tpu.models.layers import timestep_embedding
+        s = jnp.asarray([list(sizes)], jnp.float32)
+        return timestep_embedding(s.reshape(-1), 256).reshape(1, -1)
+    return cached(("size_embedding", sizes), make)
+
+
+def _make_sampler_inputs(total: int, want: Optional[int]):
+    def rows(x):
+        return x if x.shape[0] == total else jnp.repeat(x, total, axis=0)
+
+    def sampler_inputs(words, pooled, embs, contexts):
+        keys = None if words is None else smp.fold_keys(*words)
+        ys = []
+        for p, e in zip(pooled, embs):
+            vec = jnp.concatenate([p, e], axis=-1)
+            if vec.shape[-1] < want:
+                vec = jnp.pad(vec, ((0, 0), (0, want - vec.shape[-1])))
+            ys.append(rows(vec[:, :want]))
+        return keys, ys, [rows(c) for c in contexts]
+
+    return jax.jit(sampler_inputs)
+
+
 # The previous denoise's output, of any pipeline (the device is one
 # queue).  The TPU runtime lets the host run only so far ahead: some
 # enqueue call of the NEXT request returns only when the previous
-# request's denoise has finished.  Which call depends on how many small
-# programs the request enqueues first (seen on the v5e: 0.56 s of every
-# 0.61 s SD1.5 request inside PjitFunction(core); 3.4 s of every 3.6 s
-# SDXL request inside the jnp.repeat of _sdxl_vector_cond; 1.77 s of a
-# 2.6 s four-chip cycle at the first shard placement).  At those three
-# places the host meets the device by name (trace.device_wait), so the
-# calls themselves are the enqueue alone and ``dispatch`` is the host's
-# own seconds.  It blocks where the runtime blocked anyway.
+# request's denoise has finished, and which call depends on how many
+# programs the request enqueues first.  While a request's sampler inputs
+# were made eagerly (fifty tiny programs an SDXL request) that was the
+# jnp.repeat of the ADM vector (3.4 s of every 3.6 s SDXL request; PR 24),
+# and everything enqueued behind it ran with the device dry.  Since PR 52
+# a request enqueues a dozen programs (``sampler_inputs`` is ONE), the
+# runtime holds the host at none of them, and the host meets the device
+# by name (trace.device_wait) at the last call before the denoise, here
+# in ``sample``, for SD1.5 and SDXL alike; a fan-out request meets it
+# once more, before its first shard placement, where the runtime held
+# the host anyway (1.77 s of a 2.6 s four-chip cycle), with its programs
+# already enqueued.  Behind the wait there is only ``core``'s own call,
+# so ``dispatch`` is the host's own seconds.
 #
 # Both are plain calls beside the jitted call, never a wrapper around it:
 # two Python frames (a helper and its lambda) between ``sample`` and

@@ -25,10 +25,36 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from comfyui_distributed_tpu.parallel import sharding as shd
 
 Model = Callable[..., jax.Array]  # model(x, sigma, **extra) -> denoised
+
+
+def seed_words(seeds) -> Tuple[Any, Any]:
+    """Per-sample seeds as their low and high 32-bit words (``uint32[B]``
+    each): what ``fold_keys`` takes.  Host seeds (numpy / python ints) are
+    64-bit, as the reference's seed widget is, and both words come back as
+    numpy: nothing touches the device.  A ``jax.Array`` (a tracer too) is
+    treated as 32-bit (x64 is disabled under jit): it is its own low word,
+    the high word is zero."""
+    if isinstance(seeds, jax.Array):
+        return seeds, np.zeros(seeds.shape, np.uint32)
+    s = np.asarray(seeds, dtype=np.uint64)
+    return ((s & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+            (s >> np.uint64(32)).astype(np.uint32))
+
+
+def fold_keys(lo, hi, idx) -> jax.Array:
+    """``[B, 2]`` PRNG keys from ``seed_words`` and per-sample fold
+    indices: the high word is folded in separately, so seeds differing by
+    2^32 stay distinct, then the index, so rows sharing a seed still get
+    distinct streams.  Integer arithmetic only: the same bits eagerly and
+    under a jit (``DiffusionPipeline.sampler_inputs`` calls it there)."""
+    lo, hi, idx = (jnp.asarray(v).astype(jnp.uint32) for v in (lo, hi, idx))
+    return jax.vmap(lambda l, h, i: jax.random.fold_in(
+        jax.random.fold_in(jax.random.PRNGKey(l), h), i))(lo, hi, idx)
 
 
 def sample_keys(seeds, idx=None) -> jax.Array:
@@ -40,24 +66,13 @@ def sample_keys(seeds, idx=None) -> jax.Array:
     seed produce identical sub-batches (reference parity: a run without a
     DistributedSeed node yields duplicate images on every participant).
 
-    Accepts 64-bit host seeds (numpy/python ints) without collision: the high
-    word is folded in separately, so seeds differing by 2^32 stay distinct
-    (the reference's seed widget is 64-bit).  Traced jax arrays are treated
-    as 32-bit (x64 is disabled under jit)."""
-    import numpy as _np
-    if isinstance(seeds, jax.Array):
-        lo = seeds.astype(jnp.uint32)
-        hi = jnp.zeros_like(lo)
-    else:
-        s = _np.asarray(seeds, dtype=_np.uint64)
-        lo = jnp.asarray((s & _np.uint64(0xFFFFFFFF)).astype(_np.uint32))
-        hi = jnp.asarray((s >> _np.uint64(32)).astype(_np.uint32))
+    Eager: a dozen tiny programs a call.  A request's denoise gets its
+    keys from ``DiffusionPipeline.sampler_inputs`` instead (the same
+    ``fold_keys`` inside the one program that makes its other inputs)."""
+    lo, hi = seed_words(seeds)
     if idx is None:
-        idx = jnp.arange(lo.shape[0], dtype=jnp.uint32)
-    else:
-        idx = jnp.asarray(idx).astype(jnp.uint32)
-    return jax.vmap(lambda l, h, i: jax.random.fold_in(
-        jax.random.fold_in(jax.random.PRNGKey(l), h), i))(lo, hi, idx)
+        idx = np.arange(lo.shape[0], dtype=np.uint32)
+    return fold_keys(lo, hi, idx)
 
 
 def make_noise_fn(keys: jax.Array) -> Callable[[jax.Array, Tuple[int, ...]], jax.Array]:

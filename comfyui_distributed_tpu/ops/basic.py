@@ -1564,7 +1564,7 @@ class SamplerCustom(Op):
                 middle_context=prep.mid_context, cfg2=prep.cfg2,
                 guidance=prep.guidance, c_concat=prep.c_concat,
                 gligen_objs=prep.gligen_objs,
-                donate_latents=prep.donate_latents)
+                donate_latents=prep.donate_latents, keys=prep.keys)
         out_d = {"samples": DeviceLatent(out), **_latent_meta(latent_image),
                  "local_batch": prep.local_batch, "fanout": prep.fanout}
         return (out_d, dict(out_d))
@@ -1727,7 +1727,7 @@ class SamplerCustomAdvanced(Op):
                 middle_context=prep.mid_context, cfg2=cfg2,
                 guidance=guidance, c_concat=prep.c_concat,
                 gligen_objs=prep.gligen_objs,
-                donate_latents=prep.donate_latents)
+                donate_latents=prep.donate_latents, keys=prep.keys)
         out_d = {"samples": DeviceLatent(out), **_latent_meta(latent_image),
                  "local_batch": prep.local_batch, "fanout": prep.fanout}
         return (out_d, dict(out_d))
@@ -1795,7 +1795,7 @@ class KSampler(Op):
                 middle_context=prep.mid_context, cfg2=prep.cfg2,
                 guidance=prep.guidance, c_concat=prep.c_concat,
                 gligen_objs=prep.gligen_objs,
-                donate_latents=prep.donate_latents)
+                donate_latents=prep.donate_latents, keys=prep.keys)
         out_d = {"samples": DeviceLatent(out),
                  "local_batch": prep.local_batch,
                  "fanout": prep.fanout}
@@ -1842,7 +1842,7 @@ class KSamplerAdvanced(Op):
                 middle_context=prep.mid_context, cfg2=prep.cfg2,
                 guidance=prep.guidance, c_concat=prep.c_concat,
                 gligen_objs=prep.gligen_objs,
-                donate_latents=prep.donate_latents)
+                donate_latents=prep.donate_latents, keys=prep.keys)
         out_d = {"samples": DeviceLatent(out),
                  "local_batch": prep.local_batch,
                  "fanout": prep.fanout}
@@ -2001,6 +2001,10 @@ class _SampleInputs:
     # value arrived device-resident (e.g. a hires chain reusing an
     # upstream KSampler's output that other nodes may also consume).
     donate_latents: bool = False
+    # the per-sample PRNG keys of ``seeds`` / ``sample_idx``, made in the
+    # one program that made ``context`` / ``uncond`` / ``y``
+    # (registry.sampler_inputs): the sampler node hands them to ``sample``
+    keys: object = None
 
 
 def _maybe_gligen_model(model, *conds):
@@ -2041,18 +2045,12 @@ def _prepare_sample_inputs(ctx: OpContext, model, seed, latent_image,
     # device-resident tensor plane: the latent stays a jax.Array end to
     # end — only its SHAPE is consulted here.  A host array (fresh
     # EmptyLatentImage batch, a numpy-edited latent) pays one counted
-    # h2d put and yields a donation-safe fresh buffer.
+    # h2d put and yields a donation-safe fresh buffer (below, behind the
+    # one program that makes the other inputs).
     raw = latent_image["samples"]
     raw_arr = raw.data if isinstance(raw, DeviceTensor) else raw
     fanout = int(latent_image.get("fanout", 1))
-    if fanout > 1:
-        # a fan-out request places a shard per replica before its
-        # denoise is enqueued: the runtime holds the host at the first
-        # of those calls until the previous denoise is done (1.77 s of a
-        # 2.6 s cycle on four chips), so meet the device here by name
-        registry.wait_previous_denoise()
-    lat = as_device_array(raw)
-    total = lat.shape[0]
+    total, lat_h, lat_w = (int(n) for n in np.shape(raw_arr)[:3])
     local_b = int(latent_image.get("local_batch", total // max(fanout, 1)))
 
     if isinstance(seed, SeedValue):
@@ -2097,45 +2095,55 @@ def _prepare_sample_inputs(ctx: OpContext, model, seed, latent_image,
     def _align_tokens(c):
         return align_cond_tokens(c, t_align)
 
-    lat_dev = lat
     mesh = ctx.runtime.mesh if ctx.runtime is not None else None
-    if fanout > 1 and mesh is not None:
-        lat_dev = coll.shard_batch(lat, mesh)
-
+    sharded = fanout > 1 and mesh is not None
     adm = model.family.unet.adm_in_channels is not None
+    unclip_adm = adm and getattr(model.family, "adm_kind",
+                                 "sdxl") == "unclip"
 
-    def _build_entries(src):
+    # every entry's context at ``total`` rows, its ADM vector (each entry
+    # carries its OWN pooled: regional SDXL's region B must not ride
+    # region A's; source selection shared with the tile refine) and the
+    # keys: ONE program, enqueued behind whatever the device is running
+    vectors = [_sdxl_vector_source(
+        model, adm_cond_source(model.family, e, positive),
+        lat_h * 8, lat_w * 8) for e in all_entries] \
+        if adm and not unclip_adm else []
+    keys, ys, contexts = registry.sampler_inputs(
+        model, total, seeds, local_idx, vectors,
+        [_align_tokens(e.context) for e in all_entries])
+    if unclip_adm:
+        ys = [_unclip_vector_cond(model, e, total) for e in all_entries]
+
+    if sharded:
+        # a fan-out request places a shard per replica before its
+        # denoise is enqueued: the runtime holds the host at the first
+        # of those calls until the previous denoise is done (1.77 s of a
+        # 2.6 s cycle on four chips), so meet the device here by name.
+        # Only placements and ``core`` follow
+        registry.wait_previous_denoise()
+        contexts = [coll.shard_batch(ce, mesh) for ce in contexts]
+        ys = [coll.shard_batch(ye, mesh) for ye in ys]
+    lat = as_device_array(raw)
+    lat_dev = coll.shard_batch(lat, mesh) if sharded else lat
+
+    def _build_entries(src, at):
         out = []
-        ys = []
-        for e in src:
-            ce = jnp.repeat(_align_tokens(e.context), total, axis=0)
-            if fanout > 1 and mesh is not None:
-                ce = coll.shard_batch(ce, mesh)
-            am = _materialize_area_mask(e, lat.shape[1], lat.shape[2],
-                                        total)
-            if (am is not None and fanout > 1 and mesh is not None
-                    and am.shape[0] == total):
+        for e, ce in zip(src, contexts[at:at + len(src)]):
+            am = _materialize_area_mask(e, lat_h, lat_w, total)
+            if am is not None and sharded and am.shape[0] == total:
                 # per-sample masks ride the data axis like the noise
                 # mask; single-row masks stay replicated
                 am = coll.shard_batch(np.asarray(am), mesh)
             srange = entry_sigma_range(model, e)
             out.append((ce, am,
                         float(getattr(e, "area_strength", 1.0)), srange))
-            if adm:
-                # each entry carries its OWN pooled ADM vector (regional
-                # SDXL: region B must not ride region A's pooled) —
-                # source selection shared with the tile refine
-                ye = _sdxl_vector_cond(
-                    model, adm_cond_source(model.family, e, positive),
-                    total, lat.shape[1] * 8, lat.shape[2] * 8)
-                if fanout > 1 and mesh is not None:
-                    ye = coll.shard_batch(ye, mesh)
-                ys.append(ye)
-        return out, ys
+        return out, ys[at:at + len(src)]
 
-    cond_entries, y_conds = _build_entries(pos_entries)
-    unc_entries, y_unconds = _build_entries(neg_entries)
-    mid_built, y_mids = _build_entries(mid_entries)
+    cond_entries, y_conds = _build_entries(pos_entries, 0)
+    unc_entries, y_unconds = _build_entries(neg_entries, len(pos_entries))
+    mid_built, y_mids = _build_entries(
+        mid_entries, len(pos_entries) + len(neg_entries))
     multi = len(cond_entries) > 1 or len(unc_entries) > 1 \
         or any(m is not None or s != 1.0 or sr is not None
                for _, m, s, sr in cond_entries + unc_entries + mid_built)
@@ -2148,8 +2156,6 @@ def _prepare_sample_inputs(ctx: OpContext, model, seed, latent_image,
                 ") requires plain single-entry positive/negative "
                 "conditionings")
         mid_ctx = mid_built[0][0]
-    unclip_adm = adm and getattr(model.family, "adm_kind",
-                                 "sdxl") == "unclip"
     if multi:
         ctx_arr = cond_entries
         unc_arr = unc_entries
@@ -2380,7 +2386,7 @@ def _prepare_sample_inputs(ctx: OpContext, model, seed, latent_image,
                          mid_context=mid_ctx, guidance=guidance,
                          cfg2=cfg2, c_concat=c_concat,
                          gligen_objs=gligen_objs,
-                         donate_latents=lat_dev is not raw_arr)
+                         donate_latents=lat_dev is not raw_arr, keys=keys)
 
 
 def _unclip_vector_cond(pipe, cond: Conditioning, batch: int):
@@ -2429,22 +2435,14 @@ def _unclip_vector_cond(pipe, cond: Conditioning, batch: int):
     return jnp.repeat(jnp.asarray(acc), batch, axis=0)
 
 
-def _sdxl_vector_cond(pipe, cond: Conditioning, batch: int,
-                      height: int, width: int):
-    """SDXL ADM vector: pooled text emb + size conditioning embeddings.
+def _sdxl_vector_source(pipe, cond: Conditioning, height: int,
+                        width: int):
+    """``(pooled, sizes)``: what an SDXL ADM vector is made from, both
+    still where they are (the pooled text embedding on the device, the
+    size scalars on the host), for ``registry.sampler_inputs``.
     A Conditioning carrying ``size_cond`` (CLIPTextEncodeSDXL /
     ...Refiner) supplies its own scalar tuple; otherwise the actual
-    latent dims stand in as (H, W, 0, 0, H, W).  unclip-ADM families
-    route to _unclip_vector_cond instead."""
-    from comfyui_distributed_tpu.models.layers import timestep_embedding
-    if getattr(pipe.family, "adm_kind", "sdxl") == "unclip":
-        return _unclip_vector_cond(pipe, cond, batch)
-    # the first device work of an SDXL request's sampler inputs: where
-    # the runtime holds the host until the previous denoise is done
-    registry.wait_previous_denoise()
-    pooled = cond.pooled
-    if pooled is None:
-        pooled = jnp.zeros((1, 1280))
+    latent dims stand in as (H, W, 0, 0, H, W)."""
     sc = getattr(cond, "size_cond", None)
     if sc is None:
         # fallback scalar layout when the encode node didn't supply one:
@@ -2456,14 +2454,19 @@ def _sdxl_vector_cond(pipe, cond: Conditioning, batch: int,
             sc = (height, width, 0, 0, 6.0)
         else:
             sc = (height, width, 0, 0, height, width)
-    sizes = jnp.asarray([[float(v) for v in sc]], jnp.float32)
-    emb = timestep_embedding(sizes.reshape(-1), 256).reshape(1, -1)
-    vec = jnp.concatenate([pooled, emb], axis=-1)
-    want = pipe.family.unet.adm_in_channels
-    if vec.shape[-1] < want:
-        vec = jnp.pad(vec, ((0, 0), (0, want - vec.shape[-1])))
-    vec = vec[:, :want]
-    return jnp.repeat(vec, batch, axis=0)
+    return cond.pooled, tuple(float(v) for v in sc)
+
+
+def _sdxl_vector_cond(pipe, cond: Conditioning, batch: int,
+                      height: int, width: int):
+    """SDXL ADM vector ``[batch, adm_in_channels]``: pooled text emb +
+    size conditioning embeddings, one program.  unclip-ADM families route
+    to _unclip_vector_cond instead."""
+    if getattr(pipe.family, "adm_kind", "sdxl") == "unclip":
+        return _unclip_vector_cond(pipe, cond, batch)
+    return registry.sampler_inputs(
+        pipe, batch,
+        vectors=[_sdxl_vector_source(pipe, cond, height, width)])[1][0]
 
 
 @register_op

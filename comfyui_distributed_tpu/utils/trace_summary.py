@@ -74,6 +74,13 @@ annotations.  The summary holds, per chip and as a mean over chips:
   ``idle_under`` is to ``idle``; ``top_idle_between``, the stretches that
   cost most by their owner and the programs on either side (``s``
   seconds, ``n`` stretches, ``owner``, ``before``, ``after``);
+* ``programs_per_denoise`` (PR 52): how many programs the host enqueues
+  an image batch: the executions of ANY program that begin from the
+  first whole execution of the denoise (``jit_core``) to the last one's
+  start, by the denoises that begin there, summed over the chips, so the
+  slice's edges cut nothing.  Each is an enqueue from Python, and the
+  device is dry while the next is being made unless a longer one covers
+  it.  Absent where no chip saw two whole denoises;
 * ``names_found``: whether any operation carried a path at all.
 """
 
@@ -96,6 +103,9 @@ HOST_PLANE = "/host:CPU"
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 EDGE_NS = 10_000        # an execution this close to the slice's edge is cut
+# the denoise scan's program: ``DiffusionPipeline.sample`` jits a function
+# named ``core`` (the benchmark's configurations name the same pattern)
+DENOISE_PROGRAM = "jit_core"
 _MODULE_ID = re.compile(r"\(\d+\)$")
 GAPS, NONE, IDLE = "gaps", "none", "idle"
 OP_NAME = "tf_op"       # the event-metadata statistic that holds op_name
@@ -658,6 +668,11 @@ def _chip(plane: dict, spans, timeline=None) -> Dict[str, Any]:
             chip["top_idle_between"] = _idle_by_executor(
                 idle_s, idle_e, ran[k], ran[k + 1], timeline)
     whole = (ms > t0 + EDGE_NS) & (ms + md < t1 - EDGE_NS)
+    cycles = ms[whole & (names_of[midx] == DENOISE_PROGRAM)]
+    if cycles.size > 1:
+        chip["denoise_cycles"] = int(cycles.size) - 1
+        chip["programs_in_cycles"] = int(
+            ((ms >= cycles[0]) & (ms < cycles[-1])).sum())
     # the execution each leaf operation started in
     k = np.searchsorted(ms, ls, "right") - 1
     inside = (k >= 0) & (ls < (ms + md)[np.maximum(k, 0)])
@@ -765,6 +780,10 @@ def summarize(events: dict, traced_s: float = 0.0) -> Dict[str, Any]:
                                   for c in chips) / len(chips),
         "programs": programs, "idle": idle,
         "idle_under": _mean([c["idle_under"] for c in chips])})
+    cycles = sum(c.get("denoise_cycles", 0) for c in chips)
+    if cycles:
+        out["programs_per_denoise"] = sum(
+            c.get("programs_in_cycles", 0) for c in chips) / cycles
     if all("idle_by_executor" in c for c in chips):
         by_owner = _mean([c["idle_by_executor"] for c in chips])
         by_class = dict.fromkeys(EXEC_CLASSES, 0.0)

@@ -654,6 +654,56 @@ def test_an_execution_that_holds_no_operation_is_idle_from_end_to_end():
         {"s": us(50), "n": 1.0, "before": ts.STARTS, "after": ts.ENDS}]
 
 
+def cycles_events():
+    """Three requests on chip 0, each a text encoder, the sampler's inputs,
+    the denoise and a decode (the first request's encoder is cut by the
+    slice's start, the last one's decode by its end); chip 1 sees that
+    first encoder, then the denoise and the decode of each, as a fan-out's
+    other chips do."""
+    k = 1000
+
+    def plane(chip, names, starts, durs):
+        uniq = sorted(set(names))
+        return {"name": f"/device:TPU:{chip}", "lines": [
+            {"name": "XLA Ops", "names": ["fusion.1"], "paths": [""],
+             "name_idx": [0] * len(starts), "start_ns": starts,
+             "dur_ns": durs},
+            {"name": "XLA Modules", "names": [f"{n}({i})"
+                                              for i, n in enumerate(uniq)],
+             "name_idx": [uniq.index(n) for n in names],
+             "start_ns": starts, "dur_ns": durs}]}
+
+    request = ["jit__unknown", "jit_sampler_inputs", "jit_core",
+               "jit__lambda"]
+    starts = [r * 100 * k + at * k for r in range(3)
+              for at in (0, 10, 20, 70)]
+    durs = [5 * k, 2 * k, 45 * k, 10 * k] * 3
+    other = [(n, s, d) for n, s, d in zip(request * 3, starts, durs)
+             if n in ("jit_core", "jit__lambda") or s == 0]
+    return {"planes": [
+        plane(0, request * 3, starts, durs),
+        plane(1, [n for n, _, _ in other], [s for _, s, _ in other],
+              [d for _, _, d in other])]}
+
+
+def test_programs_per_denoise_counts_whole_cycles_on_every_chip():
+    """From the first whole denoise's start to the last one's: 4 + 4
+    programs in 2 cycles on chip 0, 2 + 2 in 2 on chip 1; the slice's
+    edges (an encoder before the first denoise, a decode behind the last)
+    count in no cycle.  The summary's other fields are what they were."""
+    ev = cycles_events()
+    s = ts.summarize(ev)
+    assert [(c["denoise_cycles"], c["programs_in_cycles"])
+            for c in s["chips"]] == [(2, 8), (2, 4)]
+    assert s["programs_per_denoise"] == 3.0
+    assert s["programs"]["jit_core"]["count"] == 3.0
+    # one chip alone: a request's four programs
+    assert ts.summarize({"planes": ev["planes"][:1]})[
+        "programs_per_denoise"] == 4.0
+    # a slice that holds one whole denoise has no cycle to count
+    assert "programs_per_denoise" not in ts.summarize(accounted_events())
+
+
 @pytest.mark.parametrize("recorded, pinned", [
     ("tpu_v5e_unet_block_scan.xplane.pb",
      "tpu_v5e_unet_block_scan.summary_pr35.json"),
